@@ -1,7 +1,8 @@
 """Serve a tiny GPT-2 through the LLM inference plane and stream
 tokens — over the deployment handle and over HTTP (chunked ndjson).
 
-Run:  JAX_PLATFORMS=cpu python examples/serve_llm.py
+Run:  python examples/serve_llm.py   (on a TPU host the replica leases
+one chip; elsewhere it computes on the CPU)
 
 The deployment hosts one continuous-batching GenerationEngine per
 replica (paged KV cache, step-granularity admission); requests carry

@@ -72,6 +72,12 @@ def init(
     if log_to_driver is not None:
         overrides["log_to_driver"] = log_to_driver
     cfg = RuntimeConfig.from_env(overrides)
+    # One XLA compile cache for every process of the runtime: where
+    # JAX_COMPILATION_CACHE_DIR says, else the fixed default; the
+    # processes this driver starts inherit it.
+    from .util import compile_cache
+
+    compile_cache.apply()
     if address and address.startswith("rt://"):
         # Remote driver: one connection to the head's ClientServer; no
         # cluster-routable agent needed on this machine (ref:

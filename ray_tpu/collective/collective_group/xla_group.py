@@ -53,10 +53,7 @@ def _ensure_jax_world(store, group_name: str, world_size: int,
     # compiled into jaxlib; the flag only affects CPU client creation,
     # so it is harmless on TPU.  Must happen before the first backend
     # touch — the client is built lazily on first jax.devices().
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # older jaxlib without the flag: CPU stays single-process
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     key = f"col/{group_name}/coordinator"
     # Entry-stamped as gang op #0 (the regular collectives start at
     # seq 1): while a rank sits inside the rendezvous — waiting for

@@ -472,9 +472,23 @@ class Controller:
     async def _health_loop(self) -> None:
         period = self.config.raylet_heartbeat_period_ms / 1000.0
         threshold = period * self.config.health_check_failure_threshold
+        last_tick = time.time()
         while not self._shutdown.is_set():
             await asyncio.sleep(period)
             now = time.time()
+            late = now - last_tick - period
+            last_tick = now
+            if late > period:
+                # This loop itself ran late: the controller's event
+                # loop was stalled, or the whole machine was (a TPU
+                # runtime starting up pins memory, and a VM can stand
+                # still for seconds while it does).  No heartbeat could
+                # be received in that time either, so it is not counted
+                # against the nodes.
+                logger.warning("health loop ran %.1fs late; not counted "
+                               "as missed heartbeats", late)
+                for node in self.nodes.values():
+                    node.last_heartbeat += late
             for node in list(self.nodes.values()):
                 if node.alive and now - node.last_heartbeat > threshold:
                     await self._mark_node_dead(node, "missed heartbeats")

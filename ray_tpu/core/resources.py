@@ -5,9 +5,9 @@ pluggable accelerator managers (ref: src/ray/common/scheduling/,
 python/ray/_private/accelerators/tpu.py).  Resources are float-valued named
 capacities; "CPU", "TPU", and "memory" are predefined.  TPU detection reads
 /dev/accel* and vfio device nodes the way the reference's
-TPUAcceleratorManager does, plus JAX-visible device count as a fallback, and
-publishes pod/topology extra resources so multi-host slices can gang-schedule
-with node affinity.
+TPUAcceleratorManager does (never JAX: that would make the agent the chip's
+owner), and publishes pod/topology extra resources so multi-host slices can
+gang-schedule with node affinity.
 """
 
 from __future__ import annotations
@@ -80,38 +80,59 @@ class ResourceSet:
 @dataclass
 class TPUInfo:
     num_chips: int
-    accelerator_type: str  # e.g. "v5e"
+    accelerator_type: str  # e.g. "v5e"; "" where the host does not say
     topology: str  # e.g. "2x4"
     pod_name: Optional[str] = None
     worker_id: int = 0
 
 
+# PCI ids of TPU chips as the host shows them (vendor 0x1ae0 is Google).
+# Only ids read off a real host are listed: 0x0063 on a v5e (PR 21).
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICE_GEN = {"0x0063": "v5e"}
+
+
+def _chip_device_nodes() -> list:
+    """The chips' device nodes.  A v5e host shows one ``/dev/vfio/<n>``
+    per chip beside the ``/dev/vfio/vfio`` control node (a one-chip
+    machine shows e.g. ``/dev/vfio/2`` alone: the name is an IOMMU
+    group, not a chip index); older hosts show ``/dev/accel<n>``."""
+    nodes = glob.glob("/dev/accel*")
+    if not nodes:
+        nodes = [v for v in glob.glob("/dev/vfio/*")
+                 if os.path.basename(v).isdigit()]
+    return sorted(nodes)
+
+
+def _pci_generation() -> str:
+    """The chip generation read from the PCI bus, "" where unknown."""
+    for dev in glob.glob("/sys/bus/pci/devices/*"):
+        try:
+            with open(os.path.join(dev, "vendor")) as f:
+                if f.read().strip() != _GOOGLE_PCI_VENDOR:
+                    continue
+            with open(os.path.join(dev, "device")) as f:
+                gen = _TPU_PCI_DEVICE_GEN.get(f.read().strip())
+        except OSError:
+            continue
+        if gen:
+            return gen
+    return ""
+
+
 def detect_tpu(override_chips: int = 0) -> Optional[TPUInfo]:
-    """Detect local TPU chips.
+    """Detect local TPU chips without touching them.
 
-    Mirrors the detection strategy of the reference's TPUAcceleratorManager
-    (ref: python/ray/_private/accelerators/tpu.py:97-110): count /dev/accel*
-    or /dev/vfio device nodes, read GCE TPU env/metadata when present.  We
-    additionally fall back to a cheap JAX device query only if explicitly
-    requested by env (importing jax is expensive for control-plane procs).
+    Counts device nodes the way the reference's TPUAcceleratorManager
+    does (ref: python/ray/_private/accelerators/tpu.py:97-110) and
+    reads GCE TPU env when present.  It never asks JAX: the first
+    process to initialise a backend owns the chip, and that must be a
+    leased worker, not the node agent.
     """
-    if override_chips:
-        chips = override_chips
-    else:
-        chips = len(glob.glob("/dev/accel*"))
-        if chips == 0:
-            vfio = glob.glob("/dev/vfio/*")
-            chips = len([v for v in vfio if os.path.basename(v).isdigit()])
-        if chips == 0 and os.environ.get("RT_TPU_FROM_JAX") == "1":
-            try:
-                import jax  # noqa: deferred, expensive
-
-                chips = len([d for d in jax.devices() if d.platform == "tpu"])
-            except Exception:
-                chips = 0
+    chips = override_chips or len(_chip_device_nodes())
     if chips == 0:
         return None
-    accel = os.environ.get("TPU_ACCELERATOR_TYPE", "v5e")
+    accel = os.environ.get("TPU_ACCELERATOR_TYPE") or _pci_generation()
     topology = os.environ.get("TPU_TOPOLOGY", "")
     pod = os.environ.get("TPU_NAME") or os.environ.get("TPU_WORKER_HOSTNAMES")
     worker_id = int(os.environ.get("TPU_WORKER_ID", "0") or 0)
@@ -138,7 +159,7 @@ def node_resources(
             amounts[TPU] = float(info.num_chips)
             # Pod-level gang-scheduling labels, as resource entries the way the
             # reference exposes TPU-{type}-{topology}-head (ref: tpu.py:230,330).
-            if info.topology:
+            if info.topology and info.accelerator_type:
                 amounts[f"TPU-{info.accelerator_type}-{info.topology}-head"] = (
                     1.0 if info.worker_id == 0 else 0.0
                 )

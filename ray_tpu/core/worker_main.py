@@ -9,9 +9,10 @@ creation becomes that actor's dedicated process with per-caller ordered
 method queues, a thread pool honoring ``max_concurrency``, and native
 asyncio execution for coroutine methods.
 
-TPU isolation: chip ids granted with the lease are exported as
-``TPU_VISIBLE_CHIPS`` *before* any user code imports jax, the analogue of
-the reference's per-worker CUDA_VISIBLE_DEVICES handling (ref:
+TPU isolation: chip ids granted with the lease are exported to libtpu
+before the worker's first JAX backend initialisation, and a worker with
+no chip lease is kept off the chips (core/chip_lease.py) — the analogue
+of the reference's per-worker CUDA_VISIBLE_DEVICES handling (ref:
 python/ray/_private/accelerators/tpu.py TPU_VISIBLE_CHIPS).
 """
 
@@ -45,6 +46,7 @@ from typing import Any, Dict, List, Optional, Tuple  # noqa: E402
 # telemetry, and the collective stack are imported lazily at first
 # use — a prestarted pool worker must be cheap to fork, and most
 # workers never touch most of that stack until their first frame.
+from . import chip_lease  # noqa: E402
 from . import runtime as runtime_mod  # noqa: E402
 from . import serialization  # noqa: E402
 from .cluster_runtime import ClusterRuntime  # noqa: E402
@@ -224,18 +226,17 @@ class Worker:
                 now = time.time()
                 if now - last_metrics >= period:
                     last_metrics = now
-                    import sys as _sys
-
                     # Device-memory watermarks ride the metrics tick,
-                    # but only once user code has already paid the jax
-                    # import — a no-jax worker must not drag it in.
-                    if "jax" in _sys.modules:
-                        try:
-                            from ray_tpu.util import xprof as _xprof
+                    # read only from a backend user code has already
+                    # started: the tick never starts one (that would
+                    # take the chip) and never imports jax.
+                    from ray_tpu.util import xprof as _xprof
 
-                            _xprof.publish_device_memory()
-                        except Exception:
-                            pass
+                    try:
+                        _xprof.publish_device_memory()
+                    except Exception:
+                        logger.debug("device memory poll failed",
+                                     exc_info=True)
                     from ray_tpu.util.metrics import registry
 
                     snap = registry().snapshot()
@@ -492,10 +493,6 @@ class Worker:
 
     def _execute_sync(self, spec: TaskSpec, fn, lease_id: Optional[int],
                       chip_ids: List[int]) -> TaskResult:
-        if chip_ids:
-            os.environ["TPU_VISIBLE_CHIPS"] = ",".join(map(str, chip_ids))
-            os.environ.setdefault("TPU_CHIPS_PER_PROCESS_BOUNDS",
-                                  f"1,{len(chip_ids)},1")
         prev_lease = self.runtime.current_lease_id
         if lease_id is not None:
             self.runtime.current_lease_id = lease_id
@@ -531,6 +528,7 @@ class Worker:
             spec.hp[hotpath.EXEC_START] = time.perf_counter()
         self._emit_event(spec, "RUNNING", **trace_extra)
         try:
+            chip_lease.apply_lease(chip_ids)
             pos, kwargs = self._resolve_args(spec)
             result = fn(*pos, **kwargs)
             out = self._package_returns(spec, result)
@@ -909,8 +907,6 @@ class Worker:
             return {"ok": False,
                     "error": repr(RuntimeEnvSetupError(env_err))}
         chip_ids = p.get("chip_ids") or []
-        if chip_ids:
-            os.environ["TPU_VISIBLE_CHIPS"] = ",".join(map(str, chip_ids))
         self.runtime.current_lease_id = p.get("lease_id")
         cls = self._load_func(spec)
         loop = asyncio.get_event_loop()
@@ -918,6 +914,7 @@ class Worker:
         def _construct():
             self.runtime.set_current_task(spec.task_id)
             try:
+                chip_lease.apply_lease(chip_ids)
                 pos, kwargs = self._resolve_args(spec)
                 return cls(*pos, **kwargs), None
             except BaseException as e:  # noqa: BLE001

@@ -39,6 +39,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ..util import chips
 from .kv_cache import PagePool, init_cache, pages_for
 from .sampling import SamplingParams, sample
 
@@ -107,6 +108,23 @@ class _Sequence:
         self.warmup = warmup
 
 
+def jit_forward(model):
+    """The engine's one jitted forward: it serves prefill ([1, bucket])
+    and decode ([max_batch, 1]); XLA specializes per shape.  Donating
+    the pooled KV buffers makes the update in-place on TPU."""
+    import jax
+
+    def fwd(p, tokens, k_pages, v_pages, page_table, positions):
+        logits, new = model.apply(
+            p, tokens,
+            kv_cache={"k_pages": k_pages, "v_pages": v_pages,
+                      "page_table": page_table},
+            positions=positions)
+        return logits, new["k_pages"], new["v_pages"]
+
+    return jax.jit(fwd, donate_argnums=(2, 3))
+
+
 class GenerationEngine:
     """Continuous-batching engine for one GPT-2 / Llama replica."""
 
@@ -136,6 +154,8 @@ class GenerationEngine:
         else:
             raise TypeError(f"unsupported model_cfg {type(model_cfg)}")
         self._params = params
+        # What this engine computes on, as JAX reports it (stats()).
+        self._device = chips.describe_devices()
         head_dim = model_cfg.d_model // model_cfg.n_head
         self.max_context = min(
             self.cfg.max_context or model_cfg.max_seq, model_cfg.max_seq,
@@ -147,22 +167,12 @@ class GenerationEngine:
                               self.cfg.page_size, n_kv, head_dim,
                               model_cfg.dtype)
 
-        def fwd(p, tokens, k_pages, v_pages, page_table, positions):
-            logits, new = self._model.apply(
-                p, tokens,
-                kv_cache={"k_pages": k_pages, "v_pages": v_pages,
-                          "page_table": page_table},
-                positions=positions)
-            return logits, new["k_pages"], new["v_pages"]
-
-        # One jitted forward serves prefill ([1, bucket]) and decode
-        # ([max_batch, 1]); XLA specializes per shape.  Donating the
-        # pooled KV buffers makes the update in-place on TPU.
-        self._fwd = jax.jit(fwd, donate_argnums=(2, 3))
+        self._fwd = jit_forward(self._model)
         # Per-shape AOT executables (lower().compile()): the compile
         # is timed and the program registered with the xprof plane
-        # (rt perf); None marks a shape that fell back to plain jit.
+        # (rt perf).
         self._fwd_cache: Dict[Any, Any] = {}
+        self._compile_seconds: Dict[str, float] = {}
 
         self._lock = threading.Lock()
         self._wake = threading.Condition(self._lock)
@@ -338,8 +348,10 @@ class GenerationEngine:
             self.stop()
 
     def stats(self) -> Dict[str, Any]:
+        peak_hbm = chips.peak_device_memory_bytes()
         with self._lock:
             return {
+                "peak_hbm_bytes": peak_hbm,
                 "kv_pages_used": self.pool.used,
                 "kv_pages_total": self.pool.num_pages,
                 "running": len(self._running),
@@ -352,6 +364,9 @@ class GenerationEngine:
                 "max_context": self.max_context,
                 "step_errors": self._step_errors,
                 "last_error": self._last_error,
+                # Compiled forwards by name -> compile seconds.
+                "programs": dict(self._compile_seconds),
+                "device": dict(self._device),
                 # TTFT phase + TPOT accounting (bench decomposition).
                 "ttft_requests": self._ttft_requests,
                 "ttft_waiting_s_total": self._waiting_s_total,
@@ -467,45 +482,31 @@ class GenerationEngine:
 
         First sight of a (kind, token-shape) pair pays the one compile
         jit would pay anyway, but via ``lower().compile()`` so the
-        compile is timed, counted (``rt_xla_compiles_total``) and the
-        program's cost/memory/collective facts registered with the
-        xprof plane.  Any AOT failure falls back to the plain jit path
-        — observability must never fail the request path."""
+        compile is timed (``stats()["programs"]``), counted
+        (``rt_xla_compiles_total``) and the program's cost/memory facts
+        registered with the xprof plane.  There is one way to compile
+        and run: a compile error or a run error surfaces as itself (the
+        KV pages are donated, so there is nothing to retry with)."""
         key = (kind, args[1].shape)
         cached = self._fwd_cache.get(key)
         # A cache entry is only valid for the _fwd it was compiled
         # from — if _fwd was swapped (fault injection, hot reload) the
         # stale executable must not keep serving.
         if cached is None or cached[0] is not self._fwd:
-            exe = None
             t0 = time.perf_counter()
-            try:
-                exe = self._fwd.lower(*args).compile()
-            except Exception:
-                exe = None
+            exe = self._fwd.lower(*args).compile()
+            dt = time.perf_counter() - t0
+            name = f"llm_{kind}[{args[1].shape[1]}]" \
+                if kind == "prefill" else f"llm_{kind}"
+            self._compile_seconds[name] = dt
             try:
                 from ..util import xprof
 
-                name = f"llm_{kind}[{args[1].shape[1]}]" \
-                    if kind == "prefill" else f"llm_{kind}"
-                if exe is not None:
-                    xprof.register_compiled(
-                        name, exe,
-                        compile_seconds=time.perf_counter() - t0)
-                else:
-                    xprof.count_compile(
-                        name, time.perf_counter() - t0)
+                xprof.register_compiled(name, exe, compile_seconds=dt)
             except Exception:
-                pass
-            self._fwd_cache[key] = (self._fwd, exe)
-        _, exe = self._fwd_cache[key]
-        if exe is None:
-            return self._fwd(*args)
-        try:
-            return exe(*args)
-        except Exception:
-            self._fwd_cache[key] = (self._fwd, None)
-            return self._fwd(*args)
+                pass    # registering with xprof is best-effort
+            cached = self._fwd_cache[key] = (self._fwd, exe)
+        return cached[1](*args)
 
     def _prefill(self, seq: _Sequence) -> None:
         n = len(seq.tokens)

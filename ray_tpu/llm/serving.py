@@ -22,8 +22,10 @@ KV pages (the eviction path, pinned by tests).
 
 from __future__ import annotations
 
+import os
 from typing import Any, Dict, Optional
 
+from ..util import compile_cache
 from .engine import EngineConfig, GenerationEngine
 from .sampling import SamplingParams
 
@@ -53,7 +55,9 @@ class LLMDeployment:
         # background thread builds + warms the engine; requests block
         # on readiness.
         self._ready = threading.Event()
-        self._init_error: Optional[str] = None
+        # This replica process's persistent compile-cache hits/misses.
+        self._cache_counts = compile_cache.watch()
+        self._init_error: Optional[BaseException] = None
         self._engine: Optional[GenerationEngine] = None
 
         def _build() -> None:
@@ -67,7 +71,9 @@ class LLMDeployment:
                     engine.warmup()
                 self._engine = engine
             except Exception as e:  # noqa: BLE001 — surfaced per call
-                self._init_error = repr(e)
+                # ...and by check_health(): a replica whose engine
+                # never came up is replaced, not kept routable.
+                self._init_error = e
             finally:
                 self._ready.set()
 
@@ -80,8 +86,15 @@ class LLMDeployment:
             raise RuntimeError("LLM engine initialization timed out")
         if self._init_error is not None:
             raise RuntimeError(
-                f"LLM engine failed to initialize: {self._init_error}")
+                "LLM engine failed to initialize: "
+                f"{self._init_error!r}") from self._init_error
         return self._engine
+
+    def check_health(self) -> None:
+        """Serve's health probe: raises once engine initialisation has
+        failed (still building is healthy — it answers probes)."""
+        if self._init_error is not None:
+            self._engine_or_raise(0)
 
     def __call__(self, payload: Optional[Dict[str, Any]]):
         engine = self._engine_or_raise()
@@ -123,7 +136,13 @@ class LLMDeployment:
             engine.cancel(seq.sid)
 
     def stats(self) -> Dict[str, Any]:
-        return self._engine_or_raise().stats()
+        """The engine's stats, plus this replica process's persistent
+        compile-cache hits and misses."""
+        out = self._engine_or_raise().stats()
+        out["compile_cache"] = {
+            "dir": os.environ.get(compile_cache.ENV),
+            **self._cache_counts}
+        return out
 
 
 def llm_deployment(name: str = "llm", model: str = "gpt2",
@@ -142,15 +161,23 @@ def llm_deployment(name: str = "llm", model: str = "gpt2",
     through the existing request autoscaler.  ``max_ongoing_requests``
     defaults to the engine's max_batch so admission control saturates
     exactly when the continuous batch does.
+
+    Each replica LEASES the chip it computes on, like any other TPU
+    actor, so the scheduler never places other chip work on it: one
+    chip where the running cluster has chips, none on a CPU cluster.
     """
     from .. import serve
+    from ..util.chips import max_node_chips
 
     engine_cfg = engine_cfg or EngineConfig()
     if max_ongoing_requests is None:
         max_ongoing_requests = engine_cfg.max_batch
+    actor_options: Dict[str, Any] = {"num_cpus": num_cpus}
+    if max_node_chips() > 0:
+        actor_options["num_tpus"] = 1
     dep = serve.deployment(
         LLMDeployment, name=name, num_replicas=num_replicas,
-        ray_actor_options={"num_cpus": num_cpus},
+        ray_actor_options=actor_options,
         autoscaling_config=autoscaling,
         route_prefix=route_prefix,
         max_ongoing_requests=max_ongoing_requests)

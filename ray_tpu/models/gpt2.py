@@ -108,8 +108,21 @@ def _attention(cfg: GPT2Config, q, k, v):
         # in 1024-blocks with causal block-skipping.
         from ..ops import flash_attention
 
-        return flash_attention(q, k, v, causal=True,
-                               block_q=1024, block_k=1024)
+        flash = functools.partial(flash_attention, causal=True,
+                                  block_q=1024, block_k=1024)
+        if cfg.mesh is None or cfg.mesh.size == 1:
+            return flash(q, k, v)
+        # A Mosaic kernel is not partitioned automatically: across a
+        # mesh it runs per shard, batch and heads split as the rules
+        # say (attention is independent over both; the sequence stays
+        # whole — splitting it is ring attention's job).
+        from jax import shard_map
+
+        rules = (cfg.rules or ShardingRules()).prune(cfg.mesh)
+        spec = rules.spec(("batch", None, "heads", None))
+        return shard_map(flash, mesh=cfg.mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)
     if cfg.attn_impl == "dense":
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                             preferred_element_type=jnp.float32)

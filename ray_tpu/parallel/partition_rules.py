@@ -19,6 +19,7 @@ passes an explicit ``default`` spec.
 
 from __future__ import annotations
 
+import math
 import re
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -130,26 +131,34 @@ def spec_from_json(data: Optional[Sequence]) -> Any:
     return PS(*entries)
 
 
-def prune_spec(spec: Any, axis_sizes: Dict[str, int]) -> Any:
+def prune_spec(spec: Any, axis_sizes: Dict[str, int],
+               shape: Optional[Sequence[int]] = None) -> Any:
     """Drop mesh axes a smaller/renamed mesh no longer has (or has at
     size 1) from a spec — how a checkpoint saved under
     ``P('fsdp', 'tensor')`` restores onto a mesh with no ``tensor``
-    axis: the dim simply stops being partitioned."""
+    axis: the dim simply stops being partitioned.  With ``shape``, an
+    axis is also dropped from a dim it does not divide (GPT-2's vocab
+    of 50257 over ``tensor=2``): that dim stays whole instead of the
+    placement failing."""
     from jax.sharding import PartitionSpec as PS
 
     entries = []
-    for entry in tuple(spec):
+    for i, entry in enumerate(tuple(spec)):
         if entry is None:
             entries.append(None)
             continue
         axes = entry if isinstance(entry, (tuple, list)) else (entry,)
-        kept = tuple(a for a in axes if axis_sizes.get(a, 1) > 1)
+        kept = [a for a in axes if axis_sizes.get(a, 1) > 1]
+        if shape is not None:
+            while kept and shape[i] % math.prod(
+                    axis_sizes[a] for a in kept):
+                kept.pop()
         if not kept:
             entries.append(None)
         elif len(kept) == 1:
             entries.append(kept[0])
         else:
-            entries.append(kept)
+            entries.append(tuple(kept))
     while entries and entries[-1] is None:
         entries.pop()
     return PS(*entries)

@@ -92,8 +92,16 @@ def with_logical_constraint(x, logical: LogicalAxes, mesh=None,
         # Under shard_map/jit with an ambient mesh, bare specs work.
         return jax.lax.with_sharding_constraint(
             x, rules.spec(logical))
-    return jax.lax.with_sharding_constraint(
-        x, logical_sharding(mesh, logical, rules))
+    from jax.sharding import NamedSharding
+
+    from .partition_rules import prune_spec
+
+    # Fitted to the array: an axis that does not divide its dim (a
+    # vocab of 50257 over tensor=2) leaves that dim unconstrained.
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    spec = prune_spec(logical_sharding(mesh, logical, rules).spec,
+                      sizes, x.shape)
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def shard_pytree(tree, mesh, logical_fn, rules=None):

@@ -19,6 +19,9 @@ import ray_tpu
 from .deployment import Application, Deployment
 
 CONTROLLER_NAME = "rt_serve_controller"
+# How long a replica that has not yet answered its first health probe
+# may keep timing out before it is replaced.
+REPLICA_STARTUP_GRACE_S = 120.0
 
 
 class _Replica:
@@ -199,6 +202,12 @@ class _Replica:
         return self._open_streams
 
     def health(self) -> bool:
+        """Alive, and — where the deployment defines ``check_health``
+        (ref: serve's user-defined health check) — healthy by its own
+        account: raising there gets the replica replaced."""
+        check = getattr(self._instance, "check_health", None)
+        if check is not None:
+            check()
         return True
 
 
@@ -377,10 +386,31 @@ class ServeController:
             entry = self.deployments.get(name)
             if entry is None or entry["gen"] != gen:
                 return  # redeployed/deleted while probing; stale view
+            # Replica key -> time of its first probe timeout, 0.0 once
+            # it has answered a probe.
+            startup = entry.setdefault("startup", {})
+            now = time.time()
             for i, h in enumerate(health):
-                if isinstance(h, Exception):
-                    self.replace_dead_replica(name, i,
-                                              reason="health_probe")
+                key = replicas[i].actor_id.hex()
+                if not isinstance(h, Exception):
+                    startup[key] = 0.0
+                    continue
+                # A probe that merely TIMES OUT on a replica that has
+                # never answered one is a replica still starting (a
+                # chip lease waits for a fresh worker, which then
+                # imports the ML stack): give it REPLICA_STARTUP_GRACE_S
+                # before calling it dead.  A dead actor's probe fails
+                # at once with its own error, and is replaced at once.
+                if isinstance(h, TimeoutError) \
+                        and startup.get(key) != 0.0 \
+                        and now - startup.setdefault(key, now) \
+                        < REPLICA_STARTUP_GRACE_S:
+                    continue
+                self.replace_dead_replica(name, i,
+                                          reason="health_probe")
+            live = {r.actor_id.hex() for r in entry["replicas"]}
+            entry["startup"] = {k: v for k, v in startup.items()
+                                if k in live}
             counts = [v for v in ongoing
                       if not isinstance(v, Exception)]
             self._autoscale_locked(entry, name, counts)
@@ -1118,10 +1148,18 @@ class DeploymentHandle:
         # + first-frame window (time-to-first-token, for generation).
         item_timeout = max(timeout_s or 0.0, 120.0)
         tried: set = set()
+        last_fault: Optional[BaseException] = None
         for attempt in range(self._max_retries + 1):
             t_att = time.time()
             with tracing.request_scope(rid):
-                replica, key = self._pick(exclude=tried, strict=True)
+                try:
+                    replica, key = self._pick(exclude=tried, strict=True)
+                except Exception as e:
+                    # Out of replicas on a RETRY: say what the earlier
+                    # attempts died of, not only that none is left.
+                    if last_fault is not None:
+                        raise e from last_fault
+                    raise
                 gen = replica.handle_request_stream.options(
                     num_returns="streaming").remote(args, kwargs)
             delivered = 0
@@ -1185,6 +1223,7 @@ class DeploymentHandle:
                     raise
                 self._breakers.record_failure(key)
                 tried.add(key)
+                last_fault = e
                 if delivered == 0:
                     if attempt < self._max_retries and \
                             not deadline.expired:

@@ -326,12 +326,30 @@ def shard_host_tree(tree: Any, mesh, specs: Any) -> Any:
     return jax.tree_util.tree_map(put, tree, shardings)
 
 
+def fitted_state_specs(state: Any, mesh, rules, *,
+                       default: Any = None) -> Any:
+    """``state_specs`` fitted to THIS mesh and these shapes: an axis
+    the mesh lacks, or one that does not divide its dim (GPT-2's vocab
+    of 50257 over tensor=2), leaves that dim whole.  These are the
+    specs the placed arrays really have, so they are what a manifest
+    records.  Works on shapes (``jax.eval_shape``) as on arrays."""
+    import jax
+
+    from ..parallel.partition_rules import prune_spec
+
+    sizes = mesh_axis_sizes(mesh)
+    return jax.tree_util.tree_map(
+        lambda leaf, spec: prune_spec(spec, sizes,
+                                      getattr(leaf, "shape", ())),
+        state, state_specs(state, rules, default=default))
+
+
 def shard_train_state(state: Any, mesh, rules, *,
                       default: Any = None) -> Tuple[Any, Any]:
     """Rule-driven NamedSharding placement of a TrainState onto the
     gang mesh; returns ``(sharded_state, specs)`` — the specs double
     as the sharded checkpoint plane's per-leaf manifest specs."""
-    specs = state_specs(state, rules, default=default)
+    specs = fitted_state_specs(state, mesh, rules, default=default)
     return shard_host_tree(state, mesh, specs), specs
 
 

@@ -151,12 +151,6 @@ class TrainSession:
             Gauge("rt_train_tokens_per_sec",
                   "Per-worker training throughput.").set(tps)
             if tel.model_flops_per_token > 0:
-                peak = tel.resolved_peak_flops() * max(
-                    tel.devices_per_worker, 1)
-                Gauge("rt_train_mfu",
-                      "Achieved model FLOPs utilization (0-1) from "
-                      "the declared FLOPs-per-token figure.").set(
-                    tps * tel.model_flops_per_token / peak)
                 # The roofline's measured point: achieved model
                 # FLOP/s per worker (rt perf plots it against the
                 # attainable ceiling at the program's intensity).
@@ -164,6 +158,14 @@ class TrainSession:
                       "Achieved model FLOP/s per worker from the "
                       "declared FLOPs-per-token figure.").set(
                     tps * tel.model_flops_per_token)
+                # Last: a device with no row in the chip peak table
+                # (the CPU) raises here and gets no MFU gauge.
+                peak = tel.resolved_peak_flops() * max(
+                    tel.devices_per_worker, 1)
+                Gauge("rt_train_mfu",
+                      "Achieved model FLOPs utilization (0-1) from "
+                      "the declared FLOPs-per-token figure.").set(
+                    tps * tel.model_flops_per_token / peak)
         except Exception:
             pass  # telemetry must never fail a training step
 

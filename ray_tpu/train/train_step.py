@@ -140,12 +140,13 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
         except Exception:
             pass
 
-    # aot[0]: None = first call pending, False = fell back to the
-    # shape-polymorphic jit path, else the AOT-compiled executable
-    # (the execution path from call one — compiling via
-    # ``lower().compile()`` instead of jit's implicit cache lets the
-    # xprof plane harvest cost/memory/collective facts without paying
-    # a second compile).
+    # The one execution path: the first call compiles ahead of time
+    # (``lower().compile()``, so the xprof plane harvests cost/memory/
+    # collective facts from the executable that runs, with no second
+    # compile) and every call runs that executable.  A compile error,
+    # a run error, or inputs the executable was not compiled for
+    # surface as themselves: the state is donated, so there is nothing
+    # to retry with.
     aot = [None]
 
     def timed_step(state, batch):
@@ -154,30 +155,9 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
         t0 = _time.perf_counter()
         with goodput.ledger().phase(phase):
             if first:
-                try:
-                    aot[0] = jitted.lower(state, batch).compile()
-                except Exception:
-                    aot[0] = False
-            exe = aot[0] if aot[0] else jitted
-            try:
-                out = exe(state, batch)
-            except Exception:
-                if exe is jitted:
-                    raise
-                # New input shapes/shardings vs the AOT executable:
-                # fall back to the polymorphic jit path for good and
-                # count the recompile.
-                aot[0] = False
-                rt0 = _time.perf_counter()
-                out = jitted(state, batch)
-                try:
-                    from ..util import xprof
-
-                    xprof.count_compile(
-                        "train_step",
-                        _time.perf_counter() - rt0)
-                except Exception:
-                    pass
+                aot[0] = jitted.lower(state, batch).compile()
+                timed_step.compile_seconds = _time.perf_counter() - t0
+            out = aot[0](state, batch)
         dt = _time.perf_counter() - t0
         try:
             from ..util.metrics import Gauge, Histogram
@@ -186,19 +166,22 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
                 Gauge("rt_train_compile_seconds",
                       "Host-side duration of the first (tracing + "
                       "XLA compile) step invocation.").set(dt)
-                if aot[0]:
-                    from ..util import xprof
+                from ..util import xprof
 
-                    xprof.register_compiled("train_step", aot[0],
-                                            mesh_axes=mesh_axes,
-                                            compile_seconds=dt)
+                xprof.register_compiled("train_step", aot[0],
+                                        mesh_axes=mesh_axes,
+                                        compile_seconds=dt)
             else:
                 Histogram("rt_train_step_dispatch_seconds",
                           "Host-side duration of the jitted step call "
                           "(approximate under async dispatch)."
                           ).observe(dt)
         except Exception:
-            pass
+            pass    # registering with xprof is best-effort
         return out
 
+    # The executable every call runs (None before the first call), and
+    # what its compile took: callers check the program that ran.
+    timed_step.compiled = lambda: aot[0]
+    timed_step.compile_seconds = None
     return timed_step

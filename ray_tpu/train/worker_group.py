@@ -99,6 +99,17 @@ class WorkerGroup:
             "resources": res or None,
             "max_concurrency": 2,  # run() + control calls
         }
+        if opts["num_tpus"]:
+            # A demand no node can ever meet would otherwise sit out
+            # the placement timeout (or pend forever as a bare actor).
+            from ..util.chips import max_node_chips
+
+            have = max_node_chips()
+            if opts["num_tpus"] > have:
+                raise RuntimeError(
+                    f"each training worker asks for {opts['num_tpus']:g}"
+                    f" TPU chip(s) but the largest node has {have:g}; "
+                    "not schedulable on this cluster")
         if placement_strategy:
             bundles = []
             for _ in range(num_workers):

@@ -5,7 +5,7 @@ wall-clock spent on useful training steps versus everything that is not
 (cf. Google's ML Goodput methodology; the reference ships an equivalent
 through ray train's metrics + dashboard stack).  This module is the
 process-local half of that layer: a ledger that attributes elapsed time
-to one of a fixed phase taxonomy
+to one of a fixed set of phases
 
     compute     — running training steps on the accelerator
     compile     — XLA tracing/compilation (first step, reshards)
@@ -119,7 +119,7 @@ class GoodputLedger:
     def enter(self, name: str) -> None:
         if name not in self._seconds:
             raise ValueError(
-                f"unknown goodput phase {name!r} (taxonomy: "
+                f"unknown goodput phase {name!r} (known phases: "
                 f"{sorted(self._seconds)} — 'idle' is derived)")
         with self._lock:
             self._attribute(self._clock())

@@ -133,6 +133,8 @@ def cluster_summary(*, address: Optional[str] = None) -> Dict[str, Any]:
                 prog = _xla_prog(tags.get("fn", "?"))
                 if name == "rt_xla_cost_flops":
                     prog["flops"] = max(prog["flops"], val)
+                    if tags.get("device_kind"):
+                        prog["device_kind"] = tags["device_kind"]
                 elif name == "rt_xla_cost_bytes":
                     prog["bytes"] = max(prog["bytes"], val)
                 elif name == "rt_xla_memory_bytes":

@@ -163,10 +163,11 @@ def test_step_failure_poisons_inflight_but_engine_survives(setup):
     eng, params = setup
     real_fwd = eng._fwd
 
-    def boom(*a, **k):
-        raise RuntimeError("injected step failure")
+    class Boom:
+        def lower(self, *a, **k):
+            raise RuntimeError("injected step failure")
 
-    eng._fwd = boom
+    eng._fwd = Boom()
     try:
         frames = list(eng.frames(eng.submit([1, 2, 3], max_tokens=5)))
         assert "error" in frames[-1]

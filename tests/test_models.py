@@ -84,17 +84,3 @@ def test_llama_forward_and_loss():
     assert np.isfinite(float(loss))
     # Untrained loss should be near ln(vocab).
     assert abs(float(loss) - np.log(cfg.vocab_size)) < 1.0
-
-
-def test_graft_entry_points():
-    import importlib.util
-    import sys
-
-    spec = importlib.util.spec_from_file_location(
-        "__graft_entry__", "/root/repo/__graft_entry__.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    fn, args = mod.entry()
-    out = jax.jit(fn)(*args)
-    assert out.shape[0] == args[1].shape[0]
-    mod.dryrun_multichip(8)

@@ -158,3 +158,51 @@ def test_pipeline_gradients_flow():
     g_ref = jax.grad(ref_loss)(ws)
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
                                atol=2e-5, rtol=2e-5)
+
+
+# One sharded train step per MeshSpec layout over the 8-device CPU mesh
+# (what the old driver entry point's multi-chip dry run guarded, without
+# its subprocess): DP x SP x TP with ring attention, DP x EP x TP with
+# MoE blocks, and a dcn axis stacked over data x tensor.
+_MESH_LAYOUTS = {
+    "dp_sp_tp": (MeshSpec(data=2, fsdp=1, seq=2, tensor=2),
+                 dict(vocab_size=512, n_layer=2, n_head=4, d_model=128,
+                      d_ff=256, max_seq=64, attn_impl="ring"), 4),
+    "dp_ep_tp": (MeshSpec(data=2, expert=2, tensor=2),
+                 dict(vocab_size=256, n_layer=2, n_head=4, d_model=64,
+                      d_ff=128, max_seq=32, moe_num_experts=4,
+                      moe_every=2), 4),
+    "dcn_dp_tp": (MeshSpec(dcn=2, data=2, tensor=2),
+                  dict(vocab_size=256, n_layer=2, n_head=4, d_model=64,
+                       d_ff=128, max_seq=32), 8),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(_MESH_LAYOUTS))
+def test_sharded_train_step_on_meshspec_axes(layout):
+    from ray_tpu.models.gpt2 import (GPT2Config, gpt2_init, gpt2_loss_fn,
+                                     gpt2_param_axes)
+    from ray_tpu.train.train_step import (TrainState, make_optimizer,
+                                          make_sharded_train_step,
+                                          shard_state)
+
+    spec, model_kw, batch = _MESH_LAYOUTS[layout]
+    mesh = create_mesh(spec, jax.devices()[:8])
+    rules = ShardingRules()
+    cfg = GPT2Config(mesh=mesh, rules=rules, remat=True, **model_kw)
+    optimizer = make_optimizer(total_steps=10, warmup_steps=2)
+    state = shard_state(
+        TrainState.create(gpt2_init(cfg, jax.random.PRNGKey(0)),
+                          optimizer),
+        mesh, gpt2_param_axes, rules)
+    step = make_sharded_train_step(
+        lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=0), optimizer,
+        mesh)
+    tokens = jax.device_put(
+        jnp.zeros((batch, cfg.max_seq + 1), jnp.int32),
+        logical_sharding(mesh, ("batch", None), rules))
+    state, metrics = step(state, {"tokens": tokens})
+    assert np.isfinite(float(metrics["loss"])), metrics
+    # The executable that ran is the ahead-of-time one, compiled for
+    # this mesh.
+    assert step.compiled() is not None

@@ -222,7 +222,7 @@ def test_admission_gate_disabled_and_unbounded_capacity():
             assert gate2.depth() == 0
 
 
-# ----------------------------------------------------- fault taxonomy
+# ----------------------------------------------------- fault classes
 def test_system_faults_vs_user_exceptions():
     assert is_system_fault(ActorDiedError("abc", "died"))
     assert is_system_fault(WorkerCrashedError("crashed"))
