@@ -858,7 +858,8 @@ class NodeAgent:
         worker and the artifact path is reported back through the
         controller so `rt profile --jax` can list it cluster-wide)."""
         req = {"duration_s": p.get("duration_s", 3.0),
-               "log_dir": p.get("log_dir"), "force": p.get("force")}
+               "log_dir": p.get("log_dir"), "force": p.get("force"),
+               "python_tracer": bool(p.get("python_tracer"))}
 
         async def _one(w):
             cli = RpcClient(w.addr, tag="jaxprof")

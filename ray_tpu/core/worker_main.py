@@ -1228,7 +1228,17 @@ class Worker:
         a TensorBoard-loadable directory and return its path.  Guarded:
         jax is only touched if user code ALREADY imported it in this
         process (tier-1 CPU runs and non-ML workers must never pay the
-        jax import); ``force`` opts into importing it anyway."""
+        jax import); ``force`` opts into importing it anyway.
+
+        The capture holds the device's programs and operations (the
+        flash kernels as ``flash_fwd`` / ``flash_dq`` / ``flash_dkv``,
+        every operation under its ``jax.named_scope``) and, on the same
+        clock, the host's ``spans.annotate`` annotations: the engine's
+        ``llm.*`` phases, the trainer's ``train.*``, every
+        ``spans.span`` / ``tracing.start_span`` block.  The Python
+        tracer is OFF unless ``python_tracer`` is set: tracing every
+        Python call slows the host loop that is being measured and
+        makes the trace many times larger."""
         if "jax" not in sys.modules and not p.get("force"):
             return {"ok": False,
                     "error": "jax not imported in this worker "
@@ -1243,7 +1253,10 @@ class Worker:
             import jax
 
             os.makedirs(log_dir, exist_ok=True)
-            jax.profiler.start_trace(log_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = \
+                1 if p.get("python_tracer") else 0
+            jax.profiler.start_trace(log_dir, profiler_options=options)
             try:
                 # The capture window: jax activity on OTHER threads
                 # (the train loop) lands in the trace while we sleep.

@@ -25,6 +25,15 @@ Sampling happens host-side from the last valid position's logits
 enter the jitted step.  Tokens stream out through per-sequence queues;
 the serve deployment (serving.py) turns them into streaming-generator
 frames.
+
+Where a step's time goes is measured inside it, on two clocks that
+agree: every phase of ``step()`` is a profiler annotation
+(``spans.annotate``: ``llm.step`` > ``llm.admit`` > ``llm.prefill`` >
+``llm.prefill.run`` ...), so a device capture shows what the host was
+doing in each idle gap, and the leaves' ``perf_counter`` sums are in
+``stats()["phase_s"]`` (PHASE_LEAVES; ``llm.other`` is the rest of
+``step_s``, so the parts sum to the whole).  Nothing of this goes into
+the span ring: only the per-request lifecycle spans do.
 """
 
 from __future__ import annotations
@@ -40,6 +49,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ..util import chips
+from ..util.spans import annotate
 from .kv_cache import PagePool, init_cache, pages_for
 from .sampling import SamplingParams, sample
 
@@ -59,6 +69,38 @@ class EngineConfig:
     # sequence (covers long recompute-preemption parks under KV
     # pressure; size it to worst-case pool contention).
     stream_idle_timeout_s: float = 300.0
+
+
+# The leaf phases of one step(): each is an annotation of this name and a
+# cumulative-seconds entry of stats()["phase_s"].  ``llm.admit`` is the
+# admission's own time (lock, page allocation), its prefills apart.
+PHASE_LEAVES = (
+    "llm.cancel", "llm.admit",
+    "llm.prefill.pack", "llm.prefill.run", "llm.prefill.fetch",
+    "llm.prefill.sample",
+    "llm.decode.pages", "llm.decode.pack", "llm.decode.run",
+    "llm.decode.fetch", "llm.decode.sample",
+    "llm.publish")
+
+
+class _Phase:
+    """One leaf phase: a profiler annotation and, around the same
+    statements, a ``perf_counter`` pair added to the phase's sum."""
+
+    __slots__ = ("_sums", "_name", "_annotation", "_t0")
+
+    def __init__(self, sums: Dict[str, float], name: str):
+        self._sums, self._name = sums, name
+        self._annotation = annotate(name)
+
+    def __enter__(self) -> None:
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> bool:
+        self._sums[self._name] += time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        return False
 
 
 def _bucket(n: int, floor: int = 8) -> int:
@@ -125,6 +167,17 @@ def jit_forward(model):
     return jax.jit(fwd, donate_argnums=(2, 3))
 
 
+def _program_bytes(exe) -> int:
+    """What the device must hold to run a compiled program, by the
+    compiler's own count; 0 where the backend gives none."""
+    try:
+        m = exe.memory_analysis()
+        return int(m.argument_size_in_bytes + m.output_size_in_bytes
+                   - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    except Exception:
+        return 0
+
+
 class GenerationEngine:
     """Continuous-batching engine for one GPT-2 / Llama replica."""
 
@@ -173,6 +226,10 @@ class GenerationEngine:
         # (rt perf).
         self._fwd_cache: Dict[Any, Any] = {}
         self._compile_seconds: Dict[str, float] = {}
+        # The largest program compiled here, by the compiler's own total
+        # (arguments + outputs - aliased + temporaries): taken once per
+        # compile, so stats() asks the device nothing.
+        self._peak_program_bytes = 0
 
         self._lock = threading.Lock()
         self._wake = threading.Condition(self._lock)
@@ -190,6 +247,15 @@ class GenerationEngine:
         self._tokens_total = 0
         self._prefill_tokens_total = 0
         self._evictions = 0
+        self._prefills = 0
+        self._compiles = 0
+        # The engine thread sums a step's leaves in _pending and adds
+        # them to the totals with the step's own time in one go, under
+        # the lock, so that a stats() taken mid-step still sums up.
+        self._phase_s: Dict[str, float] = dict.fromkeys(PHASE_LEAVES, 0.0)
+        self._pending: Dict[str, float] = dict.fromkeys(PHASE_LEAVES, 0.0)
+        self._step_s = 0.0
+        self._prefill_wall_s = 0.0      # inside _prefill, leaves or not
         self._seq_seed = seed
         # TTFT phase accounting (engine-side): waiting-queue + prefill
         # totals feed bench.py's decomposition print; TPOT (inter-
@@ -348,10 +414,21 @@ class GenerationEngine:
             self.stop()
 
     def stats(self) -> Dict[str, Any]:
-        peak_hbm = chips.peak_device_memory_bytes()
         with self._lock:
+            phase_s = dict(self._phase_s)
+            step_s = self._step_s
             return {
-                "peak_hbm_bytes": peak_hbm,
+                # Largest compiled forward, by memory_analysis(): what
+                # the device must hold to run it (the allocator's peak
+                # leaves program temporaries out).
+                "peak_hbm_bytes": self._peak_program_bytes,
+                # Where step() spent its time, cumulative seconds: the
+                # leaves and llm.other sum to step_s.
+                "phase_s": phase_s,
+                "step_s": step_s,
+                "llm.other": step_s - sum(phase_s.values()),
+                "prefills": self._prefills,
+                "compiles": self._compiles,
                 "kv_pages_used": self.pool.used,
                 "kv_pages_total": self.pool.num_pages,
                 "running": len(self._running),
@@ -382,7 +459,8 @@ class GenerationEngine:
                 while (not self._waiting and not self._running
                        and not self._cancelled
                        and not self._stop.is_set()):
-                    self._wake.wait(timeout=0.5)
+                    with annotate("llm.idle"):
+                        self._wake.wait(timeout=0.5)
                 if self._stop.is_set():
                     break
             try:
@@ -406,15 +484,39 @@ class GenerationEngine:
         """ONE engine iteration: cancellations -> admissions (prefill)
         -> batched decode -> retirement.  Public for deterministic
         single-step tests."""
-        self._process_cancellations()
-        self._admit()
-        if self._running:
-            self._decode_step()
-        self._steps += 1
-        self._last_batch = len(self._running)
-        self._publish_gauges()
+        t0 = time.perf_counter()
+        try:
+            with annotate("llm.step", step=self._steps,
+                          running=len(self._running),
+                          waiting=len(self._waiting)):
+                with self._phase("llm.cancel"):
+                    self._process_cancellations()
+                with annotate("llm.admit"):
+                    t1, inside = time.perf_counter(), self._prefill_wall_s
+                    try:
+                        self._admit()
+                    finally:        # self time: the prefills apart
+                        self._pending["llm.admit"] += (
+                            time.perf_counter() - t1
+                            - (self._prefill_wall_s - inside))
+                if self._running:
+                    with annotate("llm.decode", batch=len(self._running)):
+                        self._decode_step()
+                self._last_batch = len(self._running)
+                with self._phase("llm.publish"):
+                    self._publish_gauges()
+            self._steps += 1
+        finally:
+            with self._lock:
+                for name, seconds in self._pending.items():
+                    self._phase_s[name] += seconds
+                    self._pending[name] = 0.0
+                self._step_s += time.perf_counter() - t0
         return {"running": len(self._running),
                 "waiting": len(self._waiting)}
+
+    def _phase(self, name: str) -> _Phase:
+        return _Phase(self._pending, name)
 
     def _process_cancellations(self) -> None:
         with self._lock:
@@ -493,12 +595,16 @@ class GenerationEngine:
         # from — if _fwd was swapped (fault injection, hot reload) the
         # stale executable must not keep serving.
         if cached is None or cached[0] is not self._fwd:
-            t0 = time.perf_counter()
-            exe = self._fwd.lower(*args).compile()
-            dt = time.perf_counter() - t0
             name = f"llm_{kind}[{args[1].shape[1]}]" \
                 if kind == "prefill" else f"llm_{kind}"
+            t0 = time.perf_counter()
+            with annotate("llm.compile", program=name):
+                exe = self._fwd.lower(*args).compile()
+            dt = time.perf_counter() - t0
             self._compile_seconds[name] = dt
+            self._compiles += 1
+            self._peak_program_bytes = max(self._peak_program_bytes,
+                                           _program_bytes(exe))
             try:
                 from ..util import xprof
 
@@ -509,6 +615,18 @@ class GenerationEngine:
         return cached[1](*args)
 
     def _prefill(self, seq: _Sequence) -> None:
+        t0 = time.perf_counter()
+        try:
+            with annotate("llm.prefill", seq=seq.sid,
+                          request_id=seq.request_id or "",
+                          prompt_tokens=len(seq.tokens),
+                          bucket=_bucket(len(seq.tokens))):
+                self._prefill_annotated(seq)
+        finally:
+            self._prefills += 1
+            self._prefill_wall_s += time.perf_counter() - t0
+
+    def _prefill_annotated(self, seq: _Sequence) -> None:
         n = len(seq.tokens)
         # First admission only (a recompute-preempted sequence
         # re-prefills but already emitted its first token — its
@@ -523,23 +641,27 @@ class GenerationEngine:
             self._observe_phase("engine_waiting", waited)
             self._req_span(seq, "engine_waiting", seq.submitted_ts,
                            t_admit)
-        pad = _bucket(n)
-        tokens = np.zeros((1, pad), np.int32)
-        tokens[0, :n] = seq.tokens
-        positions = np.full((1, pad), -1, np.int32)
-        positions[0, :n] = np.arange(n)
-        table = self._page_table_row(seq)[None, :]
-        logits, k, v = self._call_fwd("prefill", self._params, tokens,
-                                      self._kv["k_pages"],
-                                      self._kv["v_pages"], table,
-                                      positions)
+        with self._phase("llm.prefill.pack"):
+            pad = _bucket(n)
+            tokens = np.zeros((1, pad), np.int32)
+            tokens[0, :n] = seq.tokens
+            positions = np.full((1, pad), -1, np.int32)
+            positions[0, :n] = np.arange(n)
+            table = self._page_table_row(seq)[None, :]
+        with self._phase("llm.prefill.run"):
+            logits, k, v = self._call_fwd(
+                "prefill", self._params, tokens, self._kv["k_pages"],
+                self._kv["v_pages"], table, positions)
         self._kv["k_pages"], self._kv["v_pages"] = k, v
         seq.n_cached = n
         self._prefill_tokens_total += n
         self._count("prefill", n)
         with self._lock:
             self._running.append(seq)
-        self._emit_token(seq, np.asarray(logits[0, n - 1]))
+        with self._phase("llm.prefill.fetch"):
+            last = np.asarray(logits[0, n - 1])
+        with self._phase("llm.prefill.sample"):
+            self._emit_token(seq, last)
         if first_admission:
             t_first = time.time()
             self._prefill_s_total += t_first - t_admit
@@ -551,28 +673,32 @@ class GenerationEngine:
     def _decode_step(self) -> None:
         """One batched decode forward over every running sequence."""
         B = self.cfg.max_batch
-        for seq in list(self._running):
-            if seq in self._running:   # an earlier ensure may evict it
-                self._ensure_page(seq)
-        batch = list(self._running)
+        with self._phase("llm.decode.pages"):
+            for seq in list(self._running):
+                if seq in self._running:   # an earlier ensure may evict it
+                    self._ensure_page(seq)
+            batch = list(self._running)
         if not batch:
             return
-        tokens = np.zeros((B, 1), np.int32)
-        positions = np.full((B, 1), -1, np.int32)
-        table = np.zeros((B, self._pages_per_seq), np.int32)
-        for i, seq in enumerate(batch):
-            tokens[i, 0] = seq.tokens[-1]
-            positions[i, 0] = seq.n_cached
-            table[i] = self._page_table_row(seq)
-        logits, k, v = self._call_fwd("decode", self._params, tokens,
-                                      self._kv["k_pages"],
-                                      self._kv["v_pages"], table,
-                                      positions)
+        with self._phase("llm.decode.pack"):
+            tokens = np.zeros((B, 1), np.int32)
+            positions = np.full((B, 1), -1, np.int32)
+            table = np.zeros((B, self._pages_per_seq), np.int32)
+            for i, seq in enumerate(batch):
+                tokens[i, 0] = seq.tokens[-1]
+                positions[i, 0] = seq.n_cached
+                table[i] = self._page_table_row(seq)
+        with self._phase("llm.decode.run"):
+            logits, k, v = self._call_fwd(
+                "decode", self._params, tokens, self._kv["k_pages"],
+                self._kv["v_pages"], table, positions)
         self._kv["k_pages"], self._kv["v_pages"] = k, v
-        logits_np = np.asarray(logits[:, 0])
-        for i, seq in enumerate(batch):
-            seq.n_cached += 1
-            self._emit_token(seq, logits_np[i])
+        with self._phase("llm.decode.fetch"):
+            logits_np = np.asarray(logits[:, 0])
+        with self._phase("llm.decode.sample"):
+            for i, seq in enumerate(batch):
+                seq.n_cached += 1
+                self._emit_token(seq, logits_np[i])
 
     def _ensure_page(self, seq: _Sequence) -> bool:
         """Guarantee a KV slot for position ``seq.n_cached``; on pool

@@ -48,15 +48,17 @@ def paged_store(k_pages, v_pages, k_new, v_new, page_table, positions):
     prompt length) and batched decode (T = 1, padded rows) alike.
     """
     num_pages, page_size = k_pages.shape[0], k_pages.shape[1]
-    pos = jnp.maximum(positions, 0)
-    page_ix = jnp.take_along_axis(page_table, pos // page_size, axis=1)
-    # Out-of-range index => dropped write for padded slots.
-    page_ix = jnp.where(positions >= 0, page_ix, num_pages)
-    slot = pos % page_size
-    k_pages = k_pages.at[page_ix, slot].set(
-        k_new.astype(k_pages.dtype), mode="drop")
-    v_pages = v_pages.at[page_ix, slot].set(
-        v_new.astype(v_pages.dtype), mode="drop")
+    with jax.named_scope("kv.store"):
+        pos = jnp.maximum(positions, 0)
+        page_ix = jnp.take_along_axis(page_table, pos // page_size,
+                                      axis=1)
+        # Out-of-range index => dropped write for padded slots.
+        page_ix = jnp.where(positions >= 0, page_ix, num_pages)
+        slot = pos % page_size
+        k_pages = k_pages.at[page_ix, slot].set(
+            k_new.astype(k_pages.dtype), mode="drop")
+        v_pages = v_pages.at[page_ix, slot].set(
+            v_new.astype(v_pages.dtype), mode="drop")
     return k_pages, v_pages
 
 
@@ -71,24 +73,25 @@ def paged_attend(q, k_pages, v_pages, page_table, positions):
     heads and repeat to h at attend time, exactly like the full
     forward."""
     b, t, h, d = q.shape
-    ks = k_pages[page_table]          # [B, P, page, h_kv, d]
-    vs = v_pages[page_table]
-    p, page = ks.shape[1], ks.shape[2]
-    ks = ks.reshape(b, p * page, ks.shape[3], d)
-    vs = vs.reshape(b, p * page, vs.shape[3], d)
-    h_kv = ks.shape[2]
-    if h_kv != h:                      # GQA: repeat KV groups
-        rep = h // h_kv
-        ks = jnp.repeat(ks, rep, axis=2)
-        vs = jnp.repeat(vs, rep, axis=2)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, ks,
-                        preferred_element_type=jnp.float32)
-    scores = scores * (d ** -0.5)
-    kv_pos = jnp.arange(p * page, dtype=jnp.int32)
-    mask = kv_pos[None, None, None, :] <= positions[:, None, :, None]
-    scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, vs)
+    with jax.named_scope("kv.attend"):
+        ks = k_pages[page_table]          # [B, P, page, h_kv, d]
+        vs = v_pages[page_table]
+        p, page = ks.shape[1], ks.shape[2]
+        ks = ks.reshape(b, p * page, ks.shape[3], d)
+        vs = vs.reshape(b, p * page, vs.shape[3], d)
+        h_kv = ks.shape[2]
+        if h_kv != h:                      # GQA: repeat KV groups
+            rep = h // h_kv
+            ks = jnp.repeat(ks, rep, axis=2)
+            vs = jnp.repeat(vs, rep, axis=2)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, ks,
+                            preferred_element_type=jnp.float32)
+        scores = scores * (d ** -0.5)
+        kv_pos = jnp.arange(p * page, dtype=jnp.int32)
+        mask = kv_pos[None, None, None, :] <= positions[:, None, :, None]
+        scores = jnp.where(mask, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, vs)
 
 
 def pages_for(n_tokens: int, page_size: int) -> int:

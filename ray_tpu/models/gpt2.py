@@ -9,7 +9,11 @@ TPU-first design notes:
   "ring" (context parallel over the ``seq`` mesh axis, SURVEY.md §5.7),
   or "ulysses" (head/seq all-to-all);
 - jax.checkpoint per block when ``remat`` so long-context activation
-  memory trades against recompute.
+  memory trades against recompute;
+- ``jax.named_scope`` names the parts (``embed``, ``attn.qkv``,
+  ``attn.core``, ``attn.out``, ``mlp``, ``lm_head``, ``loss``) inside
+  flax's own module scopes (``h_<i>``, ``ln_f``): a device trace and an
+  HLO dump say whose each operation is.  Metadata only.
 
 Role-equivalent to the reference's GPT-2 release-test workloads (ref:
 release/train_tests LLM configs; the reference trains them via
@@ -160,13 +164,15 @@ class Block(nn.Module):
         h = cfg.n_head
         d_head = cfg.d_model // h
         y = nn.LayerNorm(dtype=cfg.dtype, name="ln_1")(x)
-        qkv = nn.Dense(3 * cfg.d_model, dtype=cfg.dtype, name="c_attn",
-                       kernel_init=nn.initializers.normal(0.02))(y)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        b, t = q.shape[0], q.shape[1]
-        q = q.reshape(b, t, h, d_head)
-        k = k.reshape(b, t, h, d_head)
-        v = v.reshape(b, t, h, d_head)
+        with jax.named_scope("attn.qkv"):
+            qkv = nn.Dense(3 * cfg.d_model, dtype=cfg.dtype,
+                           name="c_attn",
+                           kernel_init=nn.initializers.normal(0.02))(y)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            b, t = q.shape[0], q.shape[1]
+            q = q.reshape(b, t, h, d_head)
+            k = k.reshape(b, t, h, d_head)
+            v = v.reshape(b, t, h, d_head)
         if cache is not None:
             # Decode mode: write this step's K/V into the paged pool,
             # attend q against the gathered history (prefill and
@@ -174,42 +180,47 @@ class Block(nn.Module):
             # — the serving engine hosts one replica per chip.
             from ..llm.kv_cache import paged_attend, paged_store
 
-            k_pages, v_pages = paged_store(
-                cache["k_pages"], cache["v_pages"], k, v,
-                cache["page_table"], cache["positions"])
-            att = paged_attend(q, k_pages, v_pages,
-                               cache["page_table"], cache["positions"])
+            with jax.named_scope("attn.core"):
+                k_pages, v_pages = paged_store(
+                    cache["k_pages"], cache["v_pages"], k, v,
+                    cache["page_table"], cache["positions"])
+                att = paged_attend(q, k_pages, v_pages,
+                                   cache["page_table"],
+                                   cache["positions"])
             new_cache = (k_pages, v_pages)
         else:
-            q = _constrain(q, ("batch", "seq", "heads", None), cfg)
-            k = _constrain(k, ("batch", "seq", "heads", None), cfg)
-            v = _constrain(v, ("batch", "seq", "heads", None), cfg)
-            att = _attention(cfg, q, k, v)
+            with jax.named_scope("attn.core"):
+                q = _constrain(q, ("batch", "seq", "heads", None), cfg)
+                k = _constrain(k, ("batch", "seq", "heads", None), cfg)
+                v = _constrain(v, ("batch", "seq", "heads", None), cfg)
+                att = _attention(cfg, q, k, v)
             new_cache = None
-        att = att.reshape(b, t, cfg.d_model)
-        att = nn.Dense(cfg.d_model, dtype=cfg.dtype, name="c_proj",
-                       kernel_init=nn.initializers.normal(
-                           0.02 / (2 * cfg.n_layer) ** 0.5))(att)
-        x = x + att
+        with jax.named_scope("attn.out"):
+            att = att.reshape(b, t, cfg.d_model)
+            att = nn.Dense(cfg.d_model, dtype=cfg.dtype, name="c_proj",
+                           kernel_init=nn.initializers.normal(
+                               0.02 / (2 * cfg.n_layer) ** 0.5))(att)
+            x = x + att
         y = nn.LayerNorm(dtype=cfg.dtype, name="ln_2")(x)
-        if self.use_moe:
-            from ..ops.moe import MoEMLP
+        with jax.named_scope("mlp"):
+            if self.use_moe:
+                from ..ops.moe import MoEMLP
 
-            y = MoEMLP(d_model=cfg.d_model, d_ff=cfg.d_ff,
-                       num_experts=cfg.moe_num_experts,
-                       top_k=cfg.moe_top_k,
-                       capacity_factor=cfg.moe_capacity_factor,
-                       dtype=cfg.dtype, name="moe_mlp")(y)
+                y = MoEMLP(d_model=cfg.d_model, d_ff=cfg.d_ff,
+                           num_experts=cfg.moe_num_experts,
+                           top_k=cfg.moe_top_k,
+                           capacity_factor=cfg.moe_capacity_factor,
+                           dtype=cfg.dtype, name="moe_mlp")(y)
+            else:
+                y = nn.Dense(cfg.d_ff, dtype=cfg.dtype, name="mlp_in",
+                             kernel_init=nn.initializers.normal(0.02))(y)
+                y = _constrain(y, ("batch", "seq", "mlp"), cfg)
+                y = nn.gelu(y)
+                y = nn.Dense(cfg.d_model, dtype=cfg.dtype,
+                             name="mlp_out",
+                             kernel_init=nn.initializers.normal(
+                                 0.02 / (2 * cfg.n_layer) ** 0.5))(y)
             out = x + y
-            return out if new_cache is None else (out, new_cache)
-        y = nn.Dense(cfg.d_ff, dtype=cfg.dtype, name="mlp_in",
-                     kernel_init=nn.initializers.normal(0.02))(y)
-        y = _constrain(y, ("batch", "seq", "mlp"), cfg)
-        y = nn.gelu(y)
-        y = nn.Dense(cfg.d_model, dtype=cfg.dtype, name="mlp_out",
-                     kernel_init=nn.initializers.normal(
-                         0.02 / (2 * cfg.n_layer) ** 0.5))(y)
-        out = x + y
         return out if new_cache is None else (out, new_cache)
 
 
@@ -236,12 +247,15 @@ class GPT2(nn.Module):
         wpe = self.param("wpe", nn.initializers.normal(0.01),
                          (cfg.max_seq, cfg.d_model), jnp.float32)
         t = tokens.shape[1]
-        if decode:
-            pos = jnp.maximum(positions, 0)
-            x = wte.astype(cfg.dtype)[tokens] + wpe.astype(cfg.dtype)[pos]
-        else:
-            x = wte.astype(cfg.dtype)[tokens] + wpe.astype(cfg.dtype)[:t]
-        x = _constrain(x, ("batch", "seq", "embed"), cfg)
+        with jax.named_scope("embed"):
+            if decode:
+                pos = jnp.maximum(positions, 0)
+                x = wte.astype(cfg.dtype)[tokens] \
+                    + wpe.astype(cfg.dtype)[pos]
+            else:
+                x = wte.astype(cfg.dtype)[tokens] \
+                    + wpe.astype(cfg.dtype)[:t]
+            x = _constrain(x, ("batch", "seq", "embed"), cfg)
         block = Block
         if cfg.remat and not decode:
             # Decode steps are memory-light; remat would only slow them.
@@ -265,9 +279,10 @@ class GPT2(nn.Module):
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
         if return_hidden:
             return x
-        logits = jnp.einsum("btd,vd->btv", x, wte.astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-        logits = _constrain(logits, ("batch", "seq", "vocab"), cfg)
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("btd,vd->btv", x, wte.astype(cfg.dtype),
+                                preferred_element_type=jnp.float32)
+            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg)
         if decode:
             return logits, {"k_pages": jnp.stack(new_k),
                             "v_pages": jnp.stack(new_v),
@@ -317,13 +332,15 @@ def _chunked_xent(x, wte, targets, chunk: int) -> jnp.ndarray:
     the dX / dWte einsums — measured +5% step throughput over the
     whole-logits path at B16/T1024 on one chip, and the live-slab
     memory drops from O(T*V) to O(chunk*V)."""
-    total, _ = _xent_fwd_impl(x, wte, targets, chunk)
+    with jax.named_scope("loss"):
+        total, _ = _xent_fwd_impl(x, wte, targets, chunk)
     b, t, _d = x.shape
     return total / (b * t)
 
 
 def _chunked_xent_fwd(x, wte, targets, chunk):
-    total, lses = _xent_fwd_impl(x, wte, targets, chunk)
+    with jax.named_scope("loss"):
+        total, lses = _xent_fwd_impl(x, wte, targets, chunk)
     b, t, _d = x.shape
     return total / (b * t), (x, wte, targets, lses)
 
@@ -350,11 +367,12 @@ def _chunked_xent_bwd(chunk, res, g):
                              preferred_element_type=jnp.float32)
         return dw, dx_c
 
-    dw, dxs = jax.lax.scan(body,
-                           jnp.zeros(wte.shape, jnp.float32),
-                           (xs, ts, lses))
-    dx = jnp.moveaxis(dxs, 0, 1).reshape(b, t, d)
-    return dx, dw.astype(wte.dtype), None
+    with jax.named_scope("loss"):
+        dw, dxs = jax.lax.scan(body,
+                               jnp.zeros(wte.shape, jnp.float32),
+                               (xs, ts, lses))
+        dx = jnp.moveaxis(dxs, 0, 1).reshape(b, t, d)
+        return dx, dw.astype(wte.dtype), None
 
 
 _chunked_xent.defvjp(_chunked_xent_fwd, _chunked_xent_bwd)
@@ -389,9 +407,11 @@ def gpt2_loss_fn(cfg: GPT2Config, params, batch,
     else:
         logits = GPT2(cfg).apply(params, inputs)
         aux = 0.0
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll) + cfg.moe_aux_weight * aux
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None],
+                                 axis=-1)[..., 0]
+        return -jnp.mean(ll) + cfg.moe_aux_weight * aux
 
 
 def gpt2_partition_rules():

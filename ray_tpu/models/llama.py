@@ -178,53 +178,60 @@ class LlamaBlock(nn.Module):
         b, t = x.shape[0], x.shape[1]
         y = RMSNorm(cfg.rms_eps, cfg.dtype, name="attn_norm")(x)
         init = nn.initializers.normal(0.02)
-        q = nn.Dense(h * d_head, use_bias=False, dtype=cfg.dtype,
-                     kernel_init=init, name="wq")(y).reshape(b, t, h, d_head)
-        k = nn.Dense(hk * d_head, use_bias=False, dtype=cfg.dtype,
-                     kernel_init=init, name="wk")(y).reshape(b, t, hk,
-                                                             d_head)
-        v = nn.Dense(hk * d_head, use_bias=False, dtype=cfg.dtype,
-                     kernel_init=init, name="wv")(y).reshape(b, t, hk,
-                                                             d_head)
         positions = cache["positions"] if cache is not None else None
-        q = _rope(q, cfg.rope_theta, positions)
-        k = _rope(k, cfg.rope_theta, positions)
+        # Scope names as in models/gpt2.py (metadata only).
+        with jax.named_scope("attn.qkv"):
+            q = nn.Dense(h * d_head, use_bias=False, dtype=cfg.dtype,
+                         kernel_init=init,
+                         name="wq")(y).reshape(b, t, h, d_head)
+            k = nn.Dense(hk * d_head, use_bias=False, dtype=cfg.dtype,
+                         kernel_init=init,
+                         name="wk")(y).reshape(b, t, hk, d_head)
+            v = nn.Dense(hk * d_head, use_bias=False, dtype=cfg.dtype,
+                         kernel_init=init,
+                         name="wv")(y).reshape(b, t, hk, d_head)
+            q = _rope(q, cfg.rope_theta, positions)
+            k = _rope(k, cfg.rope_theta, positions)
         if cache is not None:
             # Decode mode: the cache stores the hk GROUPED heads
             # (post-RoPE); repeat-to-h happens at attend time, so GQA
             # shrinks the pooled cache by h/hk.
             from ..llm.kv_cache import paged_attend, paged_store
 
-            k_pages, v_pages = paged_store(
-                cache["k_pages"], cache["v_pages"], k, v,
-                cache["page_table"], positions)
-            att = paged_attend(q, k_pages, v_pages,
-                               cache["page_table"], positions)
+            with jax.named_scope("attn.core"):
+                k_pages, v_pages = paged_store(
+                    cache["k_pages"], cache["v_pages"], k, v,
+                    cache["page_table"], positions)
+                att = paged_attend(q, k_pages, v_pages,
+                                   cache["page_table"], positions)
             new_cache = (k_pages, v_pages)
         else:
-            if hk != h:  # GQA: repeat KV groups to full heads
-                rep = h // hk
-                k = jnp.repeat(k, rep, axis=2)
-                v = jnp.repeat(v, rep, axis=2)
-            q = _constrain(q, ("batch", "seq", "heads", None), cfg)
-            k = _constrain(k, ("batch", "seq", "heads", None), cfg)
-            v = _constrain(v, ("batch", "seq", "heads", None), cfg)
-            att = _attention(cfg, q, k, v)
+            with jax.named_scope("attn.core"):
+                if hk != h:  # GQA: repeat KV groups to full heads
+                    rep = h // hk
+                    k = jnp.repeat(k, rep, axis=2)
+                    v = jnp.repeat(v, rep, axis=2)
+                q = _constrain(q, ("batch", "seq", "heads", None), cfg)
+                k = _constrain(k, ("batch", "seq", "heads", None), cfg)
+                v = _constrain(v, ("batch", "seq", "heads", None), cfg)
+                att = _attention(cfg, q, k, v)
             new_cache = None
-        att = att.reshape(b, t, cfg.d_model)
-        att = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                       kernel_init=init, name="wo")(att)
-        x = x + att
+        with jax.named_scope("attn.out"):
+            att = att.reshape(b, t, cfg.d_model)
+            att = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                           kernel_init=init, name="wo")(att)
+            x = x + att
         y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
-        gate = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
-                        kernel_init=init, name="w_gate")(y)
-        up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
-                      kernel_init=init, name="w_up")(y)
-        z = nn.silu(gate) * up
-        z = _constrain(z, ("batch", "seq", "mlp"), cfg)
-        down = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                        kernel_init=init, name="w_down")(z)
-        out = x + down
+        with jax.named_scope("mlp"):
+            gate = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
+                            kernel_init=init, name="w_gate")(y)
+            up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
+                          kernel_init=init, name="w_up")(y)
+            z = nn.silu(gate) * up
+            z = _constrain(z, ("batch", "seq", "mlp"), cfg)
+            down = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                            kernel_init=init, name="w_down")(z)
+            out = x + down
         return out if new_cache is None else (out, new_cache)
 
 
@@ -240,8 +247,9 @@ class Llama(nn.Module):
         decode = kv_cache is not None
         emb = self.param("embed", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), jnp.float32)
-        x = emb.astype(cfg.dtype)[tokens]
-        x = _constrain(x, ("batch", "seq", "embed"), cfg)
+        with jax.named_scope("embed"):
+            x = emb.astype(cfg.dtype)[tokens]
+            x = _constrain(x, ("batch", "seq", "embed"), cfg)
         block = LlamaBlock
         if cfg.remat and not decode:
             block = nn.remat(LlamaBlock, prevent_cse=False)
@@ -262,9 +270,10 @@ class Llama(nn.Module):
         x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
         head = self.param("lm_head", nn.initializers.normal(0.02),
                           (cfg.d_model, cfg.vocab_size), jnp.float32)
-        logits = jnp.einsum("btd,dv->btv", x, head.astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-        logits = _constrain(logits, ("batch", "seq", "vocab"), cfg)
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("btd,dv->btv", x, head.astype(cfg.dtype),
+                                preferred_element_type=jnp.float32)
+            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg)
         if decode:
             return logits, {"k_pages": jnp.stack(new_k),
                             "v_pages": jnp.stack(new_v),
@@ -284,9 +293,11 @@ def llama_loss_fn(cfg: LlamaConfig, params, batch):
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     logits = Llama(cfg).apply(params, inputs)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None],
+                                 axis=-1)[..., 0]
+        return -jnp.mean(ll)
 
 
 def llama_partition_rules():

@@ -96,31 +96,33 @@ def _flash_forward(q, k, v, *, scale, bq, bk, causal, interpret):
     grid = (bh, nq, nk)
     kernel = functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk,
                                causal=causal)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 8, t), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),   # running max
-            pltpu.VMEM((bq, 128), jnp.float32),   # running denominator
-            pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v)
+    with jax.named_scope("flash_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            name="flash_fwd",
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+                jax.ShapeDtypeStruct((bh, 8, t), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),   # running max
+                pltpu.VMEM((bq, 128), jnp.float32),   # running denominator
+                pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(q, k, v)
     return out, lse
 
 
@@ -226,54 +228,58 @@ def _flash_backward(res, g, *, scale, bq, bk, causal, interpret,
     delta = jnp.broadcast_to(delta[:, None, :], lse.shape)    # (bh, 8, t)
     nq, nk = pl.cdiv(t, bq), pl.cdiv(t, bk)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, bq=bq, bk=bk,
-                          causal=causal),
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, 8, bq), lambda b, j, i: (b, 0, i)),
-            pl.BlockSpec((1, 8, bq), lambda b, j, i: (b, 0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    with jax.named_scope("flash_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, scale=scale, bq=bq, bk=bk,
+                              causal=causal),
+            name="flash_dkv",
+            grid=(bh, nk, nq),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
+                pl.BlockSpec((1, 8, bq), lambda b, j, i: (b, 0, i)),
+                pl.BlockSpec((1, 8, bq), lambda b, j, i: (b, 0, i)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, t, d), k.dtype),
+                jax.ShapeDtypeStruct((bh, t, d), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(q, k, v, do, lse, delta)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, bq=bq, bk=bk,
-                          causal=causal),
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    with jax.named_scope("flash_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, scale=scale, bq=bq, bk=bk,
+                              causal=causal),
+            name="flash_dq",
+            grid=(bh, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i)),
+                pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 

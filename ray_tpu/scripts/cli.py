@@ -394,7 +394,10 @@ def cmd_profile(args) -> int:
     """On-demand profiler capture on live workers.  ``--jax`` runs a
     jax.profiler trace on every worker that has jax loaded and prints
     the artifact directories (TensorBoard-loadable; also recorded in
-    the controller telemetry feed)."""
+    the controller telemetry feed).  A capture holds the device's
+    programs and operations and the host's ``llm.*`` / ``train.*``
+    annotations on one clock; ``--python-tracer`` adds every Python
+    call, at the cost of a slower host loop and a much larger trace."""
     from ray_tpu.util import state as state_api
 
     if not args.jax:
@@ -412,7 +415,8 @@ def cmd_profile(args) -> int:
         args.duration = 120.0
     results = state_api.jax_profile(
         duration_s=args.duration, node_id=args.node or None,
-        force=args.force, address=address)
+        force=args.force, python_tracer=args.python_tracer,
+        address=address)
     if not results:
         print("(no live workers found)")
         return 1
@@ -1075,13 +1079,22 @@ def _build_parser() -> argparse.ArgumentParser:
                         help="on-demand profiler capture on workers")
     sp.add_argument("--jax", action="store_true",
                     help="jax.profiler trace on workers with jax "
-                         "loaded (TensorBoard-loadable artifacts)")
+                         "loaded (TensorBoard-loadable artifacts): "
+                         "device programs and operations (kernels "
+                         "flash_fwd/flash_dq/flash_dkv, jax.named_scope "
+                         "names) and, on the same clock, the host's "
+                         "llm.* (engine step phases) and train.* "
+                         "(report, input, dispatch) annotations")
     sp.add_argument("--duration", type=float, default=3.0,
                     help="capture window seconds (default 3)")
     sp.add_argument("--node", default="", help="node id prefix filter")
     sp.add_argument("--force", action="store_true",
                     help="import jax into workers that have not "
                          "loaded it yet")
+    sp.add_argument("--python-tracer", action="store_true",
+                    help="also trace every Python call: slows the "
+                         "host loop being measured and makes the "
+                         "trace many times larger (default off)")
     sp.add_argument("--address", default="")
     sp.set_defaults(fn=cmd_profile)
 

@@ -17,6 +17,7 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
+from ..util.spans import annotate
 from .checkpoint import Checkpoint
 from .config import TelemetryConfig
 
@@ -51,23 +52,43 @@ class TrainSession:
     _interrupt: Optional[Dict[str, Any]] = None
     _last_interrupt_poll: float = 0.0
     _interrupt_poll_period_s: float = 1.0
+    # Metric handles by name, each built at its first use and kept:
+    # report() runs every step, and building one pays name validation
+    # and the registry's global lock.
+    _metrics: Dict[str, Any] = field(default_factory=dict)
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None) -> None:
-        self._report_index += 1
-        self._observe_step(metrics)
-        payload = {"rank": self.world_rank, "metrics": dict(metrics),
-                   "index": self._report_index,
-                   "checkpoint_path": checkpoint.path if checkpoint
-                   else None}
-        if checkpoint is not None and self.interrupted():
-            # Tag the payload so the driver (and the metrics history)
-            # can tell a checkpoint-on-notice from a periodic save.
-            payload["preempt_ckpt"] = True
-        if self.result_queue is not None:
-            import ray_tpu
+        """In a profiler capture: ``train.report`` with
+        ``train.report.observe`` (telemetry) and ``train.report.push``
+        (the blocking RPC to the result queue) inside."""
+        with annotate("train.report"):
+            self._report_index += 1
+            with annotate("train.report.observe"):
+                self._observe_step(metrics)
+            payload = {"rank": self.world_rank, "metrics": dict(metrics),
+                       "index": self._report_index,
+                       "checkpoint_path": checkpoint.path if checkpoint
+                       else None}
+            if checkpoint is not None and self.interrupted():
+                # Tag the payload so the driver (and the metrics
+                # history) can tell a checkpoint-on-notice from a
+                # periodic save.
+                payload["preempt_ckpt"] = True
+            if self.result_queue is not None:
+                import ray_tpu
 
-            ray_tpu.get(self.result_queue.push.remote(payload))
+                with annotate("train.report.push"):
+                    ray_tpu.get(self.result_queue.push.remote(payload))
+
+    def _metric(self, kind: str, name: str, description: str):
+        m = self._metrics.get(name)
+        if m is None:
+            from ..util import metrics as metrics_mod
+
+            m = self._metrics[name] = getattr(metrics_mod, kind)(
+                name, description)
+        return m
 
     # ------------------------------------------------- drain/preemption
     def interruption(self) -> Optional[Dict[str, Any]]:
@@ -115,19 +136,18 @@ class TrainSession:
         data wait + host overhead); tokens/sec and achieved MFU derive
         from the declared TelemetryConfig figures."""
         try:
-            from ..util.metrics import Gauge, Histogram
-
             now = self._clock()
             last, self._last_report_ts = self._last_report_ts, now
             step = metrics.get("step", self._report_index)
-            Gauge("rt_train_step",
-                  "Latest reported training step.").set(float(step))
+            self._metric("Gauge", "rt_train_step",
+                         "Latest reported training step."
+                         ).set(float(step))
             if last is None:
                 return
             dt = max(now - last, 1e-9)
-            Histogram("rt_train_step_time_seconds",
-                      "Wall-clock between session.report calls "
-                      "(per-step time).").observe(dt)
+            self._metric("Histogram", "rt_train_step_time_seconds",
+                         "Wall-clock between session.report calls "
+                         "(per-step time).").observe(dt)
             # Timeline span per step, tagged step/rank: the cluster
             # timeline's per-rank step rows and the `rt timeline
             # --summary` critical path (slowest rank per step) are
@@ -148,23 +168,25 @@ class TrainSession:
             if tokens <= 0:
                 return
             tps = tokens / dt
-            Gauge("rt_train_tokens_per_sec",
-                  "Per-worker training throughput.").set(tps)
+            self._metric("Gauge", "rt_train_tokens_per_sec",
+                         "Per-worker training throughput.").set(tps)
             if tel.model_flops_per_token > 0:
                 # The roofline's measured point: achieved model
                 # FLOP/s per worker (rt perf plots it against the
                 # attainable ceiling at the program's intensity).
-                Gauge("rt_train_achieved_flops_per_sec",
-                      "Achieved model FLOP/s per worker from the "
-                      "declared FLOPs-per-token figure.").set(
+                self._metric(
+                    "Gauge", "rt_train_achieved_flops_per_sec",
+                    "Achieved model FLOP/s per worker from the "
+                    "declared FLOPs-per-token figure.").set(
                     tps * tel.model_flops_per_token)
                 # Last: a device with no row in the chip peak table
                 # (the CPU) raises here and gets no MFU gauge.
                 peak = tel.resolved_peak_flops() * max(
                     tel.devices_per_worker, 1)
-                Gauge("rt_train_mfu",
-                      "Achieved model FLOPs utilization (0-1) from "
-                      "the declared FLOPs-per-token figure.").set(
+                self._metric(
+                    "Gauge", "rt_train_mfu",
+                    "Achieved model FLOPs utilization (0-1) from "
+                    "the declared FLOPs-per-token figure.").set(
                     tps * tel.model_flops_per_token / peak)
         except Exception:
             pass  # telemetry must never fail a training step
@@ -359,7 +381,7 @@ def data_wait():
     the per-step data-wait histogram."""
     from ..util import goodput
 
-    with goodput.timed_phase(
+    with annotate("train.input.wait"), goodput.timed_phase(
             "data_stall", "rt_train_data_wait_seconds",
             "Time the step loop spent waiting on input data."):
         yield
@@ -407,6 +429,11 @@ def iter_device_batches(batches, *, depth: int = 2, transfer=None,
             # feeder, let the consumer's compute overlap it.
             return jax.device_put(b)
 
-    return iter_prefetched(batches, depth=depth, transform=transfer,
+    def annotated_transfer(b):      # on the prefetch thread
+        with annotate("train.input.transfer"):
+            return transfer(b)
+
+    return iter_prefetched(batches, depth=depth,
+                           transform=annotated_transfer,
                            wait_cm=data_wait,
                            thread_name="rt-device-prefetch")

@@ -51,12 +51,17 @@ def make_train_step(loss_fn: Callable, optimizer
     """loss_fn(params, batch) -> scalar.  Returns step(state, batch)."""
 
     def step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params)
-        params = optax.apply_updates(state.params, updates)
-        metrics = {"loss": loss,
-                   "grad_norm": optax.global_norm(grads)}
+        # The scopes name the step's parts in a device trace and in an
+        # HLO dump; they change no operation.
+        with jax.named_scope("loss_and_grad"):
+            loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("grad_norm"):
+            grad_norm = optax.global_norm(grads)
+        metrics = {"loss": loss, "grad_norm": grad_norm}
         return TrainState(step=state.step + 1, params=params,
                           opt_state=opt_state), metrics
 
@@ -130,6 +135,7 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
     import time as _time
 
     from ..util import goodput
+    from ..util.spans import annotate
 
     mesh_axes = None
     if mesh is not None:
@@ -148,6 +154,7 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
     # surface as themselves: the state is donated, so there is nothing
     # to retry with.
     aot = [None]
+    dispatch_hist = [None]      # built once, at the second call
 
     def timed_step(state, batch):
         first = aot[0] is None
@@ -155,9 +162,11 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
         t0 = _time.perf_counter()
         with goodput.ledger().phase(phase):
             if first:
-                aot[0] = jitted.lower(state, batch).compile()
+                with annotate("train.step.compile"):
+                    aot[0] = jitted.lower(state, batch).compile()
                 timed_step.compile_seconds = _time.perf_counter() - t0
-            out = aot[0](state, batch)
+            with annotate("train.step.dispatch"):
+                out = aot[0](state, batch)
         dt = _time.perf_counter() - t0
         try:
             from ..util.metrics import Gauge, Histogram
@@ -172,10 +181,12 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
                                         mesh_axes=mesh_axes,
                                         compile_seconds=dt)
             else:
-                Histogram("rt_train_step_dispatch_seconds",
-                          "Host-side duration of the jitted step call "
-                          "(approximate under async dispatch)."
-                          ).observe(dt)
+                if dispatch_hist[0] is None:
+                    dispatch_hist[0] = Histogram(
+                        "rt_train_step_dispatch_seconds",
+                        "Host-side duration of the jitted step call "
+                        "(approximate under async dispatch).")
+                dispatch_hist[0].observe(dt)
         except Exception:
             pass    # registering with xprof is best-effort
         return out

@@ -26,15 +26,24 @@ deque op — negligible next to any traced operation); the
 ``tracing_enabled`` config flag only controls trace-context
 *propagation* through task submission.  This module must import
 without jax or aiohttp present (tier-1 CPU guard).
+
+``annotate`` is the one bridge from host code to the profiler's clock:
+where jax is already loaded it opens a ``jax.profiler.TraceAnnotation``,
+so a device capture (``rt profile --jax``, the benchmark's traced runs)
+shows what the host was doing on the same axis as the device's
+operations.  ``span`` and ``tracing.start_span`` enter it as well; hot
+loops (the engine's step phases, the train loop) use it WITHOUT the
+ring and keep a ``time.perf_counter`` sum instead.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional
 
 DEFAULT_CAPACITY = 4096
@@ -132,15 +141,35 @@ def record_span(name: str, start: float, end: float, *,
         pass
 
 
+_NO_ANNOTATION = nullcontext()
+
+
+def annotate(name: str, **tags: Any):
+    """A context manager that puts ``name`` (and ``tags``, as the event's
+    stats) on the profiler's clock: a ``jax.profiler.TraceAnnotation``
+    where jax is already in this process, one shared no-op otherwise.
+    Never imports jax and never raises; with no capture running an
+    annotation costs one atomic load.  It records nothing in the ring."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_ANNOTATION
+    try:
+        return jax.profiler.TraceAnnotation(name, **tags)
+    except Exception:   # a half-imported jax (another thread mid-import)
+        return _NO_ANNOTATION
+
+
 @contextmanager
 def span(name: str, cat: str = "span",
          tags: Optional[Dict[str, Any]] = None):
     """Time a block and record it: ``with spans.span("load_batch"): ...``
     — unlike ``tracing.start_span`` this does not open a propagating
-    trace context, it only records the timing."""
+    trace context, it only records the timing (and shows under the same
+    name in a profiler capture, see ``annotate``)."""
     t0 = time.time()
     try:
-        yield
+        with annotate(name):
+            yield
     finally:
         record_span(name, t0, time.time(), cat=cat, tags=tags)
 

@@ -431,13 +431,21 @@ def profile_worker(*, worker_id: Optional[str] = None,
 def jax_profile(*, duration_s: float = 3.0,
                 node_id: Optional[str] = None,
                 force: bool = False,
+                python_tracer: bool = False,
                 address: Optional[str] = None) -> List[Dict]:
     """Start an on-demand ``jax.profiler`` capture on every live worker
     (optionally filtered by node prefix) and return
     [{node_id, pid, ok, path|error}, ...].  Workers that never imported
     jax are skipped unless ``force`` (the tier-1 CPU guard); artifact
     paths are also reported to the controller (``telemetry()`` →
-    ``profiles``)."""
+    ``profiles``).
+
+    A capture holds the device's programs and operations (kernels and
+    ``jax.named_scope`` names) and the host's ``spans.annotate``
+    annotations (``llm.*``, ``train.*``, span blocks) on one clock.
+    ``python_tracer`` also traces every Python call: it slows the host
+    loop being measured and makes the trace many times larger, so it
+    is off by default."""
     from concurrent.futures import ThreadPoolExecutor
 
     nodes = _agents(node_id, address)
@@ -447,7 +455,8 @@ def jax_profile(*, duration_s: float = 3.0,
     def _one(n):
         try:
             r = _agent_call(n["agent_addr"], "jax_profile_workers",
-                            {"duration_s": duration_s, "force": force})
+                            {"duration_s": duration_s, "force": force,
+                             "python_tracer": python_tracer})
         except Exception as e:  # noqa: BLE001 — one dead agent must
             # not discard every other node's finished capture
             return [{"node_id": n["node_id"], "pid": -1, "ok": False,

@@ -70,11 +70,14 @@ def set_span_context(ctx: Optional[Dict[str, str]]) -> None:
 class start_span:
     """Context manager opening a span under the current one.  On exit
     the finished span is also recorded into the process span ring
-    (util/spans.py) so it shows up in the cluster timeline."""
+    (util/spans.py) so it shows up in the cluster timeline; while it is
+    open it is a profiler annotation of the same name
+    (``spans.annotate``), so a device capture shows it too."""
 
     def __init__(self, name: str):
         self.name = name
         self._prev: Optional[Dict[str, str]] = None
+        self._annotation: Any = None
         self.ctx: Dict[str, str] = {}
 
     def __enter__(self) -> "start_span":
@@ -90,6 +93,10 @@ class start_span:
         self._prev = parent
         self._t0 = time.time()
         _current.set(self.ctx)
+        from . import spans as _spans
+
+        self._annotation = _spans.annotate(self.name)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
@@ -97,6 +104,7 @@ class start_span:
         try:
             from . import spans as _spans
 
+            self._annotation.__exit__(None, None, None)
             _spans.record_span(self.name, self._t0, time.time(),
                                cat="span", trace=self.ctx)
         except Exception:

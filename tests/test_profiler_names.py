@@ -1,0 +1,377 @@
+"""One clock (PR 24): host phases as profiler annotations and stats()
+counters, named kernels and scopes.  Plain asserts on names and counts:
+``spans.annotate`` and who enters it, the engine's ``phase_s``, what a CPU
+``jax.profiler`` capture of a tiny engine and of ``session.report`` holds,
+the names in the lowered programs, that the scopes change no bit, and the
+operator's capture options."""
+
+import asyncio
+import contextlib
+import dataclasses
+import glob
+import subprocess
+import sys
+import types
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import ray_tpu
+from ray_tpu.llm import engine as engine_mod
+from ray_tpu.llm.engine import (PHASE_LEAVES, EngineConfig,
+                                GenerationEngine, jit_forward)
+from ray_tpu.llm.kv_cache import init_cache
+from ray_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_init, gpt2_loss_fn
+from ray_tpu.train.session import TrainSession, iter_device_batches
+from ray_tpu.train.train_step import (TrainState, make_optimizer,
+                                      make_sharded_train_step,
+                                      make_train_step)
+from ray_tpu.util import chips, spans, tracing
+
+CFG = dataclasses.replace(GPT2Config.tiny(), remat=False,
+                          dtype=jnp.float32)
+TRAIN_CFG = dataclasses.replace(GPT2Config.tiny(), attn_impl="flash")
+
+
+# ------------------------------------------------------------ the bridge
+
+def test_annotate_without_jax_imports_none_and_is_one_shared_noop():
+    code = (
+        "import sys\n"
+        "import ray_tpu.util.spans as s\n"
+        "a = s.annotate('x', step=1)\n"
+        "with a:\n"
+        "    pass\n"
+        "with s.span('y'):\n"
+        "    pass\n"
+        "assert a is s.annotate('z'), 'not one shared no-op'\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+        "assert [e['name'] for e in s.snapshot()] == ['y']\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+
+
+def test_annotate_with_jax_loaded_is_a_trace_annotation():
+    assert isinstance(spans.annotate("x", bucket=16),
+                      jax.profiler.TraceAnnotation)
+
+
+def test_annotate_never_raises_on_a_half_imported_jax(monkeypatch):
+    monkeypatch.setitem(sys.modules, "jax", types.ModuleType("jax"))
+    with spans.annotate("x", a=1):      # no jax.profiler yet: the no-op
+        pass
+    assert spans.annotate("x") is spans.annotate("y")
+
+
+@pytest.mark.parametrize("opener", ["span", "start_span"])
+def test_scoped_spans_enter_annotate_and_still_record(monkeypatch, opener):
+    entered = []
+
+    @contextlib.contextmanager
+    def fake(name, **tags):
+        entered.append(name)
+        yield
+
+    monkeypatch.setattr(spans, "annotate", fake)
+    ring = spans.reset()
+    with (spans.span("load") if opener == "span"
+          else tracing.start_span("load")):
+        assert entered == ["load"]
+    assert [e["name"] for e in ring.snapshot()] == ["load"]
+
+
+# ------------------------------------------------------------ the engine
+
+def _engine(**kw):
+    return GenerationEngine(
+        model_cfg=CFG, params=gpt2_init(CFG, jax.random.PRNGKey(3)),
+        engine_cfg=EngineConfig(page_size=4, num_pages=64, max_batch=4,
+                                prefill_token_budget=64,
+                                max_tokens_default=4, **kw))
+
+
+@pytest.fixture(scope="module")
+def scripted():
+    """No thread: two prompts of one bucket and one of another, stepped
+    until all are done."""
+    eng = _engine()
+    for prompt in ([5, 6, 7], [8, 9, 10, 11], list(range(1, 12))):
+        eng.submit(prompt, max_tokens=3)
+    steps = 0
+    while eng.step()["running"] or steps == 0:
+        steps += 1
+    return eng, steps + 1
+
+
+def test_phase_counters_partition_the_step(scripted):
+    eng, steps = scripted
+    s = eng.stats()
+    assert s["steps"] == steps
+    assert tuple(s["phase_s"]) == PHASE_LEAVES == (
+        "llm.cancel", "llm.admit", "llm.prefill.pack", "llm.prefill.run",
+        "llm.prefill.fetch", "llm.prefill.sample", "llm.decode.pages",
+        "llm.decode.pack", "llm.decode.run", "llm.decode.fetch",
+        "llm.decode.sample", "llm.publish")
+    assert all(v >= 0 for v in s["phase_s"].values())
+    assert s["llm.other"] >= 0
+    assert sum(s["phase_s"].values()) + s["llm.other"] == \
+        pytest.approx(s["step_s"], rel=1e-12)
+    assert s["phase_s"]["llm.decode.run"] > 0
+    assert s["phase_s"]["llm.prefill.fetch"] > 0
+
+
+def test_prefills_and_compiles_count_what_the_script_did(scripted):
+    eng, _ = scripted
+    s = eng.stats()
+    assert s["prefills"] == 3
+    # buckets 8 and 16, and the decode program
+    assert s["compiles"] == 3 == len(s["programs"])
+    assert set(s["programs"]) == {"llm_prefill[8]", "llm_prefill[16]",
+                                  "llm_decode"}
+
+
+def test_stats_asks_the_device_nothing_and_peak_is_the_programs(
+        scripted, monkeypatch):
+    eng, _ = scripted
+
+    def boom(*a, **kw):
+        raise AssertionError("stats() queried the device")
+
+    monkeypatch.setattr(chips, "peak_device_memory_bytes", boom)
+    for d in jax.local_devices():
+        monkeypatch.setattr(type(d), "memory_stats", boom, raising=False)
+    s = eng.stats()
+    totals = [engine_mod._program_bytes(exe)
+              for _, exe in eng._fwd_cache.values()]
+    assert len(totals) == 3 and min(totals) > 0
+    assert s["peak_hbm_bytes"] == max(totals)
+
+
+# ----------------------------------------------------- what a capture holds
+
+def _capture(tmp_path, fn):
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("llm.", "train.")):
+                    events.setdefault(e.name, []).append(dict(e.stats))
+    return events
+
+
+def test_cpu_capture_of_a_tiny_engine_holds_the_phases(tmp_path):
+    eng = _engine()
+    eng.submit(list(range(1, 12)), max_tokens=3, request_id="abc")
+    eng.step()                  # bucket 16 and decode compile here
+
+    def run():
+        eng.submit([5, 6, 7], max_tokens=3)
+        for _ in range(4):
+            eng.step()
+
+    events = _capture(tmp_path, run)
+    assert set(PHASE_LEAVES) | {"llm.step", "llm.decode",
+                                "llm.prefill"} <= set(events)
+    assert len(events["llm.step"]) == 4
+    assert {"step", "running", "waiting"} <= set(events["llm.step"][0])
+    assert len(events["llm.decode.fetch"]) == len(events["llm.decode"])
+    prefill, = events["llm.prefill"]
+    assert prefill["bucket"] == 8 and prefill["prompt_tokens"] == 3
+    assert events["llm.decode"][0]["batch"] == 2
+    # bucket 8 is new to this engine: its compile is inside the capture
+    assert events["llm.compile"] == [{"program": "llm_prefill[8]"}]
+
+
+class _FakeQueue:
+    class push:                         # noqa: N801 — an actor method
+        @staticmethod
+        def remote(payload):
+            return payload
+
+
+def test_cpu_capture_of_session_report_holds_observe_and_push(
+        tmp_path, monkeypatch):
+    monkeypatch.setattr(ray_tpu, "get", lambda ref: ref)
+    session = TrainSession(world_rank=0, world_size=1, local_rank=0,
+                           local_world_size=1, node_rank=0,
+                           experiment_name="t", result_queue=_FakeQueue())
+    batches = iter_device_batches(
+        [{"x": np.zeros((2, 2), np.float32)}] * 3)
+
+    def run():
+        for i, _ in enumerate(batches):
+            session.report({"step": i, "tokens": 8})
+
+    events = _capture(tmp_path, run)
+    for name in ("train.report", "train.report.observe",
+                 "train.report.push", "train.input.transfer"):
+        assert len(events[name]) == 3, name
+
+
+def test_report_builds_each_metric_once(monkeypatch):
+    from ray_tpu.util import metrics
+
+    built = []
+    for kind in ("Gauge", "Histogram"):
+        real = getattr(metrics, kind)
+
+        def counting(name, description, _real=real):
+            built.append(name)
+            return _real(name, description)
+
+        monkeypatch.setattr(metrics, kind, counting)
+    session = TrainSession(world_rank=0, world_size=1, local_rank=0,
+                           local_world_size=1, node_rank=0,
+                           experiment_name="t")
+    for i in range(4):
+        session.report({"step": i, "tokens": 8})
+    assert sorted(built) == ["rt_train_step", "rt_train_step_time_seconds",
+                             "rt_train_tokens_per_sec"]
+
+
+# ------------------------------------------------- names in the programs
+
+def _train_step_and_args(cfg=TRAIN_CFG):
+    optimizer = make_optimizer()
+    state = TrainState.create(gpt2_init(cfg, jax.random.PRNGKey(0)),
+                              optimizer)
+    batch = {"tokens": jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (2, cfg.max_seq + 1)), jnp.int32)}
+    step = make_train_step(
+        lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=32), optimizer)
+    return step, state, batch
+
+
+def _forward_and_args(cfg=CFG):
+    params = gpt2_init(cfg, jax.random.PRNGKey(3))
+    kv = init_cache(cfg.n_layer, 8, 4, cfg.n_head,
+                    cfg.d_model // cfg.n_head, cfg.dtype)
+    tokens = jnp.asarray([[5, 6, 7, 0]], jnp.int32)
+    positions = jnp.asarray([[0, 1, 2, -1]], jnp.int32)
+    table = jnp.asarray([[1, 2, 0, 0]], jnp.int32)
+    return jit_forward(GPT2(cfg)), (params, tokens, kv["k_pages"],
+                                    kv["v_pages"], table, positions)
+
+
+def test_lowered_train_step_names_kernels_and_scopes():
+    step, state, batch = _train_step_and_args()
+    text = jax.jit(step).lower(state, batch).as_text(debug_info=True)
+    for name in ("flash_fwd", "flash_dq", "flash_dkv", "loss_and_grad",
+                 "optimizer", "grad_norm", "attn.qkv", "attn.core",
+                 "attn.out", "mlp", "embed"):
+        assert name in text, name
+    # the chunked loss, forward and backward (a custom_vjp: both sides
+    # are traced as jvp(loss))
+    assert "loss_and_grad/jvp(loss)/" in text
+    assert "transpose(loss_and_grad)/jvp(loss)/" in text
+
+
+def test_lowered_engine_forward_names_the_cache_scopes():
+    fwd, args = _forward_and_args()
+    text = fwd.lower(*args).as_text(debug_info=True)
+    for name in ("kv.store", "kv.attend", "attn.core", "embed", "lm_head"):
+        assert name in text, name
+    assert "jit_fwd" in text        # the reader of decode.device_ms.sat
+
+
+@pytest.mark.parametrize("what", ["train_step", "engine_forward"])
+def test_scopes_change_no_bit(monkeypatch, what):
+    def run():
+        if what == "train_step":
+            step, state, batch = _train_step_and_args()
+            _, m = jax.jit(step)(state, batch)
+            return [np.asarray(m["loss"]), np.asarray(m["grad_norm"])]
+        fwd, args = _forward_and_args()
+        logits, k, _ = fwd(*args)
+        return [np.asarray(logits), np.asarray(k)]
+
+    with_scopes = run()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = run()
+    for a, b in zip(with_scopes, without):
+        assert a.tobytes() == b.tobytes()
+
+
+def test_timed_step_annotates_dispatch_and_builds_its_histogram_once(
+        tmp_path, monkeypatch):
+    from ray_tpu.util import metrics
+
+    built = []
+    real = metrics.Histogram
+
+    def counting(name, *a, **kw):
+        built.append(name)
+        return real(name, *a, **kw)
+
+    monkeypatch.setattr(metrics, "Histogram", counting)
+    cfg = dataclasses.replace(GPT2Config.tiny(), attn_impl="dense")
+    _, state, batch = _train_step_and_args(cfg)
+    step = make_sharded_train_step(
+        lambda p, b: gpt2_loss_fn(cfg, p, b), make_optimizer(),
+        donate=False)
+
+    def run():
+        for _ in range(4):
+            step(state, batch)
+
+    events = _capture(tmp_path, run)
+    assert len(events["train.step.compile"]) == 1
+    assert len(events["train.step.dispatch"]) == 4
+    assert built.count("rt_train_step_dispatch_seconds") == 1
+
+
+# --------------------------------------------------- the operator's capture
+
+@pytest.mark.parametrize("request_fields,level", [
+    ({}, 0), ({"python_tracer": False}, 0), ({"python_tracer": True}, 1)])
+def test_worker_jax_profile_python_tracer_off_by_default(
+        monkeypatch, tmp_path, request_fields, level):
+    from ray_tpu.core.worker_main import Worker
+
+    seen = {}
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda log_dir, profiler_options=None: seen.update(
+            level=profiler_options.python_tracer_level, dir=log_dir))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    reply = asyncio.run(Worker.jax_profile(
+        types.SimpleNamespace(),
+        {"duration_s": 0.01, "log_dir": str(tmp_path), **request_fields}))
+    assert reply == {"ok": True, "path": str(tmp_path)}
+    assert seen == {"level": level, "dir": str(tmp_path)}
+
+
+@pytest.mark.parametrize("flag", [False, True])
+def test_state_and_cli_pass_python_tracer_through(monkeypatch, flag):
+    from ray_tpu.scripts import cli
+    from ray_tpu.util import state
+
+    calls = []
+    monkeypatch.setattr(state, "_agents", lambda node_id, address: [
+        {"agent_addr": "a:1", "node_id": "n0"}])
+    monkeypatch.setattr(
+        state, "_agent_call",
+        lambda addr, method, req: calls.append((method, req))
+        or {"results": [{"pid": 1, "ok": True, "path": "/p"}]})
+    monkeypatch.setattr(cli, "resolve_address", lambda address: "c:1")
+    argv = ["profile", "--jax"] + (["--python-tracer"] if flag else [])
+    args = cli._build_parser().parse_args(argv)
+    assert args.fn(args) == 0
+    (method, req), = calls
+    assert method == "jax_profile_workers"
+    assert req["python_tracer"] is flag
