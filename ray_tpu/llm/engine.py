@@ -152,8 +152,12 @@ class _Sequence:
 
 def jit_forward(model):
     """The engine's one jitted forward: it serves prefill ([1, bucket])
-    and decode ([max_batch, 1]); XLA specializes per shape.  Donating
-    the pooled KV buffers makes the update in-place on TPU."""
+    and decode ([max_batch, 1]); XLA specializes per shape.
+    ``k_pages`` / ``v_pages`` are the whole pool, each
+    [L, pages, page, h_kv*d]; donated, and carried through the layers
+    by the model, they are updated in place: the program scatters the
+    new rows and holds no second pool (tests/test_llm.py and
+    tests/test_tpu_compile.py pin that)."""
     import jax
 
     def fwd(p, tokens, k_pages, v_pages, page_table, positions):

@@ -1,20 +1,26 @@
 """Paged KV cache — fixed-size pages from one preallocated device pool.
 
 vLLM-style memory management adapted to JAX/TPU: the K/V history of
-every running sequence lives in ONE device buffer per model
-([n_layer, num_pages, page_size, n_kv_head, head_dim]), carved into
-fixed-size pages.  A sequence maps logical token positions to physical
-pages through its page table (position p lives in page
-``table[p // page_size]`` at slot ``p % page_size``), so sequences
-grow without reallocation or copying, free pages are recycled at step
-granularity, and fragmentation is bounded by one partial page per
-sequence.  Because the pool shape is static, the jitted decode step
+every running sequence lives in ONE device buffer per model (K and V
+each [n_layer, num_pages, page_size, n_kv_head * head_dim]), carved
+into fixed-size pages.  The heads are FOLDED into the minor dimension:
+with a 64-wide head last, the TPU tiles the pool page-minor and every
+scatter and gather pays a transpose of the whole layer; a page that is
+a row-major [page_size, h_kv*d] tile is updated where it lies.  A
+sequence maps logical token positions to physical pages through its
+page table (position p lives in page ``table[p // page_size]`` at slot
+``p % page_size``), so sequences grow without reallocation or copying,
+free pages are recycled at step granularity, and fragmentation is
+bounded by one partial page per sequence.  Because the pool shape is static, the jitted decode step
 compiles once — admission/retirement only edits page tables and host
 accounting.
 
 Two pure jnp helpers implement the data path (used by the models'
 decode-mode forwards): ``paged_store`` scatters fresh K/V into pages,
 ``paged_attend`` gathers a batch's pages and runs masked attention.
+Both take the WHOLE pool and a layer index, and the forward carries
+that one pool from layer to layer: slicing a layer out and stacking
+the layers again makes XLA build a new pool beside the donated one.
 ``PagePool`` is the host-side allocator; it exports
 ``rt_llm_kv_pages_{used,total}`` gauges on every alloc/free so KV
 occupancy is visible in ``rt telemetry`` and the doctor can see leaks.
@@ -31,23 +37,28 @@ import jax.numpy as jnp
 
 def init_cache(n_layer: int, num_pages: int, page_size: int,
                n_kv_head: int, head_dim: int, dtype: Any) -> Dict[str, Any]:
-    """Preallocate the pooled K/V buffers (zeros; pages are recycled
-    without clearing — the position mask in paged_attend makes stale
-    contents unreachable)."""
-    shape = (n_layer, num_pages, page_size, n_kv_head, head_dim)
+    """Preallocate the pooled K/V buffers, each
+    [n_layer, num_pages, page_size, n_kv_head * head_dim] (zeros; pages
+    are recycled without clearing — the position mask in paged_attend
+    makes stale contents unreachable)."""
+    shape = (n_layer, num_pages, page_size, n_kv_head * head_dim)
     return {"k_pages": jnp.zeros(shape, dtype),
             "v_pages": jnp.zeros(shape, dtype)}
 
 
-def paged_store(k_pages, v_pages, k_new, v_new, page_table, positions):
-    """Scatter new K/V ([B, T, h_kv, d]) into the page pool.
+def paged_store(k_pages, v_pages, layer, k_new, v_new, page_table,
+                positions):
+    """Scatter new K/V ([B, T, h_kv, d]) into layer ``layer`` of the
+    WHOLE pool ([L, pages, page, h_kv*d]) as rows [B, T, h_kv*d], and
+    return the pool.
 
     ``positions`` is [B, T] absolute token positions; negative entries
     are padding and are dropped (scatter mode="drop" via an
     out-of-range page index), so one call serves prefill (T = padded
     prompt length) and batched decode (T = 1, padded rows) alike.
     """
-    num_pages, page_size = k_pages.shape[0], k_pages.shape[1]
+    num_pages, page_size = k_pages.shape[1], k_pages.shape[2]
+    b, t = positions.shape
     with jax.named_scope("kv.store"):
         pos = jnp.maximum(positions, 0)
         page_ix = jnp.take_along_axis(page_table, pos // page_size,
@@ -55,31 +66,32 @@ def paged_store(k_pages, v_pages, k_new, v_new, page_table, positions):
         # Out-of-range index => dropped write for padded slots.
         page_ix = jnp.where(positions >= 0, page_ix, num_pages)
         slot = pos % page_size
-        k_pages = k_pages.at[page_ix, slot].set(
-            k_new.astype(k_pages.dtype), mode="drop")
-        v_pages = v_pages.at[page_ix, slot].set(
-            v_new.astype(v_pages.dtype), mode="drop")
+        k_pages = k_pages.at[layer, page_ix, slot].set(
+            k_new.reshape(b, t, -1).astype(k_pages.dtype), mode="drop")
+        v_pages = v_pages.at[layer, page_ix, slot].set(
+            v_new.reshape(b, t, -1).astype(v_pages.dtype), mode="drop")
     return k_pages, v_pages
 
 
-def paged_attend(q, k_pages, v_pages, page_table, positions):
-    """Causal attention of q ([B, T, h, d]) against the paged cache.
+def paged_attend(q, k_pages, v_pages, layer, page_table, positions):
+    """Causal attention of q ([B, T, h, d]) against layer ``layer`` of
+    the paged cache.
 
-    Gathers each sequence's pages ([B, P, page, h_kv, d] ->
-    [B, P*page, h_kv, d]) and masks by ABSOLUTE position: cache slot j
-    is visible to a query at position p iff j <= p, which both
-    enforces causality and hides unwritten/stale slots (every position
-    <= p has been written by construction).  GQA caches store h_kv
-    heads and repeat to h at attend time, exactly like the full
-    forward."""
+    Gathers each sequence's pages as folded rows ([B, P, page, h_kv*d])
+    and only then views them as [B, P*page, h_kv, d]; masks by ABSOLUTE
+    position: cache slot j is visible to a query at position p iff
+    j <= p, which both enforces causality and hides unwritten/stale
+    slots (every position <= p has been written by construction).  GQA
+    caches store h_kv heads and repeat to h at attend time, exactly
+    like the full forward."""
     b, t, h, d = q.shape
     with jax.named_scope("kv.attend"):
-        ks = k_pages[page_table]          # [B, P, page, h_kv, d]
-        vs = v_pages[page_table]
+        ks = k_pages[layer, page_table]   # [B, P, page, h_kv*d]
+        vs = v_pages[layer, page_table]
         p, page = ks.shape[1], ks.shape[2]
-        ks = ks.reshape(b, p * page, ks.shape[3], d)
-        vs = vs.reshape(b, p * page, vs.shape[3], d)
-        h_kv = ks.shape[2]
+        h_kv = ks.shape[3] // d
+        ks = ks.reshape(b, p * page, h_kv, d)
+        vs = vs.reshape(b, p * page, h_kv, d)
         if h_kv != h:                      # GQA: repeat KV groups
             rep = h // h_kv
             ks = jnp.repeat(ks, rep, axis=2)

@@ -182,9 +182,9 @@ class Block(nn.Module):
 
             with jax.named_scope("attn.core"):
                 k_pages, v_pages = paged_store(
-                    cache["k_pages"], cache["v_pages"], k, v,
-                    cache["page_table"], cache["positions"])
-                att = paged_attend(q, k_pages, v_pages,
+                    cache["k_pages"], cache["v_pages"], cache["layer"],
+                    k, v, cache["page_table"], cache["positions"])
+                att = paged_attend(q, k_pages, v_pages, cache["layer"],
                                    cache["page_table"],
                                    cache["positions"])
             new_cache = (k_pages, v_pages)
@@ -234,12 +234,14 @@ class GPT2(nn.Module):
 
         Decode mode attends against the paged KV pool instead of
         recomputing the sequence: ``kv_cache`` is {"k_pages",
-        "v_pages": [L, pages, page, h, d], "page_table": [B, P]} and
+        "v_pages": [L, pages, page, h*d], "page_table": [B, P]} and
         ``positions`` [B, T] gives each new token's absolute position
         (negative = padding).  One prefill call (T = prompt length)
         populates the cache; each decode call appends T=1 tokens.
-        Returns (logits, new_kv_cache) — token-identical to the full
-        forward (pinned by tests/test_llm.py)."""
+        Returns (logits, new_kv_cache): the pool it was given, carried
+        whole through the layers and updated (llm/kv_cache.py says
+        why) — token-identical to the full forward (pinned by
+        tests/test_llm.py)."""
         cfg = self.cfg
         decode = kv_cache is not None
         wte = self.param("wte", nn.initializers.normal(0.02),
@@ -260,19 +262,19 @@ class GPT2(nn.Module):
         if cfg.remat and not decode:
             # Decode steps are memory-light; remat would only slow them.
             block = nn.remat(Block, prevent_cse=False)
-        new_k, new_v = [], []
+        if decode:
+            # ONE pool through every layer, updated where it lies.
+            k_pages, v_pages = kv_cache["k_pages"], kv_cache["v_pages"]
         for i in range(cfg.n_layer):
             use_moe = (cfg.moe_num_experts > 0
                        and i % cfg.moe_every == cfg.moe_every - 1)
             blk = block(cfg, use_moe=use_moe, name=f"h_{i}")
             if decode:
-                x, (k_i, v_i) = blk(
-                    x, cache={"k_pages": kv_cache["k_pages"][i],
-                              "v_pages": kv_cache["v_pages"][i],
+                x, (k_pages, v_pages) = blk(
+                    x, cache={"k_pages": k_pages, "v_pages": v_pages,
+                              "layer": i,
                               "page_table": kv_cache["page_table"],
                               "positions": positions})
-                new_k.append(k_i)
-                new_v.append(v_i)
             else:
                 x = blk(x)
             x = _constrain(x, ("batch", "seq", "embed"), cfg)
@@ -284,8 +286,7 @@ class GPT2(nn.Module):
                                 preferred_element_type=jnp.float32)
             logits = _constrain(logits, ("batch", "seq", "vocab"), cfg)
         if decode:
-            return logits, {"k_pages": jnp.stack(new_k),
-                            "v_pages": jnp.stack(new_v),
+            return logits, {"k_pages": k_pages, "v_pages": v_pages,
                             "page_table": kv_cache["page_table"]}
         return logits
 

@@ -200,9 +200,9 @@ class LlamaBlock(nn.Module):
 
             with jax.named_scope("attn.core"):
                 k_pages, v_pages = paged_store(
-                    cache["k_pages"], cache["v_pages"], k, v,
-                    cache["page_table"], positions)
-                att = paged_attend(q, k_pages, v_pages,
+                    cache["k_pages"], cache["v_pages"], cache["layer"],
+                    k, v, cache["page_table"], positions)
+                att = paged_attend(q, k_pages, v_pages, cache["layer"],
                                    cache["page_table"], positions)
             new_cache = (k_pages, v_pages)
         else:
@@ -242,6 +242,8 @@ class Llama(nn.Module):
     def __call__(self, tokens, kv_cache=None, positions=None):
         """Full forward (kv_cache=None) or incremental decode step
         against the paged KV pool — same contract as GPT2.__call__:
+        ``k_pages`` / ``v_pages`` are [L, pages, page, h_kv*d] (the
+        GROUPED heads, folded), carried whole through the layers;
         decode mode returns (logits, new_kv_cache)."""
         cfg = self.cfg
         decode = kv_cache is not None
@@ -253,17 +255,17 @@ class Llama(nn.Module):
         block = LlamaBlock
         if cfg.remat and not decode:
             block = nn.remat(LlamaBlock, prevent_cse=False)
-        new_k, new_v = [], []
+        if decode:
+            # ONE pool through every layer, updated where it lies.
+            k_pages, v_pages = kv_cache["k_pages"], kv_cache["v_pages"]
         for i in range(cfg.n_layer):
             blk = block(cfg, name=f"layer_{i}")
             if decode:
-                x, (k_i, v_i) = blk(
-                    x, cache={"k_pages": kv_cache["k_pages"][i],
-                              "v_pages": kv_cache["v_pages"][i],
+                x, (k_pages, v_pages) = blk(
+                    x, cache={"k_pages": k_pages, "v_pages": v_pages,
+                              "layer": i,
                               "page_table": kv_cache["page_table"],
                               "positions": positions})
-                new_k.append(k_i)
-                new_v.append(v_i)
             else:
                 x = blk(x)
             x = _constrain(x, ("batch", "seq", "embed"), cfg)
@@ -275,8 +277,7 @@ class Llama(nn.Module):
                                 preferred_element_type=jnp.float32)
             logits = _constrain(logits, ("batch", "seq", "vocab"), cfg)
         if decode:
-            return logits, {"k_pages": jnp.stack(new_k),
-                            "v_pages": jnp.stack(new_v),
+            return logits, {"k_pages": k_pages, "v_pages": v_pages,
                             "page_table": kv_cache["page_table"]}
         return logits
 

@@ -239,6 +239,57 @@ def test_llama_incremental_decode_token_identical():
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
+# --------------------------------------------- the pool stays in place
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama_gqa"])
+def test_forward_updates_donated_pool_in_place(family):
+    """The engine's jitted forward aliases BOTH pool arrays to its
+    outputs and holds no second pool among its temporaries: the model
+    carries one [L, pages, page, h_kv*d] pool through the layers and
+    scatters into it.  Slicing a layer out and stacking the layers
+    again put 1.80-1.94 pool arrays of temporaries here; carried, the
+    four programs below hold 0.09-0.32 (activations).
+    float32 on purpose: the CPU backend upcasts a bf16 scatter to f32
+    and would put a pool-sized temporary back that the TPU never has
+    (tests/test_tpu_compile.py holds the TPU's program to the same)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_cache, pages_for
+
+    if family == "gpt2":
+        from ray_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_init
+
+        cfg = dataclasses.replace(GPT2Config.tiny(), n_layer=4,
+                                  remat=False, dtype=jnp.float32)
+        model, init, n_kv_head = GPT2(cfg), gpt2_init, cfg.n_head
+    else:
+        from ray_tpu.models.llama import Llama, LlamaConfig, llama_init
+
+        cfg = dataclasses.replace(LlamaConfig.tiny(), n_layer=4,
+                                  remat=False, dtype=jnp.float32)
+        assert cfg.n_kv_head < cfg.n_head
+        model, init, n_kv_head = Llama(cfg), llama_init, cfg.n_kv_head
+    page_size = 16
+    params = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
+    kv = jax.eval_shape(lambda: init_cache(
+        cfg.n_layer, 64, page_size, n_kv_head, cfg.d_model // cfg.n_head,
+        cfg.dtype))
+    pool_bytes = kv["k_pages"].size * kv["k_pages"].dtype.itemsize
+    fwd = jit_forward(model)
+    for shape in ((2, 1), (1, 32)):          # decode, prefill
+        ints = jax.ShapeDtypeStruct(shape, jnp.int32)
+        table = jax.ShapeDtypeStruct(
+            (shape[0], pages_for(cfg.max_seq, page_size)), jnp.int32)
+        m = fwd.lower(params, ints, kv["k_pages"], kv["v_pages"], table,
+                      ints).compile().memory_analysis()
+        assert m.alias_size_in_bytes == 2 * pool_bytes, shape
+        assert m.temp_size_in_bytes < 0.5 * pool_bytes, (
+            shape, m.temp_size_in_bytes / pool_bytes)
+
+
 # --------------------------------------------------- rope table cache
 
 

@@ -16,6 +16,7 @@ name the model imports) — not an option of the program.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -127,19 +128,67 @@ def _engine_args(cfg, one_chip, tokens_shape, num_pages=2048,
             ints(tokens_shape))
 
 
-@pytest.mark.parametrize("kind,tokens_shape", [("decode", (8, 1)),
-                                               ("prefill", (1, 512))])
-def test_engine_forward_compiles_at_124m(one_chip, kind, tokens_shape):
-    """The engine's own jitted forward (llm/engine.py jit_forward) over a
-    2048-page KV pool: the decode step [max_batch, 1] and one prefill
-    bucket."""
+ENGINE_WIDTHS = {"124m": GPT2_124M,
+                 "large": dict(GPT2_124M, n_layer=36, n_head=20,
+                               d_model=1280, d_ff=5120)}
+
+
+@pytest.fixture(scope="module")
+def engine_program(one_chip):
+    """(compiled jit_forward, the shape of one pool array) by size:
+    one compile for all the tests of a size."""
     from ray_tpu.llm.engine import jit_forward
     from ray_tpu.models.gpt2 import GPT2, GPT2Config
 
-    cfg = GPT2Config(**GPT2_124M, attn_impl="dense", remat=False)
-    compiled = jit_forward(GPT2(cfg)).lower(
-        *_engine_args(cfg, one_chip, tokens_shape)).compile()
+    @functools.lru_cache(maxsize=None)
+    def program(widths, tokens_shape, num_pages):
+        cfg = GPT2Config(**ENGINE_WIDTHS[widths], attn_impl="dense",
+                         remat=False)
+        args = _engine_args(cfg, one_chip, tokens_shape,
+                            num_pages=num_pages)
+        return jit_forward(GPT2(cfg)).lower(*args).compile(), args[2]
+
+    return program
+
+
+@pytest.mark.parametrize("kind,tokens_shape", [("decode", (8, 1)),
+                                               ("prefill", (1, 512))])
+def test_engine_forward_compiles_at_124m(engine_program, kind,
+                                         tokens_shape):
+    """The engine's own jitted forward (llm/engine.py jit_forward) over a
+    2048-page KV pool: the decode step [max_batch, 1] and one prefill
+    bucket."""
+    compiled, _ = engine_program("124m", tokens_shape, 2048)
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+_POOL_PASS = re.compile(r"^\s*(?:ROOT )?%?[\w.-]+ = \w+\[([\d,]+)\]\S* "
+                        r"(copy|copy-done|slice|dynamic-update-slice)\(")
+
+
+@pytest.mark.parametrize("widths,tokens_shape,num_pages", [
+    ("124m", (8, 1), 2048), ("124m", (1, 512), 2048),
+    ("large", (16, 1), 1024)],
+    ids=["124m_decode", "124m_prefill", "large_decode_cell"])
+def test_engine_forward_updates_pool_in_place(engine_program, widths,
+                                              tokens_shape, num_pages):
+    """No pass over the pool but the scatter of the new rows and the
+    gather of the running sequences' pages (the last case at the sizes
+    of serve-gpt2-large-sat).  With the heads as their own 64-wide
+    minor dimension the TPU tiles the pool page-minor, and every layer
+    paid a slice, transposes (`copy`) and a dynamic-update-slice of
+    one layer of the pool, K and V: 252 such copies and 5.34 GB of
+    temporaries at the cell's sizes, against none and 0.36 GB."""
+    compiled, pool = engine_program(widths, tokens_shape, num_pages)
+    sizes = (pool.size, pool.size // pool.shape[0])     # pool, layer
+    passes = []
+    for line in compiled.as_text().splitlines():
+        m = _POOL_PASS.match(line)
+        if m and np.prod([int(d) for d in m.group(1).split(",")]) in sizes:
+            passes.append(line.strip()[:120])
+    assert not passes, (len(passes), passes[:4])
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.5 * pool.size * pool.dtype.itemsize
 
 
 def _train_step_and_shapes(cfg, loss_chunk):
