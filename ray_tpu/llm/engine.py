@@ -157,16 +157,22 @@ def jit_forward(model):
     [L, pages, page, h_kv*d]; donated, and carried through the layers
     by the model, they are updated in place: the program scatters the
     new rows and holds no second pool (tests/test_llm.py and
-    tests/test_tpu_compile.py pin that)."""
+    tests/test_tpu_compile.py pin that).  A model with experts
+    returns a fourth output, its routing counters ([layers, 3] int32,
+    ops/moe.py ``moe_counters``)."""
     import jax
 
+    from ..ops.moe import moe_counters
+
     def fwd(p, tokens, k_pages, v_pages, page_table, positions):
-        logits, new = model.apply(
+        (logits, new), state = model.apply(
             p, tokens,
             kv_cache={"k_pages": k_pages, "v_pages": v_pages,
                       "page_table": page_table},
-            positions=positions)
-        return logits, new["k_pages"], new["v_pages"]
+            positions=positions, mutable=["intermediates"])
+        out = (logits, new["k_pages"], new["v_pages"])
+        moe = moe_counters(state.get("intermediates", {}))
+        return out if moe is None else out + (moe,)
 
     return jax.jit(fwd, donate_argnums=(2, 3))
 
@@ -183,33 +189,25 @@ def _program_bytes(exe) -> int:
 
 
 class GenerationEngine:
-    """Continuous-batching engine for one GPT-2 / Llama replica."""
+    """Continuous-batching engine for one replica of a model family
+    (a row of ``ray_tpu.models.MODEL_FAMILIES``)."""
 
     def __init__(self, model: str = "gpt2", model_cfg: Any = None,
                  engine_cfg: Optional[EngineConfig] = None,
                  params: Any = None, seed: int = 0):
         import jax
 
-        from ..models.gpt2 import GPT2, GPT2Config, gpt2_init
-        from ..models.llama import Llama, LlamaConfig, llama_init
+        from ..models import MODEL_FAMILIES, family_of
 
         self.cfg = engine_cfg or EngineConfig()
         if model_cfg is None:
-            model_cfg = (GPT2Config.tiny() if model == "gpt2"
-                         else LlamaConfig.tiny())
+            model_cfg = MODEL_FAMILIES[model].tiny()
         self.model_cfg = model_cfg
-        if isinstance(model_cfg, GPT2Config):
-            self._model = GPT2(model_cfg)
-            n_kv = model_cfg.n_head
-            if params is None:
-                params = gpt2_init(model_cfg, jax.random.PRNGKey(seed))
-        elif isinstance(model_cfg, LlamaConfig):
-            self._model = Llama(model_cfg)
-            n_kv = model_cfg.n_kv_head
-            if params is None:
-                params = llama_init(model_cfg, jax.random.PRNGKey(seed))
-        else:
-            raise TypeError(f"unsupported model_cfg {type(model_cfg)}")
+        family = family_of(model_cfg)
+        self._model = family.module(model_cfg)
+        n_kv = family.kv_heads(model_cfg)
+        if params is None:
+            params = family.init(model_cfg, jax.random.PRNGKey(seed))
         self._params = params
         # What this engine computes on, as JAX reports it (stats()).
         self._device = chips.describe_devices()
@@ -253,6 +251,9 @@ class GenerationEngine:
         self._evictions = 0
         self._prefills = 0
         self._compiles = 0
+        # Routing counters of a model with experts, cumulative over
+        # DECODE runs (stats()["moe"]); stays empty for a dense model.
+        self._moe: Dict[str, int] = {}
         # The engine thread sums a step's leaves in _pending and adds
         # them to the totals with the step's own time in one go, under
         # the lock, so that a stats() taken mid-step still sums up.
@@ -454,6 +455,11 @@ class GenerationEngine:
                 "ttft_prefill_s_total": self._prefill_s_total,
                 "tpot_s_total": self._tpot_s_total,
                 "tpot_count": self._tpot_count,
+                # Routing of the decode runs (absent for a dense model):
+                # layer_runs = runs x layers; pairs = real rows x k x
+                # layers; experts_hit and max_load summed over layers
+                # and runs.
+                **({"moe": dict(self._moe)} if self._moe else {}),
             }
 
     # ------------------------------------------------------ engine loop
@@ -653,7 +659,7 @@ class GenerationEngine:
             positions[0, :n] = np.arange(n)
             table = self._page_table_row(seq)[None, :]
         with self._phase("llm.prefill.run"):
-            logits, k, v = self._call_fwd(
+            logits, k, v, *_ = self._call_fwd(
                 "prefill", self._params, tokens, self._kv["k_pages"],
                 self._kv["v_pages"], table, positions)
         self._kv["k_pages"], self._kv["v_pages"] = k, v
@@ -693,12 +699,21 @@ class GenerationEngine:
                 positions[i, 0] = seq.n_cached
                 table[i] = self._page_table_row(seq)
         with self._phase("llm.decode.run"):
-            logits, k, v = self._call_fwd(
+            logits, k, v, *moe = self._call_fwd(
                 "decode", self._params, tokens, self._kv["k_pages"],
                 self._kv["v_pages"], table, positions)
         self._kv["k_pages"], self._kv["v_pages"] = k, v
         with self._phase("llm.decode.fetch"):
             logits_np = np.asarray(logits[:, 0])
+            if moe:
+                from ..ops.moe import MOE_COUNTERS
+
+                per_layer = np.asarray(moe[0])      # [layers, 3]
+                adds = dict(zip(MOE_COUNTERS, per_layer.sum(axis=0)),
+                            layer_runs=len(per_layer))
+                with self._lock:
+                    for key, add in adds.items():
+                        self._moe[key] = self._moe.get(key, 0) + int(add)
         with self._phase("llm.decode.sample"):
             for i, seq in enumerate(batch):
                 seq.n_cached += 1

@@ -48,11 +48,10 @@ class GPT2Config:
     mesh: Any = None                  # jax Mesh for CP shard_map wrappers
     rules: Any = None                 # ShardingRules override
     # Mixture-of-Experts: >0 turns every ``moe_every``-th block's MLP
-    # into an expert-parallel MoEMLP (ops/moe.py).
+    # into a dropless MoEMLP (ops/moe.py) of two-matrix GELU experts.
     moe_num_experts: int = 0
     moe_every: int = 2
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
 
     @staticmethod
@@ -209,8 +208,9 @@ class Block(nn.Module):
                 y = MoEMLP(d_model=cfg.d_model, d_ff=cfg.d_ff,
                            num_experts=cfg.moe_num_experts,
                            top_k=cfg.moe_top_k,
-                           capacity_factor=cfg.moe_capacity_factor,
-                           dtype=cfg.dtype, name="moe_mlp")(y)
+                           dtype=cfg.dtype, name="moe_mlp")(
+                               y, None if cache is None
+                               else cache["positions"] >= 0)
             else:
                 y = nn.Dense(cfg.d_ff, dtype=cfg.dtype, name="mlp_in",
                              kernel_init=nn.initializers.normal(0.02))(y)
@@ -379,17 +379,10 @@ def _chunked_xent_bwd(chunk, res, g):
 _chunked_xent.defvjp(_chunked_xent_fwd, _chunked_xent_bwd)
 
 
-def _moe_aux_total(inter) -> jnp.ndarray:
-    total = jnp.asarray(0.0, jnp.float32)
-    for leaf in jax.tree_util.tree_leaves(inter):
-        total = total + jnp.sum(jnp.asarray(leaf, jnp.float32))
-    return total
-
-
 def gpt2_loss_fn(cfg: GPT2Config, params, batch,
                  loss_chunk: int = 128) -> jnp.ndarray:
     """Next-token cross entropy; batch: {tokens [B, T+1] int32}.
-    MoE configs add the sown Switch load-balancing auxiliary loss."""
+    MoE configs add the load-balancing auxiliary loss (ops/moe.py)."""
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     t = inputs.shape[1]
@@ -404,7 +397,9 @@ def gpt2_loss_fn(cfg: GPT2Config, params, batch,
     if moe:
         logits, state = GPT2(cfg).apply(params, inputs,
                                         mutable=["intermediates"])
-        aux = _moe_aux_total(state.get("intermediates", {}))
+        from ..ops.moe import moe_losses
+
+        aux = moe_losses(state["intermediates"])["load_balancing"]
     else:
         logits = GPT2(cfg).apply(params, inputs)
         aux = 0.0

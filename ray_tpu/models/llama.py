@@ -1,4 +1,7 @@
-"""Llama family — RMSNorm + RoPE + GQA + SwiGLU decoder.
+"""Llama family — RMSNorm + RoPE + GQA + SwiGLU decoder — and OLMoE,
+which is this block plus two things: QK-norm (an RMSNorm over the whole
+``q`` and ``k`` before the head split and RoPE) and sparse experts in
+place of the dense SwiGLU (``ops/moe.py``: dropless top-k).
 
 Covers the reference's Llama fine-tune workloads (ref: release/train_tests
 LLM configs) natively.  Same logical-axis discipline as gpt2.py; grouped
@@ -32,10 +35,22 @@ class LlamaConfig:
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    # What the weights are held in: init makes each leaf in float32 and
+    # casts it to this (OLMoE: bfloat16, as its checkpoint).
+    param_dtype: Any = jnp.float32
     attn_impl: str = "dense"
     remat: bool = True
     mesh: Any = None
     rules: Any = None
+    # OLMoE (Muennighoff et al. 2024): RMSNorm over the full-width q and
+    # k; n_experts > 0 makes every block's FFN ``experts_per_token`` of
+    # ``n_experts`` gated experts of width ``d_ff`` each.
+    qk_norm: bool = False
+    n_experts: int = 0
+    experts_per_token: int = 0
+    norm_topk_prob: bool = False
+    moe_aux_weight: float = 0.01      # load-balancing loss
+    moe_z_weight: float = 0.001       # router z-loss
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -43,10 +58,38 @@ class LlamaConfig:
                            d_model=128, d_ff=384, max_seq=128)
 
     @staticmethod
+    def olmoe_tiny(**overrides) -> "LlamaConfig":
+        """OLMoE's shape at a test's size: 2 layers, 64 wide, 4 heads of
+        16, top-2 of 8 experts of width 32."""
+        return LlamaConfig(**{**dict(
+            vocab_size=256, n_layer=2, n_head=4, n_kv_head=4, d_model=64,
+            d_ff=32, max_seq=128, qk_norm=True, n_experts=8,
+            experts_per_token=2, dtype=jnp.float32), **overrides})
+
+    @staticmethod
+    def olmoe_1b_7b(**overrides) -> "LlamaConfig":
+        """allenai/OLMoE-1B-7B-0125-Instruct as published: 16 layers,
+        2048 wide, 16 heads of 128, top-8 of 64 experts of width 1024,
+        bf16 weights."""
+        return LlamaConfig(**{**dict(
+            vocab_size=50304, n_layer=16, n_head=16, n_kv_head=16,
+            d_model=2048, d_ff=1024, max_seq=4096, qk_norm=True,
+            n_experts=64, experts_per_token=8,
+            param_dtype=jnp.bfloat16), **overrides})
+
+    @staticmethod
     def llama2_7b() -> "LlamaConfig":
         return LlamaConfig(vocab_size=32000, n_layer=32, n_head=32,
                            n_kv_head=32, d_model=4096, d_ff=11008,
                            max_seq=4096)
+
+    def _ffn_params_per_token(self) -> int:
+        """Matmul weights one token passes through in a block's FFN: the
+        dense SwiGLU, or its k experts and the router."""
+        if self.n_experts:
+            return (3 * self.d_model * self.d_ff * self.experts_per_token
+                    + self.d_model * self.n_experts)
+        return 3 * self.d_model * self.d_ff
 
     def flops_per_token(self) -> float:
         head_dim = self.d_model // self.n_head
@@ -55,7 +98,7 @@ class LlamaConfig:
                         self.d_model * self.d_model            # q
                         + 2 * self.d_model * self.n_kv_head * head_dim
                         + self.d_model * self.d_model          # o
-                        + 3 * self.d_model * self.d_ff))
+                        + self._ffn_params_per_token()))
         attn = 6 * 2 * self.n_layer * self.d_model * self.max_seq
         return 6.0 * n_params + attn
 
@@ -74,7 +117,7 @@ class LlamaConfig:
                              self.d_model * self.d_model
                              + 2 * self.d_model * self.n_kv_head * head_dim
                              + self.d_model * self.d_model
-                             + 3 * self.d_model * self.d_ff))
+                             + self._ffn_params_per_token()))
         attn = 4 * self.n_layer * self.d_model * ctx
         return 2.0 * matmul_params + attn
 
@@ -182,16 +225,20 @@ class LlamaBlock(nn.Module):
         # Scope names as in models/gpt2.py (metadata only).
         with jax.named_scope("attn.qkv"):
             q = nn.Dense(h * d_head, use_bias=False, dtype=cfg.dtype,
-                         kernel_init=init,
-                         name="wq")(y).reshape(b, t, h, d_head)
+                         kernel_init=init, name="wq")(y)
             k = nn.Dense(hk * d_head, use_bias=False, dtype=cfg.dtype,
-                         kernel_init=init,
-                         name="wk")(y).reshape(b, t, hk, d_head)
+                         kernel_init=init, name="wk")(y)
             v = nn.Dense(hk * d_head, use_bias=False, dtype=cfg.dtype,
                          kernel_init=init,
                          name="wv")(y).reshape(b, t, hk, d_head)
-            q = _rope(q, cfg.rope_theta, positions)
-            k = _rope(k, cfg.rope_theta, positions)
+            if cfg.qk_norm:     # over the whole width, before the split
+                with jax.named_scope("attn.qk_norm"):
+                    q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
+                    k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
+            q = _rope(q.reshape(b, t, h, d_head), cfg.rope_theta,
+                      positions)
+            k = _rope(k.reshape(b, t, hk, d_head), cfg.rope_theta,
+                      positions)
         if cache is not None:
             # Decode mode: the cache stores the hk GROUPED heads
             # (post-RoPE); repeat-to-h happens at attend time, so GQA
@@ -223,14 +270,26 @@ class LlamaBlock(nn.Module):
             x = x + att
         y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
         with jax.named_scope("mlp"):
-            gate = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
-                            kernel_init=init, name="w_gate")(y)
-            up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
-                          kernel_init=init, name="w_up")(y)
-            z = nn.silu(gate) * up
-            z = _constrain(z, ("batch", "seq", "mlp"), cfg)
-            down = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                            kernel_init=init, name="w_down")(z)
+            if cfg.n_experts:
+                from ..ops.moe import MoEMLP
+
+                down = MoEMLP(
+                    d_model=cfg.d_model, d_ff=cfg.d_ff,
+                    num_experts=cfg.n_experts,
+                    top_k=cfg.experts_per_token, gated=True,
+                    norm_topk_prob=cfg.norm_topk_prob, act=nn.silu,
+                    dtype=cfg.dtype, name="moe")(
+                        y, None if positions is None else positions >= 0)
+            else:
+                gate = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
+                                kernel_init=init, name="w_gate")(y)
+                up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
+                              kernel_init=init, name="w_up")(y)
+                z = nn.silu(gate) * up
+                z = _constrain(z, ("batch", "seq", "mlp"), cfg)
+                down = nn.Dense(cfg.d_model, use_bias=False,
+                                dtype=cfg.dtype, kernel_init=init,
+                                name="w_down")(z)
             out = x + down
         return out if new_cache is None else (out, new_cache)
 
@@ -282,23 +341,70 @@ class Llama(nn.Module):
         return logits
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _init_leaf(key, shape, ones: bool, dtype):
+    x = jnp.ones(shape, jnp.float32) if ones \
+        else 0.02 * jax.random.normal(key, shape, jnp.float32)
+    return x.astype(dtype)
+
+
 def llama_init(cfg: LlamaConfig, rng):
+    """The weights from the seed, leaf by leaf: each leaf is drawn in
+    float32 from a key folded from its path (normal, std 0.02; a norm's
+    scale is 1) and cast to ``cfg.param_dtype`` under ``jit``, so no
+    float32 copy of the whole tree ever exists, on any backend, and the
+    model's forward is never run to make weights."""
     import dataclasses
+    import zlib
 
     init_cfg = dataclasses.replace(cfg, mesh=None, attn_impl="dense")
-    tokens = jnp.zeros((1, min(cfg.max_seq, 8)), jnp.int32)
-    return Llama(init_cfg).init(rng, tokens)
+    shapes = jax.eval_shape(Llama(init_cfg).init, rng,
+                            jnp.zeros((1, min(cfg.max_seq, 8)), jnp.int32))
+
+    def make(path, spec):
+        name = "/".join(str(getattr(p, "key", p)) for p in path)
+        key = jax.random.fold_in(rng, zlib.crc32(name.encode()))
+        return _init_leaf(key, spec.shape, name.endswith("scale"),
+                          jnp.dtype(cfg.param_dtype))
+
+    return jax.tree_util.tree_map_with_path(make, shapes)
 
 
-def llama_loss_fn(cfg: LlamaConfig, params, batch):
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    logits = Llama(cfg).apply(params, inputs)
+def _next_token_xent(logits, targets):
     with jax.named_scope("loss"):
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         ll = jnp.take_along_axis(logp, targets[..., None],
                                  axis=-1)[..., 0]
         return -jnp.mean(ll)
+
+
+def llama_loss_fn(cfg: LlamaConfig, params, batch):
+    tokens = batch["tokens"]
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    return _next_token_xent(Llama(cfg).apply(params, inputs), targets)
+
+
+def olmoe_loss_fn(cfg: LlamaConfig, params, batch,
+                  with_metrics: bool = False):
+    """Mean next-token cross entropy + ``moe_aux_weight`` x the
+    load-balancing loss + ``moe_z_weight`` x the router z-loss, both
+    summed over the layers (ops/moe.py ``moe_losses``).  With
+    ``with_metrics`` returns (loss, {"ce", "moe_load_balancing",
+    "moe_router_z", "moe_max_load_over_mean"}) for a step built with
+    ``has_aux``."""
+    from ..ops.moe import moe_losses
+
+    tokens = batch["tokens"]
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    logits, state = Llama(cfg).apply(params, inputs,
+                                     mutable=["intermediates"])
+    ce = _next_token_xent(logits, targets)
+    moe = moe_losses(state["intermediates"])
+    loss = ce + cfg.moe_aux_weight * moe["load_balancing"] \
+        + cfg.moe_z_weight * moe["router_z"]
+    if not with_metrics:
+        return loss
+    return loss, {"ce": ce, **{f"moe_{k}": v for k, v in moe.items()}}
 
 
 def llama_partition_rules():
@@ -317,7 +423,25 @@ def llama_partition_rules():
     )
 
 
+def olmoe_partition_rules():
+    """Llama's rules and the experts': every expert on every chip, its
+    matrices sharded over fsdp x tensor on their ``d`` and ``f``
+    dimensions (an ``expert`` mesh axis is ROADMAP Design 2's)."""
+    from jax.sharding import PartitionSpec as PS
+
+    return (
+        (r"moe/(w_gate|w_up)$", PS(None, "fsdp", "tensor")),
+        (r"moe/w_down$", PS(None, "tensor", "fsdp")),
+        (r"moe/router$", PS("fsdp", None)),
+    ) + llama_partition_rules()
+
+
 def llama_param_axes(path: str, leaf) -> Tuple[Optional[str], ...]:
+    from ..ops.moe import moe_param_axes
+
+    moe = moe_param_axes(path, leaf)
+    if moe is not None:
+        return moe
     if "embed" in path and leaf.ndim == 2:
         return ("vocab", "embed_fsdp")
     if "lm_head" in path:

@@ -1,108 +1,198 @@
-"""Mixture-of-Experts feed-forward with expert parallelism.
+"""Mixture-of-Experts feed-forward: dropless token-choice routing as
+sorted, grouped matmuls.
 
-Fills SURVEY §2.3's EP row (absent from the reference, which delegates
-MoE to user frameworks).  TPU-first formulation (GShard/Switch style,
-public papers): routing is expressed as DENSE one-hot dispatch/combine
-einsums over an [experts, capacity] buffer — no ragged all-to-all
-primitive exists in XLA, and the dense-einsum form is exactly what GSPMD
-partitions well: with expert weights sharded over the ``expert`` mesh
-axis and tokens over ``data``, XLA lowers the dispatch/combine einsums
-to all-to-alls over ICI automatically.
+ONE path, for every model that has experts (OLMoE through
+``models/llama.py``; GPT-2's synthetic ``moe_num_experts`` option as its
+ungated case):
 
-Components:
-- top-k router with fp32 gating, probability renormalization over the
-  chosen experts, and the Switch load-balancing auxiliary loss
-  (fraction-of-tokens x mean-gate per expert, scaled by E);
-- capacity enforcement (capacity_factor x tokens/experts): tokens over
-  an expert's capacity are dropped (their combine weight is zero, so
-  the residual stream passes them through unchanged);
-- batched expert FFNs as single [E, ...] einsums (one MXU-friendly
-  matmul per projection, not a Python loop over experts).
+- ``moe.route``: router logits, softmax over ALL experts and top-k in
+  float32 (a bf16 near-tie must not flip an expert); the weights are
+  the softmax's own values, renormalised over the chosen k only where
+  the model says so (``norm_topk_prob``);
+- ``moe.dispatch``: the ``S x k`` (token, expert) pairs are flattened
+  and sorted by expert with a STABLE sort, so a pair's place depends on
+  nothing but the pairs before it; the rows are gathered in that order;
+- ``moe.experts``: the expert matmuls run as grouped matmuls over the
+  sorted rows with the group sizes from the routing
+  (``jax.lax.ragged_dot``: on the TPU the compiler lowers it to its own
+  Mosaic grouped-matmul kernel, which reads the matrices of the experts
+  that have rows and no others; PERF.md has the measurement);
+- ``moe.combine``: un-sort, weight, sum over k.
+
+No capacity, no one-hot dispatch, no dropped token: every pair is
+computed, and the executed expert FLOPs are ``S x k`` rows' worth.  Rows
+marked invalid (the decode batch's padding: negative positions) are sent
+to no expert and count nowhere.  Differentiable end to end (the trainer
+runs the same op).
+
+Each layer sows ``moe`` into flax's ``intermediates``: ``load`` [E] (pairs
+per expert), ``prob_mean`` [E] and ``z`` (mean squared log-sum-exp of the
+router logits), from which come the training losses (``moe_losses``) and
+the engine's counters (``moe_counters``).
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 
+def route(logits, k: int, norm_topk_prob: bool):
+    """Router logits [S, E] (float32) -> (weights [S, k], experts [S, k],
+    probs [S, E]): softmax over all experts, the k largest with ties to
+    the lower index."""
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts, probs
+
+
+def grouped_ffn(rows, group_sizes, w_gate, w_in, w_out, act: Callable):
+    """The experts over rows sorted by expert: ``act(rows @ w_gate[e]) *
+    (rows @ w_in[e])`` (ungated where ``w_gate`` is None), then
+    ``@ w_out[e]``, each a grouped matmul."""
+    h = jax.lax.ragged_dot(rows, w_in, group_sizes)
+    if w_gate is not None:
+        h = act(jax.lax.ragged_dot(rows, w_gate, group_sizes)) * h
+    else:
+        h = act(h)
+    return jax.lax.ragged_dot(h, w_out, group_sizes)
+
+
 class MoEMLP(nn.Module):
-    """Drop-in replacement for a transformer MLP block."""
+    """Drop-in replacement for a transformer MLP block.  ``gated``:
+    three matrices per expert (``w_gate``, ``w_up``, ``w_down``: SwiGLU
+    with ``act`` = silu), else two (``w_in``, ``w_out``)."""
 
     d_model: int
     d_ff: int
     num_experts: int
     top_k: int = 2
-    capacity_factor: float = 1.25
+    gated: bool = False
+    norm_topk_prob: bool = True
+    act: Callable = nn.gelu
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray,
+                 valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+        """x [B, T, d]; ``valid`` [B, T] bool marks the real rows (None:
+        all)."""
         b, t, d = x.shape
-        e = self.num_experts
-        s = b * t
-        capacity = max(int(self.capacity_factor * s / e), self.top_k)
+        e, k, s = self.num_experts, self.top_k, b * t
+        init = nn.initializers.normal(0.02)
+        router = self.param("router", init, (d, e), jnp.float32)
+        names = ("w_up", "w_down") if self.gated else ("w_in", "w_out")
+        w_gate = self.param("w_gate", init, (e, d, self.d_ff),
+                            jnp.float32) if self.gated else None
+        w_in = self.param(names[0], init, (e, d, self.d_ff), jnp.float32)
+        w_out = self.param(names[1], init, (e, self.d_ff, d), jnp.float32)
         xf = x.reshape(s, d)
+        real = jnp.ones((s,), bool) if valid is None else valid.reshape(s)
 
-        # ---- router (fp32: gating decisions must not flip in bf16)
-        router = self.param("router",
-                            nn.initializers.normal(0.02 / d ** 0.5),
-                            (d, e), jnp.float32)
-        logits = jnp.asarray(xf, jnp.float32) @ router          # [S, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, gate_idx = jax.lax.top_k(probs, self.top_k)  # [S, K]
-        # Renormalize over the selected experts.
-        gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-9)
+        with jax.named_scope("moe.route"):
+            logits = xf.astype(jnp.float32) @ router.astype(jnp.float32)
+            weights, experts, probs = route(logits, k, self.norm_topk_prob)
+            # An invalid row's pairs go to "expert E": behind every
+            # group, in none of them.
+            experts = jnp.where(real[:, None], experts, e)
+            load = jnp.zeros((e + 1,), jnp.int32).at[
+                experts.reshape(-1)].add(1)[:e]
+            n_real = jnp.maximum(jnp.sum(real), 1).astype(jnp.float32)
+            keep = real[:, None].astype(jnp.float32)
+            self.sow("intermediates", "moe", {
+                "load": load,
+                "prob_mean": jnp.sum(probs * keep, axis=0) / n_real,
+                "z": jnp.sum(jnp.square(jax.nn.logsumexp(logits, axis=-1))
+                             * keep[:, 0]) / n_real})
 
-        # ---- Switch aux loss: E * sum_e f_e * P_e  (ref: the public
-        # Switch Transformer formulation) — sown for the trainer to add.
-        assign1 = jax.nn.one_hot(gate_idx[:, 0], e)             # top-1
-        f = assign1.mean(0)
-        p = probs.mean(0)
-        self.sow("intermediates", "moe_aux", e * jnp.sum(f * p))
+        with jax.named_scope("moe.dispatch"):
+            order = jnp.argsort(experts.reshape(-1), stable=True)
+            rows = xf.astype(self.dtype)[order // k]            # [S*k, d]
 
-        # ---- capacity: position of each (token, k) within its expert.
-        onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)   # [S,K,E]
-        flatk = onehot.reshape(s * self.top_k, e)  # k-major per token
-        pos = jnp.cumsum(flatk, axis=0) - flatk                 # [SK, E]
-        pos = (pos * flatk).sum(-1).reshape(s, self.top_k)      # [S, K]
-        keep = pos < capacity
-        gate_vals = gate_vals * keep
+        with jax.named_scope("moe.experts"):
+            out = grouped_ffn(
+                rows, load,
+                None if w_gate is None else w_gate.astype(self.dtype),
+                w_in.astype(self.dtype), w_out.astype(self.dtype),
+                self.act)
 
-        # ---- dispatch/combine one-hots: [S, K, E, C]
-        pos_oh = jax.nn.one_hot(jnp.where(keep, pos, capacity),
-                                capacity, dtype=self.dtype)
-        disp = (jnp.asarray(onehot, self.dtype)[..., None]
-                * pos_oh[:, :, None, :])                        # [S,K,E,C]
-        dispatch = disp.sum(1)                                  # [S, E, C]
-        combine = (disp * jnp.asarray(gate_vals, self.dtype)
-                   [:, :, None, None]).sum(1)                   # [S, E, C]
+        with jax.named_scope("moe.combine"):
+            # Rows behind the last group are whatever the kernel left
+            # there: zeroed, not weighted.
+            in_group = jnp.arange(s * k) < jnp.sum(load)
+            out = jnp.where(in_group[:, None], out, 0)
+            back = jnp.zeros((s * k,), jnp.int32).at[order].set(
+                jnp.arange(s * k, dtype=jnp.int32))
+            y = jnp.einsum("skd,sk->sd", out[back].reshape(s, k, d),
+                           weights.astype(self.dtype),
+                           preferred_element_type=jnp.float32)
+        return y.astype(self.dtype).reshape(b, t, d)
 
-        # ---- expert FFNs, batched over E.
-        w_in = self.param("w_in", nn.initializers.normal(0.02),
-                          (e, d, self.d_ff), jnp.float32)
-        w_out = self.param("w_out", nn.initializers.normal(0.02),
-                           (e, self.d_ff, d), jnp.float32)
-        expert_in = jnp.einsum("sec,sd->ecd", dispatch,
-                               jnp.asarray(xf, self.dtype))     # [E,C,D]
-        h = jnp.einsum("ecd,edf->ecf", expert_in,
-                       jnp.asarray(w_in, self.dtype))
-        h = nn.gelu(h)
-        out = jnp.einsum("ecf,efd->ecd", h,
-                         jnp.asarray(w_out, self.dtype))        # [E,C,D]
-        y = jnp.einsum("sec,ecd->sd", combine, out)             # [S, D]
-        return y.reshape(b, t, d)
+
+def moe_layers(intermediates) -> List[Dict[str, jnp.ndarray]]:
+    """What each MoE layer sowed, in the tree's (layer) order."""
+    found: List[Dict[str, jnp.ndarray]] = []
+
+    def walk(node) -> None:
+        if isinstance(node, dict) or hasattr(node, "items"):
+            for key, child in node.items():
+                if key == "moe" and isinstance(child, tuple):
+                    found.extend(child)
+                else:
+                    walk(child)
+    walk(intermediates)
+    return found
+
+
+def moe_losses(intermediates) -> Dict[str, jnp.ndarray]:
+    """Summed over the layers, as the HF implementation sums them:
+    ``load_balancing`` = E x sum_e f_e P_e (f_e the share of the S x k
+    assignments that went to expert e, P_e its mean router probability),
+    ``router_z`` = mean squared log-sum-exp of the router logits; and
+    ``max_load_over_mean``, the fullest expert's rows over the mean, the
+    worst layer's."""
+    lb = z = jnp.float32(0.0)
+    worst = jnp.float32(0.0)
+    for layer in moe_layers(intermediates):
+        load = layer["load"].astype(jnp.float32)
+        e = load.shape[0]
+        pairs = jnp.maximum(jnp.sum(load), 1.0)
+        lb = lb + e * jnp.sum(load / pairs * layer["prob_mean"])
+        z = z + layer["z"]
+        worst = jnp.maximum(worst, jnp.max(load) * e / pairs)
+    return {"load_balancing": lb, "router_z": z,
+            "max_load_over_mean": worst}
+
+
+MOE_COUNTERS = ("pairs", "experts_hit", "max_load")
+
+
+def moe_counters(intermediates) -> Optional[jnp.ndarray]:
+    """[layers, 3] int32, per layer ``MOE_COUNTERS``: the (real row,
+    expert) pairs, the experts with at least one row, the largest group:
+    what the engine fetches beside the logits.  None for a model without
+    experts."""
+    layers = moe_layers(intermediates)
+    if not layers:
+        return None
+    return jnp.stack([
+        jnp.stack([jnp.sum(m["load"]), jnp.sum(m["load"] > 0),
+                   jnp.max(m["load"])]) for m in layers]).astype(jnp.int32)
 
 
 def moe_param_axes(path: str, leaf) -> Optional[Tuple]:
     """Logical axes for MoE params (None = not a MoE param)."""
+    if "moe" not in path:
+        return None
     if "router" in path:
         return ("embed_fsdp", None)
-    if "w_in" in path:
+    if any(n in path for n in ("w_in", "w_gate", "w_up")):
         return ("expert", "embed_fsdp", "mlp")
-    if "w_out" in path:
+    if any(n in path for n in ("w_out", "w_down")):
         return ("expert", "mlp", "embed_fsdp")
     return None

@@ -46,22 +46,26 @@ def make_optimizer(learning_rate: float = 3e-4,
     )
 
 
-def make_train_step(loss_fn: Callable, optimizer
+def make_train_step(loss_fn: Callable, optimizer, has_aux: bool = False
                     ) -> Callable[[TrainState, Dict], Tuple[TrainState, Dict]]:
-    """loss_fn(params, batch) -> scalar.  Returns step(state, batch)."""
+    """loss_fn(params, batch) -> scalar, or with ``has_aux`` (scalar,
+    {name: scalar}), whose entries join the step's metrics (a model's
+    own losses: OLMoE's router losses).  Returns step(state, batch)."""
 
     def step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
         # The scopes name the step's parts in a device trace and in an
         # HLO dump; they change no operation.
         with jax.named_scope("loss_and_grad"):
-            loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
+            loss, grads = jax.value_and_grad(loss_fn, has_aux=has_aux)(
+                state.params, batch)
+        loss, aux = loss if has_aux else (loss, {})
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(
                 grads, state.opt_state, state.params)
             params = optax.apply_updates(state.params, updates)
         with jax.named_scope("grad_norm"):
             grad_norm = optax.global_norm(grads)
-        metrics = {"loss": loss, "grad_norm": grad_norm}
+        metrics = {**aux, "loss": loss, "grad_norm": grad_norm}
         return TrainState(step=state.step + 1, params=params,
                           opt_state=opt_state), metrics
 
@@ -89,7 +93,7 @@ def shard_state(state: TrainState, mesh, param_axes_fn, rules=None
 def make_sharded_train_step(loss_fn, optimizer, mesh=None,
                             donate: bool = True, telemetry: bool = True,
                             state_shardings=None,
-                            batch_sharding=None):
+                            batch_sharding=None, has_aux: bool = False):
     """Jit the step; with a mesh, shardings propagate from the state
     placement (GSPMD), so no explicit in_shardings are needed.
 
@@ -109,7 +113,7 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
     timing under async dispatch is an approximation — the per-step
     truth is the report-cadence ``rt_train_step_time_seconds``.
     """
-    step = make_train_step(loss_fn, optimizer)
+    step = make_train_step(loss_fn, optimizer, has_aux)
     jit_kwargs: Dict[str, Any] = {}
     if state_shardings is not None:
         from jax.sharding import NamedSharding, PartitionSpec

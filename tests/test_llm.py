@@ -242,7 +242,7 @@ def test_llama_incremental_decode_token_identical():
 # --------------------------------------------- the pool stays in place
 
 
-@pytest.mark.parametrize("family", ["gpt2", "llama_gqa"])
+@pytest.mark.parametrize("family", ["gpt2", "llama_gqa", "olmoe"])
 def test_forward_updates_donated_pool_in_place(family):
     """The engine's jitted forward aliases BOTH pool arrays to its
     outputs and holds no second pool among its temporaries: the model
@@ -268,15 +268,21 @@ def test_forward_updates_donated_pool_in_place(family):
     else:
         from ray_tpu.models.llama import Llama, LlamaConfig, llama_init
 
-        cfg = dataclasses.replace(LlamaConfig.tiny(), n_layer=4,
-                                  remat=False, dtype=jnp.float32)
-        assert cfg.n_kv_head < cfg.n_head
+        tiny = LlamaConfig.tiny if family == "llama_gqa" \
+            else LlamaConfig.olmoe_tiny     # experts, and a 4th output
+        cfg = dataclasses.replace(tiny(), n_layer=4, remat=False,
+                                  dtype=jnp.float32)
+        assert (cfg.n_kv_head < cfg.n_head) == (family == "llama_gqa")
         model, init, n_kv_head = Llama(cfg), llama_init, cfg.n_kv_head
     page_size = 16
+    # The CPU lowers a grouped matmul to a dense one over all experts:
+    # ~0.9 MB of temporaries that do not grow with the pool, so the
+    # OLMoE case takes a pool large enough to tell the two apart.
+    num_pages = 256 if family == "olmoe" else 64
     params = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
     kv = jax.eval_shape(lambda: init_cache(
-        cfg.n_layer, 64, page_size, n_kv_head, cfg.d_model // cfg.n_head,
-        cfg.dtype))
+        cfg.n_layer, num_pages, page_size, n_kv_head,
+        cfg.d_model // cfg.n_head, cfg.dtype))
     pool_bytes = kv["k_pages"].size * kv["k_pages"].dtype.itemsize
     fwd = jit_forward(model)
     for shape in ((2, 1), (1, 32)):          # decode, prefill

@@ -191,6 +191,50 @@ def test_engine_forward_updates_pool_in_place(engine_program, widths,
         < 0.5 * pool.size * pool.dtype.itemsize
 
 
+def test_olmoe_decode_cell_compiles_with_grouped_matmuls_in_place(
+        one_chip):
+    """The decode program of serve-olmoe-1b-7b-sat (OLMoE-1B-7B's widths,
+    8 layers, bf16 weights, [16, 1] over 1024 pages, max_context 1024):
+    it fits the chip; every expert matmul is the compiler's own grouped
+    kernel (per layer one metadata call and three matmuls: a dense
+    fallback over all 64 experts would show none); and the pool is
+    updated where it lies, as for the dense models."""
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_cache, pages_for
+    from ray_tpu.models.llama import Llama, LlamaConfig, llama_init
+
+    cfg = LlamaConfig.olmoe_1b_7b(n_layer=8, attn_impl="dense",
+                                  remat=False)
+    params = jax.eval_shape(lambda: llama_init(cfg, jax.random.PRNGKey(0)))
+    assert {x.dtype for x in jax.tree_util.tree_leaves(params)} == {
+        jnp.dtype(jnp.bfloat16)}
+    kv = jax.eval_shape(lambda: init_cache(
+        cfg.n_layer, 1024, 16, cfg.n_kv_head, cfg.d_model // cfg.n_head,
+        cfg.dtype))
+    ints = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                             sharding=one_chip)
+    compiled = jit_forward(Llama(cfg)).lower(
+        _on(params, one_chip), ints((16, 1)), _on(kv["k_pages"], one_chip),
+        _on(kv["v_pages"], one_chip), ints((16, pages_for(1024, 16))),
+        ints((16, 1))).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+    text = compiled.as_text()
+    assert text.count('op_name="ragged-dot-metadata"') == cfg.n_layer
+    assert text.count("ragged_dot_tiling") == 3 * cfg.n_layer
+    # By shape, not by size: the rows gathered for attention (16 x 1024
+    # positions) have as many elements as one layer of this pool.
+    pool = kv["k_pages"]
+    shapes = (",".join(map(str, pool.shape)),
+              ",".join(map(str, pool.shape[1:])))
+    passes = [line.strip()[:120] for line in text.splitlines()
+              if (m := _POOL_PASS.match(line)) and m.group(1) in shapes]
+    assert not passes, (len(passes), passes[:4])
+    # 0.28 GB: one layer's gathered K and V in float32, a quarter of an
+    # 8-layer pool array each; a second pool would be 1.07 GB.
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.6 * pool.size * pool.dtype.itemsize
+
+
 def _train_step_and_shapes(cfg, loss_chunk):
     from ray_tpu.models.gpt2 import gpt2_init, gpt2_loss_fn
     from ray_tpu.train.train_step import TrainState, make_optimizer

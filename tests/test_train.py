@@ -79,6 +79,48 @@ def test_jax_trainer_single_worker(tmp_path):
     assert losses[-1] < losses[0]
 
 
+def _olmoe_loop(config):
+    """Tiny OLMoE (models/llama.py with experts): the step built with
+    ``has_aux`` puts the router losses among its metrics."""
+    import jax
+
+    from ray_tpu import train
+    from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.train.train_step import (TrainState, make_optimizer,
+                                          make_sharded_train_step)
+
+    family = MODEL_FAMILIES["olmoe"]
+    cfg = family.tiny()
+    opt = make_optimizer(learning_rate=1e-3, warmup_steps=1, total_steps=20)
+    state = TrainState.create(family.init(cfg, jax.random.PRNGKey(0)), opt)
+    step_fn = make_sharded_train_step(
+        lambda p, b: family.loss(cfg, p, b, with_metrics=True), opt,
+        has_aux=True)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0,
+                                cfg.vocab_size)
+    for i in range(config["steps"]):
+        state, metrics = step_fn(state, {"tokens": tokens})
+        train.report({k: float(v) for k, v in metrics.items()}
+                     | {"step": i + 1})
+    return float(metrics["loss"])
+
+
+def test_jax_trainer_runs_olmoe_and_reports_router_losses(tmp_path):
+    result = JaxTrainer(
+        _olmoe_loop, train_loop_config={"steps": 4},
+        scaling_config=ScalingConfig(num_workers=1),
+        run_config=RunConfig(name="olmoe", storage_path=str(tmp_path))
+    ).fit()
+    assert result.error is None and result.metrics["step"] == 4
+    first, last = (h["metrics"] for h in (result.metrics_history[0],
+                                          result.metrics_history[-1]))
+    assert last["loss"] < first["loss"]
+    # 2 layers: the balanced minimum of the load-balancing loss is 2
+    assert first["moe_load_balancing"] >= 2.0 and first["moe_router_z"] > 0
+    assert 1.0 <= first["moe_max_load_over_mean"] <= 8.0
+    assert first["loss"] > first["ce"]
+
+
 def test_jax_trainer_resume(tmp_path):
     run = RunConfig(name="t2", storage_path=str(tmp_path))
     r1 = JaxTrainer(_gpt2_loop, train_loop_config={"steps": 3},
