@@ -6,9 +6,9 @@ scheduling) over a vLLM-style paged KV cache, served through
 PR-8 resilience semantics.  See README "LLM serving" and
 ``bench.py --serve-llm``.
 
-The package imports jax lazily through its submodules' call paths
-where possible — ``sampling`` is numpy-only so pure sampling users
-never pay a jax import.
+Tokens are chosen on the device (``sampling.sample_tokens``, a jitted
+program the engine runs after every forward); ``sample`` and the other
+numpy functions of ``sampling`` are its plain reference.
 """
 
 from __future__ import annotations

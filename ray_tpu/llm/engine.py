@@ -20,11 +20,15 @@ re-prefills (prompt + everything it already generated) when pages free
 up, so already-streamed tokens are never re-emitted and greedy output
 is unchanged.
 
-Sampling happens host-side from the last valid position's logits
-(sampling.py, numpy), so per-request temperature/top-k/top-p never
-enter the jitted step.  Tokens stream out through per-sequence queues;
-the serve deployment (serving.py) turns them into streaming-generator
-frames.
+Tokens are chosen ON THE DEVICE: after each forward one jitted sampler
+(sampling.py ``jit_sampler``) takes the device-resident logits of the
+last positions and, as data, every row's temperature / top-k / top-p,
+seed words and token index, and the host fetches ``[max_batch]`` int32
+ids, never a ``[max_batch, V]`` array.  One compiled sampler serves
+every mix of requests; a request's tokens depend on its seed and the
+token's index alone (not on its slot, its neighbours, or a recompute
+preemption).  Tokens stream out through per-sequence queues; the serve
+deployment (serving.py) turns them into streaming-generator frames.
 
 Where a step's time goes is measured inside it, on two clocks that
 agree: every phase of ``step()`` is a profiler annotation
@@ -51,7 +55,8 @@ import numpy as np
 from ..util import chips
 from ..util.spans import annotate
 from .kv_cache import PagePool, init_cache, pages_for
-from .sampling import SamplingParams, sample
+from .sampling import (SamplingParams, jit_sampler, pack_rows,
+                       seed_words)
 
 
 @dataclass(frozen=True)
@@ -74,6 +79,8 @@ class EngineConfig:
 # The leaf phases of one step(): each is an annotation of this name and a
 # cumulative-seconds entry of stats()["phase_s"].  ``llm.admit`` is the
 # admission's own time (lock, page allocation), its prefills apart.
+# ``.run`` launches the forward and the sampler, ``.fetch`` waits for the
+# token ids, ``.sample`` is the host's bookkeeping per token.
 PHASE_LEAVES = (
     "llm.cancel", "llm.admit",
     "llm.prefill.pack", "llm.prefill.run", "llm.prefill.fetch",
@@ -116,7 +123,7 @@ class _Sequence:
     """One in-flight generation request (engine-internal)."""
 
     __slots__ = ("sid", "tokens", "prompt_len", "max_tokens", "params",
-                 "rng", "out", "pages", "n_cached", "generated",
+                 "seed", "out", "pages", "n_cached", "generated",
                  "finished", "cancelled", "submitted_ts",
                  "request_id", "first_token_ts", "last_token_ts",
                  "warmup")
@@ -130,7 +137,7 @@ class _Sequence:
         self.prompt_len = len(prompt)
         self.max_tokens = max_tokens
         self.params = params
-        self.rng = np.random.default_rng(seed)
+        self.seed = seed_words(seed)    # the sampler's key, two uint32
         self.out: "queue.Queue" = queue.Queue()
         self.pages: List[int] = []
         self.n_cached = 0               # tokens written into KV pages
@@ -223,10 +230,11 @@ class GenerationEngine:
                               model_cfg.dtype)
 
         self._fwd = jit_forward(self._model)
-        # Per-shape AOT executables (lower().compile()): the compile
-        # is timed and the program registered with the xprof plane
-        # (rt perf).
-        self._fwd_cache: Dict[Any, Any] = {}
+        self._sampler, self._last_rows = jit_sampler(self.cfg.max_batch)
+        # AOT executables by program name (lower().compile()): the
+        # compile is timed and the program registered with the xprof
+        # plane (rt perf).
+        self._exe_cache: Dict[str, Any] = {}
         self._compile_seconds: Dict[str, float] = {}
         # The largest program compiled here, by the compiler's own total
         # (arguments + outputs - aliased + temporaries): taken once per
@@ -254,6 +262,12 @@ class GenerationEngine:
         # Routing counters of a model with experts, cumulative over
         # DECODE runs (stats()["moe"]); stays empty for a dense model.
         self._moe: Dict[str, int] = {}
+        # What the sampler was handed, counted from the packed rows:
+        # ``steps`` launches (one a decode step, one a prefill), of which
+        # ``steps_sampled`` held a row with temperature > 0 and took the
+        # search; the others were argmax alone (stats()["sampling"]).
+        self._sampling = {"rows_greedy": 0, "rows_sampled": 0,
+                          "steps": 0, "steps_sampled": 0}
         # The engine thread sums a step's leaves in _pending and adds
         # them to the totals with the step's own time in one go, under
         # the lock, so that a stats() taken mid-step still sums up.
@@ -446,8 +460,10 @@ class GenerationEngine:
                 "max_context": self.max_context,
                 "step_errors": self._step_errors,
                 "last_error": self._last_error,
-                # Compiled forwards by name -> compile seconds.
+                # Compiled programs (forwards, the sampler, the row
+                # pickers) by name -> compile seconds.
                 "programs": dict(self._compile_seconds),
+                "sampling": dict(self._sampling),
                 "device": dict(self._device),
                 # TTFT phase + TPOT accounting (bench decomposition).
                 "ttft_requests": self._ttft_requests,
@@ -590,26 +606,31 @@ class GenerationEngine:
         return row
 
     def _call_fwd(self, kind: str, *args):
-        """Dispatch the forward through a per-shape AOT executable.
+        """The forward of this token shape: ``llm_decode``, or
+        ``llm_prefill[bucket]``."""
+        name = f"llm_{kind}[{args[1].shape[1]}]" \
+            if kind == "prefill" else f"llm_{kind}"
+        return self._call(self._fwd, name, *args)
 
-        First sight of a (kind, token-shape) pair pays the one compile
-        jit would pay anyway, but via ``lower().compile()`` so the
-        compile is timed (``stats()["programs"]``), counted
-        (``rt_xla_compiles_total``) and the program's cost/memory facts
-        registered with the xprof plane.  There is one way to compile
-        and run: a compile error or a run error surfaces as itself (the
-        KV pages are donated, so there is nothing to retry with)."""
-        key = (kind, args[1].shape)
-        cached = self._fwd_cache.get(key)
-        # A cache entry is only valid for the _fwd it was compiled
+    def _call(self, fn, name: str, *args):
+        """Dispatch a jitted function through the AOT executable of this
+        name (one name per shape: the engine's shapes are fixed).
+
+        First sight of a name pays the one compile jit would pay anyway,
+        but via ``lower().compile()`` so the compile is timed
+        (``stats()["programs"]``), counted (``rt_xla_compiles_total``)
+        and the program's cost/memory facts registered with the xprof
+        plane.  There is one way to compile and run: a compile error or
+        a run error surfaces as itself (the KV pages are donated, so
+        there is nothing to retry with)."""
+        cached = self._exe_cache.get(name)
+        # A cache entry is only valid for the function it was compiled
         # from — if _fwd was swapped (fault injection, hot reload) the
         # stale executable must not keep serving.
-        if cached is None or cached[0] is not self._fwd:
-            name = f"llm_{kind}[{args[1].shape[1]}]" \
-                if kind == "prefill" else f"llm_{kind}"
+        if cached is None or cached[0] is not fn:
             t0 = time.perf_counter()
             with annotate("llm.compile", program=name):
-                exe = self._fwd.lower(*args).compile()
+                exe = fn.lower(*args).compile()
             dt = time.perf_counter() - t0
             self._compile_seconds[name] = dt
             self._compiles += 1
@@ -621,8 +642,22 @@ class GenerationEngine:
                 xprof.register_compiled(name, exe, compile_seconds=dt)
             except Exception:
                 pass    # registering with xprof is best-effort
-            cached = self._fwd_cache[key] = (self._fwd, exe)
+            cached = self._exe_cache[name] = (fn, exe)
         return cached[1](*args)
+
+    def _pack_sampling(self, batch: List[_Sequence]):
+        """The sampler's per-row arguments for ``batch`` (row i is
+        ``batch[i]``), counted into stats()["sampling"]."""
+        knobs, words = pack_rows(
+            ((seq.params, seq.seed, seq.generated) for seq in batch),
+            self.cfg.max_batch)
+        sampled = int(np.count_nonzero(knobs[:, 0]))
+        counts = self._sampling
+        counts["rows_sampled"] += sampled
+        counts["rows_greedy"] += len(batch) - sampled
+        counts["steps"] += 1
+        counts["steps_sampled"] += sampled > 0
+        return knobs, words
 
     def _prefill(self, seq: _Sequence) -> None:
         t0 = time.perf_counter()
@@ -658,20 +693,26 @@ class GenerationEngine:
             positions = np.full((1, pad), -1, np.int32)
             positions[0, :n] = np.arange(n)
             table = self._page_table_row(seq)[None, :]
+            sampling = self._pack_sampling([seq])
         with self._phase("llm.prefill.run"):
             logits, k, v, *_ = self._call_fwd(
                 "prefill", self._params, tokens, self._kv["k_pages"],
                 self._kv["v_pages"], table, positions)
-        self._kv["k_pages"], self._kv["v_pages"] = k, v
+            self._kv["k_pages"], self._kv["v_pages"] = k, v
+            ids = self._call(
+                self._sampler, "llm_sample",
+                self._call(self._last_rows, f"llm_last[{pad}]", logits,
+                           np.int32(n - 1)),
+                *sampling)
         seq.n_cached = n
         self._prefill_tokens_total += n
         self._count("prefill", n)
         with self._lock:
             self._running.append(seq)
         with self._phase("llm.prefill.fetch"):
-            last = np.asarray(logits[0, n - 1])
+            token = int(np.asarray(ids)[0])
         with self._phase("llm.prefill.sample"):
-            self._emit_token(seq, last)
+            self._emit_token(seq, token)
         if first_admission:
             t_first = time.time()
             self._prefill_s_total += t_first - t_admit
@@ -698,13 +739,16 @@ class GenerationEngine:
                 tokens[i, 0] = seq.tokens[-1]
                 positions[i, 0] = seq.n_cached
                 table[i] = self._page_table_row(seq)
+            sampling = self._pack_sampling(batch)
         with self._phase("llm.decode.run"):
             logits, k, v, *moe = self._call_fwd(
                 "decode", self._params, tokens, self._kv["k_pages"],
                 self._kv["v_pages"], table, positions)
-        self._kv["k_pages"], self._kv["v_pages"] = k, v
+            self._kv["k_pages"], self._kv["v_pages"] = k, v
+            ids = self._call(self._sampler, "llm_sample", logits,
+                             *sampling)
         with self._phase("llm.decode.fetch"):
-            logits_np = np.asarray(logits[:, 0])
+            ids = np.asarray(ids).tolist()      # [max_batch] int32
             if moe:
                 from ..ops.moe import MOE_COUNTERS
 
@@ -715,9 +759,9 @@ class GenerationEngine:
                     for key, add in adds.items():
                         self._moe[key] = self._moe.get(key, 0) + int(add)
         with self._phase("llm.decode.sample"):
-            for i, seq in enumerate(batch):
+            for seq, token in zip(batch, ids):
                 seq.n_cached += 1
-                self._emit_token(seq, logits_np[i])
+                self._emit_token(seq, token)
 
     def _ensure_page(self, seq: _Sequence) -> bool:
         """Guarantee a KV slot for position ``seq.n_cached``; on pool
@@ -760,8 +804,8 @@ class GenerationEngine:
         self._evictions += 1
         self._count("evictions")
 
-    def _emit_token(self, seq: _Sequence, logits_row: np.ndarray) -> None:
-        tok = sample(logits_row, seq.params, seq.rng)
+    def _emit_token(self, seq: _Sequence, tok: int) -> None:
+        """The host's bookkeeping for one token the device chose."""
         seq.tokens.append(tok)
         seq.generated += 1
         self._tokens_total += 1

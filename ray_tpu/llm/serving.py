@@ -37,6 +37,9 @@ class LLMDeployment:
     Request payload (JSON-able dict):
       {"prompt": [token ids], "max_tokens": int?, "temperature": f?,
        "top_k": int?, "top_p": f?, "seed": int?}
+    ``seed`` is any int: a sampled request's tokens depend on it and on
+    each token's index alone (the device sampler's key), whatever else
+    is in the batch; without one the engine gives each request its own.
     Response frames: {"token": id, "index": i} per token, then
       {"done": true, "reason": "eos"|"length", "n_tokens": n}
     (or {"error": "..."} for a rejected/failed request).

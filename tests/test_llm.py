@@ -5,12 +5,14 @@ caching, decode FLOPs helpers, and the telemetry surfacing."""
 
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
 from ray_tpu.llm.sampling import (SamplingParams, apply_temperature,
-                                  greedy, sample, softmax, top_k_mask,
-                                  top_p_mask)
+                                  greedy, jit_sampler, keep_mask,
+                                  pack_rows, sample, seed_words, softmax,
+                                  top_k_mask, top_p_mask)
 
 # ------------------------------------------------------------ sampling
 
@@ -94,6 +96,182 @@ def test_sampling_params_validate():
     with pytest.raises(ValueError):
         SamplingParams(top_p=1.5).validate()
     SamplingParams(temperature=0.8, top_k=40, top_p=0.95).validate()
+
+
+# ------------------------------------------ the sampler on the device
+
+GPT2_VOCAB = 50257
+
+
+def _device_tokens(rows, params, seeds=None, indices=None, pad=0):
+    """``sample_tokens`` over ``rows`` [B, V] (``pad`` padded rows of
+    NaN behind them), one SamplingParams a row."""
+    rows = np.asarray(rows, np.float32)
+    n, vocab = rows.shape
+    seeds = seeds if seeds is not None else range(n)
+    indices = indices if indices is not None else [0] * n
+    logits = np.full((n + pad, 1, vocab), np.nan, np.float32)
+    logits[:n, 0] = rows
+    knobs, words = pack_rows(
+        zip(params, map(seed_words, seeds), indices), n + pad)
+    return np.asarray(jit_sampler(n + pad)[0](logits, knobs, words))[:n]
+
+
+def _seeded_rows(seed, n=3, vocab=GPT2_VOCAB, scale=3.0):
+    return (np.random.default_rng(seed).standard_normal((n, vocab))
+            * scale).astype(np.float32)
+
+
+def _reference_keep(row, top_k, top_p):
+    return np.isfinite(top_p_mask(top_k_mask(
+        np.asarray(row, np.float64), top_k), top_p))
+
+
+@pytest.mark.parametrize("case", ["distinct", "ties", "all_equal",
+                                  "filters_ignored"])
+def test_device_greedy_is_numpy_argmax(case):
+    rows = _seeded_rows(11)
+    params = [SamplingParams()] * 3
+    if case == "ties":      # the largest value twice: the lower index
+        for r, (i, j) in zip(rows, [(40000, 7), (123, 50256), (9, 10)]):
+            r[i] = r[j] = r.max() + 1.0
+    elif case == "all_equal":
+        rows[:] = 0.25
+    elif case == "filters_ignored":
+        params = [SamplingParams(0.0, top_k=3, top_p=0.5)] * 3
+    got = _device_tokens(rows, params)
+    np.testing.assert_array_equal(got, np.argmax(rows, axis=-1))
+    assert got.dtype == np.int32
+
+
+@pytest.mark.parametrize("top_k,top_p", [
+    (50, 1.0), (1, 1.0), (0, 1.0), (GPT2_VOCAB, 1.0), (GPT2_VOCAB + 5, 1.0),
+    (0, 0.95), (0, 0.5), (0, 1e-9), (40, 0.9), (1000, 0.3), (5, 0.999)])
+def test_device_keep_mask_is_the_reference_set(top_k, top_p):
+    """The searched cut-offs leave what the reference's sort leaves, on
+    seeded rows the size of GPT-2's vocabulary at three temperatures.
+    float32 sums against the reference's float64: a token whose mass
+    before it lies within 1e-5 of top_p may fall either way."""
+    rows = _seeded_rows(top_k * 7 + int(top_p * 1000)) \
+        / np.array([[0.7], [1.0], [1.3]], np.float32)
+    got = np.asarray(jax.jit(keep_mask)(
+        rows, np.full(3, top_k, np.int32), np.full(3, top_p, np.float32)))
+    for row, mask in zip(rows, got):
+        want = _reference_keep(row, top_k, top_p)
+        firm = _reference_keep(row, top_k, max(top_p - 1e-5, 1e-12)) \
+            if top_p < 1.0 else want
+        loose = _reference_keep(row, top_k, min(top_p + 1e-5, 1.0)) \
+            if top_p < 1.0 else want
+        assert mask.sum() >= 1
+        assert not (firm & ~mask).any() and not (mask & ~loose).any()
+        assert abs(int(mask.sum()) - int(want.sum())) <= 1
+
+
+@pytest.mark.parametrize("case", ["ties_at_kth", "ties_at_kth_then_top_p",
+                                  "negative_and_zero", "top_p_tiny_tied"])
+def test_device_keep_mask_with_ties(case):
+    row = _seeded_rows(5, n=1)[0]
+    top = np.argsort(-row)
+    if case == "ties_at_kth":       # the 3rd, 4th and 5th largest equal
+        row[top[2:5]] = row[top[2]]
+        got = np.asarray(jax.jit(keep_mask)(
+            row[None], np.array([3], np.int32), np.ones(1, np.float32)))[0]
+        want = _reference_keep(row, 3, 1.0)
+        assert want.sum() == 5      # ties stay, as top_k_mask
+    elif case == "ties_at_kth_then_top_p":
+        row[top[2:5]] = row[top[2]]
+        row[top[:2]] += 4.0         # the nucleus ends inside the top two
+        got = np.asarray(jax.jit(keep_mask)(
+            row[None], np.array([3], np.int32),
+            np.array([0.6], np.float32)))[0]
+        want = _reference_keep(row, 3, 0.6)
+    elif case == "negative_and_zero":   # -0.0 and 0.0 are one value
+        row = np.array([-1.0, -0.0, 0.0, -2.0, -3.0], np.float32)
+        got = np.asarray(jax.jit(keep_mask)(
+            row[None], np.array([1], np.int32), np.ones(1, np.float32)))[0]
+        want = _reference_keep(row, 1, 1.0)
+        assert want.sum() == 2
+    else:   # the largest value twice under a tiny top_p: the reference
+        # keeps the lower index, the search keeps both (the documented
+        # difference: ties on the nucleus's boundary all stay)
+        row[top[1]] = row[top[0]]
+        got = np.asarray(jax.jit(keep_mask)(
+            row[None], np.zeros(1, np.int32),
+            np.array([1e-9], np.float32)))[0]
+        want = np.zeros_like(got)
+        want[top[:2]] = True
+        assert _reference_keep(row, 0, 1e-9).sum() == 1
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("params", [
+    SamplingParams(temperature=0.7),
+    SamplingParams(temperature=1.0, top_k=3),
+    SamplingParams(temperature=1.3, top_p=0.8),
+    SamplingParams(temperature=0.9, top_k=6, top_p=0.9)])
+def test_device_sample_matches_numpy_reference_distribution(params):
+    """20k draws of one row (one seed, the token's index counting up)
+    against the reference's probabilities."""
+    logits = np.array([1.0, 0.5, 0.0, -0.5, 2.0, -1.5, 0.25, 1.5],
+                      np.float32)
+    x = top_p_mask(top_k_mask(apply_temperature(logits, params.temperature),
+                              params.top_k), params.top_p)
+    ref = softmax(x)
+    n = 20000
+    got = _device_tokens(np.tile(logits, (n, 1)), [params] * n,
+                         seeds=[1234567] * n, indices=range(n))
+    counts = np.bincount(got, minlength=len(logits))
+    assert set(np.flatnonzero(counts)) <= set(np.flatnonzero(ref))
+    np.testing.assert_allclose(counts / n, ref, atol=0.012)
+
+
+@pytest.mark.parametrize("neighbours", ["padding", "nan", "other_requests",
+                                        "other_slot"])
+def test_device_rows_do_not_disturb_each_other(neighbours):
+    """A request's token depends on its logits, its parameters, its seed
+    and the token's index: not on its slot, on padding or on NaN rows."""
+    rows = _seeded_rows(21, n=2)
+    params = [SamplingParams(0.8, top_p=0.95), SamplingParams()]
+    alone = _device_tokens(rows, params, seeds=[77, 5], indices=[3, 9])
+    if neighbours == "padding":
+        got = _device_tokens(rows, params, seeds=[77, 5], indices=[3, 9],
+                             pad=6)
+    elif neighbours == "nan":
+        wide = np.concatenate([rows, np.full_like(rows, np.nan)])
+        got = _device_tokens(
+            wide, params + [SamplingParams(1.0, top_k=4, top_p=0.5)] * 2,
+            seeds=[77, 5, 1, 2], indices=[3, 9, 0, 0])[:2]
+    elif neighbours == "other_requests":
+        wide = np.concatenate([rows, _seeded_rows(22, n=2)])
+        got = _device_tokens(
+            wide, params + [SamplingParams(1.2, top_k=50)] * 2,
+            seeds=[77, 5, 1 << 40, -3], indices=[3, 9, 1, 2])[:2]
+    else:
+        got = _device_tokens(rows[::-1], params[::-1], seeds=[5, 77],
+                             indices=[9, 3])[::-1]
+    np.testing.assert_array_equal(got, alone)
+    assert alone[1] == np.argmax(rows[1])
+
+
+def test_device_draws_follow_seed_and_index():
+    rows = np.tile(_seeded_rows(31, n=1, vocab=4096, scale=1.0), (6, 1))
+    p = [SamplingParams(temperature=1.0)] * 6
+    big = (1 << 70) + 12345         # any Python int is a seed
+    got = _device_tokens(rows, p, seeds=[9, 9, 10, big, big, -1],
+                         indices=[0, 1, 0, 5, 5, 0])
+    assert got[3] == got[4]
+    assert len(set(got.tolist())) >= 4    # 4096 tokens, near-flat row
+    assert seed_words(big) == (12345, 0) and seed_words(-1) == (
+        0xFFFFFFFF, 0xFFFFFFFF)
+
+
+def test_last_rows_places_the_prefill_row():
+    _, last_rows = jit_sampler(4)
+    logits = np.arange(2 * 8 * 5, dtype=np.float32).reshape(2, 8, 5)[:1]
+    out = np.asarray(last_rows(logits, np.int32(5)))
+    assert out.shape == (4, 1, 5)
+    np.testing.assert_array_equal(out[0, 0], logits[0, 5])
+    assert not out[1:].any()
 
 
 # ------------------------------------------------------------ page pool

@@ -144,6 +144,95 @@ def test_seeded_sampling_reproducible(setup):
     assert other != one or True   # different seed may coincide; no pin
 
 
+SAMPLED = SamplingParams(temperature=0.9, top_k=50, top_p=0.95)
+
+
+def _tokens(eng, seq):
+    return [f["token"] for f in eng.frames(seq) if "token" in f]
+
+
+@pytest.mark.parametrize("company", ["full_batch", "later_slot",
+                                     "recompute_preemption"])
+def test_seeded_request_gives_the_same_tokens_in_any_company(setup,
+                                                             company):
+    """The draw's key is (the request's seed, the token's index): alone,
+    among a full batch of other requests, in another slot, or evicted
+    and re-prefilled, a request gives the same tokens."""
+    eng, params = setup
+    prompt, seed = [10, 20, 30], 4242 + (1 << 35)
+    alone = eng.generate(prompt, max_tokens=12, params=SAMPLED, seed=seed)
+    assert len(alone) == 12
+    if company == "recompute_preemption":
+        eng = GenerationEngine(
+            model_cfg=CFG, params=params,
+            engine_cfg=EngineConfig(page_size=4, num_pages=8,
+                                    max_batch=4)).start()
+    try:
+        others = [([7, 8, 9, 11], SamplingParams(temperature=1.1), 5),
+                  ([300, 2], SamplingParams(), None),
+                  ([40, 41, 42, 43, 44], SAMPLED, seed + 1)]
+        if company == "full_batch":
+            seqs = [eng.submit(prompt, 12, SAMPLED, seed)] + [
+                eng.submit(p, 12, sp, sd) for p, sp, sd in others]
+        else:
+            seqs = [eng.submit(p, 12, sp, sd) for p, sp, sd in others[:2]]
+            if company == "later_slot":     # the others are decoding
+                it = eng.frames(seqs[0])
+                next(it), next(it)
+            seqs.insert(0, eng.submit(prompt, 12, SAMPLED, seed))
+        assert _tokens(eng, seqs[0]) == alone
+        for seq in seqs[1:]:
+            list(eng.frames(seq))
+        if company == "recompute_preemption":
+            assert eng.stats()["evictions"] > 0
+    finally:
+        if company == "recompute_preemption":
+            eng.stop()
+
+
+def test_one_sampler_program_for_every_mix_and_ids_to_the_host():
+    """warmup() pays every compile a mixed batch needs; what a decode
+    step hands the host is [max_batch] int32, pinned on the sampler
+    program's own output; stats()["sampling"] counts what was packed."""
+    params = gpt2_init(CFG, jax.random.PRNGKey(3))
+    eng = GenerationEngine(
+        model_cfg=CFG, params=params,
+        engine_cfg=EngineConfig(page_size=4, num_pages=64, max_batch=4))
+    eng.warmup()
+    warm = eng.stats()
+    assert set(warm["programs"]) == {"llm_prefill[8]", "llm_last[8]",
+                                     "llm_sample", "llm_decode"}
+    assert warm["sampling"] == {"rows_greedy": 2, "rows_sampled": 0,
+                                "steps": 2, "steps_sampled": 0}
+    out, = jax.tree_util.tree_leaves(
+        eng._exe_cache["llm_sample"][1].out_info)
+    assert (out.shape, out.dtype) == ((4,), np.int32)
+    fetched = jax.tree_util.tree_leaves(
+        eng._exe_cache["llm_decode"][1].out_info)[0]
+    assert fetched.shape == (4, 1, CFG.vocab_size)  # stays on the device
+
+    eng.start()
+    try:
+        seqs = [eng.submit([5, 6, 7], 6, SamplingParams(), None),
+                eng.submit([8, 9], 6, SAMPLED, 1),
+                eng.submit([1, 2, 3, 4], 6,
+                           SamplingParams(temperature=0.5, top_p=0.5), 2)]
+        for seq in seqs:
+            assert len(_tokens(eng, seq)) == 6
+    finally:
+        eng.stop()
+    after = eng.stats()
+    assert after["compiles"] == warm["compiles"] == 4
+    assert after["programs"] == warm["programs"]
+    counts = after["sampling"]
+    assert counts["rows_greedy"] == 2 + 6 and counts["rows_sampled"] == 12
+    assert counts["rows_greedy"] + counts["rows_sampled"] == \
+        after["tokens_generated"]
+    # every launch after the warm-up held a sampled row
+    assert counts["steps"] - counts["steps_sampled"] <= 2 + 1
+    assert counts["steps_sampled"] >= 5
+
+
 def test_submit_rejects_bad_requests(setup):
     eng, _ = setup
     with pytest.raises(ValueError):

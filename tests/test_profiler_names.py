@@ -128,10 +128,12 @@ def test_prefills_and_compiles_count_what_the_script_did(scripted):
     eng, _ = scripted
     s = eng.stats()
     assert s["prefills"] == 3
-    # buckets 8 and 16, and the decode program
-    assert s["compiles"] == 3 == len(s["programs"])
+    # buckets 8 and 16 with their row pickers, the decode program and
+    # the one sampler
+    assert s["compiles"] == 6 == len(s["programs"])
     assert set(s["programs"]) == {"llm_prefill[8]", "llm_prefill[16]",
-                                  "llm_decode"}
+                                  "llm_last[8]", "llm_last[16]",
+                                  "llm_decode", "llm_sample"}
 
 
 def test_stats_asks_the_device_nothing_and_peak_is_the_programs(
@@ -146,8 +148,8 @@ def test_stats_asks_the_device_nothing_and_peak_is_the_programs(
         monkeypatch.setattr(type(d), "memory_stats", boom, raising=False)
     s = eng.stats()
     totals = [engine_mod._program_bytes(exe)
-              for _, exe in eng._fwd_cache.values()]
-    assert len(totals) == 3 and min(totals) > 0
+              for _, exe in eng._exe_cache.values()]
+    assert len(totals) == 6 and min(totals) > 0
     assert s["peak_hbm_bytes"] == max(totals)
 
 
@@ -193,7 +195,8 @@ def test_cpu_capture_of_a_tiny_engine_holds_the_phases(tmp_path):
     assert prefill["bucket"] == 8 and prefill["prompt_tokens"] == 3
     assert events["llm.decode"][0]["batch"] == 2
     # bucket 8 is new to this engine: its compile is inside the capture
-    assert events["llm.compile"] == [{"program": "llm_prefill[8]"}]
+    assert events["llm.compile"] == [{"program": "llm_prefill[8]"},
+                                     {"program": "llm_last[8]"}]
 
 
 class _FakeQueue:
@@ -286,6 +289,22 @@ def test_lowered_engine_forward_names_the_cache_scopes():
     for name in ("kv.store", "kv.attend", "attn.core", "embed", "lm_head"):
         assert name in text, name
     assert "jit_fwd" in text        # the reader of decode.device_ms.sat
+
+
+def test_lowered_sampler_is_a_program_of_its_own_under_scope_sample():
+    """A trace files the sampler's operations under ``sample``, in
+    programs that are not ``jit_fwd``: decode.device_ms.sat reads the
+    forward alone."""
+    from ray_tpu.llm.sampling import jit_sampler, pack_rows
+
+    sampler, last_rows = jit_sampler(4)
+    logits = jnp.zeros((4, 1, 128), jnp.float32)
+    text = sampler.lower(logits, *pack_rows([], 4)).as_text(debug_info=True)
+    assert "jit_sample_tokens" in text and "jit_fwd" not in text
+    assert "sample_tokens)/sample/" in text
+    text = last_rows.lower(jnp.zeros((1, 8, 128), jnp.float32),
+                           np.int32(2)).as_text(debug_info=True)
+    assert "jit_last_rows" in text and "last_rows)/sample/" in text
 
 
 @pytest.mark.parametrize("what", ["train_step", "engine_forward"])

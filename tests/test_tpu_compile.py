@@ -191,6 +191,34 @@ def test_engine_forward_updates_pool_in_place(engine_program, widths,
         < 0.5 * pool.size * pool.dtype.itemsize
 
 
+@pytest.mark.parametrize("program", ["sample", "last[1024]"])
+def test_engine_sampler_compiles_at_the_cells_sizes(one_chip, program):
+    """The engine's sampler (llm/sampling.py) at max_batch 16 and GPT-2's
+    vocabulary: a search, no sort (the TPU compiler takes 20-30 s to
+    compile a sort of 50,257 values: that would be every replica's
+    set-up), int32 ids out, a few MB of temporaries."""
+    import time
+
+    from ray_tpu.llm.sampling import jit_sampler
+
+    sampler, last_rows = jit_sampler(16)
+    on = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    vocab = GPT2_124M["vocab_size"]
+    t0 = time.perf_counter()
+    if program == "sample":
+        compiled = sampler.lower(
+            on((16, 1, vocab), jnp.float32), on((16, 2), jnp.float32),
+            on((16, 4), jnp.uint32)).compile()
+        out, = jax.tree_util.tree_leaves(compiled.out_info)
+        assert (out.shape, out.dtype) == ((16,), jnp.int32)
+    else:
+        compiled = last_rows.lower(on((1, 1024, vocab), jnp.float32),
+                                   on((), jnp.int32)).compile()
+    assert time.perf_counter() - t0 < 15
+    assert not re.search(r" sort\(", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 32e6
+
+
 def test_olmoe_decode_cell_compiles_with_grouped_matmuls_in_place(
         one_chip):
     """The decode program of serve-olmoe-1b-7b-sat (OLMoE-1B-7B's widths,
