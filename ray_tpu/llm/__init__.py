@@ -3,8 +3,8 @@
 Continuous-batching generation engine (Orca-style iteration-level
 scheduling) over a vLLM-style paged KV cache, served through
 ``ray_tpu.serve`` with token streaming, request autoscaling, and the
-PR-8 resilience semantics.  See README "LLM serving" and
-``bench.py --serve-llm``.
+PR-8 resilience semantics.  See README "LLM serving"; the serving
+cells of ``BENCHMARK.json`` (``benchmark/run.py``) measure it.
 
 Tokens are chosen on the device (``sampling.sample_tokens``, a jitted
 program the engine runs after every forward); ``sample`` and the other

@@ -10,7 +10,7 @@ forward over every running sequence (padded to the fixed ``max_batch``
 shape so the jitted step compiles once), and (3) retires finished
 sequences and frees their pages immediately.  A request submitted while
 others are mid-generation starts decoding on the very next step — the
-continuous-batching property the serve bench measures as TTFT under
+continuous-batching property the serving cells measure as TTFT under
 load (pinned by tests/test_llm_engine.py).
 
 Memory pressure is handled vLLM-style by recompute preemption: when a
@@ -277,8 +277,8 @@ class GenerationEngine:
         self._prefill_wall_s = 0.0      # inside _prefill, leaves or not
         self._seq_seed = seed
         # TTFT phase accounting (engine-side): waiting-queue + prefill
-        # totals feed bench.py's decomposition print; TPOT (inter-
-        # token gap) sums feed the serve_llm_tpot_p99_ms ledger row.
+        # totals and TPOT (inter-token gap) sums, read through
+        # stats().
         self._waiting_s_total = 0.0
         self._prefill_s_total = 0.0
         self._ttft_requests = 0
@@ -465,7 +465,7 @@ class GenerationEngine:
                 "programs": dict(self._compile_seconds),
                 "sampling": dict(self._sampling),
                 "device": dict(self._device),
-                # TTFT phase + TPOT accounting (bench decomposition).
+                # TTFT phase + TPOT accounting.
                 "ttft_requests": self._ttft_requests,
                 "ttft_waiting_s_total": self._waiting_s_total,
                 "ttft_prefill_s_total": self._prefill_s_total,

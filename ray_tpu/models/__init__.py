@@ -8,9 +8,9 @@ top-k sparse experts, ops/moe.py).  All models are flax.linen with
 rules, so DP/FSDP/TP/CP layouts are a rules-table choice, not a model
 edit.
 
-``MODEL_FAMILIES`` is the one table the engine (``llm/engine.py``), the
-multi-host training plane (``train.distributed.rules_for_model``), bench
-and the CLI resolve a family through.  A fourth family is a row here:
+``MODEL_FAMILIES`` is the one table the engine (``llm/engine.py``) and
+the multi-host training plane (``train.distributed.rules_for_model``)
+resolve a family through.  A fourth family is a row here:
 its config class, module, init, loss, partition rules, a tiny preset for
 tests, and how many KV heads its cache stores (the module's ``__call__``
 takes ``kv_cache=`` / ``positions=`` as GPT2's does, llm/kv_cache.py).
@@ -21,10 +21,9 @@ from dataclasses import dataclass
 from typing import Any, Callable
 
 from .gpt2 import (GPT2, GPT2Config, gpt2_init, gpt2_loss_fn,  # noqa: F401
-                   gpt2_param_axes, gpt2_partition_rules)
+                   gpt2_partition_rules)
 from .llama import (Llama, LlamaConfig, llama_init,  # noqa: F401
-                    llama_loss_fn, llama_param_axes,
-                    llama_partition_rules, olmoe_loss_fn,
+                    llama_loss_fn, llama_partition_rules, olmoe_loss_fn,
                     olmoe_partition_rules)
 
 
@@ -61,7 +60,3 @@ def family_of(model_cfg) -> ModelFamily:
     raise TypeError(f"unsupported model_cfg {type(model_cfg)}: no row "
                     "of ray_tpu.models.MODEL_FAMILIES has its class")
 
-
-# Model-family name -> partition-rule-set factory.
-PARTITION_RULE_SETS = {name: fam.partition_rules
-                       for name, fam in MODEL_FAMILIES.items()}

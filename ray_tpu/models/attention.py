@@ -1,0 +1,111 @@
+"""The attention core both blocks call: causal attention over q, k, v
+already projected, position-encoded and split into heads.
+
+One function, ``attention``, under the scope ``attn.core``:
+
+- with a ``cache`` (the engine's prefill and decode): this step's K/V
+  are written into the paged pool the layers carry and q attends
+  against the gathered history (``llm/kv_cache.py``).  Runs unsharded —
+  the serving engine hosts one replica per chip;
+- without one (training, the full forward): grouped KV heads are
+  repeated to the query heads, q/k/v are constrained as the activation
+  table says, and ``cfg.attn_impl`` picks ``dense`` (XLA-fused,
+  GSPMD-partitioned), ``flash`` (the Pallas kernel, per shard),
+  ``ring`` (context parallel over the ``seq`` mesh axis, SURVEY.md
+  §5.7) or ``ulysses`` (head/seq all-to-all).
+
+A kernel GSPMD cannot partition runs under ``shard_map``; its spec comes
+from the same table and the same fitting rule as the constraints
+(``parallel/sharding.py logical_spec``), so a head count the ``tensor``
+axis does not divide stays whole there as everywhere else.
+
+``cfg`` is a GPT2Config or a LlamaConfig: ``attn_impl``, ``mesh`` and
+``dtype`` are read.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ..parallel.sharding import logical_spec, with_logical_constraint
+
+
+def _sharded(fn, mesh, logical, shape):
+    from jax import shard_map
+
+    spec = logical_spec(mesh, logical, shape)
+    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)
+
+
+def _attention(cfg, q, k, v):
+    """q, k, v: [B, T, H, D] -> [B, T, H, D]."""
+    if cfg.attn_impl == "dense":
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=jnp.float32)
+        scores = scores * (q.shape[-1] ** -0.5)
+        t = q.shape[1]
+        mask = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0) >= \
+            jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+        scores = jnp.where(mask[None, None], scores, -1e30)
+        p = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    if cfg.attn_impl == "flash":
+        # Pallas blockwise kernel (ops/flash_attention.py): no [T, T]
+        # score matrix in HBM.  Measured on v5e at pretraining shapes:
+        # whole-sequence blocks (clamped to 1024) win — per-program
+        # overhead dominates below 512, and a [1024,1024] f32 score
+        # block still fits VMEM comfortably.  Longer sequences stream
+        # in 1024-blocks with causal block-skipping.
+        from ..ops import flash_attention
+
+        flash = functools.partial(flash_attention, causal=True,
+                                  block_q=1024, block_k=1024)
+        if cfg.mesh is None or cfg.mesh.size == 1:
+            return flash(q, k, v)
+        # A Mosaic kernel is not partitioned automatically: across a
+        # mesh it runs per shard, batch and heads split as the table
+        # says (attention is independent over both; the sequence stays
+        # whole — splitting it is ring attention's job).
+        return _sharded(flash, cfg.mesh, ("batch", None, "heads", None),
+                        q.shape)(q, k, v)
+    from ..parallel.ring_attention import ring_attention
+    from ..parallel.ulysses import ulysses_attention
+
+    if cfg.mesh is None:
+        raise ValueError(f"attn_impl={cfg.attn_impl!r} needs cfg.mesh")
+    inner = (ring_attention if cfg.attn_impl == "ring"
+             else ulysses_attention)
+    return _sharded(functools.partial(inner, causal=True), cfg.mesh,
+                    ("batch", "seq", None, None), q.shape)(q, k, v)
+
+
+def attention(cfg, q, k, v, cache=None):
+    """q: [B, T, H, D]; k, v: [B, T, Hkv, D] (H a multiple of Hkv).
+    Returns (att [B, T, H, D], new_cache): ``new_cache`` is the updated
+    (k_pages, v_pages) when ``cache`` ({"k_pages", "v_pages", "layer",
+    "page_table", "positions"}) is given, else None."""
+    with jax.named_scope("attn.core"):
+        if cache is not None:
+            # The pool stores the Hkv GROUPED heads; repeat-to-H happens
+            # at attend time, so GQA shrinks the pooled cache by H/Hkv.
+            from ..llm.kv_cache import paged_attend, paged_store
+
+            k_pages, v_pages = paged_store(
+                cache["k_pages"], cache["v_pages"], cache["layer"],
+                k, v, cache["page_table"], cache["positions"])
+            att = paged_attend(q, k_pages, v_pages, cache["layer"],
+                               cache["page_table"], cache["positions"])
+            return att, (k_pages, v_pages)
+        rep = q.shape[2] // k.shape[2]
+        if rep != 1:  # GQA: repeat KV groups to full heads
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
+        heads = ("batch", "seq", "heads", None)
+        q = with_logical_constraint(q, heads, cfg.mesh)
+        k = with_logical_constraint(k, heads, cfg.mesh)
+        v = with_logical_constraint(v, heads, cfg.mesh)
+        return _attention(cfg, q, k, v), None

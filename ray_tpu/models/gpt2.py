@@ -5,9 +5,8 @@ TPU-first design notes:
   fp32 moments), matmuls hit the MXU with preferred_element_type fp32;
 - every weight/activation dim carries a logical name consumed by
   ray_tpu.parallel.sharding rules (DP/FSDP/TP = table change);
-- attention impl selectable: "dense" (XLA-fused, GSPMD-partitioned),
-  "ring" (context parallel over the ``seq`` mesh axis, SURVEY.md §5.7),
-  or "ulysses" (head/seq all-to-all);
+- attention impl selectable (models/attention.py, the core this block
+  shares with llama.py): "dense", "flash", "ring" or "ulysses";
 - jax.checkpoint per block when ``remat`` so long-context activation
   memory trades against recompute;
 - ``jax.named_scope`` names the parts (``embed``, ``attn.qkv``,
@@ -23,14 +22,15 @@ torch+DeepSpeed, here the model is native).
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass, field
-from typing import Any, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..parallel.sharding import ShardingRules, with_logical_constraint
+from ..parallel.sharding import with_logical_constraint as _constrain
+from .attention import attention
 
 
 @dataclass(frozen=True)
@@ -45,8 +45,7 @@ class GPT2Config:
     dtype: Any = jnp.bfloat16
     attn_impl: str = "dense"          # dense | flash | ring | ulysses
     remat: bool = True
-    mesh: Any = None                  # jax Mesh for CP shard_map wrappers
-    rules: Any = None                 # ShardingRules override
+    mesh: Any = None                  # jax Mesh the activations lie on
     # Mixture-of-Experts: >0 turns every ``moe_every``-th block's MLP
     # into a dropless MoEMLP (ops/moe.py) of two-matrix GELU experts.
     moe_num_experts: int = 0
@@ -93,66 +92,6 @@ class GPT2Config:
         return 2.0 * matmul_params + attn
 
 
-def _constrain(x, logical, cfg: GPT2Config):
-    rules = cfg.rules or ShardingRules()
-    if cfg.mesh is None:
-        return x
-    return with_logical_constraint(x, logical, cfg.mesh, rules)
-
-
-def _attention(cfg: GPT2Config, q, k, v):
-    """q,k,v: [B, T, H, D] -> [B, T, H, D]."""
-    if cfg.attn_impl == "flash":
-        # Pallas blockwise kernel (ops/flash_attention.py): no [T, T]
-        # score matrix in HBM.  Measured on v5e at pretraining shapes:
-        # whole-sequence blocks (clamped to 1024) win — per-program
-        # overhead dominates below 512, and a [1024,1024] f32 score
-        # block still fits VMEM comfortably.  Longer sequences stream
-        # in 1024-blocks with causal block-skipping.
-        from ..ops import flash_attention
-
-        flash = functools.partial(flash_attention, causal=True,
-                                  block_q=1024, block_k=1024)
-        if cfg.mesh is None or cfg.mesh.size == 1:
-            return flash(q, k, v)
-        # A Mosaic kernel is not partitioned automatically: across a
-        # mesh it runs per shard, batch and heads split as the rules
-        # say (attention is independent over both; the sequence stays
-        # whole — splitting it is ring attention's job).
-        from jax import shard_map
-
-        rules = (cfg.rules or ShardingRules()).prune(cfg.mesh)
-        spec = rules.spec(("batch", None, "heads", None))
-        return shard_map(flash, mesh=cfg.mesh,
-                         in_specs=(spec, spec, spec), out_specs=spec,
-                         check_vma=False)(q, k, v)
-    if cfg.attn_impl == "dense":
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                            preferred_element_type=jnp.float32)
-        scores = scores * (q.shape[-1] ** -0.5)
-        t = q.shape[1]
-        mask = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0) >= \
-            jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
-        scores = jnp.where(mask[None, None], scores, -1e30)
-        p = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.ring_attention import ring_attention
-    from ..parallel.ulysses import ulysses_attention
-
-    if cfg.mesh is None:
-        raise ValueError(f"attn_impl={cfg.attn_impl!r} needs cfg.mesh")
-    inner = (ring_attention if cfg.attn_impl == "ring"
-             else ulysses_attention)
-    spec = P(("data", "fsdp"), "seq", None, None)
-    fn = shard_map(functools.partial(inner, causal=True),
-                   mesh=cfg.mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_vma=False)
-    return fn(q, k, v)
-
-
 class Block(nn.Module):
     cfg: GPT2Config
     use_moe: bool = False
@@ -172,28 +111,10 @@ class Block(nn.Module):
             q = q.reshape(b, t, h, d_head)
             k = k.reshape(b, t, h, d_head)
             v = v.reshape(b, t, h, d_head)
-        if cache is not None:
-            # Decode mode: write this step's K/V into the paged pool,
-            # attend q against the gathered history (prefill and
-            # single-token decode take the same path).  Runs unsharded
-            # — the serving engine hosts one replica per chip.
-            from ..llm.kv_cache import paged_attend, paged_store
-
-            with jax.named_scope("attn.core"):
-                k_pages, v_pages = paged_store(
-                    cache["k_pages"], cache["v_pages"], cache["layer"],
-                    k, v, cache["page_table"], cache["positions"])
-                att = paged_attend(q, k_pages, v_pages, cache["layer"],
-                                   cache["page_table"],
-                                   cache["positions"])
-            new_cache = (k_pages, v_pages)
-        else:
-            with jax.named_scope("attn.core"):
-                q = _constrain(q, ("batch", "seq", "heads", None), cfg)
-                k = _constrain(k, ("batch", "seq", "heads", None), cfg)
-                v = _constrain(v, ("batch", "seq", "heads", None), cfg)
-                att = _attention(cfg, q, k, v)
-            new_cache = None
+        # With a cache (prefill and single-token decode alike) this
+        # step's K/V go into the paged pool and q attends against the
+        # gathered history.
+        att, new_cache = attention(cfg, q, k, v, cache)
         with jax.named_scope("attn.out"):
             att = att.reshape(b, t, cfg.d_model)
             att = nn.Dense(cfg.d_model, dtype=cfg.dtype, name="c_proj",
@@ -214,7 +135,7 @@ class Block(nn.Module):
             else:
                 y = nn.Dense(cfg.d_ff, dtype=cfg.dtype, name="mlp_in",
                              kernel_init=nn.initializers.normal(0.02))(y)
-                y = _constrain(y, ("batch", "seq", "mlp"), cfg)
+                y = _constrain(y, ("batch", "seq", "mlp"), cfg.mesh)
                 y = nn.gelu(y)
                 y = nn.Dense(cfg.d_model, dtype=cfg.dtype,
                              name="mlp_out",
@@ -257,7 +178,7 @@ class GPT2(nn.Module):
             else:
                 x = wte.astype(cfg.dtype)[tokens] \
                     + wpe.astype(cfg.dtype)[:t]
-            x = _constrain(x, ("batch", "seq", "embed"), cfg)
+            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
         block = Block
         if cfg.remat and not decode:
             # Decode steps are memory-light; remat would only slow them.
@@ -277,14 +198,14 @@ class GPT2(nn.Module):
                               "positions": positions})
             else:
                 x = blk(x)
-            x = _constrain(x, ("batch", "seq", "embed"), cfg)
+            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
         if return_hidden:
             return x
         with jax.named_scope("lm_head"):
             logits = jnp.einsum("btd,vd->btv", x, wte.astype(cfg.dtype),
                                 preferred_element_type=jnp.float32)
-            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg)
+            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg.mesh)
         if decode:
             return logits, {"k_pages": k_pages, "v_pages": v_pages,
                             "page_table": kv_cache["page_table"]}
@@ -294,8 +215,9 @@ class GPT2(nn.Module):
 def gpt2_init(cfg: GPT2Config, rng) -> Any:
     import dataclasses
 
-    # Init traces a tiny batch; sharding constraints (and CP shard_map)
-    # don't apply to it and would reject the shapes — strip them.
+    # Init traces a tiny batch; sharding constraints (and the context-
+    # parallel kernels) don't apply to it and would reject the shapes —
+    # strip them.
     init_cfg = dataclasses.replace(cfg, mesh=None, attn_impl="dense")
     tokens = jnp.zeros((1, min(cfg.max_seq, 8)), jnp.int32)
     return GPT2(init_cfg).init(rng, tokens)
@@ -413,10 +335,12 @@ def gpt2_loss_fn(cfg: GPT2Config, params, batch,
 def gpt2_partition_rules():
     """Default fsdp+tensor partition rules for GPT-2 param trees, in
     ``match_partition_rules`` form ((regex, PartitionSpec) pairs, first
-    match wins).  Mirrors ``gpt2_param_axes`` through the DEFAULT_RULES
-    table (vocab/heads/mlp → ``tensor``, embed_fsdp → ``fsdp``) but as
-    path regexes, so the elastic checkpoint plane can persist and
-    re-derive layouts without importing model code."""
+    match wins).  THE description of how the weights shard: placed by
+    ``train.distributed.fitted_state_specs``, persisted by the elastic
+    checkpoint plane.  An output dimension lies on the mesh axis the
+    activation table (parallel/sharding.py) gives the activation it
+    produces (vocab/heads/mlp → ``tensor``; pinned by
+    tests/test_parallel.py), the other dimension on ``fsdp``."""
     from jax.sharding import PartitionSpec as PS
 
     return (
@@ -431,28 +355,3 @@ def gpt2_partition_rules():
         (r"moe_mlp/router$", PS("fsdp", None)),
         (r"(bias|scale)$", PS()),
     )
-
-
-def gpt2_param_axes(path: str, leaf) -> Tuple[Optional[str], ...]:
-    """Logical axes per parameter path for shard_pytree
-    (DP/FSDP/TP/EP)."""
-    from ..ops.moe import moe_param_axes
-
-    moe = moe_param_axes(path, leaf)
-    if moe is not None:
-        return moe
-    if "wte" in path:
-        return ("vocab", "embed_fsdp")
-    if "wpe" in path:
-        return (None, None)
-    if leaf.ndim == 1:
-        return (None,)
-    if "c_attn" in path:
-        return ("embed_fsdp", "heads")
-    if "c_proj" in path:
-        return ("heads", "embed_fsdp")
-    if "mlp_in" in path:
-        return ("embed_fsdp", "mlp")
-    if "mlp_out" in path:
-        return ("mlp", "embed_fsdp")
-    return (None,) * leaf.ndim

@@ -4,23 +4,25 @@ which is this block plus two things: QK-norm (an RMSNorm over the whole
 place of the dense SwiGLU (``ops/moe.py``: dropless top-k).
 
 Covers the reference's Llama fine-tune workloads (ref: release/train_tests
-LLM configs) natively.  Same logical-axis discipline as gpt2.py; grouped
-KV heads carry the "kv" logical name so TP over ``tensor`` can shard
-query heads while replicating (or sharding) KV heads independently.
+LLM configs) natively.  Same logical-axis discipline as gpt2.py, and the
+same attention core (models/attention.py): grouped KV heads are stored
+grouped in the paged cache and repeated to the query heads for the
+full forward.
 """
 
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Any, Optional, Tuple
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..parallel.sharding import ShardingRules, with_logical_constraint
+from ..parallel.sharding import with_logical_constraint as _constrain
+from .attention import attention
 
 
 @dataclass(frozen=True)
@@ -41,7 +43,6 @@ class LlamaConfig:
     attn_impl: str = "dense"
     remat: bool = True
     mesh: Any = None
-    rules: Any = None
     # OLMoE (Muennighoff et al. 2024): RMSNorm over the full-width q and
     # k; n_experts > 0 makes every block's FFN ``experts_per_token`` of
     # ``n_experts`` gated experts of width ``d_ff`` each.
@@ -122,13 +123,6 @@ class LlamaConfig:
         return 2.0 * matmul_params + attn
 
 
-def _constrain(x, logical, cfg):
-    if cfg.mesh is None:
-        return x
-    return with_logical_constraint(x, logical, cfg.mesh,
-                                   cfg.rules or ShardingRules())
-
-
 @functools.lru_cache(maxsize=64)
 def _rope_tables(seq_len: int, head_dim: int, theta: float):
     """Cached sin/cos tables keyed by (seq_len, head_dim): every block
@@ -182,34 +176,6 @@ class RMSNorm(nn.Module):
         return (norm * scale).astype(self.dtype)
 
 
-def _attention(cfg, q, k, v):
-    if cfg.attn_impl == "dense":
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                            preferred_element_type=jnp.float32)
-        scores = scores * (q.shape[-1] ** -0.5)
-        t = q.shape[1]
-        mask = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0) >= \
-            jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
-        scores = jnp.where(mask[None, None], scores, -1e30)
-        p = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.ring_attention import ring_attention
-    from ..parallel.ulysses import ulysses_attention
-
-    if cfg.mesh is None:
-        raise ValueError(f"attn_impl={cfg.attn_impl!r} needs cfg.mesh")
-    inner = (ring_attention if cfg.attn_impl == "ring"
-             else ulysses_attention)
-    spec = P(("data", "fsdp"), "seq", None, None)
-    fn = shard_map(functools.partial(inner, causal=True),
-                   mesh=cfg.mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_vma=False)
-    return fn(q, k, v)
-
-
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
 
@@ -239,30 +205,9 @@ class LlamaBlock(nn.Module):
                       positions)
             k = _rope(k.reshape(b, t, hk, d_head), cfg.rope_theta,
                       positions)
-        if cache is not None:
-            # Decode mode: the cache stores the hk GROUPED heads
-            # (post-RoPE); repeat-to-h happens at attend time, so GQA
-            # shrinks the pooled cache by h/hk.
-            from ..llm.kv_cache import paged_attend, paged_store
-
-            with jax.named_scope("attn.core"):
-                k_pages, v_pages = paged_store(
-                    cache["k_pages"], cache["v_pages"], cache["layer"],
-                    k, v, cache["page_table"], positions)
-                att = paged_attend(q, k_pages, v_pages, cache["layer"],
-                                   cache["page_table"], positions)
-            new_cache = (k_pages, v_pages)
-        else:
-            with jax.named_scope("attn.core"):
-                if hk != h:  # GQA: repeat KV groups to full heads
-                    rep = h // hk
-                    k = jnp.repeat(k, rep, axis=2)
-                    v = jnp.repeat(v, rep, axis=2)
-                q = _constrain(q, ("batch", "seq", "heads", None), cfg)
-                k = _constrain(k, ("batch", "seq", "heads", None), cfg)
-                v = _constrain(v, ("batch", "seq", "heads", None), cfg)
-                att = _attention(cfg, q, k, v)
-            new_cache = None
+        # The cache stores the hk GROUPED heads (post-RoPE); the full
+        # forward repeats them to h.
+        att, new_cache = attention(cfg, q, k, v, cache)
         with jax.named_scope("attn.out"):
             att = att.reshape(b, t, cfg.d_model)
             att = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
@@ -286,7 +231,7 @@ class LlamaBlock(nn.Module):
                 up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
                               kernel_init=init, name="w_up")(y)
                 z = nn.silu(gate) * up
-                z = _constrain(z, ("batch", "seq", "mlp"), cfg)
+                z = _constrain(z, ("batch", "seq", "mlp"), cfg.mesh)
                 down = nn.Dense(cfg.d_model, use_bias=False,
                                 dtype=cfg.dtype, kernel_init=init,
                                 name="w_down")(z)
@@ -310,7 +255,7 @@ class Llama(nn.Module):
                          (cfg.vocab_size, cfg.d_model), jnp.float32)
         with jax.named_scope("embed"):
             x = emb.astype(cfg.dtype)[tokens]
-            x = _constrain(x, ("batch", "seq", "embed"), cfg)
+            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
         block = LlamaBlock
         if cfg.remat and not decode:
             block = nn.remat(LlamaBlock, prevent_cse=False)
@@ -327,14 +272,14 @@ class Llama(nn.Module):
                               "positions": positions})
             else:
                 x = blk(x)
-            x = _constrain(x, ("batch", "seq", "embed"), cfg)
+            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
         x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
         head = self.param("lm_head", nn.initializers.normal(0.02),
                           (cfg.d_model, cfg.vocab_size), jnp.float32)
         with jax.named_scope("lm_head"):
             logits = jnp.einsum("btd,dv->btv", x, head.astype(cfg.dtype),
                                 preferred_element_type=jnp.float32)
-            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg)
+            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg.mesh)
         if decode:
             return logits, {"k_pages": k_pages, "v_pages": v_pages,
                             "page_table": kv_cache["page_table"]}
@@ -426,7 +371,8 @@ def llama_partition_rules():
 def olmoe_partition_rules():
     """Llama's rules and the experts': every expert on every chip, its
     matrices sharded over fsdp x tensor on their ``d`` and ``f``
-    dimensions (an ``expert`` mesh axis is ROADMAP Design 2's)."""
+    dimensions (experts over an ``expert`` mesh axis is ROADMAP
+    Reach 5's)."""
     from jax.sharding import PartitionSpec as PS
 
     return (
@@ -434,26 +380,3 @@ def olmoe_partition_rules():
         (r"moe/w_down$", PS(None, "tensor", "fsdp")),
         (r"moe/router$", PS("fsdp", None)),
     ) + llama_partition_rules()
-
-
-def llama_param_axes(path: str, leaf) -> Tuple[Optional[str], ...]:
-    from ..ops.moe import moe_param_axes
-
-    moe = moe_param_axes(path, leaf)
-    if moe is not None:
-        return moe
-    if "embed" in path and leaf.ndim == 2:
-        return ("vocab", "embed_fsdp")
-    if "lm_head" in path:
-        return ("embed_fsdp", "vocab")
-    if leaf.ndim == 1:
-        return (None,)
-    if any(k in path for k in ("wq", "wk", "wv")):
-        return ("embed_fsdp", "heads")
-    if "wo" in path:
-        return ("heads", "embed_fsdp")
-    if any(k in path for k in ("w_gate", "w_up")):
-        return ("embed_fsdp", "mlp")
-    if "w_down" in path:
-        return ("mlp", "embed_fsdp")
-    return (None,) * leaf.ndim

@@ -33,7 +33,7 @@ the engine's counters (``moe_counters``).
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional
 
 import flax.linen as nn
 import jax
@@ -183,16 +183,3 @@ def moe_counters(intermediates) -> Optional[jnp.ndarray]:
     return jnp.stack([
         jnp.stack([jnp.sum(m["load"]), jnp.sum(m["load"] > 0),
                    jnp.max(m["load"])]) for m in layers]).astype(jnp.int32)
-
-
-def moe_param_axes(path: str, leaf) -> Optional[Tuple]:
-    """Logical axes for MoE params (None = not a MoE param)."""
-    if "moe" not in path:
-        return None
-    if "router" in path:
-        return ("embed_fsdp", None)
-    if any(n in path for n in ("w_in", "w_gate", "w_up")):
-        return ("expert", "embed_fsdp", "mlp")
-    if any(n in path for n in ("w_out", "w_down")):
-        return ("expert", "mlp", "embed_fsdp")
-    return None

@@ -8,10 +8,10 @@ Ulysses all-to-all) — absent from the reference (§5.7) and first-class
 here.
 """
 
-from .mesh import (MeshSpec, create_mesh, gang_mesh,  # noqa: F401
-                   local_mesh, process_contiguous_devices)
+from .mesh import (AXIS_ORDER, gang_mesh,  # noqa: F401
+                   process_contiguous_devices)
 from .sharding import (ShardingRules, logical_sharding,  # noqa: F401
-                       shard_pytree, with_logical_constraint)
+                       logical_spec, with_logical_constraint)
 from .partition_rules import (match_partition_rules,  # noqa: F401
                               named_tree_map, prune_spec, shard_tree,
                               tree_shardings)

@@ -4,101 +4,25 @@ TPU-native design (no reference counterpart — the reference has no mesh
 concept; its parallelism is process groups).  Axis vocabulary follows the
 scaling playbook: ``data`` (DP), ``fsdp`` (sharded optimizer/params over
 DCN or ICI), ``tensor`` (TP over ICI), ``seq`` (context/sequence
-parallel), ``pipeline`` (PP), ``expert`` (MoE).  A MeshSpec names the
-axes and sizes; create_mesh lays devices out so the fastest-varying axes
-(tensor, seq) land on physically adjacent ICI neighbours, which is what
-jax.experimental.mesh_utils optimizes for on real TPU topologies.
+parallel), ``pipeline`` (PP), ``expert`` (MoE).  ``gang_mesh`` is the
+one constructor: it takes the axes a job uses, by name, and lays the
+devices out in process-major C order (its docstring says why not
+``jax.experimental.mesh_utils``).
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
-# ``dcn`` is the outermost (slowest) axis: data-parallel replicas
-# across TPU SLICES communicate over the data-center network, while
-# every axis to its right stays inside a slice on ICI (ref: the
-# multi-slice mesh recipe — gradient all-reduce hierarchically: ICI
-# within a slice, DCN across slices).
+# The one vocabulary of mesh axis names (``gang_mesh`` takes no other):
+# the partition rules and the activation table (parallel/sharding.py)
+# are written in it.  ``dcn`` is the outermost (slowest) axis:
+# data-parallel replicas across TPU SLICES communicate over the
+# data-center network, while every axis to its right stays inside a
+# slice on ICI (ref: the multi-slice mesh recipe — gradient all-reduce
+# hierarchically: ICI within a slice, DCN across slices).
 AXIS_ORDER = ("dcn", "data", "fsdp", "expert", "pipeline", "seq",
               "tensor")
-
-
-@dataclass
-class MeshSpec:
-    """Named parallelism degrees; -1 on one axis means "all remaining"."""
-
-    dcn: int = 1
-    data: int = 1
-    fsdp: int = 1
-    expert: int = 1
-    pipeline: int = 1
-    seq: int = 1
-    tensor: int = 1
-
-    def axes(self) -> Dict[str, int]:
-        return {name: getattr(self, name) for name in AXIS_ORDER}
-
-    def resolve(self, n_devices: int) -> "MeshSpec":
-        sizes = self.axes()
-        wildcard = [k for k, v in sizes.items() if v == -1]
-        if len(wildcard) > 1:
-            raise ValueError("only one axis may be -1")
-        known = 1
-        for k, v in sizes.items():
-            if v != -1:
-                if v <= 0:
-                    raise ValueError(f"axis {k} has invalid size {v}")
-                known *= v
-        if wildcard:
-            if n_devices % known:
-                raise ValueError(
-                    f"{n_devices} devices not divisible by fixed axes "
-                    f"product {known}")
-            sizes[wildcard[0]] = n_devices // known
-        else:
-            if known != n_devices:
-                raise ValueError(
-                    f"mesh {sizes} needs {known} devices, have {n_devices}")
-        return MeshSpec(**sizes)
-
-    def nontrivial_axes(self) -> Tuple[str, ...]:
-        return tuple(k for k, v in self.axes().items() if v > 1)
-
-
-def create_mesh(spec: MeshSpec, devices: Optional[Sequence] = None):
-    """Build a jax.sharding.Mesh for the spec over the given devices
-    (default: all global devices, honoring jax.distributed worlds)."""
-    import jax
-    import numpy as np
-    from jax.sharding import Mesh
-
-    if devices is None:
-        devices = jax.devices()
-    devices = list(devices)
-    spec = spec.resolve(len(devices))
-    sizes = spec.axes()
-    shape = tuple(sizes[a] for a in AXIS_ORDER)
-    try:
-        from jax.experimental import mesh_utils
-
-        # Topology-aware layout on real TPU slices (ICI-adjacent tensor/
-        # seq axes); falls back below for virtual CPU meshes.
-        dev_array = mesh_utils.create_device_mesh(
-            shape, devices=np.asarray(devices))
-    except Exception:
-        dev_array = np.asarray(devices).reshape(shape)
-    return Mesh(dev_array, AXIS_ORDER)
-
-
-def local_mesh(spec: Optional[MeshSpec] = None):
-    """Mesh over this process's addressable devices only (single-host)."""
-    import jax
-
-    devices = jax.local_devices()
-    if spec is None:
-        spec = MeshSpec(data=len(devices))
-    return create_mesh(spec, devices)
 
 
 def process_contiguous_devices() -> List:
@@ -127,14 +51,18 @@ def gang_mesh(axis_sizes: Dict[str, int],
     process-major C-order already lands the fastest (rightmost) axis
     on intra-host ICI, which is what the default fsdp x tensor policy
     wants."""
-    import jax
     import numpy as np
     from jax.sharding import Mesh
 
+    names = tuple(axis_sizes)
+    unknown = [a for a in names if a not in AXIS_ORDER]
+    if unknown:
+        raise ValueError(
+            f"mesh axes {unknown} are not in the vocabulary {AXIS_ORDER}: "
+            "no partition rule or activation rule would ever name them")
     if devices is None:
         devices = process_contiguous_devices()
     devices = list(devices)
-    names = tuple(axis_sizes)
     shape = tuple(int(axis_sizes[a]) for a in names)
     n = 1
     for s in shape:

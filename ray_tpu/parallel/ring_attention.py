@@ -14,7 +14,6 @@ the per-step body so activation memory stays O(seq_local) per device.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -198,18 +197,3 @@ def _ring_attention_flash(q, k, v, *, axis_name: str, causal: bool,
     (_, out, _), _ = jax.lax.scan(step, ((k, v), out0, lse0),
                                   jnp.arange(n))
     return out.astype(q.dtype)
-
-
-def ring_attention_sharded(mesh, q, k, v, *, causal: bool = True,
-                           rules=None):
-    """Convenience wrapper: runs ring_attention under shard_map on
-    ``mesh`` with batch over (data, fsdp) and sequence over ``seq``."""
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    spec = P(("data", "fsdp"), "seq", "tensor", None)
-    fn = shard_map(
-        functools.partial(ring_attention, causal=causal),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False)
-    return fn(q, k, v)

@@ -162,18 +162,17 @@ def global_batch_slice(global_batch_size: int,
 # ===================================================================
 
 def rules_for_model(name: str):
-    """The partition-rule set for a model family by name — the one
-    registry the trainer/bench/CLI surfaces share (lazy imports: the
-    registry itself never pays flax)."""
-    from ..models import PARTITION_RULE_SETS
+    """The partition-rule set of a row of ``models.MODEL_FAMILIES``, by
+    name (lazy import: this module never pays flax)."""
+    from ..models import MODEL_FAMILIES
 
     key = name.lower().replace("-", "").replace("_", "")
-    fn = PARTITION_RULE_SETS.get(key)
-    if fn is None:
+    fam = MODEL_FAMILIES.get(key)
+    if fam is None:
         raise KeyError(
             f"no partition-rule set for model {name!r}; known: "
-            f"{sorted(PARTITION_RULE_SETS)}")
-    return fn()
+            f"{sorted(MODEL_FAMILIES)}")
+    return fam.partition_rules()
 
 
 # ===================================================================
@@ -202,18 +201,10 @@ class DistributedMesh:
     world: int = 1
     group_name: str = ""
 
-    def batch_sharding(self, spec: Any = None):
-        """NamedSharding for batches: batch dim over ``fsdp`` unless a
-        spec says otherwise (pruned to the mesh's real axes)."""
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as PS
-
-        from ..parallel.partition_rules import prune_spec
-
-        spec = PS("fsdp") if spec is None else spec
-        return NamedSharding(self.mesh,
-                             prune_spec(spec,
-                                        mesh_axis_sizes(self.mesh)))
+    def batch_sharding(self):
+        """NamedSharding for batches: the activation table's ``batch``
+        row on this mesh (``fsdp``, on an fsdp x tensor mesh)."""
+        return batch_sharding(self.mesh)
 
     def batch_slice(self, global_batch_size: int) -> Tuple[int, int]:
         """The rows of the global batch THIS rank feeds."""
@@ -277,18 +268,20 @@ def setup_distributed_mesh(*, fsdp: Optional[int] = None,
         per_host = len(devices)
     shape = derive_mesh_shape(world, per_host, fsdp=fsdp,
                               tensor=tensor)
+    from ..parallel.mesh import gang_mesh
+
     mesh = gang_mesh(shape, devices)
     return DistributedMesh(mesh=mesh, axis_sizes=shape, rank=rank,
                            world=world, group_name=gname)
 
 
-def gang_mesh(axis_sizes: Dict[str, int],
-              devices: Optional[List[Any]] = None):
-    """Process-contiguous mesh over the gang (see
-    ``parallel.mesh.gang_mesh`` for the layout invariant)."""
-    from ..parallel.mesh import gang_mesh as _gang_mesh
+def batch_sharding(mesh):
+    """Where a batch's rows lie on ``mesh``: the activation table's
+    ``batch`` row (parallel/sharding.py), the same one the models
+    constrain their activations by."""
+    from ..parallel.sharding import logical_sharding
 
-    return _gang_mesh(axis_sizes, devices)
+    return logical_sharding(mesh, ("batch",))
 
 
 def state_specs(state: Any, rules, *, default: Any = None) -> Any:
@@ -353,36 +346,13 @@ def shard_train_state(state: Any, mesh, rules, *,
     return shard_host_tree(state, mesh, specs), specs
 
 
-def put_global_batch(local_batch: Any, mesh, *, spec: Any = None,
+def put_global_batch(local_batch: Any, mesh, *,
                      global_batch_size: Optional[int] = None) -> Any:
-    """Per-rank batch slice -> ONE global array sharded along the data
-    (``fsdp``) axis.  Single-process: device_put.  Multi-process:
-    ``make_array_from_process_local_data`` — each host contributes
-    only the rows it loaded (``global_batch_slice`` rows), the runtime
-    wires them into the global batch with zero host-side gather."""
-    import jax
-    import numpy as np
-    from jax.sharding import NamedSharding
-    from jax.sharding import PartitionSpec as PS
-
-    from ..parallel.partition_rules import prune_spec
-
-    spec = PS("fsdp") if spec is None else spec
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    sharding = NamedSharding(mesh, prune_spec(spec, sizes))
-    if jax.process_count() == 1:
-        return jax.tree_util.tree_map(
-            lambda x: jax.device_put(x, sharding), local_batch)
-
-    def put(x):
-        x = np.asarray(x)
-        gshape = None
-        if global_batch_size is not None:
-            gshape = (int(global_batch_size),) + x.shape[1:]
-        return jax.make_array_from_process_local_data(sharding, x,
-                                                      gshape)
-
-    return jax.tree_util.tree_map(put, local_batch)
+    """Per-rank batch slice -> ONE global array under
+    ``batch_sharding(mesh)`` (``batch_transfer`` says how)."""
+    return batch_transfer(batch_sharding(mesh),
+                          global_batch_size=global_batch_size)(
+                              local_batch)
 
 
 def batch_transfer(sharding, *,
@@ -390,8 +360,11 @@ def batch_transfer(sharding, *,
                    ) -> Callable[[Any], Any]:
     """The ``transfer`` callable ``iter_device_batches(sharding=...)``
     builds: per-batch placement under a NamedSharding target, safe in
-    both single- and multi-process worlds (no host-side gather — each
-    process ships only its local rows)."""
+    both single- and multi-process worlds.  Single-process:
+    device_put.  Multi-process:
+    ``make_array_from_process_local_data`` — each host contributes
+    only the rows it loaded (``global_batch_slice`` rows), the runtime
+    wires them into the global batch with zero host-side gather."""
     import jax
 
     def transfer(batch):

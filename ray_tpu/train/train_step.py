@@ -3,9 +3,10 @@
 TPU-first: one compiled XLA program per step — loss, grads (via
 jax.value_and_grad through remat'd blocks), optax update, all under a
 single jit with donated state so HBM holds one copy of params+moments.
-Parallelism arrives via the mesh shardings placed on the state by
-``shard_state`` (DP grads become psums XLA inserts from the shardings —
-no hand-written collectives here).
+Parallelism arrives via the shardings the state is placed under
+(``train.distributed.fitted_state_specs`` from a family's partition
+rules; DP grads become psums XLA inserts from the shardings — no
+hand-written collectives here).
 """
 
 from __future__ import annotations
@@ -70,24 +71,6 @@ def make_train_step(loss_fn: Callable, optimizer, has_aux: bool = False
                           opt_state=opt_state), metrics
 
     return step
-
-
-def shard_state(state: TrainState, mesh, param_axes_fn, rules=None
-                ) -> TrainState:
-    """Place params AND optimizer moments with the param sharding rules
-    (moments mirror param shapes, so the same logical axes apply)."""
-    from ..parallel.sharding import shard_pytree
-
-    params = shard_pytree(state.params, mesh, param_axes_fn, rules)
-
-    def opt_axes(path: str, leaf):
-        # Moment tensors repeat the param path inside the optax tree.
-        return param_axes_fn(path, leaf)
-
-    opt_state = jax.tree_util.tree_map(
-        lambda x: x, state.opt_state)  # structural copy
-    opt_state = shard_pytree(opt_state, mesh, opt_axes, rules)
-    return TrainState(step=state.step, params=params, opt_state=opt_state)
 
 
 def make_sharded_train_step(loss_fn, optimizer, mesh=None,

@@ -10,7 +10,9 @@ the test suite fails on (tests/test_perf_ledger.py).
 
 Record with:
   python -m ray_tpu.util.microbenchmark --record [--quick]
-  python bench.py --record            (and --long-context --record)
+The ``scale/`` and ``bench/`` rows were written by two scripts that are
+gone (PR 28); they stay in ``PERF.jsonl`` as data, judged by their
+floors below, until ROADMAP Design 4 takes this ledger up.
 """
 
 from __future__ import annotations
@@ -60,9 +62,8 @@ FLOORS: Dict[str, "tuple[float, int]"] = {
     # VERDICT "ledger floor should ratchet to the real target") with
     # headroom under the >=26 ops/s measured bar.
     "scale/many_actors_50": (10.0, 7),
-    # r8 LLM inference plane: bench.py --serve-llm streams a tiny
-    # GPT-2 through the continuous-batching engine at saturating
-    # concurrency (8 clients).  Measured ~900-1000 tokens/s on the
+    # r8 LLM inference plane: a tiny GPT-2 streamed through the
+    # continuous-batching engine at saturating concurrency (8 clients).  Measured ~900-1000 tokens/s on the
     # 1-core CI box; 150 keeps the usual noisy-neighbor headroom while
     # pinning that the serving path stays an order of magnitude above
     # a sequential (batch-of-1) decode loop.  TTFT percentiles are

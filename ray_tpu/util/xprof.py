@@ -11,9 +11,9 @@ This module harvests those numbers (``register_compiled``), attributes
 each collective to the mesh axes its replica groups span, and combines
 the static program facts with measured step time into a roofline
 report: achieved vs attainable FLOP/s at the program's arithmetic
-intensity, per-axis collective byte/time shares, and a step
-decomposition that reproduces MFU_ANALYSIS.md's hand-measured table
-automatically (``measure_step_decomposition``).
+intensity, per-axis collective byte/time shares, and a forward /
+backward / optimizer step decomposition
+(``measure_step_decomposition``).
 
 Layering matters here: everything above the "jax layer" marker is
 plain Python over plain dicts — no jax, no aiohttp, no cluster (the
@@ -638,13 +638,12 @@ def measure_step_decomposition(loss_fn, optimizer, state, batch, *,
                                flops_per_step: Optional[float] = None,
                                peak_flops: Optional[float] = None
                                ) -> Dict[str, Any]:
-    """MFU_ANALYSIS.md's hand-measured step decomposition, automated:
-    forward / backward / optimizer seconds via differenced
-    state-carried ``lax.scan`` loops.
+    """The step's decomposition: forward / backward / optimizer
+    seconds via differenced state-carried ``lax.scan`` loops.
 
-    The measurement trap the hand analysis documents: a loop-invariant
-    body gets const-hoisted by XLA (a ~10x optimistic "forward
-    time"), so every segment loop THREADS state through the scan —
+    The measurement trap: a loop-invariant body gets const-hoisted by
+    XLA (a ~10x optimistic "forward time"), so every segment loop
+    THREADS state through the scan —
     the forward loop folds the previous loss into the batch, the grad
     loop additionally consumes the gradients through their norm, and
     the full loop carries the real TrainState.

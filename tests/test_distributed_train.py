@@ -309,6 +309,7 @@ sys.path.insert(0, {repo!r})
 import jax
 import numpy as np
 from ray_tpu.models.gpt2 import GPT2Config, gpt2_init, gpt2_loss_fn
+from ray_tpu.parallel.mesh import gang_mesh
 from ray_tpu.parallel.partition_rules import tree_shardings
 from ray_tpu.train import distributed as dist
 from ray_tpu.train.train_step import (TrainState, make_optimizer,
@@ -317,7 +318,7 @@ cfg = GPT2Config(**{cfg!r})
 optimizer = make_optimizer(**{opt!r})
 state = TrainState.create(gpt2_init(cfg, jax.random.PRNGKey(0)),
                           optimizer)
-mesh = dist.gang_mesh({{"fsdp": 2, "tensor": 2}})
+mesh = gang_mesh({{"fsdp": 2, "tensor": 2}})
 state, specs = dist.shard_train_state(state, mesh,
                                       dist.rules_for_model("gpt2"))
 dm = dist.DistributedMesh(mesh=mesh,

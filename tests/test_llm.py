@@ -417,6 +417,39 @@ def test_llama_incremental_decode_token_identical():
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_cached_forward_goes_through_the_one_attention_core(
+        family, monkeypatch):
+    """Both blocks hand the paged cache to models/attention.py: each
+    layer's store and attend are called from there, once, and nowhere
+    else (what they compute is pinned above)."""
+    import sys
+
+    import jax
+
+    from ray_tpu.llm import kv_cache
+    from ray_tpu.models import MODEL_FAMILIES
+
+    callers = []
+    for name in ("paged_store", "paged_attend"):
+        real = getattr(kv_cache, name)
+
+        def spy(*args, _real=real, _name=name):
+            callers.append(
+                (_name, sys._getframe(1).f_globals["__name__"]))
+            return _real(*args)
+
+        monkeypatch.setattr(kv_cache, name, spy)
+    fam = MODEL_FAMILIES[family]
+    cfg = dataclasses.replace(fam.tiny(), remat=False)
+    params = fam.init(cfg, jax.random.PRNGKey(0))
+    _decode_loop(fam.module(cfg), params, cfg, fam.kv_heads(cfg),
+                 [3, 17, 42], 1)
+    assert callers == [("paged_store", "ray_tpu.models.attention"),
+                       ("paged_attend", "ray_tpu.models.attention")
+                       ] * cfg.n_layer
+
+
 # --------------------------------------------- the pool stays in place
 
 

@@ -9,12 +9,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.gpt2 import (GPT2, GPT2Config, gpt2_init, gpt2_loss_fn,
-                                 gpt2_param_axes)
+from ray_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_init, gpt2_loss_fn
 from ray_tpu.models.llama import (Llama, LlamaConfig, llama_init,
                                   llama_loss_fn)
 from ray_tpu.train.train_step import (TrainState, make_optimizer,
-                                      make_sharded_train_step, shard_state)
+                                      make_sharded_train_step)
 
 
 def _batch(cfg, batch=4, key=0):
@@ -49,22 +48,28 @@ def test_gpt2_loss_decreases():
 
 
 def test_gpt2_sharded_training_step():
-    from ray_tpu.parallel import MeshSpec, create_mesh
-    from ray_tpu.parallel.sharding import ShardingRules, logical_sharding
+    from ray_tpu.parallel import gang_mesh
+    from ray_tpu.parallel.partition_rules import tree_shardings
+    from ray_tpu.train import distributed as dist
 
-    mesh = create_mesh(MeshSpec(data=2, seq=2, tensor=2))
-    rules = ShardingRules()
-    cfg = dataclasses.replace(GPT2Config.tiny(), mesh=mesh, rules=rules,
+    mesh = gang_mesh({"data": 2, "seq": 2, "tensor": 2})
+    cfg = dataclasses.replace(GPT2Config.tiny(), mesh=mesh,
                               attn_impl="ring", dtype=jnp.float32)
-    params = gpt2_init(cfg, jax.random.PRNGKey(0))
     opt = make_optimizer(total_steps=10)
-    state = shard_state(TrainState.create(params, opt), mesh,
-                        gpt2_param_axes, rules)
+    # Placed as the trainer places it: the family's partition rules,
+    # fitted to this mesh.
+    state, specs = dist.shard_train_state(
+        TrainState.create(gpt2_init(cfg, jax.random.PRNGKey(0)), opt),
+        mesh, dist.rules_for_model("gpt2"))
+    c_attn = state.params["params"]["h_0"]["c_attn"]["kernel"]
+    assert c_attn.sharding.spec == jax.sharding.PartitionSpec(
+        None, "tensor")
     step = make_sharded_train_step(
-        lambda p, b: gpt2_loss_fn(cfg, p, b), opt)
-    tokens = jax.device_put(
-        _batch(cfg)["tokens"],
-        logical_sharding(mesh, ("batch", None), rules))
+        lambda p, b: gpt2_loss_fn(cfg, p, b), opt, mesh=mesh,
+        state_shardings=tree_shardings(mesh, specs),
+        batch_sharding=dist.batch_sharding(mesh))
+    tokens = jax.device_put(_batch(cfg)["tokens"],
+                            dist.batch_sharding(mesh))
     state, metrics = step(state, {"tokens": tokens})
     assert np.isfinite(float(metrics["loss"]))
     # Ring attention must equal the dense path.
@@ -73,6 +78,28 @@ def test_gpt2_sharded_training_step():
     ring_loss = gpt2_loss_fn(cfg, state.params, _batch(cfg))
     np.testing.assert_allclose(float(dense_loss), float(ring_loss),
                                rtol=2e-4)
+
+
+def test_llama_flash_agrees_with_dense():
+    """Both blocks share one attention core (models/attention.py), so
+    the Llama block has the flash kernel GPT-2 trains with (interpret
+    mode here), GQA heads repeated before it; loss and gradients agree
+    with dense."""
+    cfg = dataclasses.replace(LlamaConfig.tiny(), attn_impl="flash",
+                              remat=False, dtype=jnp.float32)
+    assert cfg.n_kv_head < cfg.n_head
+    params = llama_init(cfg, jax.random.PRNGKey(0))
+    batch = _batch(cfg, batch=2)
+    dense = dataclasses.replace(cfg, attn_impl="dense")
+    loss_f, grads_f = jax.value_and_grad(
+        lambda p: llama_loss_fn(cfg, p, batch))(params)
+    loss_d, grads_d = jax.value_and_grad(
+        lambda p: llama_loss_fn(dense, p, batch))(params)
+    np.testing.assert_allclose(float(loss_f), float(loss_d), rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(grads_f),
+                    jax.tree_util.tree_leaves(grads_d)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-5, rtol=2e-4)
 
 
 def test_llama_forward_and_loss():

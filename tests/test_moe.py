@@ -8,8 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.gpt2 import (GPT2Config, gpt2_init, gpt2_loss_fn,
-                                 gpt2_param_axes)
+from ray_tpu.models.gpt2 import GPT2Config, gpt2_init, gpt2_loss_fn
 from ray_tpu.ops.moe import MoEMLP, moe_layers, moe_losses
 
 
@@ -185,30 +184,34 @@ def test_gpt2_moe_trains():
 
 def test_gpt2_moe_expert_parallel_mesh():
     """Full sharded train step with a real expert mesh axis on the
-    8-device virtual CPU mesh (DP x EP x TP)."""
-    from ray_tpu.parallel import MeshSpec, create_mesh
-    from ray_tpu.parallel.sharding import ShardingRules, logical_sharding
-    from ray_tpu.train.train_step import (TrainState, make_optimizer,
-                                          make_sharded_train_step,
-                                          shard_state)
+    8-device virtual CPU mesh (DP x EP x TP), the state placed by the
+    family's partition rules."""
+    from jax.sharding import PartitionSpec as P
 
-    mesh = create_mesh(MeshSpec(data=2, expert=2, tensor=2))
-    rules = ShardingRules()
+    from ray_tpu.parallel import gang_mesh
+    from ray_tpu.parallel.partition_rules import tree_shardings
+    from ray_tpu.train import distributed as dist
+    from ray_tpu.train.train_step import (TrainState, make_optimizer,
+                                          make_sharded_train_step)
+
+    mesh = gang_mesh({"data": 2, "expert": 2, "tensor": 2})
     cfg = GPT2Config(vocab_size=128, n_layer=2, n_head=4, d_model=64,
                      d_ff=128, max_seq=32, remat=True, mesh=mesh,
-                     rules=rules, moe_num_experts=4, moe_every=2)
-    params = gpt2_init(cfg, jax.random.PRNGKey(0))
+                     moe_num_experts=4, moe_every=2)
     opt = make_optimizer(total_steps=10)
-    state = TrainState.create(params, opt)
-    state = shard_state(state, mesh, gpt2_param_axes, rules)
+    state, specs = dist.shard_train_state(
+        TrainState.create(gpt2_init(cfg, jax.random.PRNGKey(0)), opt),
+        mesh, dist.rules_for_model("gpt2"))
     # Expert weights are actually sharded over the expert axis.
     w_in = state.params["params"]["h_1"]["moe_mlp"]["w_in"]
-    assert "expert" in str(w_in.sharding.spec)
+    assert w_in.sharding.spec == P("expert", None, "tensor")
+    assert not w_in.sharding.is_fully_replicated
     step = make_sharded_train_step(
-        lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=0), opt, mesh)
-    tokens = jax.device_put(
-        jnp.zeros((4, 33), jnp.int32),
-        logical_sharding(mesh, ("batch", None), rules))
+        lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=0), opt,
+        mesh=mesh, state_shardings=tree_shardings(mesh, specs),
+        batch_sharding=dist.batch_sharding(mesh))
+    tokens = jax.device_put(jnp.zeros((4, 33), jnp.int32),
+                            dist.batch_sharding(mesh))
     state, metrics = step(state, {"tokens": tokens})
     jax.block_until_ready(metrics)
     assert np.isfinite(float(metrics["loss"]))

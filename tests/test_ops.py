@@ -103,7 +103,7 @@ def test_chunked_xent_matches_plain():
     # fused custom_vjp backward recomputes logits chunk-wise and folds
     # softmax-minus-onehot into the grad einsums, so per-element
     # rounding differs from the autodiff whole-logits path (measured
-    # <=0.2% of the peak gradient magnitude; see MFU_ANALYSIS.md).
+    # <=0.2% of the peak gradient magnitude).
     g1 = jax.grad(lambda p: gpt2_loss_fn(cfg, p, {"tokens": toks},
                                          loss_chunk=0))(params)
     g2 = jax.grad(lambda p: gpt2_loss_fn(cfg, p, {"tokens": toks},

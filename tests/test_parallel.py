@@ -9,9 +9,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.parallel import (MeshSpec, create_mesh, pipeline_apply,
+from ray_tpu.parallel import (AXIS_ORDER, gang_mesh, pipeline_apply,
                               ring_attention, ulysses_attention)
-from ray_tpu.parallel.sharding import ShardingRules, logical_sharding
+from ray_tpu.parallel.sharding import (DEFAULT_RULES, logical_sharding,
+                                       logical_spec)
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
@@ -27,32 +28,95 @@ def dense_attention(q, k, v, causal=True):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(p.dtype)).astype(q.dtype)
 
 
-def test_mesh_spec_resolve():
-    spec = MeshSpec(data=-1, tensor=2).resolve(8)
-    assert spec.data == 4 and spec.tensor == 2
-    with pytest.raises(ValueError):
-        MeshSpec(data=3).resolve(8)
-
-
-def test_create_mesh_axes():
-    mesh = create_mesh(MeshSpec(data=2, tensor=4))
+def test_gang_mesh_axes():
+    mesh = gang_mesh({"data": 2, "tensor": 4})
     assert mesh.shape["data"] == 2 and mesh.shape["tensor"] == 4
-    assert set(mesh.axis_names) == {"dcn", "data", "fsdp", "expert",
-                                    "pipeline", "seq", "tensor"}
+    assert mesh.axis_names == ("data", "tensor")
+    # Process-major C order: the rightmost axis varies fastest.
+    assert [d.id for d in mesh.devices.ravel()] == sorted(
+        d.id for d in jax.devices())
+
+
+@pytest.mark.parametrize("axes,why", [
+    ({"data": 2, "model": 4}, "not in the vocabulary"),
+    ({"data": 3}, "needs 3 devices"),
+], ids=["unknown_axis", "wrong_device_count"])
+def test_gang_mesh_refuses(axes, why):
+    """One vocabulary (AXIS_ORDER: the names the partition rules and the
+    activation table are written in) and every device accounted for."""
+    assert "model" not in AXIS_ORDER
+    with pytest.raises(ValueError, match=why):
+        gang_mesh(axes)
 
 
 def test_sharding_rules_prune():
-    mesh = create_mesh(MeshSpec(data=8))
+    mesh = gang_mesh({"data": 8})
     sh = logical_sharding(mesh, ("batch", "embed"))
-    assert sh.spec == P(("data",), None)
-    sh2 = logical_sharding(mesh, ("batch", "mlp"))  # tensor axis size 1
-    assert sh2.spec == P(("data",), None)
+    assert sh.spec == P("data")
+    sh2 = logical_sharding(mesh, ("batch", "mlp"))  # no tensor axis
+    assert sh2.spec == P("data")
+    # Fitted to the array as well: an axis that does not divide its dim
+    # leaves the dim whole (25 heads, a vocabulary of 50257).
+    mesh = gang_mesh({"fsdp": 2, "seq": 1, "tensor": 4})
+    heads = ("batch", "seq", "heads", None)
+    assert logical_spec(mesh, heads) == P("fsdp", None, "tensor")
+    assert logical_spec(mesh, heads, (4, 8, 25, 64)) == P("fsdp")
+    assert logical_spec(mesh, heads, (3, 8, 12, 64)) == P(None, None,
+                                                          "tensor")
+
+
+@pytest.mark.parametrize("fsdp,tensor", [(1, 1), (2, 2), (4, 1)])
+def test_batch_sharding_on_the_cells_meshes(fsdp, tensor):
+    """The table's ``batch`` row, fitted to the trainer's fsdp x tensor
+    mesh, is the batch over ``fsdp``: what ``global_batch_slice`` and
+    the benchmark's compile tool assume."""
+    from jax.sharding import NamedSharding
+
+    from ray_tpu.train.distributed import DistributedMesh
+
+    axes = {"fsdp": fsdp, "tensor": tensor}
+    mesh = gang_mesh(axes, jax.devices()[:fsdp * tensor])
+    got = DistributedMesh(mesh=mesh, axis_sizes=axes).batch_sharding()
+    assert got.is_equivalent_to(NamedSharding(mesh, P("fsdp")), 2)
+
+
+# The output dimension of each family's first attention, MLP and
+# embedding weight: (path in the tiny preset's params, dim, logical name).
+_OUTPUT_DIMS = {
+    "gpt2": [("h_0/c_attn/kernel", 1, "heads"),
+             ("h_0/mlp_in/kernel", 1, "mlp"), ("wte", 0, "vocab")],
+    "llama": [("layer_0/wq/kernel", 1, "heads"),
+              ("layer_0/w_gate/kernel", 1, "mlp"), ("embed", 0, "vocab")],
+    "olmoe": [("layer_0/wq/kernel", 1, "heads"),
+              ("layer_0/moe/w_gate", 2, "mlp"), ("embed", 0, "vocab")],
+}
+
+
+@pytest.mark.parametrize("family", sorted(_OUTPUT_DIMS))
+def test_rules_and_table_agree(family):
+    """The weights' description (a family's partition rules) and the
+    activations' (the table) are two tables that must tell one story: a
+    weight's output dimension lies on the mesh axis the table gives the
+    activation it produces."""
+    from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.parallel.partition_rules import match_partition_rules
+    from ray_tpu.train.distributed import rules_for_model
+
+    fam = MODEL_FAMILIES[family]
+    cfg = fam.tiny()
+    params = jax.eval_shape(lambda: fam.init(cfg, jax.random.PRNGKey(0)))
+    specs = match_partition_rules(rules_for_model(family), params)
+    for path, dim, logical in _OUTPUT_DIMS[family]:
+        spec = specs["params"]
+        for key in path.split("/"):
+            spec = spec[key]
+        assert spec[dim] == DEFAULT_RULES[logical], (path, spec, logical)
 
 
 @pytest.mark.parametrize("impl", ["flash", "lax"])
 @pytest.mark.parametrize("causal", [True, False])
 def test_ring_attention_matches_dense(causal, impl):
-    mesh = create_mesh(MeshSpec(seq=4, data=2))
+    mesh = gang_mesh({"data": 2, "seq": 4})
     b, t, h, d = 2, 32, 4, 16
     key = jax.random.PRNGKey(0)
     q, k, v = jax.random.normal(key, (3, b, t, h, d), jnp.float32)
@@ -70,7 +134,7 @@ def test_ring_attention_matches_dense(causal, impl):
 
 @pytest.mark.parametrize("impl", ["flash", "lax"])
 def test_ring_attention_gradients(impl):
-    mesh = create_mesh(MeshSpec(seq=4, data=-1))
+    mesh = gang_mesh({"data": 2, "seq": 4})
     b, t, h, d = 1, 16, 2, 8
     q, k, v = jax.random.normal(jax.random.PRNGKey(1), (3, b, t, h, d))
 
@@ -94,7 +158,7 @@ def test_ring_attention_gradients(impl):
 
 
 def test_ulysses_matches_dense():
-    mesh = create_mesh(MeshSpec(seq=4, data=-1))
+    mesh = gang_mesh({"data": 2, "seq": 4})
     b, t, h, d = 2, 32, 8, 16  # heads divisible by seq axis
     q, k, v = jax.random.normal(jax.random.PRNGKey(2), (3, b, t, h, d))
 
@@ -109,7 +173,7 @@ def test_ulysses_matches_dense():
 
 
 def test_pipeline_matches_sequential():
-    mesh = create_mesh(MeshSpec(pipeline=4, data=-1))
+    mesh = gang_mesh({"data": 2, "pipeline": 4})
     s, b, dim = 4, 8, 16
     keys = jax.random.split(jax.random.PRNGKey(3), s)
     ws = jnp.stack([jax.random.normal(k, (dim, dim)) * 0.3 for k in keys])
@@ -132,7 +196,7 @@ def test_pipeline_matches_sequential():
 
 
 def test_pipeline_gradients_flow():
-    mesh = create_mesh(MeshSpec(pipeline=4, data=-1))
+    mesh = gang_mesh({"data": 2, "pipeline": 4})
     s, b, dim = 4, 8, 8
     ws = jax.random.normal(jax.random.PRNGKey(5), (s, dim, dim)) * 0.3
     x = jax.random.normal(jax.random.PRNGKey(6), (b, dim))
@@ -160,49 +224,100 @@ def test_pipeline_gradients_flow():
                                atol=2e-5, rtol=2e-5)
 
 
-# One sharded train step per MeshSpec layout over the 8-device CPU mesh
-# (what the old driver entry point's multi-chip dry run guarded, without
-# its subprocess): DP x SP x TP with ring attention, DP x EP x TP with
-# MoE blocks, and a dcn axis stacked over data x tensor.
+# One sharded train step per mesh layout over the 8-device CPU mesh,
+# built and placed as the cells' trainer does it (gang_mesh, the
+# family's partition rules fitted to the mesh, state born sharded, the
+# step pinned to those shardings): DP x SP x TP with ring attention,
+# DP x EP x TP with MoE blocks, and a dcn axis stacked over data x
+# tensor.
 _MESH_LAYOUTS = {
-    "dp_sp_tp": (MeshSpec(data=2, fsdp=1, seq=2, tensor=2),
+    "dp_sp_tp": ({"data": 2, "seq": 2, "tensor": 2},
                  dict(vocab_size=512, n_layer=2, n_head=4, d_model=128,
                       d_ff=256, max_seq=64, attn_impl="ring"), 4),
-    "dp_ep_tp": (MeshSpec(data=2, expert=2, tensor=2),
+    "dp_ep_tp": ({"data": 2, "expert": 2, "tensor": 2},
                  dict(vocab_size=256, n_layer=2, n_head=4, d_model=64,
                       d_ff=128, max_seq=32, moe_num_experts=4,
                       moe_every=2), 4),
-    "dcn_dp_tp": (MeshSpec(dcn=2, data=2, tensor=2),
+    "dcn_dp_tp": ({"dcn": 2, "data": 2, "tensor": 2},
                   dict(vocab_size=256, n_layer=2, n_head=4, d_model=64,
                        d_ff=128, max_seq=32), 8),
 }
 
 
+def sharded_step(cfg, mesh, optimizer, family="gpt2", loss_chunk=0):
+    """(state, step, batch_sharding) on ``mesh``, as
+    benchmark/harness/train_runner.py makes them."""
+    from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.parallel.partition_rules import tree_shardings
+    from ray_tpu.train import distributed as dist
+    from ray_tpu.train.train_step import (TrainState,
+                                          make_sharded_train_step)
+
+    fam = MODEL_FAMILIES[family]
+
+    def create(key):
+        return TrainState.create(fam.init(cfg, key), optimizer)
+
+    key = jax.random.PRNGKey(0)
+    specs = dist.fitted_state_specs(jax.eval_shape(create, key), mesh,
+                                    dist.rules_for_model(family))
+    shardings = tree_shardings(mesh, specs)
+    state = jax.jit(create, out_shardings=shardings)(key)
+    batch_sharding = dist.batch_sharding(mesh)
+    step = make_sharded_train_step(
+        lambda p, b: fam.loss(cfg, p, b, loss_chunk=loss_chunk),
+        optimizer, mesh=mesh, state_shardings=shardings,
+        batch_sharding=batch_sharding)
+    return state, step, batch_sharding
+
+
 @pytest.mark.parametrize("layout", sorted(_MESH_LAYOUTS))
 def test_sharded_train_step_on_meshspec_axes(layout):
-    from ray_tpu.models.gpt2 import (GPT2Config, gpt2_init, gpt2_loss_fn,
-                                     gpt2_param_axes)
-    from ray_tpu.train.train_step import (TrainState, make_optimizer,
-                                          make_sharded_train_step,
-                                          shard_state)
+    from ray_tpu.models.gpt2 import GPT2Config
+    from ray_tpu.train.train_step import make_optimizer
 
-    spec, model_kw, batch = _MESH_LAYOUTS[layout]
-    mesh = create_mesh(spec, jax.devices()[:8])
-    rules = ShardingRules()
-    cfg = GPT2Config(mesh=mesh, rules=rules, remat=True, **model_kw)
-    optimizer = make_optimizer(total_steps=10, warmup_steps=2)
-    state = shard_state(
-        TrainState.create(gpt2_init(cfg, jax.random.PRNGKey(0)),
-                          optimizer),
-        mesh, gpt2_param_axes, rules)
-    step = make_sharded_train_step(
-        lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=0), optimizer,
-        mesh)
+    axes, model_kw, batch = _MESH_LAYOUTS[layout]
+    mesh = gang_mesh(axes)
+    cfg = GPT2Config(mesh=mesh, remat=True, **model_kw)
+    state, step, batch_sharding = sharded_step(
+        cfg, mesh, make_optimizer(total_steps=10, warmup_steps=2))
+    if layout == "dp_ep_tp":
+        w_in = state.params["params"]["h_1"]["moe_mlp"]["w_in"]
+        assert w_in.sharding.spec == P("expert", None, "tensor")
     tokens = jax.device_put(
-        jnp.zeros((batch, cfg.max_seq + 1), jnp.int32),
-        logical_sharding(mesh, ("batch", None), rules))
+        jnp.zeros((batch, cfg.max_seq + 1), jnp.int32), batch_sharding)
+    # The batch lies where the model constrains its activations to lie.
+    assert batch_sharding.spec == logical_spec(mesh, ("batch",))
     state, metrics = step(state, {"tokens": tokens})
     assert np.isfinite(float(metrics["loss"])), metrics
     # The executable that ran is the ahead-of-time one, compiled for
     # this mesh.
     assert step.compiled() is not None
+
+
+def test_sharded_flash_step_with_heads_the_tensor_axis_does_not_divide():
+    """Three heads over tensor=2: the flash kernel's shard_map spec is
+    fitted to the array like every other activation's (the heads stay
+    whole, as GPT-2's vocabulary does), so the step compiles, and it
+    computes what the unsharded step computes."""
+    import dataclasses
+
+    from ray_tpu.models.gpt2 import GPT2Config
+    from ray_tpu.train.train_step import make_optimizer
+
+    mesh = gang_mesh({"fsdp": 2, "tensor": 2}, jax.devices()[:4])
+    plain = GPT2Config(vocab_size=256, n_layer=2, n_head=3, d_model=96,
+                       d_ff=192, max_seq=128, attn_impl="flash",
+                       dtype=jnp.float32)
+    optimizer = make_optimizer(total_steps=10, warmup_steps=2)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 129), 0, 256,
+                                jnp.int32)
+    losses = {}
+    for name, m in (("one", gang_mesh({"fsdp": 1}, jax.devices()[:1])),
+                    ("four", mesh)):
+        cfg = dataclasses.replace(plain, mesh=m if m.size > 1 else None)
+        state, step, batch_sharding = sharded_step(cfg, m, optimizer)
+        _, metrics = step(
+            state, {"tokens": jax.device_put(tokens, batch_sharding)})
+        losses[name] = float(metrics["loss"])
+    np.testing.assert_allclose(losses["four"], losses["one"], rtol=1e-5)

@@ -10,14 +10,11 @@ finders.  One subprocess test compiles a real sharded train step over
 a 4-virtual-device fsdp x tensor mesh and asserts the harvested
 collectives land nonzero bytes on BOTH axes.
 
-Slow half: ``python bench.py --fsdp`` end to end (2-process gloo gang)
-asserting the member reports both axis shares and the parent drops the
-CPU MFU row, plus the automated step decomposition agreeing with
-MFU_ANALYSIS.md's hand-measured structure (optimizer ~free; of-peak
-ratios only judged on a real accelerator).
+Slow half: the automated step decomposition's structure (optimizer
+~free, backward over forward; of-peak ratios only judged on a real
+accelerator).
 """
 
-import json
 import os
 import subprocess
 import sys
@@ -490,35 +487,13 @@ def test_sharded_step_registers_collectives_on_both_axes():
     assert "AXES_OK" in out.stdout, out.stderr[-4000:] + out.stdout
 
 
-# ------------------------------------------------ slow: bench paths
+# ------------------------------------------------ slow
 @pytest.mark.slow
-def test_fsdp_bench_reports_axis_shares_and_drops_cpu_mfu():
-    """`python bench.py --fsdp` (the real 2-process gloo gang): the
-    member harvests per-axis collective shares from its own timed
-    executable, BOTH mesh axes come back nonzero, and the parent emits
-    no MFU key on a CPU gang (the honesty half of the satellite)."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--fsdp"],
-        capture_output=True, text=True, timeout=580,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert out.returncode == 0, out.stdout[-4000:] + out.stderr[-2000:]
-    row = json.loads(out.stdout.strip().splitlines()[-1])
-    assert row["metric"] == "train_fsdp_tokens_per_sec"
-    assert row["platform"] == "cpu"
-    assert "mfu" not in row
-    shares = row["axis_shares"]
-    assert shares.get("fsdp", 0.0) > 0.0, shares
-    assert shares.get("tensor", 0.0) > 0.0, shares
-    assert sum(shares.values()) == pytest.approx(1.0, abs=0.01)
-
-
-@pytest.mark.slow
-def test_step_decomposition_agrees_with_mfu_analysis():
-    """The automated decomposition reproduces MFU_ANALYSIS.md's
-    structure on the bench config: segments sum to the full step,
-    the optimizer is ~free, and backward outweighs forward (remat).
-    Of-peak ratios are only judged against a real accelerator's peak
-    (the ~35% forward claim); on CPU they are structural only."""
+def test_step_decomposition_has_the_steps_structure():
+    """The automated decomposition on the GPT-2 124M config: segments
+    sum to the full step, the optimizer is ~free, and backward
+    outweighs forward (remat).  Of-peak ratios are only judged against
+    a real accelerator's peak; on CPU they are structural only."""
     import jax
 
     from ray_tpu.models.gpt2 import (GPT2Config, gpt2_init,
@@ -555,13 +530,13 @@ def test_step_decomposition_agrees_with_mfu_analysis():
     sh = d["shares"]
     assert sh["forward"] + sh["backward"] + sh["optimizer"] == \
         pytest.approx(1.0, abs=0.05)
-    # MFU_ANALYSIS: "the optimizer is ~free" — it is an elementwise
-    # pass over params, dwarfed by the matmul fwd/bwd.
+    # The optimizer is ~free: an elementwise pass over params,
+    # dwarfed by the matmul fwd/bwd.
     assert sh["optimizer"] < 0.15, d
     # Remat makes backward strictly heavier than forward.
     assert d["backward_s"] > d["forward_s"], d
     if on_accel:
-        # The hand analysis pins forward at ~35% of peak on the bench
+        # Forward ran at ~35% of peak when measured by hand on this
         # config; hold the automated number to the same ballpark.
         assert 0.15 < d["of_peak"]["forward"] < 0.60, d
         assert d["of_peak"]["full_step"] > 0.10, d
