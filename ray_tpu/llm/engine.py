@@ -268,6 +268,18 @@ class GenerationEngine:
         # search; the others were argmax alone (stats()["sampling"]).
         self._sampling = {"rows_greedy": 0, "rows_sampled": 0,
                           "steps": 0, "steps_sampled": 0}
+        # What the decode steps' attention reads of the pool, counted
+        # from the packed rows (stats()["attention"]): ``kv_rows_read``
+        # is what the paged-decode kernel's copies move (each running
+        # row's whole pages up to its length, this step's token
+        # included, times the layers; one row = one position's K and V
+        # of a layer, ``kv_row_bytes``),
+        # ``kv_rows_held`` what a gather of every row's whole page table
+        # moves (max_batch x pages_per_seq x page_size x layers a run).
+        self._attention = {
+            "decode_runs": 0, "kv_rows_read": 0, "kv_rows_held": 0,
+            "kv_row_bytes": 2 * n_kv * head_dim
+            * self._kv["k_pages"].dtype.itemsize}
         # The engine thread sums a step's leaves in _pending and adds
         # them to the totals with the step's own time in one go, under
         # the lock, so that a stats() taken mid-step still sums up.
@@ -464,6 +476,7 @@ class GenerationEngine:
                 # pickers) by name -> compile seconds.
                 "programs": dict(self._compile_seconds),
                 "sampling": dict(self._sampling),
+                "attention": dict(self._attention),
                 "device": dict(self._device),
                 # TTFT phase + TPOT accounting.
                 "ttft_requests": self._ttft_requests,
@@ -735,10 +748,18 @@ class GenerationEngine:
             tokens = np.zeros((B, 1), np.int32)
             positions = np.full((B, 1), -1, np.int32)
             table = np.zeros((B, self._pages_per_seq), np.int32)
+            pages_read = 0
             for i, seq in enumerate(batch):
                 tokens[i, 0] = seq.tokens[-1]
                 positions[i, 0] = seq.n_cached
                 table[i] = self._page_table_row(seq)
+                pages_read += pages_for(seq.n_cached + 1,
+                                        self.cfg.page_size)
+            rows = self.cfg.page_size * self.model_cfg.n_layer
+            counts = self._attention
+            counts["decode_runs"] += 1
+            counts["kv_rows_read"] += pages_read * rows
+            counts["kv_rows_held"] += table.size * rows
             sampling = self._pack_sampling(batch)
         with self._phase("llm.decode.run"):
             logits, k, v, *moe = self._call_fwd(

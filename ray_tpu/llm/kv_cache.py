@@ -15,12 +15,19 @@ bounded by one partial page per sequence.  Because the pool shape is static, the
 compiles once — admission/retirement only edits page tables and host
 accounting.
 
-Two pure jnp helpers implement the data path (used by the models'
-decode-mode forwards): ``paged_store`` scatters fresh K/V into pages,
-``paged_attend`` gathers a batch's pages and runs masked attention.
-Both take the WHOLE pool and a layer index, and the forward carries
-that one pool from layer to layer: slicing a layer out and stacking
-the layers again makes XLA build a new pool beside the donated one.
+Three functions implement the data path, called from the one attention
+core (``models/attention.py``, cached branch), once a layer each:
+``paged_store`` scatters fresh K/V into pages; then, for a decode step
+(one query row a sequence) on the ``tpu`` backend,
+``ops/paged_attention.py paged_decode``, a Pallas kernel, reads each
+sequence's pages where they lie, through the page table and up to the
+sequence's length; for a prefill, and on every other backend,
+``paged_attend`` (pure jnp, here) gathers the batch's pages and runs
+masked attention: it is the kernel's plain definition and what the
+kernel is tested against.  All three take the WHOLE pool and a layer
+index, and the forward carries that one pool from layer to layer:
+slicing a layer out and stacking the layers again makes XLA build a new
+pool beside the donated one.
 ``PagePool`` is the host-side allocator; it exports
 ``rt_llm_kv_pages_{used,total}`` gauges on every alloc/free so KV
 occupancy is visible in ``rt telemetry`` and the doctor can see leaks.
@@ -40,7 +47,7 @@ def init_cache(n_layer: int, num_pages: int, page_size: int,
     """Preallocate the pooled K/V buffers, each
     [n_layer, num_pages, page_size, n_kv_head * head_dim] (zeros; pages
     are recycled without clearing — the position mask in paged_attend
-    makes stale contents unreachable)."""
+    and the lengths in paged_decode make stale contents unreachable)."""
     shape = (n_layer, num_pages, page_size, n_kv_head * head_dim)
     return {"k_pages": jnp.zeros(shape, dtype),
             "v_pages": jnp.zeros(shape, dtype)}

@@ -4,9 +4,14 @@ already projected, position-encoded and split into heads.
 One function, ``attention``, under the scope ``attn.core``:
 
 - with a ``cache`` (the engine's prefill and decode): this step's K/V
-  are written into the paged pool the layers carry and q attends
-  against the gathered history (``llm/kv_cache.py``).  Runs unsharded —
-  the serving engine hosts one replica per chip;
+  are written into the paged pool the layers carry (``llm/kv_cache.py
+  paged_store``), then q attends against the history: a decode step
+  (one query row a sequence) on the ``tpu`` backend through the Pallas
+  kernel that reads each sequence's pages where they lie
+  (``ops/paged_attention.py paged_decode``), a prefill and every other
+  backend through ``paged_attend``, the gather that is the kernel's
+  plain definition.  Runs unsharded — the serving engine hosts one
+  replica per chip;
 - without one (training, the full forward): grouped KV heads are
   repeated to the query heads, q/k/v are constrained as the activation
   table says, and ``cfg.attn_impl`` picks ``dense`` (XLA-fused,
@@ -83,6 +88,15 @@ def _attention(cfg, q, k, v):
                     ("batch", "seq", None, None), q.shape)(q, k, v)
 
 
+def _decode_kernel(q, k_pages) -> bool:
+    """Whether the cached branch takes the paged-decode kernel: by q's
+    shape, the pool's and the backend, nothing else."""
+    from ..ops import paged_attention
+
+    return jax.default_backend() == "tpu" \
+        and paged_attention.supported(q, k_pages)
+
+
 def attention(cfg, q, k, v, cache=None):
     """q: [B, T, H, D]; k, v: [B, T, Hkv, D] (H a multiple of Hkv).
     Returns (att [B, T, H, D], new_cache): ``new_cache`` is the updated
@@ -90,15 +104,23 @@ def attention(cfg, q, k, v, cache=None):
     "page_table", "positions"}) is given, else None."""
     with jax.named_scope("attn.core"):
         if cache is not None:
-            # The pool stores the Hkv GROUPED heads; repeat-to-H happens
-            # at attend time, so GQA shrinks the pooled cache by H/Hkv.
+            # The pool stores the Hkv GROUPED heads; each query head
+            # meets its group at attend time, so GQA shrinks the pooled
+            # cache by H/Hkv.
             from ..llm.kv_cache import paged_attend, paged_store
+            from ..ops import paged_attention
 
             k_pages, v_pages = paged_store(
                 cache["k_pages"], cache["v_pages"], cache["layer"],
                 k, v, cache["page_table"], cache["positions"])
-            att = paged_attend(q, k_pages, v_pages, cache["layer"],
-                               cache["page_table"], cache["positions"])
+            if _decode_kernel(q, k_pages):
+                # A padded row's position is -1: length 0, zeros out.
+                att = paged_attention.paged_decode(
+                    q, k_pages, v_pages, cache["layer"],
+                    cache["page_table"], cache["positions"][:, 0] + 1)
+            else:
+                att = paged_attend(q, k_pages, v_pages, cache["layer"],
+                                   cache["page_table"], cache["positions"])
             return att, (k_pages, v_pages)
         rep = q.shape[2] // k.shape[2]
         if rep != 1:  # GQA: repeat KV groups to full heads

@@ -3,7 +3,9 @@
 The compute path of the framework is XLA-compiled jax; these kernels
 cover the places where XLA's fusion leaves HBM bandwidth on the table —
 first of all attention, whose materialized [B,H,T,T] score matrix
-dominates memory traffic at pretraining shapes.
+dominates memory traffic at pretraining shapes (``flash_attention``),
+and whose gathered copy of the paged KV pool dominated a serving decode
+step (``paged_attention.paged_decode``).
 """
 
 from .flash_attention import flash_attention  # noqa: F401

@@ -421,33 +421,51 @@ def test_llama_incremental_decode_token_identical():
 def test_cached_forward_goes_through_the_one_attention_core(
         family, monkeypatch):
     """Both blocks hand the paged cache to models/attention.py: each
-    layer's store and attend are called from there, once, and nowhere
-    else (what they compute is pinned above)."""
+    layer stores once and then attends once, from there and nowhere
+    else: through ``paged_attend`` on this backend; where the dispatch
+    says kernel (the ``tpu`` backend's rule, by q's shape), a decode
+    step through ``paged_decode`` and the prefill still through
+    ``paged_attend``, to the same tokens."""
     import sys
 
     import jax
 
+    import ray_tpu.models.attention as attention
     from ray_tpu.llm import kv_cache
     from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.ops import paged_attention
 
     callers = []
-    for name in ("paged_store", "paged_attend"):
-        real = getattr(kv_cache, name)
+    for module, name in ((kv_cache, "paged_store"),
+                         (kv_cache, "paged_attend"),
+                         (paged_attention, "paged_decode")):
+        real = getattr(module, name)
 
         def spy(*args, _real=real, _name=name):
             callers.append(
                 (_name, sys._getframe(1).f_globals["__name__"]))
             return _real(*args)
 
-        monkeypatch.setattr(kv_cache, name, spy)
+        monkeypatch.setattr(module, name, spy)
     fam = MODEL_FAMILIES[family]
     cfg = dataclasses.replace(fam.tiny(), remat=False)
     params = fam.init(cfg, jax.random.PRNGKey(0))
-    _decode_loop(fam.module(cfg), params, cfg, fam.kv_heads(cfg),
-                 [3, 17, 42], 1)
-    assert callers == [("paged_store", "ray_tpu.models.attention"),
-                       ("paged_attend", "ray_tpu.models.attention")
-                       ] * cfg.n_layer
+
+    def two_steps():        # a prefill, then one decode step
+        del callers[:]
+        return _decode_loop(fam.module(cfg), params, cfg,
+                            fam.kv_heads(cfg), [3, 17, 42], 2)[0]
+
+    def layers(attend):
+        return [("paged_store", "ray_tpu.models.attention"),
+                (attend, "ray_tpu.models.attention")] * cfg.n_layer
+
+    tokens = two_steps()
+    assert callers == layers("paged_attend") * 2
+    monkeypatch.setattr(attention, "_decode_kernel",
+                        lambda q, k_pages: q.shape[1] == 1)
+    assert two_steps() == tokens
+    assert callers == layers("paged_attend") + layers("paged_decode")
 
 
 # --------------------------------------------- the pool stays in place

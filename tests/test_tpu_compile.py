@@ -14,6 +14,7 @@ branch here, so the tests steer it (``interpret=False``, or patching the
 name the model imports) — not an option of the program.
 """
 
+import contextlib
 import functools
 import os
 import re
@@ -141,14 +142,33 @@ def engine_program(one_chip):
     from ray_tpu.models.gpt2 import GPT2, GPT2Config
 
     @functools.lru_cache(maxsize=None)
-    def program(widths, tokens_shape, num_pages):
+    def program(widths, tokens_shape, num_pages, kernel=False):
         cfg = GPT2Config(**ENGINE_WIDTHS[widths], attn_impl="dense",
                          remat=False)
         args = _engine_args(cfg, one_chip, tokens_shape,
                             num_pages=num_pages)
-        return jit_forward(GPT2(cfg)).lower(*args).compile(), args[2]
+        with _as_on_tpu(kernel):
+            return jit_forward(GPT2(cfg)).lower(*args).compile(), args[2]
 
     return program
+
+
+@contextlib.contextmanager
+def _as_on_tpu(kernel=True):
+    """The cached attention dispatches as the backend ``tpu`` would (a
+    decode step takes the compiled paged-decode kernel); with
+    ``kernel=False`` it is left as this backend has it."""
+    import ray_tpu.models.attention as attention
+    from ray_tpu.ops import paged_attention
+
+    with pytest.MonkeyPatch.context() as patch:
+        if kernel:
+            patch.setattr(attention, "_decode_kernel",
+                          paged_attention.supported)
+            patch.setattr(paged_attention, "paged_decode",
+                          functools.partial(paged_attention.paged_decode,
+                                            interpret=False))
+        yield
 
 
 @pytest.mark.parametrize("kind,tokens_shape", [("decode", (8, 1)),
@@ -191,6 +211,73 @@ def test_engine_forward_updates_pool_in_place(engine_program, widths,
         < 0.5 * pool.size * pool.dtype.itemsize
 
 
+def _assert_attends_in_place(compiled, pool, n_layer, temp_bytes):
+    """A decode program that attends through the paged-decode kernel:
+    one call a layer, no gathered copy of the pages, no pass over the
+    pool or a layer of it, fewer temporaries than the gather needed."""
+    text = compiled.as_text()
+    calls = re.findall(r"^\s*(?:ROOT )?%paged_decode[\w.]* = .*custom-call\(",
+                       text, re.M)
+    assert len(calls) == n_layer, len(calls)
+    layers, pages, page, width = pool.shape
+    gathered = re.findall(
+        rf"= \w+\[16,\d+,{page},{width}\]\S* (?:gather|fusion)\(", text)
+    assert not gathered, gathered[:2]
+    shapes = (",".join(map(str, pool.shape)),
+              ",".join(map(str, pool.shape[1:])))
+    passes = [line.strip()[:120] for line in text.splitlines()
+              if (m := _POOL_PASS.match(line)) and m.group(1) in shapes]
+    assert not passes, (len(passes), passes[:4])
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_bytes
+
+
+def test_large_decode_cell_attends_through_the_paged_kernel(
+        engine_program):
+    """The decode program of serve-gpt2-large-sat ([16, 1] over 1024
+    pages) as the backend ``tpu`` builds it: 36 ``paged_decode`` calls on
+    the pool where it lies.  The gather's program held 0.361 GB of
+    temporaries (PERF.md, PR 28)."""
+    compiled, pool = engine_program("large", (16, 1), 1024, kernel=True)
+    _assert_attends_in_place(compiled, pool, 36, 0.3e9)
+
+
+def test_prefill_program_is_the_same_on_either_dispatch(engine_program):
+    """A prefill (T > 1) attends through ``paged_attend`` whatever the
+    backend: the dispatch that hands a decode step to the kernel leaves
+    its program as it was, to the letter."""
+    def text(kernel):
+        # Less what records the Python call stack: each instruction's
+        # metadata and the tables of files and frames at the top.
+        compiled, _ = engine_program("124m", (1, 512), 2048, kernel=kernel)
+        text = re.sub(r",? ?metadata=\{[^}]*\}", "", compiled.as_text())
+        return re.sub(r"(?s)\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n",
+                      text, count=1)
+
+    assert "paged_decode" not in text(True)
+    assert text(True) == text(False)
+
+
+@pytest.mark.parametrize("h,h_kv,d,dtype", [
+    (32, 8, 128, jnp.bfloat16), (12, 12, 64, jnp.bfloat16),
+    (12, 12, 64, jnp.float32)],
+    ids=["gqa_32_over_8_of_128", "mha_12_of_64", "mha_12_of_64_float32"])
+def test_paged_decode_kernel_compiles(one_chip, h, h_kv, d, dtype):
+    """The kernel alone at widths no cell has: grouped-query heads (four
+    query heads a K/V head, Llama-3-8B's), GPT-2 124M's (chip_smoke.py's
+    replica), and a float32 pool."""
+    from ray_tpu.ops.paged_attention import paged_decode, supported
+
+    on = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    pool = on((4, 512, 16, h_kv * d), dtype)
+    assert supported(on((16, 1, h, d), dtype), pool)
+    compiled = jax.jit(functools.partial(
+        paged_decode, layer=2, interpret=False)).lower(
+        on((16, 1, h, d), dtype), pool, pool,
+        page_table=on((16, 64), jnp.int32),
+        lengths=on((16,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
 @pytest.mark.parametrize("program", ["sample", "last[1024]"])
 def test_engine_sampler_compiles_at_the_cells_sizes(one_chip, program):
     """The engine's sampler (llm/sampling.py) at max_batch 16 and GPT-2's
@@ -219,8 +306,10 @@ def test_engine_sampler_compiles_at_the_cells_sizes(one_chip, program):
     assert compiled.memory_analysis().temp_size_in_bytes < 32e6
 
 
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["gather", "paged_kernel"])
 def test_olmoe_decode_cell_compiles_with_grouped_matmuls_in_place(
-        one_chip):
+        one_chip, kernel):
     """The decode program of serve-olmoe-1b-7b-sat (OLMoE-1B-7B's widths,
     8 layers, bf16 weights, [16, 1] over 1024 pages, max_context 1024):
     it fits the chip; every expert matmul is the compiler's own grouped
@@ -241,10 +330,11 @@ def test_olmoe_decode_cell_compiles_with_grouped_matmuls_in_place(
         cfg.dtype))
     ints = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
                              sharding=one_chip)
-    compiled = jit_forward(Llama(cfg)).lower(
-        _on(params, one_chip), ints((16, 1)), _on(kv["k_pages"], one_chip),
-        _on(kv["v_pages"], one_chip), ints((16, pages_for(1024, 16))),
-        ints((16, 1))).compile()
+    with _as_on_tpu(kernel):
+        compiled = jit_forward(Llama(cfg)).lower(
+            _on(params, one_chip), ints((16, 1)),
+            _on(kv["k_pages"], one_chip), _on(kv["v_pages"], one_chip),
+            ints((16, pages_for(1024, 16))), ints((16, 1))).compile()
     assert _device_bytes(compiled) < HBM_BYTES
     text = compiled.as_text()
     assert text.count('op_name="ragged-dot-metadata"') == cfg.n_layer
@@ -261,6 +351,8 @@ def test_olmoe_decode_cell_compiles_with_grouped_matmuls_in_place(
     # 8-layer pool array each; a second pool would be 1.07 GB.
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 0.6 * pool.size * pool.dtype.itemsize
+    if kernel:      # as the backend ``tpu`` builds it: nothing gathered
+        _assert_attends_in_place(compiled, pool, cfg.n_layer, 0.2e9)
 
 
 def _train_step_and_shapes(cfg, loss_chunk):
