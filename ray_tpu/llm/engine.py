@@ -20,6 +20,16 @@ re-prefills (prompt + everything it already generated) when pages free
 up, so already-streamed tokens are never re-emitted and greedy output
 is unchanged.
 
+A model whose layers are not all attention keeps a SECOND kind of cache
+(models.CacheSpec; kv_cache.py ``init_state`` / ``SlotPool``): beside its
+pages (for the layers that hold K/V, and only those) a sequence takes one
+slot of the state pool at admission, for the recurrent state of its
+state-space layers, and gives it back with its pages at retirement,
+cancellation and eviction (the re-prefill rebuilds the state from
+position 0).  Both pools are donated to the one jitted forward and
+updated where they lie; a decode row without a sequence carries a slot
+index outside the pool.
+
 Tokens are chosen ON THE DEVICE: after each forward one jitted sampler
 (sampling.py ``jit_sampler``) takes the device-resident logits of the
 last positions and, as data, every row's temperature / top-k / top-p,
@@ -54,7 +64,8 @@ import numpy as np
 
 from ..util import chips
 from ..util.spans import annotate
-from .kv_cache import PagePool, init_cache, pages_for
+from .kv_cache import (PagePool, SlotPool, init_cache, init_state,
+                       pages_for)
 from .sampling import (SamplingParams, jit_sampler, pack_rows,
                        seed_words)
 
@@ -123,7 +134,7 @@ class _Sequence:
     """One in-flight generation request (engine-internal)."""
 
     __slots__ = ("sid", "tokens", "prompt_len", "max_tokens", "params",
-                 "seed", "out", "pages", "n_cached", "generated",
+                 "seed", "out", "pages", "slot", "n_cached", "generated",
                  "finished", "cancelled", "submitted_ts",
                  "request_id", "first_token_ts", "last_token_ts",
                  "warmup")
@@ -140,6 +151,7 @@ class _Sequence:
         self.seed = seed_words(seed)    # the sampler's key, two uint32
         self.out: "queue.Queue" = queue.Queue()
         self.pages: List[int] = []
+        self.slot: Optional[int] = None  # its place in the state pool
         self.n_cached = 0               # tokens written into KV pages
         self.generated = 0
         self.finished = False
@@ -164,24 +176,36 @@ def jit_forward(model):
     [L, pages, page, h_kv*d]; donated, and carried through the layers
     by the model, they are updated in place: the program scatters the
     new rows and holds no second pool (tests/test_llm.py and
-    tests/test_tpu_compile.py pin that).  A model with experts
-    returns a fourth output, its routing counters ([layers, 3] int32,
-    ops/moe.py ``moe_counters``)."""
+    tests/test_tpu_compile.py pin that).  A model whose cache spec has
+    recurrent layers takes three more arguments, the state pool's
+    ``conv`` and ``ssm`` (llm/kv_cache.py ``init_state``; donated and
+    updated in place as the pages are) and each row's slot ``[B]``, and
+    returns the two after ``v_pages``.  A model with experts returns one
+    more output, its routing counters ([layers, 3] int32, ops/moe.py
+    ``moe_counters``)."""
     import jax
 
+    from ..models import family_of
     from ..ops.moe import moe_counters
 
-    def fwd(p, tokens, k_pages, v_pages, page_table, positions):
-        (logits, new), state = model.apply(
-            p, tokens,
-            kv_cache={"k_pages": k_pages, "v_pages": v_pages,
-                      "page_table": page_table},
-            positions=positions, mutable=["intermediates"])
+    recurrent = family_of(model.cfg).cache(model.cfg).state_layers > 0
+
+    def fwd(p, tokens, k_pages, v_pages, page_table, positions, *state):
+        cache = {"k_pages": k_pages, "v_pages": v_pages,
+                 "page_table": page_table}
+        if recurrent:
+            cache["conv"], cache["ssm"], cache["slots"] = state
+        (logits, new), sown = model.apply(
+            p, tokens, kv_cache=cache, positions=positions,
+            mutable=["intermediates"])
         out = (logits, new["k_pages"], new["v_pages"])
-        moe = moe_counters(state.get("intermediates", {}))
+        if recurrent:
+            out += (new["conv"], new["ssm"])
+        moe = moe_counters(sown.get("intermediates", {}))
         return out if moe is None else out + (moe,)
 
-    return jax.jit(fwd, donate_argnums=(2, 3))
+    return jax.jit(fwd,
+                   donate_argnums=(2, 3) + ((6, 7) if recurrent else ()))
 
 
 def _program_bytes(exe) -> int:
@@ -212,22 +236,46 @@ class GenerationEngine:
         self.model_cfg = model_cfg
         family = family_of(model_cfg)
         self._model = family.module(model_cfg)
-        n_kv = family.kv_heads(model_cfg)
+        # What a sequence keeps on the device, by layer kind
+        # (models.CacheSpec): pages for the layers with K/V, a slot of
+        # the state pool for the recurrent ones.
+        spec = self._cache_spec = family.cache(model_cfg)
+        n_kv, head_dim = spec.kv_heads, spec.head_dim
         if params is None:
             params = family.init(model_cfg, jax.random.PRNGKey(seed))
         self._params = params
         # What this engine computes on, as JAX reports it (stats()).
         self._device = chips.describe_devices()
-        head_dim = model_cfg.d_model // model_cfg.n_head
         self.max_context = min(
             self.cfg.max_context or model_cfg.max_seq, model_cfg.max_seq,
             self.cfg.num_pages * self.cfg.page_size)
         self._pages_per_seq = pages_for(self.max_context,
                                         self.cfg.page_size)
         self.pool = PagePool(self.cfg.num_pages, self.cfg.page_size)
-        self._kv = init_cache(model_cfg.n_layer, self.cfg.num_pages,
+        self._kv = init_cache(spec.kv_layers, self.cfg.num_pages,
                               self.cfg.page_size, n_kv, head_dim,
                               model_cfg.dtype)
+        # One slot a running sequence; None for a model without
+        # recurrent layers, whose programs and stats() are as they were.
+        # What the decode steps' recurrent layers move is counted beside
+        # the slots (stats()["state"]): ``state_rows_updated`` = running
+        # rows x recurrent layers, one row = one sequence's conv window
+        # and state of one layer (``state_row_bytes``, read and written
+        # once a step); ``mixer_weight_bytes`` = one layer's mixer
+        # matrices.
+        self.slots = self._state = None
+        self._state_counts: Dict[str, int] = {}
+        if spec.state_layers:
+            self.slots = SlotPool(self.cfg.max_batch)
+            self._state = init_state(spec, self.cfg.max_batch,
+                                     model_cfg.dtype)
+            self._state_counts = {
+                "decode_runs": 0, "state_rows_updated": 0,
+                "state_row_bytes": sum(
+                    int(a[0, 0].size) * a.dtype.itemsize
+                    for a in self._state.values()),
+                "mixer_weight_bytes": model_cfg.mixer_params()
+                * np.dtype(model_cfg.param_dtype).itemsize}
 
         self._fwd = jit_forward(self._model)
         self._sampler, self._last_rows = jit_sampler(self.cfg.max_batch)
@@ -489,6 +537,12 @@ class GenerationEngine:
                 # layers; experts_hit and max_load summed over layers
                 # and runs.
                 **({"moe": dict(self._moe)} if self._moe else {}),
+                # The second kind of cache (absent for a model without
+                # recurrent layers).
+                **({"state": {"slots_total": self.slots.slots,
+                              "slots_used": self.slots.used,
+                              **self._state_counts}}
+                   if self.slots is not None else {}),
             }
 
     # ------------------------------------------------------ engine loop
@@ -595,6 +649,11 @@ class GenerationEngine:
                     pages = self.pool.alloc(n_pages)
                     if pages is None:
                         return      # wait for frees/retirements
+                    if self.slots is not None:
+                        seq.slot = self.slots.take()
+                        if seq.slot is None:    # as many slots as rows
+                            self.pool.free(pages)
+                            return
                     self._waiting.popleft()
                     seq.pages = pages
                     oversized = None
@@ -618,12 +677,26 @@ class GenerationEngine:
         row[:len(seq.pages)] = seq.pages
         return row
 
-    def _call_fwd(self, kind: str, *args):
-        """The forward of this token shape: ``llm_decode``, or
-        ``llm_prefill[bucket]``."""
-        name = f"llm_{kind}[{args[1].shape[1]}]" \
+    def _call_fwd(self, kind: str, tokens, table, positions,
+                  batch: List[_Sequence]):
+        """The forward of this token shape (``llm_decode``, or
+        ``llm_prefill[bucket]``) over the caches, which it updates:
+        returns (logits, the routing counters or None).  Row i of the
+        forward is ``batch[i]``."""
+        name = f"llm_{kind}[{tokens.shape[1]}]" \
             if kind == "prefill" else f"llm_{kind}"
-        return self._call(self._fwd, name, *args)
+        args = (self._params, tokens, self._kv["k_pages"],
+                self._kv["v_pages"], table, positions)
+        if self._state is not None:
+            # a row without a sequence: a slot outside the pool
+            slots = np.full(tokens.shape[0], self.slots.slots, np.int32)
+            slots[:len(batch)] = [seq.slot for seq in batch]
+            args += (self._state["conv"], self._state["ssm"], slots)
+        logits, k, v, *rest = self._call(self._fwd, name, *args)
+        self._kv["k_pages"], self._kv["v_pages"] = k, v
+        if self._state is not None:
+            self._state["conv"], self._state["ssm"], *rest = rest
+        return logits, (rest[0] if rest else None)
 
     def _call(self, fn, name: str, *args):
         """Dispatch a jitted function through the AOT executable of this
@@ -708,10 +781,8 @@ class GenerationEngine:
             table = self._page_table_row(seq)[None, :]
             sampling = self._pack_sampling([seq])
         with self._phase("llm.prefill.run"):
-            logits, k, v, *_ = self._call_fwd(
-                "prefill", self._params, tokens, self._kv["k_pages"],
-                self._kv["v_pages"], table, positions)
-            self._kv["k_pages"], self._kv["v_pages"] = k, v
+            logits, _ = self._call_fwd("prefill", tokens, table,
+                                       positions, [seq])
             ids = self._call(
                 self._sampler, "llm_sample",
                 self._call(self._last_rows, f"llm_last[{pad}]", logits,
@@ -755,25 +826,27 @@ class GenerationEngine:
                 table[i] = self._page_table_row(seq)
                 pages_read += pages_for(seq.n_cached + 1,
                                         self.cfg.page_size)
-            rows = self.cfg.page_size * self.model_cfg.n_layer
+            rows = self.cfg.page_size * self._cache_spec.kv_layers
             counts = self._attention
             counts["decode_runs"] += 1
             counts["kv_rows_read"] += pages_read * rows
             counts["kv_rows_held"] += table.size * rows
+            if self._state_counts:
+                self._state_counts["decode_runs"] += 1
+                self._state_counts["state_rows_updated"] += \
+                    len(batch) * self._cache_spec.state_layers
             sampling = self._pack_sampling(batch)
         with self._phase("llm.decode.run"):
-            logits, k, v, *moe = self._call_fwd(
-                "decode", self._params, tokens, self._kv["k_pages"],
-                self._kv["v_pages"], table, positions)
-            self._kv["k_pages"], self._kv["v_pages"] = k, v
+            logits, moe = self._call_fwd("decode", tokens, table,
+                                         positions, batch)
             ids = self._call(self._sampler, "llm_sample", logits,
                              *sampling)
         with self._phase("llm.decode.fetch"):
             ids = np.asarray(ids).tolist()      # [max_batch] int32
-            if moe:
+            if moe is not None:
                 from ..ops.moe import MOE_COUNTERS
 
-                per_layer = np.asarray(moe[0])      # [layers, 3]
+                per_layer = np.asarray(moe)         # [layers, 3]
                 adds = dict(zip(MOE_COUNTERS, per_layer.sum(axis=0)),
                             layer_runs=len(per_layer))
                 with self._lock:
@@ -819,11 +892,21 @@ class GenerationEngine:
             if victim in self._running:
                 self._running.remove(victim)
             self._waiting.appendleft(victim)
-        self.pool.free(victim.pages)
-        victim.pages = []
+        self._release(victim)
         victim.n_cached = 0
         self._evictions += 1
         self._count("evictions")
+
+    def _release(self, seq: _Sequence) -> None:
+        """Give back what ``seq`` holds of the device's caches: its pages
+        and, where the model keeps a recurrent state, its slot (at
+        retirement, cancellation and eviction alike: a re-prefill
+        rebuilds the state from position 0)."""
+        self.pool.free(seq.pages)
+        seq.pages = []
+        if self.slots is not None:
+            self.slots.give(seq.slot)
+            seq.slot = None
 
     def _emit_token(self, seq: _Sequence, tok: int) -> None:
         """The host's bookkeeping for one token the device chose."""
@@ -859,8 +942,7 @@ class GenerationEngine:
         if seq.finished:
             return
         seq.finished = True
-        self.pool.free(seq.pages)
-        seq.pages = []
+        self._release(seq)
         self._seqs.pop(seq.sid, None)
         if seq.first_token_ts is not None and \
                 seq.last_token_ts is not None and seq.generated > 1:

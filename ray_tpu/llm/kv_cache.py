@@ -28,6 +28,19 @@ kernel is tested against.  All three take the WHOLE pool and a layer
 index, and the forward carries that one pool from layer to layer:
 slicing a layer out and stacking the layers again makes XLA build a new
 pool beside the donated one.
+
+A model with recurrent layers (``models/granite.py``: Mamba-2 mixers)
+keeps a SECOND kind of cache beside the pages: one slot a sequence in the
+state pool, ``conv`` [state layers, slots, d_conv-1, conv_dim] (the conv
+window, in the model's dtype) and ``ssm`` [state layers, slots, H, P, N]
+(float32), of fixed size whatever the sequence's length
+(``init_state``).  The K/V pool is then built for the attention layers
+only.  The model reads and writes a row's slot where it lies, through a
+``[B]`` slot index (an index outside the pool: a padded row, nothing
+changed), and carries both arrays whole through its layers as the K/V
+pool is carried; ``SlotPool`` is the host-side allocator, a sequence
+takes a slot with its first pages and gives it back with them.
+
 ``PagePool`` is the host-side allocator; it exports
 ``rt_llm_kv_pages_{used,total}`` gauges on every alloc/free so KV
 occupancy is visible in ``rt telemetry`` and the doctor can see leaks.
@@ -51,6 +64,17 @@ def init_cache(n_layer: int, num_pages: int, page_size: int,
     shape = (n_layer, num_pages, page_size, n_kv_head * head_dim)
     return {"k_pages": jnp.zeros(shape, dtype),
             "v_pages": jnp.zeros(shape, dtype)}
+
+
+def init_state(spec, slots: int, dtype: Any) -> Dict[str, Any]:
+    """The state pool of a model whose cache spec (``models.CacheSpec``)
+    has recurrent layers: zeros; a slot is not cleared when it changes
+    hands — a forward that starts at position 0 starts from zeros
+    whatever the slot holds (models/granite.py)."""
+    return {"conv": jnp.zeros((spec.state_layers, slots)
+                              + tuple(spec.conv_shape), dtype),
+            "ssm": jnp.zeros((spec.state_layers, slots)
+                             + tuple(spec.ssm_shape), jnp.float32)}
 
 
 def paged_store(k_pages, v_pages, layer, k_new, v_new, page_table,
@@ -80,7 +104,8 @@ def paged_store(k_pages, v_pages, layer, k_new, v_new, page_table,
     return k_pages, v_pages
 
 
-def paged_attend(q, k_pages, v_pages, layer, page_table, positions):
+def paged_attend(q, k_pages, v_pages, layer, page_table, positions,
+                 scale=None):
     """Causal attention of q ([B, T, h, d]) against layer ``layer`` of
     the paged cache.
 
@@ -90,7 +115,8 @@ def paged_attend(q, k_pages, v_pages, layer, page_table, positions):
     j <= p, which both enforces causality and hides unwritten/stale
     slots (every position <= p has been written by construction).  GQA
     caches store h_kv heads and repeat to h at attend time, exactly
-    like the full forward."""
+    like the full forward.  ``scale`` multiplies the scores (None:
+    ``d ** -0.5``)."""
     b, t, h, d = q.shape
     with jax.named_scope("kv.attend"):
         ks = k_pages[layer, page_table]   # [B, P, page, h_kv*d]
@@ -105,7 +131,7 @@ def paged_attend(q, k_pages, v_pages, layer, page_table, positions):
             vs = jnp.repeat(vs, rep, axis=2)
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, ks,
                             preferred_element_type=jnp.float32)
-        scores = scores * (d ** -0.5)
+        scores = scores * (d ** -0.5 if scale is None else scale)
         kv_pos = jnp.arange(p * page, dtype=jnp.int32)
         mask = kv_pos[None, None, None, :] <= positions[:, None, :, None]
         scores = jnp.where(mask, scores, -1e30)
@@ -190,5 +216,58 @@ class PagePool:
         try:
             self._gauges[0].set(float(self.num_pages - free_now))
             self._gauges[1].set(float(self.num_pages))
+        except Exception:
+            pass
+
+
+class SlotPool:
+    """Host-side allocator of the state pool's slots: one a sequence,
+    lowest free first; occupancy exported as ``rt_llm_state_slots_used``
+    / ``rt_llm_state_slots_total`` beside the pages'.  Called from the
+    engine thread only."""
+
+    def __init__(self, slots: int):
+        self.slots = int(slots)
+        self._free: List[int] = list(range(self.slots - 1, -1, -1))
+        self._gauges = None
+        try:
+            from ..util.metrics import Gauge
+
+            self._gauges = (
+                Gauge("rt_llm_state_slots_used",
+                      "Recurrent-state slots currently held by "
+                      "sequences."),
+                Gauge("rt_llm_state_slots_total",
+                      "Recurrent-state slots in the device pool."))
+        except Exception:
+            pass
+        self._publish()
+
+    def take(self) -> Optional[int]:
+        if not self._free:
+            return None
+        slot = self._free.pop()
+        self._publish()
+        return slot
+
+    def give(self, slot: Optional[int]) -> None:
+        if slot is None:
+            return
+        if slot in self._free:
+            raise AssertionError(f"state slot {slot} given back twice")
+        self._free.append(slot)
+        self._free.sort(reverse=True)
+        self._publish()
+
+    @property
+    def used(self) -> int:
+        return self.slots - len(self._free)
+
+    def _publish(self) -> None:
+        if self._gauges is None:
+            return
+        try:
+            self._gauges[0].set(float(self.used))
+            self._gauges[1].set(float(self.slots))
         except Exception:
             pass

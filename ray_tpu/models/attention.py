@@ -24,8 +24,10 @@ from the same table and the same fitting rule as the constraints
 (``parallel/sharding.py logical_spec``), so a head count the ``tensor``
 axis does not divide stays whole there as everywhere else.
 
-``cfg`` is a GPT2Config or a LlamaConfig: ``attn_impl``, ``mesh`` and
-``dtype`` are read.
+``cfg`` is a GPT2Config, a LlamaConfig or a GraniteConfig: ``attn_impl``,
+``mesh`` and ``dtype`` are read.  Position encoding is the block's
+business: what comes in is attended as it is (Granite's layers pass q and
+k with none).
 """
 
 from __future__ import annotations
@@ -46,12 +48,12 @@ def _sharded(fn, mesh, logical, shape):
                      out_specs=spec, check_vma=False)
 
 
-def _attention(cfg, q, k, v):
+def _attention(cfg, q, k, v, scale=None):
     """q, k, v: [B, T, H, D] -> [B, T, H, D]."""
     if cfg.attn_impl == "dense":
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                             preferred_element_type=jnp.float32)
-        scores = scores * (q.shape[-1] ** -0.5)
+        scores = scores * (q.shape[-1] ** -0.5 if scale is None else scale)
         t = q.shape[1]
         mask = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0) >= \
             jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
@@ -68,7 +70,8 @@ def _attention(cfg, q, k, v):
         from ..ops import flash_attention
 
         flash = functools.partial(flash_attention, causal=True,
-                                  block_q=1024, block_k=1024)
+                                  block_q=1024, block_k=1024,
+                                  scale=scale)
         if cfg.mesh is None or cfg.mesh.size == 1:
             return flash(q, k, v)
         # A Mosaic kernel is not partitioned automatically: across a
@@ -82,6 +85,9 @@ def _attention(cfg, q, k, v):
 
     if cfg.mesh is None:
         raise ValueError(f"attn_impl={cfg.attn_impl!r} needs cfg.mesh")
+    if scale is not None:
+        raise ValueError(f"attn_impl={cfg.attn_impl!r} keeps the scale "
+                         "1/sqrt(d)")
     inner = (ring_attention if cfg.attn_impl == "ring"
              else ulysses_attention)
     return _sharded(functools.partial(inner, causal=True), cfg.mesh,
@@ -97,11 +103,13 @@ def _decode_kernel(q, k_pages) -> bool:
         and paged_attention.supported(q, k_pages)
 
 
-def attention(cfg, q, k, v, cache=None):
+def attention(cfg, q, k, v, cache=None, scale=None):
     """q: [B, T, H, D]; k, v: [B, T, Hkv, D] (H a multiple of Hkv).
     Returns (att [B, T, H, D], new_cache): ``new_cache`` is the updated
     (k_pages, v_pages) when ``cache`` ({"k_pages", "v_pages", "layer",
-    "page_table", "positions"}) is given, else None."""
+    "page_table", "positions"}) is given, else None.  ``scale``
+    multiplies q k^T (None: ``D ** -0.5``; a model that states its own,
+    models/granite.py, passes it), on every branch."""
     with jax.named_scope("attn.core"):
         if cache is not None:
             # The pool stores the Hkv GROUPED heads; each query head
@@ -117,10 +125,12 @@ def attention(cfg, q, k, v, cache=None):
                 # A padded row's position is -1: length 0, zeros out.
                 att = paged_attention.paged_decode(
                     q, k_pages, v_pages, cache["layer"],
-                    cache["page_table"], cache["positions"][:, 0] + 1)
+                    cache["page_table"], cache["positions"][:, 0] + 1,
+                    scale=scale)
             else:
                 att = paged_attend(q, k_pages, v_pages, cache["layer"],
-                                   cache["page_table"], cache["positions"])
+                                   cache["page_table"], cache["positions"],
+                                   scale=scale)
             return att, (k_pages, v_pages)
         rep = q.shape[2] // k.shape[2]
         if rep != 1:  # GQA: repeat KV groups to full heads
@@ -130,4 +140,4 @@ def attention(cfg, q, k, v, cache=None):
         q = with_logical_constraint(q, heads, cfg.mesh)
         k = with_logical_constraint(k, heads, cfg.mesh)
         v = with_logical_constraint(v, heads, cfg.mesh)
-        return _attention(cfg, q, k, v), None
+        return _attention(cfg, q, k, v, scale), None

@@ -1,0 +1,603 @@
+"""Granite 4.0-H family (HF ``model_type`` granitemoehybrid) — layers of
+two kinds in one model: Mamba-2 state-space mixers (Dao & Gu 2024,
+"Transformers are SSMs") and, every few layers, grouped-query attention
+with NO position encoding; every layer followed by sparse experts
+(``ops/moe.py``, of which a chip may hold a share) plus one shared gated
+MLP.  Granite's four multipliers scale the embedding, each residual
+branch, the attention scores and the logits; the output head is the
+embedding, tied.
+
+Layer ``i``: ``h = RMSNorm(x)``; ``m = Mamba2(h)`` or ``Attn(h)`` by
+``layer_types[i]``; ``x = x + residual_multiplier x m``; ``h = RMSNorm(x)``;
+``x = x + residual_multiplier x (MoE(h) + Shared(h))``.
+
+The Mamba-2 mixer (one group of B and C shared by all heads): ``[z | xBC |
+dt] = h W_in``; ``xBC = silu(causal depthwise conv_4(xBC) + b)``, split
+into ``x`` (heads x head size), ``B``, ``C`` (state size each); ``dt =
+softplus(dt + dt_bias)``, ``A = -exp(A_log)``; per head the state ``S_t =
+exp(dt_t A) S_{t-1} + dt_t x_t B_t^T`` and ``y_t = S_t C_t + D x_t``; ``y =
+RMSNorm(y x silu(z))`` over the whole inner width; ``y W_out``.  ``dt``,
+the decay and the state are float32.
+
+A forward over many positions (training, the full forward, the engine's
+prefill) computes it in chunks (``ssm.scan``: within a chunk of
+``mamba_chunk`` positions as matmuls, the state carried from chunk to
+chunk); a decode step is the recurrence once (``ssm.step``).  With a
+cache (``llm/kv_cache.py``: the paged K/V pool for the attention layers,
+the state pool ``conv`` / ``ssm`` for the mixers, one slot a sequence)
+each row's state is read from and written to its slot, the pools carried
+whole through the layers.  A position < 0 is padding: it neither decays
+nor feeds the state and is kept out of the conv window, so a prefill
+padded to its bucket leaves the state of its last real position; a row
+whose slot index lies outside the pool (a padded decode row) changes
+nothing: its conv window is dropped, and its state, read from the index
+clipped into the pool, goes back as it was read.  A forward whose first
+position is 0 starts from a zero state whatever its slot held: that is
+how a slot is cleared for the sequence that takes it.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+import zlib
+from dataclasses import dataclass
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..parallel.sharding import with_logical_constraint as _constrain
+from .attention import attention
+from .llama import RMSNorm, _next_token_xent
+
+MAMBA, ATTENTION = "mamba", "attention"
+
+
+@dataclass(frozen=True)
+class GraniteConfig:
+    """ibm-granite/granite-4.0-h-small as published (the defaults): 40
+    layers, attention at 5, 15, 25, 35; 4096 wide; 32 query and 8 K/V
+    heads of 128; 128 Mamba heads of 64 with state 128; top-10 of 72
+    experts of width 768 and a shared expert of width 1536."""
+    vocab_size: int = 100352
+    layer_types: Tuple[str, ...] = tuple(
+        ATTENTION if i % 10 == 5 else MAMBA for i in range(40))
+    d_model: int = 4096
+    n_head: int = 32
+    n_kv_head: int = 8
+    d_ff: int = 768                     # one routed expert's width
+    shared_d_ff: int = 1536
+    n_experts: int = 72                 # what the router scores
+    experts_per_token: int = 10
+    # The share of the routed experts held here (expert parallelism:
+    # ops/moe.py); None holds all ``n_experts``.
+    first_expert: int = 0
+    held_experts: Optional[int] = None
+    mamba_n_heads: int = 128
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk: int = 256
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    attention_multiplier: float = 0.0078125
+    logits_scaling: float = 16.0
+    max_seq: int = 131072
+    rms_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+    attn_impl: str = "dense"
+    remat: bool = True
+    mesh: Any = None
+
+    def __post_init__(self):
+        if self.mamba_n_groups != 1:
+            raise ValueError("one group of B and C is what is written "
+                             "here (mamba_n_groups = 1)")
+        bad = set(self.layer_types) - {MAMBA, ATTENTION}
+        if bad:
+            raise ValueError(f"layer_types holds {sorted(bad)}")
+
+    @staticmethod
+    def tiny(**overrides) -> "GraniteConfig":
+        """The shape at a test's size: [mamba, mamba, attention, mamba],
+        64 wide, 4 Mamba heads of 16 with state 16 and chunks of 8,
+        top-2 of 8 experts of width 32, a shared expert of width 32."""
+        return GraniteConfig(**{**dict(
+            vocab_size=256, layer_types=(MAMBA, MAMBA, ATTENTION, MAMBA),
+            d_model=64, n_head=4, n_kv_head=2, d_ff=32, shared_d_ff=32,
+            n_experts=8, experts_per_token=2, mamba_n_heads=4,
+            mamba_d_head=16, mamba_d_state=16, mamba_chunk=8, max_seq=128,
+            attention_multiplier=1 / 16,    # 1 / head size, as the source's
+            dtype=jnp.float32, param_dtype=jnp.float32), **overrides})
+
+    @property
+    def n_layer(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def head_dim(self) -> int:
+        return self.d_model // self.n_head
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    def layers_of(self, kind: str) -> int:
+        return sum(t == kind for t in self.layer_types)
+
+    def mixer_params(self) -> int:
+        """One state-space layer's mixer matrices (``in_proj`` and
+        ``out_proj``), in parameters."""
+        return self.d_model * (self.d_inner + self.conv_dim
+                               + self.mamba_n_heads) \
+            + self.d_inner * self.d_model
+
+    def flops_per_token(self) -> float:
+        """Training FLOPs a token: 6 x the matmul parameters a token
+        passes through (its k experts, not all of them)."""
+        attn = 2 * self.d_model * (self.n_head + self.n_kv_head) \
+            * self.head_dim
+        ffn = 3 * self.d_model * (self.d_ff * self.experts_per_token
+                                  + self.shared_d_ff) \
+            + self.d_model * self.n_experts
+        n = self.vocab_size * self.d_model + self.n_layer * ffn \
+            + self.layers_of(MAMBA) * self.mixer_params() \
+            + self.layers_of(ATTENTION) * attn
+        return 6.0 * n
+
+
+# ------------------------------------------------------------ the mixer
+
+def ssd_scan(x, dt, a, b_mat, c_mat, chunk: int, state=None):
+    """The state-space recurrence over T positions in chunks.
+
+    x [B, T, H, P], dt [B, T, H] (0 at a padded position: no decay, no
+    input), a [H] (negative), b_mat / c_mat [B, T, N], all float32;
+    ``state`` [B, H, P, N] (None: zeros).  Returns (y [B, T, H, P] without
+    the ``D x`` term, the state after the last position).
+
+    With ``l_t = sum_{s <= t} dt_s a`` inside a chunk: the chunk's own
+    part is ``y_t = sum_{s <= t} exp(l_t - l_s) (C_t . B_s) dt_s x_s``,
+    two matmuls; what the chunk inherits is ``exp(l_t) S_in C_t``; and it
+    hands on ``S_out = exp(l_Q) S_in + sum_s exp(l_Q - l_s) dt_s x_s
+    B_s^T``.  T is filled up to whole chunks (of at most T) with dt = 0."""
+    bsz, t, h, p = x.shape
+    n = b_mat.shape[-1]
+    chunk = min(chunk, t)
+    fill = -t % chunk
+    if fill:
+        x, dt, b_mat, c_mat = (
+            jnp.pad(z, [(0, 0), (0, fill)] + [(0, 0)] * (z.ndim - 2))
+            for z in (x, dt, b_mat, c_mat))
+    nc = (t + fill) // chunk
+
+    def chunks(z):      # [B, nc*Q, ...] -> [nc, B, Q, ...]
+        return jnp.moveaxis(
+            z.reshape((bsz, nc, chunk) + z.shape[2:]), 1, 0)
+
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def one(s_in, blk):
+        xc, dtc, bc, cc = blk
+        log = jnp.cumsum(dtc * a, axis=1)                     # [B,Q,H]
+        by_head = jnp.swapaxes(log, 1, 2)                     # [B,H,Q]
+        # exp(l_t - l_s), t >= s; a masked entry's exponent may be
+        # positive: mask the exponent, not the product.
+        seg = by_head[..., :, None] - by_head[..., None, :]   # [B,H,Q,Q]
+        decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+        cb = jnp.einsum("btn,bsn->bts", cc, bc)               # [B,Q,Q]
+        xdt = xc * dtc[..., None]                             # [B,Q,H,P]
+        y = jnp.einsum("bhts,bshp->bthp", cb[:, None] * decay, xdt)
+        y = y + jnp.exp(log)[..., None] * jnp.einsum(
+            "bhpn,btn->bthp", s_in, cc)
+        to_end = jnp.exp(log[:, -1:, :] - log)                # [B,Q,H]
+        s_out = jnp.exp(log[:, -1, :])[:, :, None, None] * s_in \
+            + jnp.einsum("bshp,bsn->bhpn", to_end[..., None] * xdt, bc)
+        return s_out, y
+
+    if state is None:
+        state = jnp.zeros((bsz, h, p, n), jnp.float32)
+    state, y = jax.lax.scan(one, state,
+                            tuple(chunks(z) for z in (x, dt, b_mat, c_mat)))
+    y = jnp.moveaxis(y, 0, 1).reshape(bsz, nc * chunk, h, p)
+    return y[:, :t], state
+
+
+def _step_rows(ssm_pool, layer, slots, fresh, dt, a, x, b_mat, c_mat):
+    """The recurrence once for every row of a decode batch, each row's
+    state read from and written to its slot of layer ``layer`` of the
+    WHOLE pool ``[L, slots, H, P, N]``, row by row, where it lies: no
+    gathered copy of the batch's states (gathered and scattered, XLA
+    moved six times a row's 4 MB where this moves three: my compiles for
+    a v5e, PR 31).  Two passes: the states updated in place, then ``y_t =
+    S_t C_t`` read from them (in one loop the read of the old state and
+    the write of the new made the CPU's compiler copy the pool).  dt
+    [B, H] (0: a padded row, whose slot index is clipped into the pool
+    and whose state is written back as it was read), x [B, H, P], b_mat
+    / c_mat [B, N].  Returns (y [B, H, P] without the ``D x`` term, the
+    pool)."""
+    decay = jnp.exp(dt * a)                                    # [B,H]
+    xdt = dt[..., None] * x                                    # [B,H,P]
+    rows = x.shape[0]
+    one = (1, 1) + ssm_pool.shape[2:]
+
+    def at(i):
+        return (layer, jnp.clip(slots[i], 0, ssm_pool.shape[1] - 1),
+                0, 0, 0)
+
+    def update(i, pool):
+        s = jax.lax.dynamic_slice(pool, at(i), one)[0, 0]
+        s = jnp.where(fresh[i], 0.0, s.astype(jnp.float32))
+        s = decay[i][:, None, None] * s \
+            + xdt[i][:, :, None] * b_mat[i][None, None, :]
+        return jax.lax.dynamic_update_slice(
+            pool, s[None, None].astype(pool.dtype), at(i))
+
+    ssm_pool = jax.lax.fori_loop(0, rows, update, ssm_pool)
+
+    def read(i, ys):
+        # a multiply and a sum in float32: no matmul rounds the state
+        s = jax.lax.dynamic_slice(ssm_pool, at(i), one)[0, 0]
+        y = jnp.sum(s.astype(jnp.float32) * c_mat[i][None, None, :],
+                    axis=-1)
+        return jax.lax.dynamic_update_slice(ys, y[None], (i, 0, 0))
+
+    ys = jax.lax.fori_loop(0, rows, read, jnp.zeros(x.shape, jnp.float32))
+    return ys, ssm_pool
+
+
+def _load_rows(ssm_pool, layer, slots):
+    """Each row's state [B, H, P, N] read from its slot of layer
+    ``layer``, row by row (a slot index outside the pool is clipped:
+    a padded row reads some other row's state, and nothing is made of
+    it)."""
+    last = ssm_pool.shape[1] - 1
+    one = (1, 1) + ssm_pool.shape[2:]
+
+    def row(i, out):
+        s = jax.lax.dynamic_slice(
+            ssm_pool, (layer, jnp.clip(slots[i], 0, last), 0, 0, 0), one)
+        return jax.lax.dynamic_update_slice(out, s[0], (i, 0, 0, 0))
+
+    return jax.lax.fori_loop(
+        0, slots.shape[0], row,
+        jnp.zeros(slots.shape + ssm_pool.shape[2:], ssm_pool.dtype))
+
+
+def _store_rows(ssm_pool, layer, slots, states):
+    """Write ``states`` [B, H, P, N] into their slots of layer ``layer``
+    of the pool [L, slots, H, P, N], row by row, in place (a padded
+    row's slot index is clipped, as ``_load_rows`` clipped it: with dt
+    = 0 throughout it hands back the state it was given, and its
+    clipped slot gets back what it held).  Through a view with H and P
+    merged: written in five dimensions, the compiler gave the POOL the
+    layout its update came in (P before H) and copied all of it in and
+    out of every prefill of one chunk (1.2 GB of temporaries at the
+    cell's sizes; my compile for a v5e, PR 31).  Merged, only the update
+    is re-laid."""
+    n_layers, n_slots, h, p, n = ssm_pool.shape
+    flat = ssm_pool.reshape(n_layers, n_slots, h * p, n)
+    states = states.reshape(-1, 1, 1, h * p, n).astype(flat.dtype)
+
+    def row(i, pool):
+        at = (layer, jnp.clip(slots[i], 0, n_slots - 1), 0, 0)
+        return jax.lax.dynamic_update_slice(pool, states[i], at)
+
+    flat = jax.lax.fori_loop(0, slots.shape[0], row, flat)
+    return flat.reshape(ssm_pool.shape)
+
+
+class Mamba2Mixer(nn.Module):
+    cfg: GraniteConfig
+
+    @nn.compact
+    def __call__(self, hid, cache=None):
+        """hid [B, T, d] -> [B, T, d]; with ``cache`` ({"conv", "ssm",
+        "layer", "slots", "positions"}: the WHOLE state pool and this
+        mixer's layer in it) returns (out, (conv, ssm)) with each row's
+        slot updated."""
+        cfg = self.cfg
+        b, t, _ = hid.shape
+        h, p, n = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+        di, dc, kw = cfg.d_inner, cfg.conv_dim, cfg.mamba_d_conv
+        init = nn.initializers.normal(0.02)
+        f32 = jnp.float32
+        with jax.named_scope("ssm.in_proj"):
+            proj = nn.Dense(di + dc + h, use_bias=False, dtype=cfg.dtype,
+                            kernel_init=init, name="in_proj")(hid)
+            z, xbc, dt = jnp.split(proj, [di, di + dc], axis=-1)
+        conv_w = self.param("conv_w", init, (kw, dc), f32)
+        conv_b = self.param("conv_b", nn.initializers.zeros, (dc,), f32)
+        a_log = self.param("A_log", nn.initializers.zeros, (h,), f32)
+        d_skip = self.param("D", nn.initializers.ones, (h,), f32)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (h,), f32)
+
+        valid = fresh = conv_pool = ssm_pool = None
+        if cache is not None:
+            valid = cache["positions"] >= 0                    # [B, T]
+            fresh = cache["positions"][:, 0] == 0              # [B]
+            conv_pool, ssm_pool = cache["conv"], cache["ssm"]
+            layer, slots = cache["layer"], cache["slots"]
+        with jax.named_scope("ssm.conv"):
+            if cache is None:
+                before = jnp.zeros((b, kw - 1, dc), xbc.dtype)
+            else:       # the last kw-1 inputs of the row's past
+                # (the pool through a view with the window's two
+                # dimensions merged, for the reason _store_rows gives)
+                flat = conv_pool.reshape(conv_pool.shape[:2] + (-1,))
+                before = jnp.where(
+                    fresh[:, None, None], 0,
+                    flat[layer, slots].reshape(b, kw - 1, dc))
+            window = jnp.concatenate([before.astype(xbc.dtype), xbc],
+                                     axis=1)                   # [B,T+3,dc]
+            conv = sum(window[:, i:i + t].astype(f32) * conv_w[i]
+                       for i in range(kw)) + conv_b
+            xbc_act = nn.silu(conv)
+            if cache is not None:
+                # The window the NEXT position needs: the kw-1 inputs up
+                # to the last real one (a prefill's padding lies behind
+                # them and is left out).
+                n_real = jnp.sum(valid, axis=1)                # [B]
+                keep = jax.vmap(lambda w, i: jax.lax.dynamic_slice_in_dim(
+                    w, i, kw - 1, axis=0))(window, n_real)
+                conv_pool = flat.at[layer, slots].set(
+                    keep.reshape(b, -1).astype(flat.dtype),
+                    mode="drop").reshape(conv_pool.shape)
+        xs, b_mat, c_mat = jnp.split(xbc_act, [di, di + n], axis=-1)
+        xs = xs.reshape(b, t, h, p)
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias)         # [B,T,H]
+        if valid is not None:
+            dt = jnp.where(valid[..., None], dt, 0.0)
+        a = -jnp.exp(a_log)
+        if cache is not None and t == 1:
+            with jax.named_scope("ssm.step"):
+                y, ssm_pool = _step_rows(
+                    ssm_pool, layer, slots, fresh, dt[:, 0], a, xs[:, 0],
+                    b_mat[:, 0], c_mat[:, 0])
+                y = y[:, None]
+        else:
+            with jax.named_scope("ssm.scan"):
+                s_in = None
+                if cache is not None:
+                    s_in = jnp.where(
+                        fresh[:, None, None, None], 0.0,
+                        _load_rows(ssm_pool, layer, slots).astype(f32))
+                y, s_out = ssd_scan(xs, dt, a, b_mat, c_mat,
+                                    cfg.mamba_chunk, s_in)
+                if cache is not None:
+                    ssm_pool = _store_rows(ssm_pool, layer, slots, s_out)
+        with jax.named_scope("ssm.gate_norm"):
+            y = y + d_skip[:, None] * xs
+            y = y.reshape(b, t, di) * nn.silu(z.astype(f32))
+            y = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(y)
+        with jax.named_scope("ssm.out_proj"):
+            out = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                           kernel_init=init, name="out_proj")(y)
+        return out if cache is None else (out, (conv_pool, ssm_pool))
+
+
+class GraniteAttention(nn.Module):
+    cfg: GraniteConfig
+
+    @nn.compact
+    def __call__(self, y, cache=None):
+        cfg = self.cfg
+        h, hk, dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+        b, t = y.shape[0], y.shape[1]
+        init = nn.initializers.normal(0.02)
+        with jax.named_scope("attn.qkv"):       # no position encoding
+            q, k, v = (nn.Dense(heads * dh, use_bias=False, dtype=cfg.dtype,
+                                kernel_init=init, name=name)(y)
+                       .reshape(b, t, heads, dh)
+                       for name, heads in (("wq", h), ("wk", hk),
+                                           ("wv", hk)))
+        att, new_cache = attention(cfg, q, k, v, cache,
+                                   scale=cfg.attention_multiplier)
+        with jax.named_scope("attn.out"):
+            out = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                           kernel_init=init,
+                           name="wo")(att.reshape(b, t, h * dh))
+        return out, new_cache
+
+
+class GraniteBlock(nn.Module):
+    cfg: GraniteConfig
+    kind: str
+
+    @nn.compact
+    def __call__(self, x, cache=None):
+        """``cache`` is the attention core's (an attention layer) or the
+        mixer's (a state-space layer); returns x, or (x, what the layer
+        updated)."""
+        from ..ops.moe import MoEMLP
+
+        cfg = self.cfg
+        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mixer_norm")(x)
+        if self.kind == ATTENTION:
+            m, new = GraniteAttention(cfg, name="attn")(y, cache)
+        else:
+            m = Mamba2Mixer(cfg, name="mamba")(y, cache)
+            new = None
+            if cache is not None:
+                m, new = m
+        x = x + (cfg.residual_multiplier * m).astype(x.dtype)
+        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
+        positions = cache["positions"] if cache is not None else None
+        with jax.named_scope("mlp"):
+            routed = MoEMLP(
+                d_model=cfg.d_model, d_ff=cfg.d_ff,
+                num_experts=cfg.n_experts, top_k=cfg.experts_per_token,
+                gated=True, norm_topk_prob=True, act=nn.silu,
+                dtype=cfg.dtype, first_expert=cfg.first_expert,
+                held_experts=cfg.held_experts, name="moe")(
+                    y, None if positions is None else positions >= 0)
+            with jax.named_scope("moe.shared"):
+                init = nn.initializers.normal(0.02)
+                gate, up = (nn.Dense(cfg.shared_d_ff, use_bias=False,
+                                     dtype=cfg.dtype, kernel_init=init,
+                                     name=name)(y)
+                            for name in ("shared_gate", "shared_up"))
+                z = _constrain(nn.silu(gate) * up,
+                               ("batch", "seq", "mlp"), cfg.mesh)
+                shared = nn.Dense(cfg.d_model, use_bias=False,
+                                  dtype=cfg.dtype, kernel_init=init,
+                                  name="shared_down")(z)
+            x = x + (cfg.residual_multiplier * (routed + shared)
+                     ).astype(x.dtype)
+        return x if cache is None else (x, new)
+
+
+class Granite(nn.Module):
+    cfg: GraniteConfig
+
+    @nn.compact
+    def __call__(self, tokens, kv_cache=None, positions=None):
+        """Full forward (kv_cache=None) or a step against the caches,
+        the contract of GPT2.__call__ with one more kind of cache:
+        ``k_pages`` / ``v_pages`` [attention layers, pages, page,
+        h_kv*d]; ``conv`` [state-space layers, slots, d_conv-1,
+        conv_dim], ``ssm`` [state-space layers, slots, H, P, N] float32
+        and ``slots`` [B] (each row's slot; outside the pool: a padded
+        row); all carried whole through the layers.  Returns (logits,
+        the cache updated)."""
+        cfg = self.cfg
+        cached = kv_cache is not None
+        emb = self.param("embed", nn.initializers.normal(0.02),
+                         (cfg.vocab_size, cfg.d_model), jnp.float32)
+        with jax.named_scope("embed"):
+            x = emb.astype(cfg.dtype)[tokens] * jnp.asarray(
+                cfg.embedding_multiplier, cfg.dtype)
+            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
+        block = GraniteBlock
+        if cfg.remat and not cached:
+            block = nn.remat(GraniteBlock, prevent_cse=False)
+        if cached:
+            new = dict(kv_cache)
+        seen = {MAMBA: 0, ATTENTION: 0}
+        for i, kind in enumerate(cfg.layer_types):
+            blk = block(cfg, kind, name=f"layer_{i}")
+            if not cached:
+                x = blk(x)
+            elif kind == ATTENTION:
+                x, (new["k_pages"], new["v_pages"]) = blk(x, cache={
+                    "k_pages": new["k_pages"], "v_pages": new["v_pages"],
+                    "layer": seen[kind], "page_table": new["page_table"],
+                    "positions": positions})
+            else:
+                x, (new["conv"], new["ssm"]) = blk(x, cache={
+                    "conv": new["conv"], "ssm": new["ssm"],
+                    "layer": seen[kind], "slots": new["slots"],
+                    "positions": positions})
+            seen[kind] += 1
+            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
+        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
+        with jax.named_scope("lm_head"):        # tied to the embedding
+            logits = jnp.einsum("btd,vd->btv", x, emb.astype(cfg.dtype),
+                                preferred_element_type=jnp.float32) \
+                / cfg.logits_scaling
+            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg.mesh)
+        return (logits, new) if cached else logits
+
+
+# ------------------------------------------------------ init, loss, rules
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _normal_leaf(key, shape, std: float, dtype):
+    return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+
+
+def _special_leaf(cfg: GraniteConfig, name: str, key, shape):
+    """The mixer's leaves that are not normal(0, 0.02): None for the
+    others."""
+    leaf = name.rsplit("/", 1)[-1]
+    if leaf == "embed":     # so that embedding_multiplier x E has std 0.02
+        return _normal_leaf(key, shape, 0.02 / cfg.embedding_multiplier,
+                            jnp.dtype(cfg.param_dtype))
+    if name.endswith(("wq/kernel", "wk/kernel")):
+        # q and k each larger by sqrt(1/sqrt(d) / attention_multiplier),
+        # so that the scores have the spread 1/sqrt(d) gives at 0.02
+        grow = (cfg.head_dim ** -0.5 / cfg.attention_multiplier) ** 0.5
+        return _normal_leaf(key, shape, 0.02 * grow,
+                            jnp.dtype(cfg.param_dtype))
+    if leaf == "A_log":
+        return jnp.log(jnp.arange(1, shape[0] + 1, dtype=jnp.float32))
+    if leaf == "D":
+        return jnp.ones(shape, jnp.float32)
+    if leaf == "dt_bias":   # inverse softplus of a step in [1e-3, 1e-1]
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                     * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    if leaf in ("conv_w", "conv_b"):    # PyTorch's depthwise default
+        bound = cfg.mamba_d_conv ** -0.5
+        return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+    return None
+
+
+def granite_init(cfg: GraniteConfig, rng):
+    """The weights from the seed, leaf by leaf as ``llama_init`` makes
+    them (shapes by ``eval_shape``; each leaf float32 from a key folded
+    from its path, cast to ``cfg.param_dtype``): matrices normal(0,
+    0.02), norm scales 1, the tied embedding normal(0, 0.02 /
+    ``embedding_multiplier``) (at 0.02 the tied head makes every
+    position's largest logit its own input token by a wide margin, and
+    greedy decoding repeats the prompt's last token whatever the layers
+    compute), ``wq`` and ``wk`` normal(0, 0.02 x (1/sqrt(d) /
+    ``attention_multiplier``)^(1/2)) (0.0673 at the published sizes: at
+    0.02 the scores ``attention_multiplier x q k^T`` have a standard
+    deviation of 0.14, every softmax is flat, and attention is an average
+    over the past that neither a wrong scale nor a position encoding nor
+    a wrong page can move), and the mixer's own: ``A_log = log(1..H)``,
+    ``D = 1``, ``dt_bias`` the inverse softplus of a step log-uniform in
+    [0.001, 0.1], the conv's weight and bias uniform in +-1/sqrt(taps);
+    those four stay float32 (they feed ``dt`` and the decay)."""
+    from .llama import _init_leaf
+
+    init_cfg = dataclasses.replace(cfg, mesh=None, attn_impl="dense")
+    shapes = jax.eval_shape(Granite(init_cfg).init, rng,
+                            jnp.zeros((1, 8), jnp.int32))
+
+    def make(path, spec):
+        name = "/".join(str(getattr(p, "key", p)) for p in path)
+        key = jax.random.fold_in(rng, zlib.crc32(name.encode()))
+        special = _special_leaf(cfg, name, key, spec.shape)
+        if special is not None:
+            return special
+        return _init_leaf(key, spec.shape, name.endswith("scale"),
+                          jnp.dtype(cfg.param_dtype))
+
+    return jax.tree_util.tree_map_with_path(make, shapes)
+
+
+def granite_loss_fn(cfg: GraniteConfig, params, batch):
+    """Mean next-token cross entropy (the source's config names no router
+    loss coefficient, so there is none)."""
+    tokens = batch["tokens"]
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    return _next_token_xent(Granite(cfg).apply(params, inputs), targets)
+
+
+def granite_partition_rules():
+    """fsdp + tensor rules for Granite trees: the mixer's projections as
+    a column- then a row-parallel pair, the experts as OLMoE's, every
+    expert on every chip (an ``expert`` mesh axis is ROADMAP Reach's)."""
+    from jax.sharding import PartitionSpec as PS
+
+    return (
+        ("embed$", PS("tensor", "fsdp")),
+        (r"moe/(w_gate|w_up)$", PS(None, "fsdp", "tensor")),
+        (r"moe/w_down$", PS(None, "tensor", "fsdp")),
+        (r"moe/router$", PS("fsdp", None)),
+        (r"(w[qkv]|in_proj|shared_gate|shared_up)/kernel$",
+         PS("fsdp", "tensor")),
+        (r"(wo|out_proj|shared_down)/kernel$", PS("tensor", "fsdp")),
+        (r"(scale|bias|conv_w|conv_b|A_log|D|dt_bias)$", PS()),
+    )

@@ -336,7 +336,8 @@ _flash_bhtd_lse.defvjp(_flash_bhtd_lse_fwd, _flash_bhtd_lse_bwd)
 
 def flash_attention_with_lse(q, k, v, *, causal: bool = True,
                              block_q: int = 256, block_k: int = 256,
-                             interpret: bool | None = None):
+                             interpret: bool | None = None,
+                             scale: float | None = None):
     """Flash attention that also returns the row log-sum-exp.
 
     q, k, v: [B, T, H, D] -> (out [B, T, H, D], lse [B, T, H] fp32).
@@ -351,7 +352,7 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = True,
     if t % block_q or t % block_k:
         raise ValueError(f"seq len {t} must divide block sizes "
                          f"({block_q}, {block_k})")
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else float(scale)
 
     def fold(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
@@ -365,8 +366,10 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = True,
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: int = 256, block_k: int = 256,
-                    interpret: bool | None = None):
-    """Causal flash attention.  q, k, v: [B, T, H, D] -> [B, T, H, D].
+                    interpret: bool | None = None,
+                    scale: float | None = None):
+    """Causal flash attention.  q, k, v: [B, T, H, D] -> [B, T, H, D];
+    ``scale`` multiplies q k^T (None: ``D ** -0.5``).
 
     ``interpret=None`` auto-selects: the compiled kernel when the
     backend is ``tpu`` (never interpreted there unless a caller asks by
@@ -384,7 +387,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     if t % block_q or t % block_k:
         raise ValueError(f"seq len {t} must divide block sizes "
                          f"({block_q}, {block_k})")
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else float(scale)
 
     def fold(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)

@@ -25,6 +25,16 @@ marked invalid (the decode batch's padding: negative positions) are sent
 to no expert and count nowhere.  Differentiable end to end (the trainer
 runs the same op).
 
+A layer may hold a SHARE of its experts (``first_expert``,
+``held_experts``: a contiguous run of the ``num_experts`` the router
+scores; one chip's part under expert parallelism).  It routes over all of
+them, sends the pairs of the experts it does not hold where the invalid
+rows' pairs go, to no group, and returns its own experts' part of the
+result; its matrices, ``load`` and ``prob_mean`` are of the held experts
+only.  Nothing stands in for the absent ones: the shares of all the chips
+add up to the whole layer (tests/test_granite.py).  Holding all of them
+is the default.
+
 Each layer sows ``moe`` into flax's ``intermediates``: ``load`` [E] (pairs
 per expert), ``prob_mean`` [E] and ``z`` (mean squared log-sum-exp of the
 router logits), from which come the training losses (``moe_losses``) and
@@ -76,6 +86,8 @@ class MoEMLP(nn.Module):
     norm_topk_prob: bool = True
     act: Callable = nn.gelu
     dtype: Any = jnp.bfloat16
+    first_expert: int = 0               # the share held here:
+    held_experts: Optional[int] = None  # None: all ``num_experts``
 
     @nn.compact
     def __call__(self, x: jnp.ndarray,
@@ -83,9 +95,13 @@ class MoEMLP(nn.Module):
         """x [B, T, d]; ``valid`` [B, T] bool marks the real rows (None:
         all)."""
         b, t, d = x.shape
-        e, k, s = self.num_experts, self.top_k, b * t
+        n, k, s = self.num_experts, self.top_k, b * t
+        e = n if self.held_experts is None else self.held_experts
+        first = self.first_expert
+        if not 0 <= first <= first + e <= n:
+            raise ValueError(f"experts {first}..{first + e} of {n}")
         init = nn.initializers.normal(0.02)
-        router = self.param("router", init, (d, e), jnp.float32)
+        router = self.param("router", init, (d, n), jnp.float32)
         names = ("w_up", "w_down") if self.gated else ("w_in", "w_out")
         w_gate = self.param("w_gate", init, (e, d, self.d_ff),
                             jnp.float32) if self.gated else None
@@ -98,7 +114,13 @@ class MoEMLP(nn.Module):
             logits = xf.astype(jnp.float32) @ router.astype(jnp.float32)
             weights, experts, probs = route(logits, k, self.norm_topk_prob)
             # An invalid row's pairs go to "expert E": behind every
-            # group, in none of them.
+            # group, in none of them; so do the pairs of an expert that
+            # is not held here.
+            if e != n:
+                experts = experts - first
+                experts = jnp.where((experts >= 0) & (experts < e),
+                                    experts, e)
+                probs = probs[:, first:first + e]
             experts = jnp.where(real[:, None], experts, e)
             load = jnp.zeros((e + 1,), jnp.int32).at[
                 experts.reshape(-1)].add(1)[:e]

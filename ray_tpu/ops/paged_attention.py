@@ -146,14 +146,16 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm,
 
 def paged_decode(q, k_pages, v_pages, layer, page_table, lengths,
                  *, block_pages: int = BLOCK_PAGES,
-                 interpret: bool | None = None):
+                 interpret: bool | None = None,
+                 scale: float | None = None):
     """Attention of one query row a sequence, q ``[B, 1, h, d]``, against
     layer ``layer`` of the WHOLE pool ``[L, pages, page, h_kv*d]``:
     sequence ``b`` attends positions ``0 .. lengths[b] - 1``, which live
     in the pages ``page_table[b]`` names (entries beyond its pages are
     never read).  Returns ``[B, 1, h, d]`` in q's dtype; a row of length
     0 gives zeros.  float32 scores and softmax over K/V as stored: the
-    mathematics of ``paged_attend``, which is its plain definition.
+    mathematics of ``paged_attend``, which is its plain definition
+    (``scale`` multiplies the scores; None: ``d ** -0.5``).
 
     ``interpret=None`` runs the compiled kernel on the ``tpu`` backend
     and the interpreter elsewhere (tests)."""
@@ -167,12 +169,14 @@ def paged_decode(q, k_pages, v_pages, layer, page_table, lengths,
     return _paged_decode(
         q, k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1),
         page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-        block_pages=block_pages, interpret=interpret)
+        block_pages=block_pages, interpret=interpret,
+        scale=q.shape[-1] ** -0.5 if scale is None else float(scale))
 
 
-@functools.partial(jax.jit, static_argnames=("block_pages", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("block_pages", "interpret", "scale"))
 def _paged_decode(q, k_pages, v_pages, layer, page_table, lengths, *,
-                  block_pages, interpret):
+                  block_pages, interpret, scale):
     b, _, h, d = q.shape
     page, hd = k_pages.shape[2], k_pages.shape[3]
     h_kv = hd // d
@@ -184,7 +188,7 @@ def _paged_decode(q, k_pages, v_pages, layer, page_table, lengths, *,
     rows_g = -(-h_kv // sublanes) * sublanes
     rows = block_pages * page
     kernel = functools.partial(
-        _decode_kernel, scale=d ** -0.5, d=d, rep=rep, rows_g=rows_g,
+        _decode_kernel, scale=scale, d=d, rep=rep, rows_g=rows_g,
         page=page, block_pages=block_pages)
     with jax.named_scope("kv.attend"):
         out = pl.pallas_call(

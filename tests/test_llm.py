@@ -441,10 +441,10 @@ def test_cached_forward_goes_through_the_one_attention_core(
                          (paged_attention, "paged_decode")):
         real = getattr(module, name)
 
-        def spy(*args, _real=real, _name=name):
+        def spy(*args, _real=real, _name=name, **kwargs):
             callers.append(
                 (_name, sys._getframe(1).f_globals["__name__"]))
-            return _real(*args)
+            return _real(*args, **kwargs)
 
         monkeypatch.setattr(module, name, spy)
     fam = MODEL_FAMILIES[family]
@@ -454,7 +454,7 @@ def test_cached_forward_goes_through_the_one_attention_core(
     def two_steps():        # a prefill, then one decode step
         del callers[:]
         return _decode_loop(fam.module(cfg), params, cfg,
-                            fam.kv_heads(cfg), [3, 17, 42], 2)[0]
+                            fam.cache(cfg).kv_heads, [3, 17, 42], 2)[0]
 
     def layers(attend):
         return [("paged_store", "ray_tpu.models.attention"),
@@ -523,6 +523,56 @@ def test_forward_updates_donated_pool_in_place(family):
         assert m.alias_size_in_bytes == 2 * pool_bytes, shape
         assert m.temp_size_in_bytes < 0.5 * pool_bytes, (
             shape, m.temp_size_in_bytes / pool_bytes)
+
+
+@pytest.mark.parametrize("shape", [(2, 1), (1, 32), (1, 8)],
+                         ids=["decode", "prefill_chunks", "prefill_1chunk"])
+def test_forward_updates_the_donated_state_pool_in_place(shape):
+    """The second kind of cache as the first: a model with recurrent
+    layers (models/granite.py) takes the state pool's ``conv`` and
+    ``ssm`` donated, carries them whole through its layers and writes
+    each row's slot where it lies: all four arrays are aliased to the
+    outputs (a decode step; a prefill of several chunks, 32 positions in
+    chunks of 8, and of one), and the decode step's temporaries do not
+    grow with the state pool.  (A prefill's do HERE: the CPU's compiler
+    copies the pool around the one-row loops that read and write the
+    prefill's slot; the TPU's programs, which hold no such copy, are
+    pinned in tests/test_tpu_compile.py.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_cache, init_state, pages_for
+    from ray_tpu.models import MODEL_FAMILIES
+
+    fam = MODEL_FAMILIES["granitemoehybrid"]
+    cfg = dataclasses.replace(fam.tiny(), remat=False,
+                              layer_types=("mamba", "attention") * 2
+                              + ("mamba",) * 2)
+    spec = fam.cache(cfg)
+    assert (spec.kv_layers, spec.state_layers) == (2, 4)
+    params = jax.eval_shape(lambda: fam.init(cfg, jax.random.PRNGKey(0)))
+    kv = jax.eval_shape(lambda: init_cache(
+        spec.kv_layers, 64, 16, spec.kv_heads, spec.head_dim, cfg.dtype))
+    state = jax.eval_shape(lambda: init_state(spec, 256, cfg.dtype))
+    assert state["ssm"].shape == (4, 256, 4, 16, 16)
+    assert state["conv"].shape == (4, 256, 3, 96)
+
+    def nbytes(a):
+        return a.size * a.dtype.itemsize
+
+    state_bytes = nbytes(state["conv"]) + nbytes(state["ssm"])
+    ints = jax.ShapeDtypeStruct(shape, jnp.int32)
+    m = jit_forward(fam.module(cfg)).lower(
+        params, ints, kv["k_pages"], kv["v_pages"],
+        jax.ShapeDtypeStruct((shape[0], pages_for(cfg.max_seq, 16)),
+                             jnp.int32), ints, state["conv"],
+        state["ssm"], jax.ShapeDtypeStruct(shape[:1], jnp.int32)
+    ).compile().memory_analysis()
+    assert m.alias_size_in_bytes == 2 * nbytes(kv["k_pages"]) + state_bytes
+    if shape[1] == 1:
+        assert m.temp_size_in_bytes < 0.5 * nbytes(state["ssm"]), (
+            shape, m.temp_size_in_bytes / nbytes(state["ssm"]))
 
 
 # --------------------------------------------------- rope table cache

@@ -214,7 +214,9 @@ def test_engine_builds_every_family_from_its_registry_row(family):
     while not seq.finished:
         engine.step()
     assert seq.generated == 3 and engine.stats()["step_errors"] == 0
-    assert ("moe" in engine.stats()) == (family == "olmoe")
+    assert ("moe" in engine.stats()) == (
+        family in ("olmoe", "granitemoehybrid"))
+    assert ("state" in engine.stats()) == (family == "granitemoehybrid")
 
 
 def test_engine_counts_gpt2s_moe_option_too():

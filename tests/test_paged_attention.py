@@ -146,7 +146,7 @@ def test_engine_tokens_are_the_same_through_the_kernel(family,
 
     fam = MODEL_FAMILIES[family]
     cfg = dataclasses.replace(fam.tiny(), remat=False, dtype=jnp.float32)
-    assert (fam.kv_heads(cfg) < cfg.n_head) == (family == "llama")
+    assert (fam.cache(cfg).kv_heads < cfg.n_head) == (family == "llama")
     # Weights large enough that the greedy tokens vary.
     params = jax.tree_util.tree_map(
         lambda x: x * 6.0, fam.init(cfg, jax.random.PRNGKey(5)))

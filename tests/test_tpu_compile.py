@@ -355,6 +355,73 @@ def test_olmoe_decode_cell_compiles_with_grouped_matmuls_in_place(
         _assert_attends_in_place(compiled, pool, cfg.n_layer, 0.2e9)
 
 
+@pytest.mark.parametrize("tokens_shape", [(16, 1), (1, 256), (1, 512)],
+                         ids=["decode", "prefill_1chunk", "prefill_2chunks"])
+def test_granite_cell_updates_both_caches_in_place(one_chip, tokens_shape):
+    """The programs of serve-granite-4.0-h-small-sat at the published
+    widths (one period of the layer pattern: 9 state-space layers and 1
+    with attention; 18 of 72 experts held; bf16; max_batch 16 slots, 1024
+    pages, max_context 1024), as the backend ``tpu`` builds them: they
+    fit the chip; the K/V pool (of the ONE attention layer) and the state
+    pool (``conv``, ``ssm`` float32 [9, 16, 128, 64, 128]) are aliased to
+    the outputs and updated where they lie: no copy of a pool or of a
+    layer of it, and temporaries of less than a twentieth of the state
+    pool for the decode step (a gathered batch of states is a ninth) and
+    a quarter for a prefill (activations; the pool re-laid for its
+    update, as a one-chunk prefill first had it, is all of it); the
+    attention layer's decode step goes through the paged kernel with
+    Granite's scale; the experts run as the compiler's grouped kernels
+    over the 18 held."""
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_cache, init_state, pages_for
+    from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.models.granite import GraniteConfig
+
+    row = MODEL_FAMILIES["granitemoehybrid"]
+    cfg = GraniteConfig(layer_types=GraniteConfig().layer_types[:10],
+                        held_experts=18, max_seq=1024, attn_impl="dense",
+                        remat=False)
+    spec = row.cache(cfg)
+    assert (spec.kv_layers, spec.state_layers) == (1, 9)
+    params = jax.eval_shape(lambda: row.init(cfg, jax.random.PRNGKey(0)))
+    kv = jax.eval_shape(lambda: init_cache(
+        spec.kv_layers, 1024, 16, spec.kv_heads, spec.head_dim, cfg.dtype))
+    state = jax.eval_shape(lambda: init_state(spec, 16, cfg.dtype))
+    b = tokens_shape[0]
+    ints = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                             sharding=one_chip)
+    with _as_on_tpu():
+        compiled = jit_forward(row.module(cfg)).lower(
+            _on(params, one_chip), ints(tokens_shape),
+            _on(kv["k_pages"], one_chip), _on(kv["v_pages"], one_chip),
+            ints((b, pages_for(1024, 16))), ints(tokens_shape),
+            _on(state["conv"], one_chip), _on(state["ssm"], one_chip),
+            ints((b,))).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+    m = compiled.memory_analysis()
+    pools = [kv["k_pages"], kv["v_pages"], state["conv"], state["ssm"]]
+    assert m.alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in pools)
+    ssm = state["ssm"]
+    share = 0.05 if tokens_shape[1] == 1 else 0.25
+    assert m.temp_size_in_bytes < share * ssm.size * ssm.dtype.itemsize, \
+        m.temp_size_in_bytes / (ssm.size * ssm.dtype.itemsize)
+    text = compiled.as_text()
+    # (a layer of ``conv`` has the shape of a decode batch's windows)
+    shapes = {",".join(map(str, shape)) for a in pools
+              for shape in (a.shape, a.shape[1:])} - {
+        ",".join(map(str, state["conv"].shape[1:]))}
+    copies = [line.strip()[:120] for line in text.splitlines()
+              if (hit := re.match(r"^\s*(?:ROOT )?%?[\w.-]+ = \w+\[([\d,]+)\]"
+                                  r"\S* (copy|copy-done|transpose)\(", line))
+              and hit.group(1) in shapes]
+    assert not copies, (len(copies), copies[:4])
+    assert text.count('op_name="ragged-dot-metadata"') == cfg.n_layer
+    kernels = re.findall(
+        r"^\s*(?:ROOT )?%paged_decode[\w.]* = .*custom-call\(", text, re.M)
+    assert len(kernels) == (1 if tokens_shape[1] == 1 else 0)
+
+
 def _train_step_and_shapes(cfg, loss_chunk):
     from ray_tpu.models.gpt2 import gpt2_init, gpt2_loss_fn
     from ray_tpu.train.train_step import TrainState, make_optimizer

@@ -121,6 +121,45 @@ def test_jax_trainer_runs_olmoe_and_reports_router_losses(tmp_path):
     assert first["loss"] > first["ce"]
 
 
+def _granite_loop(config):
+    """Tiny Granite (models/granite.py: state-space layers beside
+    attention, experts 2-5 of 8 held plus the shared one) through the
+    registry row: the chunked scan and the expert share under grad."""
+    import dataclasses
+
+    import jax
+
+    from ray_tpu import train
+    from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.train.train_step import (TrainState, make_optimizer,
+                                          make_sharded_train_step)
+
+    family = MODEL_FAMILIES["granitemoehybrid"]
+    cfg = dataclasses.replace(family.tiny(), first_expert=2,
+                              held_experts=4)
+    opt = make_optimizer(learning_rate=1e-3, warmup_steps=1, total_steps=20)
+    state = TrainState.create(family.init(cfg, jax.random.PRNGKey(0)), opt)
+    step_fn = make_sharded_train_step(
+        lambda p, b: family.loss(cfg, p, b), opt)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 30), 0,
+                                cfg.vocab_size)
+    for i in range(config["steps"]):
+        state, metrics = step_fn(state, {"tokens": tokens})
+        train.report({"loss": float(metrics["loss"]), "step": i + 1})
+    return float(metrics["loss"])
+
+
+def test_jax_trainer_runs_granite(tmp_path):
+    result = JaxTrainer(
+        _granite_loop, train_loop_config={"steps": 3},
+        scaling_config=ScalingConfig(num_workers=1),
+        run_config=RunConfig(name="granite", storage_path=str(tmp_path))
+    ).fit()
+    assert result.error is None and result.metrics["step"] == 3
+    losses = [h["metrics"]["loss"] for h in result.metrics_history]
+    assert losses[-1] < losses[0] < 6.0     # ln(256) = 5.55 at the start
+
+
 def test_jax_trainer_resume(tmp_path):
     run = RunConfig(name="t2", storage_path=str(tmp_path))
     r1 = JaxTrainer(_gpt2_loop, train_loop_config={"steps": 3},
