@@ -13,6 +13,29 @@ others are mid-generation starts decoding on the very next step — the
 continuous-batching property the serving cells measure as TTFT under
 load (pinned by tests/test_llm_engine.py).
 
+The loop is a pipeline of depth one: the engine LAUNCHES a program (a
+prefill, or a decode step) and only then reads the token ids of the
+program launched before it, so the host's bookkeeping, its frame queues
+and its next schedule run under the device's work and not between its
+programs.  Nothing a launch needs comes from the host's copy of the ids:
+a sequence keeps its row of the decode batch while it runs, each row's
+latest id stays on the device (``sampling.py jit_feed`` puts a program's
+ids into the ``[max_batch, 1]`` array the next decode step takes as its
+tokens), and positions, pages and the sampler's key advance when a
+program is LAUNCHED (``_Sequence.launched``, ``n_cached``); tokens,
+frames, counters and retirement advance when its ids are DELIVERED
+(``_Sequence.generated``).  A sequence that reaches ``max_tokens`` or
+the context's end by its launched count is not launched again and gives
+its row and pages back at once (the device runs programs in launch
+order: whatever takes them next writes after it).  Only EOS and
+cancellation are not known ahead: such a row was launched once more, and
+that one result is dropped.  The pipeline drains (the ids are read
+before anything is decided) for an eviction, on a step error, when the
+batch is empty and at ``stop()``.  ``stats()["pipeline"]`` counts
+``launched_ahead`` (programs launched while another's ids were unread),
+``drains`` by cause (``evict``, ``error``, ``empty``, ``stop``) and
+``rows_discarded`` (launched rows whose result was dropped).
+
 Memory pressure is handled vLLM-style by recompute preemption: when a
 running sequence needs a page and the pool is empty, the most recently
 admitted OTHER sequence is evicted — pages freed, tokens kept — and
@@ -46,12 +69,16 @@ agree: every phase of ``step()`` is a profiler annotation
 ``llm.prefill.run`` ...), so a device capture shows what the host was
 doing in each idle gap, and the leaves' ``perf_counter`` sums are in
 ``stats()["phase_s"]`` (PHASE_LEAVES; ``llm.other`` is the rest of
-``step_s``, so the parts sum to the whole).  Nothing of this goes into
-the span ring: only the per-request lifecycle spans do.
+``step_s``, so the parts sum to the whole).  The ids of the program
+before are fetched AFTER the launch and inside the ``llm.decode`` /
+``llm.prefill`` annotation of that launch, so a capture still finds each
+device run's start under the annotation that launched it.  Nothing of
+this goes into the span ring: only the per-request lifecycle spans do.
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import queue
 import threading
@@ -66,7 +93,7 @@ from ..util import chips
 from ..util.spans import annotate
 from .kv_cache import (PagePool, SlotPool, init_cache, init_state,
                        pages_for)
-from .sampling import (SamplingParams, jit_sampler, pack_rows,
+from .sampling import (SamplingParams, jit_feed, jit_sampler, pack_rows,
                        seed_words)
 
 
@@ -90,8 +117,10 @@ class EngineConfig:
 # The leaf phases of one step(): each is an annotation of this name and a
 # cumulative-seconds entry of stats()["phase_s"].  ``llm.admit`` is the
 # admission's own time (lock, page allocation), its prefills apart.
-# ``.run`` launches the forward and the sampler, ``.fetch`` waits for the
-# token ids, ``.sample`` is the host's bookkeeping per token.
+# ``.run`` launches the forward, the sampler and the feed; ``.fetch`` waits
+# for the token ids of the program launched BEFORE that one (of the
+# program itself in a drain); ``.sample`` is the host's bookkeeping per
+# token of the ids just fetched.
 PHASE_LEAVES = (
     "llm.cancel", "llm.admit",
     "llm.prefill.pack", "llm.prefill.run", "llm.prefill.fetch",
@@ -134,8 +163,8 @@ class _Sequence:
     """One in-flight generation request (engine-internal)."""
 
     __slots__ = ("sid", "tokens", "prompt_len", "max_tokens", "params",
-                 "seed", "out", "pages", "slot", "n_cached", "generated",
-                 "finished", "cancelled", "submitted_ts",
+                 "seed", "out", "pages", "slot", "n_cached", "launched",
+                 "generated", "finished", "cancelled", "submitted_ts",
                  "request_id", "first_token_ts", "last_token_ts",
                  "warmup")
 
@@ -151,8 +180,15 @@ class _Sequence:
         self.seed = seed_words(seed)    # the sampler's key, two uint32
         self.out: "queue.Queue" = queue.Queue()
         self.pages: List[int] = []
-        self.slot: Optional[int] = None  # its place in the state pool
-        self.n_cached = 0               # tokens written into KV pages
+        # Its row while it runs: of the decode batch, of the device's
+        # token array and, where the model keeps one, of the state pool.
+        self.slot: Optional[int] = None
+        # Advanced at LAUNCH: positions written into KV pages by the
+        # programs launched so far, and the tokens those programs
+        # sample (the next one's index: the sampler's key word).
+        self.n_cached = 0
+        self.launched = 0
+        # Advanced at DELIVERY, with ``tokens`` and the frames.
         self.generated = 0
         self.finished = False
         self.cancelled = False
@@ -167,6 +203,27 @@ class _Sequence:
         # multi-second samples must not enter the TTFT-phase/TPOT
         # accounting real traffic is judged by.
         self.warmup = warmup
+
+
+class _Flight:
+    """A launched program whose token ids the host has not read: the ids
+    ``[max_batch]`` (and a decode step's routing counters) still on the
+    device, and whose each row is."""
+
+    __slots__ = ("kind", "ids", "moe", "rows", "admitted")
+
+    def __init__(self, kind: str, ids, moe, rows,
+                 admitted: Optional[float] = None):
+        self.kind = kind                # "decode" or "prefill"
+        self.ids, self.moe = ids, moe
+        self.rows: List[tuple] = rows   # (sequence, its row of ``ids``)
+        # When a FIRST admission's prefill began: its delivery is the
+        # request's first token (TTFT's prefill phase ends there).
+        self.admitted = admitted
+
+
+# A decode row without a sequence, to the sampler: argmax.
+_IDLE_ROW = (SamplingParams(), (0, 0), 0)
 
 
 def jit_forward(model):
@@ -255,18 +312,20 @@ class GenerationEngine:
         self._kv = init_cache(spec.kv_layers, self.cfg.num_pages,
                               self.cfg.page_size, n_kv, head_dim,
                               model_cfg.dtype)
-        # One slot a running sequence; None for a model without
-        # recurrent layers, whose programs and stats() are as they were.
-        # What the decode steps' recurrent layers move is counted beside
-        # the slots (stats()["state"]): ``state_rows_updated`` = running
-        # rows x recurrent layers, one row = one sequence's conv window
-        # and state of one layer (``state_row_bytes``, read and written
-        # once a step); ``mixer_weight_bytes`` = one layer's mixer
-        # matrices.
-        self.slots = self._state = None
+        # One slot a running sequence: its row of the decode batch
+        # (it keeps it while it runs, so the device's ids of one step
+        # are the next step's tokens row for row) and, where the model
+        # has recurrent layers, of the state pool.  What the decode
+        # steps' recurrent layers move is counted beside the slots
+        # (stats()["state"], absent for a model without such layers):
+        # ``state_rows_updated`` = running rows x recurrent layers, one
+        # row = one sequence's conv window and state of one layer
+        # (``state_row_bytes``, read and written once a step);
+        # ``mixer_weight_bytes`` = one layer's mixer matrices.
+        self.slots = SlotPool(self.cfg.max_batch)
+        self._state = None
         self._state_counts: Dict[str, int] = {}
         if spec.state_layers:
-            self.slots = SlotPool(self.cfg.max_batch)
             self._state = init_state(spec, self.cfg.max_batch,
                                      model_cfg.dtype)
             self._state_counts = {
@@ -279,6 +338,18 @@ class GenerationEngine:
 
         self._fwd = jit_forward(self._model)
         self._sampler, self._last_rows = jit_sampler(self.cfg.max_batch)
+        # Each row's latest token id, [max_batch, 1] int32, on the
+        # device from the first feed on: what a decode step takes as its
+        # tokens.  Every program's ids are fed into it at launch.
+        self._feeder = jit_feed()
+        self._all_rows = np.arange(self.cfg.max_batch, dtype=np.int32)
+        self._tokens: Any = np.zeros_like(self._all_rows)[:, None]
+        # Launched programs whose ids are unread, oldest first: one
+        # between steps, two while the older one is being delivered.
+        self._flights: "deque[_Flight]" = deque()
+        self._pipeline = {"launched_ahead": 0, "rows_discarded": 0}
+        self._drains = dict.fromkeys(("evict", "error", "empty", "stop"),
+                                     0)
         # AOT executables by program name (lower().compile()): the
         # compile is timed and the program registered with the xprof
         # plane (rt perf).
@@ -390,6 +461,13 @@ class GenerationEngine:
             self._wake.notify_all()
         if self._thread is not None:
             self._thread.join(timeout=10)
+        if self._thread is None or not self._thread.is_alive():
+            # the loop has ended: deliver the ids it left unread
+            try:
+                with self._timed():
+                    self._drain("stop")
+            except Exception as e:  # noqa: BLE001
+                self._poison(e)
 
     def submit(self, prompt: List[int],
                max_tokens: Optional[int] = None,
@@ -525,6 +603,11 @@ class GenerationEngine:
                 "programs": dict(self._compile_seconds),
                 "sampling": dict(self._sampling),
                 "attention": dict(self._attention),
+                # The depth-one pipeline: programs launched while
+                # another's ids were unread, drains by cause, launched
+                # rows whose result was dropped (EOS, cancellation).
+                "pipeline": {**self._pipeline,
+                             "drains": dict(self._drains)},
                 "device": dict(self._device),
                 # TTFT phase + TPOT accounting.
                 "ttft_requests": self._ttft_requests,
@@ -542,7 +625,7 @@ class GenerationEngine:
                 **({"state": {"slots_total": self.slots.slots,
                               "slots_used": self.slots.used,
                               **self._state_counts}}
-                   if self.slots is not None else {}),
+                   if self._state is not None else {}),
             }
 
     # ------------------------------------------------------ engine loop
@@ -559,26 +642,40 @@ class GenerationEngine:
             try:
                 self.step()
             except Exception as e:  # noqa: BLE001
-                # Poison the in-flight sequences (their device/pool
-                # state may be mid-mutation) but KEEP the engine loop
-                # alive: the replica stays routable and health-checked
-                # either way, so dying here would brick it for every
-                # future request over one transient step failure.
-                self._last_error = repr(e)
-                self._step_errors += 1
-                with self._wake:
-                    seqs = list(self._running) + list(self._waiting)
-                    self._running.clear()
-                    self._waiting.clear()
-                for s in seqs:
-                    self._retire(s, error=repr(e))
+                self._poison(e)
+
+    def _poison(self, e: Exception) -> None:
+        """Error-retire the in-flight sequences (their device/pool
+        state may be mid-mutation), those of a launched program whose
+        ids are unread too (a device error surfaces at the fetch), but
+        KEEP the engine loop alive: the replica stays routable and
+        health-checked either way, so dying here would brick it for
+        every future request over one transient step failure."""
+        self._last_error = repr(e)
+        self._step_errors += 1
+        with self._wake:
+            seqs = list(self._running) + list(self._waiting) + [
+                seq for flight in self._flights for seq, _ in flight.rows]
+            self._drains["error"] += bool(self._flights)
+            self._running.clear()
+            self._waiting.clear()
+            self._flights.clear()
+        self._tokens = np.zeros_like(self._all_rows)[:, None]
+        for s in seqs:
+            self._retire(s, error=repr(e))
 
     def step(self) -> Dict[str, Any]:
-        """ONE engine iteration: cancellations -> admissions (prefill)
-        -> batched decode -> retirement.  Public for deterministic
-        single-step tests."""
-        t0 = time.perf_counter()
-        try:
+        """ONE engine iteration: cancellations -> admissions (each a
+        prefill LAUNCHED) -> one batched decode step LAUNCHED; after
+        each launch the ids of the program launched before it are
+        fetched and delivered (tokens, frames, retirement), while the
+        device runs the new one.  It returns with one program in
+        flight, its ids unread, unless nothing is left to launch: then
+        those ids are delivered here too, so the last step of a
+        sequence ends with its frames out.  The pipeline also drains
+        before an eviction; a device error surfaces at a fetch.  Public
+        for deterministic single-step tests."""
+        with self._timed():
             with annotate("llm.step", step=self._steps,
                           running=len(self._running),
                           waiting=len(self._waiting)):
@@ -595,18 +692,30 @@ class GenerationEngine:
                 if self._running:
                     with annotate("llm.decode", batch=len(self._running)):
                         self._decode_step()
+                if not self._running:
+                    self._drain("empty")
                 self._last_batch = len(self._running)
                 with self._phase("llm.publish"):
                     self._publish_gauges()
             self._steps += 1
+        return {"running": len(self._running),
+                "waiting": len(self._waiting)}
+
+    @contextlib.contextmanager
+    def _timed(self):
+        """The engine thread sums the leaves of what runs inside in
+        _pending; they are added to the totals with the whole's own time
+        in one go, under the lock, so that a stats() taken mid-step
+        still sums up."""
+        t0 = time.perf_counter()
+        try:
+            yield
         finally:
             with self._lock:
                 for name, seconds in self._pending.items():
                     self._phase_s[name] += seconds
                     self._pending[name] = 0.0
                 self._step_s += time.perf_counter() - t0
-        return {"running": len(self._running),
-                "waiting": len(self._waiting)}
 
     def _phase(self, name: str) -> _Phase:
         return _Phase(self._pending, name)
@@ -624,12 +733,15 @@ class GenerationEngine:
                     self._running.remove(seq)
                 if seq in self._waiting:
                     self._waiting.remove(seq)
+            # a program in flight may hold a row of it: dropped at its
+            # delivery
             self._retire(seq, reason="cancelled")
 
     def _admit(self) -> None:
         """Step-granularity admission: pull waiting sequences into the
         running batch (each admission = one prefill forward), bounded
-        by max_batch, the page pool, and the per-step token budget."""
+        by max_batch (a free row), the page pool, and the per-step token
+        budget."""
         budget = self.cfg.prefill_token_budget - len(self._running)
         while True:
             with self._lock:
@@ -649,11 +761,10 @@ class GenerationEngine:
                     pages = self.pool.alloc(n_pages)
                     if pages is None:
                         return      # wait for frees/retirements
-                    if self.slots is not None:
-                        seq.slot = self.slots.take()
-                        if seq.slot is None:    # as many slots as rows
-                            self.pool.free(pages)
-                            return
+                    seq.slot = self.slots.take()
+                    if seq.slot is None:        # as many slots as rows
+                        self.pool.free(pages)
+                        return
                     self._waiting.popleft()
                     seq.pages = pages
                     oversized = None
@@ -665,10 +776,11 @@ class GenerationEngine:
             try:
                 self._prefill(seq)
             except Exception as e:  # noqa: BLE001
-                # The seq is out of _waiting but not yet in _running —
-                # the loop's poison pass can't see it, so retire it
-                # here (frees its pages, delivers the error frame)
-                # before re-raising for the step-error accounting.
+                # The seq is out of _waiting and may be in neither
+                # _running nor a flight yet — the loop's poison pass
+                # can't see it then, so retire it here (frees its
+                # pages, delivers the error frame) before re-raising
+                # for the step-error accounting.
                 self._retire(seq, error=repr(e))
                 raise
 
@@ -677,20 +789,17 @@ class GenerationEngine:
         row[:len(seq.pages)] = seq.pages
         return row
 
-    def _call_fwd(self, kind: str, tokens, table, positions,
-                  batch: List[_Sequence]):
+    def _call_fwd(self, kind: str, tokens, table, positions, slots):
         """The forward of this token shape (``llm_decode``, or
         ``llm_prefill[bucket]``) over the caches, which it updates:
-        returns (logits, the routing counters or None).  Row i of the
-        forward is ``batch[i]``."""
+        returns (logits, the routing counters or None).  ``slots`` is
+        each row's slot of the state pool (a row without a sequence:
+        the index outside the pool), taken by a model that has one."""
         name = f"llm_{kind}[{tokens.shape[1]}]" \
             if kind == "prefill" else f"llm_{kind}"
         args = (self._params, tokens, self._kv["k_pages"],
                 self._kv["v_pages"], table, positions)
         if self._state is not None:
-            # a row without a sequence: a slot outside the pool
-            slots = np.full(tokens.shape[0], self.slots.slots, np.int32)
-            slots[:len(batch)] = [seq.slot for seq in batch]
             args += (self._state["conv"], self._state["ssm"], slots)
         logits, k, v, *rest = self._call(self._fwd, name, *args)
         self._kv["k_pages"], self._kv["v_pages"] = k, v
@@ -731,19 +840,101 @@ class GenerationEngine:
             cached = self._exe_cache[name] = (fn, exe)
         return cached[1](*args)
 
-    def _pack_sampling(self, batch: List[_Sequence]):
-        """The sampler's per-row arguments for ``batch`` (row i is
-        ``batch[i]``), counted into stats()["sampling"]."""
-        knobs, words = pack_rows(
-            ((seq.params, seq.seed, seq.generated) for seq in batch),
-            self.cfg.max_batch)
+    def _pack_sampling(self, rows: List[tuple]):
+        """The sampler's per-row arguments for ``rows``, (sequence, its
+        row) pairs: each draws its token number ``launched``; counted
+        into stats()["sampling"]."""
+        entries = [_IDLE_ROW] * self.cfg.max_batch
+        for seq, row in rows:
+            entries[row] = (seq.params, seq.seed, seq.launched)
+        knobs, words = pack_rows(entries, self.cfg.max_batch)
         sampled = int(np.count_nonzero(knobs[:, 0]))
         counts = self._sampling
         counts["rows_sampled"] += sampled
-        counts["rows_greedy"] += len(batch) - sampled
+        counts["rows_greedy"] += len(rows) - sampled
         counts["steps"] += 1
         counts["steps_sampled"] += sampled > 0
         return knobs, words
+
+    # ------------------------------------------------- the pipeline
+    def _feed(self, ids, rows) -> None:
+        """``ids[j]`` becomes row ``rows[j]``'s latest token, on the
+        device (a row index outside the batch: dropped)."""
+        self._tokens = self._call(self._feeder, "llm_feed", self._tokens,
+                                  ids, rows)
+
+    def _is_last(self, seq: _Sequence, count: int) -> bool:
+        """Whether ``seq``'s token number ``count`` (from 1) ends it by
+        length: at ``max_tokens``, or where the NEXT write position,
+        ``prompt_len + count - 1``, leaves the page-table window or the
+        model's max_seq.  Asked of the launched count at launch and of
+        the delivered count at delivery."""
+        return count >= seq.max_tokens \
+            or seq.prompt_len + count - 1 >= self.max_context
+
+    def _launched(self, seq: _Sequence) -> None:
+        """Count the program just launched for ``seq``.  At its length
+        limit it is not launched again and gives its row and pages back
+        now, one program before its last frame: the device runs programs
+        in launch order, so whatever takes them next writes after it."""
+        seq.launched += 1
+        if self._is_last(seq, seq.launched):
+            with self._lock:
+                if seq in self._running:
+                    self._running.remove(seq)
+            self._release(seq)
+
+    def _launch(self, flight: _Flight) -> None:
+        """``flight`` is on the device's queue: now read the ids of the
+        program before it and do their bookkeeping, under the leaves of
+        the annotation that launched ``flight``."""
+        self._pipeline["launched_ahead"] += bool(self._flights)
+        self._flights.append(flight)
+        if len(self._flights) > 1:
+            self._deliver(f"llm.{flight.kind}")
+
+    def _drain(self, cause: str) -> None:
+        """Read every launched program's ids before going on."""
+        if self._flights:
+            self._drains[cause] += 1
+        while self._flights:
+            self._deliver(f"llm.{self._flights[0].kind}")
+
+    def _deliver(self, leaves: str) -> None:
+        """Fetch the oldest launched program's ids and deliver them: a
+        token, a frame and perhaps retirement for each of its rows; a
+        row whose sequence ended meanwhile (EOS, cancellation) is
+        dropped.  A device error surfaces at the fetch, with the flight
+        still listed for the poison pass."""
+        flight = self._flights[0]
+        with self._phase(leaves + ".fetch"):
+            ids = np.asarray(flight.ids).tolist()   # [max_batch] int32
+            per_layer = None if flight.moe is None \
+                else np.asarray(flight.moe)         # [layers, 3]
+        self._flights.popleft()
+        with self._phase(leaves + ".sample"):
+            if per_layer is not None:
+                from ..ops.moe import MOE_COUNTERS
+
+                adds = dict(zip(MOE_COUNTERS, per_layer.sum(axis=0)),
+                            layer_runs=len(per_layer))
+                with self._lock:
+                    for key, add in adds.items():
+                        self._moe[key] = self._moe.get(key, 0) + int(add)
+            for seq, row in flight.rows:
+                if seq.finished:
+                    self._pipeline["rows_discarded"] += 1
+                    continue
+                self._emit_token(seq, ids[row])
+                if flight.admitted is not None:
+                    t_first = time.time()
+                    self._prefill_s_total += t_first - flight.admitted
+                    self._observe_phase("prefill",
+                                        t_first - flight.admitted)
+                    self._req_span(seq, "prefill", flight.admitted,
+                                   t_first,
+                                   tags={"prompt_tokens": seq.prompt_len})
+                    seq.first_token_ts = t_first
 
     def _prefill(self, seq: _Sequence) -> None:
         t0 = time.perf_counter()
@@ -763,7 +954,7 @@ class GenerationEngine:
         # re-prefills but already emitted its first token — its
         # waiting/prefill phases were accounted the first time), and
         # never the warmup sequence (it pays the compiles).
-        first_admission = seq.generated == 0 and not seq.warmup
+        first_admission = seq.launched == 0 and not seq.warmup
         t_admit = time.time()
         if first_admission:
             waited = max(t_admit - seq.submitted_ts, 0.0)
@@ -779,51 +970,55 @@ class GenerationEngine:
             positions = np.full((1, pad), -1, np.int32)
             positions[0, :n] = np.arange(n)
             table = self._page_table_row(seq)[None, :]
-            sampling = self._pack_sampling([seq])
+            flight_rows = [(seq, 0)]
+            sampling = self._pack_sampling(flight_rows)
+            # its id, row 0 of the sampler's, to its own row
+            feed_to = np.full(self.cfg.max_batch, self.cfg.max_batch,
+                              np.int32)
+            feed_to[0] = seq.slot
         with self._phase("llm.prefill.run"):
-            logits, _ = self._call_fwd("prefill", tokens, table,
-                                       positions, [seq])
+            logits, _ = self._call_fwd(
+                "prefill", tokens, table, positions,
+                np.asarray([seq.slot], np.int32))
             ids = self._call(
                 self._sampler, "llm_sample",
                 self._call(self._last_rows, f"llm_last[{pad}]", logits,
                            np.int32(n - 1)),
                 *sampling)
+            self._feed(ids, feed_to)
         seq.n_cached = n
         self._prefill_tokens_total += n
         self._count("prefill", n)
         with self._lock:
             self._running.append(seq)
-        with self._phase("llm.prefill.fetch"):
-            token = int(np.asarray(ids)[0])
-        with self._phase("llm.prefill.sample"):
-            self._emit_token(seq, token)
-        if first_admission:
-            t_first = time.time()
-            self._prefill_s_total += t_first - t_admit
-            self._observe_phase("prefill", t_first - t_admit)
-            self._req_span(seq, "prefill", t_admit, t_first,
-                           tags={"prompt_tokens": n})
-            seq.first_token_ts = t_first
+        self._launched(seq)
+        self._launch(_Flight("prefill", ids, None, flight_rows,
+                             t_admit if first_admission else None))
 
     def _decode_step(self) -> None:
-        """One batched decode forward over every running sequence."""
+        """One batched decode forward over every running sequence, each
+        in its own row, launched on the device's own copy of the ids."""
         B = self.cfg.max_batch
         with self._phase("llm.decode.pages"):
-            for seq in list(self._running):
-                if seq in self._running:   # an earlier ensure may evict it
-                    self._ensure_page(seq)
-            batch = list(self._running)
+            dry = not self._ensure_pages(evict=not self._flights)
+        if dry:
+            # A victim re-prefills from its tokens, and what the unread
+            # ids end may free the page: deliver them first.
+            self._drain("evict")
+            with self._phase("llm.decode.pages"):
+                self._ensure_pages(evict=True)
+        batch = list(self._running)
         if not batch:
             return
         with self._phase("llm.decode.pack"):
-            tokens = np.zeros((B, 1), np.int32)
             positions = np.full((B, 1), -1, np.int32)
             table = np.zeros((B, self._pages_per_seq), np.int32)
+            slots = np.full(B, B, np.int32)
             pages_read = 0
-            for i, seq in enumerate(batch):
-                tokens[i, 0] = seq.tokens[-1]
-                positions[i, 0] = seq.n_cached
-                table[i] = self._page_table_row(seq)
+            for seq in batch:
+                slots[seq.slot] = seq.slot
+                positions[seq.slot, 0] = seq.n_cached
+                table[seq.slot] = self._page_table_row(seq)
                 pages_read += pages_for(seq.n_cached + 1,
                                         self.cfg.page_size)
             rows = self.cfg.page_size * self._cache_spec.kv_layers
@@ -835,38 +1030,40 @@ class GenerationEngine:
                 self._state_counts["decode_runs"] += 1
                 self._state_counts["state_rows_updated"] += \
                     len(batch) * self._cache_spec.state_layers
-            sampling = self._pack_sampling(batch)
+            flight_rows = [(seq, seq.slot) for seq in batch]
+            sampling = self._pack_sampling(flight_rows)
         with self._phase("llm.decode.run"):
-            logits, moe = self._call_fwd("decode", tokens, table,
-                                         positions, batch)
+            logits, moe = self._call_fwd("decode", self._tokens, table,
+                                         positions, slots)
             ids = self._call(self._sampler, "llm_sample", logits,
                              *sampling)
-        with self._phase("llm.decode.fetch"):
-            ids = np.asarray(ids).tolist()      # [max_batch] int32
-            if moe is not None:
-                from ..ops.moe import MOE_COUNTERS
+            self._feed(ids, self._all_rows)
+        for seq in batch:
+            seq.n_cached += 1
+            self._launched(seq)
+        self._launch(_Flight("decode", ids, moe, flight_rows))
 
-                per_layer = np.asarray(moe)         # [layers, 3]
-                adds = dict(zip(MOE_COUNTERS, per_layer.sum(axis=0)),
-                            layer_runs=len(per_layer))
-                with self._lock:
-                    for key, add in adds.items():
-                        self._moe[key] = self._moe.get(key, 0) + int(add)
-        with self._phase("llm.decode.sample"):
-            for seq, token in zip(batch, ids):
-                seq.n_cached += 1
-                self._emit_token(seq, token)
+    def _ensure_pages(self, evict: bool) -> bool:
+        """A KV slot for every running sequence's next position; False
+        where the pool ran dry and ``evict`` does not allow a victim."""
+        for seq in list(self._running):
+            if seq in self._running \
+                    and not self._ensure_page(seq, evict):
+                return False
+        return True
 
-    def _ensure_page(self, seq: _Sequence) -> bool:
+    def _ensure_page(self, seq: _Sequence, evict: bool) -> bool:
         """Guarantee a KV slot for position ``seq.n_cached``; on pool
         exhaustion evict the most recently admitted other sequence
-        (recompute preemption) and retry."""
+        (recompute preemption) and retry, if ``evict`` allows."""
         needed = seq.n_cached // self.cfg.page_size + 1
         while len(seq.pages) < needed:
             pages = self.pool.alloc(1)
             if pages is not None:
                 seq.pages.extend(pages)
-                return True
+                continue
+            if not evict:
+                return False
             victim = None
             with self._lock:
                 for cand in reversed(self._running):
@@ -879,7 +1076,7 @@ class GenerationEngine:
                         self._running.remove(seq)
                 self._retire(seq, error="KV pool exhausted with no "
                                         "evictable sequence")
-                return False
+                return True
             self._evict(victim)
         return True
 
@@ -887,7 +1084,8 @@ class GenerationEngine:
         """Recompute preemption: drop the victim's pages, keep its
         tokens, park it at the FRONT of the waiting queue — it
         re-prefills (prompt + generated) once pages free up, without
-        re-emitting anything already streamed."""
+        re-emitting anything already streamed.  Nothing of it is in
+        flight: the pipeline was drained."""
         with self._lock:
             if victim in self._running:
                 self._running.remove(victim)
@@ -899,14 +1097,13 @@ class GenerationEngine:
 
     def _release(self, seq: _Sequence) -> None:
         """Give back what ``seq`` holds of the device's caches: its pages
-        and, where the model keeps a recurrent state, its slot (at
-        retirement, cancellation and eviction alike: a re-prefill
-        rebuilds the state from position 0)."""
+        and its slot (at its last launch, retirement, cancellation and
+        eviction alike: a re-prefill rebuilds the state from position
+        0).  A second call finds nothing to give."""
         self.pool.free(seq.pages)
         seq.pages = []
-        if self.slots is not None:
-            self.slots.give(seq.slot)
-            seq.slot = None
+        self.slots.give(seq.slot)
+        seq.slot = None
 
     def _emit_token(self, seq: _Sequence, tok: int) -> None:
         """The host's bookkeeping for one token the device chose."""
@@ -928,10 +1125,9 @@ class GenerationEngine:
         seq.last_token_ts = now
         seq.out.put({"token": tok, "index": seq.generated - 1})
         eos = self.cfg.eos_id is not None and tok == self.cfg.eos_id
-        # n_cached is the NEXT write position: continuing needs it
-        # inside both the page-table window and the model's max_seq.
-        if eos or seq.generated >= seq.max_tokens \
-                or seq.n_cached >= self.max_context:
+        # By length it left the batch at its last launch; an EOS is
+        # known only here, one program after that row's next launch.
+        if eos or self._is_last(seq, seq.generated):
             with self._lock:
                 if seq in self._running:
                     self._running.remove(seq)

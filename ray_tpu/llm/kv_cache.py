@@ -39,7 +39,10 @@ only.  The model reads and writes a row's slot where it lies, through a
 ``[B]`` slot index (an index outside the pool: a padded row, nothing
 changed), and carries both arrays whole through its layers as the K/V
 pool is carried; ``SlotPool`` is the host-side allocator, a sequence
-takes a slot with its first pages and gives it back with them.
+takes a slot with its first pages and gives it back with them.  The slot
+is also the sequence's row of the decode batch, for every model: a
+sequence keeps its row while it runs, so the ids one decode step leaves
+on the device are the next step's tokens row for row (llm/engine.py).
 
 ``PagePool`` is the host-side allocator; it exports
 ``rt_llm_kv_pages_{used,total}`` gauges on every alloc/free so KV
@@ -221,10 +224,11 @@ class PagePool:
 
 
 class SlotPool:
-    """Host-side allocator of the state pool's slots: one a sequence,
-    lowest free first; occupancy exported as ``rt_llm_state_slots_used``
-    / ``rt_llm_state_slots_total`` beside the pages'.  Called from the
-    engine thread only."""
+    """Host-side allocator of the slots: one a running sequence, its row
+    of the decode batch and (where the model keeps one) of the state
+    pool; lowest free first; occupancy exported as
+    ``rt_llm_state_slots_used`` / ``rt_llm_state_slots_total`` beside the
+    pages'.  Called from the engine thread only."""
 
     def __init__(self, slots: int):
         self.slots = int(slots)
@@ -235,10 +239,11 @@ class SlotPool:
 
             self._gauges = (
                 Gauge("rt_llm_state_slots_used",
-                      "Recurrent-state slots currently held by "
-                      "sequences."),
+                      "Slots (decode-batch rows; recurrent-state "
+                      "slots) currently held by sequences."),
                 Gauge("rt_llm_state_slots_total",
-                      "Recurrent-state slots in the device pool."))
+                      "Slots (decode-batch rows; recurrent-state "
+                      "slots) of the engine."))
         except Exception:
             pass
         self._publish()

@@ -208,3 +208,18 @@ def jit_sampler(max_batch: int):
             return out.at[0, 0].set(row)
 
     return jax.jit(sample_tokens), jax.jit(last_rows)
+
+
+def jit_feed():
+    """The engine's ``feed(tokens[B, 1], ids[B], rows[B]) -> tokens``:
+    ``ids[j]`` put at row ``rows[j]`` (an index outside the batch: that
+    id is dropped), the other rows kept.  It keeps every row's latest
+    token id on the device, where the next decode step takes it, so that
+    a launch never waits for the host's copy of the ids: a decode step's
+    ids go row for row (``rows = arange(B)``), a prefill's row 0 to the
+    row its sequence runs in."""
+    def feed(tokens, ids, rows):
+        with jax.named_scope("sample"):
+            return tokens.at[rows, 0].set(ids, mode="drop")
+
+    return jax.jit(feed)

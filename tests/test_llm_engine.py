@@ -201,7 +201,8 @@ def test_one_sampler_program_for_every_mix_and_ids_to_the_host():
     eng.warmup()
     warm = eng.stats()
     assert set(warm["programs"]) == {"llm_prefill[8]", "llm_last[8]",
-                                     "llm_sample", "llm_decode"}
+                                     "llm_sample", "llm_feed",
+                                     "llm_decode"}
     assert warm["sampling"] == {"rows_greedy": 2, "rows_sampled": 0,
                                 "steps": 2, "steps_sampled": 0}
     out, = jax.tree_util.tree_leaves(
@@ -222,7 +223,7 @@ def test_one_sampler_program_for_every_mix_and_ids_to_the_host():
     finally:
         eng.stop()
     after = eng.stats()
-    assert after["compiles"] == warm["compiles"] == 4
+    assert after["compiles"] == warm["compiles"] == 5
     assert after["programs"] == warm["programs"]
     counts = after["sampling"]
     assert counts["rows_greedy"] == 2 + 6 and counts["rows_sampled"] == 12
@@ -299,3 +300,238 @@ def test_length_cap_at_max_context():
         assert eng.stats()["kv_pages_used"] == 0
     finally:
         eng.stop()
+
+
+# --------------------------------------------- the depth-one pipeline
+
+def _family_engine(family, **engine):
+    """A tiny float32 model of ``family`` with weights large enough that
+    the greedy tokens vary, and an engine over it."""
+    from ray_tpu.models import MODEL_FAMILIES
+
+    fam = MODEL_FAMILIES[family]
+    cfg = dataclasses.replace(fam.tiny(), remat=False, dtype=jnp.float32)
+    params = jax.tree_util.tree_map(
+        lambda x: x * 6.0, fam.init(cfg, jax.random.PRNGKey(5)))
+    return GenerationEngine(
+        model=family, model_cfg=cfg, params=params,
+        engine_cfg=EngineConfig(**{**dict(page_size=4, num_pages=64,
+                                          max_batch=3), **engine}))
+
+
+MIXED = [([3, 17, 42, 7, 99, 5, 23, 11, 2, 64, 31, 8, 90, 12, 55, 71, 6,
+           19], 9), ([9, 4], 14), ([80, 1, 33, 27, 60], 1),
+         ([7, 7, 7], 6), ([100, 2, 50, 4, 25, 8], 11), ([61], 2),
+         ([12, 13, 14, 15, 16, 17, 18, 19, 20], 5)]
+
+
+def _step_until_done(eng, seqs):
+    while not all(s.finished for s in seqs):
+        eng.step()
+    assert eng.stats()["step_errors"] == 0, eng.stats()["last_error"]
+    return [s.tokens[s.prompt_len:] for s in seqs]
+
+
+@pytest.mark.parametrize("family", ["gpt2", "olmoe", "granitemoehybrid"])
+def test_pipelined_batch_gives_what_each_request_gives_alone(family):
+    """Seven greedy requests of mixed lengths over three rows (rows
+    change hands while others run; one ends at its prefill): token for
+    token what each gives alone, every frame in order, ``done`` after
+    the last token; nearly every program was launched ahead and no row's
+    result was dropped (no EOS: every length is known at launch)."""
+    eng = _family_engine(family).start()
+    try:
+        alone = [eng.generate(p, max_tokens=n) for p, n in MIXED]
+        assert [len(t) for t in alone] == [n for _, n in MIXED]
+        assert len({t for out in alone for t in out}) > 6
+        before = eng.stats()
+        seqs = [eng.submit(p, max_tokens=n) for p, n in MIXED]
+        frames = [list(eng.frames(s)) for s in seqs]
+    finally:
+        eng.stop()
+    for fr, want in zip(frames, alone):
+        assert [f["token"] for f in fr[:-1]] == want
+        assert [f["index"] for f in fr[:-1]] == list(range(len(want)))
+        assert fr[-1] == {"done": True, "reason": "length",
+                          "n_tokens": len(want)}
+    after = eng.stats()
+    pipe, was = after["pipeline"], before["pipeline"]
+    assert pipe["rows_discarded"] == 0
+    assert pipe["drains"]["evict"] == pipe["drains"]["error"] == 0
+    programs = (after["steps"] - before["steps"]) \
+        + (after["prefills"] - before["prefills"])
+    drains = pipe["drains"]["empty"] - was["drains"]["empty"]
+    assert 1 <= drains <= 3
+    assert pipe["launched_ahead"] - was["launched_ahead"] >= \
+        programs - 2 * drains - 1
+    assert after["kv_pages_used"] == 0 and eng.slots.used == 0
+    assert after["tokens_generated"] - before["tokens_generated"] == \
+        sum(n for _, n in MIXED)
+
+
+def test_eos_mid_batch_costs_one_row_step_and_frees_the_row():
+    """EOS is the one end not known at launch: the row was launched once
+    more, that result is dropped (never a frame after EOS), its pages and
+    slot go back at the EOS's delivery, and the request that takes the
+    row next is served correctly."""
+    plain = _family_engine("granitemoehybrid")
+    seqs = [plain.submit(p, max_tokens=n) for p, n in MIXED]
+    streams = _step_until_done(plain, seqs)
+    # a token in the middle of the longest stream
+    eos = streams[1][6]
+    want = [s[:s.index(eos) + 1] if eos in s else s for s in streams]
+    cut_short = sum(len(w) < len(s) for w, s in zip(want, streams))
+    assert cut_short >= 1 and any(eos not in s for s in streams)
+
+    eng = _family_engine("granitemoehybrid", eos_id=eos)
+    seqs = [eng.submit(p, max_tokens=n) for p, n in MIXED]
+    assert _step_until_done(eng, seqs) == want
+    for seq, tokens, full in zip(seqs, want, streams):
+        frames = []
+        while not seq.out.empty():
+            frames.append(seq.out.get())
+        assert [f["token"] for f in frames[:-1]] == tokens
+        ended = "eos" if tokens[-1] == eos else "length"
+        assert frames[-1] == {"done": True, "reason": ended,
+                              "n_tokens": len(tokens)}
+    stats = eng.stats()
+    # one launched row dropped for every stream an EOS cut short
+    assert stats["pipeline"]["rows_discarded"] == cut_short
+    assert stats["kv_pages_used"] == 0 and eng.pool.available == 64
+    assert eng.slots.used == 0 and stats["state"]["slots_used"] == 0
+    assert stats["running"] == stats["waiting"] == 0
+
+
+def test_cancel_with_a_program_in_flight_drops_its_row():
+    eng = _family_engine("gpt2")
+    keep = eng.submit([9, 4], max_tokens=12)
+    cut = eng.submit([5, 100, 23, 77], max_tokens=40)
+    for _ in range(3):
+        eng.step()
+    assert len(eng._flights) == 1
+    assert cut in [seq for seq, _ in eng._flights[0].rows]
+    assert cut.launched == cut.generated + 1 == 4
+    eng.cancel(cut.sid)
+    eng.step()
+    # retired at once, pages and row back, nothing of the launched step
+    assert cut.finished and cut.slot is None and cut.pages == []
+    assert cut.generated == 3 and len(cut.tokens) == 4 + 3
+    frames = []
+    while not cut.out.empty():
+        frames.append(cut.out.get())
+    assert frames[-1] == {"done": True, "reason": "cancelled",
+                          "n_tokens": 3}
+    assert [f["index"] for f in frames[:-1]] == [0, 1, 2]
+    late = eng.submit([80, 1, 33], max_tokens=5)    # takes the row
+    tokens = _step_until_done(eng, [keep, late])
+    assert late.finished and eng.stats()["pipeline"]["rows_discarded"] == 1
+    fresh = _family_engine("gpt2").start()
+    assert tokens == [fresh.generate([9, 4], max_tokens=12),
+                      fresh.generate([80, 1, 33], max_tokens=5)]
+    fresh.stop()
+    assert eng.pool.used == 0 and eng.slots.used == 0
+
+
+def test_stop_delivers_the_ids_left_unread():
+    eng = _family_engine("gpt2")
+    seq = eng.submit([9, 4], max_tokens=12)
+    eng.step()
+    eng.step()
+    assert len(eng._flights) == 1 and seq.launched == seq.generated + 1
+    eng.stop()
+    assert not eng._flights and seq.launched == seq.generated == 3
+    assert eng.stats()["pipeline"]["drains"]["stop"] == 1
+    s = eng.stats()
+    assert sum(s["phase_s"].values()) + s["llm.other"] == \
+        pytest.approx(s["step_s"], rel=1e-12)
+
+
+def test_eviction_with_a_program_in_flight_drains_first():
+    """A pool too small for three sequences at their lengths: the pages
+    phase finds the pool dry with a program's ids unread, delivers them
+    (a victim re-prefills from ALL its tokens) and only then evicts;
+    greedy output is what a roomy engine serves."""
+    requests = MIXED[:2] + MIXED[4:5]
+    roomy = _family_engine("gpt2")
+    want = _step_until_done(
+        roomy, [roomy.submit(p, max_tokens=n + 8) for p, n in requests])
+    tight = _family_engine("gpt2", num_pages=12)
+    got = _step_until_done(
+        tight, [tight.submit(p, max_tokens=n + 8) for p, n in requests])
+    assert got == want
+    stats = tight.stats()
+    assert stats["evictions"] > 0
+    assert stats["pipeline"]["drains"]["evict"] >= stats["evictions"] > 0
+    assert stats["pipeline"]["rows_discarded"] == 0
+    assert stats["kv_pages_used"] == 0 and tight.slots.used == 0
+
+
+class _Unreadable:
+    """Ids of a program that failed on the device: the launch went
+    through, the error waits at the fetch."""
+
+    def __array__(self, *a, **kw):
+        raise RuntimeError("injected device failure")
+
+
+def test_a_device_error_surfaces_at_the_fetch_and_the_loop_lives():
+    """Every sequence with anything in flight gets its error frame: one
+    still running, and one that left the batch at its last launch and
+    whose last ids were unread."""
+    eng = _family_engine("gpt2")
+    ending = eng.submit([9, 4], max_tokens=3)
+    running = eng.submit([5, 100, 23, 77], max_tokens=50)
+    eng.step()
+    eng.step()
+    flight, = eng._flights
+    assert ending not in eng._running and not ending.finished
+    assert ending in [seq for seq, _ in flight.rows]
+    assert ending.generated == 2 and ending.launched == 3
+    flight.ids = _Unreadable()
+    eng.start()
+    try:
+        a, b = list(eng.frames(ending)), list(eng.frames(running))
+        assert [f.get("index") for f in a[:-1]] == [0, 1]
+        for frames in (a, b):
+            assert "injected device failure" in frames[-1]["error"]
+        stats = eng.stats()
+        assert stats["step_errors"] == 1
+        assert stats["pipeline"]["drains"]["error"] == 1
+        assert stats["kv_pages_used"] == 0 and eng.slots.used == 0
+        assert stats["running"] == stats["waiting"] == 0
+        assert not eng._flights
+        # the loop lives, and serves what a fresh engine serves
+        again = eng.generate([80, 1, 33], max_tokens=5)
+    finally:
+        eng.stop()
+    fresh = _family_engine("gpt2").start()
+    assert again == fresh.generate([80, 1, 33], max_tokens=5)
+    fresh.stop()
+
+
+def test_sampled_tokens_are_the_samplers_for_the_launched_index(setup):
+    """temperature > 0: the key's token index is the LAUNCHED count, so a
+    request's draws are what the sampler gives, asked directly, for
+    (seed, 0), (seed, 1), ... on the full forward's logits; alone and
+    among others; and what the engine's loop gave before the pipeline."""
+    from ray_tpu.llm.sampling import jit_sampler, pack_rows, seed_words
+
+    eng, params = setup
+    prompt, seed, steps = [10, 20, 30], 977, 10
+    model, sampler = GPT2(CFG), jit_sampler(1)[0]
+    toks = list(prompt)
+    for index in range(steps):
+        logits = model.apply(params, jnp.asarray([toks], jnp.int32))
+        knobs, words = pack_rows([(SAMPLED, seed_words(seed), index)], 1)
+        toks.append(int(np.asarray(
+            sampler(logits[:, -1:], knobs, words))[0]))
+    want = toks[len(prompt):]
+    assert want == [100, 94, 498, 347, 201, 498, 380, 281, 9, 30]
+    assert eng.generate(prompt, max_tokens=steps, params=SAMPLED,
+                        seed=seed) == want
+    others = [eng.submit(p, 12, sp, sd) for p, sp, sd in (
+        ([7, 8, 9, 11], SamplingParams(temperature=1.1), 5),
+        ([300, 2], SamplingParams(), None))]
+    assert _tokens(eng, eng.submit(prompt, steps, SAMPLED, seed)) == want
+    for seq in others:
+        list(eng.frames(seq))

@@ -128,12 +128,12 @@ def test_prefills_and_compiles_count_what_the_script_did(scripted):
     eng, _ = scripted
     s = eng.stats()
     assert s["prefills"] == 3
-    # buckets 8 and 16 with their row pickers, the decode program and
-    # the one sampler
-    assert s["compiles"] == 6 == len(s["programs"])
+    # buckets 8 and 16 with their row pickers, the decode program,
+    # the one sampler and the feed of its ids to the next step
+    assert s["compiles"] == 7 == len(s["programs"])
     assert set(s["programs"]) == {"llm_prefill[8]", "llm_prefill[16]",
                                   "llm_last[8]", "llm_last[16]",
-                                  "llm_decode", "llm_sample"}
+                                  "llm_decode", "llm_sample", "llm_feed"}
 
 
 def test_stats_asks_the_device_nothing_and_peak_is_the_programs(
@@ -149,7 +149,7 @@ def test_stats_asks_the_device_nothing_and_peak_is_the_programs(
     s = eng.stats()
     totals = [engine_mod._program_bytes(exe)
               for _, exe in eng._exe_cache.values()]
-    assert len(totals) == 6 and min(totals) > 0
+    assert len(totals) == 7 and min(totals) > 0
     assert s["peak_hbm_bytes"] == max(totals)
 
 
@@ -190,13 +190,99 @@ def test_cpu_capture_of_a_tiny_engine_holds_the_phases(tmp_path):
                                 "llm.prefill"} <= set(events)
     assert len(events["llm.step"]) == 4
     assert {"step", "running", "waiting"} <= set(events["llm.step"][0])
-    assert len(events["llm.decode.fetch"]) == len(events["llm.decode"])
+    # one fetch after every launch, of the program before it, and the
+    # last ids read in the step that launched them (the drain)
+    assert len(events["llm.decode.fetch"]) == len(events["llm.decode"]) + 1
     prefill, = events["llm.prefill"]
     assert prefill["bucket"] == 8 and prefill["prompt_tokens"] == 3
     assert events["llm.decode"][0]["batch"] == 2
     # bucket 8 is new to this engine: its compile is inside the capture
     assert events["llm.compile"] == [{"program": "llm_prefill[8]"},
                                      {"program": "llm_last[8]"}]
+
+
+def test_ids_are_fetched_after_the_next_launch_inside_its_annotation(
+        monkeypatch):
+    """The pipeline by the order of events on the host: every name is
+    still entered, the leaves still sum to the step, and the fetch of
+    program N lies after the launch of program N+1, inside the
+    ``llm.decode`` / ``llm.prefill`` annotation that launched N+1 — where
+    a capture of a device-bound engine finds run N+1's start."""
+    log = []
+
+    class Recorded:
+        def __init__(self, name, **tags):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+            return False
+
+    monkeypatch.setattr(engine_mod, "annotate", Recorded)
+    eng = _engine()
+    real_call, real_deliver = eng._call, eng._deliver
+    launched = []                       # forwards, in launch order
+
+    def call(fn, name, *args):
+        if name.startswith(("llm_decode", "llm_prefill")):
+            launched.append(name)
+            log.append(("launch", len(launched) - 1))
+        return real_call(fn, name, *args)
+
+    fetched = []
+
+    def deliver(leaves):
+        # flights and forwards are launched one for one, in order
+        log.append(("fetch", len(launched) - len(eng._flights)))
+        fetched.append(len(eng._flights))
+        return real_deliver(leaves)
+
+    monkeypatch.setattr(eng, "_call", call)
+    monkeypatch.setattr(eng, "_deliver", deliver)
+    eng.submit(list(range(1, 12)), max_tokens=6)
+    eng.step()
+    eng.submit([5, 6, 7], max_tokens=4)
+    while eng.step()["running"]:
+        pass
+
+    names = {name for what, name in log if what == "enter"}
+    assert set(PHASE_LEAVES) | {"llm.step", "llm.decode",
+                                "llm.prefill"} <= names
+    s = eng.stats()
+    assert sum(s["phase_s"].values()) + s["llm.other"] == \
+        pytest.approx(s["step_s"], rel=1e-12)
+
+    opened, launch_at, checked = [], {}, 0      # opened: (name, serial)
+    for serial, (what, x) in enumerate(log):
+        if what == "enter":
+            opened.append((x, serial))
+        elif what == "exit":
+            assert opened.pop()[0] == x
+        elif what == "launch":
+            parent, leaf = opened[-2:]
+            assert parent[0] == ("llm.decode" if launched[x] == "llm_decode"
+                                 else "llm.prefill")
+            assert leaf[0] == parent[0] + ".run"
+            launch_at[x] = parent
+        else:                           # the fetch of forward number x
+            if x + 1 not in launch_at:  # the drain: nothing was launched
+                assert x == len(launched) - 1
+                assert [name for name, _ in opened] == ["llm.step"]
+                continue
+            # N+1 is launched, and that very annotation is still open
+            assert opened[-1] == launch_at[x + 1]
+            checked += 1
+    assert len(launched) == 2 + 5 and checked == len(launched) - 1
+    # two flights while the older is delivered, one in a drain
+    assert fetched == [2] * checked + [1]
+    pipeline = s["pipeline"]
+    assert pipeline["launched_ahead"] == checked
+    assert pipeline["drains"] == {"evict": 0, "error": 0, "empty": 1,
+                                  "stop": 0}
+    assert pipeline["rows_discarded"] == 0
 
 
 class _FakeQueue:
