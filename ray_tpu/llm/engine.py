@@ -46,8 +46,9 @@ is unchanged.
 A model whose layers are not all attention keeps a SECOND kind of cache
 (models.CacheSpec; kv_cache.py ``init_state`` / ``SlotPool``): beside its
 pages (for the layers that hold K/V, and only those) a sequence takes one
-slot of the state pool at admission, for the recurrent state of its
-state-space layers, and gives it back with its pages at retirement,
+slot of the state pool at admission, for what its recurrent layers keep
+(Granite: a conv window and a state-space state; LFM2: a conv window
+alone), and gives it back with its pages at retirement,
 cancellation and eviction (the re-prefill rebuilds the state from
 position 0).  Both pools are donated to the one jitted forward and
 updated where they lie; a decode row without a sequence carries a slot
@@ -234,35 +235,35 @@ def jit_forward(model):
     by the model, they are updated in place: the program scatters the
     new rows and holds no second pool (tests/test_llm.py and
     tests/test_tpu_compile.py pin that).  A model whose cache spec has
-    recurrent layers takes three more arguments, the state pool's
-    ``conv`` and ``ssm`` (llm/kv_cache.py ``init_state``; donated and
-    updated in place as the pages are) and each row's slot ``[B]``, and
-    returns the two after ``v_pages``.  A model with experts returns one
-    more output, its routing counters ([layers, 3] int32, ops/moe.py
-    ``moe_counters``)."""
+    recurrent layers takes, after the positions, the state pool's arrays
+    (llm/kv_cache.py ``state_arrays``: ``conv`` and ``ssm``, or ``conv``
+    alone; donated and updated in place as the pages are) and each row's
+    slot ``[B]``, and returns the arrays after ``v_pages``.  A model
+    with experts returns one more output, its routing counters ([layers
+    with experts, 3] int32, ops/moe.py ``moe_counters``)."""
     import jax
 
     from ..models import family_of
     from ..ops.moe import moe_counters
+    from .kv_cache import state_arrays
 
-    recurrent = family_of(model.cfg).cache(model.cfg).state_layers > 0
+    held = state_arrays(family_of(model.cfg).cache(model.cfg))
 
     def fwd(p, tokens, k_pages, v_pages, page_table, positions, *state):
         cache = {"k_pages": k_pages, "v_pages": v_pages,
                  "page_table": page_table}
-        if recurrent:
-            cache["conv"], cache["ssm"], cache["slots"] = state
+        if held:
+            cache.update(zip(held + ("slots",), state, strict=True))
         (logits, new), sown = model.apply(
             p, tokens, kv_cache=cache, positions=positions,
             mutable=["intermediates"])
-        out = (logits, new["k_pages"], new["v_pages"])
-        if recurrent:
-            out += (new["conv"], new["ssm"])
+        out = (logits, new["k_pages"], new["v_pages"]) \
+            + tuple(new[name] for name in held)
         moe = moe_counters(sown.get("intermediates", {}))
         return out if moe is None else out + (moe,)
 
-    return jax.jit(fwd,
-                   donate_argnums=(2, 3) + ((6, 7) if recurrent else ()))
+    return jax.jit(fwd, donate_argnums=(2, 3) + tuple(
+        range(6, 6 + len(held))))
 
 
 def _program_bytes(exe) -> int:
@@ -319,9 +320,11 @@ class GenerationEngine:
         # steps' recurrent layers move is counted beside the slots
         # (stats()["state"], absent for a model without such layers):
         # ``state_rows_updated`` = running rows x recurrent layers, one
-        # row = one sequence's conv window and state of one layer
-        # (``state_row_bytes``, read and written once a step);
-        # ``mixer_weight_bytes`` = one layer's mixer matrices.
+        # row = what one sequence keeps in one such layer, by the spec
+        # (``state_row_bytes``: the conv window, and the state where
+        # there is one; read and written once a step);
+        # ``mixer_weight_bytes`` = one layer's mixer weights, by the
+        # model's config (``mixer_params``).
         self.slots = SlotPool(self.cfg.max_batch)
         self._state = None
         self._state_counts: Dict[str, int] = {}
@@ -800,11 +803,13 @@ class GenerationEngine:
         args = (self._params, tokens, self._kv["k_pages"],
                 self._kv["v_pages"], table, positions)
         if self._state is not None:
-            args += (self._state["conv"], self._state["ssm"], slots)
+            args += (*self._state.values(), slots)
         logits, k, v, *rest = self._call(self._fwd, name, *args)
         self._kv["k_pages"], self._kv["v_pages"] = k, v
         if self._state is not None:
-            self._state["conv"], self._state["ssm"], *rest = rest
+            n = len(self._state)
+            self._state = dict(zip(self._state, rest[:n]))
+            rest = rest[n:]
         return logits, (rest[0] if rest else None)
 
     def _call(self, fn, name: str, *args):

@@ -34,7 +34,11 @@ keeps a SECOND kind of cache beside the pages: one slot a sequence in the
 state pool, ``conv`` [state layers, slots, d_conv-1, conv_dim] (the conv
 window, in the model's dtype) and ``ssm`` [state layers, slots, H, P, N]
 (float32), of fixed size whatever the sequence's length
-(``init_state``).  The K/V pool is then built for the attention layers
+(``init_state``).  A model whose recurrent layers keep a window and NO
+state (``models/lfm2.py``: short-conv mixers, ``ssm_shape == ()``) has a
+state pool of the one array ``conv``: ``state_arrays`` names what a
+spec's pool holds, and the engine carries, donates and aliases those and
+nothing else.  The K/V pool is then built for the attention layers
 only.  The model reads and writes a row's slot where it lies, through a
 ``[B]`` slot index (an index outside the pool: a padded row, nothing
 changed), and carries both arrays whole through its layers as the K/V
@@ -52,7 +56,7 @@ occupancy is visible in ``rt telemetry`` and the doctor can see leaks.
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,15 +73,28 @@ def init_cache(n_layer: int, num_pages: int, page_size: int,
             "v_pages": jnp.zeros(shape, dtype)}
 
 
+def state_arrays(spec) -> Tuple[str, ...]:
+    """The state pool's arrays for a cache spec (``models.CacheSpec``),
+    in the order the forward takes and returns them: ``conv`` where the
+    recurrent layers keep a window, ``ssm`` where they keep a state."""
+    if not spec.state_layers:
+        return ()
+    return tuple(name for name, shape in (("conv", spec.conv_shape),
+                                          ("ssm", spec.ssm_shape))
+                 if shape)
+
+
 def init_state(spec, slots: int, dtype: Any) -> Dict[str, Any]:
-    """The state pool of a model whose cache spec (``models.CacheSpec``)
-    has recurrent layers: zeros; a slot is not cleared when it changes
-    hands — a forward that starts at position 0 starts from zeros
-    whatever the slot holds (models/granite.py)."""
-    return {"conv": jnp.zeros((spec.state_layers, slots)
-                              + tuple(spec.conv_shape), dtype),
-            "ssm": jnp.zeros((spec.state_layers, slots)
-                             + tuple(spec.ssm_shape), jnp.float32)}
+    """The state pool of a model whose cache spec has recurrent layers
+    (``state_arrays``; no array for an empty shape): zeros; a slot is
+    not cleared when it changes hands — a forward that starts at
+    position 0 starts from zeros whatever the slot holds
+    (models/layers.py ``slot_conv``, models/granite.py)."""
+    dtypes = {"conv": dtype, "ssm": jnp.float32}
+    return {name: jnp.zeros((spec.state_layers, slots)
+                            + tuple(getattr(spec, name + "_shape")),
+                            dtypes[name])
+            for name in state_arrays(spec)}
 
 
 def paged_store(k_pages, v_pages, layer, k_new, v_new, page_table,

@@ -1,19 +1,24 @@
 """ray_tpu.models — TPU-first reference model families.
 
-Four families run through both the trainer and the serving engine:
+Five families run through both the trainer and the serving engine:
 GPT-2 (pretrain baseline, BASELINE.json headline metric), Llama
 (RoPE/GQA/SwiGLU), OLMoE (the Llama block with QK-norm and dropless
-top-k sparse experts, ops/moe.py) and Granite 4.0-H (``granitemoehybrid``:
+top-k sparse experts, ops/moe.py), Granite 4.0-H (``granitemoehybrid``:
 Mamba-2 state-space layers with an attention layer among every few, a
-share of the routed experts plus a shared one, models/granite.py).  All
-models are flax.linen with
+share of the routed experts plus a shared one, models/granite.py) and
+LFM2-MoE (``lfm2moe``: gated short-convolution mixers whose whole
+recurrent state is a two-row window, grouped-query attention with a
+per-head QK-norm among every few, two dense layers ahead of experts
+routed by sigmoid scores and a selection bias, models/lfm2.py).  What
+more than one block is built from (RMSNorm, RoPE, the conv over a slot's
+window) is in models/layers.py.  All models are flax.linen with
 *logical* dimension names threaded through ray_tpu.parallel.sharding
 rules, so DP/FSDP/TP/CP layouts are a rules-table choice, not a model
 edit.
 
 ``MODEL_FAMILIES`` is the one table the engine (``llm/engine.py``) and
 the multi-host training plane (``train.distributed.rules_for_model``)
-resolve a family through.  A fifth family is a row here:
+resolve a family through.  A sixth family is a row here:
 its config class, module, init, loss, partition rules, a tiny preset for
 tests, and its cache spec (the module's ``__call__`` takes ``kv_cache=``
 / ``positions=`` as GPT2's does, llm/kv_cache.py).  The cache spec
@@ -21,11 +26,13 @@ tests, and its cache spec (the module's ``__call__`` takes ``kv_cache=``
 and in which layers: how many layers hold K/V in the paged pool and at
 what width (``kv_layers`` x ``kv_heads`` x ``head_dim``: every layer for
 the first three families), and how many hold a recurrent state and its
-shapes (``state_layers``, ``conv_shape``, ``ssm_shape``: none but for
-Granite, whose 9 layers in 10 keep a conv window and a float32 state-space
-state in a slot and no K/V).  The engine builds both pools from it.
+shapes (``state_layers``, ``conv_shape``, ``ssm_shape``: none for the
+first three; Granite's 9 layers in 10 keep a conv window and a float32
+state-space state in a slot and no K/V; LFM2's 3 in 4 keep a conv window
+alone, ``ssm_shape == ()``).  The engine builds both pools from it, and
+of the state pool the arrays the spec has.
 Keys are normalized lowercase-no-separator ("gpt2", "llama", "olmoe",
-"granitemoehybrid").
+"granitemoehybrid", "lfm2moe").
 """
 
 from dataclasses import dataclass
@@ -36,6 +43,8 @@ from .granite import (Granite, GraniteConfig, granite_init,  # noqa: F401
 
 from .gpt2 import (GPT2, GPT2Config, gpt2_init, gpt2_loss_fn,  # noqa: F401
                    gpt2_partition_rules)
+from .lfm2 import (Lfm2, Lfm2Config, lfm2_init,  # noqa: F401
+                   lfm2_loss_fn, lfm2_partition_rules)
 from .llama import (Llama, LlamaConfig, llama_init,  # noqa: F401
                     llama_loss_fn, llama_partition_rules, olmoe_loss_fn,
                     olmoe_partition_rules)
@@ -49,7 +58,7 @@ class CacheSpec:
     head_dim: int
     state_layers: int = 0               # layers with a recurrent state
     conv_shape: Tuple[int, ...] = ()    # one sequence, one layer (dtype)
-    ssm_shape: Tuple[int, ...] = ()     # one sequence, one layer, float32
+    ssm_shape: Tuple[int, ...] = ()     # the same, float32; (): none
 
 
 def _attention_only(kv_heads: Callable[[Any], int]):
@@ -62,6 +71,12 @@ def _granite_cache(cfg: GraniteConfig) -> CacheSpec:
         cfg.layers_of("attention"), cfg.n_kv_head, cfg.head_dim,
         cfg.layers_of("mamba"), (cfg.mamba_d_conv - 1, cfg.conv_dim),
         (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state))
+
+
+def _lfm2_cache(cfg: Lfm2Config) -> CacheSpec:
+    return CacheSpec(
+        cfg.layers_of("full_attention"), cfg.n_kv_head, cfg.head_dim,
+        cfg.layers_of("conv"), (cfg.conv_taps - 1, cfg.d_model))
 
 
 @dataclass(frozen=True)
@@ -88,6 +103,9 @@ MODEL_FAMILIES = {
     "granitemoehybrid": ModelFamily(
         GraniteConfig, Granite, granite_init, granite_loss_fn,
         granite_partition_rules, GraniteConfig.tiny, _granite_cache),
+    "lfm2moe": ModelFamily(Lfm2Config, Lfm2, lfm2_init, lfm2_loss_fn,
+                           lfm2_partition_rules, Lfm2Config.tiny,
+                           _lfm2_cache),
 }
 
 

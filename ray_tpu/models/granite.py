@@ -38,10 +38,8 @@ how a slot is cleared for the sequence that takes it.
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import math
-import zlib
 from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
@@ -51,7 +49,8 @@ import jax.numpy as jnp
 
 from ..parallel.sharding import with_logical_constraint as _constrain
 from .attention import attention
-from .llama import RMSNorm, _next_token_xent
+from .layers import RMSNorm, init_by_leaf, slot_conv
+from .llama import _next_token_xent
 
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -321,37 +320,16 @@ class Mamba2Mixer(nn.Module):
         d_skip = self.param("D", nn.initializers.ones, (h,), f32)
         dt_bias = self.param("dt_bias", nn.initializers.zeros, (h,), f32)
 
-        valid = fresh = conv_pool = ssm_pool = None
+        valid = fresh = window = ssm_pool = None
         if cache is not None:
             valid = cache["positions"] >= 0                    # [B, T]
             fresh = cache["positions"][:, 0] == 0              # [B]
-            conv_pool, ssm_pool = cache["conv"], cache["ssm"]
-            layer, slots = cache["layer"], cache["slots"]
+            ssm_pool, layer, slots = (cache["ssm"], cache["layer"],
+                                      cache["slots"])
+            window = (cache["conv"], layer, slots, fresh, valid)
         with jax.named_scope("ssm.conv"):
-            if cache is None:
-                before = jnp.zeros((b, kw - 1, dc), xbc.dtype)
-            else:       # the last kw-1 inputs of the row's past
-                # (the pool through a view with the window's two
-                # dimensions merged, for the reason _store_rows gives)
-                flat = conv_pool.reshape(conv_pool.shape[:2] + (-1,))
-                before = jnp.where(
-                    fresh[:, None, None], 0,
-                    flat[layer, slots].reshape(b, kw - 1, dc))
-            window = jnp.concatenate([before.astype(xbc.dtype), xbc],
-                                     axis=1)                   # [B,T+3,dc]
-            conv = sum(window[:, i:i + t].astype(f32) * conv_w[i]
-                       for i in range(kw)) + conv_b
-            xbc_act = nn.silu(conv)
-            if cache is not None:
-                # The window the NEXT position needs: the kw-1 inputs up
-                # to the last real one (a prefill's padding lies behind
-                # them and is left out).
-                n_real = jnp.sum(valid, axis=1)                # [B]
-                keep = jax.vmap(lambda w, i: jax.lax.dynamic_slice_in_dim(
-                    w, i, kw - 1, axis=0))(window, n_real)
-                conv_pool = flat.at[layer, slots].set(
-                    keep.reshape(b, -1).astype(flat.dtype),
-                    mode="drop").reshape(conv_pool.shape)
+            xbc_act, conv_pool = slot_conv(xbc, conv_w, window,
+                                           bias=conv_b, act=nn.silu)
         xs, b_mat, c_mat = jnp.split(xbc_act, [di, di + n], axis=-1)
         xs = xs.reshape(b, t, h, p)
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias)         # [B,T,H]
@@ -559,22 +537,8 @@ def granite_init(cfg: GraniteConfig, rng):
     ``D = 1``, ``dt_bias`` the inverse softplus of a step log-uniform in
     [0.001, 0.1], the conv's weight and bias uniform in +-1/sqrt(taps);
     those four stay float32 (they feed ``dt`` and the decay)."""
-    from .llama import _init_leaf
-
-    init_cfg = dataclasses.replace(cfg, mesh=None, attn_impl="dense")
-    shapes = jax.eval_shape(Granite(init_cfg).init, rng,
-                            jnp.zeros((1, 8), jnp.int32))
-
-    def make(path, spec):
-        name = "/".join(str(getattr(p, "key", p)) for p in path)
-        key = jax.random.fold_in(rng, zlib.crc32(name.encode()))
-        special = _special_leaf(cfg, name, key, spec.shape)
-        if special is not None:
-            return special
-        return _init_leaf(key, spec.shape, name.endswith("scale"),
-                          jnp.dtype(cfg.param_dtype))
-
-    return jax.tree_util.tree_map_with_path(make, shapes)
+    return init_by_leaf(Granite, cfg, rng,
+                        functools.partial(_special_leaf, cfg))
 
 
 def granite_loss_fn(cfg: GraniteConfig, params, batch):

@@ -12,17 +12,16 @@ full forward.
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..parallel.sharding import with_logical_constraint as _constrain
 from .attention import attention
+from .layers import RMSNorm, _rope, init_by_leaf
 
 
 @dataclass(frozen=True)
@@ -121,59 +120,6 @@ class LlamaConfig:
                              + self._ffn_params_per_token()))
         attn = 4 * self.n_layer * self.d_model * ctx
         return 2.0 * matmul_params + attn
-
-
-@functools.lru_cache(maxsize=64)
-def _rope_tables(seq_len: int, head_dim: int, theta: float):
-    """Cached sin/cos tables keyed by (seq_len, head_dim): every block
-    of every forward shares one host constant per shape instead of
-    re-deriving the tables inside each traced layer (they are shape-
-    static, so recomputation bought nothing but trace time and
-    duplicated constants).  Deliberately NUMPY arrays — caching a
-    jnp array materialized under an outer jit would leak that trace's
-    tracer into later traces; numpy constants embed safely anywhere.
-    Returns ([T, D/2] cos, [T, D/2] sin) in fp32."""
-    half = head_dim // 2
-    freqs = theta ** (-np.arange(0, half, dtype=np.float32) / half)
-    angles = np.arange(seq_len, dtype=np.float32)[:, None] * freqs[None, :]
-    return np.cos(angles), np.sin(angles)
-
-
-def _rope(x, theta: float, positions=None):
-    """Rotary embedding over [B, T, H, D] (D even).  ``positions``
-    ([B, T] absolute, negative = padding) selects per-token angles for
-    the decode path; None means contiguous 0..T-1 (training/prefill
-    full forward) served from the cached tables."""
-    b, t, h, d = x.shape
-    half = d // 2
-    if positions is None:
-        cos, sin = _rope_tables(t, d, theta)
-        cos = cos[None, :, None, :]
-        sin = sin[None, :, None, :]
-    else:
-        pos = jnp.maximum(positions, 0).astype(jnp.float32)
-        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-        angles = pos[..., None] * freqs            # [B, T, half]
-        cos = jnp.cos(angles)[:, :, None, :]
-        sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                          axis=-1)
-    return out.astype(x.dtype)
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        x32 = x.astype(jnp.float32)
-        norm = x32 * jax.lax.rsqrt(
-            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
-        return (norm * scale).astype(self.dtype)
 
 
 class LlamaBlock(nn.Module):
@@ -286,33 +232,11 @@ class Llama(nn.Module):
         return logits
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _init_leaf(key, shape, ones: bool, dtype):
-    x = jnp.ones(shape, jnp.float32) if ones \
-        else 0.02 * jax.random.normal(key, shape, jnp.float32)
-    return x.astype(dtype)
-
-
 def llama_init(cfg: LlamaConfig, rng):
-    """The weights from the seed, leaf by leaf: each leaf is drawn in
-    float32 from a key folded from its path (normal, std 0.02; a norm's
-    scale is 1) and cast to ``cfg.param_dtype`` under ``jit``, so no
-    float32 copy of the whole tree ever exists, on any backend, and the
-    model's forward is never run to make weights."""
-    import dataclasses
-    import zlib
-
-    init_cfg = dataclasses.replace(cfg, mesh=None, attn_impl="dense")
-    shapes = jax.eval_shape(Llama(init_cfg).init, rng,
-                            jnp.zeros((1, min(cfg.max_seq, 8)), jnp.int32))
-
-    def make(path, spec):
-        name = "/".join(str(getattr(p, "key", p)) for p in path)
-        key = jax.random.fold_in(rng, zlib.crc32(name.encode()))
-        return _init_leaf(key, spec.shape, name.endswith("scale"),
-                          jnp.dtype(cfg.param_dtype))
-
-    return jax.tree_util.tree_map_with_path(make, shapes)
+    """The weights from the seed, leaf by leaf (``models/layers.py``
+    ``init_by_leaf``): every matrix normal(0, 0.02), every norm's scale
+    1, cast to ``cfg.param_dtype``."""
+    return init_by_leaf(Llama, cfg, rng)
 
 
 def _next_token_xent(logits, targets):

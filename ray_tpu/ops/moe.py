@@ -5,10 +5,16 @@ ONE path, for every model that has experts (OLMoE through
 ``models/llama.py``; GPT-2's synthetic ``moe_num_experts`` option as its
 ungated case):
 
-- ``moe.route``: router logits, softmax over ALL experts and top-k in
-  float32 (a bf16 near-tie must not flip an expert); the weights are
-  the softmax's own values, renormalised over the chosen k only where
-  the model says so (``norm_topk_prob``);
+- ``moe.route``: router logits, a score for each of ALL the experts
+  (``scoring``: ``softmax`` over them, or ``sigmoid`` of each logit
+  alone) and top-k, in float32 (a bf16 near-tie must not flip an
+  expert).  WHICH experts and WHAT weight may come from different
+  numbers: with a selection bias (``select_bias``: the leaf
+  ``expert_bias`` [E], which no gradient trains) the k largest of
+  ``score + bias`` are chosen, and their weights are the scores alone.
+  The weights are renormalised over the chosen k only where the model
+  says so (``norm_topk_prob``), each family in its own form (``w / sum
+  w``, or ``w / (sum w + norm_eps)``);
 - ``moe.dispatch``: the ``S x k`` (token, expert) pairs are flattened
   and sorted by expert with a STABLE sort, so a pair's place depends on
   nothing but the pairs before it; the rows are gathered in that order;
@@ -36,9 +42,13 @@ add up to the whole layer (tests/test_granite.py).  Holding all of them
 is the default.
 
 Each layer sows ``moe`` into flax's ``intermediates``: ``load`` [E] (pairs
-per expert), ``prob_mean`` [E] and ``z`` (mean squared log-sum-exp of the
-router logits), from which come the training losses (``moe_losses``) and
-the engine's counters (``moe_counters``).
+per expert), ``prob_mean`` [E] (the mean score; under ``sigmoid`` scoring
+the scores of a row do not add up to 1) and ``z`` (mean squared
+log-sum-exp of the router logits: under ``softmax`` the squared log of the
+scores' normaliser, the z-loss; under ``sigmoid`` nothing normalises the
+scores and ``z`` is the same statistic of the logits, a measure of their
+size that no loss uses), from which come the training losses
+(``moe_losses``) and the engine's counters (``moe_counters``).
 """
 
 from __future__ import annotations
@@ -50,15 +60,28 @@ import jax
 import jax.numpy as jnp
 
 
-def route(logits, k: int, norm_topk_prob: bool):
+def route(logits, k: int, norm_topk_prob: bool, scoring: str = "softmax",
+          select_bias=None, norm_eps: float = 0.0):
     """Router logits [S, E] (float32) -> (weights [S, k], experts [S, k],
-    probs [S, E]): softmax over all experts, the k largest with ties to
-    the lower index."""
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+    scores [S, E]).  The scores: ``softmax`` over all experts, or the
+    ``sigmoid`` of each logit.  The experts: the k largest scores, or
+    with ``select_bias`` [E] the k largest of ``score + bias``, ties to
+    the lower index.  The weights: the chosen experts' scores, the bias
+    NOT in them; with ``norm_topk_prob`` divided by their sum (+
+    ``norm_eps`` where a family has one)."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring {scoring!r}")
+    scores = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
+        else jax.nn.sigmoid(logits)
+    if select_bias is None:
+        weights, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(scores + select_bias, k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk_prob:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return weights, experts, probs
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + norm_eps if norm_eps else total)
+    return weights, experts, scores
 
 
 def grouped_ffn(rows, group_sizes, w_gate, w_in, w_out, act: Callable):
@@ -84,6 +107,9 @@ class MoEMLP(nn.Module):
     top_k: int = 2
     gated: bool = False
     norm_topk_prob: bool = True
+    scoring: str = "softmax"            # or "sigmoid"
+    select_bias: bool = False           # choose on score + ``expert_bias``
+    norm_eps: float = 0.0               # in the renormalisation's sum
     act: Callable = nn.gelu
     dtype: Any = jnp.bfloat16
     first_expert: int = 0               # the share held here:
@@ -102,6 +128,12 @@ class MoEMLP(nn.Module):
             raise ValueError(f"experts {first}..{first + e} of {n}")
         init = nn.initializers.normal(0.02)
         router = self.param("router", init, (d, n), jnp.float32)
+        # Moved by the experts' load outside autograd where a model is
+        # trained with it; here it is as init drew it: no gradient, and
+        # ``train_step.make_optimizer`` leaves the leaf out of its decay.
+        bias = jax.lax.stop_gradient(self.param(
+            "expert_bias", nn.initializers.zeros, (n,), jnp.float32)) \
+            if self.select_bias else None
         names = ("w_up", "w_down") if self.gated else ("w_in", "w_out")
         w_gate = self.param("w_gate", init, (e, d, self.d_ff),
                             jnp.float32) if self.gated else None
@@ -112,7 +144,9 @@ class MoEMLP(nn.Module):
 
         with jax.named_scope("moe.route"):
             logits = xf.astype(jnp.float32) @ router.astype(jnp.float32)
-            weights, experts, probs = route(logits, k, self.norm_topk_prob)
+            weights, experts, probs = route(
+                logits, k, self.norm_topk_prob, self.scoring, bias,
+                self.norm_eps)
             # An invalid row's pairs go to "expert E": behind every
             # group, in none of them; so do the pairs of an expert that
             # is not held here.

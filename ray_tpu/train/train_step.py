@@ -30,6 +30,16 @@ class TrainState(struct.PyTreeNode):
                    opt_state=optimizer.init(params))
 
 
+def _decayed(params):
+    """Which leaves the decoupled weight decay shrinks: every one but a
+    leaf the model holds out of the gradient (``ops/moe.py``
+    ``expert_bias``) -- with no gradient Adam's update is zero, and decay
+    alone would still pull such a leaf toward 0 on every step."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: getattr(path[-1], "key", None) != "expert_bias",
+        params)
+
+
 def make_optimizer(learning_rate: float = 3e-4,
                    warmup_steps: int = 100,
                    total_steps: int = 10000,
@@ -43,7 +53,8 @@ def make_optimizer(learning_rate: float = 3e-4,
         end_value=learning_rate * 0.1)
     return optax.chain(
         optax.clip_by_global_norm(grad_clip),
-        optax.adamw(schedule, b1=b1, b2=b2, weight_decay=weight_decay),
+        optax.adamw(schedule, b1=b1, b2=b2, weight_decay=weight_decay,
+                    mask=_decayed),
     )
 
 

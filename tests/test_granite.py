@@ -265,16 +265,37 @@ def test_engine_counts_what_the_state_and_the_experts_moved(params):
 
 # --------------------------------------------------- the shares add up
 
-def test_the_four_expert_shares_and_the_shared_expert_add_up(params):
+def _lfm2_layer():
+    """One sparse layer of a tiny LFM2 (sigmoid scores, a selection bias
+    that is not zero, ``s / (sum + 1e-6)``), its reference and config."""
+    from benchmark.reference import lfm2_moe_ref
+    from ray_tpu.models.lfm2 import Lfm2Config, lfm2_init
+
+    tree = _scaled(lfm2_init(Lfm2Config.tiny(), jax.random.PRNGKey(7)))
+    config = {"num_experts": 8, "num_experts_per_tok": 2}
+    return tree["params"]["layer_2"], lfm2_moe_ref, config, dict(
+        scoring="sigmoid", select_bias=True, norm_eps=1e-6)
+
+
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_the_four_expert_shares_and_the_shared_expert_add_up(params,
+                                                             scoring):
     """Expert parallelism over four chips of two experts each, on one
     layer's input: the routed parts the four shares compute (ops/moe.py
-    told which experts it holds) plus the shared expert counted ONCE equal
-    the uncut reference's MoE(h) + Shared(h)."""
+    told which experts it holds) plus, where the model has one, the shared
+    expert counted ONCE equal the uncut reference's whole layer.  Under
+    softmax scoring (Granite: ``MoE(h) + Shared(h)``) and under sigmoid
+    scoring with a selection bias (models/lfm2.py: the renormalisation is
+    over the k CHOSEN, held here or not; no shared expert)."""
     import flax.linen as nn
 
     from ray_tpu.ops.moe import MoEMLP
 
-    layer = params["params"]["layer_1"]
+    if scoring == "softmax":
+        layer, reference, config, how = (params["params"]["layer_1"], ref,
+                                         CONFIG, {})
+    else:
+        layer, reference, config, how = _lfm2_layer()
     h = jnp.asarray(np.random.default_rng(5).normal(size=(1, 23, 64)),
                     jnp.float32)
     parts = []
@@ -284,7 +305,7 @@ def test_the_four_expert_shares_and_the_shared_expert_add_up(params):
             moe[name] = moe[name][2 * rank:2 * rank + 2]
         op = MoEMLP(d_model=64, d_ff=32, num_experts=8, top_k=2, gated=True,
                     norm_topk_prob=True, act=nn.silu, dtype=jnp.float32,
-                    first_expert=2 * rank, held_experts=2)
+                    first_expert=2 * rank, held_experts=2, **how)
         y, sown = op.apply({"params": moe}, h, mutable=["intermediates"])
         (m,) = sown["intermediates"]["moe"]
         assert m["load"].shape == (2,)      # the held experts only
@@ -292,9 +313,9 @@ def test_the_four_expert_shares_and_the_shared_expert_add_up(params):
     assert sum(n for _, n in parts) == 23 * 2       # every pair, once
     assert all(float(jnp.max(jnp.abs(y))) > 0 for y, _ in parts)
     flat = h.reshape(23, 64)
-    want = ref._experts_eager(flat, layer["moe"], CONFIG) \
-        + ref._shared(flat, layer)
-    got = sum(y for y, _ in parts).reshape(23, 64) + ref._shared(flat, layer)
+    shared = ref._shared(flat, layer) if scoring == "softmax" else 0.0
+    want = reference._experts_eager(flat, layer["moe"], config) + shared
+    got = sum(y for y, _ in parts).reshape(23, 64) + shared
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
@@ -319,7 +340,7 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(params, tokens):
 
 def test_the_registry_builds_the_fourth_family():
     row = MODEL_FAMILIES["granitemoehybrid"]
-    assert len(MODEL_FAMILIES) == 4 and row.config is GraniteConfig
+    assert len(MODEL_FAMILIES) == 5 and row.config is GraniteConfig
     assert family_of(row.tiny()).module is Granite
     spec = row.cache(GraniteConfig())       # as published: 36 + 4 layers
     assert (spec.kv_layers, spec.kv_heads, spec.head_dim) == (4, 8, 128)

@@ -1,0 +1,327 @@
+"""LFM2-MoE (models/lfm2.py: gated short-convolution mixers beside
+grouped-query attention with a per-head QK-norm, two dense layers ahead of
+experts routed by sigmoid scores and a selection bias) held to its plain
+float32 reference (benchmark/reference/lfm2_moe_ref.py) at a tiny size on
+the CPU: [conv, conv, attention, conv, conv], the first two layers dense,
+64 wide, 4 query and 2 K/V heads of 16, top-2 of 8 experts of width 32, 3
+taps.  Through the model, the engine's jitted forward with BOTH caches
+(the paged K/V pool and a state pool that is a conv window and nothing
+else), the engine's slots and counters, the router alone, the loss and the
+family registry."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import lfm2_moe_ref as ref
+from benchmark.tools import lfm2_faults
+from ray_tpu.models import MODEL_FAMILIES, CacheSpec, family_of
+from ray_tpu.models.lfm2 import Lfm2, Lfm2Config, lfm2_init, lfm2_loss_fn
+from ray_tpu.ops.moe import route
+
+CFG = Lfm2Config.tiny(remat=False)
+# The reference's configuration: the source's keys at the tiny size.
+CONFIG = {"num_hidden_layers": 5, "layer_types": list(CFG.layer_types),
+          "hidden_size": 64, "num_attention_heads": 4,
+          "num_key_value_heads": 2, "num_dense_layers": 2,
+          "num_experts": 8, "num_experts_per_tok": 2, "conv_L_cache": 3,
+          "norm_eps": 1e-5, "norm_topk_prob": True,
+          "routed_scaling_factor": 1,
+          "rope_parameters": {"rope_theta": 1000000}}
+
+
+def _scaled(params, factor=8.0):
+    """std-0.02 weights at 64 wide leave every router near-uniform; scaled
+    up, routing is decided and an error of the mathematics shows
+    (tests/test_olmoe.py).  The 1-D leaves (norm scales, expert_bias) and
+    the conv's taps stay as drawn."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, w: w if w.ndim == 1 or path[-1].key == "conv_w"
+        else factor * w, params)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return _scaled(lfm2_init(CFG, jax.random.PRNGKey(7)))
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 27)),
+                       jnp.int32)
+
+
+# ------------------------------------------------ forward against reference
+
+def test_forward_equals_reference(params, tokens):
+    """The full forward against the reference, logits of size ~1; and the
+    selection bias is live: without it the logits move."""
+    want = ref.forward(CONFIG, params, tokens)
+    assert float(jnp.std(want)) > 0.05
+    got = jax.jit(lambda p, t: Lfm2(CFG).apply(p, t))(params, tokens)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    with lfm2_faults.fault("no_select_bias", CFG, params) as (_, unbiased):
+        moved = ref.forward(CONFIG, unbiased, tokens)
+    assert float(jnp.max(jnp.abs(moved - want))) > 1e-2
+
+
+# ------------------------------- engine: both caches, slots, the counters
+
+PROMPTS = ([3, 17, 42, 99, 7, 250, 8], [9] * 19, [5, 1, 200, 31, 64])
+
+
+def test_prefill_then_decode_equals_reference_through_both_caches(params):
+    """Three sequences of unequal length, each prefilled padded to its
+    bucket (7 -> 8, 19 -> 32, 5 -> 8 positions) into slots that held other
+    numbers, then decoded together in a batch of 6 rows of which row 1 and
+    row 5 are empty: at every generated position the logits through
+    prefill, the K/V pool, the window in its slot and batched decode equal
+    the reference's full forward over prompt + generated tokens.  A larger
+    batch with more padding gives the same."""
+    served, logits = lfm2_faults.serve(CFG, params, PROMPTS, 6, max_batch=6)
+    for prompt, toks, rows in zip(PROMPTS, served, logits):
+        want = np.asarray(ref.forward(
+            CONFIG, params, jnp.asarray([list(prompt) + toks[:-1]],
+                                        jnp.int32)))[0][len(prompt) - 1:]
+        assert len(want) == len(rows) == 6
+        np.testing.assert_allclose(np.stack(rows), want, atol=5e-5)
+    served9, logits9 = lfm2_faults.serve(CFG, params, PROMPTS, 6,
+                                         max_batch=9)
+    assert served9 == served
+    for a, b in zip(logits, logits9):
+        np.testing.assert_allclose(np.stack(a), np.stack(b), atol=1e-6)
+
+
+def _engine(params, **engine):
+    from ray_tpu.llm.engine import EngineConfig, GenerationEngine
+
+    return GenerationEngine(
+        model_cfg=CFG, params=params,
+        engine_cfg=EngineConfig(**{**dict(page_size=4, num_pages=64,
+                                          max_batch=2), **engine}))
+
+
+def _run(engine, *requests):
+    seqs = [engine.submit(list(p), max_tokens=n) for p, n in requests]
+    while not all(s.finished for s in seqs):
+        engine.step()
+    assert engine.stats()["step_errors"] == 0, engine.stats()["last_error"]
+    return [s.tokens[s.prompt_len:] for s in seqs]
+
+
+def test_a_slot_that_changes_hands_is_not_read_by_its_next_owner(params):
+    """Slots change hands without being cleared: the second sequence in
+    slot 0 (and the third, in a slot another sequence left mid-stream by
+    cancellation) get the tokens a fresh engine gives them; an eviction's
+    re-prefill rebuilds the window and reproduces the stream."""
+    engine = _engine(params)
+    first = _run(engine, (PROMPTS[1], 9))
+    assert engine.stats()["state"]["slots_used"] == 0
+    again = _run(engine, (PROMPTS[0], 9), (PROMPTS[2], 9))
+    assert again == _run(_engine(params), (PROMPTS[0], 9), (PROMPTS[2], 9))
+    assert first == _run(_engine(params), (PROMPTS[1], 9))
+    cut = engine.submit(list(PROMPTS[1]), max_tokens=30)
+    for _ in range(4):
+        engine.step()
+    assert engine.stats()["state"]["slots_used"] == 1 and cut.slot == 0
+    engine.cancel(cut.sid)
+    engine.step()
+    assert cut.finished and cut.slot is None
+    assert _run(engine, (PROMPTS[0], 9)) == [again[0]]
+    requests = ((PROMPTS[0], 20), (PROMPTS[2], 20))
+    tight = _engine(params, num_pages=10)
+    served = _run(tight, *requests)
+    assert tight.stats()["evictions"] > 0
+    assert served == _run(_engine(params), *requests)
+
+
+def test_engine_counts_a_state_pool_that_is_a_window_alone(params):
+    """stats()["state"] from the spec and the config, not from Granite's
+    shapes: one row = a window of 2 x 64 float32 and NO state-space state
+    (the pool has no ``ssm`` array); one layer's mixer = in_proj + out_proj
+    + the taps.  stats()["moe"]: ``layer_runs`` counts the 3 layers that
+    HAVE experts of the 5; the K/V rows the ONE attention layer."""
+    engine = _engine(params, max_batch=4)
+    assert set(engine._state) == {"conv"}
+    assert engine._state["conv"].shape == (4, 4, 2, 64)
+    for prompt in PROMPTS[:2]:
+        engine.submit(list(prompt), max_tokens=5)
+    engine.step()
+    assert engine.stats()["state"]["slots_used"] == 2
+    while engine.stats()["running"]:
+        engine.step()
+    stats = engine.stats()
+    state, moe, att = stats["state"], stats["moe"], stats["attention"]
+    runs = state["decode_runs"]
+    assert state["slots_total"] == 4 and state["slots_used"] == 0
+    assert runs == att["decode_runs"] == 4          # 5 tokens: 1 + 4 steps
+    assert state["state_rows_updated"] == 2 * 4 * runs  # 4 conv layers
+    assert state["state_row_bytes"] == 2 * 64 * 4
+    assert state["mixer_weight_bytes"] == (4 * 64 * 64 + 3 * 64) * 4
+    assert moe["layer_runs"] == 3 * runs
+    assert moe["pairs"] == 2 * 2 * 3 * runs         # rows x k x layers
+    want = sum(-(-(n + i) // 4) * 4
+               for n in (len(PROMPTS[0]), len(PROMPTS[1]))
+               for i in range(1, 5))
+    assert att["kv_rows_read"] == want
+
+
+def test_the_lowered_forward_names_the_scopes_the_readers_file_by():
+    """benchmark/harness/conv_phases.py and moe_phases.py file a trace's
+    operations by these names: the four ``conv.*`` scopes (the window's
+    scatter under ``conv.window``), ``attn.qk_norm``, ``mlp.dense`` inside
+    ``mlp``, and the sigmoid and the top-k under ``moe.route``."""
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_cache, init_state, pages_for
+
+    spec = MODEL_FAMILIES["lfm2moe"].cache(CFG)
+    params = jax.eval_shape(lambda: lfm2_init(CFG, jax.random.PRNGKey(0)))
+    kv = jax.eval_shape(lambda: init_cache(
+        spec.kv_layers, 16, 4, spec.kv_heads, spec.head_dim, CFG.dtype))
+    state = jax.eval_shape(lambda: init_state(spec, 2, CFG.dtype))
+    ints = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    text = jit_forward(Lfm2(CFG)).lower(
+        params, ints, kv["k_pages"], kv["v_pages"],
+        jax.ShapeDtypeStruct((2, pages_for(CFG.max_seq, 4)), jnp.int32),
+        ints, state["conv"], jax.ShapeDtypeStruct((2,), jnp.int32)
+    ).as_text(debug_info=True)
+    for name in ("conv.in_proj", "conv.gate", "conv.window", "conv.out_proj",
+                 "attn.qk_norm", "mlp/mlp.dense", "moe.route", "moe.experts",
+                 "kv.store", "lm_head"):
+        assert name in text, name
+    lines = text.splitlines()
+    assert any("conv.window" in x and "scatter" in x for x in lines)
+    assert any("moe.route" in x and "logistic" in x for x in lines)
+    assert any("moe.route" in x and "top_k" in x for x in lines)
+    assert not any("ssm." in x for x in lines)
+
+
+# ------------------------------------------------------- the router alone
+
+def _logits(rows=64, experts=8, seed=1):
+    return jnp.asarray(np.random.default_rng(seed).normal(
+        size=(rows, experts)), jnp.float32)
+
+
+def test_a_bias_changes_which_experts_and_never_a_weight():
+    """Selection on ``sigmoid(r) + b``, weights from ``sigmoid(r)`` alone:
+    a bias large enough to put expert 5 into every row's choice changes
+    the chosen set of most rows, and every weight is still the chosen
+    expert's own sigmoid over the chosen experts' sum; a draw as init's
+    (std 0.02, at 64 experts top-4) moves some rows' choice and not all."""
+    r = _logits()
+    s = jax.nn.sigmoid(r)
+    w0, e0, scores = route(r, 2, True, "sigmoid", jnp.zeros(8), 1e-6)
+    np.testing.assert_array_equal(scores, s)
+    bias = jnp.zeros(8).at[5].set(10.0)
+    w1, e1, _ = route(r, 2, True, "sigmoid", bias, 1e-6)
+    assert bool(jnp.all(e1[:, 0] == 5))
+    assert float(jnp.mean(jnp.any(e0 != e1, axis=1))) > 0.5
+    chosen = jnp.take_along_axis(s, e1, axis=1)
+    np.testing.assert_allclose(
+        w1, chosen / (jnp.sum(chosen, 1, keepdims=True) + 1e-6), rtol=1e-6)
+    assert float(jnp.max(w1)) < 1.0         # no 10 in any weight
+    wide = 0.9 * _logits(rows=256, experts=64, seed=3)
+    drawn = 0.02 * jax.random.normal(jax.random.PRNGKey(0), (64,))
+    _, e3, _ = route(wide, 4, True, "sigmoid", jnp.zeros(64), 1e-6)
+    _, e4, _ = route(wide, 4, True, "sigmoid", drawn, 1e-6)
+    moved = float(jnp.mean(jnp.any(jnp.sort(e3, 1) != jnp.sort(e4, 1), 1)))
+    assert 0.2 < moved < 0.8, moved
+
+
+def test_ties_go_to_the_lower_index():
+    r = jnp.zeros((3, 8), jnp.float32).at[1, 6].set(1.0)
+    for scoring in ("softmax", "sigmoid"):
+        _, e, _ = route(r, 3, True, scoring)
+        np.testing.assert_array_equal(e, [[0, 1, 2], [6, 0, 1], [0, 1, 2]])
+    bias = jnp.zeros(8).at[3].set(0.1).at[4].set(0.1)
+    _, e, _ = route(r, 2, True, "sigmoid", bias)
+    np.testing.assert_array_equal(e, [[3, 4], [6, 3], [3, 4]])
+
+
+def test_the_weights_sum_to_one_over_one_plus_eps_over_the_sum():
+    r = _logits(seed=2)
+    w, e, s = route(r, 4, True, "sigmoid", jnp.zeros(8), 1e-6)
+    total = jnp.sum(jnp.take_along_axis(s, e, 1), 1)
+    np.testing.assert_allclose(jnp.sum(w, 1), 1 / (1 + 1e-6 / total),
+                               rtol=1e-6)
+    plain, _, _ = route(r, 4, False, "sigmoid")
+    np.testing.assert_allclose(plain, jnp.take_along_axis(s, e, 1))
+    # softmax, as it was: the k largest probabilities over their sum
+    w, e, p = route(r, 4, True)
+    top = jnp.take_along_axis(p, e, 1)
+    np.testing.assert_allclose(w, top / jnp.sum(top, 1, keepdims=True),
+                               rtol=1e-6)
+    with pytest.raises(ValueError):
+        route(r, 4, True, "tanh")
+
+
+# -------------------------------------------------------------- training
+
+def test_loss_and_every_gradient_leaf_equal_the_reference(params, tokens):
+    """... and ``expert_bias`` takes no gradient, in the program as in the
+    reference."""
+    loss, grads = jax.jit(lambda p: jax.value_and_grad(
+        lambda q: lfm2_loss_fn(CFG, q, {"tokens": tokens}))(p))(params)
+    want, want_grads = jax.jit(
+        lambda p: ref.loss_and_grads(CONFIG, p, tokens))(params)
+    assert abs(float(loss) - float(want)) < 1e-5
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    assert len(flat) == len(jax.tree_util.tree_leaves(params)) > 40
+    frozen = 0
+    for (path, g), r in zip(flat, jax.tree_util.tree_leaves(want_grads)):
+        scale = float(jnp.max(jnp.abs(r)))
+        if path[-1].key == "expert_bias":
+            assert scale == 0 and float(jnp.max(jnp.abs(g))) == 0
+            frozen += 1
+            continue
+        assert scale > 0, jax.tree_util.keystr(path)
+        assert float(jnp.max(jnp.abs(g - r))) < 2e-4 * scale, \
+            jax.tree_util.keystr(path)
+    assert frozen == 3
+
+
+# -------------------------------------------------------------- registry
+
+def test_the_registry_builds_the_fifth_family():
+    row = MODEL_FAMILIES["lfm2moe"]
+    assert len(MODEL_FAMILIES) == 5 and row.config is Lfm2Config
+    assert family_of(row.tiny()).module is Lfm2
+    spec = row.cache(Lfm2Config())      # as published: 30 + 10 layers
+    assert spec == CacheSpec(10, 8, 64, 30, (2, 2048), ())
+    cut = dataclasses.replace(
+        Lfm2Config(), layer_types=Lfm2Config().layer_types[:10])
+    assert row.cache(cut) == CacheSpec(
+        kv_layers=2, kv_heads=8, head_dim=64, state_layers=8,
+        conv_shape=(2, 2048), ssm_shape=())
+    assert cut.n_moe_layers == 8
+    assert cut.mixer_params() * 2 == 33_554_432 + 3 * 2048 * 2
+    from ray_tpu.train.distributed import rules_for_model
+
+    assert rules_for_model("lfm2_moe") == row.partition_rules()
+
+
+# ----------------------------------- the comparison can tell right from wrong
+
+@pytest.fixture(scope="module")
+def served_right(params):
+    prompts = [list(PROMPTS[1]) + list(PROMPTS[0]) * 3, list(PROMPTS[0])]
+    served, logits = lfm2_faults.serve(CFG, params, prompts, 12)
+    return prompts, served, logits
+
+
+@pytest.mark.parametrize("name", lfm2_faults.FAULTS)
+def test_each_fault_moves_the_served_logits(params, served_right, name):
+    """The things the chip run holds to the cell's tolerance
+    (benchmark/tools/lfm2_faults.py), here at the tiny size in float32,
+    fed the right program's tokens: each moves some logit by far more
+    than the ~1e-6 that separate the right program from the reference."""
+    prompts, served, right = served_right
+    with lfm2_faults.fault(name, CFG, params) as (cfg, p):
+        _, wrong = lfm2_faults.serve(cfg, p, prompts, 12, forced=served)
+    apart = max(float(np.max(np.abs(np.stack(a) - np.stack(b))))
+                for a, b in zip(right, wrong))
+    assert apart > 1e-3, apart

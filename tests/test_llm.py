@@ -525,6 +525,45 @@ def test_forward_updates_donated_pool_in_place(family):
             shape, m.temp_size_in_bytes / pool_bytes)
 
 
+def test_a_window_alone_is_the_whole_donated_state_pool():
+    """A model whose recurrent layers keep a conv window and NO state
+    (models/lfm2.py, ``ssm_shape == ()``): ``init_state`` builds no
+    ``ssm`` array, the forward takes and returns ``conv`` alone, and it
+    is aliased to the output as the pages are."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import (init_cache, init_state, pages_for,
+                                      state_arrays)
+    from ray_tpu.models import MODEL_FAMILIES
+
+    fam = MODEL_FAMILIES["lfm2moe"]
+    cfg = dataclasses.replace(fam.tiny(), remat=False)
+    spec = fam.cache(cfg)
+    assert state_arrays(spec) == ("conv",)
+    assert state_arrays(MODEL_FAMILIES["granitemoehybrid"].cache(
+        MODEL_FAMILIES["granitemoehybrid"].tiny())) == ("conv", "ssm")
+    assert state_arrays(MODEL_FAMILIES["gpt2"].cache(
+        MODEL_FAMILIES["gpt2"].tiny())) == ()
+    params = jax.eval_shape(lambda: fam.init(cfg, jax.random.PRNGKey(0)))
+    kv = jax.eval_shape(lambda: init_cache(
+        spec.kv_layers, 64, 16, spec.kv_heads, spec.head_dim, cfg.dtype))
+    state = jax.eval_shape(lambda: init_state(spec, 256, cfg.dtype))
+    assert list(state) == ["conv"] and state["conv"].shape == (4, 256, 2, 64)
+    ints = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    compiled = jit_forward(fam.module(cfg)).lower(
+        params, ints, kv["k_pages"], kv["v_pages"],
+        jax.ShapeDtypeStruct((2, pages_for(cfg.max_seq, 16)), jnp.int32),
+        ints, state["conv"], jax.ShapeDtypeStruct((2,), jnp.int32)).compile()
+    pools = (kv["k_pages"], kv["v_pages"], state["conv"])
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in pools)
+    # logits, the three pools, the routing counters of the 3 sparse layers
+    assert [o.shape for o in jax.tree_util.tree_leaves(
+        compiled.out_info)][-1] == (3, 3)
+
+
 @pytest.mark.parametrize("shape", [(2, 1), (1, 32), (1, 8)],
                          ids=["decode", "prefill_chunks", "prefill_1chunk"])
 def test_forward_updates_the_donated_state_pool_in_place(shape):
@@ -581,7 +620,7 @@ def test_forward_updates_the_donated_state_pool_in_place(shape):
 def test_rope_tables_cached_and_equivalent():
     import jax.numpy as jnp
 
-    from ray_tpu.models.llama import _rope, _rope_tables
+    from ray_tpu.models.layers import _rope, _rope_tables
 
     a_cos, a_sin = _rope_tables(32, 16, 10000.0)
     b_cos, b_sin = _rope_tables(32, 16, 10000.0)

@@ -332,7 +332,8 @@ def _step_until_done(eng, seqs):
     return [s.tokens[s.prompt_len:] for s in seqs]
 
 
-@pytest.mark.parametrize("family", ["gpt2", "olmoe", "granitemoehybrid"])
+@pytest.mark.parametrize("family", ["gpt2", "olmoe", "granitemoehybrid",
+                                    "lfm2moe"])
 def test_pipelined_batch_gives_what_each_request_gives_alone(family):
     """Seven greedy requests of mixed lengths over three rows (rows
     change hands while others run; one ends at its prefill): token for

@@ -89,6 +89,9 @@ _OUTPUT_DIMS = {
               ("layer_0/w_gate/kernel", 1, "mlp"), ("embed", 0, "vocab")],
     "olmoe": [("layer_0/wq/kernel", 1, "heads"),
               ("layer_0/moe/w_gate", 2, "mlp"), ("embed", 0, "vocab")],
+    "lfm2moe": [("layer_2/attn/wq/kernel", 1, "heads"),
+                ("layer_0/w_gate/kernel", 1, "mlp"),
+                ("layer_2/moe/w_gate", 2, "mlp"), ("embed", 0, "vocab")],
 }
 
 

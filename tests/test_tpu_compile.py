@@ -422,6 +422,62 @@ def test_granite_cell_updates_both_caches_in_place(one_chip, tokens_shape):
     assert len(kernels) == (1 if tokens_shape[1] == 1 else 0)
 
 
+@pytest.mark.parametrize("tokens_shape", [(16, 1), (1, 1024)],
+                         ids=["decode", "prefill_1024"])
+def test_lfm2_cell_compiles_at_the_benchmarks_sizes(one_chip, tokens_shape):
+    """The decode and ``prefill[1024]`` programs of serve-lfm2-24b-a2b-sat
+    at the benchmark's sizes (layers 0-9 as published: 8 short-conv and 2
+    attention layers, 2 dense FFNs and 8 x 64 experts, the whole
+    vocabulary; bf16; max_batch 16 slots, 1024 pages, max_context 1024),
+    as the backend ``tpu`` builds them: 10.53 GB of weights, under the
+    chip's 16 GB with the float32 logits of a 1024-position prefill; the
+    K/V pool (of the 2 attention layers) and the state pool, which is the
+    ``conv`` array and nothing else ([8, 16, 2, 2048] bf16: 8 KB a
+    sequence a layer), aliased to the outputs; the attention layers'
+    decode step through the paged kernel at 8 K/V heads of 64; the
+    experts as the compiler's grouped kernels in the 8 layers that have
+    them."""
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_cache, init_state, pages_for
+    from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.models.lfm2 import Lfm2Config
+
+    row = MODEL_FAMILIES["lfm2moe"]
+    cfg = Lfm2Config(layer_types=Lfm2Config().layer_types[:10],
+                     attn_impl="dense", remat=False)
+    spec = row.cache(cfg)
+    assert (spec.kv_layers, spec.state_layers, spec.ssm_shape) == (2, 8, ())
+    params = jax.eval_shape(lambda: row.init(cfg, jax.random.PRNGKey(0)))
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert abs(weights - 10.53e9) < 0.01 * 10.53e9
+    kv = jax.eval_shape(lambda: init_cache(
+        spec.kv_layers, 1024, 16, spec.kv_heads, spec.head_dim, cfg.dtype))
+    state = jax.eval_shape(lambda: init_state(spec, 16, cfg.dtype))
+    assert list(state) == ["conv"]
+    assert state["conv"].shape == (8, 16, 2, 2048)
+    b = tokens_shape[0]
+    ints = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                             sharding=one_chip)
+    with _as_on_tpu():
+        compiled = jit_forward(row.module(cfg)).lower(
+            _on(params, one_chip), ints(tokens_shape),
+            _on(kv["k_pages"], one_chip), _on(kv["v_pages"], one_chip),
+            ints((b, pages_for(1024, 16))), ints(tokens_shape),
+            _on(state["conv"], one_chip), ints((b,))).compile()
+    assert _device_bytes(compiled) < 11.5e9
+    m = compiled.memory_analysis()
+    pools = [kv["k_pages"], kv["v_pages"], state["conv"]]
+    assert m.alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in pools)
+    assert m.temp_size_in_bytes < 0.1e9
+    text = compiled.as_text()
+    assert text.count('op_name="ragged-dot-metadata"') == cfg.n_moe_layers
+    kernels = re.findall(
+        r"^\s*(?:ROOT )?%paged_decode[\w.]* = .*custom-call\(", text, re.M)
+    assert len(kernels) == (2 if tokens_shape[1] == 1 else 0)
+
+
 def _train_step_and_shapes(cfg, loss_chunk):
     from ray_tpu.models.gpt2 import gpt2_init, gpt2_loss_fn
     from ray_tpu.train.train_step import TrainState, make_optimizer

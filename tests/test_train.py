@@ -93,6 +93,7 @@ def _olmoe_loop(config):
     cfg = family.tiny()
     opt = make_optimizer(learning_rate=1e-3, warmup_steps=1, total_steps=20)
     state = TrainState.create(family.init(cfg, jax.random.PRNGKey(0)), opt)
+    frozen = _frozen_leaves(state.params)
     step_fn = make_sharded_train_step(
         lambda p, b: family.loss(cfg, p, b, with_metrics=True), opt,
         has_aux=True)
@@ -121,10 +122,12 @@ def test_jax_trainer_runs_olmoe_and_reports_router_losses(tmp_path):
     assert first["loss"] > first["ce"]
 
 
-def _granite_loop(config):
-    """Tiny Granite (models/granite.py: state-space layers beside
-    attention, experts 2-5 of 8 held plus the shared one) through the
-    registry row: the chunked scan and the expert share under grad."""
+def _hybrid_loop(config):
+    """A tiny hybrid through its registry row, experts 2-5 of 8 held:
+    Granite (models/granite.py: state-space layers beside attention, a
+    shared expert; the chunked scan under grad) or LFM2 (models/lfm2.py:
+    short-conv mixers, dense layers ahead of experts routed by sigmoid
+    scores and a frozen selection bias)."""
     import dataclasses
 
     import jax
@@ -134,11 +137,12 @@ def _granite_loop(config):
     from ray_tpu.train.train_step import (TrainState, make_optimizer,
                                           make_sharded_train_step)
 
-    family = MODEL_FAMILIES["granitemoehybrid"]
+    family = MODEL_FAMILIES[config["family"]]
     cfg = dataclasses.replace(family.tiny(), first_expert=2,
                               held_experts=4)
     opt = make_optimizer(learning_rate=1e-3, warmup_steps=1, total_steps=20)
     state = TrainState.create(family.init(cfg, jax.random.PRNGKey(0)), opt)
+    frozen = _frozen_leaves(state.params)
     step_fn = make_sharded_train_step(
         lambda p, b: family.loss(cfg, p, b), opt)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 30), 0,
@@ -146,14 +150,32 @@ def _granite_loop(config):
     for i in range(config["steps"]):
         state, metrics = step_fn(state, {"tokens": tokens})
         train.report({"loss": float(metrics["loss"]), "step": i + 1})
+    # A leaf no gradient trains is bit-equal after the steps: neither the
+    # update nor the optimizer's weight decay moves it.
+    after = _frozen_leaves(state.params)
+    assert len(after) == config["frozen_leaves"]
+    for name, leaf in after.items():
+        assert np.array_equal(leaf, frozen[name]), name
+        assert np.any(leaf != 0), name
     return float(metrics["loss"])
 
 
-def test_jax_trainer_runs_granite(tmp_path):
+def _frozen_leaves(params):
+    import jax
+
+    return {jax.tree_util.keystr(path): np.array(leaf) for path, leaf
+            in jax.tree_util.tree_leaves_with_path(params)
+            if path[-1].key == "expert_bias"}
+
+
+@pytest.mark.parametrize("family", ["granitemoehybrid", "lfm2moe"])
+def test_jax_trainer_runs_granite(tmp_path, family):
     result = JaxTrainer(
-        _granite_loop, train_loop_config={"steps": 3},
+        _hybrid_loop, train_loop_config={
+            "steps": 3, "family": family,
+            "frozen_leaves": {"granitemoehybrid": 0, "lfm2moe": 3}[family]},
         scaling_config=ScalingConfig(num_workers=1),
-        run_config=RunConfig(name="granite", storage_path=str(tmp_path))
+        run_config=RunConfig(name=family, storage_path=str(tmp_path))
     ).fit()
     assert result.error is None and result.metrics["step"] == 3
     losses = [h["metrics"]["loss"] for h in result.metrics_history]
