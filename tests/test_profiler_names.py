@@ -170,7 +170,7 @@ def _capture(tmp_path, fn):
     for plane in ProfileData.from_file(path).planes:
         for line in plane.lines:
             for e in line.events:
-                if e.name.startswith(("llm.", "train.")):
+                if e.name.startswith(("llm.", "train.", "flash.")):
                     events.setdefault(e.name, []).append(dict(e.stats))
     return events
 
@@ -438,6 +438,24 @@ def test_timed_step_annotates_dispatch_and_builds_its_histogram_once(
     assert len(events["train.step.compile"]) == 1
     assert len(events["train.step.dispatch"]) == 4
     assert built.count("rt_train_step_dispatch_seconds") == 1
+
+
+def test_capture_of_a_step_compile_holds_the_flash_schedule(tmp_path):
+    """The kernels' wrapper says, where the step is traced, how much of
+    the score square they compute: a sequence of 512 in one block is
+    walked in two strips, 3 of the 4 sub-block pairs (PR 34)."""
+    cfg = dataclasses.replace(GPT2Config.tiny(), attn_impl="flash",
+                              max_seq=512, n_layer=1, n_head=2)
+    _, state, batch = _train_step_and_args(cfg)
+    step = make_sharded_train_step(
+        lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=128),
+        make_optimizer(), donate=False)
+    events = _capture(tmp_path, lambda: step(state, batch))
+    assert len(events["train.step.compile"]) == 1
+    assert events["flash.schedule"], sorted(events)
+    for tags in events["flash.schedule"]:
+        assert tags == {"t": 512, "block": 512, "sub": 256, "visited": 3,
+                        "square": 4}
 
 
 # --------------------------------------------------- the operator's capture

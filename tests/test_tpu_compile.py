@@ -92,9 +92,27 @@ def _device_bytes(compiled) -> float:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("b,t,block", [(16, 1024, 1024), (1, 8192, 256)],
-                         ids=["gpt2_b16_t1024_blk1024", "b1_t8192_blk256"])
-def test_flash_attention_fwd_bwd_compiles(one_chip, b, t, block):
+KERNEL_NAMES = ("flash_fwd", "flash_dq", "flash_dkv")
+
+
+def _kernel_calls(text):
+    """How many instructions of the compiled text call each flash kernel
+    (the benchmark's readers file device time by these names)."""
+    return {name: len(re.findall(rf"%{name}(?:\.\d+)? = ", text))
+            for name in KERNEL_NAMES}
+
+
+# The last three are the benchmark cells' own calls (32 rows a chip, the
+# four-chip cell's shard) and a family with heads of 128: one 1024 block
+# a head, walked in sub-blocks.
+@pytest.mark.parametrize("b,t,h,d,block", [
+    (16, 1024, 12, 64, 1024), (1, 8192, 12, 64, 256),
+    (32, 1024, 12, 64, 1024), (16, 1024, 10, 64, 1024),
+    (8, 1024, 16, 128, 1024)],
+    ids=["gpt2_b16_t1024_blk1024", "b1_t8192_blk256",
+         "cell_b32_t1024_blk1024", "fsdp2x2_shard_b16_h10_blk1024",
+         "d128_b8_t1024_blk1024"])
+def test_flash_attention_fwd_bwd_compiles(one_chip, b, t, h, d, block):
     from ray_tpu.ops.flash_attention import flash_attention
 
     def loss(q, k, v):
@@ -102,12 +120,14 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, b, t, block):
                               block_k=block, interpret=False)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    x = jax.ShapeDtypeStruct((b, t, 12, 64), jnp.bfloat16,
+    x = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16,
                              sharding=one_chip)
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))
                        ).lower(x, x, x).compile()
-    # Forward, dq and dkv kernels are all in the program.
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # Forward, dq and dkv kernels are all in the program, by name.
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert _kernel_calls(text) == dict.fromkeys(KERNEL_NAMES, 1)
 
 
 def _engine_args(cfg, one_chip, tokens_shape, num_pages=2048,
@@ -507,7 +527,10 @@ def test_gpt2_124m_train_step_compiles_and_fits(one_chip,
     compiled = step.lower(_on(state, one_chip),
                           _on(batch, one_chip)).compile()
     assert _device_bytes(compiled) < HBM_BYTES
-    assert "tpu_custom_call" in compiled.as_text()
+    # One kernel of each name a layer (the remat'd block's second
+    # forward is merged with the first: ``prevent_cse=False``).
+    assert _kernel_calls(compiled.as_text()) == dict.fromkeys(
+        KERNEL_NAMES, cfg.n_layer)
 
 
 def test_gpt2_sharded_step_compiles_for_four_chips(
