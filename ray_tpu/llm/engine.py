@@ -70,16 +70,18 @@ agree: every phase of ``step()`` is a profiler annotation
 ``llm.prefill.run`` ...), so a device capture shows what the host was
 doing in each idle gap, and the leaves' ``perf_counter`` sums are in
 ``stats()["phase_s"]`` (PHASE_LEAVES; ``llm.other`` is the rest of
-``step_s``, so the parts sum to the whole).  The ids of the program
-before are fetched AFTER the launch and inside the ``llm.decode`` /
-``llm.prefill`` annotation of that launch, so a capture still finds each
-device run's start under the annotation that launched it.  Nothing of
-this goes into the span ring: only the per-request lifecycle spans do.
+``step_s``, so the parts sum to the whole), with the engine thread's CPU
+time beside them, read on every 16th step (``phase_cpu_s``,
+``step_cpu_s``, ``cpu_sample``: ``spans.Phases``).
+The ids of the program before are fetched AFTER the launch and inside
+the ``llm.decode`` / ``llm.prefill`` annotation of that launch, so a
+capture still finds each device run's start under the annotation that
+launched it.  Nothing of this goes into the span ring: only the
+per-request lifecycle spans do.
 """
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import queue
 import threading
@@ -91,7 +93,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ..util import chips
-from ..util.spans import annotate
+from ..util.spans import Phases, annotate
 from .kv_cache import (PagePool, SlotPool, init_cache, init_state,
                        pages_for)
 from .sampling import (SamplingParams, jit_feed, jit_sampler, pack_rows,
@@ -129,26 +131,6 @@ PHASE_LEAVES = (
     "llm.decode.pages", "llm.decode.pack", "llm.decode.run",
     "llm.decode.fetch", "llm.decode.sample",
     "llm.publish")
-
-
-class _Phase:
-    """One leaf phase: a profiler annotation and, around the same
-    statements, a ``perf_counter`` pair added to the phase's sum."""
-
-    __slots__ = ("_sums", "_name", "_annotation", "_t0")
-
-    def __init__(self, sums: Dict[str, float], name: str):
-        self._sums, self._name = sums, name
-        self._annotation = annotate(name)
-
-    def __enter__(self) -> None:
-        self._annotation.__enter__()
-        self._t0 = time.perf_counter()
-
-    def __exit__(self, *exc) -> bool:
-        self._sums[self._name] += time.perf_counter() - self._t0
-        self._annotation.__exit__(*exc)
-        return False
 
 
 def _bucket(n: int, floor: int = 8) -> int:
@@ -402,13 +384,13 @@ class GenerationEngine:
             "decode_runs": 0, "kv_rows_read": 0, "kv_rows_held": 0,
             "kv_row_bytes": 2 * n_kv * head_dim
             * self._kv["k_pages"].dtype.itemsize}
-        # The engine thread sums a step's leaves in _pending and adds
-        # them to the totals with the step's own time in one go, under
-        # the lock, so that a stats() taken mid-step still sums up.
-        self._phase_s: Dict[str, float] = dict.fromkeys(PHASE_LEAVES, 0.0)
-        self._pending: Dict[str, float] = dict.fromkeys(PHASE_LEAVES, 0.0)
-        self._step_s = 0.0
-        self._prefill_wall_s = 0.0      # inside _prefill, leaves or not
+        # A step's leaves are handed over with the step's own time in
+        # one go, under the lock: a stats() taken mid-step still sums up.
+        self._phases = Phases(PHASE_LEAVES, "llm.other", lock=self._lock,
+                              cpu_every=16)
+        self._phase = self._phases.leaf
+        # inside _prefill, leaves or not: wall and CPU seconds
+        self._prefill_wall_s = self._prefill_cpu_s = 0.0
         self._seq_seed = seed
         # TTFT phase accounting (engine-side): waiting-queue + prefill
         # totals and TPOT (inter-token gap) sums, read through
@@ -467,7 +449,7 @@ class GenerationEngine:
         if self._thread is None or not self._thread.is_alive():
             # the loop has ended: deliver the ids it left unread
             try:
-                with self._timed():
+                with self._phases.whole():
                     self._drain("stop")
             except Exception as e:  # noqa: BLE001
                 self._poison(e)
@@ -575,18 +557,16 @@ class GenerationEngine:
 
     def stats(self) -> Dict[str, Any]:
         with self._lock:
-            phase_s = dict(self._phase_s)
-            step_s = self._step_s
             return {
                 # Largest compiled forward, by memory_analysis(): what
                 # the device must hold to run it (the allocator's peak
                 # leaves program temporaries out).
                 "peak_hbm_bytes": self._peak_program_bytes,
                 # Where step() spent its time, cumulative seconds: the
-                # leaves and llm.other sum to step_s.
-                "phase_s": phase_s,
-                "step_s": step_s,
-                "llm.other": step_s - sum(phase_s.values()),
+                # leaves and llm.other sum to step_s (phase_cpu_s,
+                # step_cpu_s: the engine thread's CPU time in the steps
+                # of cpu_sample).
+                **self._phases.totals(),
                 "prefills": self._prefills,
                 "compiles": self._compiles,
                 "kv_pages_used": self.pool.used,
@@ -594,7 +574,6 @@ class GenerationEngine:
                 "running": len(self._running),
                 "waiting": len(self._waiting),
                 "steps": self._steps,
-                "last_batch": self._last_batch,
                 "tokens_generated": self._tokens_total,
                 "prefill_tokens": self._prefill_tokens_total,
                 "evictions": self._evictions,
@@ -678,20 +657,24 @@ class GenerationEngine:
         sequence ends with its frames out.  The pipeline also drains
         before an eviction; a device error surfaces at a fetch.  Public
         for deterministic single-step tests."""
-        with self._timed():
+        with self._phases.whole():
             with annotate("llm.step", step=self._steps,
                           running=len(self._running),
                           waiting=len(self._waiting)):
                 with self._phase("llm.cancel"):
                     self._process_cancellations()
                 with annotate("llm.admit"):
-                    t1, inside = time.perf_counter(), self._prefill_wall_s
+                    (w0, c0), pw, pc = (self._phases.clocks(),
+                                        self._prefill_wall_s,
+                                        self._prefill_cpu_s)
                     try:
                         self._admit()
                     finally:        # self time: the prefills apart
-                        self._pending["llm.admit"] += (
-                            time.perf_counter() - t1
-                            - (self._prefill_wall_s - inside))
+                        w1, c1 = self._phases.clocks()
+                        self._phases.add(
+                            "llm.admit",
+                            w1 - w0 - (self._prefill_wall_s - pw),
+                            c1 - c0 - (self._prefill_cpu_s - pc))
                 if self._running:
                     with annotate("llm.decode", batch=len(self._running)):
                         self._decode_step()
@@ -703,25 +686,6 @@ class GenerationEngine:
             self._steps += 1
         return {"running": len(self._running),
                 "waiting": len(self._waiting)}
-
-    @contextlib.contextmanager
-    def _timed(self):
-        """The engine thread sums the leaves of what runs inside in
-        _pending; they are added to the totals with the whole's own time
-        in one go, under the lock, so that a stats() taken mid-step
-        still sums up."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            with self._lock:
-                for name, seconds in self._pending.items():
-                    self._phase_s[name] += seconds
-                    self._pending[name] = 0.0
-                self._step_s += time.perf_counter() - t0
-
-    def _phase(self, name: str) -> _Phase:
-        return _Phase(self._pending, name)
 
     def _process_cancellations(self) -> None:
         with self._lock:
@@ -942,7 +906,7 @@ class GenerationEngine:
                     seq.first_token_ts = t_first
 
     def _prefill(self, seq: _Sequence) -> None:
-        t0 = time.perf_counter()
+        w0, c0 = self._phases.clocks()
         try:
             with annotate("llm.prefill", seq=seq.sid,
                           request_id=seq.request_id or "",
@@ -951,7 +915,9 @@ class GenerationEngine:
                 self._prefill_annotated(seq)
         finally:
             self._prefills += 1
-            self._prefill_wall_s += time.perf_counter() - t0
+            w1, c1 = self._phases.clocks()
+            self._prefill_wall_s += w1 - w0
+            self._prefill_cpu_s += c1 - c0
 
     def _prefill_annotated(self, seq: _Sequence) -> None:
         n = len(seq.tokens)

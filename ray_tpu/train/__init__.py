@@ -20,7 +20,7 @@ from .session import (checkpoint_dir, checkpoint_on_notice,  # noqa
                       get_local_rank, get_world_rank, get_world_size,
                       interrupted, interruption, iter_device_batches,
                       load_sharded_checkpoint, report,
-                      save_sharded_checkpoint)
+                      save_sharded_checkpoint, stats)
 from .sharded_checkpoint import (load_sharded,  # noqa: F401
                                  save_sharded, verify_checkpoint)
 from .trainer import (DataParallelTrainer, JaxTrainer,  # noqa: F401

@@ -15,13 +15,24 @@ import tempfile
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
-from ..util.spans import annotate
+from ..util.spans import Phases, annotate
 from .checkpoint import Checkpoint
 from .config import TelemetryConfig
 
 _session: Optional["TrainSession"] = None
+
+# The leaves of a step loop's period, on the loop's thread; the rest of
+# a period is ``train.other``: the loop's own code, which in a loop that
+# reads its loss is the wait for the device.  ``train.input.transfer``
+# runs on the prefetch thread: its own sum and count, beside the period.
+TRAIN_LEAVES = ("train.input.wait", "train.step.dispatch",
+                "train.report.observe", "train.report.push")
+
+
+def _new_phases() -> Phases:
+    return Phases(TRAIN_LEAVES, "train.other")
 
 
 @dataclass
@@ -56,15 +67,20 @@ class TrainSession:
     # report() runs every step, and building one pays name validation
     # and the registry's global lock.
     _metrics: Dict[str, Any] = field(default_factory=dict)
+    # Where the loop's time goes, period by period: a period runs from
+    # the end of one report() to the end of the next.
+    phases: Phases = field(default_factory=_new_phases)
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None) -> None:
-        """In a profiler capture: ``train.report`` with
-        ``train.report.observe`` (telemetry) and ``train.report.push``
-        (the blocking RPC to the result queue) inside."""
-        with annotate("train.report"):
+        """In a profiler capture: ``train.report`` (tag ``step``: the
+        period's number, which the period's ``train.step.dispatch``
+        carries too) with ``train.report.observe`` (telemetry) and
+        ``train.report.push`` (the blocking RPC to the result queue)
+        inside.  Its end closes the period."""
+        with annotate("train.report", step=self._report_index):
             self._report_index += 1
-            with annotate("train.report.observe"):
+            with self.phases.leaf("train.report.observe"):
                 self._observe_step(metrics)
             payload = {"rank": self.world_rank, "metrics": dict(metrics),
                        "index": self._report_index,
@@ -78,8 +94,12 @@ class TrainSession:
             if self.result_queue is not None:
                 import ray_tpu
 
-                with annotate("train.report.push"):
+                with self.phases.leaf("train.report.push"):
                     ray_tpu.get(self.result_queue.push.remote(payload))
+        self.phases.close()
+
+    def stats(self) -> Dict[str, Any]:
+        return phase_stats(self.phases)
 
     def _metric(self, kind: str, name: str, description: str):
         m = self._metrics.get(name)
@@ -301,6 +321,42 @@ def report(metrics: Dict[str, Any],
     get_session().report(metrics, checkpoint)
 
 
+# What a step loop outside a session accumulates in: there each call of
+# the step closes a period (train_step.py).
+_loose = _new_phases()
+
+
+def step_account() -> Tuple[Phases, Optional[int]]:
+    """Where this process's step loop accounts for its time, and the
+    number of the period under way: the active session's accumulator and
+    its report index; outside a session the process's own and None (the
+    step counts its own calls)."""
+    s = _session
+    return (_loose, None) if s is None else (s.phases, s._report_index)
+
+
+def phase_stats(phases: Phases) -> Dict[str, Any]:
+    """Cumulative seconds over ``steps`` whole periods: the leaves
+    (``phase_s``) and ``train.other`` sum to ``step_s``, on the loop
+    thread's CPU clock ``phase_cpu_s`` and the rest to ``step_cpu_s``
+    (read in every period here: ``cpu_sample`` repeats the wall's sums);
+    ``longest_step_s`` is the worst single period (one stall on the
+    machine shows here and not in a median); ``transfer_s`` over
+    ``transfers`` is the prefetch thread's ``train.input.transfer``.  A
+    period that compiled the step is in none of them."""
+    with phases.lock:
+        transfer_s, transfers = phases.apart_s.get(
+            "train.input.transfer", (0.0, 0))
+        return {"steps": phases.count, **phases.totals(),
+                "transfer_s": transfer_s, "transfers": transfers,
+                "longest_step_s": phases.longest_s}
+
+
+def stats() -> Dict[str, Any]:
+    """``phase_stats`` of this process's step loop (``step_account``)."""
+    return phase_stats(step_account()[0])
+
+
 def get_checkpoint() -> Optional[Checkpoint]:
     return get_session().get_checkpoint()
 
@@ -381,7 +437,7 @@ def data_wait():
     the per-step data-wait histogram."""
     from ..util import goodput
 
-    with annotate("train.input.wait"), goodput.timed_phase(
+    with step_account()[0].leaf("train.input.wait"), goodput.timed_phase(
             "data_stall", "rt_train_data_wait_seconds",
             "Time the step loop spent waiting on input data."):
         yield
@@ -429,8 +485,10 @@ def iter_device_batches(batches, *, depth: int = 2, transfer=None,
             # feeder, let the consumer's compute overlap it.
             return jax.device_put(b)
 
+    account = step_account()[0]
+
     def annotated_transfer(b):      # on the prefetch thread
-        with annotate("train.input.transfer"):
+        with account.apart("train.input.transfer"):
             return transfer(b)
 
     return iter_prefetched(batches, depth=depth,

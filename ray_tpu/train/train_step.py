@@ -103,9 +103,11 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
     attributed to the goodput ledger: the first invocation (trace +
     XLA compile) lands in the ``compile`` phase and sets the
     ``rt_train_compile_seconds`` gauge; later invocations land in
-    ``compute`` and feed the dispatch-time histogram.  Host-side
-    timing under async dispatch is an approximation — the per-step
-    truth is the report-cadence ``rt_train_step_time_seconds``.
+    ``compute``, and the call into the executable is the leaf
+    ``train.step.dispatch`` of the loop's phase accumulator
+    (``session.step_account``; ``train.stats()``), tagged with the
+    period's number: the one ``train.report`` carries in a session, the
+    call's own count outside one, where each call closes a period.
     """
     step = make_train_step(loss_fn, optimizer, has_aux)
     jit_kwargs: Dict[str, Any] = {}
@@ -134,6 +136,7 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
 
     from ..util import goodput
     from ..util.spans import annotate
+    from .session import step_account
 
     mesh_axes = None
     if mesh is not None:
@@ -152,41 +155,38 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
     # surface as themselves: the state is donated, so there is nothing
     # to retry with.
     aot = [None]
-    dispatch_hist = [None]      # built once, at the second call
+    calls = [0]
 
     def timed_step(state, batch):
         first = aot[0] is None
-        phase = "compile" if first else "compute"
-        t0 = _time.perf_counter()
-        with goodput.ledger().phase(phase):
+        account, period = step_account()
+        with goodput.ledger().phase("compile" if first else "compute"):
             if first:
+                account.void()      # a period that compiles is no step
+                t0 = _time.perf_counter()
                 with annotate("train.step.compile"):
                     aot[0] = jitted.lower(state, batch).compile()
                 timed_step.compile_seconds = _time.perf_counter() - t0
-            with annotate("train.step.dispatch"):
+            with account.leaf("train.step.dispatch",
+                              step=calls[0] if period is None else period):
                 out = aot[0](state, batch)
-        dt = _time.perf_counter() - t0
-        try:
-            from ..util.metrics import Gauge, Histogram
+        calls[0] += 1
+        if period is None:
+            account.close()
+        if first:
+            try:
+                from ..util import xprof
+                from ..util.metrics import Gauge
 
-            if first:
+                dt = _time.perf_counter() - t0
                 Gauge("rt_train_compile_seconds",
                       "Host-side duration of the first (tracing + "
                       "XLA compile) step invocation.").set(dt)
-                from ..util import xprof
-
                 xprof.register_compiled("train_step", aot[0],
                                         mesh_axes=mesh_axes,
                                         compile_seconds=dt)
-            else:
-                if dispatch_hist[0] is None:
-                    dispatch_hist[0] = Histogram(
-                        "rt_train_step_dispatch_seconds",
-                        "Host-side duration of the jitted step call "
-                        "(approximate under async dispatch).")
-                dispatch_hist[0].observe(dt)
-        except Exception:
-            pass    # registering with xprof is best-effort
+            except Exception:
+                pass    # registering with xprof is best-effort
         return out
 
     # The executable every call runs (None before the first call), and

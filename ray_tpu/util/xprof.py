@@ -11,9 +11,7 @@ This module harvests those numbers (``register_compiled``), attributes
 each collective to the mesh axes its replica groups span, and combines
 the static program facts with measured step time into a roofline
 report: achieved vs attainable FLOP/s at the program's arithmetic
-intensity, per-axis collective byte/time shares, and a forward /
-backward / optimizer step decomposition
-(``measure_step_decomposition``).
+intensity, and per-axis collective byte/time shares.
 
 Layering matters here: everything above the "jax layer" marker is
 plain Python over plain dicts — no jax, no aiohttp, no cluster (the
@@ -631,117 +629,6 @@ def publish_device_memory() -> int:
                       tags={"device": str(d.id), "kind": kind})
                 n += 1
     return n
-
-
-def measure_step_decomposition(loss_fn, optimizer, state, batch, *,
-                               steps: int = 8, reps: int = 2,
-                               flops_per_step: Optional[float] = None,
-                               peak_flops: Optional[float] = None
-                               ) -> Dict[str, Any]:
-    """The step's decomposition: forward / backward / optimizer
-    seconds via differenced state-carried ``lax.scan`` loops.
-
-    The measurement trap: a loop-invariant body gets const-hoisted by
-    XLA (a ~10x optimistic "forward time"), so every segment loop
-    THREADS state through the scan —
-    the forward loop folds the previous loss into the batch, the grad
-    loop additionally consumes the gradients through their norm, and
-    the full loop carries the real TrainState.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    def _dep(tree, carry):
-        # Fold a data dependency on the carry into every batch leaf so
-        # the body cannot be hoisted out of the scan.
-        z = carry * 0
-        return jax.tree_util.tree_map(
-            lambda x: x + z.astype(x.dtype)
-            if hasattr(x, "dtype") else x, tree)
-
-    def fwd_loop(params, b):
-        def body(c, _):
-            loss = loss_fn(params, _dep(b, c))
-            return loss.astype(jnp.float32), None
-
-        c, _ = jax.lax.scan(body, jnp.float32(0.0), None,
-                            length=steps)
-        return c
-
-    def grad_loop(params, b):
-        def body(c, _):
-            loss, grads = jax.value_and_grad(loss_fn)(params,
-                                                      _dep(b, c))
-            # Consume the grads (sum of squares) so backward survives
-            # dead-code elimination; 0-weighted into the carry.
-            gn = sum(jnp.sum(jnp.square(g)) for g in
-                     jax.tree_util.tree_leaves(grads))
-            return (loss + 0.0 * gn).astype(jnp.float32), None
-
-        c, _ = jax.lax.scan(body, jnp.float32(0.0), None,
-                            length=steps)
-        return c
-
-    from ..train.train_step import make_train_step
-
-    step_fn = make_train_step(loss_fn, optimizer)
-
-    def full_loop(s, b):
-        def body(st, _):
-            st, m = step_fn(st, b)
-            return st, m["loss"]
-
-        s, losses = jax.lax.scan(body, s, None, length=steps)
-        # Touch the final state so the last optimizer update is live.
-        probe = jax.tree_util.tree_leaves(s.params)[0]
-        return losses[-1] + 0.0 * probe.ravel()[0].astype(
-            losses.dtype)
-
-    def _time(fn, *args):
-        jitted = jax.jit(fn)
-        out = jitted(*args)
-        _ = jax.device_get(out)         # compile + warm
-        best = float("inf")
-        for _i in range(max(reps, 1)):
-            t0 = time.perf_counter()
-            out = jitted(*args)
-            _ = jax.device_get(out)     # sync through async dispatch
-            best = min(best, time.perf_counter() - t0)
-        return best / steps
-
-    t_fwd = _time(fwd_loop, state.params, batch)
-    t_grad = _time(grad_loop, state.params, batch)
-    t_full = _time(full_loop, state, batch)
-    fwd = t_fwd
-    bwd = max(t_grad - t_fwd, 0.0)
-    opt = max(t_full - t_grad, 0.0)
-    out: Dict[str, Any] = {
-        "steps": steps,
-        "forward_s": fwd,
-        "backward_s": bwd,
-        "optimizer_s": opt,
-        "full_step_s": t_full,
-        "shares": {
-            "forward": fwd / t_full if t_full > 0 else 0.0,
-            "backward": bwd / t_full if t_full > 0 else 0.0,
-            "optimizer": opt / t_full if t_full > 0 else 0.0,
-        },
-    }
-    if flops_per_step:
-        out["flops_per_step"] = float(flops_per_step)
-        peak = peak_flops or resolve_peak_flops()
-        if peak > 0:
-            # fwd:bwd flops split by the standard 1:2 convention.
-            of_peak = {}
-            if fwd > 0:
-                of_peak["forward"] = flops_per_step / 3.0 / fwd / peak
-            if bwd > 0:
-                of_peak["backward"] = \
-                    flops_per_step * 2.0 / 3.0 / bwd / peak
-            if t_full > 0:
-                of_peak["full_step"] = flops_per_step / t_full / peak
-            out["of_peak"] = of_peak
-    return out
 
 
 # ------------------------------------------------------------------

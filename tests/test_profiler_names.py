@@ -25,6 +25,7 @@ from ray_tpu.llm.engine import (PHASE_LEAVES, EngineConfig,
                                 GenerationEngine, jit_forward)
 from ray_tpu.llm.kv_cache import init_cache
 from ray_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_init, gpt2_loss_fn
+from ray_tpu.train import session as session_mod
 from ray_tpu.train.session import TrainSession, iter_device_batches
 from ray_tpu.train.train_step import (TrainState, make_optimizer,
                                       make_sharded_train_step,
@@ -94,6 +95,102 @@ def _engine(**kw):
                                 max_tokens_default=4, **kw))
 
 
+# ------------------------------------------------- the phase accumulator
+
+class _Clocks:
+    """A wall clock and a CPU clock that move only when told to."""
+
+    def __init__(self):
+        self.wall = self.cpu = 0.0
+
+    def spend(self, wall, cpu):
+        self.wall += wall
+        self.cpu += cpu
+
+    def phases(self, leaves, other="x.other", **kw):
+        return spans.Phases(leaves, other, clock=lambda: self.wall,
+                            cpu_clock=lambda: self.cpu, **kw)
+
+
+def test_phases_leaves_and_other_sum_to_the_whole_on_both_clocks():
+    t = _Clocks()
+    ph = t.phases(("x.a", "x.b"))
+    for _ in range(3):
+        with ph.whole():
+            t.spend(1.0, 0.5)                   # the loop's own: other
+            with ph.leaf("x.a", step=1):
+                t.spend(4.0, 0.25)              # mostly waited
+            with ph.leaf("x.b"):
+                t.spend(2.0, 2.0)               # all computed
+            ph.add("x.b", 0.5, 0.5)
+            t.spend(0.5, 0.5)
+        t.spend(100.0, 100.0)                   # between wholes: nobody's
+    with ph.lock:
+        s = ph.totals()
+    assert ph.count == 3 and ph.longest_s == 7.5
+    assert s["phase_s"] == {"x.a": 12.0, "x.b": 7.5}
+    assert s["phase_cpu_s"] == {"x.a": 0.75, "x.b": 7.5}
+    assert (s["step_s"], s["step_cpu_s"]) == (22.5, 9.75)
+    assert s["x.other"] == 22.5 - 19.5
+    assert s["step_cpu_s"] - sum(s["phase_cpu_s"].values()) == 1.5
+    # the CPU clock was read in every whole: the sample is all of them
+    assert s["cpu_sample"] == {"steps": 3, "phase_s": s["phase_s"],
+                               "step_s": s["step_s"]}
+
+
+def test_phases_read_the_cpu_clock_on_one_whole_in_cpu_every():
+    """A CPU-clock read is a system call (6 us on the chip's host): the
+    engine reads it on every 16th step.  The CPU sums are over those
+    wholes, ``cpu_sample`` is the wall clock over the SAME wholes, and
+    leaves + other = whole holds on either."""
+    t = _Clocks()
+    reads = []
+    ph = spans.Phases(("x.a",), "x.other", cpu_every=3,
+                      clock=lambda: t.wall,
+                      cpu_clock=lambda: reads.append(1) or t.cpu)
+    for k in range(7):              # wholes 0, 3 and 6 are sampled
+        with ph.whole():
+            t.spend(1.0, 1.0)
+            with ph.leaf("x.a"):
+                t.spend(2.0 + k, 0.5)
+    with ph.lock:
+        s = ph.totals()
+    assert (ph.count, ph.cpu_count) == (7, 3)
+    assert len(reads) == 3 * 4      # a whole's two and its leaf's two
+    assert s["phase_s"] == {"x.a": 14.0 + 21.0} and s["step_s"] == 42.0
+    assert s["cpu_sample"] == {"steps": 3, "phase_s": {"x.a": 6.0 + 9.0},
+                               "step_s": 18.0}
+    assert s["phase_cpu_s"] == {"x.a": 1.5} and s["step_cpu_s"] == 4.5
+
+
+def test_phases_hand_over_once_a_whole_and_drop_a_void_one():
+    t = _Clocks()
+    ph = t.phases(("x.a",))
+    ph.close()                      # no whole was open: only a start
+    with ph.leaf("x.a"):
+        t.spend(1.0, 1.0)
+    with ph.lock:                   # mid-whole: nothing handed over yet
+        mid = ph.totals()
+    assert ph.count == 0 and mid["step_s"] == 0.0 == mid["phase_s"]["x.a"]
+    t.spend(2.0, 0.0)
+    ph.close()
+    ph.void()                       # the next one holds a compile
+    with ph.leaf("x.a"):
+        t.spend(50.0, 50.0)
+    ph.close()
+    with ph.leaf("x.a"):
+        t.spend(1.0, 0.0)
+    ph.close()
+    with ph.apart("x.elsewhere"):   # another thread's: its own sum
+        t.spend(7.0, 0.0)
+    with ph.lock:
+        s = ph.totals()
+    assert ph.count == 2 and ph.longest_s == 3.0
+    assert s["phase_s"] == {"x.a": 2.0} and s["step_s"] == 4.0
+    assert s["x.other"] == 2.0
+    assert ph.apart_s == {"x.elsewhere": [7.0, 1]}
+
+
 @pytest.fixture(scope="module")
 def scripted():
     """No thread: two prompts of one bucket and one of another, stepped
@@ -122,6 +219,28 @@ def test_phase_counters_partition_the_step(scripted):
         pytest.approx(s["step_s"], rel=1e-12)
     assert s["phase_s"]["llm.decode.run"] > 0
     assert s["phase_s"]["llm.prefill.fetch"] > 0
+
+
+def test_phase_cpu_counters_have_the_leaves_and_fit_inside_the_wall(
+        scripted):
+    """The engine thread's CPU clock beside the wall clock: the same
+    leaves, no leaf computing for longer than it took, and the rest of
+    the step's CPU time (llm.other's) not negative.  ``last_batch`` is
+    the gauge's private field, no key of stats()."""
+    eng, _ = scripted
+    s = eng.stats()
+    sample = s["cpu_sample"]        # the steps whose CPU time was read
+    assert tuple(s["phase_cpu_s"]) == tuple(sample["phase_s"]) == \
+        PHASE_LEAVES
+    assert 1 <= sample["steps"] == -(-s["steps"] // 16)
+    slack = 2e-3 * sample["steps"]  # the two clocks' own granularity
+    for name, cpu in s["phase_cpu_s"].items():
+        assert 0 <= cpu <= sample["phase_s"][name] + slack, name
+        assert sample["phase_s"][name] <= s["phase_s"][name]
+    assert 0 < s["step_cpu_s"] <= sample["step_s"] + slack
+    assert sample["step_s"] <= s["step_s"]
+    assert s["step_cpu_s"] - sum(s["phase_cpu_s"].values()) >= -slack
+    assert "last_batch" not in s and not hasattr(engine_mod, "_Phase")
 
 
 def test_prefills_and_compiles_count_what_the_script_did(scripted):
@@ -222,6 +341,7 @@ def test_ids_are_fetched_after_the_next_launch_inside_its_annotation(
             return False
 
     monkeypatch.setattr(engine_mod, "annotate", Recorded)
+    monkeypatch.setattr(spans, "annotate", Recorded)    # the leaves'
     eng = _engine()
     real_call, real_deliver = eng._call, eng._deliver
     launched = []                       # forwards, in launch order
@@ -309,6 +429,77 @@ def test_cpu_capture_of_session_report_holds_observe_and_push(
     for name in ("train.report", "train.report.observe",
                  "train.report.push", "train.input.transfer"):
         assert len(events[name]) == 3, name
+
+
+@pytest.fixture
+def active_session(monkeypatch):
+    monkeypatch.setattr(ray_tpu, "get", lambda ref: ref)
+    session = session_mod.init_session(
+        world_rank=0, world_size=1, local_rank=0, local_world_size=1,
+        node_rank=0, experiment_name="t", result_queue=_FakeQueue())
+    yield session
+    session_mod.shutdown_session()
+
+
+def test_scripted_session_partitions_its_period(active_session):
+    """Input wait, dispatch, report: the four leaves and train.other sum
+    to the period on both clocks; the prefetch thread's transfer is
+    counted apart; the first report only starts the first period."""
+    session = active_session
+    batches = ray_tpu.train.iter_device_batches(
+        [{"x": np.zeros((2, 2), np.float32)}] * 4, depth=1)
+    for i, _ in enumerate(batches):
+        with session_mod.step_account()[0].leaf(
+                "train.step.dispatch", step=session_mod.step_account()[1]):
+            pass
+        session.report({"step": i, "tokens": 8})
+    s = ray_tpu.train.stats()
+    assert s == session.stats()
+    assert set(s) == {"steps", "step_s", "step_cpu_s", "phase_s",
+                      "phase_cpu_s", "train.other", "transfer_s",
+                      "transfers", "longest_step_s", "cpu_sample"}
+    assert s["cpu_sample"] == {"steps": 3, "phase_s": s["phase_s"],
+                               "step_s": s["step_s"]}
+    assert s["steps"] == 3
+    assert tuple(s["phase_s"]) == tuple(s["phase_cpu_s"]) == \
+        session_mod.TRAIN_LEAVES == (
+            "train.input.wait", "train.step.dispatch",
+            "train.report.observe", "train.report.push")
+    assert sum(s["phase_s"].values()) + s["train.other"] == \
+        pytest.approx(s["step_s"], rel=1e-12)
+    assert s["train.other"] >= 0
+    assert s["phase_s"]["train.report.push"] > 0
+    assert 0 < s["step_cpu_s"] <= s["step_s"] + 2e-3 * s["steps"]
+    assert s["step_s"] / s["steps"] <= s["longest_step_s"] <= s["step_s"]
+    assert s["transfers"] == 4 and s["transfer_s"] > 0
+    assert "train.input.transfer" not in s["phase_s"]
+
+
+def test_cpu_capture_of_a_tiny_train_loop_pairs_dispatch_and_report(
+        tmp_path, active_session):
+    """What the gap readers pair a device run with: each step's
+    ``train.step.dispatch`` and ``train.report`` carry the same ``step``,
+    the session's report index, and a period that compiled the step is
+    in no sum."""
+    cfg = dataclasses.replace(GPT2Config.tiny(), attn_impl="dense")
+    _, state, batch = _train_step_and_args(cfg)
+    step = make_sharded_train_step(
+        lambda p, b: gpt2_loss_fn(cfg, p, b), make_optimizer(),
+        donate=False)
+
+    def run():
+        for i in range(4):      # the first call compiles
+            _, metrics = step(state, batch)
+            ray_tpu.train.report({"step": i,
+                                  "loss": float(metrics["loss"])})
+
+    events = _capture(tmp_path, run)
+    assert len(events["train.step.compile"]) == 1
+    assert [e["step"] for e in events["train.step.dispatch"]] == \
+        [e["step"] for e in events["train.report"]] == [0, 1, 2, 3]
+    s = active_session.stats()
+    assert s["steps"] == 3 and s["phase_s"]["train.step.dispatch"] > 0
+    assert s["longest_step_s"] < step.compile_seconds
 
 
 def test_report_builds_each_metric_once(monkeypatch):
@@ -414,6 +605,10 @@ def test_scopes_change_no_bit(monkeypatch, what):
 
 def test_timed_step_annotates_dispatch_and_builds_its_histogram_once(
         tmp_path, monkeypatch):
+    """No histogram any more (nothing read it): outside a session the
+    step's calls are the loop's periods, the dispatch is their leaf in
+    ``train.stats()``, tagged with the call's own count, and the call
+    that compiled is in no sum."""
     from ray_tpu.util import metrics
 
     built = []
@@ -424,6 +619,7 @@ def test_timed_step_annotates_dispatch_and_builds_its_histogram_once(
         return real(name, *a, **kw)
 
     monkeypatch.setattr(metrics, "Histogram", counting)
+    monkeypatch.setattr(session_mod, "_loose", session_mod._new_phases())
     cfg = dataclasses.replace(GPT2Config.tiny(), attn_impl="dense")
     _, state, batch = _train_step_and_args(cfg)
     step = make_sharded_train_step(
@@ -436,8 +632,14 @@ def test_timed_step_annotates_dispatch_and_builds_its_histogram_once(
 
     events = _capture(tmp_path, run)
     assert len(events["train.step.compile"]) == 1
-    assert len(events["train.step.dispatch"]) == 4
-    assert built.count("rt_train_step_dispatch_seconds") == 1
+    assert [e["step"] for e in events["train.step.dispatch"]] == [0, 1, 2, 3]
+    assert "rt_train_step_dispatch_seconds" not in built
+    s = ray_tpu.train.stats()
+    assert s["steps"] == 3
+    assert 0 < s["phase_s"]["train.step.dispatch"] <= s["step_s"]
+    assert sum(s["phase_s"].values()) + s["train.other"] == \
+        pytest.approx(s["step_s"], rel=1e-12)
+    assert s["longest_step_s"] < step.compile_seconds
 
 
 def test_capture_of_a_step_compile_holds_the_flash_schedule(tmp_path):
