@@ -48,6 +48,9 @@ def _sharded(fn, mesh, logical, shape):
                      out_specs=spec, check_vma=False)
 
 
+_FLASH = dict(causal=True, block_q=1024, block_k=1024)   # _attention says why
+
+
 def _attention(cfg, q, k, v, scale=None):
     """q, k, v: [B, T, H, D] -> [B, T, H, D]."""
     if cfg.attn_impl == "dense":
@@ -61,8 +64,10 @@ def _attention(cfg, q, k, v, scale=None):
         p = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", p, v)
     if cfg.attn_impl == "flash":
-        # Pallas blockwise kernel (ops/flash_attention.py): no [T, T]
-        # score matrix in HBM.  GRID blocks of the whole sequence
+        # Pallas blockwise kernel (ops/flash_attention.py, whose module
+        # docstring says the layout: heads merged into the minor
+        # dimension, read where they lie): no [T, T] score matrix in
+        # HBM.  GRID blocks of the whole sequence
         # (clamped to 1024): measured on v5e at pretraining shapes (PR
         # 34; T = 1024, 384 heads of 64, the three kernels' ms a step
         # of 12 layers), grid blocks of 256 take 135.7 and of 512 72.1
@@ -76,9 +81,7 @@ def _attention(cfg, q, k, v, scale=None):
         # diagonal skipped, each diagonal one walked the same.
         from ..ops import flash_attention
 
-        flash = functools.partial(flash_attention, causal=True,
-                                  block_q=1024, block_k=1024,
-                                  scale=scale)
+        flash = functools.partial(flash_attention, scale=scale, **_FLASH)
         if cfg.mesh is None or cfg.mesh.size == 1:
             return flash(q, k, v)
         # A Mosaic kernel is not partitioned automatically: across a
@@ -99,6 +102,30 @@ def _attention(cfg, q, k, v, scale=None):
              else ulysses_attention)
     return _sharded(functools.partial(inner, causal=True), cfg.mesh,
                     ("batch", "seq", None, None), q.shape)(q, k, v)
+
+
+def attention_qkv(cfg, qkv, heads: int):
+    """Training's ``attention`` for a block whose ONE projection makes q,
+    k and v (GPT-2's ``c_attn``): qkv [B, T, 3*H*D] -> [B, T, H*D], what
+    the output projection reads.  On one device ``attn_impl="flash"``
+    reads q, k and v where they lie in ``qkv``
+    (``ops/flash_attention.py flash_attention_qkv``).  Everything else
+    gets them split into heads, as ``attention`` takes them: the other
+    implementations, and flash across a mesh, where ``c_attn``'s columns
+    lie on the ``tensor`` axis as [q | k | v] and not by head, so the
+    split is the reshard to heads (a collective, as ever), and each
+    shard's kernels then read its own heads' three arrays as they lie."""
+    b, t, _ = qkv.shape
+    if cfg.attn_impl == "flash" and (cfg.mesh is None
+                                     or cfg.mesh.size == 1):
+        from ..ops import flash_attention_qkv
+
+        with jax.named_scope("attn.core"):
+            return flash_attention_qkv(qkv, heads, **_FLASH)
+    with jax.named_scope("attn.qkv"):
+        q, k, v = (x.reshape(b, t, heads, -1)
+                   for x in jnp.split(qkv, 3, axis=-1))
+    return attention(cfg, q, k, v)[0].reshape(b, t, -1)
 
 
 def _decode_kernel(q, k_pages) -> bool:

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.sharding import with_logical_constraint as _constrain
-from .attention import attention
+from .attention import attention, attention_qkv
 
 
 @dataclass(frozen=True)
@@ -106,15 +106,19 @@ class Block(nn.Module):
             qkv = nn.Dense(3 * cfg.d_model, dtype=cfg.dtype,
                            name="c_attn",
                            kernel_init=nn.initializers.normal(0.02))(y)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            b, t = q.shape[0], q.shape[1]
-            q = q.reshape(b, t, h, d_head)
-            k = k.reshape(b, t, h, d_head)
-            v = v.reshape(b, t, h, d_head)
-        # With a cache (prefill and single-token decode alike) this
-        # step's K/V go into the paged pool and q attends against the
-        # gathered history.
-        att, new_cache = attention(cfg, q, k, v, cache)
+        b, t = qkv.shape[0], qkv.shape[1]
+        if cache is None:
+            # Training: q, k, v stay where c_attn left them and the
+            # output comes as c_proj reads it (attention_qkv).
+            att, new_cache = attention_qkv(cfg, qkv, h), None
+        else:
+            # This step's K/V go into the paged pool (prefill and
+            # single-token decode alike) and q attends against the
+            # gathered history.
+            with jax.named_scope("attn.qkv"):
+                q, k, v = (part.reshape(b, t, h, d_head)
+                           for part in jnp.split(qkv, 3, axis=-1))
+            att, new_cache = attention(cfg, q, k, v, cache)
         with jax.named_scope("attn.out"):
             att = att.reshape(b, t, cfg.d_model)
             att = nn.Dense(cfg.d_model, dtype=cfg.dtype, name="c_proj",

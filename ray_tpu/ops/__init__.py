@@ -8,4 +8,5 @@ and whose gathered copy of the paged KV pool dominated a serving decode
 step (``paged_attention.paged_decode``).
 """
 
-from .flash_attention import flash_attention  # noqa: F401
+from .flash_attention import (flash_attention,  # noqa: F401
+                              flash_attention_qkv)

@@ -8,11 +8,26 @@ Q/K/V/O (ref: the role of the reference's fused attention backends, e.g.
 torch SDPA/FlashAttention used by release/train_tests LLM configs —
 rebuilt here natively for the MXU rather than bound from a CUDA library).
 
-Layout: q, k, v are [BH, T, D] (batch*heads folded — each program works
-on one head).  Grid (BH, num_q_blocks, num_kv_blocks) with the kv axis
-innermost and "arbitrary" semantics: per (bh, q-block) the kernel scans
-kv blocks, maintaining running max/denominator (m, l) and an fp32
-accumulator in VMEM scratch.
+Layout (said once, here): the kernels take q, k, v where the projection
+left them and write ``out``, ``dq``, ``dk``, ``dv`` where the next one
+reads them: ``[B, T, H*D]``, heads in the minor dimension, addressed by
+BlockSpec index maps.  A block's minor dimension is 128 lanes: ONE head
+of 128 to a program, or TWO adjacent heads of 64 (``packed``).  The two
+are kept apart by zeroing the other head's lanes of q (and of dO): heads
+of 64 half-fill the MXU's 128-deep tiles anyway, so contracting a zeroed
+half against the whole 128-lane k (v) block costs no pass and adds exact
+zeros; the row-side results (``acc``, ``dq``) take each head's lanes by
+a select, the column-side ones (``dk``, ``dv``) come out with zeros in
+the other head's lanes and are simply summed.  q, k and v may be three
+column ranges of ONE ``[B, T, 3*H*D]`` array (``flash_attention_qkv``:
+GPT-2's ``c_attn`` output, never split).  Every other shape (an odd
+count of heads of 64, other head sizes) is ``folded``: transposed to
+``[B*H, T, D]``, which the same kernels read as B*H rows of one head
+each.  The choice is by shapes alone (``_packs``).  Grid (B, head
+blocks, num_q_blocks, num_kv_blocks) with the kv axis innermost and
+"arbitrary" semantics: per (b, head block, q-block) the kernel scans
+kv blocks, maintaining running max/denominator (m, l) a head and an
+fp32 accumulator in VMEM scratch.
 
 Causal (``causal_schedule`` is the one description of it): grid blocks
 above the diagonal are skipped (predicated off).  A grid block ON the
@@ -29,8 +44,9 @@ masked; blocks under the diagonal are computed whole, unmasked.
 Backward: custom_vjp with the standard two-kernel flash backward — a
 dkv kernel (grid over kv blocks, scanning q) and a dq kernel (grid over
 q blocks, scanning kv), both recomputing P from the saved row-wise
-log-sum-exp instead of reading a stored score matrix, both walking a
-diagonal block by the same strips as the forward.
+log-sum-exp instead of reading a stored score matrix and delta =
+rowsum(dO * O) from the ``out`` and ``dO`` blocks they hold, both
+walking a diagonal block by the same strips as the forward.
 """
 
 from __future__ import annotations
@@ -176,33 +192,116 @@ def _lanes(x, n):
     return jnp.tile(x, (1, n // _LANES))
 
 
-def _column(ref, rows):
-    """Rows of a per-row statistic kept lane-major, ``(1, 8, t)``, as a
-    ``(rows, 1)`` column."""
-    return ref[0, :1, rows].reshape(-1, 1)
+# ------------------------------------------------------------ the layout
+class _Call(NamedTuple):
+    """What one call of the kernels is, hashable (the jitted wrappers'
+    static argument).  The operands are ``[b, t, heads * d]`` with
+    ``hpp`` heads to a program, so a block is ``hpp * d`` lanes wide;
+    ``cols`` are the column blocks at which q, k and v start in their
+    arrays (all 0 unless the three are ranges of one array)."""
+    heads: int
+    d: int
+    hpp: int
+    cols: Tuple[int, int, int]
+    scale: float
+    bq: int
+    bk: int
+    causal: bool
+    interpret: bool
+
+    @property
+    def w(self):                     # lanes of a block
+        return self.hpp * self.d
+
+    @property
+    def n(self):                     # programs (head blocks) a batch row
+        return self.heads // self.hpp
+
+    def schedule(self, t):
+        if self.causal:
+            return causal_schedule(t, self.bq, self.bk, self.d)
+        blocks = (t // self.bq) * (t // self.bk)
+        return CausalSchedule(0, (), blocks, blocks)
+
+
+def _packs(h: int, d: int) -> int:
+    """Heads a program where ``[B, T, h * d]`` is read as it lies
+    (``packed``): 1 of 128 lanes, 2 of 64; 0 where the shape has to be
+    folded to ``[B*h, T, d]`` instead.  By shapes alone."""
+    if d not in (64, _LANES) or (h * d) % _LANES:
+        return 0
+    return _LANES // d
+
+
+def _head(x, a, call):
+    """Head ``a`` of a block's lanes with the other head's zeroed:
+    contracted against a whole block, the zeros add exact zeros."""
+    if call.hpp == 1:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    mine = lane < call.d if a == 0 else lane >= call.d
+    return jnp.where(mine, x, jnp.zeros_like(x))
+
+
+def _by_head(xs, call):
+    """One block from a head's results each: head ``a``'s lanes from
+    ``xs[a]`` (whose other lanes hold a cross term or a copy)."""
+    if call.hpp == 1:
+        return xs[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, xs[0].shape, 1)
+    return jnp.where(lane < call.d, xs[0], xs[1])
+
+
+def _column(ref, a, rows):
+    """Rows of head ``a``'s per-row statistic kept lane-major,
+    ``(hpp, 8, t)``, as a ``(rows, 1)`` column."""
+    return ref[a, :1, rows].reshape(-1, 1)
+
+
+def _specs(call, qi, ki):
+    """BlockSpecs of a kernel over the grid ``(b, head block, x, y)``;
+    ``qi`` / ``ki`` pick the q / kv block from ``(x, y)``."""
+    def rows(n, col, at):
+        return pl.BlockSpec((1, n, call.w),
+                            lambda b, h, x, y: (b, at(x, y), col + h))
+
+    cq, ck, cv = call.cols
+    return {"q": rows(call.bq, cq, qi), "k": rows(call.bk, ck, ki),
+            "v": rows(call.bk, cv, ki),
+            "q_out": rows(call.bq, 0, qi), "kv_out": rows(call.bk, 0, ki),
+            # per-row statistics: [b * heads, 8, t], see _fwd_kernel
+            "stat": pl.BlockSpec(
+                (call.hpp, 8, call.bq),
+                lambda b, h, x, y: (b * call.n + h, 0, qi(x, y)))}
+
+
+_Q_MAJOR = (lambda x, y: x, lambda x, y: y)     # grid (.., q block, kv block)
+_KV_MAJOR = (lambda x, y: y, lambda x, y: x)    # grid (.., kv block, q block)
+_PARAMS = dict(compiler_params=pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")))
 
 
 # --------------------------------------------------------------- forward
-def _softmax_step(q, k, v, m, l, acc, scale, mask):
+def _softmax_step(q, k, v, m, l, scale, mask):
     """One online-softmax update of the rows ``q`` by the keys ``k``:
     running max ``m`` and denominator ``l``, each (rows, 128) with its
     value in every lane (measured on v5e, PR 34: as (rows, 1) columns the
     whole-block forward takes 18.9 ms where this takes 16.6, to the same
-    bits), accumulator ``acc`` (rows, d), all float32."""
+    bits), float32.  Returns them with ``alpha``, by which the rows'
+    accumulator shrinks, and ``P V``, which it gains."""
     s = _scores(q, k, scale, mask)
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - _lanes(m_new, s.shape[1]))
     alpha = jnp.exp(m - m_new)
     l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-    acc = acc * _lanes(alpha, acc.shape[1]) \
-        + _dot(p.astype(v.dtype), v, _NN)
-    return m_new, l, acc
+    return m_new, l, alpha, _dot(p.astype(v.dtype), v, _NN)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, bq, bk, causal, sched):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+                m_scr, l_scr, acc_scr, *, call, sched):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    nk = pl.num_programs(3)
+    heads = range(call.hpp)
 
     @pl.when(ki == 0)
     def _init():
@@ -212,61 +311,68 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     def tile(r, c, mask):
         """Rows ``r`` of the q block attend rows ``c`` of the kv block."""
-        m_scr[r, :], l_scr[r, :], acc_scr[r, :] = _softmax_step(
-            q_ref[0, r, :], k_ref[0, c, :], v_ref[0, c, :],
-            m_scr[r, :], l_scr[r, :], acc_scr[r, :], scale, mask)
+        q, k, v = q_ref[0, r, :], k_ref[0, c, :], v_ref[0, c, :]
+        alphas, pvs = [], []
+        for a in heads:
+            m_scr[a, r, :], l_scr[a, r, :], alpha, pv = _softmax_step(
+                _head(q, a, call), k, v, m_scr[a, r, :], l_scr[a, r, :],
+                call.scale, mask)
+            alphas.append(_lanes(alpha, call.w))
+            pvs.append(pv)
+        acc_scr[r, :] = acc_scr[r, :] * _by_head(alphas, call) \
+            + _by_head(pvs, call)
 
-    _over_block(causal, sched, qi, ki, bq, bk, tile)
+    _over_block(call.causal, sched, qi, ki, call.bq, call.bk, tile)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = l_scr[:, :1]
-        inv = jnp.where(l > 0, 1.0 / jnp.where(l > 0, l, 1.0), 0.0)
-        o_ref[0] = (acc_scr[...] * inv).astype(o_ref.dtype)
-        lse = m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30))
-        # (bh, 8, t) layout: TPU blocks need sublane dims divisible by 8,
-        # so the per-row lse is replicated across 8 sublanes.
-        lse_ref[0] = jnp.broadcast_to(lse.reshape(1, -1),
-                                      (8, lse.shape[0]))
+        invs = []
+        for a in heads:
+            l = l_scr[a]
+            invs.append(_lanes(
+                jnp.where(l > 0, 1.0 / jnp.where(l > 0, l, 1.0), 0.0),
+                call.w))
+            lse = m_scr[a][:, :1] + jnp.log(jnp.maximum(l[:, :1], 1e-30))
+            # (b * heads, 8, t) layout: TPU blocks need sublane dims
+            # divisible by 8, so the per-row lse is replicated across 8
+            # sublanes.
+            lse_ref[a] = jnp.broadcast_to(lse.reshape(1, -1),
+                                          (8, lse.shape[0]))
+        o_ref[0] = (acc_scr[...] * _by_head(invs, call)).astype(o_ref.dtype)
+
+
+def _qkv(ops):
+    """(q, k, v) of a call's operands: three arrays, or ONE that holds
+    them as column ranges (``call.cols`` says where)."""
+    return ops if len(ops) == 3 else ops * 3
 
 
 # Jitted: every layer of a model calls ONE traced and lowered function
 # (PR 29's lesson: a kernel body is lowered once, not once a call site).
-@functools.partial(jax.jit, static_argnames=("scale", "bq", "bk", "causal",
-                                             "interpret"))
-def _flash_forward(q, k, v, *, scale, bq, bk, causal, interpret):
-    bh, t, d = q.shape
-    nq, nk = pl.cdiv(t, bq), pl.cdiv(t, bk)
-    grid = (bh, nq, nk)
-    kernel = functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk,
-                               causal=causal,
-                               sched=causal_schedule(t, bq, bk, d))
+@functools.partial(jax.jit, static_argnames=("call",))
+def _flash_forward(ops, *, call):
+    q, k, v = _qkv(ops)
+    b, t, _ = q.shape
+    spec = _specs(call, *_Q_MAJOR)
     with jax.named_scope("flash_fwd"):
         out, lse = pl.pallas_call(
-            kernel,
+            functools.partial(_fwd_kernel, call=call,
+                              sched=call.schedule(t)),
             name="flash_fwd",
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i)),
-            ],
+            grid=(b, call.n, t // call.bq, t // call.bk),
+            in_specs=[spec["q"], spec["k"], spec["v"]],
+            out_specs=[spec["q_out"], spec["stat"]],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-                jax.ShapeDtypeStruct((bh, 8, t), jnp.float32),
+                jax.ShapeDtypeStruct((b, t, call.heads * call.d), q.dtype),
+                jax.ShapeDtypeStruct((b * call.heads, 8, t), jnp.float32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((bq, _LANES), jnp.float32),  # running max
-                pltpu.VMEM((bq, _LANES), jnp.float32),  # running denominator
-                pltpu.VMEM((bq, d), jnp.float32),       # output accumulator
+                # running max and denominator, a head
+                pltpu.VMEM((call.hpp, call.bq, _LANES), jnp.float32),
+                pltpu.VMEM((call.hpp, call.bq, _LANES), jnp.float32),
+                pltpu.VMEM((call.bq, call.w), jnp.float32),  # accumulator
             ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
+            interpret=call.interpret, **_PARAMS,
         )(q, k, v)
     return out, lse
 
@@ -279,11 +385,40 @@ def _p_ds(q, k, v, do, lse, delta, scale, mask):
     return p, p * (_dot(do, v, _NT) - delta)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, bq, bk, causal, sched):
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+def _backward_refs(refs, with_dlse):
+    """A backward kernel's refs: q, k, v, then what ``_rows_by_head``
+    reads (``dlse`` only where the op exposes its lse), then the rest."""
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest = refs
+    dlse_ref = rest.pop(0) if with_dlse else None
+    return (k_ref, v_ref, (q_ref, o_ref, do_ref, lse_ref, dlse_ref), *rest)
+
+
+def _rows_by_head(refs, r, call):
+    """The q rows ``r`` a head of the block: (q, dO, lse, delta), q and
+    dO with the other head's lanes zeroed, the statistics as (rows, 1)
+    columns.  delta = rowsum(dO * O) is computed here from the blocks
+    the program holds anyway (summed over the block's whole lanes with
+    the other head's zeroed; XLA, asked for it, lays the whole product
+    out anew).  ``dlse``, the cotangent of an exposed log-sum-exp (ring
+    attention's merge weights): d(lse_i)/d(s_ij) = p_ij, so it enters
+    ds = p * (dp - delta + dlse) exactly like delta with the opposite
+    sign."""
+    q_ref, o_ref, do_ref, lse_ref, dlse_ref = refs
+    q, do = q_ref[0, r, :], do_ref[0, r, :]
+    prod = do.astype(jnp.float32) * o_ref[0, r, :].astype(jnp.float32)
+    for a in range(call.hpp):
+        delta = jnp.sum(_head(prod, a, call), axis=1, keepdims=True)
+        if dlse_ref is not None:
+            delta = delta - _column(dlse_ref, a, r)
+        yield (_head(q, a, call), _head(do, a, call),
+               _column(lse_ref, a, r), delta)
+
+
+def _dkv_kernel(*refs, call, sched, with_dlse):
+    k_ref, v_ref, rows, dk_ref, dv_ref, dk_scr, dv_scr = \
+        _backward_refs(refs, with_dlse)
+    ki, qi = pl.program_id(2), pl.program_id(3)
+    nq = pl.num_programs(3)
 
     @pl.when(qi == 0)
     def _init():
@@ -291,14 +426,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     def tile(r, c, mask):
-        q, do = q_ref[0, r, :], do_ref[0, r, :]
-        p, ds = _p_ds(q, k_ref[0, c, :], v_ref[0, c, :], do,
-                      _column(lse_ref, r), _column(delta_ref, r), scale,
-                      mask)
-        dv_scr[c, :] += _dot(p.astype(do.dtype), do, _TN)   # P^T @ dO
-        dk_scr[c, :] += scale * _dot(ds.astype(q.dtype), q, _TN)
+        k, v = k_ref[0, c, :], v_ref[0, c, :]
+        for q, do, lse, delta in _rows_by_head(rows, r, call):
+            p, ds = _p_ds(q, k, v, do, lse, delta, call.scale, mask)
+            # zeros in the other head's lanes of q and dO: these land
+            # in this head's lanes alone
+            dv_scr[c, :] += _dot(p.astype(do.dtype), do, _TN)   # P^T @ dO
+            dk_scr[c, :] += call.scale * _dot(ds.astype(q.dtype), q, _TN)
 
-    _over_block(causal, sched, qi, ki, bq, bk, tile)
+    _over_block(call.causal, sched, qi, ki, call.bq, call.bk, tile)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -306,161 +442,126 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, *, scale, bq, bk, causal, sched):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+def _dq_kernel(*refs, call, sched, with_dlse):
+    k_ref, v_ref, rows, dq_ref, dq_scr = _backward_refs(refs, with_dlse)
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    nk = pl.num_programs(3)
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     def tile(r, c, mask):
-        k = k_ref[0, c, :]
-        _, ds = _p_ds(q_ref[0, r, :], k, v_ref[0, c, :], do_ref[0, r, :],
-                      _column(lse_ref, r), _column(delta_ref, r), scale,
-                      mask)
-        dq_scr[r, :] += scale * _dot(ds.astype(k.dtype), k, _NN)  # dS @ K
+        k, v = k_ref[0, c, :], v_ref[0, c, :]
+        dqs = [_dot(_p_ds(q, k, v, do, lse, delta, call.scale, mask)[1]
+                    .astype(k.dtype), k, _NN)                   # dS @ K
+               for q, do, lse, delta in _rows_by_head(rows, r, call)]
+        dq_scr[r, :] += call.scale * _by_head(dqs, call)
 
-    _over_block(causal, sched, qi, ki, bq, bk, tile)
+    _over_block(call.causal, sched, qi, ki, call.bq, call.bk, tile)
 
     @pl.when(ki == nk - 1)
     def _finalize():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bq", "bk", "causal",
-                                             "interpret"))
-def _flash_backward(res, g, dlse=None, *, scale, bq, bk, causal,
-                    interpret):
-    q, k, v, out, lse = res
-    do = g
-    bh, t, d = q.shape
-    sched = causal_schedule(t, bq, bk, d)
-    # delta_i = rowsum(dO_i * O_i) — cheap, fused by XLA.
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                                  # (bh, t)
-    if dlse is not None:
-        # Cotangent flowing into the exposed log-sum-exp output (ring
-        # attention's merge weights): d(lse_i)/d(s_ij) = p_ij, so the
-        # per-row dlse term enters ds = p*(dp - delta + dlse) — i.e.
-        # exactly like delta with opposite sign.  Fold it in here so
-        # the two backward kernels need no changes.
-        delta = delta - dlse.astype(jnp.float32)
-    delta = jnp.broadcast_to(delta[:, None, :], lse.shape)    # (bh, 8, t)
-    nq, nk = pl.cdiv(t, bq), pl.cdiv(t, bk)
+@functools.partial(jax.jit, static_argnames=("call",))
+def _flash_backward(res, do, dlse=None, *, call):
+    ops, out, lse = res
+    q, k, v = _qkv(ops)
+    b, t, _ = q.shape
+    sched = call.schedule(t)
+    nq, nk = t // call.bq, t // call.bk
+    like = jax.ShapeDtypeStruct((b, t, call.heads * call.d), q.dtype)
+    stats = (lse,) if dlse is None else (
+        lse, jnp.broadcast_to(dlse.astype(jnp.float32)[:, None, :],
+                              lse.shape))                  # (b*h, 8, t)
+    kw = dict(call=call, sched=sched, with_dlse=dlse is not None)
 
+    def operands(spec):
+        return [spec["q"], spec["k"], spec["v"], spec["q_out"],
+                spec["q_out"]] + [spec["stat"]] * len(stats)
+
+    spec = _specs(call, *_KV_MAJOR)
     with jax.named_scope("flash_dkv"):
         dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel, scale=scale, bq=bq, bk=bk,
-                              causal=causal, sched=sched),
+            functools.partial(_dkv_kernel, **kw),
             name="flash_dkv",
-            grid=(bh, nk, nq),
-            in_specs=[
-                pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, 8, bq), lambda b, j, i: (b, 0, i)),
-                pl.BlockSpec((1, 8, bq), lambda b, j, i: (b, 0, i)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-                jax.ShapeDtypeStruct((bh, t, d), v.dtype),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((bk, d), jnp.float32),
-                pltpu.VMEM((bk, d), jnp.float32),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
-        )(q, k, v, do, lse, delta)
+            grid=(b, call.n, nk, nq),
+            in_specs=operands(spec),
+            out_specs=[spec["kv_out"], spec["kv_out"]],
+            out_shape=[like, like],
+            scratch_shapes=[pltpu.VMEM((call.bk, call.w), jnp.float32),
+                            pltpu.VMEM((call.bk, call.w), jnp.float32)],
+            interpret=call.interpret, **_PARAMS,
+        )(q, k, v, out, do, *stats)
 
+    spec = _specs(call, *_Q_MAJOR)
     with jax.named_scope("flash_dq"):
         dq = pl.pallas_call(
-            functools.partial(_dq_kernel, scale=scale, bq=bq, bk=bk,
-                              causal=causal, sched=sched),
+            functools.partial(_dq_kernel, **kw),
             name="flash_dq",
-            grid=(bh, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i)),
-                pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i)),
-            ],
-            out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
-        )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+            grid=(b, call.n, nq, nk),
+            in_specs=operands(spec),
+            out_specs=spec["q_out"],
+            out_shape=like,
+            scratch_shapes=[pltpu.VMEM((call.bq, call.w), jnp.float32)],
+            interpret=call.interpret, **_PARAMS,
+        )(q, k, v, out, do, *stats)
+    if len(ops) == 3:
+        return dq, dk, dv
+    # One array came in, one cotangent goes back: two kernels cannot
+    # write one array, so the three are joined here.
+    return (jnp.concatenate([dq, dk, dv], axis=-1),)
 
 
 # ------------------------------------------------------------- public op
-@functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhtd(q, k, v, scale, bq, bk, causal, interpret):
-    out, _ = _flash_forward(q, k, v, scale=scale, bq=bq, bk=bk,
-                            causal=causal, interpret=interpret)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _flash(ops, call):
+    return _flash_forward(ops, call=call)[0]
 
 
-def _flash_bhtd_fwd(q, k, v, scale, bq, bk, causal, interpret):
-    out, lse = _flash_forward(q, k, v, scale=scale, bq=bq, bk=bk,
-                              causal=causal, interpret=interpret)
-    return out, (q, k, v, out, lse)
+def _flash_fwd(ops, call):
+    out, lse = _flash_forward(ops, call=call)
+    return out, (ops, out, lse)
 
 
-def _flash_bhtd_bwd(scale, bq, bk, causal, interpret, res, g):
-    return _flash_backward(res, g, scale=scale, bq=bq, bk=bk,
-                           causal=causal, interpret=interpret)
+def _flash_bwd(call, res, g):
+    return (_flash_backward(res, g, call=call),)
 
 
-_flash_bhtd.defvjp(_flash_bhtd_fwd, _flash_bhtd_bwd)
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ------------------------------------------- partial (lse-exposing) op
 # Same kernels, but the row-wise log-sum-exp is a real (differentiable)
 # output: ring attention merges per-ring-step partial outputs with
 # lse-derived weights (see parallel/ring_attention.py).
-@functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhtd_lse(q, k, v, scale, bq, bk, causal, interpret):
-    out, lse = _flash_forward(q, k, v, scale=scale, bq=bq, bk=bk,
-                              causal=causal, interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _flash_lse(ops, call):
+    out, lse = _flash_forward(ops, call=call)
     return out, lse[:, 0, :]
 
 
-def _flash_bhtd_lse_fwd(q, k, v, scale, bq, bk, causal, interpret):
-    out, lse = _flash_forward(q, k, v, scale=scale, bq=bq, bk=bk,
-                              causal=causal, interpret=interpret)
-    return (out, lse[:, 0, :]), (q, k, v, out, lse)
+def _flash_lse_fwd(ops, call):
+    out, lse = _flash_forward(ops, call=call)
+    return (out, lse[:, 0, :]), (ops, out, lse)
 
 
-def _flash_bhtd_lse_bwd(scale, bq, bk, causal, interpret, res, g):
+def _flash_lse_bwd(call, res, g):
     do, dlse = g
-    return _flash_backward(res, do, scale=scale, bq=bq, bk=bk,
-                           causal=causal, interpret=interpret,
-                           dlse=dlse)
+    return (_flash_backward(res, do, dlse, call=call),)
 
 
-_flash_bhtd_lse.defvjp(_flash_bhtd_lse_fwd, _flash_bhtd_lse_bwd)
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def _folded(q, k, v, causal, block_q, block_k, interpret, scale):
-    """What both public ops hand the kernels: [B*H, T, D] operands, the
-    clamped blocks, the scale, and the annotation that says, when the
-    call is traced, how much of the score square the kernels compute."""
-    b, t, h, d = q.shape
+def _plan(t, h, d, causal, block_q, block_k, interpret, scale, fused=False):
+    """What the public ops hand the kernels for ``h`` heads of ``d`` over
+    ``t`` positions: the call (layout by ``_packs``, clamped blocks,
+    scale) and the annotation that says, when the call is traced, which
+    layout was taken and how much of the score square the kernels
+    compute.  ``fused``: q, k, v are column ranges of one array."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     block_q = min(block_q, t)
@@ -468,19 +569,41 @@ def _folded(q, k, v, causal, block_q, block_k, interpret, scale):
     if t % block_q or t % block_k:
         raise ValueError(f"seq len {t} must divide block sizes "
                          f"({block_q}, {block_k})")
-    scale = d ** -0.5 if scale is None else float(scale)
-
-    def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-
-    blocks = (t // block_q) * (t // block_k)
-    sched = causal_schedule(t, block_q, block_k, d) if causal \
-        else CausalSchedule(0, (), blocks, blocks)
+    hpp = _packs(h, d)
+    # folded: a row of [B*h, T, d] is one head
+    heads, per = (h, hpp) if hpp else (1, 1)
+    n = heads // per
+    call = _Call(heads, d, per, (0, n, 2 * n) if fused else (0, 0, 0),
+                 d ** -0.5 if scale is None else float(scale),
+                 block_q, block_k, bool(causal), bool(interpret))
+    sched = call.schedule(t)
     note = spans.annotate("flash.schedule", t=t, block=block_q,
                           sub=sched.sub, visited=sched.visited,
-                          square=sched.square)
-    return (fold(q), fold(k), fold(v), scale, block_q, block_k, causal,
-            interpret), note
+                          square=sched.square,
+                          layout="packed" if hpp else "folded",
+                          heads_per_program=call.hpp)
+    return call, note
+
+
+def _run(op, q, k, v, **kw):
+    """``op`` (``_flash`` or ``_flash_lse``) over q, k, v ``[B, T, H, D]``
+    in the layout their shape allows; returns what ``op`` returns with
+    ``out`` as ``[B, T, H, D]`` again."""
+    b, t, h, d = q.shape
+    call, note = _plan(t, h, d, **kw)
+    if call.heads == h:             # packed: merging H and D is a bitcast
+        ops = tuple(x.reshape(b, t, h * d) for x in (q, k, v))
+    else:
+        ops = tuple(x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+                    for x in (q, k, v))
+    with note:
+        res = op(ops, call)
+    out, *rest = res if isinstance(res, tuple) else (res,)
+    if call.heads == h:
+        out = out.reshape(b, t, h, d)
+    else:
+        out = out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return (out, *rest)
 
 
 def flash_attention_with_lse(q, k, v, *, causal: bool = True,
@@ -493,14 +616,10 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = True,
     The lse output is differentiable (its cotangent folds into the
     backward's delta term), which makes this the building block for
     blockwise/ring attention merges."""
-    b, t, h, d = q.shape
-    args, note = _folded(q, k, v, causal, block_q, block_k, interpret,
-                         scale)
-    with note:
-        out, lse = _flash_bhtd_lse(*args)
-    out = out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-    lse = lse.reshape(b, h, t).transpose(0, 2, 1)
-    return out, lse
+    b, t, h, _ = q.shape
+    out, lse = _run(_flash_lse, q, k, v, causal=causal, block_q=block_q,
+                    block_k=block_k, interpret=interpret, scale=scale)
+    return out, lse.reshape(b, h, t).transpose(0, 2, 1)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -518,9 +637,28 @@ def flash_attention(q, k, v, *, causal: bool = True,
     Block sizes must keep T % block == 0 (pretraining shapes are
     128-multiples; assert early rather than mask the tail).
     """
-    b, t, h, d = q.shape
-    args, note = _folded(q, k, v, causal, block_q, block_k, interpret,
-                         scale)
+    return _run(_flash, q, k, v, causal=causal, block_q=block_q,
+                block_k=block_k, interpret=interpret, scale=scale)[0]
+
+
+def flash_attention_qkv(qkv, heads: int, *, causal: bool = True,
+                        block_q: int = 256, block_k: int = 256,
+                        interpret: bool | None = None,
+                        scale: float | None = None):
+    """``flash_attention`` over q, k and v as ONE projection left them:
+    qkv ``[B, T, 3*H*D]`` (q's ``H*D`` columns, then k's, then v's) ->
+    ``[B, T, H*D]``, what the output projection reads.  Where the shape
+    packs, the kernels read the three as column ranges of ``qkv`` (no
+    split is materialised) and ``qkv``'s cotangent is the three joined;
+    where it does not, the array is split and folded as ever."""
+    b, t, width = qkv.shape
+    d = width // (3 * heads)
+    kw = dict(causal=causal, block_q=block_q, block_k=block_k,
+              interpret=interpret, scale=scale)
+    if not _packs(heads, d):
+        q, k, v = (x.reshape(b, t, heads, d)
+                   for x in jnp.split(qkv, 3, axis=-1))
+        return flash_attention(q, k, v, **kw).reshape(b, t, heads * d)
+    call, note = _plan(t, heads, d, fused=True, **kw)
     with note:
-        out = _flash_bhtd(*args)
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+        return _flash((qkv,), call)

@@ -151,7 +151,9 @@ def test_kernel_compile_failure_surfaces_and_nothing_falls_back(
     def refuse(*a, **k):
         raise RuntimeError("Mosaic failed to compile TPU kernel: injected")
 
+    # (GPT-2's training block enters through flash_attention_qkv)
     monkeypatch.setattr(ray_tpu.ops, "flash_attention", refuse)
+    monkeypatch.setattr(ray_tpu.ops, "flash_attention_qkv", refuse)
     cfg = GPT2Config(n_layer=1, n_head=2, d_model=32, d_ff=64,
                      vocab_size=64, max_seq=16, attn_impl="flash")
     optimizer = make_optimizer(total_steps=4)

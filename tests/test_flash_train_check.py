@@ -4,7 +4,8 @@ the benchmark's own check step (``train_runner.run_check_step``), reference
 ``benchmark/traffic/pretrain-1k-full.json``, at a small size on the CPU with
 ``attn_impl="flash"`` and a sequence long enough for the diagonal block to
 be walked in sub-blocks.  The kernels as they are pass; with the walk broken
-on purpose they do not.  (``benchmark/tests/test_train_check.py`` holds the
+on purpose they do not, nor with the two heads of a 128-lane block mixed up
+(PR 36: the cell's heads of 64 are packed two to a program).  (``benchmark/tests/test_train_check.py`` holds the
 same for the dense path and fails at collection since PR 28.)"""
 import importlib
 import io
@@ -58,9 +59,31 @@ def _backward_keeps_the_whole_square(monkeypatch):
         p_ds(q, k, v, do, lse, delta, scale, None))
 
 
+def _halves_exchanged(monkeypatch):
+    """Each head's ``out`` lands in the lanes of the other head of its
+    block (the saved lse, and so the backward's P, are right)."""
+    forward = flash._flash_forward
+
+    def exchanged(ops, *, call):
+        out, lse = forward(ops, call=call)
+        b, t, _ = out.shape
+        halves = out.reshape(b, t, call.n, call.hpp, call.d)
+        return halves[:, :, :, ::-1].reshape(out.shape), lse
+
+    monkeypatch.setattr(flash, "_flash_forward", exchanged)
+
+
+def _head_mask_left_out(monkeypatch):
+    """q (and dO) keep the other head's lanes: a head's scores are the
+    sum of both heads' of its block."""
+    monkeypatch.setattr(flash, "_head", lambda x, a, call: x)
+
+
 FAULTS = {"mask_dropped": _mask_dropped, "pairs_dropped": _pairs_dropped,
           "backward_keeps_the_whole_square":
-          _backward_keeps_the_whole_square}
+          _backward_keeps_the_whole_square,
+          "halves_exchanged": _halves_exchanged,
+          "head_mask_left_out": _head_mask_left_out}
 
 
 def _verdict(tmp_path, monkeypatch, fault=None, seed=2 ** 31 + 11):
@@ -70,6 +93,7 @@ def _verdict(tmp_path, monkeypatch, fault=None, seed=2 ** 31 + 11):
             "check_file": str(tmp_path / "check_program.npz")}
     cfg = fam.program_config(CONFIG, attn_impl="flash", remat=True)
     assert flash.causal_schedule(512, 512, 512, 64).sub     # it is walked
+    assert flash._packs(CONFIG["n_head"], 64) == 2    # two heads a program
     optimizer = make_optimizer(**TRAFFIC["step"]["optimizer"])
     state = TrainState.create(fam.init(cfg, jax.random.PRNGKey(seed)),
                               optimizer)

@@ -645,7 +645,9 @@ def test_timed_step_annotates_dispatch_and_builds_its_histogram_once(
 def test_capture_of_a_step_compile_holds_the_flash_schedule(tmp_path):
     """The kernels' wrapper says, where the step is traced, how much of
     the score square they compute: a sequence of 512 in one block is
-    walked in two strips, 3 of the 4 sub-block pairs (PR 34)."""
+    walked in two strips, 3 of the 4 sub-block pairs (PR 34); and in
+    which layout they read q, k, v: this model's two heads of 64 packed
+    into one 128-lane block, one program for both (PR 36)."""
     cfg = dataclasses.replace(GPT2Config.tiny(), attn_impl="flash",
                               max_seq=512, n_layer=1, n_head=2)
     _, state, batch = _train_step_and_args(cfg)
@@ -657,7 +659,8 @@ def test_capture_of_a_step_compile_holds_the_flash_schedule(tmp_path):
     assert events["flash.schedule"], sorted(events)
     for tags in events["flash.schedule"]:
         assert tags == {"t": 512, "block": 512, "sub": 256, "visited": 3,
-                        "square": 4}
+                        "square": 4, "layout": "packed",
+                        "heads_per_program": 2}
 
 
 # --------------------------------------------------- the operator's capture

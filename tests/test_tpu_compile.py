@@ -64,14 +64,14 @@ def mesh_2x2(topo):
 
 @pytest.fixture
 def compiled_kernel(monkeypatch):
-    """The model imports ``ray_tpu.ops.flash_attention`` at call time:
-    hand it the compiled kernel, as the backend ``tpu`` would."""
+    """The model imports ``ray_tpu.ops.flash_attention`` (GPT-2's
+    training block ``flash_attention_qkv``) at call time: hand it the
+    compiled kernel, as the backend ``tpu`` would."""
     import ray_tpu.ops
-    from ray_tpu.ops.flash_attention import flash_attention
 
-    monkeypatch.setattr(ray_tpu.ops, "flash_attention",
-                        functools.partial(flash_attention,
-                                          interpret=False))
+    for name in ("flash_attention", "flash_attention_qkv"):
+        monkeypatch.setattr(ray_tpu.ops, name, functools.partial(
+            getattr(ray_tpu.ops, name), interpret=False))
 
 
 def _on(tree, sharding):
@@ -128,6 +128,35 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, b, t, h, d, block):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3
     assert _kernel_calls(text) == dict.fromkeys(KERNEL_NAMES, 1)
+
+
+@pytest.mark.parametrize("b,h,d", [(32, 12, 64), (8, 16, 128)],
+                         ids=["cell_b32_two_heads_a_program",
+                              "d128_one_head_a_program"])
+def test_flash_attention_qkv_compiles_with_no_transpose(one_chip, b, h, d):
+    """The fused entry at the cell's shape: q, k, v are three column
+    ranges of ONE [B, T, 3*H*D] operand of each kernel, and nothing the
+    size of an activation is copied or transposed around them; the
+    cotangent is the three joined by one fusion."""
+    from ray_tpu.ops import flash_attention_qkv
+
+    def loss(qkv, w):
+        out = flash_attention_qkv(qkv, h, causal=True, block_q=1024,
+                                  block_k=1024, interpret=False)
+        return jnp.sum((out @ w).astype(jnp.float32) ** 2)
+
+    qkv = jax.ShapeDtypeStruct((b, 1024, 3 * h * d), jnp.bfloat16,
+                               sharding=one_chip)
+    w = jax.ShapeDtypeStruct((h * d, h * d), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss)).lower(qkv, w).compile() \
+        .as_text()
+    assert _kernel_calls(text) == dict.fromkeys(KERNEL_NAMES, 1)
+    whole = f"bf16[{b},1024,{3 * h * d}]"
+    for name in KERNEL_NAMES:
+        call, = re.findall(rf"%{name}(?:\.\d+)? = .*", text)
+        assert call.count(whole) == 3, call[:300]
+    assert not re.findall(r"= bf16\[[\d,]+\]\S* (?:copy|transpose)\(", text)
 
 
 def _engine_args(cfg, one_chip, tokens_shape, num_pages=2048,
@@ -529,8 +558,20 @@ def test_gpt2_124m_train_step_compiles_and_fits(one_chip,
     assert _device_bytes(compiled) < HBM_BYTES
     # One kernel of each name a layer (the remat'd block's second
     # forward is merged with the first: ``prevent_cse=False``).
-    assert _kernel_calls(compiled.as_text()) == dict.fromkeys(
-        KERNEL_NAMES, cfg.n_layer)
+    text = compiled.as_text()
+    assert _kernel_calls(text) == dict.fromkeys(KERNEL_NAMES, cfg.n_layer)
+    # The kernels read q, k, v where c_attn left them and write where
+    # c_proj reads (PR 36): no activation-sized copy or transpose is
+    # left under the attention block's scopes (the parent had 14 a
+    # layer: the split, the folds and their inverses).
+    moved = [line.strip()[:100] for line in text.splitlines()
+             if re.match(r"^\s*(?:ROOT )?%?[\w.-]+ = bf16\[[\d,]+\]\S* "
+                         r"(copy|transpose)\(", line)
+             and re.search(r'op_name="[^"]*/attn\.(qkv|core|out)/', line)
+             and np.prod([int(n) for n in re.search(
+                 r"bf16\[([\d,]+)\]", line).group(1).split(",")])
+             >= 16 * 1024 * 768]
+    assert not moved, (len(moved), moved[:4])
 
 
 def test_gpt2_sharded_step_compiles_for_four_chips(
