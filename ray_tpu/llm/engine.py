@@ -52,7 +52,11 @@ alone), and gives it back with its pages at retirement,
 cancellation and eviction (the re-prefill rebuilds the state from
 position 0).  Both pools are donated to the one jitted forward and
 updated where they lie; a decode row without a sequence carries a slot
-index outside the pool.
+index outside the pool.  A model with LATENT attention (Kimi-K2) has a
+paged pool of ONE array, a row a position a layer and no V pool
+(kv_cache.py ``pool_arrays`` / ``init_pool``): the engine builds,
+carries, donates and aliases the arrays the spec names, whichever they
+are.
 
 Tokens are chosen ON THE DEVICE: after each forward one jitted sampler
 (sampling.py ``jit_sampler``) takes the device-resident logits of the
@@ -94,7 +98,7 @@ import numpy as np
 
 from ..util import chips
 from ..util.spans import Phases, annotate
-from .kv_cache import (PagePool, SlotPool, init_cache, init_state,
+from .kv_cache import (PagePool, SlotPool, init_pool, init_state,
                        pages_for)
 from .sampling import (SamplingParams, jit_feed, jit_sampler, pack_rows,
                        seed_words)
@@ -211,41 +215,56 @@ _IDLE_ROW = (SamplingParams(), (0, 0), 0)
 
 def jit_forward(model):
     """The engine's one jitted forward: it serves prefill ([1, bucket])
-    and decode ([max_batch, 1]); XLA specializes per shape.
-    ``k_pages`` / ``v_pages`` are the whole pool, each
-    [L, pages, page, h_kv*d]; donated, and carried through the layers
-    by the model, they are updated in place: the program scatters the
-    new rows and holds no second pool (tests/test_llm.py and
-    tests/test_tpu_compile.py pin that).  A model whose cache spec has
+    and decode ([max_batch, 1]); XLA specializes per shape.  After the
+    tokens it takes the paged pool's arrays, whole (llm/kv_cache.py
+    ``pool_arrays``: ``k_pages`` and ``v_pages``, each [L, pages, page,
+    h_kv*d], or for a model with latent attention the ONE array
+    ``latent_pages`` [L, pages, page, row]); donated, and carried
+    through the layers by the model, they are updated in place: the
+    program scatters the new rows and holds no second pool
+    (tests/test_llm.py and tests/test_tpu_compile.py pin that).  Then
+    the page table and the positions.  A model whose cache spec has
     recurrent layers takes, after the positions, the state pool's arrays
     (llm/kv_cache.py ``state_arrays``: ``conv`` and ``ssm``, or ``conv``
     alone; donated and updated in place as the pages are) and each row's
-    slot ``[B]``, and returns the arrays after ``v_pages``.  A model
-    with experts returns one more output, its routing counters ([layers
-    with experts, 3] int32, ops/moe.py ``moe_counters``)."""
+    slot ``[B]``.  Returns the logits, the paged pool's arrays, then the
+    state pool's.  A model with experts returns one more output, its
+    routing counters ([layers with experts, 3] int32, ops/moe.py
+    ``moe_counters``)."""
     import jax
 
     from ..models import family_of
     from ..ops.moe import moe_counters
-    from .kv_cache import state_arrays
+    from .kv_cache import pool_arrays, state_arrays
 
-    held = state_arrays(family_of(model.cfg).cache(model.cfg))
+    spec = family_of(model.cfg).cache(model.cfg)
+    pools, held = pool_arrays(spec), state_arrays(spec)
+    n = len(pools)
 
-    def fwd(p, tokens, k_pages, v_pages, page_table, positions, *state):
-        cache = {"k_pages": k_pages, "v_pages": v_pages,
-                 "page_table": page_table}
+    def run(p, tokens, paged, page_table, positions, state):
+        cache = dict(zip(pools, paged, strict=True), page_table=page_table)
         if held:
             cache.update(zip(held + ("slots",), state, strict=True))
         (logits, new), sown = model.apply(
             p, tokens, kv_cache=cache, positions=positions,
             mutable=["intermediates"])
-        out = (logits, new["k_pages"], new["v_pages"]) \
-            + tuple(new[name] for name in held)
+        out = (logits,) + tuple(new[name] for name in pools + held)
         moe = moe_counters(sown.get("intermediates", {}))
         return out if moe is None else out + (moe,)
 
-    return jax.jit(fwd, donate_argnums=(2, 3) + tuple(
-        range(6, 6 + len(held))))
+    # The parameters' names are part of the compiled program's text (and
+    # of the compile cache's key): the K/V families keep theirs.
+    if n == 2:
+        def fwd(p, tokens, k_pages, v_pages, page_table, positions, *state):
+            return run(p, tokens, (k_pages, v_pages), page_table,
+                       positions, state)
+    else:
+        def fwd(p, tokens, latent_pages, page_table, positions, *state):
+            return run(p, tokens, (latent_pages,), page_table, positions,
+                       state)
+
+    return jax.jit(fwd, donate_argnums=tuple(range(2, 2 + n)) + tuple(
+        range(4 + n, 4 + n + len(held))))
 
 
 def _program_bytes(exe) -> int:
@@ -280,7 +299,6 @@ class GenerationEngine:
         # (models.CacheSpec): pages for the layers with K/V, a slot of
         # the state pool for the recurrent ones.
         spec = self._cache_spec = family.cache(model_cfg)
-        n_kv, head_dim = spec.kv_heads, spec.head_dim
         if params is None:
             params = family.init(model_cfg, jax.random.PRNGKey(seed))
         self._params = params
@@ -292,9 +310,8 @@ class GenerationEngine:
         self._pages_per_seq = pages_for(self.max_context,
                                         self.cfg.page_size)
         self.pool = PagePool(self.cfg.num_pages, self.cfg.page_size)
-        self._kv = init_cache(spec.kv_layers, self.cfg.num_pages,
-                              self.cfg.page_size, n_kv, head_dim,
-                              model_cfg.dtype)
+        self._kv = init_pool(spec, self.cfg.num_pages, self.cfg.page_size,
+                             model_cfg.dtype)
         # One slot a running sequence: its row of the decode batch
         # (it keeps it while it runs, so the device's ids of one step
         # are the next step's tokens row for row) and, where the model
@@ -360,6 +377,8 @@ class GenerationEngine:
         self._last_batch = 0
         self._tokens_total = 0
         self._prefill_tokens_total = 0
+        # what the prefill programs computed: each prompt's bucket
+        self._prefill_bucket_tokens = 0
         self._evictions = 0
         self._prefills = 0
         self._compiles = 0
@@ -376,14 +395,18 @@ class GenerationEngine:
         # from the packed rows (stats()["attention"]): ``kv_rows_read``
         # is what the paged-decode kernel's copies move (each running
         # row's whole pages up to its length, this step's token
-        # included, times the layers; one row = one position's K and V
-        # of a layer, ``kv_row_bytes``),
+        # included, times the layers; one row = what one position of
+        # one layer occupies in the pool, ``kv_row_bytes``: its K and
+        # its V, or its one latent row with the padding, whose widths
+        # are then beside it as ``latent_dim`` / ``rope_dim``),
         # ``kv_rows_held`` what a gather of every row's whole page table
         # moves (max_batch x pages_per_seq x page_size x layers a run).
         self._attention = {
             "decode_runs": 0, "kv_rows_read": 0, "kv_rows_held": 0,
-            "kv_row_bytes": 2 * n_kv * head_dim
-            * self._kv["k_pages"].dtype.itemsize}
+            "kv_row_bytes": sum(a.shape[-1] * a.dtype.itemsize
+                                for a in self._kv.values()),
+            **({"latent_dim": spec.latent_dim, "rope_dim": spec.rope_dim}
+               if spec.latent_dim else {})}
         # A step's leaves are handed over with the step's own time in
         # one go, under the lock: a stats() taken mid-step still sums up.
         self._phases = Phases(PHASE_LEAVES, "llm.other", lock=self._lock,
@@ -576,6 +599,9 @@ class GenerationEngine:
                 "steps": self._steps,
                 "tokens_generated": self._tokens_total,
                 "prefill_tokens": self._prefill_tokens_total,
+                # ... and what their buckets made of them: the padding
+                # a power-of-two bucket costs is the difference
+                "prefill_bucket_tokens": self._prefill_bucket_tokens,
                 "evictions": self._evictions,
                 "max_context": self.max_context,
                 "step_errors": self._step_errors,
@@ -764,12 +790,13 @@ class GenerationEngine:
         the index outside the pool), taken by a model that has one."""
         name = f"llm_{kind}[{tokens.shape[1]}]" \
             if kind == "prefill" else f"llm_{kind}"
-        args = (self._params, tokens, self._kv["k_pages"],
-                self._kv["v_pages"], table, positions)
+        args = (self._params, tokens, *self._kv.values(), table, positions)
         if self._state is not None:
             args += (*self._state.values(), slots)
-        logits, k, v, *rest = self._call(self._fwd, name, *args)
-        self._kv["k_pages"], self._kv["v_pages"] = k, v
+        logits, *rest = self._call(self._fwd, name, *args)
+        n = len(self._kv)
+        self._kv = dict(zip(self._kv, rest[:n]))
+        rest = rest[n:]
         if self._state is not None:
             n = len(self._state)
             self._state = dict(zip(self._state, rest[:n]))
@@ -959,6 +986,7 @@ class GenerationEngine:
             self._feed(ids, feed_to)
         seq.n_cached = n
         self._prefill_tokens_total += n
+        self._prefill_bucket_tokens += pad
         self._count("prefill", n)
         with self._lock:
             self._running.append(seq)

@@ -48,6 +48,22 @@ is also the sequence's row of the decode batch, for every model: a
 sequence keeps its row while it runs, so the ids one decode step leaves
 on the device are the next step's tokens row for row (llm/engine.py).
 
+A model with LATENT attention (``models/kimi.py``: MLA) keeps ONE pool
+and no V pool beside it: ``latent_pages`` [layers, pages, page, row],
+where a position's row of a layer is the compressed vector every head
+shares, ``c_kv`` (``latent_dim`` numbers, after its norm), then the one
+rotary key ``k_pe`` (``rope_dim`` numbers, after RoPE), then zeros up to
+whole tiles of 128 lanes (512 + 64 -> 640: ``CacheSpec.row_width``; the
+padding is the pool's, the store's and the kernel's one shared
+decision, and ``kv_row_bytes`` counts it).  ``pool_arrays`` names what a
+spec's paged pool holds and ``init_pool`` builds it; ``latent_store``
+scatters the rows; a decode step attends IN the latent space
+(``ops/paged_attention.py paged_decode_latent`` on the ``tpu`` backend:
+the row is read once, for the scores and, its first ``latent_dim``
+lanes, for the values; ``latent_attend`` here is its plain definition);
+a prefill starts at position 0 and needs nothing from the pool
+(``models/attention.py latent_attention``).
+
 ``PagePool`` is the host-side allocator; it exports
 ``rt_llm_kv_pages_{used,total}`` gauges on every alloc/free so KV
 occupancy is visible in ``rt telemetry`` and the doctor can see leaks.
@@ -71,6 +87,22 @@ def init_cache(n_layer: int, num_pages: int, page_size: int,
     shape = (n_layer, num_pages, page_size, n_kv_head * head_dim)
     return {"k_pages": jnp.zeros(shape, dtype),
             "v_pages": jnp.zeros(shape, dtype)}
+
+
+def pool_arrays(spec) -> Tuple[str, ...]:
+    """The paged pool's arrays for a cache spec, in the order the forward
+    takes and returns them: K and V, or the one latent pool."""
+    return ("latent_pages",) if spec.latent_dim else ("k_pages", "v_pages")
+
+
+def init_pool(spec, num_pages: int, page_size: int,
+              dtype: Any) -> Dict[str, Any]:
+    """The paged pool a cache spec asks for (``pool_arrays``), zeros."""
+    if not spec.latent_dim:
+        return init_cache(spec.kv_layers, num_pages, page_size,
+                          spec.kv_heads, spec.head_dim, dtype)
+    return {"latent_pages": jnp.zeros(
+        (spec.kv_layers, num_pages, page_size, spec.row_width), dtype)}
 
 
 def state_arrays(spec) -> Tuple[str, ...]:
@@ -97,6 +129,18 @@ def init_state(spec, slots: int, dtype: Any) -> Dict[str, Any]:
             for name in state_arrays(spec)}
 
 
+def _row_index(pages, page_table, positions):
+    """Where each position's row lies in the pool ``pages``: (page index,
+    slot in the page), [B, T] each; a padded position (< 0) gets the
+    page index one past the pool, which a ``mode="drop"`` scatter drops."""
+    num_pages, page_size = pages.shape[1], pages.shape[2]
+    pos = jnp.maximum(positions, 0)
+    page_ix = jnp.take_along_axis(page_table, pos // page_size, axis=1)
+    # Out-of-range index => dropped write for padded slots.
+    page_ix = jnp.where(positions >= 0, page_ix, num_pages)
+    return page_ix, pos % page_size
+
+
 def paged_store(k_pages, v_pages, layer, k_new, v_new, page_table,
                 positions):
     """Scatter new K/V ([B, T, h_kv, d]) into layer ``layer`` of the
@@ -108,15 +152,9 @@ def paged_store(k_pages, v_pages, layer, k_new, v_new, page_table,
     out-of-range page index), so one call serves prefill (T = padded
     prompt length) and batched decode (T = 1, padded rows) alike.
     """
-    num_pages, page_size = k_pages.shape[1], k_pages.shape[2]
     b, t = positions.shape
     with jax.named_scope("kv.store"):
-        pos = jnp.maximum(positions, 0)
-        page_ix = jnp.take_along_axis(page_table, pos // page_size,
-                                      axis=1)
-        # Out-of-range index => dropped write for padded slots.
-        page_ix = jnp.where(positions >= 0, page_ix, num_pages)
-        slot = pos % page_size
+        page_ix, slot = _row_index(k_pages, page_table, positions)
         k_pages = k_pages.at[layer, page_ix, slot].set(
             k_new.reshape(b, t, -1).astype(k_pages.dtype), mode="drop")
         v_pages = v_pages.at[layer, page_ix, slot].set(
@@ -157,6 +195,50 @@ def paged_attend(q, k_pages, v_pages, layer, page_table, positions,
         scores = jnp.where(mask, scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, vs)
+
+
+def latent_store(pages, layer, c_kv, k_pe, page_table, positions):
+    """Scatter the latent rows of ``positions`` ([B, T]; < 0: padding,
+    dropped) into layer ``layer`` of the WHOLE latent pool ([L, pages,
+    page, row]) and return it: a row is ``c_kv`` [B, T, latent] then
+    ``k_pe`` [B, T, rope] then zeros up to the pool's row width."""
+    b, t = positions.shape
+    with jax.named_scope("kv.store"):
+        page_ix, slot = _row_index(pages, page_table, positions)
+        pad = pages.shape[3] - c_kv.shape[-1] - k_pe.shape[-1]
+        rows = jnp.concatenate(
+            [c_kv.astype(pages.dtype), k_pe.astype(pages.dtype),
+             jnp.zeros((b, t, pad), pages.dtype)], axis=-1)
+        return pages.at[layer, page_ix, slot].set(rows, mode="drop")
+
+
+def latent_attend(q_lat, q_pe, pages, layer, page_table, positions,
+                  scale: float):
+    """Attention in the latent space against layer ``layer`` of the
+    latent pool, the plain definition of ``ops/paged_attention.py
+    paged_decode_latent``: every head's query (``q_lat`` [B, T, H,
+    latent], its no-rope part absorbed through the key expansion, and
+    ``q_pe`` [B, T, H, rope]) meets the SAME row a position; scores
+    ``(q_lat . c_kv + q_pe . k_pe) * scale`` in float32, cache slot j
+    visible to a query at position p iff j <= p; the values are the
+    ``c_kv`` read for the scores.  Returns ``o_lat`` [B, T, H, latent]
+    in q's dtype."""
+    b, t, h, r = q_lat.shape
+    dr = q_pe.shape[-1]
+    with jax.named_scope("kv.attend"):
+        rows = pages[layer, page_table]          # [B, P, page, row]
+        p, page = rows.shape[1], rows.shape[2]
+        rows = rows.reshape(b, p * page, -1)
+        c_kv, k_pe = rows[..., :r], rows[..., r:r + dr]
+        scores = (jnp.einsum("bqhr,bkr->bhqk", q_lat, c_kv,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhr,bkr->bhqk", q_pe, k_pe,
+                               preferred_element_type=jnp.float32)) * scale
+        kv_pos = jnp.arange(p * page, dtype=jnp.int32)
+        mask = kv_pos[None, None, None, :] <= positions[:, None, :, None]
+        scores = jnp.where(mask, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q_lat.dtype)
+        return jnp.einsum("bhqk,bkr->bqhr", probs, c_kv)
 
 
 def pages_for(n_tokens: int, page_size: int) -> int:

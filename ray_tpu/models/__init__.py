@@ -1,6 +1,6 @@
 """ray_tpu.models — TPU-first reference model families.
 
-Five families run through both the trainer and the serving engine:
+Six families run through both the trainer and the serving engine:
 GPT-2 (pretrain baseline, BASELINE.json headline metric), Llama
 (RoPE/GQA/SwiGLU), OLMoE (the Llama block with QK-norm and dropless
 top-k sparse experts, ops/moe.py), Granite 4.0-H (``granitemoehybrid``:
@@ -9,16 +9,21 @@ share of the routed experts plus a shared one, models/granite.py) and
 LFM2-MoE (``lfm2moe``: gated short-convolution mixers whose whole
 recurrent state is a two-row window, grouped-query attention with a
 per-head QK-norm among every few, two dense layers ahead of experts
-routed by sigmoid scores and a selection bias, models/lfm2.py).  What
-more than one block is built from (RMSNorm, RoPE, the conv over a slot's
-window) is in models/layers.py.  All models are flax.linen with
+routed by sigmoid scores and a selection bias, models/lfm2.py) and
+Kimi-K2 (``kimik2``: multi-head LATENT attention whose cache row is one
+compressed vector and a rotary part shared by all heads, absorbed for a
+decode step and expanded for a prefill, YaRN's rotary frequencies, a
+dense layer ahead of a shared expert beside routed ones,
+models/kimi.py).  What more than one block is built from (RMSNorm, RoPE
+with or without YaRN, the conv over a slot's window) is in
+models/layers.py.  All models are flax.linen with
 *logical* dimension names threaded through ray_tpu.parallel.sharding
 rules, so DP/FSDP/TP/CP layouts are a rules-table choice, not a model
 edit.
 
 ``MODEL_FAMILIES`` is the one table the engine (``llm/engine.py``) and
 the multi-host training plane (``train.distributed.rules_for_model``)
-resolve a family through.  A sixth family is a row here:
+resolve a family through.  A seventh family is a row here:
 its config class, module, init, loss, partition rules, a tiny preset for
 tests, and its cache spec (the module's ``__call__`` takes ``kv_cache=``
 / ``positions=`` as GPT2's does, llm/kv_cache.py).  The cache spec
@@ -29,10 +34,15 @@ the first three families), and how many hold a recurrent state and its
 shapes (``state_layers``, ``conv_shape``, ``ssm_shape``: none for the
 first three; Granite's 9 layers in 10 keep a conv window and a float32
 state-space state in a slot and no K/V; LFM2's 3 in 4 keep a conv window
-alone, ``ssm_shape == ()``).  The engine builds both pools from it, and
-of the state pool the arrays the spec has.
+alone, ``ssm_shape == ()``).  A family with latent attention keeps NO
+K/V: its ``kv_layers`` hold ONE row a position in a single pool,
+``latent_dim`` + ``rope_dim`` numbers padded to whole 128-lane tiles
+(``row_width``; Kimi-K2: 512 + 64 -> 640), and ``kv_heads`` /
+``head_dim`` are 0.  The engine builds both pools from the spec
+(``llm/kv_cache.py init_pool`` / ``init_state``), and of each the
+arrays the spec has and nothing else.
 Keys are normalized lowercase-no-separator ("gpt2", "llama", "olmoe",
-"granitemoehybrid", "lfm2moe").
+"granitemoehybrid", "lfm2moe", "kimik2").
 """
 
 from dataclasses import dataclass
@@ -43,6 +53,8 @@ from .granite import (Granite, GraniteConfig, granite_init,  # noqa: F401
 
 from .gpt2 import (GPT2, GPT2Config, gpt2_init, gpt2_loss_fn,  # noqa: F401
                    gpt2_partition_rules)
+from .kimi import (KimiK2, KimiK2Config, kimi_k2_init,  # noqa: F401
+                   kimi_k2_loss_fn, kimi_k2_partition_rules)
 from .lfm2 import (Lfm2, Lfm2Config, lfm2_init,  # noqa: F401
                    lfm2_loss_fn, lfm2_partition_rules)
 from .llama import (Llama, LlamaConfig, llama_init,  # noqa: F401
@@ -59,6 +71,13 @@ class CacheSpec:
     state_layers: int = 0               # layers with a recurrent state
     conv_shape: Tuple[int, ...] = ()    # one sequence, one layer (dtype)
     ssm_shape: Tuple[int, ...] = ()     # the same, float32; (): none
+    latent_dim: int = 0                 # > 0: ONE latent row a position
+    rope_dim: int = 0                   # (c_kv | k_pe), no K/V pools
+
+    @property
+    def row_width(self) -> int:
+        """A latent row in the pool: whole tiles of 128 lanes."""
+        return -(-(self.latent_dim + self.rope_dim) // 128) * 128
 
 
 def _attention_only(kv_heads: Callable[[Any], int]):
@@ -77,6 +96,11 @@ def _lfm2_cache(cfg: Lfm2Config) -> CacheSpec:
     return CacheSpec(
         cfg.layers_of("full_attention"), cfg.n_kv_head, cfg.head_dim,
         cfg.layers_of("conv"), (cfg.conv_taps - 1, cfg.d_model))
+
+
+def _kimi_k2_cache(cfg: KimiK2Config) -> CacheSpec:
+    return CacheSpec(cfg.n_layer, 0, 0, latent_dim=cfg.kv_lora_rank,
+                     rope_dim=cfg.qk_rope_head_dim)
 
 
 @dataclass(frozen=True)
@@ -106,6 +130,9 @@ MODEL_FAMILIES = {
     "lfm2moe": ModelFamily(Lfm2Config, Lfm2, lfm2_init, lfm2_loss_fn,
                            lfm2_partition_rules, Lfm2Config.tiny,
                            _lfm2_cache),
+    "kimik2": ModelFamily(KimiK2Config, KimiK2, kimi_k2_init,
+                          kimi_k2_loss_fn, kimi_k2_partition_rules,
+                          KimiK2Config.tiny, _kimi_k2_cache),
 }
 
 

@@ -1,7 +1,8 @@
-"""The attention core both blocks call: causal attention over q, k, v
+"""The attention core the blocks call: causal attention over q, k, v
 already projected, position-encoded and split into heads.
 
-One function, ``attention``, under the scope ``attn.core``:
+``attention``, under the scope ``attn.core`` (``latent_attention``, at
+the end, is its sibling for a cache that holds no K and V):
 
 - with a ``cache`` (the engine's prefill and decode): this step's K/V
   are written into the paged pool the layers carry (``llm/kv_cache.py
@@ -28,6 +29,30 @@ axis does not divide stays whole there as everywhere else.
 ``mesh`` and ``dtype`` are read.  Position encoding is the block's
 business: what comes in is attended as it is (Granite's layers pass q and
 k with none).
+
+``latent_attention`` (Kimi-K2's MLA, ``models/kimi.py``) has TWO paths
+over the same weights, and which step takes which is decided by the
+step's shape alone:
+
+- a DECODE step (one query row a sequence, with a cache) is ABSORBED: it
+  attends in the latent space.  ``q_nope`` goes through the key half of
+  the expansion ``W_kvb`` into ``r_kv`` numbers a head (``mla.absorb``),
+  all heads meet the one row a position the pool holds (``kv.attend``:
+  ``ops/paged_attention.py paged_decode_latent`` on the ``tpu`` backend,
+  ``llm/kv_cache.py latent_attend`` elsewhere), and the result comes
+  back through the value half (``mla.absorb``).  No key or value of
+  any head is ever made;
+- everything else is EXPANDED: a PREFILL (with a cache, more than one
+  row: the engine's prefill always starts at position 0, so the
+  prompt's own rows are all it attends) stores its latent rows
+  (``kv.store``), expands them through ``W_kvb`` to per-head keys of
+  ``d_n + d_r`` and values of ``d_v`` (``mla.expand``) and attends
+  causally among them, reading nothing from the pool; training and the
+  full forward (no cache) do the same without the store.  On the
+  ``tpu`` backend a prefill of ``_FLASH_FROM`` rows or more goes through
+  the flash kernel, v padded to the keys' width (no ``[T, T]`` score
+  array reaches HBM); shorter ones, and every other backend, take the
+  dense definition.
 """
 
 from __future__ import annotations
@@ -51,9 +76,17 @@ def _sharded(fn, mesh, logical, shape):
 _FLASH = dict(causal=True, block_q=1024, block_k=1024)   # _attention says why
 
 
-def _attention(cfg, q, k, v, scale=None):
-    """q, k, v: [B, T, H, D] -> [B, T, H, D]."""
-    if cfg.attn_impl == "dense":
+def _attention(cfg, q, k, v, scale=None, impl=None):
+    """q, k: [B, T, H, D]; v: [B, T, H, Dv] -> [B, T, H, Dv] (Dv <= D:
+    the kernels take one width, so a narrower v is padded for them and
+    the padding cut off the result).  ``impl``: in place of
+    ``cfg.attn_impl``."""
+    impl = impl or cfg.attn_impl
+    dv = v.shape[-1]
+    if impl != "dense" and dv != q.shape[-1]:
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, q.shape[-1] - dv),))
+        return _attention(cfg, q, k, v, scale, impl)[..., :dv]
+    if impl == "dense":
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                             preferred_element_type=jnp.float32)
         scores = scores * (q.shape[-1] ** -0.5 if scale is None else scale)
@@ -63,7 +96,7 @@ def _attention(cfg, q, k, v, scale=None):
         scores = jnp.where(mask[None, None], scores, -1e30)
         p = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-    if cfg.attn_impl == "flash":
+    if impl == "flash":
         # Pallas blockwise kernel (ops/flash_attention.py, whose module
         # docstring says the layout: heads merged into the minor
         # dimension, read where they lie): no [T, T] score matrix in
@@ -94,11 +127,11 @@ def _attention(cfg, q, k, v, scale=None):
     from ..parallel.ulysses import ulysses_attention
 
     if cfg.mesh is None:
-        raise ValueError(f"attn_impl={cfg.attn_impl!r} needs cfg.mesh")
+        raise ValueError(f"attn_impl={impl!r} needs cfg.mesh")
     if scale is not None:
-        raise ValueError(f"attn_impl={cfg.attn_impl!r} keeps the scale "
+        raise ValueError(f"attn_impl={impl!r} keeps the scale "
                          "1/sqrt(d)")
-    inner = (ring_attention if cfg.attn_impl == "ring"
+    inner = (ring_attention if impl == "ring"
              else ulysses_attention)
     return _sharded(functools.partial(inner, causal=True), cfg.mesh,
                     ("batch", "seq", None, None), q.shape)(q, k, v)
@@ -170,6 +203,88 @@ def attention(cfg, q, k, v, cache=None, scale=None):
         if rep != 1:  # GQA: repeat KV groups to full heads
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
+        heads = ("batch", "seq", "heads", None)
+        q = with_logical_constraint(q, heads, cfg.mesh)
+        k = with_logical_constraint(k, heads, cfg.mesh)
+        v = with_logical_constraint(v, heads, cfg.mesh)
+        return _attention(cfg, q, k, v, scale), None
+
+
+# A cached prefill of this many rows or more takes the flash kernel on
+# the ``tpu`` backend (whole 128-row tiles and then some; the engine's
+# buckets are powers of two).
+_FLASH_FROM = 256
+
+
+def _latent_kernel(q_lat, pages) -> bool:
+    """Whether the absorbed branch takes the latent paged-decode kernel:
+    by shapes and the backend, nothing else."""
+    from ..ops import paged_attention
+
+    return jax.default_backend() == "tpu" \
+        and paged_attention.latent_supported(q_lat, pages)
+
+
+def _prefill_impl(t: int) -> str:
+    """What a cached prefill of ``t`` rows attends through."""
+    return "flash" if jax.default_backend() == "tpu" \
+        and t >= _FLASH_FROM else "dense"
+
+
+def latent_attention(cfg, q_nope, q_pe, c_kv, k_pe, w_kvb, scale,
+                     cache=None):
+    """Multi-head latent attention over what the block projected:
+    ``q_nope`` [B, T, H, d_n], ``q_pe`` [B, T, H, d_r] (after RoPE),
+    ``c_kv`` [B, T, r_kv] (after its norm), ``k_pe`` [B, T, d_r] (after
+    RoPE; ONE vector shared by the heads), and the expansion ``w_kvb``
+    [r_kv, H, d_n + d_v] (a head's keys, then its values).  ``scale``
+    multiplies the scores.  Returns (att [B, T, H, d_v], the latent pool
+    updated or None).  ``cache``: {"latent_pages", "layer",
+    "page_table", "positions"}.  The module docstring says which step
+    takes which path; the two are the same mathematics
+    (tests/test_kimi.py)."""
+    d_n = q_nope.shape[-1]
+    w_k, w_v = w_kvb[..., :d_n], w_kvb[..., d_n:]
+    with jax.named_scope("attn.core"):
+        pages = None
+        if cache is not None:
+            from ..llm.kv_cache import latent_attend, latent_store
+
+            pages = latent_store(cache["latent_pages"], cache["layer"],
+                                 c_kv, k_pe, cache["page_table"],
+                                 cache["positions"])
+            if q_nope.shape[1] == 1:
+                with jax.named_scope("mla.absorb"):
+                    q_lat = jnp.einsum("bthd,rhd->bthr", q_nope, w_k)
+                if _latent_kernel(q_lat, pages):
+                    from ..ops import paged_attention
+
+                    # A padded row's position is -1: length 0, zeros.
+                    o_lat = paged_attention.paged_decode_latent(
+                        q_lat, q_pe, pages, cache["layer"],
+                        cache["page_table"],
+                        cache["positions"][:, 0] + 1, scale=scale)
+                else:
+                    o_lat = latent_attend(
+                        q_lat, q_pe, pages, cache["layer"],
+                        cache["page_table"], cache["positions"], scale)
+                with jax.named_scope("mla.absorb"):
+                    return jnp.einsum("bthr,rhv->bthv", o_lat, w_v), pages
+        with jax.named_scope("mla.expand"):
+            k_nope = jnp.einsum("btr,rhd->bthd", c_kv, w_k)
+            v = jnp.einsum("btr,rhv->bthv", c_kv, w_v)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(
+                    k_pe[:, :, None, :], k_nope.shape[:3] + k_pe.shape[2:]
+                ).astype(k_nope.dtype)], axis=-1)
+            q = jnp.concatenate([q_nope, q_pe.astype(q_nope.dtype)],
+                                axis=-1)
+        if cache is not None:
+            # From position 0: the rows attend among themselves, and a
+            # bucket's padding lies behind the real ones, where the
+            # causal mask hides it from them.
+            return _attention(cfg, q, k, v, scale,
+                              _prefill_impl(q.shape[1])), pages
         heads = ("batch", "seq", "heads", None)
         q = with_logical_constraint(q, heads, cfg.mesh)
         k = with_logical_constraint(k, heads, cfg.mesh)

@@ -2,8 +2,9 @@
 position encoding, and the depthwise causal convolution over a window
 that a sequence keeps in its slot of the state pool between steps.
 
-``RMSNorm`` and ``_rope`` serve Llama, OLMoE and LFM2 (Granite takes the
-norm); ``slot_conv`` is the conv of Granite's ``Mamba2Mixer`` (4 taps,
+``RMSNorm`` and ``_rope`` serve Llama, OLMoE, LFM2 and Kimi-K2 (Granite
+takes the norm; Kimi-K2 rotates a 64-wide PART of a head, with YaRN's
+frequencies: ``yarn_inv_freq``, ``yarn_mscale``); ``slot_conv`` is the conv of Granite's ``Mamba2Mixer`` (4 taps,
 bias, silu) and of LFM2's ``ShortConvMixer`` (3 taps, neither), written
 once; ``init_by_leaf`` makes the seeded weights of all three.
 """
@@ -12,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import zlib
 from typing import Any, Callable, Optional
 
@@ -21,8 +23,41 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1``
+    (1 where nothing is stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+@functools.lru_cache(maxsize=16)
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float,
+                  beta_slow: float) -> np.ndarray:
+    """YaRN's inverse frequencies for a rotary part of ``dim`` numbers,
+    [dim/2] float32.  With ``f_i = theta ** (-2i / dim)``: a dimension
+    that turns more than ``beta_fast`` times over the ``original_max``
+    positions keeps ``f_i`` (extrapolated), one that turns fewer than
+    ``beta_slow`` times gets ``f_i / factor`` (interpolated), and between
+    the two correction dimensions ``c(rot) = dim * ln(original_max /
+    (2 pi rot)) / (2 ln theta)`` (floor of the fast one, ceiling of the
+    slow one, clipped to 0 .. dim-1) a linear ramp mixes them."""
+    def correction(rotations: float) -> float:
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    half = dim // 2
+    f = theta ** (-np.arange(half, dtype=np.float32) / half)
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)   # 0: extrapolated, 1: not
+    return (f / factor * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
 @functools.lru_cache(maxsize=64)
-def _rope_tables(seq_len: int, head_dim: int, theta: float):
+def _rope_tables(seq_len: int, head_dim: int, theta: float, yarn=None):
     """Cached sin/cos tables keyed by (seq_len, head_dim): every block
     of every forward shares one host constant per shape instead of
     re-deriving the tables inside each traced layer (they are shape-
@@ -30,27 +65,34 @@ def _rope_tables(seq_len: int, head_dim: int, theta: float):
     duplicated constants).  Deliberately NUMPY arrays — caching a
     jnp array materialized under an outer jit would leak that trace's
     tracer into later traces; numpy constants embed safely anywhere.
-    Returns ([T, D/2] cos, [T, D/2] sin) in fp32."""
+    Returns ([T, D/2] cos, [T, D/2] sin) in fp32.  ``yarn``: the
+    arguments of ``yarn_inv_freq`` after ``theta``, where the
+    frequencies are YaRN's."""
     half = head_dim // 2
-    freqs = theta ** (-np.arange(0, half, dtype=np.float32) / half)
+    freqs = theta ** (-np.arange(0, half, dtype=np.float32) / half) \
+        if yarn is None else yarn_inv_freq(head_dim, theta, *yarn)
     angles = np.arange(seq_len, dtype=np.float32)[:, None] * freqs[None, :]
     return np.cos(angles), np.sin(angles)
 
 
-def _rope(x, theta: float, positions=None):
-    """Rotary embedding over [B, T, H, D] (D even).  ``positions``
-    ([B, T] absolute, negative = padding) selects per-token angles for
-    the decode path; None means contiguous 0..T-1 (training/prefill
-    full forward) served from the cached tables."""
+def _rope(x, theta: float, positions=None, yarn=None):
+    """Rotary embedding over [B, T, H, D] (D even; rotate-half: dimension
+    i turns with dimension i + D/2).  ``positions`` ([B, T] absolute,
+    negative = padding) selects per-token angles for the decode path;
+    None means contiguous 0..T-1 (training/prefill full forward) served
+    from the cached tables.  ``yarn`` ((factor, original_max, beta_fast,
+    beta_slow), hashable): YaRN's frequencies in place of ``theta **
+    (-2i/D)``."""
     b, t, h, d = x.shape
     half = d // 2
     if positions is None:
-        cos, sin = _rope_tables(t, d, theta)
+        cos, sin = _rope_tables(t, d, theta, yarn)
         cos = cos[None, :, None, :]
         sin = sin[None, :, None, :]
     else:
         pos = jnp.maximum(positions, 0).astype(jnp.float32)
-        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half) \
+            if yarn is None else jnp.asarray(yarn_inv_freq(d, theta, *yarn))
         angles = pos[..., None] * freqs            # [B, T, half]
         cos = jnp.cos(angles)[:, :, None, :]
         sin = jnp.sin(angles)[:, :, None, :]

@@ -14,7 +14,9 @@ ungated case):
   ``score + bias`` are chosen, and their weights are the scores alone.
   The weights are renormalised over the chosen k only where the model
   says so (``norm_topk_prob``), each family in its own form (``w / sum
-  w``, or ``w / (sum w + norm_eps)``);
+  w``, or ``w / (sum w + norm_eps)``), and a family that scales its
+  routed experts' sum multiplies them by its ``routed_scaling_factor``
+  (``MoEMLP``'s field; 1, and then no operation, for the others);
 - ``moe.dispatch``: the ``S x k`` (token, expert) pairs are flattened
   and sorted by expert with a STABLE sort, so a pair's place depends on
   nothing but the pairs before it; the rows are gathered in that order;
@@ -110,6 +112,7 @@ class MoEMLP(nn.Module):
     scoring: str = "softmax"            # or "sigmoid"
     select_bias: bool = False           # choose on score + ``expert_bias``
     norm_eps: float = 0.0               # in the renormalisation's sum
+    routed_scaling_factor: float = 1.0  # on the (renormalised) weights
     act: Callable = nn.gelu
     dtype: Any = jnp.bfloat16
     first_expert: int = 0               # the share held here:
@@ -147,6 +150,8 @@ class MoEMLP(nn.Module):
             weights, experts, probs = route(
                 logits, k, self.norm_topk_prob, self.scoring, bias,
                 self.norm_eps)
+            if self.routed_scaling_factor != 1.0:
+                weights = weights * self.routed_scaling_factor
             # An invalid row's pairs go to "expert E": behind every
             # group, in none of them; so do the pairs of an expert that
             # is not held here.

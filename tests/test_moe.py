@@ -99,6 +99,31 @@ def test_moe_ungated_renormalised_case_equals_loop():
                                atol=1e-5)
 
 
+def test_routed_scaling_factor_multiplies_the_renormalised_weights():
+    """``routed_scaling_factor`` (Kimi-K2: 2.827), ``MoEMLP``'s field: the
+    same experts, the weights after their renormalisation times the
+    factor, so the layer's output times the factor; 1, the default, is no
+    operation at all (the other families' programs keep their text)."""
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(2, 9, 16)),
+                    jnp.float32)
+    kw = dict(d_model=16, d_ff=32, num_experts=8, top_k=2,
+              scoring="sigmoid", select_bias=True, norm_eps=1e-20,
+              dtype=jnp.float32)
+    plain, scaled = MoEMLP(**kw), MoEMLP(routed_scaling_factor=2.827, **kw)
+    params = plain.init(jax.random.PRNGKey(0), x)
+    y, sown = plain.apply(params, x, mutable=["intermediates"])
+    y2, sown2 = scaled.apply(params, x, mutable=["intermediates"])
+    assert float(jnp.std(y)) > 0
+    np.testing.assert_allclose(y2, 2.827 * y, rtol=1e-5, atol=1e-7)
+    np.testing.assert_array_equal(sown["intermediates"]["moe"][0]["load"],
+                                  sown2["intermediates"]["moe"][0]["load"])
+    one = MoEMLP(routed_scaling_factor=1.0, **kw)
+    assert jax.jit(lambda p: one.apply(p, x)).lower(params).as_text() == \
+        jax.jit(lambda p: plain.apply(p, x)).lower(params).as_text()
+    assert "2.827" in jax.jit(lambda p: scaled.apply(p, x)).lower(
+        params).as_text()
+
+
 def test_moe_collapsed_router_still_serves_every_token():
     """Dropless: with the router collapsed onto one expert (every row's
     first choice, 64 rows in one group) no row is dropped, and the result

@@ -527,6 +527,74 @@ def test_lfm2_cell_compiles_at_the_benchmarks_sizes(one_chip, tokens_shape):
     assert len(kernels) == (2 if tokens_shape[1] == 1 else 0)
 
 
+@pytest.mark.parametrize("tokens_shape", [(16, 1), (1, 4096)],
+                         ids=["decode", "prefill4096"])
+def test_kimi_cell_compiles_at_the_benchmarks_sizes(one_chip, tokens_shape):
+    """The decode and ``prefill[4096]`` programs of serve-kimi-k2.5-4k at
+    the benchmark's sizes (layer 0, dense, and six sparse layers at the
+    published widths; 12 of 384 experts held, a router of 384; an eighth
+    of the vocabulary; bf16; max_batch 16, 4096 pages of 16, max_context
+    4096), as the backend ``tpu`` builds them: 9.70 GB of weights and ONE
+    latent pool [7, 4096, 16, 640] (0.59 GB: a position is 1,280 bytes a
+    layer), aliased to the output, no V pool beside it.  The decode step
+    attends through the latent paged kernel, once a layer, and makes no
+    key or value of any head; the prefill attends through the flash
+    kernel at heads of 192 (v padded), once a layer, reads nothing from
+    the pool, and holds under 1.5 GB of temporaries: no ``[T, T]`` score
+    array (64 x 4096 x 4096 float32 would be 4.3 GB a layer)."""
+    import ray_tpu.models.attention as attention
+    import ray_tpu.ops
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_pool, pages_for
+    from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.models.kimi import KimiK2Config
+    from ray_tpu.ops import paged_attention
+
+    row = MODEL_FAMILIES["kimik2"]
+    cfg = KimiK2Config(vocab_size=20480, n_layer=7, held_experts=12,
+                       attn_impl="dense", remat=False)
+    spec = row.cache(cfg)
+    params = jax.eval_shape(lambda: row.init(cfg, jax.random.PRNGKey(0)))
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert abs(weights - 9.70e9) < 0.01 * 9.70e9
+    kv = jax.eval_shape(lambda: init_pool(spec, 4096, 16, cfg.dtype))
+    assert list(kv) == ["latent_pages"]
+    assert kv["latent_pages"].shape == (7, 4096, 16, 640)
+    b = tokens_shape[0]
+    ints = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                             sharding=one_chip)
+    with pytest.MonkeyPatch.context() as patch:    # as on the tpu backend
+        patch.setattr(attention, "_latent_kernel",
+                      paged_attention.latent_supported)
+        patch.setattr(paged_attention, "paged_decode_latent",
+                      functools.partial(paged_attention.paged_decode_latent,
+                                        interpret=False))
+        patch.setattr(attention, "_prefill_impl", lambda t: "flash")
+        patch.setattr(ray_tpu.ops, "flash_attention", functools.partial(
+            ray_tpu.ops.flash_attention, interpret=False))
+        compiled = jit_forward(row.module(cfg)).lower(
+            _on(params, one_chip), ints(tokens_shape),
+            _on(kv["latent_pages"], one_chip),
+            ints((b, pages_for(4096, 16))), ints(tokens_shape)).compile()
+    decode = tokens_shape[1] == 1
+    assert _device_bytes(compiled) < (10.6e9 if decode else 12.5e9)
+    m = compiled.memory_analysis()
+    pool = kv["latent_pages"]
+    assert m.alias_size_in_bytes == pool.size * pool.dtype.itemsize
+    assert m.temp_size_in_bytes < (0.1e9 if decode else 1.5e9)
+    text = compiled.as_text()
+    assert text.count('op_name="ragged-dot-metadata"') == cfg.n_moe_layers
+
+    def calls(kernel):
+        return len(re.findall(
+            rf"^\s*(?:ROOT )?%{kernel}[\w.]* = .*custom-call\(", text,
+            re.M))
+
+    assert calls("paged_decode_latent") == (7 if decode else 0)
+    assert calls("flash_fwd") == (0 if decode else 7)
+
+
 def _train_step_and_shapes(cfg, loss_chunk):
     from ray_tpu.models.gpt2 import gpt2_init, gpt2_loss_fn
     from ray_tpu.train.train_step import TrainState, make_optimizer
