@@ -25,6 +25,14 @@ from the same table and the same fitting rule as the constraints
 (``parallel/sharding.py logical_spec``), so a head count the ``tensor``
 axis does not divide stays whole there as everywhere else.
 
+``attention_qkv`` is training's entry for a block whose ONE projection
+makes q, k and v (GPT-2's ``c_attn``).  The flash kernels read the three
+where that projection left them, on one device and across a mesh alike:
+there the projection is applied through a view of its weight that puts
+each ``tensor`` shard's own heads' q, k and v columns side by side
+(``models/gpt2.py FusedQKV``), so what crosses the axis for the split
+into heads is that weight, once a layer and pass, and no activation.
+
 ``cfg`` is a GPT2Config, a LlamaConfig or a GraniteConfig: ``attn_impl``,
 ``mesh`` and ``dtype`` are read.  Position encoding is the block's
 business: what comes in is attended as it is (Granite's layers pass q and
@@ -137,24 +145,54 @@ def _attention(cfg, q, k, v, scale=None, impl=None):
                     ("batch", "seq", None, None), q.shape)(q, k, v)
 
 
+def qkv_by_head(cfg) -> bool:
+    """Whether a block whose ONE projection makes q, k and v makes them
+    by shard of the heads for ``attention_qkv``: where the flash kernels
+    run per shard of a mesh."""
+    return cfg.attn_impl == "flash" and cfg.mesh is not None \
+        and cfg.mesh.size > 1
+
+
 def attention_qkv(cfg, qkv, heads: int):
     """Training's ``attention`` for a block whose ONE projection makes q,
-    k and v (GPT-2's ``c_attn``): qkv [B, T, 3*H*D] -> [B, T, H*D], what
-    the output projection reads.  On one device ``attn_impl="flash"``
-    reads q, k and v where they lie in ``qkv``
-    (``ops/flash_attention.py flash_attention_qkv``).  Everything else
-    gets them split into heads, as ``attention`` takes them: the other
-    implementations, and flash across a mesh, where ``c_attn``'s columns
-    lie on the ``tensor`` axis as [q | k | v] and not by head, so the
-    split is the reshard to heads (a collective, as ever), and each
-    shard's kernels then read its own heads' three arrays as they lie."""
-    b, t, _ = qkv.shape
-    if cfg.attn_impl == "flash" and (cfg.mesh is None
-                                     or cfg.mesh.size == 1):
+    k and v (GPT-2's ``c_attn``): -> [B, T, H*D], what the output
+    projection reads.  ``attn_impl="flash"`` reads q, k and v where they
+    lie in ``qkv`` (``ops/flash_attention.py flash_attention_qkv``):
+
+    - on one device ``qkv`` is [B, T, 3*H*D], the projection's output as
+      it stands;
+    - across a mesh (``qkv_by_head``) it is [shards, B, T, 3*Hs*D], Hs =
+      H / shards heads a shard, which the projection made with the
+      shards and the batch where the table puts the heads and the batch
+      (``models/gpt2.py FusedQKV``: the weight went to the heads, no
+      activation does).  Under ``shard_map`` a shard's block, [1, b, T,
+      3*Hs*D], IS the ``qkv`` of its own heads: the same kernel call as
+      on one device, and its [b, T, Hs*D] outputs side by side on
+      ``tensor`` are the rows the output projection's kernel is split
+      by.  A head count the axis does not divide is ONE shard
+      (``logical_shards``) and stays whole on every device, like any
+      other activation the axis does not divide.
+
+    The other implementations get [B, T, 3*H*D] split into heads, as
+    ``attention`` takes them."""
+    assert (qkv.ndim == 4) == qkv_by_head(cfg), qkv.shape
+    b, t = qkv.shape[-3:-1]
+    if cfg.attn_impl == "flash":
         from ..ops import flash_attention_qkv
 
+        flash = functools.partial(flash_attention_qkv, **_FLASH)
         with jax.named_scope("attn.core"):
-            return flash_attention_qkv(qkv, heads, **_FLASH)
+            if qkv.ndim == 3:
+                return flash(qkv, heads)
+            from jax import shard_map
+
+            return shard_map(
+                lambda x: flash(x[0], heads // qkv.shape[0]),
+                mesh=cfg.mesh, check_vma=False,
+                in_specs=logical_spec(
+                    cfg.mesh, ("heads", "batch", None, None), qkv.shape),
+                out_specs=logical_spec(cfg.mesh, ("batch", None, "heads"),
+                                       (b, t, heads)))(qkv)
     with jax.named_scope("attn.qkv"):
         q, k, v = (x.reshape(b, t, heads, -1)
                    for x in jnp.split(qkv, 3, axis=-1))

@@ -7,6 +7,10 @@ TPU-first design notes:
   ray_tpu.parallel.sharding rules (DP/FSDP/TP = table change);
 - attention impl selectable (models/attention.py, the core this block
   shares with llama.py): "dense", "flash", "ring" or "ulysses";
+- ``c_attn`` is stored as ONE [d, 3*H*D] matrix, columns [q | k | v];
+  where the flash kernels run per shard of a mesh it is applied through
+  a view by shard of the heads (``FusedQKV``), so the split into heads
+  moves that weight across the ``tensor`` axis and no activation;
 - jax.checkpoint per block when ``remat`` so long-context activation
   memory trades against recompute;
 - ``jax.named_scope`` names the parts (``embed``, ``attn.qkv``,
@@ -29,8 +33,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..parallel.sharding import logical_shards
 from ..parallel.sharding import with_logical_constraint as _constrain
-from .attention import attention, attention_qkv
+from .attention import attention, attention_qkv, qkv_by_head
 
 
 @dataclass(frozen=True)
@@ -92,6 +97,56 @@ class GPT2Config:
         return 2.0 * matmul_params + attn
 
 
+class FusedQKV(nn.Module):
+    """``c_attn``: the ONE projection that makes q, k and v.  Its
+    parameters are ``nn.Dense``'s, name for name and shape for shape
+    (``kernel`` [d, 3*H*D] with the columns [q | k | v], ``bias``
+    [3*H*D]), so the checkpoints, the partition rules and the optimizer
+    see one tree whichever way it is applied:
+
+    - plainly, as ``nn.Dense`` does it: [B, T, d] -> [B, T, 3*H*D];
+    - ``by_head`` (training's flash path across a mesh,
+      ``attention.qkv_by_head``): through the kernel viewed by shard of
+      the heads, [shards, d, 3 * H/shards * D], a shard's own heads' q,
+      k and v columns side by side and the shards, as many as the
+      table's ``heads`` row cuts H into on this mesh, in front and
+      constrained to that row -> [shards, B, T, 3 * H/shards * D]: each
+      ``tensor`` shard's block is the plain ``qkv`` of its own heads,
+      made by the plain matmul.  The STORED columns lie on the
+      ``tensor`` axis as two halves of [q | k | v]; the view's
+      constraint moves the WEIGHT to the heads (9.8 MB a layer at
+      gpt2-large, bf16, off the activations' path, and its gradient
+      back the same way) and nothing moves after the matmul.  (A view
+      [d, 3, H, D] with an output [B, T, 3, H, D] says the same; the
+      TPU's compiler lays that output out sequence-minor and copies it,
+      PERF.md section 6, PR 40.)"""
+    cfg: GPT2Config
+
+    @nn.compact
+    def __call__(self, x, by_head: bool = False):
+        cfg = self.cfg
+        d, h = cfg.d_model, cfg.n_head
+        kernel = self.param("kernel", nn.initializers.normal(0.02),
+                            (d, 3 * d), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros_init(), (3 * d,),
+                          jnp.float32)
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias,
+                                                  dtype=cfg.dtype)
+        if not by_head:
+            y = jax.lax.dot_general(
+                x, kernel, (((x.ndim - 1,), (0,)), ((), ())))
+            return y + jnp.reshape(bias, (1,) * (y.ndim - 1) + (-1,))
+        # [d, 3, H, D] -> [shards, d, 3 * H/shards * D]: a shard's own
+        # heads' q, k and v columns side by side, the shards in front.
+        n = logical_shards(cfg.mesh, "heads", h)
+        kernel = kernel.reshape(d, 3, n, -1).transpose(2, 0, 1, 3)
+        kernel = _constrain(kernel.reshape(n, d, -1),
+                            ("heads", None, None), cfg.mesh)
+        bias = bias.reshape(3, n, -1).transpose(1, 0, 2).reshape(n, -1)
+        y = jnp.einsum("btd,ndc->nbtc", x, kernel) + bias[:, None, None]
+        return _constrain(y, ("heads", "batch", None, None), cfg.mesh)
+
+
 class Block(nn.Module):
     cfg: GPT2Config
     use_moe: bool = False
@@ -103,10 +158,9 @@ class Block(nn.Module):
         d_head = cfg.d_model // h
         y = nn.LayerNorm(dtype=cfg.dtype, name="ln_1")(x)
         with jax.named_scope("attn.qkv"):
-            qkv = nn.Dense(3 * cfg.d_model, dtype=cfg.dtype,
-                           name="c_attn",
-                           kernel_init=nn.initializers.normal(0.02))(y)
-        b, t = qkv.shape[0], qkv.shape[1]
+            qkv = FusedQKV(cfg, name="c_attn")(
+                y, by_head=cache is None and qkv_by_head(cfg))
+        b, t = x.shape[0], x.shape[1]
         if cache is None:
             # Training: q, k, v stay where c_attn left them and the
             # output comes as c_proj reads it (attention_qkv).

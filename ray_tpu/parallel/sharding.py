@@ -72,6 +72,20 @@ def logical_spec(mesh, logical: LogicalAxes,
     return ShardingRules().fitted_spec(mesh, logical, shape)
 
 
+def logical_shards(mesh, name: str, size: int) -> int:
+    """Into how many shards the default table cuts a dimension of
+    ``size`` that carries this logical name on this mesh (1: it stays
+    whole, as where no axis of its row divides it)."""
+    import math
+
+    spec = logical_spec(mesh, (name,), (size,))
+    axes = spec[0] if len(spec) else None      # pruned: no entry left
+    if axes is None:
+        return 1
+    return math.prod(mesh.shape[a] for a in
+                     ((axes,) if isinstance(axes, str) else axes))
+
+
 def logical_sharding(mesh, logical: LogicalAxes,
                      shape: Optional[Sequence[int]] = None):
     """NamedSharding for an array whose dims carry these logical names."""

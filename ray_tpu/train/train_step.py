@@ -182,15 +182,20 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
                 Gauge("rt_train_compile_seconds",
                       "Host-side duration of the first (tracing + "
                       "XLA compile) step invocation.").set(dt)
-                xprof.register_compiled("train_step", aot[0],
-                                        mesh_axes=mesh_axes,
-                                        compile_seconds=dt)
+                info = xprof.register_compiled("train_step", aot[0],
+                                               mesh_axes=mesh_axes,
+                                               compile_seconds=dt)
+                timed_step.collective_counts = \
+                    (info or {}).get("collective_counts")
             except Exception:
                 pass    # registering with xprof is best-effort
         return out
 
-    # The executable every call runs (None before the first call), and
-    # what its compile took: callers check the program that ran.
+    # The executable every call runs (None before the first call), what
+    # its compile took and the collectives it holds by kind ({"all-reduce":
+    # n, ..., "collective-permute": n}, as xprof's "train_step" row has
+    # them): callers check the program that ran.
     timed_step.compiled = lambda: aot[0]
     timed_step.compile_seconds = None
+    timed_step.collective_counts = None
     return timed_step

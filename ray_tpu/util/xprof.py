@@ -6,7 +6,8 @@ Any jitted step can be lowered and AOT-compiled (``fn.lower(*args)
 the program XLA actually runs: ``cost_analysis()`` flops and bytes
 accessed, ``memory_analysis()`` argument/output/temp sizes, and the
 post-SPMD optimized HLO text whose collective ops (all-reduce /
-all-gather / reduce-scatter / all-to-all) name their replica groups.
+all-gather / reduce-scatter / all-to-all) name their replica groups
+(a collective-permute names pairs of devices: counted by kind only).
 This module harvests those numbers (``register_compiled``), attributes
 each collective to the mesh axes its replica groups span, and combines
 the static program facts with measured step time into a roofline
@@ -81,7 +82,7 @@ def roofline(flops: float, bytes_accessed: float, peak_flops: float,
 # HLO collective parsing.
 
 COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter",
-                  "all-to-all")
+                  "all-to-all", "collective-permute")
 
 _DTYPE_BYTES = {
     "pred": 1, "s2": 1, "u2": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -97,7 +98,7 @@ _DTYPE_BYTES = {
 _INSTR_RE = re.compile(
     r"=\s*(?P<type>\((?:[^()]|\([^()]*\))*\)"
     r"|[a-z][a-z0-9]*\[[0-9,]*\](?:\{[^}]*\})?)\s*"
-    r"(?P<op>all-reduce|all-gather|reduce-scatter|all-to-all)"
+    r"(?P<op>" + "|".join(COLLECTIVE_OPS) + r")"
     r"(?P<suffix>-start|-done)?(?:\.\d+)?\(")
 
 # replica_groups: explicit `{{0,1},{2,3}}` or iota-v2
@@ -257,14 +258,28 @@ def collective_wire_bytes(op: str, result_bytes: float,
     return result_bytes * (g - 1) / g   # all-gather / all-to-all
 
 
+def count_collectives(collectives: List[Dict[str, Any]]
+                      ) -> Dict[str, int]:
+    """How many collectives of each kind a program holds, every kind of
+    ``COLLECTIVE_OPS`` present (0 where it has none): a layout that
+    makes the partitioner reshard an activation shows here as
+    all-to-alls or collective-permutes that were not there before."""
+    return {op: sum(c["op"] == op for c in collectives)
+            for op in COLLECTIVE_OPS}
+
+
 def summarize_collectives(collectives: List[Dict[str, Any]],
                           axis_sizes: Optional[Dict[str, int]]
                           ) -> Dict[str, Dict[str, Any]]:
     """Aggregate parsed collectives into per-mesh-axis wire bytes:
-    {axis: {"bytes", "ops", "by_op": {op: bytes}}}."""
+    {axis: {"bytes", "ops", "by_op": {op: bytes}}}.  A
+    collective-permute names pairs of devices, not replica groups, and
+    is left out (``count_collectives`` counts it)."""
     out: Dict[str, Dict[str, Any]] = {}
     world = _prod(axis_sizes.values()) if axis_sizes else 0
     for c in collectives:
+        if c["op"] == "collective-permute":
+            continue
         groups = c.get("groups") or []
         if not groups and world:
             # Empty replica_groups means one group of every device.
@@ -501,7 +516,7 @@ def harvest_compiled(compiled: Any,
     Each probe degrades independently (a backend without
     cost_analysis still yields the collectives)."""
     info: Dict[str, Any] = {"flops": 0.0, "bytes": 0.0, "memory": {},
-                            "collectives": {},
+                            "collectives": {}, "collective_counts": {},
                             "device_kind": chips.local_device_kind()}
     try:
         cost = compiled.cost_analysis()
@@ -530,6 +545,7 @@ def harvest_compiled(compiled: Any,
         pass
     try:
         colls = parse_hlo_collectives(compiled.as_text())
+        info["collective_counts"] = count_collectives(colls)
         info["collectives"] = summarize_collectives(colls, mesh_axes)
     except Exception:
         pass
@@ -590,6 +606,11 @@ def _publish_program(name: str, info: Dict[str, Any]) -> None:
     for axis, a in (info.get("collectives") or {}).items():
         for op, b in (a.get("by_op") or {}).items():
             coll_g.set(b, tags={"fn": name, "axis": axis, "op": op})
+    count_g = Gauge("rt_xla_collective_ops",
+                    "Collectives of each kind in the registered program "
+                    "(an async pair once).", tag_keys=("fn", "op"))
+    for op, n in (info.get("collective_counts") or {}).items():
+        count_g.set(n, tags={"fn": name, "op": op})
     Counter("rt_xla_compiles_total",
             "XLA compile events per registered function.",
             tag_keys=("fn",)).inc(tags=tags)

@@ -169,8 +169,9 @@ def test_fused_path_under_shard_map_splits_the_heads(schedules, h,
     unsharded one."""
     from jax.sharding import Mesh
 
-    from ray_tpu.models.attention import attention_qkv
+    from ray_tpu.models.attention import attention_qkv, qkv_by_head
     from ray_tpu.models.gpt2 import GPT2Config
+    from ray_tpu.parallel.sharding import logical_shards
 
     d, b, t = 64, 2, 256
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
@@ -180,12 +181,25 @@ def test_fused_path_under_shard_map_splits_the_heads(schedules, h,
     qkv = jax.random.normal(jax.random.PRNGKey(3), (b, t, 3 * h * d))
     w = jax.random.normal(jax.random.PRNGKey(4), (b, t, h * d))
 
+    sharded = dataclasses.replace(cfg, mesh=mesh)
+
+    def as_made(cfg, qkv):
+        """``qkv`` as GPT-2's ``c_attn`` hands it over: as it stands on
+        one device, by shard of the heads across a mesh ([shards, B, T,
+        3 * H/shards * D]: ``models/gpt2.py FusedQKV``)."""
+        if not qkv_by_head(cfg):
+            return qkv
+        n = logical_shards(cfg.mesh, "heads", h)
+        assert n == 2
+        return qkv.reshape(b, t, 3, n, -1).transpose(3, 0, 1, 2, 4) \
+            .reshape(n, b, t, -1)
+
     def loss(cfg):
-        return lambda qkv: jnp.sum(attention_qkv(cfg, qkv, h) * w)
+        return lambda qkv: jnp.sum(
+            attention_qkv(cfg, as_made(cfg, qkv), h) * w)
 
     want, dwant = jax.value_and_grad(loss(cfg))(qkv)
     del schedules[:]
-    sharded = dataclasses.replace(cfg, mesh=mesh)
     got, dgot = jax.jit(jax.value_and_grad(loss(sharded)))(qkv)
     assert {(tags["layout"], tags["heads_per_program"])
             for _, tags in schedules} == {
@@ -193,5 +207,5 @@ def test_fused_path_under_shard_map_splits_the_heads(schedules, h,
     np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
     _close(dgot, dwant, 2e-5)
     q, k, v = (x.reshape(b, t, h, d) for x in jnp.split(qkv, 3, axis=-1))
-    _close(attention_qkv(sharded, qkv, h),
+    _close(attention_qkv(sharded, as_made(sharded, qkv), h),
            _dense(q, k, v, True)[0].reshape(b, t, -1), 2e-5)

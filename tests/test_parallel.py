@@ -3,6 +3,7 @@ rules, ring attention vs dense reference (values AND gradients), Ulysses,
 pipeline parallelism vs sequential execution."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -324,3 +325,185 @@ def test_sharded_flash_step_with_heads_the_tensor_axis_does_not_divide():
             state, {"tokens": jax.device_put(tokens, batch_sharding)})
         losses[name] = float(metrics["loss"])
     np.testing.assert_allclose(losses["four"], losses["one"], rtol=1e-5)
+
+
+# ---------------------------------------------------------------------
+# c_attn across a mesh (PR 40): the projection's WEIGHT goes to the heads
+# and its output is born on the ``tensor`` axis by head; no activation
+# crosses the axis for the split into q, k and v.
+
+def _qkv_cfg(heads, **kw):
+    from ray_tpu.models.gpt2 import GPT2Config
+
+    # T = 128 is no weight's dimension here (64, 192 | 256, 3x, 320, 512),
+    # so an operand that has it is an activation.
+    return GPT2Config(vocab_size=320, n_layer=2, n_head=heads,
+                      d_model=64 * heads, d_ff=512, max_seq=128,
+                      attn_impl="flash", dtype=jnp.float32, **kw)
+
+
+def _placed(params, mesh):
+    """``params`` where the GPT-2 rules, fitted to ``mesh``, put them,
+    and those specs."""
+    from ray_tpu.parallel.partition_rules import tree_shardings
+    from ray_tpu.train import distributed as dist
+    from ray_tpu.train.train_step import TrainState, make_optimizer
+
+    optimizer = make_optimizer(total_steps=10, warmup_steps=2)
+    state = jax.eval_shape(lambda p: TrainState.create(p, optimizer), params)
+    specs = dist.fitted_state_specs(state, mesh,
+                                    dist.rules_for_model("gpt2")).params
+    return jax.device_put(params, tree_shardings(mesh, specs)), specs
+
+
+_BLOCK_COLLECTIVE = re.compile(
+    r"= (?P<type>\([^=]*?\)|\S+) (?P<op>all-to-all|collective-permute)"
+    r"(?:-start)?\(.*op_name=\"[^\"]*/h_\d+/")
+
+
+def _moved_in_blocks(text, t):
+    """(opcode, result type) of every all-to-all and collective-permute
+    under a block ``h_<i>`` of a compiled step, and those among them
+    whose operand has the sequence dimension: activations."""
+    found = [(m.group("op"), m.group("type"))
+             for m in map(_BLOCK_COLLECTIVE.search, text.splitlines()) if m]
+    return found, [f for f in found
+                   if re.search(rf"[\[,]{t}[,\]]", f[1])]
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_sharded_step_moves_no_activation_for_the_qkv_split(remat):
+    """fsdp=2 x tensor=2, 4 heads of 64: the compiled training step holds
+    no all-to-all under a block and no collective-permute of an
+    activation there (the parent's held two permutes of [B, T, H*D] a
+    layer and pass under ``attn.qkv/split``)."""
+    from ray_tpu.train.train_step import make_optimizer
+
+    mesh = gang_mesh({"fsdp": 2, "tensor": 2}, jax.devices()[:4])
+    cfg = _qkv_cfg(4, remat=remat, mesh=mesh)
+    state, step, batch_sharding = sharded_step(
+        cfg, mesh, make_optimizer(total_steps=10, warmup_steps=2))
+    tokens = jax.device_put(jnp.zeros((4, cfg.max_seq + 1), jnp.int32),
+                            batch_sharding)
+    _, metrics = step(state, {"tokens": tokens})
+    assert np.isfinite(float(metrics["loss"]))
+    text = step.compiled().as_text()
+    assert "all-reduce" in text             # it IS the sharded program
+    found, activations = _moved_in_blocks(text, cfg.max_seq)
+    assert not activations, activations
+    assert not [f for f in found if f[0] == "all-to-all"], found
+    # The scan sees what it is there to see: an activation's permute.
+    sample = ('%cp = (f32[2,128,256]{2,1,0}, u32[]) collective-permute-start('
+              '%x), metadata={op_name="jit(step)/h_0/attn.qkv/split"}')
+    assert _moved_in_blocks(sample, 128)[1]
+
+
+@pytest.mark.parametrize("heads", [4, 3], ids=["heads4", "heads3"])
+@pytest.mark.parametrize("tensor", [1, 2], ids=["tensor1", "tensor2"])
+def test_sharded_qkv_gives_the_unsharded_loss_and_gradients(tensor, heads):
+    """Loss and every gradient leaf across fsdp=2 x tensor are the
+    ``mesh=None`` program's to float32 tolerance: heads the axis divides
+    (each shard's own), and three heads over tensor=2, which stay whole
+    on every shard."""
+    import dataclasses
+
+    from ray_tpu.models.gpt2 import gpt2_init, gpt2_loss_fn
+    from ray_tpu.parallel.sharding import logical_shards
+
+    mesh = gang_mesh({"fsdp": 2, "tensor": tensor},
+                     jax.devices()[:2 * tensor])
+    plain = _qkv_cfg(heads)
+    cfg = dataclasses.replace(plain, mesh=mesh)
+    assert logical_shards(mesh, "heads", heads) == \
+        (tensor if heads % tensor == 0 else 1)
+    params = gpt2_init(plain, jax.random.PRNGKey(0))
+    # c_attn's bias is zero at init: give it values, so that the bias's
+    # view is held to the plain one too.
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: x + 0.1 * jax.random.normal(
+            jax.random.PRNGKey(7), x.shape, x.dtype)
+        if "c_attn" in jax.tree_util.keystr(path) else x, params)
+    batch = {"tokens": jax.random.randint(
+        jax.random.PRNGKey(1), (4, plain.max_seq + 1), 0, plain.vocab_size,
+        jnp.int32)}
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda p: gpt2_loss_fn(plain, p, batch, loss_chunk=0)))(params)
+    from ray_tpu.train import distributed as dist
+
+    placed, _ = _placed(params, mesh)
+    got_loss, got = jax.jit(jax.value_and_grad(
+        lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=0)))(
+            placed, jax.device_put(batch, dist.batch_sharding(mesh)))
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-5)
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want))
+    for path, g in jax.tree_util.tree_leaves_with_path(got):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(flat_want[path]), rtol=2e-4, atol=2e-6,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_c_attn_is_stored_as_dense_stores_it_and_restores_its_checkpoint(
+        tmp_path):
+    """The stored tree is the one ``nn.Dense`` made (names, shapes,
+    dtypes, the very values a seed draws), the GPT-2 rules fit it as
+    before, and a sharded checkpoint of such a tree restores into this
+    one bit for bit and computes the unsharded loss."""
+    import dataclasses
+
+    import flax.linen as nn
+
+    from ray_tpu.models.gpt2 import gpt2_init, gpt2_loss_fn
+    from ray_tpu.train import distributed as dist
+    from ray_tpu.train.sharded_checkpoint import (load_sharded,
+                                                  read_manifest,
+                                                  save_sharded)
+
+    class ParentBlock(nn.Module):       # c_attn as nn.Dense, under h_0
+        @nn.compact
+        def __call__(self, x):
+            return nn.Dense(3 * x.shape[-1], name="c_attn",
+                            kernel_init=nn.initializers.normal(0.02))(x)
+
+    class ParentModel(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return ParentBlock(name="h_0")(x)
+
+    plain = _qkv_cfg(4)
+    d, key = plain.d_model, jax.random.PRNGKey(3)
+    params = gpt2_init(plain, key)
+    ours = params["params"]["h_0"]["c_attn"]
+    dense = ParentModel().init(key, jnp.zeros((1, 8, d)))[
+        "params"]["h_0"]["c_attn"]
+    assert {k: (v.shape, v.dtype) for k, v in ours.items()} == \
+        {k: (v.shape, v.dtype) for k, v in dense.items()} == \
+        {"kernel": ((d, 3 * d), jnp.float32), "bias": ((3 * d,), jnp.float32)}
+    for k in dense:
+        assert np.array_equal(np.asarray(ours[k]), np.asarray(dense[k])), k
+
+    mesh = gang_mesh({"fsdp": 2, "tensor": 2}, jax.devices()[:4])
+    placed, specs = _placed(params, mesh)
+    assert specs["params"]["h_0"]["c_attn"] == \
+        {"kernel": P("fsdp", "tensor"), "bias": P()}
+    assert specs["params"]["h_0"]["c_proj"]["kernel"] == P("tensor", "fsdp")
+
+    path = str(tmp_path / "checkpoint_000001")
+    assert save_sharded(path, placed)["committed"]
+    kernel = read_manifest(path)["leaves"]["params/h_0/c_attn/kernel"]
+    assert (kernel["shape"], kernel["spec"]) == \
+        ([d, 3 * d], ["fsdp", "tensor"])
+    restored = load_sharded(path, mesh=mesh, specs=specs,
+                            target=jax.eval_shape(lambda: params))
+    for (name, a), (_, b) in zip(
+            jax.tree_util.tree_leaves_with_path(restored),
+            jax.tree_util.tree_leaves_with_path(params)):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    batch = {"tokens": jax.random.randint(
+        jax.random.PRNGKey(1), (4, plain.max_seq + 1), 0, plain.vocab_size,
+        jnp.int32)}
+    cfg = dataclasses.replace(plain, mesh=mesh)
+    got = jax.jit(lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=0))(
+        restored, jax.device_put(batch, dist.batch_sharding(mesh)))
+    want = jax.jit(lambda p: gpt2_loss_fn(plain, p, batch, loss_chunk=0))(
+        params)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)

@@ -147,6 +147,33 @@ def test_parse_hlo_collectives_counts_definitions_not_references():
     assert colls[2]["bytes"] == pytest.approx(2 * 4 * 4)
 
 
+_TPU_STEP_LINES = """
+  %all-to-all.3 = bf16[1,2,8,1024,640]{3,4,1,2,0:T(8,128)(2,1)} all-to-all(bf16[1,2,8,1024,640]{3,4,1,2,0:T(8,128)(2,1)} %copy.1), channel_id=9, replica_groups={{0,1},{2,3}}, dimensions={1}
+  %collective-permute-start.2 = (bf16[16,1024,1280]{2,1,0:T(8,128)(2,1)}, bf16[16,1024,1280]{2,1,0:T(8,128)(2,1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(bf16[16,1024,1280]{2,1,0:T(8,128)(2,1)} %slice.4), channel_id=7, source_target_pairs={{0,1},{1,0},{2,3},{3,2}}
+  %collective-permute-done.2 = bf16[16,1024,1280]{2,1,0:T(8,128)(2,1)} collective-permute-done(%collective-permute-start.2)
+  %collective-permute.5 = s32[16,1024,1]{2,1,0} collective-permute(s32[16,1024,1]{2,1,0} %p), source_target_pairs={{0,2},{2,0}}
+  %all-reduce.17 = f32[8,4]{1,0} all-reduce(f32[8,4]{1,0} %p0), replica_groups={{0,1},{2,3}}, to_apply=%add
+  %fusion.9 = bf16[16,1024,1280]{2,1,0} fusion(%collective-permute-done.2, %all-to-all.3), kind=kLoop
+"""
+
+
+@pytest.mark.parametrize("hlo,want", [
+    (_TPU_STEP_LINES, {"all-reduce": 1, "all-gather": 0,
+                       "reduce-scatter": 0, "all-to-all": 1,
+                       "collective-permute": 2}),
+    ("", dict.fromkeys(xprof.COLLECTIVE_OPS, 0)),
+], ids=["resharded_activation", "none"])
+def test_count_collectives_by_kind(hlo, want):
+    """The per-compile counter of the step layer: every kind present, an
+    async pair once, a consumer of a collective not at all; the permutes
+    are counted and stay out of the per-axis wire bytes."""
+    colls = xprof.parse_hlo_collectives(hlo)
+    assert xprof.count_collectives(colls) == want
+    axes = xprof.summarize_collectives(colls, {"fsdp": 2, "tensor": 2})
+    assert sum(a["ops"] for a in axes.values()) == \
+        want["all-reduce"] + want["all-to-all"]
+
+
 # -------------------------------------- axis attribution
 def test_attribute_axes_on_fsdp_tensor_mesh():
     sizes = {"fsdp": 2, "tensor": 2}
@@ -470,11 +497,17 @@ def test_sharded_step_registers_collectives_on_both_axes():
         assert fsdp_b > 0, f"no fsdp-axis bytes: {{colls}}"
         assert tensor_b > 0, f"no tensor-axis bytes: {{colls}}"
         assert prog["flops"] > 0
+        # The executable's collectives by kind, once per compile, where
+        # the step keeps what it knows of the program that ran.
+        counts = prog["collective_counts"]
+        assert set(counts) == set(xprof.COLLECTIVE_OPS), counts
+        assert counts["all-reduce"] > 0 and counts["all-gather"] > 0
+        assert step.collective_counts == counts
 
         # ...and the facts went out as rt_xla_* gauges.
         names = {{s["name"] for s in registry().snapshot()}}
         for need in ("rt_xla_cost_flops", "rt_xla_collective_bytes",
-                     "rt_xla_compiles_total"):
+                     "rt_xla_collective_ops", "rt_xla_compiles_total"):
             assert need in names, names
         print("AXES_OK", json.dumps(
             {{"fsdp": fsdp_b, "tensor": tensor_b}}))
