@@ -64,6 +64,19 @@ lanes, for the values; ``latent_attend`` here is its plain definition);
 a prefill starts at position 0 and needs nothing from the pool
 (``models/attention.py latent_attention``).
 
+The two kinds of pool are independent, and a model may have BOTH
+(``models/kimi_linear.py``: latent attention in 7 layers, delta-rule
+mixers in 20): its spec has ``latent_dim`` > 0, so the paged pool is the
+one array ``latent_pages`` over the ``kv_layers`` latent layers, AND
+``state_layers`` > 0, so there is a state pool of ``conv`` (the three
+convolutions' windows, [state layers, slots, 3, 3 H d_k] in the model's
+dtype) and ``ssm`` (each head's ``d_k x d_v`` matrix, [state layers,
+slots, H, d_k, d_v] float32: 2 MB a slot a layer at 32 heads of 128).  A
+layer indexes its pool by its number among its own kind; the forward
+takes ``latent_pages``, the page table and the positions, then ``conv``,
+``ssm`` and the slots, and returns the three arrays in that order, all
+donated and aliased (``llm/engine.py jit_forward``).
+
 ``PagePool`` is the host-side allocator; it exports
 ``rt_llm_kv_pages_{used,total}`` gauges on every alloc/free so KV
 occupancy is visible in ``rt telemetry`` and the doctor can see leaks.

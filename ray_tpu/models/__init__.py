@@ -1,6 +1,6 @@
 """ray_tpu.models — TPU-first reference model families.
 
-Six families run through both the trainer and the serving engine:
+Seven families run through both the trainer and the serving engine:
 GPT-2 (pretrain baseline, BASELINE.json headline metric), Llama
 (RoPE/GQA/SwiGLU), OLMoE (the Llama block with QK-norm and dropless
 top-k sparse experts, ops/moe.py), Granite 4.0-H (``granitemoehybrid``:
@@ -14,7 +14,11 @@ Kimi-K2 (``kimik2``: multi-head LATENT attention whose cache row is one
 compressed vector and a rotary part shared by all heads, absorbed for a
 decode step and expanded for a prefill, YaRN's rotary frequencies, a
 dense layer ahead of a shared expert beside routed ones,
-models/kimi.py).  What more than one block is built from (RMSNorm, RoPE
+models/kimi.py) and Kimi-Linear (``kimilinear``: Kimi Delta Attention, a
+gated delta rule with a decay per key channel whose state is a matrix a
+head, three layers in four, and Kimi-K2's latent attention with no
+position encoding in the fourth, over Kimi-K2's FFN,
+models/kimi_linear.py).  What more than one block is built from (RMSNorm, RoPE
 with or without YaRN, the conv over a slot's window) is in
 models/layers.py.  All models are flax.linen with
 *logical* dimension names threaded through ray_tpu.parallel.sharding
@@ -23,7 +27,7 @@ edit.
 
 ``MODEL_FAMILIES`` is the one table the engine (``llm/engine.py``) and
 the multi-host training plane (``train.distributed.rules_for_model``)
-resolve a family through.  A seventh family is a row here:
+resolve a family through.  An eighth family is a row here:
 its config class, module, init, loss, partition rules, a tiny preset for
 tests, and its cache spec (the module's ``__call__`` takes ``kv_cache=``
 / ``positions=`` as GPT2's does, llm/kv_cache.py).  The cache spec
@@ -38,11 +42,16 @@ alone, ``ssm_shape == ()``).  A family with latent attention keeps NO
 K/V: its ``kv_layers`` hold ONE row a position in a single pool,
 ``latent_dim`` + ``rope_dim`` numbers padded to whole 128-lane tiles
 (``row_width``; Kimi-K2: 512 + 64 -> 640), and ``kv_heads`` /
-``head_dim`` are 0.  The engine builds both pools from the spec
+``head_dim`` are 0.  The two kinds of pool are independent: Kimi-Linear's
+spec has latent rows for its 7 latent layers (``latent_dim`` > 0) AND a
+slot for its 20 recurrent ones (``state_layers`` > 0: the three
+convolutions' window and a float32 ``[heads, d_k, d_v]`` state), and a
+layer indexes its pool by its number among its own kind.  The engine
+builds both pools from the spec
 (``llm/kv_cache.py init_pool`` / ``init_state``), and of each the
 arrays the spec has and nothing else.
 Keys are normalized lowercase-no-separator ("gpt2", "llama", "olmoe",
-"granitemoehybrid", "lfm2moe", "kimik2").
+"granitemoehybrid", "lfm2moe", "kimik2", "kimilinear").
 """
 
 from dataclasses import dataclass
@@ -55,6 +64,9 @@ from .gpt2 import (GPT2, GPT2Config, gpt2_init, gpt2_loss_fn,  # noqa: F401
                    gpt2_partition_rules)
 from .kimi import (KimiK2, KimiK2Config, kimi_k2_init,  # noqa: F401
                    kimi_k2_loss_fn, kimi_k2_partition_rules)
+from .kimi_linear import (KimiLinear, KimiLinearConfig,  # noqa: F401
+                          kimi_linear_init, kimi_linear_loss_fn,
+                          kimi_linear_partition_rules)
 from .lfm2 import (Lfm2, Lfm2Config, lfm2_init,  # noqa: F401
                    lfm2_loss_fn, lfm2_partition_rules)
 from .llama import (Llama, LlamaConfig, llama_init,  # noqa: F401
@@ -103,6 +115,14 @@ def _kimi_k2_cache(cfg: KimiK2Config) -> CacheSpec:
                      rope_dim=cfg.qk_rope_head_dim)
 
 
+def _kimi_linear_cache(cfg: KimiLinearConfig) -> CacheSpec:
+    return CacheSpec(
+        cfg.layers_of("mla"), 0, 0, cfg.layers_of("kda"),
+        (cfg.kda_conv - 1, 3 * cfg.kda_dim),
+        (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim),
+        latent_dim=cfg.kv_lora_rank, rope_dim=cfg.qk_rope_head_dim)
+
+
 @dataclass(frozen=True)
 class ModelFamily:
     config: type                       # its config dataclass
@@ -133,6 +153,10 @@ MODEL_FAMILIES = {
     "kimik2": ModelFamily(KimiK2Config, KimiK2, kimi_k2_init,
                           kimi_k2_loss_fn, kimi_k2_partition_rules,
                           KimiK2Config.tiny, _kimi_k2_cache),
+    "kimilinear": ModelFamily(
+        KimiLinearConfig, KimiLinear, kimi_linear_init,
+        kimi_linear_loss_fn, kimi_linear_partition_rules,
+        KimiLinearConfig.tiny, _kimi_linear_cache),
 }
 
 

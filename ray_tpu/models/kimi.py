@@ -41,6 +41,12 @@ weighs them by their scores over the chosen ones' sum, times
 may hold a share of its experts (``first_expert``, ``held_experts``:
 ``ops/moe.py``); the shared expert is whole on every chip.
 
+``MLAttention`` has two options that ``models/kimi_linear.py``'s latent
+layers take: ``q_lora_rank=None`` (ONE matrix ``wq`` makes the queries: no
+``wq_a``, norm or ``wq_b``) and ``mla_use_nope`` (NO rotation of ``q_pe`` and
+``k_pe``, and ``s = (d_n + d_r) ** -0.5`` alone); the FFN of a block is
+``block_ffn``, which both families' blocks call.
+
 With a cache the contract is the other families' with ONE pool:
 ``latent_pages`` [layers, pages, page, row], carried whole through the
 layers; a position < 0 is padding.
@@ -75,7 +81,9 @@ class KimiK2Config:
     n_layer: int = 61
     d_model: int = 7168
     n_head: int = 64
-    q_lora_rank: int = 1536
+    # None: ONE matrix ``wq`` makes the queries, no rank and no norm
+    # between (Kimi-Linear's MLA layers, models/kimi_linear.py).
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -98,6 +106,9 @@ class KimiK2Config:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 1.0
+    # True: NO position encoding; the 64-wide shared part is attended as
+    # it is projected and the scale is ``(d_n + d_r) ** -0.5`` alone.
+    mla_use_nope: bool = False
     max_seq: int = 262144
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
@@ -146,19 +157,16 @@ class KimiK2Config:
     @property
     def softmax_scale(self) -> float:
         """``(d_n + d_r) ** -0.5 * mscale ** 2``: YaRN's temperature is
-        in the scale, both sides of the product."""
+        in the scale, both sides of the product (no rotation: none)."""
+        if self.mla_use_nope:
+            return self.qk_head_dim ** -0.5
         return self.qk_head_dim ** -0.5 * yarn_mscale(
             self.rope_factor, self.rope_mscale_all_dim) ** 2
 
     def attention_params(self) -> int:
-        """One layer's five attention matrices, in parameters."""
-        h = self.n_head
-        return self.d_model * self.q_lora_rank \
-            + self.q_lora_rank * h * self.qk_head_dim \
-            + self.d_model * (self.kv_lora_rank + self.qk_rope_head_dim) \
-            + self.kv_lora_rank * h * (self.qk_nope_head_dim
-                                       + self.v_head_dim) \
-            + h * self.v_head_dim * self.d_model
+        """One layer's five attention matrices (four where
+        ``q_lora_rank`` is None), in parameters."""
+        return mla_params(self)
 
     def flops_per_token(self) -> float:
         """Training FLOPs a token: 6 x the matmul parameters a token
@@ -174,8 +182,22 @@ class KimiK2Config:
         return 6.0 * n
 
 
+def mla_params(cfg) -> int:
+    """One latent-attention layer's matrices, in parameters (``cfg``:
+    whatever ``MLAttention`` takes)."""
+    h = cfg.n_head
+    wq = cfg.d_model * h * cfg.qk_head_dim if cfg.q_lora_rank is None \
+        else cfg.d_model * cfg.q_lora_rank \
+        + cfg.q_lora_rank * h * cfg.qk_head_dim
+    return wq + cfg.d_model * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) \
+        + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim) \
+        + h * cfg.v_head_dim * cfg.d_model
+
+
 class MLAttention(nn.Module):
-    cfg: KimiK2Config
+    """``cfg``: a KimiK2Config, or any config with its attention's names
+    (``models/kimi_linear.py``'s: ``q_lora_rank`` None, ``mla_use_nope``)."""
+    cfg: Any
 
     @nn.compact
     def __call__(self, u, cache=None):
@@ -189,18 +211,23 @@ class MLAttention(nn.Module):
         dense = functools.partial(nn.Dense, use_bias=False,
                                   dtype=cfg.dtype, kernel_init=init)
         with jax.named_scope("mla.q"):
-            c_q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(
-                dense(cfg.q_lora_rank, name="wq_a")(u))
-            q = dense(h * (d_n + d_r), name="wq_b")(c_q) \
-                .reshape(b, t, h, d_n + d_r)
+            if cfg.q_lora_rank is None:
+                q = dense(h * (d_n + d_r), name="wq")(u)
+            else:
+                c_q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(
+                    dense(cfg.q_lora_rank, name="wq_a")(u))
+                q = dense(h * (d_n + d_r), name="wq_b")(c_q)
+            q = q.reshape(b, t, h, d_n + d_r)
             q_nope, q_pe = q[..., :d_n], q[..., d_n:]
         with jax.named_scope("mla.kv"):
             ckv = dense(r_kv + d_r, name="wkv_a")(u)
             c_kv = RMSNorm(cfg.rms_eps, cfg.dtype, name="kv_norm")(
                 ckv[..., :r_kv])
-            k_pe = _rope(ckv[..., None, r_kv:], cfg.rope_theta, positions,
-                         cfg.yarn)[:, :, 0]
-            q_pe = _rope(q_pe, cfg.rope_theta, positions, cfg.yarn)
+            k_pe = ckv[..., r_kv:]
+            if not cfg.mla_use_nope:
+                k_pe = _rope(k_pe[:, :, None], cfg.rope_theta, positions,
+                             cfg.yarn)[:, :, 0]
+                q_pe = _rope(q_pe, cfg.rope_theta, positions, cfg.yarn)
         w_kvb = self.param("wkv_b", init, (r_kv, h * (d_n + d_v)),
                            jnp.float32).astype(cfg.dtype)
         att, pages = latent_attention(
@@ -221,6 +248,36 @@ def _swiglu(cfg, y, width: int, names):
                     kernel_init=init, name=names[2])(z)
 
 
+def block_ffn(cfg, x, y, dense: bool, positions=None):
+    """``x + ffn(y)`` inside the calling block (the submodules are the
+    caller's): a dense SwiGLU of ``d_ff``, or the routed experts with the
+    shared one beside them.  ``positions`` [B, T] (< 0: padding, kept from
+    the experts) or None."""
+    with jax.named_scope("mlp"):
+        if dense:
+            with jax.named_scope("mlp.dense"):
+                down = _swiglu(cfg, y, cfg.d_ff,
+                               ("w_gate", "w_up", "w_down"))
+        else:
+            from ..ops.moe import MoEMLP
+
+            down = MoEMLP(
+                d_model=cfg.d_model, d_ff=cfg.moe_d_ff,
+                num_experts=cfg.n_experts, top_k=cfg.experts_per_token,
+                gated=True, norm_topk_prob=True, scoring="sigmoid",
+                select_bias=True, norm_eps=ROUTE_NORM_EPS,
+                routed_scaling_factor=cfg.routed_scaling_factor,
+                act=nn.silu, dtype=cfg.dtype,
+                first_expert=cfg.first_expert,
+                held_experts=cfg.held_experts, name="moe")(
+                    y, None if positions is None else positions >= 0)
+            with jax.named_scope("moe.shared"):
+                down = down + _swiglu(
+                    cfg, y, cfg.moe_d_ff * cfg.n_shared_experts,
+                    ("shared_gate", "shared_up", "shared_down"))
+        return x + down.astype(x.dtype)
+
+
 class KimiK2Block(nn.Module):
     cfg: KimiK2Config
     dense: bool
@@ -233,30 +290,8 @@ class KimiK2Block(nn.Module):
         m, pages = MLAttention(cfg, name="attn")(y, cache)
         x = x + m.astype(x.dtype)
         y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
-        positions = cache["positions"] if cache is not None else None
-        with jax.named_scope("mlp"):
-            if self.dense:
-                with jax.named_scope("mlp.dense"):
-                    down = _swiglu(cfg, y, cfg.d_ff,
-                                   ("w_gate", "w_up", "w_down"))
-            else:
-                from ..ops.moe import MoEMLP
-
-                down = MoEMLP(
-                    d_model=cfg.d_model, d_ff=cfg.moe_d_ff,
-                    num_experts=cfg.n_experts, top_k=cfg.experts_per_token,
-                    gated=True, norm_topk_prob=True, scoring="sigmoid",
-                    select_bias=True, norm_eps=ROUTE_NORM_EPS,
-                    routed_scaling_factor=cfg.routed_scaling_factor,
-                    act=nn.silu, dtype=cfg.dtype,
-                    first_expert=cfg.first_expert,
-                    held_experts=cfg.held_experts, name="moe")(
-                        y, None if positions is None else positions >= 0)
-                with jax.named_scope("moe.shared"):
-                    down = down + _swiglu(
-                        cfg, y, cfg.moe_d_ff * cfg.n_shared_experts,
-                        ("shared_gate", "shared_up", "shared_down"))
-            x = x + down.astype(x.dtype)
+        x = block_ffn(cfg, x, y, self.dense,
+                      cache["positions"] if cache is not None else None)
         return x if cache is None else (x, pages)
 
 

@@ -1,0 +1,585 @@
+"""Kimi-Linear family (HF ``model_type`` kimi_linear; moonshotai's
+Kimi-Linear-48B-A3B) — layers of two kinds in one model: Kimi Delta
+Attention (KDA: a gated delta rule whose decay is per key CHANNEL and
+whose state is a ``d_k x d_v`` matrix a head) and, after every three of
+them, multi-head LATENT attention with NO position encoding
+(``models/kimi.py MLAttention`` with ``q_lora_rank`` None and
+``mla_use_nope``); the FFN a dense SwiGLU in the first ``n_dense_layers``
+and after them a shared expert beside experts routed by sigmoid scores and
+a selection bias (``models/kimi.py block_ffn``, ``ops/moe.py``).  No bias
+anywhere; the head is untied.
+
+Layer ``l``: ``h = x + mixer_l(RMSNorm(x))``; ``y = h + ffn_l(RMSNorm(h))``.
+After the last layer one more RMSNorm, then the head.
+
+The KDA mixer on ``u`` [T, d], ``H`` heads of ``d_k = d_v``:
+
+- ``q^ = u W_q``, ``k^ = u W_k``, ``v^ = u W_v`` [T, H d_k] each; each
+  through its own depthwise causal convolution of ``kda_conv`` = 4 taps
+  and SiLU (the three side by side are one convolution over ``3 H d_k``
+  channels: ``conv_w`` [taps, 3 H d_k], ``models/layers.py slot_conv``);
+- per head ``q = q' / max(|q'|, 1e-6) * d_k ** -0.5``, ``k = k' / max(|k'|,
+  1e-6)``;
+- the decay, per key channel: ``g = -exp(A_log[h]) * softplus((u W_fa) W_fb
+  + dt_bias)`` <= 0 in float32, ``a = exp(g)``; ``beta = sigmoid(u W_b)``;
+- the state ``S`` [d_k, d_v] a head, float32, from zeros: ``S~ = diag(a_t)
+  S_{t-1}``; ``S_t = S~ + beta_t k_t (v_t - S~^T k_t)^T``; ``o_t = S_t^T q_t``;
+- ``y = RMSNorm_{d_k}(o_t) * sigmoid((u W_ga) W_gb)`` (one learned scale of
+  ``d_k`` shared by the heads); ``y W_o``.
+
+A forward over many positions (training, the full forward, the engine's
+prefill) computes the recurrence in chunks (``kda_scan``, scope
+``kda.scan``); a decode step is the recurrence once, BY SLOT: the layer's
+slab of states updated where it lies (``kda_step``, scope ``kda.step``), and
+the windows' slab likewise (``step_conv``; a prefill's and the trainer's
+convolution is ``slot_conv``).
+
+With a cache this is the first family with BOTH a latent pool and a state
+pool (``models.CacheSpec``: ``latent_dim`` > 0 and ``state_layers`` > 0):
+``latent_pages`` [MLA layers, pages, page, row] for the latent layers;
+``conv`` [KDA layers, slots, taps - 1, 3 H d_k] (the three windows, the
+model's dtype) and ``ssm`` [KDA layers, slots, H, d_k, d_v] (float32) for
+the mixers, with ``slots`` [B]; each layer indexes ITS pool by its number
+among its kind, and all three arrays are carried whole through the layers.
+A position < 0 is padding: there ``a = 1`` and ``beta = 0``, which makes
+the recurrence the identity, and the window is taken at the last real
+position (``slot_conv``); a row whose slot lies outside the pool (a padded
+decode row) changes nothing.  A forward whose first position is 0 starts
+from a zero state and a zero window whatever its slot held.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from dataclasses import dataclass
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..parallel.sharding import with_logical_constraint as _constrain
+from .kimi import EXPERT_BIAS_STD, MLAttention, block_ffn, mla_params
+from .layers import RMSNorm, init_by_leaf, slot_conv
+from .llama import _next_token_xent
+
+KDA, MLA = "kda", "mla"
+L2_EPS = 1e-6               # under the L2 norms of q and k
+
+
+@dataclass(frozen=True)
+class KimiLinearConfig:
+    """moonshotai/Kimi-Linear-48B-A3B-Instruct as published (the
+    defaults): 27 layers of 2304, latent attention at (1-indexed) 4, 8,
+    ..., 24 and 27, KDA elsewhere (32 heads of 128, 4 taps); MLA of 32
+    heads over rank 512 and widths 128 | 64 | 128 with no rotation and no
+    query rank; layer 1 a dense SwiGLU of 9216, layers 2-27 a shared
+    expert and top-8 of 256 experts of width 1024."""
+    vocab_size: int = 163840
+    layer_types: Tuple[str, ...] = tuple(
+        MLA if (i % 4 == 3 and i < 24) or i == 26 else KDA
+        for i in range(27))
+    d_model: int = 2304
+    kda_heads: int = 32
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_gate_rank: int = 128            # of the decay's and the output gate's
+    kda_chunk: int = 64                 # kda_scan's chunk
+    kda_sub_chunk: int = 16             # and the blocks inside it
+    n_head: int = 32                    # the latent layers'
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mla_use_nope: bool = True
+    d_ff: int = 9216                    # the dense layers' width
+    n_dense_layers: int = 1
+    moe_d_ff: int = 1024                # one expert's width
+    n_experts: int = 256                # what the router scores
+    experts_per_token: int = 8
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.446
+    # The share of the experts held here (ops/moe.py); None: all.
+    first_expert: int = 0
+    held_experts: Optional[int] = None
+    max_seq: int = 1048576
+    rms_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+    attn_impl: str = "dense"
+    remat: bool = True
+    mesh: Any = None
+
+    def __post_init__(self):
+        bad = set(self.layer_types) - {KDA, MLA}
+        if bad:
+            raise ValueError(f"layer_types holds {sorted(bad)}")
+        if not self.mla_use_nope:
+            raise ValueError("the latent layers take no position "
+                             "encoding (mla_use_nope); a rotation has no "
+                             "theta here")
+        if self.kda_chunk % self.kda_sub_chunk:
+            raise ValueError("kda_chunk must be whole kda_sub_chunks")
+
+    @staticmethod
+    def tiny(**overrides) -> "KimiLinearConfig":
+        """The shape at a test's size: [kda (dense FFN), kda, kda, mla,
+        kda, mla]: the dense layer inside a whole period, then a KDA and
+        the closing latent layer; 64 wide, 4 KDA heads of 16 with gates
+        of rank 8 and chunks of 8 in blocks of 4, 4 latent heads over
+        rank 24 and widths 16 | 8 | 16, a dense FFN of 96, a shared
+        expert and top-2 of 8 experts of width 32."""
+        return KimiLinearConfig(**{**dict(
+            vocab_size=256, layer_types=(KDA, KDA, KDA, MLA, KDA, MLA),
+            d_model=64, kda_heads=4, kda_head_dim=16, kda_gate_rank=8,
+            kda_chunk=8, kda_sub_chunk=4, n_head=4, kv_lora_rank=24,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            d_ff=96, moe_d_ff=32, n_experts=8, experts_per_token=2,
+            max_seq=128, dtype=jnp.float32, param_dtype=jnp.float32),
+            **overrides})
+
+    @property
+    def n_layer(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def n_moe_layers(self) -> int:
+        return max(self.n_layer - self.n_dense_layers, 0)
+
+    @property
+    def kda_dim(self) -> int:
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.qk_head_dim ** -0.5
+
+    def layers_of(self, kind: str) -> int:
+        return sum(t == kind for t in self.layer_types)
+
+    def mixer_params(self) -> int:
+        """One KDA layer's mixer (``wq``, ``wk``, ``wv``, ``wo``, the two
+        low-rank gates, ``wb`` and the taps), in parameters."""
+        d, di, r = self.d_model, self.kda_dim, self.kda_gate_rank
+        return 4 * d * di + 2 * (d * r + r * di) + d * self.kda_heads \
+            + self.kda_conv * 3 * di
+
+    def attention_params(self) -> int:
+        """One latent layer's four matrices, in parameters."""
+        return mla_params(self)
+
+    def flops_per_token(self) -> float:
+        """Training FLOPs a token: 6 x the matmul parameters a token
+        passes through (its k experts and the shared one, not all)."""
+        sparse = 3 * self.d_model * self.moe_d_ff * (
+            self.experts_per_token + self.n_shared_experts) \
+            + self.d_model * self.n_experts
+        dense = min(self.n_dense_layers, self.n_layer)
+        n = self.vocab_size * self.d_model \
+            + dense * 3 * self.d_model * self.d_ff \
+            + self.n_moe_layers * sparse \
+            + self.layers_of(KDA) * self.mixer_params() \
+            + self.layers_of(MLA) * self.attention_params()
+        return 6.0 * n
+
+
+# ------------------------------------------------------- the delta rule
+
+def _decayed_scores(left, k, gcum, sub: int):
+    """``sum_c left[i, c] k[j, c] exp(G[i, c] - G[j, c])`` for ``i >= j``
+    and 0 above the diagonal; left, k, gcum [..., C, D] (``gcum`` the
+    cumulative log-decay inside the chunk, falling) -> [..., C, C].
+
+    ``exp(G_i) * exp(-G_j)`` overflows float32 once a chunk's decay passes
+    e^-88, so only DIFFERENCES ``G_i - G_j <= 0`` are ever exponentiated:
+    the chunk is cut into blocks of ``sub`` rows; a block against itself
+    takes the pairwise differences ([sub, sub, D], exact whatever the
+    decay); a block I against an EARLIER block J goes through a reference
+    point, the first row r of I: ``exp(G_i - G_r)`` (i in I) and ``exp(G_r
+    - G_j)`` (j before r) are both <= 1, and the two sides meet in one
+    matmul."""
+    *lead, c, d = k.shape
+    ns = c // sub
+    lb, kb, gb = (z.reshape(*lead, ns, sub, d) for z in (left, k, gcum))
+    # a masked entry's exponent may be positive: mask the exponent
+    tri = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+    pair = jnp.exp(jnp.where(
+        tri, gb[..., :, None, :] - gb[..., None, :, :], -jnp.inf))
+    diag = jnp.sum(lb[..., :, None, :] * kb[..., None, :, :] * pair,
+                   axis=-1)                                # [.., ns, s, s]
+    ref = gb[..., :1, :]                                   # [.., ns, 1, D]
+    lq = lb * jnp.exp(gb - ref)
+    kd = k[..., None, :, :] * jnp.exp(
+        jnp.minimum(ref - gcum[..., None, :, :], 0.0))     # [.., ns, C, D]
+    off = jnp.einsum("...id,...jd->...ij", lq, kd).reshape(*lead, c, c)
+    block = jnp.arange(c) // sub
+    off = jnp.where(block[:, None] > block[None, :], off, 0.0)
+    eye = jnp.eye(ns, dtype=diag.dtype)
+    return off + jnp.einsum("...aij,ab->...aibj", diag, eye).reshape(
+        *lead, c, c)
+
+
+def kda_scan(q, k, v, g, beta, chunk: int, sub: int, state=None):
+    """The gated delta rule over T positions in chunks.
+
+    q, k, v, g [B, T, H, D] (q scaled, q and k normalised; g <= 0 the log
+    of the decay a key channel), beta [B, T, H], all float32; a padded
+    position has g = 0 and beta = 0 (the identity).  ``state`` [B, H, D_k,
+    D_v] (None: zeros).  Returns (o [B, T, H, D], the state after the last
+    position).
+
+    Inside a chunk, with ``G`` the cumulative sum of g and ``Gamma =
+    exp(G)``: ``A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)`` below the
+    diagonal; ``(I + A) [W | U] = diag(beta) [K * Gamma | V]`` (a unit
+    lower-triangular solve: each row's correction takes the rows before
+    it); with the incoming state ``S``: ``V' = U - W S``; ``O = (Q * Gamma)
+    S + tril((Q K^T)_decayed) V'``; ``S' = diag(Gamma_C) S + (K * Gamma_C /
+    Gamma)^T V'``.  Everything that does not depend on ``S`` is computed
+    for all chunks at once; the state is carried from chunk to chunk.  No
+    ``1 / Gamma`` is formed: ``_decayed_scores`` says how.  T is filled up
+    to whole chunks with identity positions; a T shorter than a chunk is
+    one chunk of whole blocks."""
+    bsz, t, h, d = q.shape
+    if t < chunk:
+        chunk = -(-t // sub) * sub
+    fill = -t % chunk
+    if fill:
+        q, k, v, g, beta = (
+            jnp.pad(z, [(0, 0), (0, fill)] + [(0, 0)] * (z.ndim - 2))
+            for z in (q, k, v, g, beta))
+    nc = (t + fill) // chunk
+
+    def chunks(z):      # [B, nc*C, H, ...] -> [B, nc, H, C, ...]
+        return jnp.moveaxis(
+            z.reshape((bsz, nc, chunk) + z.shape[2:]), 3, 2)
+
+    q, k, v, g = (chunks(z) for z in (q, k, v, g))
+    beta = chunks(beta)[..., None]                         # [B,nc,H,C,1]
+    gcum = jnp.cumsum(g, axis=-2)
+    gamma = jnp.exp(gcum)
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    a_mat = jnp.where(strict, _decayed_scores(k, k, gcum, sub), 0.0) * beta
+    wu = jax.scipy.linalg.solve_triangular(
+        a_mat + jnp.eye(chunk, dtype=a_mat.dtype),
+        beta * jnp.concatenate([k * gamma, v], axis=-1),
+        lower=True, unit_diagonal=True)
+    w, u = wu[..., :d], wu[..., d:]
+    qk = _decayed_scores(q, k, gcum, sub)
+    q_in = q * gamma
+    k_out = k * jnp.exp(gcum[..., -1:, :] - gcum)
+    g_out = gamma[..., -1, :]                              # [B,nc,H,D]
+
+    def one(s, blk):
+        w_c, u_c, qk_c, q_c, k_c, g_c = blk
+        v_new = u_c - jnp.einsum("bhck,bhkv->bhcv", w_c, s)
+        o = jnp.einsum("bhck,bhkv->bhcv", q_c, s) \
+            + jnp.einsum("bhcj,bhjv->bhcv", qk_c, v_new)
+        s = g_c[..., None] * s + jnp.einsum("bhck,bhcv->bhkv", k_c, v_new)
+        return s, o
+
+    if state is None:
+        state = jnp.zeros((bsz, h, d, d), jnp.float32)
+    state, o = jax.lax.scan(
+        one, state, tuple(jnp.moveaxis(z, 1, 0)
+                          for z in (w, u, qk, q_in, k_out, g_out)))
+    o = jnp.moveaxis(o, 0, 1)                              # [B,nc,H,C,D]
+    o = jnp.moveaxis(o, 2, 3).reshape(bsz, nc * chunk, h, d)
+    return o[:, :t], state
+
+
+def kda_step(pool, layer, slots, fresh, q, k, v, a, beta):
+    """The recurrence once for every row of a decode batch, BY SLOT and
+    where the states lie: the rows' small vectors (q, k, v, a [B, H, D]
+    float32, beta [B, H]; ``fresh`` [B]: the row starts from zeros) are
+    put in slot order (a one-hot sum over the rows: a slot no row names,
+    and a row whose slot lies outside the pool, a padded row, leave ``a =
+    1``, ``beta = 0``: the identity), and layer ``layer`` of the WHOLE
+    pool [L, slots, H, D_k, D_v] is decayed and corrected as one slab,
+    written back over itself.  No state is gathered or scattered: gathered,
+    the running rows' states moved seven times their bytes and each
+    gather and scatter was a loop over the rows (my compile for a v5e, PR
+    41); a slot whose sequence is not in this step is read and written
+    back as it was.  Live rows have distinct slots.  Multiplies and sums in
+    float32: no matmul rounds the state.  Returns (o [B, H, D_v], the
+    pool)."""
+    n_slots = pool.shape[1]
+    hit = slots[:, None] == jnp.arange(n_slots)[None, :]       # [B, S]
+
+    def by_slot(x, idle=0.0):       # [B, ...] -> [S, ...]
+        m = hit.reshape(hit.shape + (1,) * (x.ndim - 1))
+        put = jnp.sum(jnp.where(m, x[:, None], 0.0), axis=0)
+        live = jnp.any(hit, axis=0).reshape((n_slots,) + (1,) * (x.ndim - 1))
+        return jnp.where(live, put, idle)
+
+    q, k, v, beta = (by_slot(x) for x in (q, k, v, beta))
+    a = by_slot(a, 1.0)
+    keep = 1.0 - by_slot(fresh.astype(jnp.float32))            # [S]
+    s = pool[layer].astype(jnp.float32) * keep[:, None, None, None]
+    s = a[..., None] * s                                   # S~
+    err = v - jnp.sum(s * k[..., None], axis=-2)           # v - S~^T k
+    s = s + (beta[..., None] * k)[..., None] * err[..., None, :]
+    o = jnp.sum(s * q[..., None], axis=-2)                     # [S, H, D]
+    o = jnp.sum(jnp.where(hit[:, :, None, None], o[None], 0.0), axis=1)
+    return o, pool.at[layer].set(s.astype(pool.dtype))
+
+
+def step_conv(x, taps, window, act):
+    """``slot_conv`` for a decode step (x [B, 1, C]), BY SLOT as ``kda_step``
+    is: each row's last K-1 inputs are read from its slot, the window
+    slides by one (a padded row's stays), and the layer's slots are
+    rewritten as ONE slab, each from the row that names it (a one-hot sum
+    over the rows; a slot no row names keeps what it held).  Row by row
+    (``slot_conv``: a slice at each row's length, a scatter) each was a
+    loop over the batch on the chip, 16 x ~8 operations a layer, and with
+    20 layers the profiler's capture of a step did not end (PR 41)."""
+    pool, layer, slots, fresh, valid = window
+    b, _, c = x.shape
+    kw = taps.shape[0]
+    flat = pool.reshape(pool.shape[:2] + (-1,))
+    before = jnp.where(fresh[:, None, None], 0,
+                       flat[layer, slots].reshape(b, kw - 1, c))
+    past = jnp.concatenate([before.astype(x.dtype), x], axis=1)
+    out = act(sum(past[:, i:i + 1].astype(jnp.float32) * taps[i]
+                  for i in range(kw)))
+    keep = jnp.where(valid[:, :, None], past[:, 1:], past[:, :-1])
+    keep = keep.reshape(b, -1).astype(flat.dtype)
+    hit = slots[:, None] == jnp.arange(flat.shape[1])[None, :]     # [B, S]
+    put = jnp.sum(jnp.where(hit[:, :, None], keep[:, None], 0), axis=0)
+    slab = jnp.where(jnp.any(hit, axis=0)[:, None], put.astype(flat.dtype),
+                     flat[layer])
+    return out, flat.at[layer].set(slab).reshape(pool.shape)
+
+
+def _l2_normalised(x):
+    return x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True),
+                           L2_EPS)
+
+
+def _load_states(pool, layer, slots):
+    """Each row's state [B, H, D_k, D_v] gathered from its slot (an index
+    outside the pool is clipped: a padded row reads some other row's, and
+    nothing is made of it)."""
+    return pool.at[layer, slots].get(mode="clip").astype(jnp.float32)
+
+
+class KDAMixer(nn.Module):
+    cfg: KimiLinearConfig
+
+    @nn.compact
+    def __call__(self, u, cache=None):
+        """u [B, T, d] -> [B, T, d]; with ``cache`` ({"conv", "ssm",
+        "layer", "slots", "positions"}: the WHOLE state pool and this
+        mixer's layer in it) returns (out, (conv, ssm)) with each row's
+        slot updated."""
+        cfg = self.cfg
+        b, t, _ = u.shape
+        h, dk, di = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_dim
+        f32 = jnp.float32
+        init = nn.initializers.normal(0.02)
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=cfg.dtype, kernel_init=init)
+        with jax.named_scope("kda.proj"):
+            qkv = jnp.concatenate(
+                [dense(di, name=name)(u) for name in ("wq", "wk", "wv")],
+                axis=-1)
+            f = dense(di, name="f_b")(dense(cfg.kda_gate_rank,
+                                            name="f_a")(u))
+            z = dense(di, name="g_b")(dense(cfg.kda_gate_rank,
+                                            name="g_a")(u))
+            b_logit = dense(h, name="wb")(u)
+        conv_w = self.param("conv_w", init, (cfg.kda_conv, 3 * di), f32)
+        a_log = self.param("A_log", nn.initializers.zeros, (h,), f32)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (di,), f32)
+
+        valid = fresh = window = ssm_pool = None
+        if cache is not None:
+            valid = cache["positions"] >= 0                    # [B, T]
+            fresh = cache["positions"][:, 0] == 0              # [B]
+            ssm_pool, layer, slots = (cache["ssm"], cache["layer"],
+                                      cache["slots"])
+            window = (cache["conv"], layer, slots, fresh, valid)
+        with jax.named_scope("kda.conv"):
+            if window is not None and t == 1:
+                qkv, conv_pool = step_conv(qkv, conv_w, window, nn.silu)
+            else:
+                qkv, conv_pool = slot_conv(qkv, conv_w, window, act=nn.silu)
+        with jax.named_scope("kda.gate"):
+            q, k, v = (x.reshape(b, t, h, dk)
+                       for x in jnp.split(qkv, 3, axis=-1))
+            q, k = _l2_normalised(q) * dk ** -0.5, _l2_normalised(k)
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                (f.astype(f32) + dt_bias).reshape(b, t, h, dk))
+            beta = jax.nn.sigmoid(b_logit.astype(f32))         # [B,T,H]
+            if valid is not None:    # padding: the identity
+                g = jnp.where(valid[..., None, None], g, 0.0)
+                beta = jnp.where(valid[..., None], beta, 0.0)
+        if cache is not None and t == 1:
+            with jax.named_scope("kda.step"):
+                o, ssm_pool = kda_step(
+                    ssm_pool, layer, slots, fresh, q[:, 0], k[:, 0],
+                    v[:, 0], jnp.exp(g[:, 0]), beta[:, 0])
+                o = o[:, None]
+        else:
+            with jax.named_scope("kda.scan"):
+                s_in = None
+                if cache is not None:
+                    s_in = jnp.where(fresh[:, None, None, None], 0.0,
+                                     _load_states(ssm_pool, layer, slots))
+                o, s_out = kda_scan(q, k, v, g, beta, cfg.kda_chunk,
+                                    cfg.kda_sub_chunk, s_in)
+                if cache is not None:
+                    ssm_pool = ssm_pool.at[layer, slots].set(
+                        s_out.astype(ssm_pool.dtype), mode="drop")
+        with jax.named_scope("kda.out_norm"):
+            y = RMSNorm(cfg.rms_eps, f32, name="o_norm")(o) \
+                * jax.nn.sigmoid(z.astype(f32).reshape(b, t, h, dk))
+            y = y.reshape(b, t, di).astype(cfg.dtype)
+        with jax.named_scope("kda.out_proj"):
+            out = dense(cfg.d_model, name="wo")(y)
+        return out if cache is None else (out, (conv_pool, ssm_pool))
+
+
+class KimiLinearBlock(nn.Module):
+    cfg: KimiLinearConfig
+    kind: str
+    dense: bool
+
+    @nn.compact
+    def __call__(self, x, cache=None):
+        """``cache`` is the latent layer's or the mixer's; returns x, or
+        (x, what the layer updated)."""
+        cfg = self.cfg
+        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mixer_norm")(x)
+        if self.kind == MLA:
+            m, new = MLAttention(cfg, name="attn")(y, cache)
+        else:
+            m = KDAMixer(cfg, name="kda")(y, cache)
+            new = None
+            if cache is not None:
+                m, new = m
+        x = x + m.astype(x.dtype)
+        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
+        x = block_ffn(cfg, x, y, self.dense,
+                      cache["positions"] if cache is not None else None)
+        return x if cache is None else (x, new)
+
+
+class KimiLinear(nn.Module):
+    cfg: KimiLinearConfig
+
+    @nn.compact
+    def __call__(self, tokens, kv_cache=None, positions=None):
+        """Full forward (kv_cache=None) or a step against BOTH caches
+        (``kv_cache`` = {"latent_pages", "page_table", "conv", "ssm",
+        "slots"}, ``positions`` [B, T]; the module docstring has the
+        shapes): returns logits, or (logits, the cache updated)."""
+        cfg = self.cfg
+        cached = kv_cache is not None
+        init = nn.initializers.normal(0.02)
+        emb = self.param("embed", init, (cfg.vocab_size, cfg.d_model),
+                         jnp.float32)
+        with jax.named_scope("embed"):
+            x = emb.astype(cfg.dtype)[tokens]
+            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
+        block = KimiLinearBlock
+        if cfg.remat and not cached:
+            block = nn.remat(KimiLinearBlock, prevent_cse=False)
+        if cached:
+            new = dict(kv_cache)
+        seen = {KDA: 0, MLA: 0}
+        for i, kind in enumerate(cfg.layer_types):
+            blk = block(cfg, kind, i < cfg.n_dense_layers,
+                        name=f"layer_{i}")
+            if not cached:
+                x = blk(x)
+            elif kind == MLA:
+                x, new["latent_pages"] = blk(x, cache={
+                    "latent_pages": new["latent_pages"],
+                    "layer": seen[kind], "page_table": new["page_table"],
+                    "positions": positions})
+            else:
+                x, (new["conv"], new["ssm"]) = blk(x, cache={
+                    "conv": new["conv"], "ssm": new["ssm"],
+                    "layer": seen[kind], "slots": new["slots"],
+                    "positions": positions})
+            seen[kind] += 1
+            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
+        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
+        head = self.param("lm_head", init, (cfg.d_model, cfg.vocab_size),
+                          jnp.float32)
+        with jax.named_scope("lm_head"):        # untied
+            logits = jnp.einsum("btd,dv->btv", x, head.astype(cfg.dtype),
+                                preferred_element_type=jnp.float32)
+            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg.mesh)
+        return (logits, new) if cached else logits
+
+
+# ------------------------------------------------------ init, loss, rules
+
+def _special_leaf(cfg: KimiLinearConfig, name: str, key, shape):
+    """The leaves that are not normal(0, 0.02): None for the others."""
+    leaf = name.rsplit("/", 1)[-1]
+    if leaf == "expert_bias":
+        return EXPERT_BIAS_STD * jax.random.normal(key, shape, jnp.float32)
+    if leaf == "A_log":     # a head's rate, uniform in [1, 16]
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0,
+                                          16.0))
+    if leaf == "dt_bias":   # inverse softplus of a step in [1e-3, 1e-1]
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                     * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    if leaf == "conv_w":    # PyTorch's depthwise default, no bias
+        bound = cfg.kda_conv ** -0.5
+        return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+    return None
+
+
+def kimi_linear_init(cfg: KimiLinearConfig, rng):
+    """The weights from the seed, leaf by leaf (``models/layers.py
+    init_by_leaf``): matrices, the embedding and the head normal(0,
+    0.02), norm scales 1, ``expert_bias`` normal(0, ``EXPERT_BIAS_STD``)
+    as Kimi-K2's, and the mixer's own, which stay float32 (they feed the
+    decay): ``A_log = log(uniform(1, 16))`` a head, ``dt_bias`` the
+    inverse softplus of a step log-uniform in [0.001, 0.1] a channel (so a
+    token's log-decay lies in about -1.6 .. -0.001 and a chunk of 64 can
+    pass e^-100), the taps uniform in +-1/sqrt(taps)."""
+    return init_by_leaf(KimiLinear, cfg, rng,
+                        functools.partial(_special_leaf, cfg))
+
+
+def kimi_linear_loss_fn(cfg: KimiLinearConfig, params, batch):
+    """Mean next-token cross entropy (the source balances its experts
+    through the selection bias; no auxiliary loss has a weight in the
+    published config)."""
+    tokens = batch["tokens"]
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    return _next_token_xent(KimiLinear(cfg).apply(params, inputs), targets)
+
+
+def kimi_linear_partition_rules():
+    """fsdp + tensor rules for Kimi-Linear trees: the mixer's and the
+    latent layers' projections column-parallel into their heads, ``wo``
+    and the down projections row-parallel, the low-rank gates' first
+    halves whole, the experts as OLMoE's, every expert on every chip."""
+    from jax.sharding import PartitionSpec as PS
+
+    return (
+        ("embed$", PS("tensor", "fsdp")),
+        ("lm_head$", PS("fsdp", "tensor")),
+        (r"moe/(w_gate|w_up)$", PS(None, "fsdp", "tensor")),
+        (r"moe/w_down$", PS(None, "tensor", "fsdp")),
+        (r"moe/router$", PS("fsdp", None)),
+        (r"(wkv_a|f_a|g_a|wb)/kernel$", PS("fsdp", None)),
+        (r"(wq|wk|wv|f_b|g_b)/kernel$", PS("fsdp", "tensor")),
+        (r"wkv_b$", PS("fsdp", "tensor")),
+        (r"(w_gate|w_up|shared_gate|shared_up)/kernel$",
+         PS("fsdp", "tensor")),
+        (r"(wo|w_down|shared_down)/kernel$", PS("tensor", "fsdp")),
+        (r"(scale|expert_bias|conv_w|A_log|dt_bias)$", PS()),
+    )

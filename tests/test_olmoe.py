@@ -215,9 +215,10 @@ def test_engine_builds_every_family_from_its_registry_row(family):
         engine.step()
     assert seq.generated == 3 and engine.stats()["step_errors"] == 0
     assert ("moe" in engine.stats()) == (
-        family in ("olmoe", "granitemoehybrid", "lfm2moe", "kimik2"))
+        family in ("olmoe", "granitemoehybrid", "lfm2moe", "kimik2",
+                   "kimilinear"))
     assert ("state" in engine.stats()) == (
-        family in ("granitemoehybrid", "lfm2moe"))
+        family in ("granitemoehybrid", "lfm2moe", "kimilinear"))
 
 
 def test_engine_counts_gpt2s_moe_option_too():

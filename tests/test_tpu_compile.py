@@ -595,6 +595,93 @@ def test_kimi_cell_compiles_at_the_benchmarks_sizes(one_chip, tokens_shape):
     assert calls("flash_fwd") == (0 if decode else 7)
 
 
+@pytest.mark.parametrize("tokens_shape", [(16, 1), (1, 2048)],
+                         ids=["decode", "prefill2048"])
+def test_kimi_linear_cell_compiles_at_the_benchmarks_sizes(one_chip,
+                                                           tokens_shape):
+    """The decode and ``prefill[2048]`` programs of
+    serve-kimi-linear-48b-a3b-longout at the benchmark's sizes (all 27
+    layers at the published widths: 20 KDA mixers of 32 heads of 128, 7
+    latent layers; 16 of 256 experts held, a router of 256; the WHOLE
+    vocabulary; bf16; max_batch 16, 4096 pages of 16, max_context 4096),
+    as the backend ``tpu`` builds them: 9.91 GB of weights, ONE latent
+    pool [7, 4096, 16, 640] and the state pool's ``conv`` [20, 16, 3,
+    12288] and float32 ``ssm`` [20, 16, 32, 128, 128], all three aliased
+    to the outputs.  The decode step attends through the latent paged
+    kernel once a latent layer and updates each KDA layer's slab of states
+    where it lies, with no copy of the state pool and no loop over the rows; the prefill runs
+    the chunked scan (a triangular solve a KDA layer) and the flash
+    kernel, and holds under 1 GB of temporaries beside its 1.34 GB of
+    float32 logits."""
+    import ray_tpu.models.attention as attention
+    import ray_tpu.ops
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_pool, init_state, pages_for
+    from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.models.kimi_linear import KimiLinearConfig
+    from ray_tpu.ops import paged_attention
+
+    row = MODEL_FAMILIES["kimilinear"]
+    cfg = KimiLinearConfig(held_experts=16, attn_impl="dense", remat=False)
+    spec = row.cache(cfg)
+    params = jax.eval_shape(lambda: row.init(cfg, jax.random.PRNGKey(0)))
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert abs(weights - 9.915e9) < 0.001 * 9.915e9
+    kv = jax.eval_shape(lambda: init_pool(spec, 4096, 16, cfg.dtype))
+    state = jax.eval_shape(lambda: init_state(spec, 16, cfg.dtype))
+    assert kv["latent_pages"].shape == (7, 4096, 16, 640)
+    assert state["conv"].shape == (20, 16, 3, 12288)
+    assert state["ssm"].shape == (20, 16, 32, 128, 128)
+    b = tokens_shape[0]
+    ints = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                             sharding=one_chip)
+    with pytest.MonkeyPatch.context() as patch:    # as on the tpu backend
+        patch.setattr(attention, "_latent_kernel",
+                      paged_attention.latent_supported)
+        patch.setattr(paged_attention, "paged_decode_latent",
+                      functools.partial(paged_attention.paged_decode_latent,
+                                        interpret=False))
+        patch.setattr(attention, "_prefill_impl", lambda t: "flash")
+        patch.setattr(ray_tpu.ops, "flash_attention", functools.partial(
+            ray_tpu.ops.flash_attention, interpret=False))
+        compiled = jit_forward(row.module(cfg)).lower(
+            _on(params, one_chip), ints(tokens_shape),
+            _on(kv["latent_pages"], one_chip),
+            ints((b, pages_for(4096, 16))), ints(tokens_shape),
+            _on(state["conv"], one_chip), _on(state["ssm"], one_chip),
+            ints((b,))).compile()
+    decode = tokens_shape[1] == 1
+    assert _device_bytes(compiled) < (11.5e9 if decode else 13.5e9)
+    m = compiled.memory_analysis()
+    pools = [kv["latent_pages"], state["conv"], state["ssm"]]
+    assert m.alias_size_in_bytes == sum(a.size * a.dtype.itemsize
+                                        for a in pools)
+    assert m.temp_size_in_bytes < (0.2e9 if decode else 1.0e9)
+    text = compiled.as_text()
+    assert text.count('op_name="ragged-dot-metadata"') == cfg.n_moe_layers
+    # no pass over the state pool but the in-place writes (a layer's slab
+    # in a decode step, one row in a prefill: a dynamic-update-slice)
+    shape = ",".join(map(str, state["ssm"].shape))
+    passes = [line.strip()[:120] for line in text.splitlines()
+              if (hit := _POOL_PASS.match(line)) and hit.group(1) == shape
+              and hit.group(2) != "dynamic-update-slice"]
+    assert not passes, (len(passes), passes[:4])
+    assert text.count(f"f32[{shape}]") > 20
+
+    def calls(kernel):
+        return len(re.findall(
+            rf"^\s*(?:ROOT )?%{kernel}[\w.]* = .*custom-call\(", text,
+            re.M))
+
+    assert calls("paged_decode_latent") == (7 if decode else 0)
+    assert calls("flash_fwd") == (0 if decode else 7)
+    if decode:      # the gathers and scatters were loops over the rows
+        assert not re.search(r"^\s*%?[\w.]+ = .* while\(", text, re.M)
+    else:
+        assert "kda.scan" in text and "kda.step" not in text
+
+
 def _train_step_and_shapes(cfg, loss_chunk):
     from ray_tpu.models.gpt2 import gpt2_init, gpt2_loss_fn
     from ray_tpu.train.train_step import TrainState, make_optimizer
