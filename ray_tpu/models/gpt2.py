@@ -281,63 +281,62 @@ def gpt2_init(cfg: GPT2Config, rng) -> Any:
     return GPT2(init_cfg).init(rng, tokens)
 
 
-def _xent_fwd_impl(x, wte, targets, chunk: int):
+def _xent_chunks(x, targets, chunk: int):
+    """``x`` [b,t,d] and ``targets`` [b,t] as the scan reads them:
+    [n,b,c,d] and [n,b,c], ``n = t // chunk`` chunks along the sequence."""
     b, t, d = x.shape
     n = t // chunk
-    xs = jnp.moveaxis(x.reshape(b, n, chunk, d), 1, 0)       # [n,b,c,d]
-    ts = jnp.moveaxis(targets.reshape(b, n, chunk), 1, 0)    # [n,b,c]
+    return (jnp.moveaxis(x.reshape(b, n, chunk, d), 1, 0),
+            jnp.moveaxis(targets.reshape(b, n, chunk), 1, 0))
 
-    def body(acc, xt):
-        xc, tc = xt
-        logits = jnp.einsum("bcd,vd->bcv", xc, wte,
-                            preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)              # [b,c]
-        tgt = jnp.take_along_axis(logits, tc[..., None],
-                                  axis=-1)[..., 0]
-        return acc + jnp.sum(lse - tgt), lse
 
-    total, lses = jax.lax.scan(body, jnp.float32(0.0), (xs, ts))
-    return total, lses
+def _xent_chunk(xc, wte, tc):
+    """One chunk's float32 logits, their log-sum-exp and the sum of the
+    rows' losses."""
+    logits = jnp.einsum("bcd,vd->bcv", xc, wte,
+                        preferred_element_type=jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)                  # [b,c]
+    tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
+    return logits, lse, jnp.sum(lse - tgt)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _chunked_xent(x, wte, targets, chunk: int) -> jnp.ndarray:
     """Fused chunked cross entropy (custom_vjp): never materializes the
-    [B, T, V] logits tensor in HBM in EITHER direction.
+    [B, T, V] logits tensor in HBM, and makes each chunk's logits ONCE.
 
     The fp32 logits (~3.3 GB at GPT-2 pretraining shapes, several HBM
-    round-trips through log_softmax and its VJP) are the biggest
-    memory consumer of the step.  Forward scans seq chunks saving only
-    the per-row log-sum-exp; backward recomputes each chunk's logits
-    once and folds the softmax-minus-onehot cotangent STRAIGHT into
-    the dX / dWte einsums — measured +5% step throughput over the
-    whole-logits path at B16/T1024 on one chip, and the live-slab
-    memory drops from O(T*V) to O(chunk*V)."""
+    round-trips through log_softmax and its VJP) would be the biggest
+    memory consumer of the step; a scan over seq chunks keeps the live
+    slab at O(chunk*V).  The loss is the last thing the forward pass
+    computes and its value is a scalar, so its cotangent only SCALES
+    the gradient: under differentiation the one scan that makes a
+    chunk's logits also folds their softmax-minus-onehot straight into
+    the dX / dWte einsums (three vocabulary-sized matmuls a chunk, none
+    recomputed), the residuals are those two gradients, and the
+    backward rule multiplies them by the cotangent.  Called without
+    differentiation (evaluation) it is the value-only scan: one matmul
+    a chunk."""
+    def body(total, xt):
+        xc, tc = xt
+        _logits, _lse, loss = _xent_chunk(xc, wte, tc)
+        return total + loss, None
+
     with jax.named_scope("loss"):
-        total, _ = _xent_fwd_impl(x, wte, targets, chunk)
+        total, _ = jax.lax.scan(body, jnp.float32(0.0),
+                                _xent_chunks(x, targets, chunk))
     b, t, _d = x.shape
     return total / (b * t)
 
 
 def _chunked_xent_fwd(x, wte, targets, chunk):
-    with jax.named_scope("loss"):
-        total, lses = _xent_fwd_impl(x, wte, targets, chunk)
-    b, t, _d = x.shape
-    return total / (b * t), (x, wte, targets, lses)
-
-
-def _chunked_xent_bwd(chunk, res, g):
-    x, wte, targets, lses = res
     b, t, d = x.shape
-    n = t // chunk
-    xs = jnp.moveaxis(x.reshape(b, n, chunk, d), 1, 0)
-    ts = jnp.moveaxis(targets.reshape(b, n, chunk), 1, 0)
-    scale = g / (b * t)
+    scale = 1.0 / (b * t)
 
-    def body(dw, xt):
-        xc, tc, lse = xt
-        logits = jnp.einsum("bcd,vd->bcv", xc, wte,
-                            preferred_element_type=jnp.float32)
+    def body(carry, xt):
+        total, dw = carry
+        xc, tc = xt
+        logits, lse, loss = _xent_chunk(xc, wte, tc)
         p = jnp.exp(logits - lse[..., None])
         onehot = jax.nn.one_hot(tc, wte.shape[0], dtype=p.dtype)
         dl = ((p - onehot) * scale).astype(x.dtype)
@@ -346,14 +345,22 @@ def _chunked_xent_bwd(chunk, res, g):
         # compound rounding across T/chunk scan steps.
         dw = dw + jnp.einsum("bcv,bcd->vd", dl, xc,
                              preferred_element_type=jnp.float32)
-        return dw, dx_c
+        return (total + loss, dw), dx_c
 
     with jax.named_scope("loss"):
-        dw, dxs = jax.lax.scan(body,
-                               jnp.zeros(wte.shape, jnp.float32),
-                               (xs, ts, lses))
+        (total, dw), dxs = jax.lax.scan(
+            body, (jnp.float32(0.0), jnp.zeros(wte.shape, jnp.float32)),
+            _xent_chunks(x, targets, chunk))
         dx = jnp.moveaxis(dxs, 0, 1).reshape(b, t, d)
-        return dx, dw.astype(wte.dtype), None
+        # The empty array carries wte's dtype to the backward rule.
+        return total / (b * t), (dx, dw, jnp.zeros((0,), wte.dtype))
+
+
+def _chunked_xent_bwd(chunk, res, g):
+    dx, dw, like_wte = res
+    with jax.named_scope("loss"):
+        return ((dx * g).astype(dx.dtype),
+                (dw * g).astype(like_wte.dtype), None)
 
 
 _chunked_xent.defvjp(_chunked_xent_fwd, _chunked_xent_bwd)

@@ -265,10 +265,10 @@ def test_chunked_xent_matches_plain():
     chunked = gpt2_loss_fn(cfg, params, {"tokens": toks}, loss_chunk=128)
     assert abs(float(plain) - float(chunked)) < 1e-4
     # Gradients agree to bf16/fp32 einsum-ordering precision: the
-    # fused custom_vjp backward recomputes logits chunk-wise and folds
-    # softmax-minus-onehot into the grad einsums, so per-element
-    # rounding differs from the autodiff whole-logits path (measured
-    # <=0.2% of the peak gradient magnitude).
+    # fused custom_vjp folds each chunk's softmax-minus-onehot into the
+    # grad einsums chunk-wise, so per-element rounding differs from the
+    # autodiff whole-logits path (measured <=0.2% of the peak gradient
+    # magnitude).
     g1 = jax.grad(lambda p: gpt2_loss_fn(cfg, p, {"tokens": toks},
                                          loss_chunk=0))(params)
     g2 = jax.grad(lambda p: gpt2_loss_fn(cfg, p, {"tokens": toks},

@@ -9,6 +9,7 @@ import asyncio
 import contextlib
 import dataclasses
 import glob
+import re
 import subprocess
 import sys
 import types
@@ -558,6 +559,56 @@ def test_lowered_train_step_names_kernels_and_scopes():
     # are traced as jvp(loss))
     assert "loss_and_grad/jvp(loss)/" in text
     assert "transpose(loss_and_grad)/jvp(loss)/" in text
+
+
+def test_lowered_train_step_keeps_the_logits_under_loss_or_lm_head():
+    """step.loss_ms reads the scope ``loss``: every operation of the
+    lowered step on an array of the logits' size (the vocabulary beside
+    the batch and the chunk's rows; not wte's own [V, d]) lies under
+    ``loss`` or ``lm_head``, forward and backward."""
+    vocab = 320      # no other dimension of the tiny model
+    step, state, batch = _train_step_and_args(
+        dataclasses.replace(TRAIN_CFG, vocab_size=vocab))
+    text = jax.jit(step).lower(state, batch).as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+    logits_sized = re.compile(rf"tensor<(?:\d+x){{2,}}{vocab}x|"
+                              rf"tensor<(?:\d+x)+{vocab}x(?:\d+x)+")
+    # An operation inside a private function (the scan's body, one_hot,
+    # take_along_axis) is named from its call site on: keep each
+    # function's call sites beside the operations.
+    func, call_sites, found = None, {}, []
+    for line in text.splitlines():
+        opened = re.match(r"\s*func\.func \w+ @(\w+)\(", line)
+        if opened:
+            func = opened.group(1)
+            continue
+        ref = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        if not ref:
+            continue
+        call = re.search(r"call @(\w+)\(", line)
+        if call:
+            call_sites.setdefault(call.group(1), []).append(
+                (func, ref.group(1)))
+        if logits_sized.search(line):
+            found.append((func, ref.group(1), line))
+
+    def names(func, ref):
+        """Every name on the location's chain and on the chains of the
+        calls that lead into ``func``."""
+        out, refs = [], [ref]
+        while refs:
+            body = locs.get(refs.pop(), "")
+            out += re.findall(r'"([^"]*)"', body)
+            refs += re.findall(r"#loc\d+", body)
+        for site in call_sites.get(func, []):
+            out += names(*site)
+        return out
+
+    for func, ref, line in found:
+        assert any(re.search(r"[/(](loss|lm_head)[)/]", name)
+                   for name in names(func, ref)), line[:200]
+    # the logits, the softmax's passes and the three matmuls at least
+    assert len(found) >= 8
 
 
 def test_lowered_engine_forward_names_the_cache_scopes():

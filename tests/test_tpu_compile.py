@@ -727,6 +727,14 @@ def test_gpt2_124m_train_step_compiles_and_fits(one_chip,
                  r"bf16\[([\d,]+)\]", line).group(1).split(",")])
              >= 16 * 1024 * 768]
     assert not moved, (len(moved), moved[:4])
+    # The chunked loss makes its gradient where it makes its value
+    # (PR 42): three vocabulary-sized matmuls under scope loss, in ONE
+    # loop; a backward pass that recomputed the logits had four in two.
+    under_loss = [line for line in text.splitlines()
+                  if re.search(r'op_name="[^"]*[/(]loss[)/]', line)]
+    assert sum(bool(re.search(r" (convolution|dot)\(", line))
+               for line in under_loss) == 3
+    assert sum(" while(" in line for line in under_loss) == 1
 
 
 def test_gpt2_sharded_step_compiles_for_four_chips(
