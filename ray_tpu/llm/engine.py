@@ -194,7 +194,7 @@ class _Sequence:
 
 class _Flight:
     """A launched program whose token ids the host has not read: the ids
-    ``[max_batch]`` (and a decode step's routing counters) still on the
+    ``[max_batch]`` (and the program's routing counters) still on the
     device, and whose each row is."""
 
     __slots__ = ("kind", "ids", "moe", "rows", "admitted")
@@ -208,6 +208,12 @@ class _Flight:
         # request's first token (TTFT's prefill phase ends there).
         self.admitted = admitted
 
+
+# Of ops/moe.py ``MOE_COUNTERS`` (and ``layer_runs``), what each kind of
+# run adds up: the decode runs' readers divide by decode runs, and no
+# decode step takes the compact branch.
+_MOE_KEPT = {"decode": ("layer_runs", "pairs", "experts_hit", "max_load"),
+             "prefill": ("layer_runs", "pairs", "compact")}
 
 # A decode row without a sequence, to the sampler: argmax.
 _IDLE_ROW = (SamplingParams(), (0, 0), 0)
@@ -229,7 +235,7 @@ def jit_forward(model):
     alone; donated and updated in place as the pages are) and each row's
     slot ``[B]``.  Returns the logits, the paged pool's arrays, then the
     state pool's.  A model with experts returns one more output, its
-    routing counters ([layers with experts, 3] int32, ops/moe.py
+    routing counters ([layers with experts, 4] int32, ops/moe.py
     ``moe_counters``)."""
     import jax
 
@@ -382,9 +388,10 @@ class GenerationEngine:
         self._evictions = 0
         self._prefills = 0
         self._compiles = 0
-        # Routing counters of a model with experts, cumulative over
-        # DECODE runs (stats()["moe"]); stays empty for a dense model.
-        self._moe: Dict[str, int] = {}
+        # Routing counters of a model with experts, cumulative over the
+        # DECODE runs (stats()["moe"]) and, apart, over the prefills
+        # (stats()["moe_prefill"]); both stay empty for a dense model.
+        self._moe: Dict[str, Dict[str, int]] = {"decode": {}, "prefill": {}}
         # What the sampler was handed, counted from the packed rows:
         # ``steps`` launches (one a decode step, one a prefill), of which
         # ``steps_sampled`` held a row with temperature > 0 and took the
@@ -627,7 +634,12 @@ class GenerationEngine:
                 # layer_runs = runs x layers; pairs = real rows x k x
                 # layers; experts_hit and max_load summed over layers
                 # and runs.
-                **({"moe": dict(self._moe)} if self._moe else {}),
+                **({"moe": dict(self._moe["decode"])}
+                   if self._moe["decode"] else {}),
+                # Of the prefills, one run each: ``compact`` counts the
+                # layer runs that took the experts' compact branch.
+                **({"moe_prefill": dict(self._moe["prefill"])}
+                   if self._moe["prefill"] else {}),
                 # The second kind of cache (absent for a model without
                 # recurrent layers).
                 **({"state": {"slots_total": self.slots.slots,
@@ -906,7 +918,7 @@ class GenerationEngine:
         with self._phase(leaves + ".fetch"):
             ids = np.asarray(flight.ids).tolist()   # [max_batch] int32
             per_layer = None if flight.moe is None \
-                else np.asarray(flight.moe)         # [layers, 3]
+                else np.asarray(flight.moe)         # [layers, 4]
         self._flights.popleft()
         with self._phase(leaves + ".sample"):
             if per_layer is not None:
@@ -914,9 +926,10 @@ class GenerationEngine:
 
                 adds = dict(zip(MOE_COUNTERS, per_layer.sum(axis=0)),
                             layer_runs=len(per_layer))
+                kept = self._moe[flight.kind]
                 with self._lock:
-                    for key, add in adds.items():
-                        self._moe[key] = self._moe.get(key, 0) + int(add)
+                    for key in _MOE_KEPT[flight.kind]:
+                        kept[key] = kept.get(key, 0) + int(adds[key])
             for seq, row in flight.rows:
                 if seq.finished:
                     self._pipeline["rows_discarded"] += 1
@@ -975,7 +988,7 @@ class GenerationEngine:
                               np.int32)
             feed_to[0] = seq.slot
         with self._phase("llm.prefill.run"):
-            logits, _ = self._call_fwd(
+            logits, moe = self._call_fwd(
                 "prefill", tokens, table, positions,
                 np.asarray([seq.slot], np.int32))
             ids = self._call(
@@ -991,7 +1004,7 @@ class GenerationEngine:
         with self._lock:
             self._running.append(seq)
         self._launched(seq)
-        self._launch(_Flight("prefill", ids, None, flight_rows,
+        self._launch(_Flight("prefill", ids, moe, flight_rows,
                              t_admit if first_admission else None))
 
     def _decode_step(self) -> None:

@@ -43,6 +43,23 @@ only.  Nothing stands in for the absent ones: the shares of all the chips
 add up to the whole layer (tests/test_granite.py).  Holding all of them
 is the default.
 
+Where a layer holds a share and the input is large (a prefill),
+dispatch, experts and combine run over a static CAPACITY of rows and not
+over ``S x k``: the sort is stable and a pair without an expert here
+carries the largest key, so the held pairs are exactly the first
+``sum(load)`` entries of the sorted order, and the head of it is all
+there is to gather, multiply and add back (by token, in float32).  The
+capacity is twice the balanced share, ``2 x S x k x held / num_experts``,
+rounded up to whole tiles of 128 rows (``compact_capacity``: one tile
+more where the grouped matmul would take 512-row tiles), and the
+branch exists only where that is at most half of ``S x k``: never in a
+layer that holds all its experts, never in a decode step.  It is taken
+under ``jax.lax.cond(sum(load) <= capacity, ...)``: a router that crowds
+onto the held experts falls back to the path over all ``S x k`` rows for
+that layer and that call, so every pair is still computed, and the op
+stays differentiable.  Each call sows ``compact`` (bool: it took the
+compact branch) beside ``load``.
+
 Each layer sows ``moe`` into flax's ``intermediates``: ``load`` [E] (pairs
 per expert), ``prob_mean`` [E] (the mean score; under ``sigmoid`` scoring
 the scores of a row do not add up to 1) and ``z`` (mean squared
@@ -96,6 +113,35 @@ def grouped_ffn(rows, group_sizes, w_gate, w_in, w_out, act: Callable):
     else:
         h = act(h)
     return jax.lax.ragged_dot(h, w_out, group_sizes)
+
+
+ROW_TILE = 128
+
+
+def compact_capacity(pairs: int, held: int, num_experts: int
+                     ) -> Optional[int]:
+    """Rows the compact path runs over where a layer that holds ``held``
+    of ``num_experts`` routes ``pairs`` (S x k) pairs, or None where the
+    path does not exist.  From the shapes alone: no knob, no model's
+    name.
+
+    Twice the balanced share, ``2 x pairs x held / num_experts``, rounded
+    up to whole tiles of 128 rows (the least row block the compiler's
+    grouped matmul takes).  The path exists where that is at most half
+    the pairs and a true share is held: never in a layer that holds all
+    its experts, never in a decode step (128 | 160 pairs round to one
+    tile: more than half).  Where 512 divides the count it takes one tile
+    more: the compiler's kernel tiles the rows by the largest of 512, 256
+    and 128 that divides their count, and multiplies a whole tile for
+    every group that has a row in it, so with a share's small groups
+    (the balanced 85 rows an expert at Kimi's 4,096 bucket) 512-row
+    tiles cost 3.77 ms a layer where 2,176 rows in tiles of 128 cost
+    3.12, and 512 rows 3.48 where 640 cost 2.19 (v5e, PERF.md PR 43)."""
+    balanced = -(-2 * pairs * held // num_experts)
+    capacity = -(-balanced // ROW_TILE) * ROW_TILE
+    if held == num_experts or 2 * capacity > pairs:
+        return None
+    return capacity + ROW_TILE if capacity % 512 == 0 else capacity
 
 
 class MoEMLP(nn.Module):
@@ -165,34 +211,70 @@ class MoEMLP(nn.Module):
                 experts.reshape(-1)].add(1)[:e]
             n_real = jnp.maximum(jnp.sum(real), 1).astype(jnp.float32)
             keep = real[:, None].astype(jnp.float32)
+            capacity = compact_capacity(s * k, e, n)
+            fits = jnp.zeros((), bool) if capacity is None \
+                else jnp.sum(load) <= capacity
             self.sow("intermediates", "moe", {
-                "load": load,
+                "load": load, "compact": fits,
                 "prob_mean": jnp.sum(probs * keep, axis=0) / n_real,
                 "z": jnp.sum(jnp.square(jax.nn.logsumexp(logits, axis=-1))
                              * keep[:, 0]) / n_real})
 
+        dtype = self.dtype
+
+        def experts_of(rows):
+            with jax.named_scope("moe.experts"):
+                return grouped_ffn(
+                    rows, load,
+                    None if w_gate is None else w_gate.astype(dtype),
+                    w_in.astype(dtype), w_out.astype(dtype), self.act)
+
+        def plain(order):
+            """Every pair's row, in the sorted order: [S*k, .] arrays."""
+            with jax.named_scope("moe.dispatch"):
+                rows = xf.astype(dtype)[order // k]             # [S*k, d]
+            out = experts_of(rows)
+            with jax.named_scope("moe.combine"):
+                # Rows behind the last group are whatever the kernel left
+                # there: zeroed, not weighted.
+                in_group = jnp.arange(s * k) < jnp.sum(load)
+                out = jnp.where(in_group[:, None], out, 0)
+                back = jnp.zeros((s * k,), jnp.int32).at[order].set(
+                    jnp.arange(s * k, dtype=jnp.int32))
+                y = jnp.einsum("skd,sk->sd", out[back].reshape(s, k, d),
+                               weights.astype(dtype),
+                               preferred_element_type=jnp.float32)
+            return y.astype(dtype)
+
+        def compact(order):
+            """The held pairs alone: the sort is stable and every other
+            pair carries the largest key, so they are the first
+            ``sum(load)`` entries of ``order``, and ``capacity`` rows
+            hold them all.  [capacity, .] arrays, no [S*k, d] one."""
+            with jax.named_scope("moe.dispatch"):
+                head = order[:capacity]
+                token = head // k
+                rows = xf.astype(dtype)[token]              # [capacity, d]
+            out = experts_of(rows)
+            with jax.named_scope("moe.combine"):
+                in_group = jnp.arange(capacity) < jnp.sum(load)
+                out = jnp.where(in_group[:, None], out, 0)
+                # By token, as a matmul with [S, capacity], a row's
+                # weight at its token: S x capacity x d multiplications,
+                # 0.13 TFLOP at Kimi's 4,096 bucket.  (A scatter-add of
+                # the rows runs at 1.5 us a row on a v5e: the layer takes
+                # 8.26 ms with it and 5.23 with this; PERF.md, PR 43.)
+                weight = weights.astype(dtype).reshape(-1)[head]
+                place = jnp.where(jnp.arange(s)[:, None] == token[None, :],
+                                  weight[None, :], 0)
+                y = jnp.dot(place, out, preferred_element_type=jnp.float32)
+            return y.astype(dtype)
+
         with jax.named_scope("moe.dispatch"):
             order = jnp.argsort(experts.reshape(-1), stable=True)
-            rows = xf.astype(self.dtype)[order // k]            # [S*k, d]
-
-        with jax.named_scope("moe.experts"):
-            out = grouped_ffn(
-                rows, load,
-                None if w_gate is None else w_gate.astype(self.dtype),
-                w_in.astype(self.dtype), w_out.astype(self.dtype),
-                self.act)
-
-        with jax.named_scope("moe.combine"):
-            # Rows behind the last group are whatever the kernel left
-            # there: zeroed, not weighted.
-            in_group = jnp.arange(s * k) < jnp.sum(load)
-            out = jnp.where(in_group[:, None], out, 0)
-            back = jnp.zeros((s * k,), jnp.int32).at[order].set(
-                jnp.arange(s * k, dtype=jnp.int32))
-            y = jnp.einsum("skd,sk->sd", out[back].reshape(s, k, d),
-                           weights.astype(self.dtype),
-                           preferred_element_type=jnp.float32)
-        return y.astype(self.dtype).reshape(b, t, d)
+        y = plain(order) if capacity is None \
+            else jax.lax.cond(fits, compact, plain, order)
+        return y.reshape(b, t, d)
 
 
 def moe_layers(intermediates) -> List[Dict[str, jnp.ndarray]]:
@@ -230,17 +312,18 @@ def moe_losses(intermediates) -> Dict[str, jnp.ndarray]:
             "max_load_over_mean": worst}
 
 
-MOE_COUNTERS = ("pairs", "experts_hit", "max_load")
+MOE_COUNTERS = ("pairs", "experts_hit", "max_load", "compact")
 
 
 def moe_counters(intermediates) -> Optional[jnp.ndarray]:
-    """[layers, 3] int32, per layer ``MOE_COUNTERS``: the (real row,
-    expert) pairs, the experts with at least one row, the largest group:
-    what the engine fetches beside the logits.  None for a model without
-    experts."""
+    """[layers, 4] int32, per layer ``MOE_COUNTERS``: the (real row,
+    expert) pairs, the experts with at least one row, the largest group,
+    and 1 where the call took the compact branch: what the engine
+    fetches beside the logits.  None for a model without experts."""
     layers = moe_layers(intermediates)
     if not layers:
         return None
     return jnp.stack([
         jnp.stack([jnp.sum(m["load"]), jnp.sum(m["load"] > 0),
-                   jnp.max(m["load"])]) for m in layers]).astype(jnp.int32)
+                   jnp.max(m["load"]), m["compact"]])
+        for m in layers]).astype(jnp.int32)

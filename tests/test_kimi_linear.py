@@ -556,7 +556,7 @@ def test_a_spec_with_latent_pages_and_a_state_slot_builds_both_pools():
         jax.ShapeDtypeStruct((2,), jnp.int32))
     assert [o.shape for o in out[1:4]] == [
         kv["latent_pages"].shape, state["conv"].shape, state["ssm"].shape]
-    assert out[4].shape == (5, 3)           # the experts' counters
+    assert out[4].shape == (5, 4)           # the experts' counters
 
 
 # ----------------------------------- the comparison can tell right from wrong

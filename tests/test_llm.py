@@ -561,7 +561,7 @@ def test_a_window_alone_is_the_whole_donated_state_pool():
         a.size * a.dtype.itemsize for a in pools)
     # logits, the three pools, the routing counters of the 3 sparse layers
     assert [o.shape for o in jax.tree_util.tree_leaves(
-        compiled.out_info)][-1] == (3, 3)
+        compiled.out_info)][-1] == (3, 4)
 
 
 @pytest.mark.parametrize("shape", [(2, 1), (1, 32), (1, 8)],

@@ -163,8 +163,8 @@ def test_prefill_then_decode_equals_reference_with_padded_batch(params):
         np.testing.assert_allclose(np.stack(a), np.stack(b), atol=1e-6)
     np.testing.assert_array_equal(np.stack(counters), np.stack(counters4))
     # per layer: 2 real rows x top-2 = 4 pairs over 2 to 4 experts
-    pairs, hit, largest = np.moveaxis(np.stack(counters), -1, 0)
-    assert (pairs == 4).all()
+    pairs, hit, largest, compact = np.moveaxis(np.stack(counters), -1, 0)
+    assert (pairs == 4).all() and not compact.any()
     assert hit.shape == (4, 2) and (hit >= 2).all() and (hit <= 4).all()
     assert (largest >= 1).all() and (largest <= 2).all()
 

@@ -465,7 +465,9 @@ def test_granite_cell_updates_both_caches_in_place(one_chip, tokens_shape):
                                   r"\S* (copy|copy-done|transpose)\(", line))
               and hit.group(1) in shapes]
     assert not copies, (len(copies), copies[:4])
-    assert text.count('op_name="ragged-dot-metadata"') == cfg.n_layer
+    # (a prefill holds a share's two branches a layer, ops/moe.py)
+    assert text.count('op_name="ragged-dot-metadata"') == cfg.n_layer * (
+        1 if tokens_shape[1] == 1 else 2)
     kernels = re.findall(
         r"^\s*(?:ROOT )?%paged_decode[\w.]* = .*custom-call\(", text, re.M)
     assert len(kernels) == (1 if tokens_shape[1] == 1 else 0)
@@ -573,10 +575,11 @@ def test_kimi_cell_compiles_at_the_benchmarks_sizes(one_chip, tokens_shape):
         patch.setattr(attention, "_prefill_impl", lambda t: "flash")
         patch.setattr(ray_tpu.ops, "flash_attention", functools.partial(
             ray_tpu.ops.flash_attention, interpret=False))
-        compiled = jit_forward(row.module(cfg)).lower(
+        lowered = jit_forward(row.module(cfg)).lower(
             _on(params, one_chip), ints(tokens_shape),
             _on(kv["latent_pages"], one_chip),
-            ints((b, pages_for(4096, 16))), ints(tokens_shape)).compile()
+            ints((b, pages_for(4096, 16))), ints(tokens_shape))
+        compiled = lowered.compile()
     decode = tokens_shape[1] == 1
     assert _device_bytes(compiled) < (10.6e9 if decode else 12.5e9)
     m = compiled.memory_analysis()
@@ -584,7 +587,19 @@ def test_kimi_cell_compiles_at_the_benchmarks_sizes(one_chip, tokens_shape):
     assert m.alias_size_in_bytes == pool.size * pool.dtype.itemsize
     assert m.temp_size_in_bytes < (0.1e9 if decode else 1.5e9)
     text = compiled.as_text()
-    assert text.count('op_name="ragged-dot-metadata"') == cfg.n_moe_layers
+    # The experts' branch (ops/moe.py): none in the decode step (128
+    # pairs); in the prefill one a sparse layer, whose compact branch
+    # runs over 2,176 rows and holds no array of all 32,768 pairs' rows.
+    branches = re.findall(
+        r'"stablehlo\.case"\(.*?\) \(\{\n(.*?)\n\s*\}, \{\n(.*?)\n\s*\}\) :',
+        lowered.as_text(), re.S)
+    assert len(branches) == (0 if decode else cfg.n_moe_layers)
+    for plain, compact in branches:
+        assert "tensor<32768x7168xbf16>" in plain
+        assert "tensor<2176x7168xbf16>" in compact
+        assert not re.search(r"tensor<32768x\d+x", compact)
+    assert text.count('op_name="ragged-dot-metadata"') == \
+        cfg.n_moe_layers * (1 if decode else 2)
 
     def calls(kernel):
         return len(re.findall(
@@ -659,7 +674,8 @@ def test_kimi_linear_cell_compiles_at_the_benchmarks_sizes(one_chip,
                                         for a in pools)
     assert m.temp_size_in_bytes < (0.2e9 if decode else 1.0e9)
     text = compiled.as_text()
-    assert text.count('op_name="ragged-dot-metadata"') == cfg.n_moe_layers
+    assert text.count('op_name="ragged-dot-metadata"') == \
+        cfg.n_moe_layers * (1 if decode else 2)
     # no pass over the state pool but the in-place writes (a layer's slab
     # in a decode step, one row in a prefill: a dynamic-update-slice)
     shape = ",".join(map(str, state["ssm"].shape))
