@@ -18,20 +18,33 @@ models/kimi.py) and Kimi-Linear (``kimilinear``: Kimi Delta Attention, a
 gated delta rule with a decay per key channel whose state is a matrix a
 head, three layers in four, and Kimi-K2's latent attention with no
 position encoding in the fourth, over Kimi-K2's FFN,
-models/kimi_linear.py).  What more than one block is built from (RMSNorm, RoPE
-with or without YaRN, the conv over a slot's window) is in
-models/layers.py.  All models are flax.linen with
+models/kimi_linear.py).  All but GPT-2 are ONE decoder
+(models/decoder.py: the layer loop, the block, grouped-query attention
+around the core of models/attention.py, the FFN, the loss, the rules every
+tree shares) over a config; what more than one mixer is built from
+(RMSNorm, RoPE with or without YaRN, the conv over a slot's window, the
+init by leaf) is in models/layers.py.  All models are flax.linen with
 *logical* dimension names threaded through ray_tpu.parallel.sharding
 rules, so DP/FSDP/TP/CP layouts are a rules-table choice, not a model
 edit.
 
 ``MODEL_FAMILIES`` is the one table the engine (``llm/engine.py``) and
 the multi-host training plane (``train.distributed.rules_for_model``)
-resolve a family through.  An eighth family is a row here:
-its config class, module, init, loss, partition rules, a tiny preset for
-tests, and its cache spec (the module's ``__call__`` takes ``kv_cache=``
-/ ``positions=`` as GPT2's does, llm/kv_cache.py).  The cache spec
-(``CacheSpec``) says what one sequence keeps on the device between steps
+resolve a family through.  A ROW is a config class, its module, init,
+loss, partition rules, a tiny preset for tests, and its cache spec.  An
+eighth family is a config (published sizes, ``tiny``; ``layer_types``,
+one entry a layer; ``mixers``, which maps each entry to its KIND; what
+the FFN reads: ``n_dense_layers``, ``experts``, ``shared_d_ff``), the
+mixer it adds, and a row whose module is ``Decoder`` under its name.  A
+KIND (``decoder.Mixer``) says three things in one place: the module that
+computes the mixer (``(y, cache) -> out`` or ``(out, what it updated)``),
+the names it has in the tree, and what it keeps on the device between
+steps (which of ``k_pages`` / ``v_pages`` / ``latent_pages`` / ``conv`` /
+``ssm``, indexed through the page table or the row's slot, and the
+shapes).  The decoder's hand-over of the cache to each layer and the row's
+``CacheSpec`` (``decoder.cache_spec``) are both read from the kinds.
+
+The cache spec says what one sequence keeps on the device between steps
 and in which layers: how many layers hold K/V in the paged pool and at
 what width (``kv_layers`` x ``kv_heads`` x ``head_dim``: every layer for
 the first three families), and how many hold a recurrent state and its
@@ -55,13 +68,13 @@ Keys are normalized lowercase-no-separator ("gpt2", "llama", "olmoe",
 """
 
 from dataclasses import dataclass
-from typing import Any, Callable, Tuple
+from typing import Any, Callable
 
-from .granite import (Granite, GraniteConfig, granite_init,  # noqa: F401
-                      granite_loss_fn, granite_partition_rules)
-
+from .decoder import CacheSpec, cache_spec
 from .gpt2 import (GPT2, GPT2Config, gpt2_init, gpt2_loss_fn,  # noqa: F401
                    gpt2_partition_rules)
+from .granite import (Granite, GraniteConfig, granite_init,  # noqa: F401
+                      granite_loss_fn, granite_partition_rules)
 from .kimi import (KimiK2, KimiK2Config, kimi_k2_init,  # noqa: F401
                    kimi_k2_loss_fn, kimi_k2_partition_rules)
 from .kimi_linear import (KimiLinear, KimiLinearConfig,  # noqa: F401
@@ -72,55 +85,6 @@ from .lfm2 import (Lfm2, Lfm2Config, lfm2_init,  # noqa: F401
 from .llama import (Llama, LlamaConfig, llama_init,  # noqa: F401
                     llama_loss_fn, llama_partition_rules, olmoe_loss_fn,
                     olmoe_partition_rules)
-
-
-@dataclass(frozen=True)
-class CacheSpec:
-    """What one sequence keeps on the device, by layer kind."""
-    kv_layers: int                      # layers with K/V in the paged pool
-    kv_heads: int                       # heads the pool stores (grouped)
-    head_dim: int
-    state_layers: int = 0               # layers with a recurrent state
-    conv_shape: Tuple[int, ...] = ()    # one sequence, one layer (dtype)
-    ssm_shape: Tuple[int, ...] = ()     # the same, float32; (): none
-    latent_dim: int = 0                 # > 0: ONE latent row a position
-    rope_dim: int = 0                   # (c_kv | k_pe), no K/V pools
-
-    @property
-    def row_width(self) -> int:
-        """A latent row in the pool: whole tiles of 128 lanes."""
-        return -(-(self.latent_dim + self.rope_dim) // 128) * 128
-
-
-def _attention_only(kv_heads: Callable[[Any], int]):
-    return lambda cfg: CacheSpec(cfg.n_layer, kv_heads(cfg),
-                                 cfg.d_model // cfg.n_head)
-
-
-def _granite_cache(cfg: GraniteConfig) -> CacheSpec:
-    return CacheSpec(
-        cfg.layers_of("attention"), cfg.n_kv_head, cfg.head_dim,
-        cfg.layers_of("mamba"), (cfg.mamba_d_conv - 1, cfg.conv_dim),
-        (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state))
-
-
-def _lfm2_cache(cfg: Lfm2Config) -> CacheSpec:
-    return CacheSpec(
-        cfg.layers_of("full_attention"), cfg.n_kv_head, cfg.head_dim,
-        cfg.layers_of("conv"), (cfg.conv_taps - 1, cfg.d_model))
-
-
-def _kimi_k2_cache(cfg: KimiK2Config) -> CacheSpec:
-    return CacheSpec(cfg.n_layer, 0, 0, latent_dim=cfg.kv_lora_rank,
-                     rope_dim=cfg.qk_rope_head_dim)
-
-
-def _kimi_linear_cache(cfg: KimiLinearConfig) -> CacheSpec:
-    return CacheSpec(
-        cfg.layers_of("mla"), 0, 0, cfg.layers_of("kda"),
-        (cfg.kda_conv - 1, 3 * cfg.kda_dim),
-        (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim),
-        latent_dim=cfg.kv_lora_rank, rope_dim=cfg.qk_rope_head_dim)
 
 
 @dataclass(frozen=True)
@@ -137,26 +101,27 @@ class ModelFamily:
 MODEL_FAMILIES = {
     "gpt2": ModelFamily(GPT2Config, GPT2, gpt2_init, gpt2_loss_fn,
                         gpt2_partition_rules, GPT2Config.tiny,
-                        _attention_only(lambda cfg: cfg.n_head)),
+                        lambda cfg: CacheSpec(cfg.n_layer, cfg.n_head,
+                                              cfg.d_model // cfg.n_head)),
     "llama": ModelFamily(LlamaConfig, Llama, llama_init, llama_loss_fn,
                          llama_partition_rules, LlamaConfig.tiny,
-                         _attention_only(lambda cfg: cfg.n_kv_head)),
+                         cache_spec),
     "olmoe": ModelFamily(LlamaConfig, Llama, llama_init, olmoe_loss_fn,
                          olmoe_partition_rules, LlamaConfig.olmoe_tiny,
-                         _attention_only(lambda cfg: cfg.n_kv_head)),
+                         cache_spec),
     "granitemoehybrid": ModelFamily(
         GraniteConfig, Granite, granite_init, granite_loss_fn,
-        granite_partition_rules, GraniteConfig.tiny, _granite_cache),
+        granite_partition_rules, GraniteConfig.tiny, cache_spec),
     "lfm2moe": ModelFamily(Lfm2Config, Lfm2, lfm2_init, lfm2_loss_fn,
                            lfm2_partition_rules, Lfm2Config.tiny,
-                           _lfm2_cache),
+                           cache_spec),
     "kimik2": ModelFamily(KimiK2Config, KimiK2, kimi_k2_init,
                           kimi_k2_loss_fn, kimi_k2_partition_rules,
-                          KimiK2Config.tiny, _kimi_k2_cache),
+                          KimiK2Config.tiny, cache_spec),
     "kimilinear": ModelFamily(
         KimiLinearConfig, KimiLinear, kimi_linear_init,
         kimi_linear_loss_fn, kimi_linear_partition_rules,
-        KimiLinearConfig.tiny, _kimi_linear_cache),
+        KimiLinearConfig.tiny, cache_spec),
 }
 
 

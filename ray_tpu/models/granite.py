@@ -34,6 +34,11 @@ nothing: its conv window is dropped, and its state, read from the index
 clipped into the pool, goes back as it was read.  A forward whose first
 position is 0 starts from a zero state whatever its slot held: that is
 how a slot is cleared for the sequence that takes it.
+
+The layer loop, the block, the attention wrapper and the FFN are
+``models/decoder.py``'s; this file is the config, the mixer with its scan
+and step, its kind (``MIXERS``: the names in the tree, what a layer
+keeps) and the init.
 """
 
 from __future__ import annotations
@@ -46,11 +51,11 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as PS
 
-from ..parallel.sharding import with_logical_constraint as _constrain
-from .attention import attention
+from .decoder import (Attention, Decoder, Mixer, attention_kind,
+                      decoder_rules, next_token_loss)
 from .layers import RMSNorm, init_by_leaf, slot_conv
-from .llama import _next_token_xent
 
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -140,18 +145,22 @@ class GraniteConfig:
                                + self.mamba_n_heads) \
             + self.d_inner * self.d_model
 
-    def flops_per_token(self) -> float:
-        """Training FLOPs a token: 6 x the matmul parameters a token
-        passes through (its k experts, not all of them)."""
-        attn = 2 * self.d_model * (self.n_head + self.n_kv_head) \
-            * self.head_dim
-        ffn = 3 * self.d_model * (self.d_ff * self.experts_per_token
-                                  + self.shared_d_ff) \
-            + self.d_model * self.n_experts
-        n = self.vocab_size * self.d_model + self.n_layer * ffn \
-            + self.layers_of(MAMBA) * self.mixer_params() \
-            + self.layers_of(ATTENTION) * attn
-        return 6.0 * n
+    # What ``models/decoder.py`` reads besides the fields: the kinds, the
+    # FFN (experts and the shared one in every layer), the tied head.
+    n_dense_layers = 0
+    tied_head = True
+
+    @property
+    def mixers(self):
+        return MIXERS
+
+    @property
+    def experts(self):
+        """``ops/moe.py MoEMLP``'s arguments."""
+        return dict(d_ff=self.d_ff, num_experts=self.n_experts,
+                    top_k=self.experts_per_token,
+                    first_expert=self.first_expert,
+                    held_experts=self.held_experts)
 
 
 # ------------------------------------------------------------ the mixer
@@ -363,127 +372,26 @@ class Mamba2Mixer(nn.Module):
         return out if cache is None else (out, (conv_pool, ssm_pool))
 
 
-class GraniteAttention(nn.Module):
-    cfg: GraniteConfig
-
-    @nn.compact
-    def __call__(self, y, cache=None):
-        cfg = self.cfg
-        h, hk, dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-        b, t = y.shape[0], y.shape[1]
-        init = nn.initializers.normal(0.02)
-        with jax.named_scope("attn.qkv"):       # no position encoding
-            q, k, v = (nn.Dense(heads * dh, use_bias=False, dtype=cfg.dtype,
-                                kernel_init=init, name=name)(y)
-                       .reshape(b, t, heads, dh)
-                       for name, heads in (("wq", h), ("wk", hk),
-                                           ("wv", hk)))
-        att, new_cache = attention(cfg, q, k, v, cache,
-                                   scale=cfg.attention_multiplier)
-        with jax.named_scope("attn.out"):
-            out = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                           kernel_init=init,
-                           name="wo")(att.reshape(b, t, h * dh))
-        return out, new_cache
+class Granite(Decoder):
+    """``models/decoder.py Decoder`` over a GraniteConfig, the contract
+    of GPT2.__call__ with one more kind of cache: ``k_pages`` /
+    ``v_pages`` [attention layers, pages, page, h_kv*d]; ``conv``
+    [state-space layers, slots, d_conv-1, conv_dim], ``ssm`` [state-space
+    layers, slots, H, P, N] float32 and ``slots`` [B] (each row's slot;
+    outside the pool: a padded row); all carried whole through the
+    layers."""
 
 
-class GraniteBlock(nn.Module):
-    cfg: GraniteConfig
-    kind: str
-
-    @nn.compact
-    def __call__(self, x, cache=None):
-        """``cache`` is the attention core's (an attention layer) or the
-        mixer's (a state-space layer); returns x, or (x, what the layer
-        updated)."""
-        from ..ops.moe import MoEMLP
-
-        cfg = self.cfg
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mixer_norm")(x)
-        if self.kind == ATTENTION:
-            m, new = GraniteAttention(cfg, name="attn")(y, cache)
-        else:
-            m = Mamba2Mixer(cfg, name="mamba")(y, cache)
-            new = None
-            if cache is not None:
-                m, new = m
-        x = x + (cfg.residual_multiplier * m).astype(x.dtype)
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
-        positions = cache["positions"] if cache is not None else None
-        with jax.named_scope("mlp"):
-            routed = MoEMLP(
-                d_model=cfg.d_model, d_ff=cfg.d_ff,
-                num_experts=cfg.n_experts, top_k=cfg.experts_per_token,
-                gated=True, norm_topk_prob=True, act=nn.silu,
-                dtype=cfg.dtype, first_expert=cfg.first_expert,
-                held_experts=cfg.held_experts, name="moe")(
-                    y, None if positions is None else positions >= 0)
-            with jax.named_scope("moe.shared"):
-                init = nn.initializers.normal(0.02)
-                gate, up = (nn.Dense(cfg.shared_d_ff, use_bias=False,
-                                     dtype=cfg.dtype, kernel_init=init,
-                                     name=name)(y)
-                            for name in ("shared_gate", "shared_up"))
-                z = _constrain(nn.silu(gate) * up,
-                               ("batch", "seq", "mlp"), cfg.mesh)
-                shared = nn.Dense(cfg.d_model, use_bias=False,
-                                  dtype=cfg.dtype, kernel_init=init,
-                                  name="shared_down")(z)
-            x = x + (cfg.residual_multiplier * (routed + shared)
-                     ).astype(x.dtype)
-        return x if cache is None else (x, new)
-
-
-class Granite(nn.Module):
-    cfg: GraniteConfig
-
-    @nn.compact
-    def __call__(self, tokens, kv_cache=None, positions=None):
-        """Full forward (kv_cache=None) or a step against the caches,
-        the contract of GPT2.__call__ with one more kind of cache:
-        ``k_pages`` / ``v_pages`` [attention layers, pages, page,
-        h_kv*d]; ``conv`` [state-space layers, slots, d_conv-1,
-        conv_dim], ``ssm`` [state-space layers, slots, H, P, N] float32
-        and ``slots`` [B] (each row's slot; outside the pool: a padded
-        row); all carried whole through the layers.  Returns (logits,
-        the cache updated)."""
-        cfg = self.cfg
-        cached = kv_cache is not None
-        emb = self.param("embed", nn.initializers.normal(0.02),
-                         (cfg.vocab_size, cfg.d_model), jnp.float32)
-        with jax.named_scope("embed"):
-            x = emb.astype(cfg.dtype)[tokens] * jnp.asarray(
-                cfg.embedding_multiplier, cfg.dtype)
-            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
-        block = GraniteBlock
-        if cfg.remat and not cached:
-            block = nn.remat(GraniteBlock, prevent_cse=False)
-        if cached:
-            new = dict(kv_cache)
-        seen = {MAMBA: 0, ATTENTION: 0}
-        for i, kind in enumerate(cfg.layer_types):
-            blk = block(cfg, kind, name=f"layer_{i}")
-            if not cached:
-                x = blk(x)
-            elif kind == ATTENTION:
-                x, (new["k_pages"], new["v_pages"]) = blk(x, cache={
-                    "k_pages": new["k_pages"], "v_pages": new["v_pages"],
-                    "layer": seen[kind], "page_table": new["page_table"],
-                    "positions": positions})
-            else:
-                x, (new["conv"], new["ssm"]) = blk(x, cache={
-                    "conv": new["conv"], "ssm": new["ssm"],
-                    "layer": seen[kind], "slots": new["slots"],
-                    "positions": positions})
-            seen[kind] += 1
-            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
-        with jax.named_scope("lm_head"):        # tied to the embedding
-            logits = jnp.einsum("btd,vd->btv", x, emb.astype(cfg.dtype),
-                                preferred_element_type=jnp.float32) \
-                / cfg.logits_scaling
-            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg.mesh)
-        return (logits, new) if cached else logits
+MIXERS = {
+    MAMBA: Mixer(
+        Mamba2Mixer, "mamba", ("conv", "ssm"), lambda cfg: {
+            "conv_shape": (cfg.mamba_d_conv - 1, cfg.conv_dim),
+            "ssm_shape": (cfg.mamba_n_heads, cfg.mamba_d_head,
+                          cfg.mamba_d_state)}),
+    # no position encoding; the scores scaled by ``attention_multiplier``
+    ATTENTION: attention_kind(lambda cfg, name: Attention(
+        cfg, rope=False, scale=cfg.attention_multiplier, name=name)),
+}
 
 
 # ------------------------------------------------------ init, loss, rules
@@ -541,27 +449,15 @@ def granite_init(cfg: GraniteConfig, rng):
                         functools.partial(_special_leaf, cfg))
 
 
-def granite_loss_fn(cfg: GraniteConfig, params, batch):
-    """Mean next-token cross entropy (the source's config names no router
-    loss coefficient, so there is none)."""
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    return _next_token_xent(Granite(cfg).apply(params, inputs), targets)
+# (the source's config names no router loss coefficient, so there is none)
+granite_loss_fn = functools.partial(next_token_loss, Granite)
 
 
 def granite_partition_rules():
-    """fsdp + tensor rules for Granite trees: the mixer's projections as
-    a column- then a row-parallel pair, the experts as OLMoE's, every
-    expert on every chip (an ``expert`` mesh axis is ROADMAP Reach's)."""
-    from jax.sharding import PartitionSpec as PS
-
-    return (
-        ("embed$", PS("tensor", "fsdp")),
-        (r"moe/(w_gate|w_up)$", PS(None, "fsdp", "tensor")),
-        (r"moe/w_down$", PS(None, "tensor", "fsdp")),
-        (r"moe/router$", PS("fsdp", None)),
-        (r"(w[qkv]|in_proj|shared_gate|shared_up)/kernel$",
-         PS("fsdp", "tensor")),
-        (r"(wo|out_proj|shared_down)/kernel$", PS("tensor", "fsdp")),
-        (r"(scale|bias|conv_w|conv_b|A_log|D|dt_bias)$", PS()),
-    )
+    """``models/decoder.py decoder_rules`` after the mixer's own: its
+    projections a column- then a row-parallel pair, its small leaves
+    whole."""
+    return decoder_rules(
+        (r"in_proj/kernel$", PS("fsdp", "tensor")),
+        (r"out_proj/kernel$", PS("tensor", "fsdp")),
+        (r"(conv_w|conv_b|A_log|D|dt_bias)$", PS()))

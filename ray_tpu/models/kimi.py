@@ -44,8 +44,10 @@ may hold a share of its experts (``first_expert``, ``held_experts``:
 ``MLAttention`` has two options that ``models/kimi_linear.py``'s latent
 layers take: ``q_lora_rank=None`` (ONE matrix ``wq`` makes the queries: no
 ``wq_a``, norm or ``wq_b``) and ``mla_use_nope`` (NO rotation of ``q_pe`` and
-``k_pe``, and ``s = (d_n + d_r) ** -0.5`` alone); the FFN of a block is
-``block_ffn``, which both families' blocks call.
+``k_pe``, and ``s = (d_n + d_r) ** -0.5`` alone).  The layer loop, the
+block and the FFN are ``models/decoder.py``'s; this file is the config,
+the latent attention (``MLA_KIND``: what it is called in the tree and what
+it keeps) and the init.
 
 With a cache the contract is the other families' with ONE pool:
 ``latent_pages`` [layers, pages, page, row], carried whole through the
@@ -61,14 +63,26 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as PS
 
-from ..parallel.sharding import with_logical_constraint as _constrain
 from .attention import latent_attention
+from .decoder import Decoder, Mixer, decoder_rules, next_token_loss
 from .layers import RMSNorm, _rope, init_by_leaf, yarn_mscale
-from .llama import _next_token_xent
 
+MLA = "mla"                 # every layer's kind
 ROUTE_NORM_EPS = 1e-20      # in the sum of the chosen experts' scores
 EXPERT_BIAS_STD = 0.005     # how init draws ``expert_bias``
+
+
+def routed_experts(cfg) -> dict:
+    """``ops/moe.py MoEMLP``'s arguments for a config with Kimi-K2's FFN
+    names (``models/kimi_linear.py``'s too)."""
+    return dict(d_ff=cfg.moe_d_ff, num_experts=cfg.n_experts,
+                top_k=cfg.experts_per_token, scoring="sigmoid",
+                select_bias=True, norm_eps=ROUTE_NORM_EPS,
+                routed_scaling_factor=cfg.routed_scaling_factor,
+                first_expert=cfg.first_expert,
+                held_experts=cfg.held_experts)
 
 
 @dataclass(frozen=True)
@@ -168,18 +182,19 @@ class KimiK2Config:
         ``q_lora_rank`` is None), in parameters."""
         return mla_params(self)
 
-    def flops_per_token(self) -> float:
-        """Training FLOPs a token: 6 x the matmul parameters a token
-        passes through (its k experts and the shared one, not all)."""
-        sparse = 3 * self.d_model * self.moe_d_ff * (
-            self.experts_per_token + self.n_shared_experts) \
-            + self.d_model * self.n_experts
-        dense = min(self.n_dense_layers, self.n_layer)
-        n = self.vocab_size * self.d_model \
-            + dense * 3 * self.d_model * self.d_ff \
-            + self.n_moe_layers * sparse \
-            + self.n_layer * self.attention_params()
-        return 6.0 * n
+    # What ``models/decoder.py`` reads besides the fields: the layers'
+    # one kind, and the sparse layers' FFN.
+    @property
+    def layer_types(self):
+        return (MLA,) * self.n_layer
+
+    @property
+    def mixers(self):
+        return MIXERS
+
+    experts = property(routed_experts)
+    shared_d_ff = property(lambda self: self.moe_d_ff
+                           * self.n_shared_experts)
 
 
 def mla_params(cfg) -> int:
@@ -238,103 +253,17 @@ class MLAttention(nn.Module):
         return out, pages
 
 
-def _swiglu(cfg, y, width: int, names):
-    init = nn.initializers.normal(0.02)
-    gate, up = (nn.Dense(width, use_bias=False, dtype=cfg.dtype,
-                         kernel_init=init, name=name)(y)
-                for name in names[:2])
-    z = _constrain(nn.silu(gate) * up, ("batch", "seq", "mlp"), cfg.mesh)
-    return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                    kernel_init=init, name=names[2])(z)
+class KimiK2(Decoder):
+    """``models/decoder.py Decoder`` over a KimiK2Config: a step runs
+    against the latent pool (``kv_cache`` = {"latent_pages" [layers,
+    pages, page, row], "page_table"}, ``positions`` [B, T])."""
 
 
-def block_ffn(cfg, x, y, dense: bool, positions=None):
-    """``x + ffn(y)`` inside the calling block (the submodules are the
-    caller's): a dense SwiGLU of ``d_ff``, or the routed experts with the
-    shared one beside them.  ``positions`` [B, T] (< 0: padding, kept from
-    the experts) or None."""
-    with jax.named_scope("mlp"):
-        if dense:
-            with jax.named_scope("mlp.dense"):
-                down = _swiglu(cfg, y, cfg.d_ff,
-                               ("w_gate", "w_up", "w_down"))
-        else:
-            from ..ops.moe import MoEMLP
-
-            down = MoEMLP(
-                d_model=cfg.d_model, d_ff=cfg.moe_d_ff,
-                num_experts=cfg.n_experts, top_k=cfg.experts_per_token,
-                gated=True, norm_topk_prob=True, scoring="sigmoid",
-                select_bias=True, norm_eps=ROUTE_NORM_EPS,
-                routed_scaling_factor=cfg.routed_scaling_factor,
-                act=nn.silu, dtype=cfg.dtype,
-                first_expert=cfg.first_expert,
-                held_experts=cfg.held_experts, name="moe")(
-                    y, None if positions is None else positions >= 0)
-            with jax.named_scope("moe.shared"):
-                down = down + _swiglu(
-                    cfg, y, cfg.moe_d_ff * cfg.n_shared_experts,
-                    ("shared_gate", "shared_up", "shared_down"))
-        return x + down.astype(x.dtype)
-
-
-class KimiK2Block(nn.Module):
-    cfg: KimiK2Config
-    dense: bool
-
-    @nn.compact
-    def __call__(self, x, cache=None):
-        """Returns x, or with a ``cache`` (x, the latent pool updated)."""
-        cfg = self.cfg
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="attn_norm")(x)
-        m, pages = MLAttention(cfg, name="attn")(y, cache)
-        x = x + m.astype(x.dtype)
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
-        x = block_ffn(cfg, x, y, self.dense,
-                      cache["positions"] if cache is not None else None)
-        return x if cache is None else (x, pages)
-
-
-class KimiK2(nn.Module):
-    cfg: KimiK2Config
-
-    @nn.compact
-    def __call__(self, tokens, kv_cache=None, positions=None):
-        """Full forward (kv_cache=None) or a step against the latent pool
-        (``kv_cache`` = {"latent_pages" [layers, pages, page, row],
-        "page_table"}, ``positions`` [B, T]): returns logits, or (logits,
-        the cache updated)."""
-        cfg = self.cfg
-        cached = kv_cache is not None
-        init = nn.initializers.normal(0.02)
-        emb = self.param("embed", init, (cfg.vocab_size, cfg.d_model),
-                         jnp.float32)
-        with jax.named_scope("embed"):
-            x = emb.astype(cfg.dtype)[tokens]
-            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
-        block = KimiK2Block
-        if cfg.remat and not cached:
-            block = nn.remat(KimiK2Block, prevent_cse=False)
-        if cached:
-            new = dict(kv_cache)
-        for i in range(cfg.n_layer):
-            blk = block(cfg, i < cfg.n_dense_layers, name=f"layer_{i}")
-            if not cached:
-                x = blk(x)
-            else:
-                x, new["latent_pages"] = blk(x, cache={
-                    "latent_pages": new["latent_pages"], "layer": i,
-                    "page_table": new["page_table"],
-                    "positions": positions})
-            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
-        head = self.param("lm_head", init, (cfg.d_model, cfg.vocab_size),
-                          jnp.float32)
-        with jax.named_scope("lm_head"):        # untied
-            logits = jnp.einsum("btd,dv->btv", x, head.astype(cfg.dtype),
-                                preferred_element_type=jnp.float32)
-            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg.mesh)
-        return (logits, new) if cached else logits
+# What a latent layer keeps: ONE row a position, ``c_kv | k_pe``.
+MLA_KIND = Mixer(MLAttention, "attn", ("latent_pages",), lambda cfg: {
+    "latent_dim": cfg.kv_lora_rank, "rope_dim": cfg.qk_rope_head_dim},
+    norm="attn_norm")
+MIXERS = {MLA: MLA_KIND}
 
 
 # ------------------------------------------------------ init, loss, rules
@@ -359,32 +288,15 @@ def kimi_k2_init(cfg: KimiK2Config, rng):
                         functools.partial(_special_leaf, cfg))
 
 
-def kimi_k2_loss_fn(cfg: KimiK2Config, params, batch):
-    """Mean next-token cross entropy (the source balances its experts
-    through ``expert_bias``; its sequence-wise auxiliary loss has no
-    weight in the published config and is left out)."""
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    return _next_token_xent(KimiK2(cfg).apply(params, inputs), targets)
+# (the source balances its experts through ``expert_bias``; its
+# sequence-wise auxiliary loss has no weight in the published config)
+kimi_k2_loss_fn = functools.partial(next_token_loss, KimiK2)
 
 
 def kimi_k2_partition_rules():
-    """fsdp + tensor rules for Kimi-K2 trees: the low-rank projections
-    column-parallel into their heads, ``wo`` and the down projections
-    row-parallel, the experts as OLMoE's, every expert on every chip."""
-    from jax.sharding import PartitionSpec as PS
-
-    return (
-        ("embed$", PS("tensor", "fsdp")),
-        ("lm_head$", PS("fsdp", "tensor")),
-        (r"moe/(w_gate|w_up)$", PS(None, "fsdp", "tensor")),
-        (r"moe/w_down$", PS(None, "tensor", "fsdp")),
-        (r"moe/router$", PS("fsdp", None)),
+    """``models/decoder.py decoder_rules`` after the latent attention's
+    own: the low-rank projections column-parallel into their heads."""
+    return decoder_rules(
         (r"(wq_a|wkv_a)/kernel$", PS("fsdp", None)),
         (r"wq_b/kernel$", PS("fsdp", "tensor")),
-        (r"wkv_b$", PS("fsdp", "tensor")),
-        (r"(w_gate|w_up|shared_gate|shared_up)/kernel$",
-         PS("fsdp", "tensor")),
-        (r"(wo|w_down|shared_down)/kernel$", PS("tensor", "fsdp")),
-        (r"(scale|expert_bias)$", PS()),
-    )
+        (r"wkv_b$", PS("fsdp", "tensor")))

@@ -6,7 +6,7 @@ them, multi-head LATENT attention with NO position encoding
 (``models/kimi.py MLAttention`` with ``q_lora_rank`` None and
 ``mla_use_nope``); the FFN a dense SwiGLU in the first ``n_dense_layers``
 and after them a shared expert beside experts routed by sigmoid scores and
-a selection bias (``models/kimi.py block_ffn``, ``ops/moe.py``).  No bias
+a selection bias (``models/decoder.py ffn``, ``ops/moe.py``).  No bias
 anywhere; the head is untied.
 
 Layer ``l``: ``h = x + mixer_l(RMSNorm(x))``; ``y = h + ffn_l(RMSNorm(h))``.
@@ -50,6 +50,7 @@ from a zero state and a zero window whatever its slot held.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from dataclasses import dataclass
@@ -58,13 +59,13 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as PS
 
-from ..parallel.sharding import with_logical_constraint as _constrain
-from .kimi import EXPERT_BIAS_STD, MLAttention, block_ffn, mla_params
+from .decoder import Decoder, Mixer, decoder_rules, next_token_loss
+from .kimi import EXPERT_BIAS_STD, MLA, MLA_KIND, mla_params, routed_experts
 from .layers import RMSNorm, init_by_leaf, slot_conv
-from .llama import _next_token_xent
 
-KDA, MLA = "kda", "mla"
+KDA = "kda"
 L2_EPS = 1e-6               # under the L2 norms of q and k
 
 
@@ -174,19 +175,15 @@ class KimiLinearConfig:
         """One latent layer's four matrices, in parameters."""
         return mla_params(self)
 
-    def flops_per_token(self) -> float:
-        """Training FLOPs a token: 6 x the matmul parameters a token
-        passes through (its k experts and the shared one, not all)."""
-        sparse = 3 * self.d_model * self.moe_d_ff * (
-            self.experts_per_token + self.n_shared_experts) \
-            + self.d_model * self.n_experts
-        dense = min(self.n_dense_layers, self.n_layer)
-        n = self.vocab_size * self.d_model \
-            + dense * 3 * self.d_model * self.d_ff \
-            + self.n_moe_layers * sparse \
-            + self.layers_of(KDA) * self.mixer_params() \
-            + self.layers_of(MLA) * self.attention_params()
-        return 6.0 * n
+    # What ``models/decoder.py`` reads besides the fields: the kinds, and
+    # the sparse layers' FFN (Kimi-K2's).
+    @property
+    def mixers(self):
+        return MIXERS
+
+    experts = property(routed_experts)
+    shared_d_ff = property(lambda self: self.moe_d_ff
+                           * self.n_shared_experts)
 
 
 # ------------------------------------------------------- the delta rule
@@ -445,79 +442,19 @@ class KDAMixer(nn.Module):
         return out if cache is None else (out, (conv_pool, ssm_pool))
 
 
-class KimiLinearBlock(nn.Module):
-    cfg: KimiLinearConfig
-    kind: str
-    dense: bool
-
-    @nn.compact
-    def __call__(self, x, cache=None):
-        """``cache`` is the latent layer's or the mixer's; returns x, or
-        (x, what the layer updated)."""
-        cfg = self.cfg
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mixer_norm")(x)
-        if self.kind == MLA:
-            m, new = MLAttention(cfg, name="attn")(y, cache)
-        else:
-            m = KDAMixer(cfg, name="kda")(y, cache)
-            new = None
-            if cache is not None:
-                m, new = m
-        x = x + m.astype(x.dtype)
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
-        x = block_ffn(cfg, x, y, self.dense,
-                      cache["positions"] if cache is not None else None)
-        return x if cache is None else (x, new)
+class KimiLinear(Decoder):
+    """``models/decoder.py Decoder`` over a KimiLinearConfig: a step runs
+    against BOTH caches (``kv_cache`` = {"latent_pages", "page_table",
+    "conv", "ssm", "slots"}, ``positions`` [B, T]; the module docstring
+    has the shapes)."""
 
 
-class KimiLinear(nn.Module):
-    cfg: KimiLinearConfig
-
-    @nn.compact
-    def __call__(self, tokens, kv_cache=None, positions=None):
-        """Full forward (kv_cache=None) or a step against BOTH caches
-        (``kv_cache`` = {"latent_pages", "page_table", "conv", "ssm",
-        "slots"}, ``positions`` [B, T]; the module docstring has the
-        shapes): returns logits, or (logits, the cache updated)."""
-        cfg = self.cfg
-        cached = kv_cache is not None
-        init = nn.initializers.normal(0.02)
-        emb = self.param("embed", init, (cfg.vocab_size, cfg.d_model),
-                         jnp.float32)
-        with jax.named_scope("embed"):
-            x = emb.astype(cfg.dtype)[tokens]
-            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
-        block = KimiLinearBlock
-        if cfg.remat and not cached:
-            block = nn.remat(KimiLinearBlock, prevent_cse=False)
-        if cached:
-            new = dict(kv_cache)
-        seen = {KDA: 0, MLA: 0}
-        for i, kind in enumerate(cfg.layer_types):
-            blk = block(cfg, kind, i < cfg.n_dense_layers,
-                        name=f"layer_{i}")
-            if not cached:
-                x = blk(x)
-            elif kind == MLA:
-                x, new["latent_pages"] = blk(x, cache={
-                    "latent_pages": new["latent_pages"],
-                    "layer": seen[kind], "page_table": new["page_table"],
-                    "positions": positions})
-            else:
-                x, (new["conv"], new["ssm"]) = blk(x, cache={
-                    "conv": new["conv"], "ssm": new["ssm"],
-                    "layer": seen[kind], "slots": new["slots"],
-                    "positions": positions})
-            seen[kind] += 1
-            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
-        head = self.param("lm_head", init, (cfg.d_model, cfg.vocab_size),
-                          jnp.float32)
-        with jax.named_scope("lm_head"):        # untied
-            logits = jnp.einsum("btd,dv->btv", x, head.astype(cfg.dtype),
-                                preferred_element_type=jnp.float32)
-            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg.mesh)
-        return (logits, new) if cached else logits
+MIXERS = {
+    KDA: Mixer(KDAMixer, "kda", ("conv", "ssm"), lambda cfg: {
+        "conv_shape": (cfg.kda_conv - 1, 3 * cfg.kda_dim),
+        "ssm_shape": (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim)}),
+    MLA: dataclasses.replace(MLA_KIND, norm="mixer_norm"),
+}
 
 
 # ------------------------------------------------------ init, loss, rules
@@ -553,33 +490,18 @@ def kimi_linear_init(cfg: KimiLinearConfig, rng):
                         functools.partial(_special_leaf, cfg))
 
 
-def kimi_linear_loss_fn(cfg: KimiLinearConfig, params, batch):
-    """Mean next-token cross entropy (the source balances its experts
-    through the selection bias; no auxiliary loss has a weight in the
-    published config)."""
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    return _next_token_xent(KimiLinear(cfg).apply(params, inputs), targets)
+# (the source balances its experts through the selection bias; no
+# auxiliary loss has a weight in the published config)
+kimi_linear_loss_fn = functools.partial(next_token_loss, KimiLinear)
 
 
 def kimi_linear_partition_rules():
-    """fsdp + tensor rules for Kimi-Linear trees: the mixer's and the
-    latent layers' projections column-parallel into their heads, ``wo``
-    and the down projections row-parallel, the low-rank gates' first
-    halves whole, the experts as OLMoE's, every expert on every chip."""
-    from jax.sharding import PartitionSpec as PS
-
-    return (
-        ("embed$", PS("tensor", "fsdp")),
-        ("lm_head$", PS("fsdp", "tensor")),
-        (r"moe/(w_gate|w_up)$", PS(None, "fsdp", "tensor")),
-        (r"moe/w_down$", PS(None, "tensor", "fsdp")),
-        (r"moe/router$", PS("fsdp", None)),
+    """``models/decoder.py decoder_rules`` after the mixer's and the
+    latent layers' own: the low-rank gates' first halves whole on
+    ``tensor``, their second halves column-parallel into the heads (as
+    ``wq`` / ``wk`` / ``wv`` are), the mixer's small leaves whole."""
+    return decoder_rules(
         (r"(wkv_a|f_a|g_a|wb)/kernel$", PS("fsdp", None)),
-        (r"(wq|wk|wv|f_b|g_b)/kernel$", PS("fsdp", "tensor")),
+        (r"(f_b|g_b)/kernel$", PS("fsdp", "tensor")),
         (r"wkv_b$", PS("fsdp", "tensor")),
-        (r"(w_gate|w_up|shared_gate|shared_up)/kernel$",
-         PS("fsdp", "tensor")),
-        (r"(wo|w_down|shared_down)/kernel$", PS("tensor", "fsdp")),
-        (r"(scale|expert_bias|conv_w|A_log|dt_bias)$", PS()),
-    )
+        (r"(conv_w|A_log|dt_bias)$", PS()))

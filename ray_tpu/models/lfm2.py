@@ -31,6 +31,10 @@ pool for the attention layers, the state pool for the mixers, each row's
 slot read and written where it lies, both carried whole through the
 layers; a position < 0 is padding and a slot index outside the pool a
 padded row.
+
+The layer loop, the block, the attention wrapper and the FFN are
+``models/decoder.py``'s; this file is the config, the mixer, its kind
+(``MIXERS``: the names in the tree, what a layer keeps) and the init.
 """
 
 from __future__ import annotations
@@ -42,11 +46,11 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as PS
 
-from ..parallel.sharding import with_logical_constraint as _constrain
-from .attention import attention
-from .layers import RMSNorm, _rope, init_by_leaf, slot_conv
-from .llama import _next_token_xent
+from .decoder import (Attention, Decoder, Mixer, attention_kind,
+                      decoder_rules, next_token_loss)
+from .layers import init_by_leaf, slot_conv
 
 CONV, ATTENTION = "conv", "full_attention"
 ROUTE_NORM_EPS = 1e-6       # in the sum of the chosen experts' scores
@@ -121,20 +125,22 @@ class Lfm2Config:
         return 4 * self.d_model * self.d_model \
             + self.conv_taps * self.d_model
 
-    def flops_per_token(self) -> float:
-        """Training FLOPs a token: 6 x the matmul parameters a token
-        passes through (its k experts, not all of them)."""
-        attn = 2 * self.d_model * (self.n_head + self.n_kv_head) \
-            * self.head_dim
-        sparse = 3 * self.d_model * self.moe_d_ff * self.experts_per_token \
-            + self.d_model * self.n_experts
-        dense = min(self.n_dense_layers, self.n_layer)
-        n = self.vocab_size * self.d_model \
-            + dense * 3 * self.d_model * self.d_ff \
-            + self.n_moe_layers * sparse \
-            + self.layers_of(CONV) * self.mixer_params() \
-            + self.layers_of(ATTENTION) * attn
-        return 6.0 * n
+    # What ``models/decoder.py`` reads besides the fields: the kinds, the
+    # experts of the layers after the dense ones, the tied head.
+    tied_head = True
+
+    @property
+    def mixers(self):
+        return MIXERS
+
+    @property
+    def experts(self):
+        """``ops/moe.py MoEMLP``'s arguments."""
+        return dict(d_ff=self.moe_d_ff, num_experts=self.n_experts,
+                    top_k=self.experts_per_token, scoring="sigmoid",
+                    select_bias=True, norm_eps=ROUTE_NORM_EPS,
+                    first_expert=self.first_expert,
+                    held_experts=self.held_experts)
 
 
 class ShortConvMixer(nn.Module):
@@ -172,132 +178,20 @@ class ShortConvMixer(nn.Module):
         return out if cache is None else (out, pool)
 
 
-class Lfm2Attention(nn.Module):
-    cfg: Lfm2Config
-
-    @nn.compact
-    def __call__(self, y, cache=None):
-        cfg = self.cfg
-        h, hk, dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-        b, t = y.shape[0], y.shape[1]
-        init = nn.initializers.normal(0.02)
-        positions = cache["positions"] if cache is not None else None
-        with jax.named_scope("attn.qkv"):
-            q, k, v = (nn.Dense(heads * dh, use_bias=False, dtype=cfg.dtype,
-                                kernel_init=init, name=name)(y)
-                       .reshape(b, t, heads, dh)
-                       for name, heads in (("wq", h), ("wk", hk),
-                                           ("wv", hk)))
-            with jax.named_scope("attn.qk_norm"):   # over each head's own
-                q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
-                k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
-            q = _rope(q, cfg.rope_theta, positions)
-            k = _rope(k, cfg.rope_theta, positions)
-        att, new_cache = attention(cfg, q, k, v, cache)
-        with jax.named_scope("attn.out"):
-            out = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                           kernel_init=init,
-                           name="wo")(att.reshape(b, t, h * dh))
-        return out, new_cache
+class Lfm2(Decoder):
+    """``models/decoder.py Decoder`` over an Lfm2Config, the contract of
+    Granite's with a state pool of one array: ``k_pages`` / ``v_pages``
+    [attention layers, pages, page, h_kv*d]; ``conv`` [short-conv layers,
+    slots, taps-1, d] and ``slots`` [B]; all carried whole through the
+    layers."""
 
 
-class Lfm2Block(nn.Module):
-    cfg: Lfm2Config
-    kind: str
-    dense: bool
-
-    @nn.compact
-    def __call__(self, x, cache=None):
-        """``cache`` is the attention core's (an attention layer) or the
-        mixer's (a short-conv layer); returns x, or (x, what the layer
-        updated)."""
-        cfg = self.cfg
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mixer_norm")(x)
-        if self.kind == ATTENTION:
-            m, new = Lfm2Attention(cfg, name="attn")(y, cache)
-        else:
-            m = ShortConvMixer(cfg, name="conv")(y, cache)
-            new = None
-            if cache is not None:
-                m, new = m
-        x = x + m.astype(x.dtype)
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
-        positions = cache["positions"] if cache is not None else None
-        with jax.named_scope("mlp"):
-            if self.dense:
-                with jax.named_scope("mlp.dense"):
-                    init = nn.initializers.normal(0.02)
-                    gate, up = (nn.Dense(cfg.d_ff, use_bias=False,
-                                         dtype=cfg.dtype, kernel_init=init,
-                                         name=name)(y)
-                                for name in ("w_gate", "w_up"))
-                    z = _constrain(nn.silu(gate) * up,
-                                   ("batch", "seq", "mlp"), cfg.mesh)
-                    down = nn.Dense(cfg.d_model, use_bias=False,
-                                    dtype=cfg.dtype, kernel_init=init,
-                                    name="w_down")(z)
-            else:
-                from ..ops.moe import MoEMLP
-
-                down = MoEMLP(
-                    d_model=cfg.d_model, d_ff=cfg.moe_d_ff,
-                    num_experts=cfg.n_experts, top_k=cfg.experts_per_token,
-                    gated=True, norm_topk_prob=True, scoring="sigmoid",
-                    select_bias=True, norm_eps=ROUTE_NORM_EPS,
-                    act=nn.silu, dtype=cfg.dtype,
-                    first_expert=cfg.first_expert,
-                    held_experts=cfg.held_experts, name="moe")(
-                        y, None if positions is None else positions >= 0)
-            x = x + down.astype(x.dtype)
-        return x if cache is None else (x, new)
-
-
-class Lfm2(nn.Module):
-    cfg: Lfm2Config
-
-    @nn.compact
-    def __call__(self, tokens, kv_cache=None, positions=None):
-        """Full forward (kv_cache=None) or a step against the caches, the
-        contract of Granite.__call__ with a state pool of one array:
-        ``k_pages`` / ``v_pages`` [attention layers, pages, page,
-        h_kv*d]; ``conv`` [short-conv layers, slots, taps-1, d] and
-        ``slots`` [B]; all carried whole through the layers.  Returns
-        (logits, the cache updated)."""
-        cfg = self.cfg
-        cached = kv_cache is not None
-        emb = self.param("embed", nn.initializers.normal(0.02),
-                         (cfg.vocab_size, cfg.d_model), jnp.float32)
-        with jax.named_scope("embed"):
-            x = emb.astype(cfg.dtype)[tokens]
-            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
-        block = Lfm2Block
-        if cfg.remat and not cached:
-            block = nn.remat(Lfm2Block, prevent_cse=False)
-        if cached:
-            new = dict(kv_cache)
-        seen = {CONV: 0, ATTENTION: 0}
-        for i, kind in enumerate(cfg.layer_types):
-            blk = block(cfg, kind, i < cfg.n_dense_layers,
-                        name=f"layer_{i}")
-            if not cached:
-                x = blk(x)
-            elif kind == ATTENTION:
-                x, (new["k_pages"], new["v_pages"]) = blk(x, cache={
-                    "k_pages": new["k_pages"], "v_pages": new["v_pages"],
-                    "layer": seen[kind], "page_table": new["page_table"],
-                    "positions": positions})
-            else:
-                x, new["conv"] = blk(x, cache={
-                    "conv": new["conv"], "layer": seen[kind],
-                    "slots": new["slots"], "positions": positions})
-            seen[kind] += 1
-            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
-        with jax.named_scope("lm_head"):        # tied to the embedding
-            logits = jnp.einsum("btd,vd->btv", x, emb.astype(cfg.dtype),
-                                preferred_element_type=jnp.float32)
-            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg.mesh)
-        return (logits, new) if cached else logits
+MIXERS = {
+    CONV: Mixer(ShortConvMixer, "conv", ("conv",), lambda cfg: {
+        "conv_shape": (cfg.conv_taps - 1, cfg.d_model)}),
+    # RoPE, after an RMSNorm over each head's own q and k
+    ATTENTION: attention_kind(functools.partial(Attention, qk_norm="head")),
+}
 
 
 # ------------------------------------------------------ init, loss, rules
@@ -332,26 +226,14 @@ def lfm2_init(cfg: Lfm2Config, rng):
                         functools.partial(_special_leaf, cfg))
 
 
-def lfm2_loss_fn(cfg: Lfm2Config, params, batch):
-    """Mean next-token cross entropy (the source balances its experts
-    through ``expert_bias``, not through a loss)."""
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    return _next_token_xent(Lfm2(cfg).apply(params, inputs), targets)
+# (the source balances its experts through ``expert_bias``, not a loss)
+lfm2_loss_fn = functools.partial(next_token_loss, Lfm2)
 
 
 def lfm2_partition_rules():
-    """fsdp + tensor rules for LFM2 trees: the mixer's projections and
-    the dense FFN as column- then row-parallel pairs, the experts as
-    OLMoE's, every expert on every chip."""
-    from jax.sharding import PartitionSpec as PS
-
-    return (
-        ("embed$", PS("tensor", "fsdp")),
-        (r"moe/(w_gate|w_up)$", PS(None, "fsdp", "tensor")),
-        (r"moe/w_down$", PS(None, "tensor", "fsdp")),
-        (r"moe/router$", PS("fsdp", None)),
-        (r"(w[qkv]|in_proj|w_gate|w_up)/kernel$", PS("fsdp", "tensor")),
-        (r"(wo|out_proj|w_down)/kernel$", PS("tensor", "fsdp")),
-        (r"(scale|conv_w|expert_bias)$", PS()),
-    )
+    """``models/decoder.py decoder_rules`` after the mixer's own: its
+    projections a column- then a row-parallel pair, the taps whole."""
+    return decoder_rules(
+        (r"in_proj/kernel$", PS("fsdp", "tensor")),
+        (r"out_proj/kernel$", PS("tensor", "fsdp")),
+        ("conv_w$", PS()))

@@ -7,21 +7,23 @@ Covers the reference's Llama fine-tune workloads (ref: release/train_tests
 LLM configs) natively.  Same logical-axis discipline as gpt2.py, and the
 same attention core (models/attention.py): grouped KV heads are stored
 grouped in the paged cache and repeated to the query heads for the
-full forward.
+full forward.  The layer loop, the block, that attention's wrapper and
+the FFN are ``models/decoder.py``'s; this file is the config and the row.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Optional
 
-import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
-from ..parallel.sharding import with_logical_constraint as _constrain
-from .attention import attention
-from .layers import RMSNorm, _rope, init_by_leaf
+from .decoder import (Decoder, _next_token_xent, attention_kind,
+                      decoder_rules, gqa, next_token_loss)
+from .layers import _rope, init_by_leaf  # noqa: F401 (_rope: a fault tool's)
+
+ATTENTION = "attention"     # every layer's kind
 
 
 @dataclass(frozen=True)
@@ -83,6 +85,28 @@ class LlamaConfig:
                            n_kv_head=32, d_model=4096, d_ff=11008,
                            max_seq=4096)
 
+    # What ``models/decoder.py`` reads: the layers' kinds, and the FFN.
+    @property
+    def layer_types(self):
+        return (ATTENTION,) * self.n_layer
+
+    @property
+    def mixers(self):
+        return MIXERS
+
+    @property
+    def n_dense_layers(self) -> int:
+        return 0 if self.n_experts else self.n_layer
+
+    @property
+    def experts(self):
+        """``ops/moe.py MoEMLP``'s arguments (None: every FFN dense)."""
+        if not self.n_experts:
+            return None
+        return dict(d_ff=self.d_ff, num_experts=self.n_experts,
+                    top_k=self.experts_per_token,
+                    norm_topk_prob=self.norm_topk_prob)
+
     def _ffn_params_per_token(self) -> int:
         """Matmul weights one token passes through in a block's FFN: the
         dense SwiGLU, or its k experts and the router."""
@@ -122,114 +146,18 @@ class LlamaConfig:
         return 2.0 * matmul_params + attn
 
 
-class LlamaBlock(nn.Module):
-    cfg: LlamaConfig
-
-    @nn.compact
-    def __call__(self, x, cache=None):
-        cfg = self.cfg
-        h, hk = cfg.n_head, cfg.n_kv_head
-        d_head = cfg.d_model // h
-        b, t = x.shape[0], x.shape[1]
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="attn_norm")(x)
-        init = nn.initializers.normal(0.02)
-        positions = cache["positions"] if cache is not None else None
-        # Scope names as in models/gpt2.py (metadata only).
-        with jax.named_scope("attn.qkv"):
-            q = nn.Dense(h * d_head, use_bias=False, dtype=cfg.dtype,
-                         kernel_init=init, name="wq")(y)
-            k = nn.Dense(hk * d_head, use_bias=False, dtype=cfg.dtype,
-                         kernel_init=init, name="wk")(y)
-            v = nn.Dense(hk * d_head, use_bias=False, dtype=cfg.dtype,
-                         kernel_init=init,
-                         name="wv")(y).reshape(b, t, hk, d_head)
-            if cfg.qk_norm:     # over the whole width, before the split
-                with jax.named_scope("attn.qk_norm"):
-                    q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
-                    k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
-            q = _rope(q.reshape(b, t, h, d_head), cfg.rope_theta,
-                      positions)
-            k = _rope(k.reshape(b, t, hk, d_head), cfg.rope_theta,
-                      positions)
-        # The cache stores the hk GROUPED heads (post-RoPE); the full
-        # forward repeats them to h.
-        att, new_cache = attention(cfg, q, k, v, cache)
-        with jax.named_scope("attn.out"):
-            att = att.reshape(b, t, cfg.d_model)
-            att = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                           kernel_init=init, name="wo")(att)
-            x = x + att
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
-        with jax.named_scope("mlp"):
-            if cfg.n_experts:
-                from ..ops.moe import MoEMLP
-
-                down = MoEMLP(
-                    d_model=cfg.d_model, d_ff=cfg.d_ff,
-                    num_experts=cfg.n_experts,
-                    top_k=cfg.experts_per_token, gated=True,
-                    norm_topk_prob=cfg.norm_topk_prob, act=nn.silu,
-                    dtype=cfg.dtype, name="moe")(
-                        y, None if positions is None else positions >= 0)
-            else:
-                gate = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
-                                kernel_init=init, name="w_gate")(y)
-                up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
-                              kernel_init=init, name="w_up")(y)
-                z = nn.silu(gate) * up
-                z = _constrain(z, ("batch", "seq", "mlp"), cfg.mesh)
-                down = nn.Dense(cfg.d_model, use_bias=False,
-                                dtype=cfg.dtype, kernel_init=init,
-                                name="w_down")(z)
-            out = x + down
-        return out if new_cache is None else (out, new_cache)
+class Llama(Decoder):
+    """``models/decoder.py Decoder`` over a LlamaConfig: every layer
+    grouped-query attention with RoPE (OLMoE: a QK-norm over the width),
+    its leaves the layer's own (``layer_i/wq``), and a dense SwiGLU or
+    OLMoE's experts; the head untied.  ``k_pages`` / ``v_pages`` are [L,
+    pages, page, h_kv*d] (the GROUPED heads, folded)."""
 
 
-class Llama(nn.Module):
-    cfg: LlamaConfig
-
-    @nn.compact
-    def __call__(self, tokens, kv_cache=None, positions=None):
-        """Full forward (kv_cache=None) or incremental decode step
-        against the paged KV pool — same contract as GPT2.__call__:
-        ``k_pages`` / ``v_pages`` are [L, pages, page, h_kv*d] (the
-        GROUPED heads, folded), carried whole through the layers;
-        decode mode returns (logits, new_kv_cache)."""
-        cfg = self.cfg
-        decode = kv_cache is not None
-        emb = self.param("embed", nn.initializers.normal(0.02),
-                         (cfg.vocab_size, cfg.d_model), jnp.float32)
-        with jax.named_scope("embed"):
-            x = emb.astype(cfg.dtype)[tokens]
-            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
-        block = LlamaBlock
-        if cfg.remat and not decode:
-            block = nn.remat(LlamaBlock, prevent_cse=False)
-        if decode:
-            # ONE pool through every layer, updated where it lies.
-            k_pages, v_pages = kv_cache["k_pages"], kv_cache["v_pages"]
-        for i in range(cfg.n_layer):
-            blk = block(cfg, name=f"layer_{i}")
-            if decode:
-                x, (k_pages, v_pages) = blk(
-                    x, cache={"k_pages": k_pages, "v_pages": v_pages,
-                              "layer": i,
-                              "page_table": kv_cache["page_table"],
-                              "positions": positions})
-            else:
-                x = blk(x)
-            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
-        head = self.param("lm_head", nn.initializers.normal(0.02),
-                          (cfg.d_model, cfg.vocab_size), jnp.float32)
-        with jax.named_scope("lm_head"):
-            logits = jnp.einsum("btd,dv->btv", x, head.astype(cfg.dtype),
-                                preferred_element_type=jnp.float32)
-            logits = _constrain(logits, ("batch", "seq", "vocab"), cfg.mesh)
-        if decode:
-            return logits, {"k_pages": k_pages, "v_pages": v_pages,
-                            "page_table": kv_cache["page_table"]}
-        return logits
+MIXERS = {ATTENTION: attention_kind(
+    lambda cfg, name: functools.partial(
+        gqa, cfg, qk_norm="width" if cfg.qk_norm else None),
+    None, norm="attn_norm", residual_scope="attn.out")}
 
 
 def llama_init(cfg: LlamaConfig, rng):
@@ -239,18 +167,7 @@ def llama_init(cfg: LlamaConfig, rng):
     return init_by_leaf(Llama, cfg, rng)
 
 
-def _next_token_xent(logits, targets):
-    with jax.named_scope("loss"):
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None],
-                                 axis=-1)[..., 0]
-        return -jnp.mean(ll)
-
-
-def llama_loss_fn(cfg: LlamaConfig, params, batch):
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    return _next_token_xent(Llama(cfg).apply(params, inputs), targets)
+llama_loss_fn = functools.partial(next_token_loss, Llama)
 
 
 def olmoe_loss_fn(cfg: LlamaConfig, params, batch,
@@ -276,31 +193,6 @@ def olmoe_loss_fn(cfg: LlamaConfig, params, batch,
     return loss, {"ce": ce, **{f"moe_{k}": v for k, v in moe.items()}}
 
 
-def llama_partition_rules():
-    """Default fsdp+tensor partition rules for Llama param trees
-    (``match_partition_rules`` form; see ``gpt2_partition_rules``)."""
-    from jax.sharding import PartitionSpec as PS
-
-    return (
-        ("embed$", PS("tensor", "fsdp")),
-        ("lm_head$", PS("fsdp", "tensor")),
-        (r"w[qkv]/kernel$", PS("fsdp", "tensor")),
-        (r"wo/kernel$", PS("tensor", "fsdp")),
-        (r"(w_gate|w_up)/kernel$", PS("fsdp", "tensor")),
-        (r"w_down/kernel$", PS("tensor", "fsdp")),
-        (r"(scale|bias)$", PS()),
-    )
-
-
-def olmoe_partition_rules():
-    """Llama's rules and the experts': every expert on every chip, its
-    matrices sharded over fsdp x tensor on their ``d`` and ``f``
-    dimensions (experts over an ``expert`` mesh axis is ROADMAP
-    Reach 5's)."""
-    from jax.sharding import PartitionSpec as PS
-
-    return (
-        (r"moe/(w_gate|w_up)$", PS(None, "fsdp", "tensor")),
-        (r"moe/w_down$", PS(None, "tensor", "fsdp")),
-        (r"moe/router$", PS("fsdp", None)),
-    ) + llama_partition_rules()
+# A Llama tree has no leaf of its own, and OLMoE's experts are among the
+# rules every decoder's tree shares.
+llama_partition_rules = olmoe_partition_rules = decoder_rules

@@ -1,0 +1,113 @@
+"""The six rows over ``models/decoder.py``: each keeps the tree and the
+cache it had as a model of its own (PR 43, the parent of the PR that
+merged them), and runs its layers through the one loop."""
+
+import dataclasses
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.llm.kv_cache import pool_arrays, state_arrays
+from ray_tpu.models import MODEL_FAMILIES, CacheSpec, decoder
+
+# row -> (leaves, sha256[:16] of the tiny tree's "path shape dtype" lines;
+# the CacheSpec at the tiny preset; at the published config), all read
+# from PR 43's tree: the first from ``init(tiny)``, the other two from its
+# ``_attention_only`` / ``_granite_cache`` / ``_lfm2_cache`` /
+# ``_kimi_k2_cache`` / ``_kimi_linear_cache``.
+PARENT = {
+    "llama": ((21, "f4d3b8bd3974719c"),
+              (2, 2, 32, 0, (), (), 0, 0),
+              (32, 32, 128, 0, (), (), 0, 0)),
+    "olmoe": ((27, "28eca959d73e0c8a"),
+              (2, 4, 16, 0, (), (), 0, 0),
+              (16, 16, 128, 0, (), (), 0, 0)),
+    "granitemoehybrid": ((66, "49d6c577b0121f3e"),
+                         (1, 2, 16, 3, (3, 96), (4, 16, 16), 0, 0),
+                         (4, 8, 128, 36, (3, 8448), (128, 64, 128), 0, 0)),
+    "lfm2moe": ((51, "894a6148ae826e5a"),
+                (1, 2, 16, 4, (2, 64), (), 0, 0),
+                (10, 8, 64, 30, (2, 2048), (), 0, 0)),
+    "kimik2": ((49, "f418233d911bc179"),
+               (3, 0, 0, 0, (), (), 24, 8),
+               (61, 0, 0, 0, (), (), 512, 64)),
+    "kimilinear": ((120, "129a7fc464d44d8f"),
+                   (2, 0, 0, 4, (3, 192), (4, 16, 16), 24, 8),
+                   (7, 0, 0, 20, (3, 12288), (32, 128, 128), 512, 64)),
+}
+ROWS = sorted(PARENT)
+
+
+def _published(name):
+    config = MODEL_FAMILIES[name].config
+    if name in ("llama", "olmoe"):
+        return {"llama": config.llama2_7b, "olmoe": config.olmoe_1b_7b}[
+            name]()
+    return config()
+
+
+@pytest.mark.parametrize("name", ROWS)
+def test_the_tiny_tree_is_the_parents(name):
+    """Every leaf's path, shape and dtype: what checkpoints, the
+    benchmark's references and its loaders walk by name."""
+    fam = MODEL_FAMILIES[name]
+    shapes = jax.eval_shape(
+        lambda: fam.init(fam.tiny(), jax.random.PRNGKey(0)))
+    lines = [
+        "/".join(str(getattr(p, "key", p)) for p in path)
+        + f" {tuple(leaf.shape)} {leaf.dtype}"
+        for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]]
+    digest = hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16]
+    assert (len(lines), digest) == PARENT[name][0], lines
+
+
+@pytest.mark.parametrize("name", ROWS)
+def test_the_cache_spec_comes_from_the_layer_kinds(name):
+    fam = MODEL_FAMILIES[name]
+    assert fam.cache is decoder.cache_spec
+    for cfg, want in zip((fam.tiny(), _published(name)), PARENT[name][1:]):
+        assert fam.cache(cfg) == CacheSpec(*want)
+        # what the spec counts is what the kinds say they keep
+        kept = {array for kind in cfg.layer_types
+                for array in cfg.mixers[kind].keeps}
+        spec = fam.cache(cfg)
+        assert kept == set(pool_arrays(spec) + state_arrays(spec))
+
+
+@pytest.mark.parametrize("name", ROWS)
+def test_the_rows_module_runs_its_layers_through_the_decoder(
+        name, monkeypatch):
+    """The module is ``Decoder`` under the family's name (the name is in
+    every operation's path), with no loop or block of its own: each layer
+    is one ``Block`` of the layer's kind, dense FFNs first."""
+    fam = MODEL_FAMILIES[name]
+    assert issubclass(fam.module, decoder.Decoder)
+    assert "__call__" not in vars(fam.module)
+    cfg = dataclasses.replace(fam.tiny(), remat=False)
+    built = []
+    real = decoder.Block.__call__
+
+    def spy(self, x, cache=None):
+        built.append((self.name, self.kind, self.dense))
+        return real(self, x, cache)
+
+    monkeypatch.setattr(decoder.Block, "__call__", spy)
+    jax.eval_shape(fam.module(cfg).init, jax.random.PRNGKey(0),
+                   jnp.zeros((1, 8), jnp.int32))
+    assert built == [(f"layer_{i}", kind, i < cfg.n_dense_layers)
+                     for i, kind in enumerate(cfg.layer_types)]
+
+
+def test_the_hooks_that_left_with_the_wrappers_fail_loud():
+    """Two names the operator's fault tools patch named code that is now
+    shared (``granite_faults`` ``rotary``: ``granite.attention``;
+    ``lfm2_faults`` ``no_qk_norm``: ``lfm2.RMSNorm``).  Neither is left
+    behind as a dead import: patching it raises AttributeError and does
+    not inject nothing in silence (ROADMAP Design 5(c))."""
+    import ray_tpu.models.granite as granite
+    import ray_tpu.models.lfm2 as lfm2
+
+    assert not hasattr(granite, "attention")
+    assert not hasattr(lfm2, "RMSNorm")
