@@ -197,12 +197,13 @@ class _Flight:
     ``[max_batch]`` (and the program's routing counters) still on the
     device, and whose each row is."""
 
-    __slots__ = ("kind", "ids", "moe", "rows", "admitted")
+    __slots__ = ("kind", "ids", "moe", "residual", "rows", "admitted")
 
-    def __init__(self, kind: str, ids, moe, rows,
+    def __init__(self, kind: str, ids, counters, rows,
                  admitted: Optional[float] = None):
         self.kind = kind                # "decode" or "prefill"
-        self.ids, self.moe = ids, moe
+        self.ids = ids
+        self.moe, self.residual = counters  # ``_call_fwd``'s
         self.rows: List[tuple] = rows   # (sequence, its row of ``ids``)
         # When a FIRST admission's prefill began: its delivery is the
         # request's first token (TTFT's prefill phase ends there).
@@ -236,10 +237,13 @@ def jit_forward(model):
     slot ``[B]``.  Returns the logits, the paged pool's arrays, then the
     state pool's.  A model with experts returns one more output, its
     routing counters ([layers with experts, 4] int32, ops/moe.py
-    ``moe_counters``)."""
+    ``moe_counters``), and one whose config has a residual kind one after
+    that: what its maps sowed (float32, models/decoder.py
+    ``residual_counters``)."""
     import jax
 
     from ..models import family_of
+    from ..models.decoder import residual_counters
     from ..ops.moe import moe_counters
     from .kv_cache import pool_arrays, state_arrays
 
@@ -255,8 +259,10 @@ def jit_forward(model):
             p, tokens, kv_cache=cache, positions=positions,
             mutable=["intermediates"])
         out = (logits,) + tuple(new[name] for name in pools + held)
-        moe = moe_counters(sown.get("intermediates", {}))
-        return out if moe is None else out + (moe,)
+        sown = sown.get("intermediates", {})
+        return out + tuple(c for c in (moe_counters(sown),
+                                       residual_counters(sown))
+                           if c is not None)
 
     # The parameters' names are part of the compiled program's text (and
     # of the compile cache's key): the K/V families keep theirs.
@@ -345,6 +351,13 @@ class GenerationEngine:
                 * np.dtype(model_cfg.param_dtype).itemsize}
 
         self._fwd = jit_forward(self._model)
+        # A config with a residual kind (models/decoder.py Residual):
+        # what the kind says of itself and, from the LAST delivered
+        # program, what its maps sowed (stats()["residual"]); else None.
+        kind = getattr(model_cfg, "residual", None)
+        self._residual: Optional[Dict[str, Any]] = kind and {
+            **kind.describe(model_cfg),
+            "max_row_sum_err": None, "max_col_sum_err": None}
         self._sampler, self._last_rows = jit_sampler(self.cfg.max_batch)
         # Each row's latest token id, [max_batch, 1] int32, on the
         # device from the first feed on: what a decode step takes as its
@@ -640,6 +653,12 @@ class GenerationEngine:
                 # layer runs that took the experts' compact branch.
                 **({"moe_prefill": dict(self._moe["prefill"])}
                    if self._moe["prefill"] else {}),
+                # Of a residual path that is not one stream (absent
+                # otherwise): streams, sublayers, the Sinkhorn's rounds,
+                # and how far the last program's stream maps lay from
+                # doubly stochastic over its live rows.
+                **({"residual": dict(self._residual)}
+                   if self._residual else {}),
                 # The second kind of cache (absent for a model without
                 # recurrent layers).
                 **({"state": {"slots_total": self.slots.slots,
@@ -797,7 +816,8 @@ class GenerationEngine:
     def _call_fwd(self, kind: str, tokens, table, positions, slots):
         """The forward of this token shape (``llm_decode``, or
         ``llm_prefill[bucket]``) over the caches, which it updates:
-        returns (logits, the routing counters or None).  ``slots`` is
+        returns (logits, (the routing counters or None, the residual
+        kind's or None)).  ``slots`` is
         each row's slot of the state pool (a row without a sequence:
         the index outside the pool), taken by a model that has one."""
         name = f"llm_{kind}[{tokens.shape[1]}]" \
@@ -813,7 +833,8 @@ class GenerationEngine:
             n = len(self._state)
             self._state = dict(zip(self._state, rest[:n]))
             rest = rest[n:]
-        return logits, (rest[0] if rest else None)
+        residual = rest.pop() if self._residual else None
+        return logits, (rest[0] if rest else None, residual)
 
     def _call(self, fn, name: str, *args):
         """Dispatch a jitted function through the AOT executable of this
@@ -919,6 +940,11 @@ class GenerationEngine:
             ids = np.asarray(flight.ids).tolist()   # [max_batch] int32
             per_layer = None if flight.moe is None \
                 else np.asarray(flight.moe)         # [layers, 4]
+            if flight.residual is not None:
+                row, col = np.asarray(flight.residual).tolist()
+                with self._lock:
+                    self._residual.update(max_row_sum_err=row,
+                                          max_col_sum_err=col)
         self._flights.popleft()
         with self._phase(leaves + ".sample"):
             if per_layer is not None:
@@ -988,7 +1014,7 @@ class GenerationEngine:
                               np.int32)
             feed_to[0] = seq.slot
         with self._phase("llm.prefill.run"):
-            logits, moe = self._call_fwd(
+            logits, counters = self._call_fwd(
                 "prefill", tokens, table, positions,
                 np.asarray([seq.slot], np.int32))
             ids = self._call(
@@ -1004,7 +1030,7 @@ class GenerationEngine:
         with self._lock:
             self._running.append(seq)
         self._launched(seq)
-        self._launch(_Flight("prefill", ids, moe, flight_rows,
+        self._launch(_Flight("prefill", ids, counters, flight_rows,
                              t_admit if first_admission else None))
 
     def _decode_step(self) -> None:
@@ -1045,15 +1071,15 @@ class GenerationEngine:
             flight_rows = [(seq, seq.slot) for seq in batch]
             sampling = self._pack_sampling(flight_rows)
         with self._phase("llm.decode.run"):
-            logits, moe = self._call_fwd("decode", self._tokens, table,
-                                         positions, slots)
+            logits, counters = self._call_fwd(
+                "decode", self._tokens, table, positions, slots)
             ids = self._call(self._sampler, "llm_sample", logits,
                              *sampling)
             self._feed(ids, self._all_rows)
         for seq in batch:
             seq.n_cached += 1
             self._launched(seq)
-        self._launch(_Flight("decode", ids, moe, flight_rows))
+        self._launch(_Flight("decode", ids, counters, flight_rows))
 
     def _ensure_pages(self, evict: bool) -> bool:
         """A KV slot for every running sequence's next position; False

@@ -1,6 +1,6 @@
 """ray_tpu.models — TPU-first reference model families.
 
-Seven families run through both the trainer and the serving engine:
+Eight families run through both the trainer and the serving engine:
 GPT-2 (pretrain baseline, BASELINE.json headline metric), Llama
 (RoPE/GQA/SwiGLU), OLMoE (the Llama block with QK-norm and dropless
 top-k sparse experts, ops/moe.py), Granite 4.0-H (``granitemoehybrid``:
@@ -18,7 +18,11 @@ models/kimi.py) and Kimi-Linear (``kimilinear``: Kimi Delta Attention, a
 gated delta rule with a decay per key channel whose state is a matrix a
 head, three layers in four, and Kimi-K2's latent attention with no
 position encoding in the fourth, over Kimi-K2's FFN,
-models/kimi_linear.py).  All but GPT-2 are ONE decoder
+models/kimi_linear.py) and Xing4.0 (``xing40``: Kimi-K2's layer with the
+residual path changed: FOUR streams a token, which every sublayer reads,
+writes and mixes through maps it computes from them, a sigmoid read map, a
+write map and a Sinkhorn-normalised 4 x 4 stream map: manifold-constrained
+hyper-connections, models/xing.py).  All but GPT-2 are ONE decoder
 (models/decoder.py: the layer loop, the block, grouped-query attention
 around the core of models/attention.py, the FFN, the loss, the rules every
 tree shares) over a config; what more than one mixer is built from
@@ -31,11 +35,15 @@ edit.
 ``MODEL_FAMILIES`` is the one table the engine (``llm/engine.py``) and
 the multi-host training plane (``train.distributed.rules_for_model``)
 resolve a family through.  A ROW is a config class, its module, init,
-loss, partition rules, a tiny preset for tests, and its cache spec.  An
-eighth family is a config (published sizes, ``tiny``; ``layer_types``,
+loss, partition rules, a tiny preset for tests, and its cache spec.  A
+ninth family is a config (published sizes, ``tiny``; ``layer_types``,
 one entry a layer; ``mixers``, which maps each entry to its KIND; what
-the FFN reads: ``n_dense_layers``, ``experts``, ``shared_d_ff``), the
-mixer it adds, and a row whose module is ``Decoder`` under its name.  A
+the FFN reads: ``n_dense_layers``, ``experts``, ``shared_d_ff``; and,
+where the layers hand one another more than one stream, ``residual``, the
+RESIDUAL kind, ``decoder.Residual``: how the state begins and ends and how
+a sublayer reads and writes it), the kind it adds, and a row whose module
+is ``Decoder`` under its name (a config may extend another row's:
+``family_of`` takes the row of the config's own class first).  A
 KIND (``decoder.Mixer``) says three things in one place: the module that
 computes the mixer (``(y, cache) -> out`` or ``(out, what it updated)``),
 the names it has in the tree, and what it keeps on the device between
@@ -64,7 +72,7 @@ builds both pools from the spec
 (``llm/kv_cache.py init_pool`` / ``init_state``), and of each the
 arrays the spec has and nothing else.
 Keys are normalized lowercase-no-separator ("gpt2", "llama", "olmoe",
-"granitemoehybrid", "lfm2moe", "kimik2", "kimilinear").
+"granitemoehybrid", "lfm2moe", "kimik2", "kimilinear", "xing40").
 """
 
 from dataclasses import dataclass
@@ -85,6 +93,8 @@ from .lfm2 import (Lfm2, Lfm2Config, lfm2_init,  # noqa: F401
 from .llama import (Llama, LlamaConfig, llama_init,  # noqa: F401
                     llama_loss_fn, llama_partition_rules, olmoe_loss_fn,
                     olmoe_partition_rules)
+from .xing import (Xing, XingConfig, xing_init,  # noqa: F401
+                   xing_loss_fn, xing_partition_rules)
 
 
 @dataclass(frozen=True)
@@ -122,13 +132,18 @@ MODEL_FAMILIES = {
         KimiLinearConfig, KimiLinear, kimi_linear_init,
         kimi_linear_loss_fn, kimi_linear_partition_rules,
         KimiLinearConfig.tiny, cache_spec),
+    "xing40": ModelFamily(XingConfig, Xing, xing_init, xing_loss_fn,
+                          xing_partition_rules, XingConfig.tiny,
+                          cache_spec),
 }
 
 
 def family_of(model_cfg) -> ModelFamily:
-    """The (first) row whose config class ``model_cfg`` is an instance
-    of."""
-    for fam in MODEL_FAMILIES.values():
+    """The (first) row whose config class is ``model_cfg``'s own, else
+    the first it is an instance of (a config may extend another row's:
+    ``XingConfig`` is Kimi-K2's fields and more)."""
+    rows = list(MODEL_FAMILIES.values())
+    for fam in [f for f in rows if type(model_cfg) is f.config] + rows:
         if isinstance(model_cfg, fam.config):
             return fam
     raise TypeError(f"unsupported model_cfg {type(model_cfg)}: no row "

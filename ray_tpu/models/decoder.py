@@ -1,13 +1,21 @@
 """ONE decoder for the serving families (Llama and OLMoE, Granite 4.0-H,
-LFM2-MoE, Kimi-K2, Kimi-Linear): a layer is a MIXER kind plus an FFN kind,
-and an architecture is a config and the mixer it adds (its module, its
-scan and step, its init and the rules of its own leaves stay in its file).
+LFM2-MoE, Kimi-K2, Kimi-Linear, Xing4.0): a layer is a MIXER kind plus an
+FFN kind, joined by a RESIDUAL kind, and an architecture is a config and
+the kind it adds (its module, its scan and step, its init and the rules of
+its own leaves stay in its file).
 
 ``Decoder(cfg)`` holds the only loop over layers outside ``gpt2.py``:
 embed, one ``Block`` (``norm -> mixer -> + -> norm -> FFN -> +``) per
 entry of ``cfg.layer_types``, ``norm_f``, the head.  What it reads of a
 config, and nothing of a family's name:
 
+- ``residual`` (``Residual``; a config WITHOUT the attribute, every family
+  but one, carries ONE stream ``x`` [B, T, d] and each sublayer adds its
+  branch to it: the block above, program for program): what the layers
+  hand one another, how it begins after ``embed`` and ends before
+  ``norm_f``, and how each sublayer reads its input from it and writes its
+  output back (``models/xing.py``: four streams a token, read, written and
+  mixed through maps a sublayer computes from them);
 - ``layer_types``: one entry a layer, each a key of ``mixers``;
 - ``mixers``: ``{layer type: Mixer}``.  A ``Mixer`` says in one place the
   module that computes the kind, the names it has in the tree and what it
@@ -100,6 +108,44 @@ class Mixer:
         """What its arrays are indexed through: pages by the table, a
         recurrent state by the row's slot."""
         return "page_table" if self.keeps[0].endswith("_pages") else "slots"
+
+
+@dataclass(frozen=True)
+class Residual:
+    """A RESIDUAL kind: what the layers hand one another where that is
+    not one stream ``x`` [B, T, d] with ``x + branch`` after every
+    sublayer (the kind of a config that has no ``residual``).  The state
+    begins after ``embed`` (``begin(cfg, x)``) and ends before ``norm_f``
+    (``end(cfg, state)`` -> [B, T, d]); each of a layer's two sublayers
+    READS its input from the state through a module of its own in the
+    layer's tree (``read(cfg, name=...)(state, live)`` -> (the input
+    [B, T, d], what its write needs); ``live`` [B, T] bool or None: the
+    rows that are no padding) and WRITES its output back (``write(cfg,
+    state, what read gave, branch)`` -> the state)."""
+    begin: Callable[..., Any]
+    read: Callable[..., Any]
+    write: Callable[..., Any]
+    end: Callable[..., Any]
+    axes: Tuple[str, ...]               # the state's logical axes
+    describe: Callable[[Any], Dict[str, int]]   # cfg -> what stats() say
+    names: Tuple[str, str] = ("attn_hc", "mlp_hc")  # the two read modules
+
+
+def residual_counters(intermediates):
+    """The largest of what the read modules of a residual kind sowed
+    (``residual``: each a vector of one shape, float32) over the layers
+    and sublayers: what the engine fetches beside the logits.  None for a
+    model of one stream."""
+    found = []
+
+    def walk(node) -> None:
+        for key, child in node.items():
+            if key == "residual" and isinstance(child, tuple):
+                found.extend(child)
+            elif hasattr(child, "items"):
+                walk(child)
+    walk(intermediates)
+    return jnp.max(jnp.stack(found), axis=0) if found else None
 
 
 def cache_spec(cfg) -> CacheSpec:
@@ -199,11 +245,12 @@ def _plus(cfg, x, branch):
     return x + branch.astype(x.dtype)
 
 
-def ffn(cfg, x, y, dense: bool, positions=None):
-    """``x + ffn(y)`` inside the calling block (the submodules are the
-    caller's): a dense SwiGLU of ``d_ff``, or the routed experts and, where
-    the config has one, the shared expert beside them.  ``positions``
-    [B, T] (< 0: padding, kept from the experts) or None."""
+def ffn(cfg, y, dense: bool, positions, write):
+    """``write(ffn(y))`` inside the calling block (the submodules are the
+    caller's; ``write`` puts a branch back into the residual state): a
+    dense SwiGLU of ``d_ff``, or the routed experts and, where the config
+    has one, the shared expert beside them.  ``positions`` [B, T] (< 0:
+    padding, kept from the experts) or None."""
     with jax.named_scope("mlp"):
         if dense:
             # (filed apart where the other layers have experts)
@@ -221,7 +268,7 @@ def ffn(cfg, x, y, dense: bool, positions=None):
                     down = down + _swiglu(
                         cfg, y, cfg.shared_d_ff,
                         ("shared_gate", "shared_up", "shared_down"))
-        return _plus(cfg, x, down)
+        return write(down)
 
 
 # ------------------------------------------------- the block, the decoder
@@ -237,14 +284,31 @@ class Block(nn.Module):
         mixer updated)."""
         cfg = self.cfg
         mixer = cfg.mixers[self.kind]
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name=mixer.norm)(x)
+        res = getattr(cfg, "residual", None)    # None: ONE stream
+        positions = cache["positions"] if cache is not None else None
+
+        def read(x, sublayer: int):
+            """A sublayer's input, and what its write needs."""
+            if res is None:
+                return x, None
+            return res.read(cfg, name=res.names[sublayer])(
+                x, None if positions is None else positions >= 0)
+
+        def write(x, maps, branch):
+            if res is None:
+                return _plus(cfg, x, branch)
+            return res.write(cfg, x, maps, branch)
+
+        u, maps = read(x, 0)
+        y = RMSNorm(cfg.rms_eps, cfg.dtype, name=mixer.norm)(u)
         m = mixer.module(cfg, name=mixer.name)(y, cache)
         m, kept = m if isinstance(m, tuple) else (m, None)
         with _scope(mixer.residual_scope):
-            x = _plus(cfg, x, m)
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
-        x = ffn(cfg, x, y, self.dense,
-                cache["positions"] if cache is not None else None)
+            x = write(x, maps, m)
+        u, maps = read(x, 1)
+        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(u)
+        x = ffn(cfg, y, self.dense, positions,
+                functools.partial(write, x, maps))
         return x if cache is None else (x, kept)
 
 
@@ -266,6 +330,11 @@ class Decoder(nn.Module):
             if hasattr(cfg, "embedding_multiplier"):
                 x = x * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
             x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
+        res = getattr(cfg, "residual", None)
+        axes = ("batch", "seq", "embed")
+        if res is not None:     # the state the layers hand on
+            axes = res.axes
+            x = _constrain(res.begin(cfg, x), axes, cfg.mesh)
         block = Block
         if cfg.remat and not cached:
             block = nn.remat(Block, prevent_cse=False)
@@ -285,7 +354,9 @@ class Decoder(nn.Module):
                 new.update(zip(mixer.keeps, kept if len(mixer.keeps) > 1
                                else (kept,)))
             seen[kind] += 1
-            x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
+            x = _constrain(x, axes, cfg.mesh)
+        if res is not None:
+            x = res.end(cfg, x)
         x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
         with jax.named_scope("lm_head"):
             if getattr(cfg, "tied_head", False):

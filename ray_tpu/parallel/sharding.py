@@ -1,7 +1,7 @@
 """Logical-axis sharding rules — how an ACTIVATION is laid out.
 
 TPU-native design: model code annotates arrays with *logical* dimension
-names ("batch", "seq", "embed", "mlp", "heads", "vocab"); the
+names ("batch", "seq", "stream", "embed", "mlp", "heads", "vocab"); the
 ShardingRules table maps logical names to mesh axes.  Changing the
 parallelism strategy = changing the table, not the model.  This fills
 the reference's TP/FSDP gap (SURVEY.md §2.3 rows 2-3, delegated there to
@@ -31,6 +31,9 @@ LogicalAxes = Tuple[Optional[str], ...]
 DEFAULT_RULES: Dict[str, Union[str, Tuple[str, ...], None]] = {
     "batch": ("dcn", "data", "fsdp"),
     "seq": "seq",
+    # the residual streams of a token (models/decoder.py Residual: a
+    # state [batch, seq, stream, embed]): whole, like its ``embed``
+    "stream": None,
     "embed": None,
     "mlp": "tensor",
     "heads": "tensor",

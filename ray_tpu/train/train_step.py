@@ -30,13 +30,19 @@ class TrainState(struct.PyTreeNode):
                    opt_state=optimizer.init(params))
 
 
+_UNDECAYED = ("expert_bias", "map_bias", "map_gate")
+
+
 def _decayed(params):
     """Which leaves the decoupled weight decay shrinks: every one but a
     leaf the model holds out of the gradient (``ops/moe.py``
     ``expert_bias``) -- with no gradient Adam's update is zero, and decay
-    alone would still pull such a leaf toward 0 on every step."""
+    alone would still pull such a leaf toward 0 on every step -- and the
+    biases and gates of a residual kind's maps (``models/xing.py``
+    ``map_bias``, ``map_gate``): no matrices, and 0 is no neutral value of
+    theirs (a gate at 0 cuts a map off its input)."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, _: getattr(path[-1], "key", None) != "expert_bias",
+        lambda path, _: getattr(path[-1], "key", None) not in _UNDECAYED,
         params)
 
 

@@ -1,6 +1,8 @@
-"""The six rows over ``models/decoder.py``: each keeps the tree and the
-cache it had as a model of its own (PR 43, the parent of the PR that
-merged them), and runs its layers through the one loop."""
+"""The seven rows over ``models/decoder.py``: each of the first six keeps
+the tree and the cache it had as a model of its own (PR 43, the parent of
+the PR that merged them), the seventh (``xing40``, PR 45: the first whose
+residual path is not one stream) the tree it came with; all run their
+layers through the one loop."""
 
 import dataclasses
 import hashlib
@@ -36,6 +38,11 @@ PARENT = {
     "kimilinear": ((120, "129a7fc464d44d8f"),
                    (2, 0, 0, 4, (3, 192), (4, 16, 16), 24, 8),
                    (7, 0, 0, 20, (3, 12288), (32, 128, 128), 512, 64)),
+    # (PR 45's own tree: Kimi-K2's leaves and, a layer, ``attn_hc`` and
+    # ``mlp_hc`` of four leaves each)
+    "xing40": ((93, "937dcfec63c2317a"),
+               (4, 0, 0, 0, (), (), 24, 8),
+               (40, 0, 0, 0, (), (), 512, 64)),
 }
 ROWS = sorted(PARENT)
 
@@ -111,3 +118,22 @@ def test_the_hooks_that_left_with_the_wrappers_fail_loud():
 
     assert not hasattr(granite, "attention")
     assert not hasattr(lfm2, "RMSNorm")
+
+
+def test_a_config_without_a_residual_kind_runs_the_one_stream_block():
+    """The residual kind is read from the config as ``mixers`` and
+    ``experts`` are: only ``xing40``'s has one (``decoder.Residual``), and
+    its hooks are the names the fault tool patches
+    (benchmark/tools/xing_faults.py: ``xing.HC``, ``xing.sinkhorn``,
+    ``xing.RMSNorm``), which exist; the other rows' configs have no such
+    attribute and no leaf of a map in their trees."""
+    import ray_tpu.models.xing as xing
+
+    for name, fam in MODEL_FAMILIES.items():
+        kind = getattr(fam.tiny(), "residual", None)
+        assert (kind is not None) == (name == "xing40"), name
+    assert isinstance(xing.HC, decoder.Residual)
+    assert xing.XingConfig.tiny().residual is xing.HC
+    assert callable(xing.sinkhorn) and issubclass(
+        xing.RMSNorm, decoder.nn.Module)
+    assert not hasattr(xing, "MLAttention")     # Kimi-K2's, used from there

@@ -610,6 +610,86 @@ def test_kimi_cell_compiles_at_the_benchmarks_sizes(one_chip, tokens_shape):
     assert calls("flash_fwd") == (0 if decode else 7)
 
 
+@pytest.mark.parametrize("tokens_shape", [(16, 1), (1, 4096)],
+                         ids=["decode", "prefill4096"])
+def test_xing_cell_compiles_at_the_benchmarks_sizes(one_chip, tokens_shape):
+    """The decode and ``prefill[4096]`` programs of
+    serve-xing4.0-29b-a4b-4k at the benchmark's sizes (layers 0-1, dense,
+    and five sparse layers at the published widths carried as FOUR residual
+    streams; ALL 64 experts held; the WHOLE vocabulary; bf16; max_batch 16,
+    4096 pages of 16, max_context 4096), as the backend ``tpu`` builds
+    them: 9.85 GB of weights and Kimi-K2's ONE latent pool [7, 4096, 16,
+    640] (0.59 GB), aliased to the output: the streams live inside a step,
+    nothing of them is cached.  Every layer holds every expert, so no
+    program has the experts' compact branch (one grouped matmul's metadata
+    a sparse layer); the decode step attends through the latent paged
+    kernel and the prefill through the flash kernel, once a layer; the
+    prefill holds no ``[T, T]`` score array (32 x 4096 x 4096 float32 would
+    be 2.1 GB a layer) and its fullest program stands at 70-85% of the
+    chip's 16.9 GB (benchmark/cells/serve-xing4.0-29b-a4b-4k.json
+    ``sized``)."""
+    import ray_tpu.models.attention as attention
+    import ray_tpu.ops
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_pool, pages_for
+    from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.models.xing import XingConfig
+    from ray_tpu.ops import paged_attention
+
+    row = MODEL_FAMILIES["xing40"]
+    cfg = XingConfig(n_layer=7, attn_impl="dense", remat=False)
+    spec = row.cache(cfg)
+    params = jax.eval_shape(lambda: row.init(cfg, jax.random.PRNGKey(0)))
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert abs(weights - 9.85e9) < 0.01 * 9.85e9
+    kv = jax.eval_shape(lambda: init_pool(spec, 4096, 16, cfg.dtype))
+    assert list(kv) == ["latent_pages"]
+    assert kv["latent_pages"].shape == (7, 4096, 16, 640)
+    b = tokens_shape[0]
+    ints = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                             sharding=one_chip)
+    with pytest.MonkeyPatch.context() as patch:    # as on the tpu backend
+        patch.setattr(attention, "_latent_kernel",
+                      paged_attention.latent_supported)
+        patch.setattr(paged_attention, "paged_decode_latent",
+                      functools.partial(paged_attention.paged_decode_latent,
+                                        interpret=False))
+        patch.setattr(attention, "_prefill_impl", lambda t: "flash")
+        patch.setattr(ray_tpu.ops, "flash_attention", functools.partial(
+            ray_tpu.ops.flash_attention, interpret=False))
+        lowered = jit_forward(row.module(cfg)).lower(
+            _on(params, one_chip), ints(tokens_shape),
+            _on(kv["latent_pages"], one_chip),
+            ints((b, pages_for(4096, 16))), ints(tokens_shape))
+        compiled = lowered.compile()
+    decode = tokens_shape[1] == 1
+    total = _device_bytes(compiled)
+    m = compiled.memory_analysis()
+    pool = kv["latent_pages"]
+    assert m.alias_size_in_bytes == pool.size * pool.dtype.itemsize
+    if decode:
+        assert total < 10.7e9 and m.temp_size_in_bytes < 0.2e9
+    else:       # the fullest program: 70-85% of 16.9 GB, no [T, T] array
+        assert 0.70 * 16.9e9 < total < 0.85 * 16.9e9, total
+        assert m.temp_size_in_bytes < 2.0e9
+    text = compiled.as_text()
+    assert "stablehlo.case" not in lowered.as_text()
+    assert text.count('op_name="ragged-dot-metadata"') == cfg.n_moe_layers
+    # the outputs: logits, the pool, the routing counters, the maps' errors
+    assert [tuple(o.shape) for o in jax.tree_util.tree_leaves(
+        compiled.out_info)] == [
+        tokens_shape + (131072,), pool.shape, (cfg.n_moe_layers, 4), (2,)]
+
+    def calls(kernel):
+        return len(re.findall(
+            rf"^\s*(?:ROOT )?%{kernel}[\w.]* = .*custom-call\(", text,
+            re.M))
+
+    assert calls("paged_decode_latent") == (7 if decode else 0)
+    assert calls("flash_fwd") == (0 if decode else 7)
+
+
 @pytest.mark.parametrize("tokens_shape", [(16, 1), (1, 2048)],
                          ids=["decode", "prefill2048"])
 def test_kimi_linear_cell_compiles_at_the_benchmarks_sizes(one_chip,
