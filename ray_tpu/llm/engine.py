@@ -235,7 +235,15 @@ def jit_forward(model):
     (llm/kv_cache.py ``state_arrays``: ``conv`` and ``ssm``, or ``conv``
     alone; donated and updated in place as the pages are) and each row's
     slot ``[B]``.  Returns the logits, the paged pool's arrays, then the
-    state pool's.  A model with experts returns one more output, its
+    state pool's.  The logits are every position's, ``[B, T, V]`` float32,
+    unless the caller says which position of each row it serves, by the
+    keyword ``last`` (int32 ``[B]``, an index within ``T``): then the
+    model cuts its hidden state to that position before its final norm
+    and its head, and the logits are ``[B, 1, V]``; every cache is
+    written as without it.  The engine passes it for a prefill (the
+    prompt's last token) and not for a decode step; a caller that does
+    not pass it (the positional call) gets every position.  A model
+    with experts returns one more output, its
     routing counters ([layers with experts, 4] int32, ops/moe.py
     ``moe_counters``), and one whose config has a residual kind one after
     that: what its maps sowed (float32, models/decoder.py
@@ -251,12 +259,12 @@ def jit_forward(model):
     pools, held = pool_arrays(spec), state_arrays(spec)
     n = len(pools)
 
-    def run(p, tokens, paged, page_table, positions, state):
+    def run(p, tokens, paged, page_table, positions, state, last):
         cache = dict(zip(pools, paged, strict=True), page_table=page_table)
         if held:
             cache.update(zip(held + ("slots",), state, strict=True))
         (logits, new), sown = model.apply(
-            p, tokens, kv_cache=cache, positions=positions,
+            p, tokens, kv_cache=cache, positions=positions, last=last,
             mutable=["intermediates"])
         out = (logits,) + tuple(new[name] for name in pools + held)
         sown = sown.get("intermediates", {})
@@ -267,13 +275,15 @@ def jit_forward(model):
     # The parameters' names are part of the compiled program's text (and
     # of the compile cache's key): the K/V families keep theirs.
     if n == 2:
-        def fwd(p, tokens, k_pages, v_pages, page_table, positions, *state):
+        def fwd(p, tokens, k_pages, v_pages, page_table, positions, *state,
+                last=None):
             return run(p, tokens, (k_pages, v_pages), page_table,
-                       positions, state)
+                       positions, state, last)
     else:
-        def fwd(p, tokens, latent_pages, page_table, positions, *state):
+        def fwd(p, tokens, latent_pages, page_table, positions, *state,
+                last=None):
             return run(p, tokens, (latent_pages,), page_table, positions,
-                       state)
+                       state, last)
 
     return jax.jit(fwd, donate_argnums=tuple(range(2, 2 + n)) + tuple(
         range(4 + n, 4 + n + len(held))))
@@ -626,8 +636,8 @@ class GenerationEngine:
                 "max_context": self.max_context,
                 "step_errors": self._step_errors,
                 "last_error": self._last_error,
-                # Compiled programs (forwards, the sampler, the row
-                # pickers) by name -> compile seconds.
+                # Compiled programs (forwards, the sampler, the
+                # placement of a prefill's row) by name -> compile seconds.
                 "programs": dict(self._compile_seconds),
                 "sampling": dict(self._sampling),
                 "attention": dict(self._attention),
@@ -813,19 +823,22 @@ class GenerationEngine:
         row[:len(seq.pages)] = seq.pages
         return row
 
-    def _call_fwd(self, kind: str, tokens, table, positions, slots):
+    def _call_fwd(self, kind: str, tokens, table, positions, slots,
+                  **served):
         """The forward of this token shape (``llm_decode``, or
         ``llm_prefill[bucket]``) over the caches, which it updates:
         returns (logits, (the routing counters or None, the residual
         kind's or None)).  ``slots`` is
         each row's slot of the state pool (a row without a sequence:
-        the index outside the pool), taken by a model that has one."""
+        the index outside the pool), taken by a model that has one.
+        ``served`` is a prefill's ``last`` (``jit_forward``): the logits
+        are then that one position's."""
         name = f"llm_{kind}[{tokens.shape[1]}]" \
             if kind == "prefill" else f"llm_{kind}"
         args = (self._params, tokens, *self._kv.values(), table, positions)
         if self._state is not None:
             args += (*self._state.values(), slots)
-        logits, *rest = self._call(self._fwd, name, *args)
+        logits, *rest = self._call(self._fwd, name, *args, **served)
         n = len(self._kv)
         self._kv = dict(zip(self._kv, rest[:n]))
         rest = rest[n:]
@@ -836,7 +849,7 @@ class GenerationEngine:
         residual = rest.pop() if self._residual else None
         return logits, (rest[0] if rest else None, residual)
 
-    def _call(self, fn, name: str, *args):
+    def _call(self, fn, name: str, *args, **kwargs):
         """Dispatch a jitted function through the AOT executable of this
         name (one name per shape: the engine's shapes are fixed).
 
@@ -854,7 +867,7 @@ class GenerationEngine:
         if cached is None or cached[0] is not fn:
             t0 = time.perf_counter()
             with annotate("llm.compile", program=name):
-                exe = fn.lower(*args).compile()
+                exe = fn.lower(*args, **kwargs).compile()
             dt = time.perf_counter() - t0
             self._compile_seconds[name] = dt
             self._compiles += 1
@@ -867,7 +880,7 @@ class GenerationEngine:
             except Exception:
                 pass    # registering with xprof is best-effort
             cached = self._exe_cache[name] = (fn, exe)
-        return cached[1](*args)
+        return cached[1](*args, **kwargs)
 
     def _pack_sampling(self, rows: List[tuple]):
         """The sampler's per-row arguments for ``rows``, (sequence, its
@@ -1016,11 +1029,11 @@ class GenerationEngine:
         with self._phase("llm.prefill.run"):
             logits, counters = self._call_fwd(
                 "prefill", tokens, table, positions,
-                np.asarray([seq.slot], np.int32))
+                np.asarray([seq.slot], np.int32),
+                last=np.asarray([n - 1], np.int32))
             ids = self._call(
                 self._sampler, "llm_sample",
-                self._call(self._last_rows, f"llm_last[{pad}]", logits,
-                           np.int32(n - 1)),
+                self._call(self._last_rows, "llm_last", logits),
                 *sampling)
             self._feed(ids, feed_to)
         seq.n_cached = n

@@ -197,15 +197,14 @@ def sample_tokens(logits, knobs, words):
 
 def jit_sampler(max_batch: int):
     """The engine's two jitted programs: ``sample_tokens``, and
-    ``last_rows(logits[1, T, V], last) -> [max_batch, 1, V]``, which puts
-    a prefill's row ``logits[0, last]`` into row 0 of zeros of the decode
-    shape, so that ``sample_tokens`` is compiled for ONE shape."""
-    def last_rows(logits, last):
+    ``last_rows(logits[1, 1, V]) -> [max_batch, 1, V]``, which puts the
+    one row a prefill's forward returns (its prompt's last position,
+    llm/engine.py ``jit_forward``) into row 0 of zeros of the decode
+    shape, so that ``sample_tokens`` is compiled for ONE shape, and
+    ``last_rows`` for one too, whatever the prefill's bucket."""
+    def last_rows(logits):
         with jax.named_scope("sample"):
-            row = lax.dynamic_index_in_dim(logits[0], last, 0,
-                                           keepdims=False)
-            out = jnp.zeros((max_batch, 1) + row.shape, logits.dtype)
-            return out.at[0, 0].set(row)
+            return jnp.pad(logits, ((0, max_batch - 1), (0, 0), (0, 0)))
 
     return jax.jit(sample_tokens), jax.jit(last_rows)
 

@@ -65,7 +65,7 @@ from jax.sharding import PartitionSpec as PS
 
 from ..parallel.sharding import with_logical_constraint as _constrain
 from .attention import attention
-from .layers import RMSNorm, _rope
+from .layers import RMSNorm, _rope, served_position
 
 
 @dataclass(frozen=True)
@@ -316,10 +316,15 @@ class Decoder(nn.Module):
     cfg: Any
 
     @nn.compact
-    def __call__(self, tokens, kv_cache=None, positions=None):
+    def __call__(self, tokens, kv_cache=None, positions=None, last=None):
         """Full forward (kv_cache=None) or a step against the caches (the
         contract of GPT2.__call__, ``llm/kv_cache.py``): returns logits,
-        or (logits, the cache updated)."""
+        or (logits, the cache updated).  ``last`` (int32 [B], an index
+        within T; None: every position) is the ONE position of each row
+        the caller serves: every layer still runs, and writes its cache,
+        over all T, and what follows the last block (the residual kind's
+        end, ``norm_f``, the head) runs on that position alone: logits
+        [B, 1, V]."""
         cfg = self.cfg
         cached = kv_cache is not None
         init = nn.initializers.normal(0.02)
@@ -355,6 +360,8 @@ class Decoder(nn.Module):
                                else (kept,)))
             seen[kind] += 1
             x = _constrain(x, axes, cfg.mesh)
+        if last is not None:
+            x = served_position(x, last)
         if res is not None:
             x = res.end(cfg, x)
         x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from ..parallel.sharding import logical_shards
 from ..parallel.sharding import with_logical_constraint as _constrain
 from .attention import attention, attention_qkv, qkv_by_head
+from .layers import served_position
 
 
 @dataclass(frozen=True)
@@ -208,7 +209,7 @@ class GPT2(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False,
-                 kv_cache=None, positions=None):
+                 kv_cache=None, positions=None, last=None):
         """Full forward (kv_cache=None) or incremental decode step.
 
         Decode mode attends against the paged KV pool instead of
@@ -220,7 +221,11 @@ class GPT2(nn.Module):
         Returns (logits, new_kv_cache): the pool it was given, carried
         whole through the layers and updated (llm/kv_cache.py says
         why) — token-identical to the full forward (pinned by
-        tests/test_llm.py)."""
+        tests/test_llm.py).  ``last`` (int32 [B], an index within T;
+        None: every position) is the ONE position of each row the caller
+        serves: the blocks still run, and write their K/V, over all T,
+        and ``ln_f`` and the head run on that position alone: logits
+        [B, 1, V]."""
         cfg = self.cfg
         decode = kv_cache is not None
         wte = self.param("wte", nn.initializers.normal(0.02),
@@ -257,6 +262,8 @@ class GPT2(nn.Module):
             else:
                 x = blk(x)
             x = _constrain(x, ("batch", "seq", "embed"), cfg.mesh)
+        if last is not None:
+            x = served_position(x, last)
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
         if return_hidden:
             return x

@@ -7,6 +7,9 @@ takes the norm; Kimi-K2 rotates a 64-wide PART of a head, with YaRN's
 frequencies: ``yarn_inv_freq``, ``yarn_mscale``); ``slot_conv`` is the conv of Granite's ``Mamba2Mixer`` (4 taps,
 bias, silu) and of LFM2's ``ShortConvMixer`` (3 taps, neither), written
 once; ``init_by_leaf`` makes the seeded weights of all three.
+``served_position`` is what GPT-2 and the decoder cut their hidden state
+to, before the final norm and the head, for a caller that serves one
+position of each row.
 """
 
 from __future__ import annotations
@@ -114,6 +117,19 @@ class RMSNorm(nn.Module):
         norm = x32 * jax.lax.rsqrt(
             jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         return (norm * scale).astype(self.dtype)
+
+
+def served_position(x, last):
+    """x [B, T, ...] -> [B, 1, ...]: position ``last[b]`` (int32 [B], an
+    index within T) of row b.  Taken as B rows of ``x`` flattened to
+    [B*T, ...], the shape the compiled program gives every matmul's
+    operand: cut along the sequence of the [B, T, d] form, the TPU
+    compiler hands each sparse layer's output on a second time in that
+    form (Kimi-K2.5's ``prefill[4096]``: ten copies of an activation and
+    0.7 GB of temporaries more than with every row's logits)."""
+    b, t = x.shape[:2]
+    rows = x.reshape((b * t,) + x.shape[2:])[last + t * jnp.arange(b)]
+    return rows[:, None]
 
 
 def slot_conv(x, taps, window=None, bias=None,

@@ -5,10 +5,12 @@ residual path is not one stream) the tree it came with; all run their
 layers through the one loop."""
 
 import dataclasses
+import functools
 import hashlib
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_tpu.llm.kv_cache import pool_arrays, state_arrays
@@ -137,3 +139,68 @@ def test_a_config_without_a_residual_kind_runs_the_one_stream_block():
     assert callable(xing.sinkhorn) and issubclass(
         xing.RMSNorm, decoder.nn.Module)
     assert not hasattr(xing, "MLAttention")     # Kimi-K2's, used from there
+
+
+# ------------------------------------ the one position a prefill serves
+
+@pytest.fixture(scope="module")
+def prefill_of():
+    """row -> (its tiny config in float32, ``run(n, **served)``: the
+    engine's jitted forward of an ``n``-token prompt in a bucket of 16,
+    over fresh pools)."""
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_pool, init_state, pages_for
+
+    page = 4
+
+    @functools.lru_cache(maxsize=None)
+    def build(name):
+        fam = MODEL_FAMILIES[name]
+        cfg = dataclasses.replace(fam.tiny(), dtype=jnp.float32,
+                                  remat=False)
+        spec = fam.cache(cfg)
+        params = fam.init(cfg, jax.random.PRNGKey(7))
+        fwd = jit_forward(fam.module(cfg))
+        tokens = jax.random.randint(jax.random.PRNGKey(8), (1, BUCKET), 1,
+                                    cfg.vocab_size, jnp.int32)
+
+        def run(n, **served):
+            real = jnp.arange(BUCKET) < n
+            args = [params, jnp.where(real, tokens, 0),
+                    *init_pool(spec, 16, page, cfg.dtype).values(),
+                    jnp.arange(pages_for(cfg.max_seq, page),
+                               dtype=jnp.int32)[None] % 16,
+                    jnp.where(real, jnp.arange(BUCKET), -1)[None]]
+            if spec.state_layers:
+                args += [*init_state(spec, 2, cfg.dtype).values(),
+                         jnp.ones(1, jnp.int32)]
+            return fwd(*args, **served)
+
+        return cfg, run
+
+    return build
+
+
+BUCKET = 16
+
+
+@pytest.mark.parametrize("n", [BUCKET, BUCKET - 5],
+                         ids=["bucket_end", "inside_the_padding"])
+@pytest.mark.parametrize("name", ["gpt2"] + ROWS)
+def test_a_prefill_told_its_served_position_returns_that_row(
+        prefill_of, name, n):
+    """``last`` cuts the hidden state to one position AFTER the last
+    block: the logits [1, 1, V] are the row the same prefill returns at
+    that index among all of them, and every cache and state, and what the
+    layers sowed, is written bit for bit as without it."""
+    cfg, run = prefill_of(name)
+    all_rows, *kept = run(n)
+    assert all_rows.shape == (1, BUCKET, cfg.vocab_size)
+    one, *kept_one = run(n, last=jnp.asarray([n - 1], jnp.int32))
+    assert one.shape == (1, 1, cfg.vocab_size) and one.dtype == jnp.float32
+    np.testing.assert_allclose(one[0, 0], all_rows[0, n - 1], rtol=0,
+                               atol=1e-5)
+    assert len(kept) == len(kept_one) > 0
+    for a, b in zip(kept, kept_one):
+        np.testing.assert_array_equal(a, b)
+    assert any(np.asarray(a).any() for a in kept)      # something written

@@ -266,11 +266,13 @@ def test_device_draws_follow_seed_and_index():
 
 
 def test_last_rows_places_the_prefill_row():
+    """What a prefill's forward returns, ONE position's logits
+    [1, 1, V], goes into row 0 of the sampler's shape."""
     _, last_rows = jit_sampler(4)
-    logits = np.arange(2 * 8 * 5, dtype=np.float32).reshape(2, 8, 5)[:1]
-    out = np.asarray(last_rows(logits, np.int32(5)))
+    logits = np.arange(5, dtype=np.float32).reshape(1, 1, 5) - 2
+    out = np.asarray(last_rows(logits))
     assert out.shape == (4, 1, 5)
-    np.testing.assert_array_equal(out[0, 0], logits[0, 5])
+    np.testing.assert_array_equal(out[0, 0], logits[0, 0])
     assert not out[1:].any()
 
 

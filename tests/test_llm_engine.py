@@ -54,6 +54,34 @@ def test_engine_smoke_token_identical(setup):
         _reference(params, prompt, 6)
 
 
+# prompt -> its six greedy tokens as the parent of PR 46 served them (the
+# all-rows prefill and the per-bucket picker ``llm_last[bucket]``): a
+# prompt that fills its bucket (8, 16) and one that ends inside it.
+PARENTS_GREEDY = {(5, 100, 23): [23] * 6, tuple(range(40, 48)): [47] * 6,
+                  tuple(range(7, 18)): [97] * 6,
+                  tuple(range(300, 316)): [315] * 6}
+
+
+@pytest.mark.parametrize("prompt", PARENTS_GREEDY,
+                         ids=[f"prompt_of_{len(p)}" for p in PARENTS_GREEDY])
+def test_a_prefill_that_serves_one_position_gives_the_parents_tokens(
+        setup, prompt):
+    """The prefill hands the forward the prompt's last position and gets
+    [1, 1, V] back: the greedy tokens are the ones the all-rows prefill
+    gave, which are the full forward's; no program of the bucket's size
+    picks a row."""
+    eng, params = setup
+    served = eng.generate(list(prompt), max_tokens=6)
+    assert served == PARENTS_GREEDY[prompt] == _reference(params, prompt, 6)
+    programs = eng.stats()["programs"]
+    prefill = f"llm_prefill[{_bucket(len(prompt))}]"
+    assert {prefill, "llm_last"} <= set(programs)
+    assert not [name for name in programs if name.startswith("llm_last[")]
+    logits = jax.tree_util.tree_leaves(
+        eng._exe_cache[prefill][1].out_info)[0]
+    assert logits.shape == (1, 1, CFG.vocab_size)
+
+
 def test_mid_flight_admission_no_batch_barrier(setup):
     """A sequence submitted while another is mid-generation starts
     decoding before the first finishes — step-granularity admission,
@@ -200,9 +228,13 @@ def test_one_sampler_program_for_every_mix_and_ids_to_the_host():
         engine_cfg=EngineConfig(page_size=4, num_pages=64, max_batch=4))
     eng.warmup()
     warm = eng.stats()
-    assert set(warm["programs"]) == {"llm_prefill[8]", "llm_last[8]",
+    # (the placement of a prefill's row is ONE program, not one a bucket)
+    assert set(warm["programs"]) == {"llm_prefill[8]", "llm_last",
                                      "llm_sample", "llm_feed",
                                      "llm_decode"}
+    served = jax.tree_util.tree_leaves(
+        eng._exe_cache["llm_prefill[8]"][1].out_info)[0]
+    assert served.shape == (1, 1, CFG.vocab_size)   # not [1, 8, V]
     assert warm["sampling"] == {"rows_greedy": 2, "rows_sampled": 0,
                                 "steps": 2, "steps_sampled": 0}
     out, = jax.tree_util.tree_leaves(
