@@ -29,10 +29,11 @@ The KDA mixer on ``u`` [T, d], ``H`` heads of ``d_k = d_v``:
 
 A forward over many positions (training, the full forward, the engine's
 prefill) computes the recurrence in chunks (``kda_scan``, scope
-``kda.scan``); a decode step is the recurrence once, BY SLOT: the layer's
-slab of states updated where it lies (``kda_step``, scope ``kda.step``), and
-the windows' slab likewise (``step_conv``; a prefill's and the trainer's
-convolution is ``slot_conv``).
+``kda.scan``); a decode step is the recurrence once, each running row's
+state updated where it lies (``kda_step``, scope ``kda.step``: on the chip
+the Pallas kernel ``ops/delta_rule.py``), and the windows' slab BY SLOT
+(``step_conv``; a prefill's and the trainer's convolution is
+``slot_conv``).
 
 With a cache this is the first family with BOTH a latent pool and a state
 pool (``models.CacheSpec``: ``latent_dim`` > 0 and ``state_layers`` > 0):
@@ -290,21 +291,38 @@ def kda_scan(q, k, v, g, beta, chunk: int, sub: int, state=None):
     return o[:, :t], state
 
 
+def _step_kernel(pool, q) -> bool:
+    """Whether a decode step's recurrence takes the Pallas kernel: by the
+    pool's shape and dtype and the backend, nothing else."""
+    from ..ops import delta_rule
+
+    return jax.default_backend() == "tpu" and delta_rule.supported(pool, q)
+
+
 def kda_step(pool, layer, slots, fresh, q, k, v, a, beta):
-    """The recurrence once for every row of a decode batch, BY SLOT and
-    where the states lie: the rows' small vectors (q, k, v, a [B, H, D]
-    float32, beta [B, H]; ``fresh`` [B]: the row starts from zeros) are
-    put in slot order (a one-hot sum over the rows: a slot no row names,
-    and a row whose slot lies outside the pool, a padded row, leave ``a =
-    1``, ``beta = 0``: the identity), and layer ``layer`` of the WHOLE
-    pool [L, slots, H, D_k, D_v] is decayed and corrected as one slab,
-    written back over itself.  No state is gathered or scattered: gathered,
-    the running rows' states moved seven times their bytes and each
-    gather and scatter was a loop over the rows (my compile for a v5e, PR
-    41); a slot whose sequence is not in this step is read and written
-    back as it was.  Live rows have distinct slots.  Multiplies and sums in
-    float32: no matmul rounds the state.  Returns (o [B, H, D_v], the
-    pool)."""
+    """The recurrence once for every row of a decode batch, where the
+    states lie: q, k, v, a [B, H, D] float32, beta [B, H]; ``fresh`` [B]:
+    the row starts from zeros; layer ``layer`` of the WHOLE pool [L, slots,
+    H, D_k, D_v].  A row whose slot lies outside the pool (a padded row)
+    changes nothing and gives zeros; live rows have distinct slots.
+    Multiplies and sums in float32: no matmul rounds the state.  Returns
+    (o [B, H, D_v], the pool).
+
+    On the ``tpu`` backend, a float32 pool of whole 128 x 128 states goes
+    through ``ops/delta_rule.py kda_step``: each running row's state once
+    in and once out, no other slot touched.  Any other pool is worked BY
+    SLOT in ``jnp``, the kernel's plain definition: the rows' small vectors
+    are put in slot order (a one-hot sum over the rows: a slot no row
+    names, and a padded row, leave ``a = 1``, ``beta = 0``: the identity),
+    and the layer is decayed and corrected as one slab, written back over
+    itself (three passes over the slab on the chip where the mathematics
+    needs two; gathered by row instead, the states moved seven times
+    their bytes in loops over the rows: my compiles for a v5e, PR 41)."""
+    if _step_kernel(pool, q):
+        from ..ops import delta_rule
+
+        return delta_rule.kda_step(pool, layer, slots, fresh, q, k, v, a,
+                                   beta)
     n_slots = pool.shape[1]
     hit = slots[:, None] == jnp.arange(n_slots)[None, :]       # [B, S]
 
@@ -327,11 +345,12 @@ def kda_step(pool, layer, slots, fresh, q, k, v, a, beta):
 
 
 def step_conv(x, taps, window, act):
-    """``slot_conv`` for a decode step (x [B, 1, C]), BY SLOT as ``kda_step``
-    is: each row's last K-1 inputs are read from its slot, the window
-    slides by one (a padded row's stays), and the layer's slots are
-    rewritten as ONE slab, each from the row that names it (a one-hot sum
-    over the rows; a slot no row names keeps what it held).  Row by row
+    """``slot_conv`` for a decode step (x [B, 1, C]), BY SLOT as
+    ``kda_step``'s ``jnp`` form is: each row's last K-1 inputs are read
+    from its slot, the window slides by one (a padded row's stays), and
+    the layer's slots are rewritten as ONE slab, each from the row that
+    names it (a one-hot sum over the rows; a slot no row names keeps what
+    it held).  Row by row
     (``slot_conv``: a slice at each row's length, a scatter) each was a
     loop over the batch on the chip, 16 x ~8 operations a layer, and with
     20 layers the profiler's capture of a step did not end (PR 41)."""

@@ -11,6 +11,7 @@ forward with BOTH pools, the engine itself, the expert shares, the loss and
 the family registry."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -197,6 +198,78 @@ def test_the_decode_step_is_the_recurrence_on_the_running_rows_slots():
     np.testing.assert_array_equal(new[1, 1:3], pool[1, 1:3])
 
 
+# (slots, fresh, slots in the pool, heads, heads a block)
+KERNEL_CASES = {
+    "full_batch": ([0, 1, 2, 3], [0, 0, 0, 0], 4, 4, 2),
+    "padded_rows_among_live": ([2, 6, 0, 6, 4], [0, 0, 0, 0, 0], 6, 4, 2),
+    "padded_first_and_last": ([5, 1, 3, 5], [0, 0, 0, 0], 5, 4, 2),
+    "fresh_rows": ([1, 0, 3, 2], [1, 0, 0, 1], 4, 4, 2),
+    "fresh_beside_padded": ([4, 4, 2, 0], [1, 0, 1, 0], 4, 4, 4),
+    "rows_out_of_slot_order": ([3, 0, 2, 1], [0, 0, 0, 0], 4, 4, 1),
+    "more_slots_than_rows": ([5, 1], [0, 0], 7, 4, 2),
+    "an_odd_number_of_blocks": ([2, 4, 0], [0, 1, 0], 4, 3, 1),
+    "every_row_padded": ([3, 3], [0, 0], 3, 4, 2),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_the_step_kernel_is_the_recurrence_and_touches_the_running_rows_alone(
+        case):
+    """``ops/delta_rule.py kda_step`` (interpreted) on layer 1 of a pool
+    of 3 layers against the token-by-token recurrence: a live row's ``o``
+    and its slot's new state to 1e-6, a padded row's ``o`` zeros, and every
+    slot no row names and every OTHER layer BIT-EQUAL to what it held; the
+    by-slot ``jnp`` form gives the same."""
+    from ray_tpu.ops import delta_rule
+
+    slots, fresh, n_slots, h, block = KERNEL_CASES[case]
+    d = 128
+    (q, k, v, g, beta), _ = _drawn(1, 0.3, seed=3, b=len(slots), h=h, d=d)
+    q, k, v, g, beta = (x[:, 0] for x in (q, k, v, g, beta))
+    q = q * d ** -0.5               # as the mixer scales it
+    pool = jnp.asarray(np.random.default_rng(2).normal(
+        size=(3, n_slots, h, d, d)), jnp.float32)
+    assert delta_rule.supported(pool, q)
+    args = (pool, 1, jnp.asarray(slots), jnp.asarray(fresh, bool), q, k, v,
+            jnp.exp(g), beta)
+    o, new = delta_rule.kda_step(*args, block_heads=block, interpret=True)
+    o_slab, new_slab = kda_step(*args)
+    live = [i for i, s in enumerate(slots) if s < n_slots]
+    for i, s in enumerate(slots):
+        if i not in live:
+            np.testing.assert_array_equal(o[i], 0.0)
+            continue
+        start = jnp.zeros_like(pool[1, s]) if fresh[i] else pool[1, s]
+        want, s_want = _recurrence(*(x[i:i + 1, None]
+                                     for x in (q, k, v, g, beta)),
+                                   start[None])
+        np.testing.assert_allclose(o[i], want[0, 0], atol=1e-6)
+        np.testing.assert_allclose(new[1, s], s_want[0], atol=1e-6)
+    idle = [s for s in range(n_slots) if s not in slots]
+    np.testing.assert_array_equal(new[1, idle], pool[1, idle])
+    np.testing.assert_array_equal(new[jnp.asarray([0, 2])],
+                                  pool[jnp.asarray([0, 2])])
+    np.testing.assert_allclose(o, o_slab, atol=1e-6)
+    np.testing.assert_allclose(new, new_slab, atol=1e-6)
+
+
+def test_the_step_kernel_is_for_float32_pools_of_whole_tiles():
+    """``supported`` reads the pool's shape and dtype alone: the tiny
+    preset's 16 x 16 states and a bfloat16 pool stay on the ``jnp`` form."""
+    from ray_tpu.ops.delta_rule import supported
+
+    q = jax.ShapeDtypeStruct((2, 4, 128), jnp.float32)
+
+    def pool(dtype, *state):
+        return jax.ShapeDtypeStruct((2, 4, 4) + state, dtype)
+
+    assert supported(pool(jnp.float32, 128, 128), q)
+    assert supported(pool(jnp.float32, 256, 128), q)
+    assert not supported(pool(jnp.float32, 16, 16), q)
+    assert not supported(pool(jnp.float32, 128, 64), q)
+    assert not supported(pool(jnp.bfloat16, 128, 128), q)
+
+
 # ------------------------------------------- through the engine's programs
 
 def _against_reference(params, prompts, served, logits, n):
@@ -374,6 +447,52 @@ def test_the_lowered_forward_names_the_scopes_the_readers_file_by():
     assert any("kda.step" in x and "scatter" in x
                for x in decode.splitlines())
     assert "triangular_solve" in prefill and "triangular_solve" not in decode
+    assert "tpu_custom_call" not in decode      # 16 x 16 states: ``jnp``
+
+
+def test_the_lowered_decode_step_holds_the_kernel_the_reader_files_by_name():
+    """benchmark/harness/kda_phases.py files an instruction whose name
+    starts with ``kda_step`` under ``kda.step`` (a kernel carries no scope
+    path): with states of 128 x 128, lowered for the ``tpu`` platform as
+    that backend dispatches, the decode forward calls one such custom call
+    once a KDA layer, the pool aliased in and out, and its prefill none."""
+    import ray_tpu.models.kimi_linear as kimi_linear
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_pool, init_state, pages_for
+    from ray_tpu.ops import delta_rule
+
+    cfg = dataclasses.replace(CFG, kda_head_dim=128)
+    spec = MODEL_FAMILIES["kimilinear"].cache(cfg)
+    params = jax.eval_shape(
+        lambda: kimi_linear_init(cfg, jax.random.PRNGKey(0)))
+    kv = jax.eval_shape(lambda: init_pool(spec, 16, 4, cfg.dtype))
+    state = jax.eval_shape(lambda: init_state(spec, 2, cfg.dtype))
+
+    def lowered(shape):
+        ints = jax.ShapeDtypeStruct(shape, jnp.int32)
+        return jit_forward(KimiLinear(cfg)).trace(
+            params, ints, kv["latent_pages"], jax.ShapeDtypeStruct(
+                (shape[0], pages_for(cfg.max_seq, 4)), jnp.int32),
+            ints, state["conv"], state["ssm"],
+            jax.ShapeDtypeStruct(shape[:1], jnp.int32)
+        ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+    with pytest.MonkeyPatch.context() as patch:    # as on the tpu backend
+        patch.setattr(kimi_linear, "_step_kernel", delta_rule.supported)
+        patch.setattr(delta_rule, "kda_step", functools.partial(
+            delta_rule.kda_step, interpret=False))
+        decode, prefill = lowered((2, 1)), lowered((1, 16))
+    # (the layer is an operand: ONE lowered function, called a KDA layer)
+    call, = [x for x in decode.splitlines()
+             if "stablehlo.custom_call @tpu_custom_call" in x]
+    assert 'kernel_name = "kda_step"' in call
+    assert "output_operand_alias" in call and "operand_index = 5" in call
+    assert len([x for x in decode.splitlines()
+                if "call @_kda_step(" in x]) == cfg.layers_of("kda") == 4
+    assert any("kda.step/jit(_kda_step)" in x for x in decode.splitlines())
+    assert "kda.step" in decode and "scatter" not in "".join(
+        x for x in decode.splitlines() if "kda.step" in x)
+    assert "tpu_custom_call" not in prefill and "kda.scan" in prefill
 
 
 # ------------------------------------------- the latent layer's two options

@@ -345,6 +345,37 @@ def test_paged_decode_kernel_compiles(one_chip, h, h_kv, d, dtype):
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("h,d_k,d_v,block", [
+    (32, 128, 128, None), (12, 128, 128, 4), (8, 256, 128, None)],
+    ids=["cell_32_heads_of_128", "three_blocks_of_4_heads", "d_k_256"])
+def test_kda_step_kernel_compiles(one_chip, h, d_k, d_v, block):
+    """The delta rule's decode step alone (ops/delta_rule.py): at the
+    Kimi-Linear cell's sizes (16 rows on a pool [20, 16, 32, 128, 128],
+    blocks of 16 heads), with an odd number of blocks a row (the buffers
+    then alternate across the rows), and with states of 256 x 128.  One
+    kernel, the donated pool aliased through it and never copied."""
+    from ray_tpu.ops import delta_rule
+
+    on = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    pool = on((20, 16, h, d_k, d_v), jnp.float32)
+    row = on((16, h, d_k), jnp.float32)
+    assert delta_rule.supported(pool, row)
+    blocks = {} if block is None else {"block_heads": block}
+    compiled = jax.jit(functools.partial(
+        delta_rule.kda_step, layer=3, interpret=False, **blocks),
+        donate_argnums=(0,)).lower(
+        pool, slots=on((16,), jnp.int32), fresh=on((16,), jnp.bool_),
+        q=row, k=row, v=on((16, h, d_v), jnp.float32), a=row,
+        beta=on((16, h), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert re.search(r"^\s*(?:ROOT )?%kda_step[\w.]* = .*custom-call\(", text,
+                     re.M)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 20 * 16 * h * d_k * d_v * 4
+    assert m.temp_size_in_bytes < 4e6
+
+
 @pytest.mark.parametrize("program", ["sample", "last"])
 def test_engine_sampler_compiles_at_the_cells_sizes(one_chip, program):
     """The engine's sampler (llm/sampling.py) at max_batch 16 and GPT-2's
@@ -775,18 +806,22 @@ def test_kimi_linear_cell_compiles_at_the_benchmarks_sizes(one_chip,
     pool [7, 4096, 16, 640] and the state pool's ``conv`` [20, 16, 3,
     12288] and float32 ``ssm`` [20, 16, 32, 128, 128], all three aliased
     to the outputs.  The decode step attends through the latent paged
-    kernel once a latent layer and updates each KDA layer's slab of states
-    where it lies, with no copy of the state pool and no loop over the rows; the prefill runs
-    the chunked scan (a triangular solve a KDA layer) and the flash
+    kernel once a latent layer and runs the recurrence through the
+    ``kda_step`` kernel once a KDA layer, the state pool handed from call
+    to call as the ONE buffer it came in (each call aliases it in and out
+    and copies the running rows' states itself): no copy of the pool, no
+    slice or in-place write of a slab, no loop over the rows; the prefill
+    runs the chunked scan (a triangular solve a KDA layer) and the flash
     kernel, and holds under 1 GB of temporaries; the logits are the one
     served position's (every row's were 1.34 GB in float32)."""
     import ray_tpu.models.attention as attention
+    import ray_tpu.models.kimi_linear as kimi_linear
     import ray_tpu.ops
     from ray_tpu.llm.engine import jit_forward
     from ray_tpu.llm.kv_cache import init_pool, init_state, pages_for
     from ray_tpu.models import MODEL_FAMILIES
     from ray_tpu.models.kimi_linear import KimiLinearConfig
-    from ray_tpu.ops import paged_attention
+    from ray_tpu.ops import delta_rule, paged_attention
 
     row = MODEL_FAMILIES["kimilinear"]
     cfg = KimiLinearConfig(held_experts=16, attn_impl="dense", remat=False)
@@ -812,6 +847,9 @@ def test_kimi_linear_cell_compiles_at_the_benchmarks_sizes(one_chip,
         patch.setattr(attention, "_prefill_impl", lambda t: "flash")
         patch.setattr(ray_tpu.ops, "flash_attention", functools.partial(
             ray_tpu.ops.flash_attention, interpret=False))
+        patch.setattr(kimi_linear, "_step_kernel", delta_rule.supported)
+        patch.setattr(delta_rule, "kda_step", functools.partial(
+            delta_rule.kda_step, interpret=False))
         compiled = jit_forward(row.module(cfg)).lower(
             _on(params, one_chip), ints(tokens_shape),
             _on(kv["latent_pages"], one_chip),
@@ -829,14 +867,17 @@ def test_kimi_linear_cell_compiles_at_the_benchmarks_sizes(one_chip,
     text = compiled.as_text()
     assert text.count('op_name="ragged-dot-metadata"') == \
         cfg.n_moe_layers * (1 if decode else 2)
-    # no pass over the state pool but the in-place writes (a layer's slab
-    # in a decode step, one row in a prefill: a dynamic-update-slice)
+    # no pass over the state pool: a prefill writes one row in place (a
+    # dynamic-update-slice a KDA layer); a decode step makes the pool
+    # nowhere but as the 20 kernels' aliased result, and a layer's slab
+    # nowhere at all
     shape = ",".join(map(str, state["ssm"].shape))
+    slab = ",".join(map(str, state["ssm"].shape[1:]))
     passes = [line.strip()[:120] for line in text.splitlines()
-              if (hit := _POOL_PASS.match(line)) and hit.group(1) == shape
-              and hit.group(2) != "dynamic-update-slice"]
+              if (hit := _POOL_PASS.match(line))
+              and hit.group(1) in (shape, slab)
+              and (decode or hit.group(2) != "dynamic-update-slice")]
     assert not passes, (len(passes), passes[:4])
-    assert text.count(f"f32[{shape}]") > 20
 
     def calls(kernel):
         return len(re.findall(
@@ -845,10 +886,24 @@ def test_kimi_linear_cell_compiles_at_the_benchmarks_sizes(one_chip,
 
     assert calls("paged_decode_latent") == (7 if decode else 0)
     assert calls("flash_fwd") == (0 if decode else 7)
+    assert calls("kda_step") == (20 if decode else 0)
+    # what MAKES an array of the pool's shape (not a parameter, an element
+    # of a tuple or the tuple returned): instruction and opcode
+    made = [(hit.group(1), hit.group(2)) for line in text.splitlines()
+            if (hit := re.match(
+                rf"^\s*(?:ROOT )?%([\w.-]+) = \(?[^=]*?f32\[{shape}\][^=]*?"
+                r"[})] ([\w-]+)\(", line))
+            and hit.group(2) not in ("parameter", "get-tuple-element",
+                                     "tuple")]
     if decode:      # the gathers and scatters were loops over the rows
         assert not re.search(r"^\s*%?[\w.]+ = .* while\(", text, re.M)
+        assert len(made) == 20 and all(
+            name.startswith("kda_step") and code == "custom-call"
+            for name, code in made), made
+        assert f"f32[{slab}]" not in text
     else:
         assert "kda.scan" in text and "kda.step" not in text
+        assert len(made) >= 20
 
 
 def _train_step_and_shapes(cfg, loss_chunk):
