@@ -1,6 +1,6 @@
 """ray_tpu.models — TPU-first reference model families.
 
-Eight families run through both the trainer and the serving engine:
+Nine families run through both the trainer and the serving engine:
 GPT-2 (pretrain baseline, BASELINE.json headline metric), Llama
 (RoPE/GQA/SwiGLU), OLMoE (the Llama block with QK-norm and dropless
 top-k sparse experts, ops/moe.py), Granite 4.0-H (``granitemoehybrid``:
@@ -22,7 +22,12 @@ models/kimi_linear.py) and Xing4.0 (``xing40``: Kimi-K2's layer with the
 residual path changed: FOUR streams a token, which every sublayer reads,
 writes and mixes through maps it computes from them, a sigmoid read map, a
 write map and a Sinkhorn-normalised 4 x 4 stream map: manifold-constrained
-hyper-connections, models/xing.py).  All but GPT-2 are ONE decoder
+hyper-connections, models/xing.py) and Olmo-Hybrid (``olmohybrid``: Gated
+DeltaNet mixers, the delta rule with ONE decay a head, betas up to 2 and a
+rectangular 96 x 192 state a head, three layers in four, and full
+multi-head attention with a QK-norm and no position encoding in the fourth;
+every FFN dense; each norm on its sublayer's OUTPUT,
+models/olmo_hybrid.py).  All but GPT-2 are ONE decoder
 (models/decoder.py: the layer loop, the block, grouped-query attention
 around the core of models/attention.py, the FFN, the loss, the rules every
 tree shares) over a config; what more than one mixer is built from
@@ -36,12 +41,13 @@ edit.
 the multi-host training plane (``train.distributed.rules_for_model``)
 resolve a family through.  A ROW is a config class, its module, init,
 loss, partition rules, a tiny preset for tests, and its cache spec.  A
-ninth family is a config (published sizes, ``tiny``; ``layer_types``,
+tenth family is a config (published sizes, ``tiny``; ``layer_types``,
 one entry a layer; ``mixers``, which maps each entry to its KIND; what
 the FFN reads: ``n_dense_layers``, ``experts``, ``shared_d_ff``; and,
 where the layers hand one another more than one stream, ``residual``, the
 RESIDUAL kind, ``decoder.Residual``: how the state begins and ends and how
-a sublayer reads and writes it), the kind it adds, and a row whose module
+a sublayer reads and writes it; ``norm_output`` where each norm lies on its
+sublayer's output), the kind it adds, and a row whose module
 is ``Decoder`` under its name (a config may extend another row's:
 ``family_of`` takes the row of the config's own class first).  A
 KIND (``decoder.Mixer``) says three things in one place: the module that
@@ -67,12 +73,16 @@ K/V: its ``kv_layers`` hold ONE row a position in a single pool,
 spec has latent rows for its 7 latent layers (``latent_dim`` > 0) AND a
 slot for its 20 recurrent ones (``state_layers`` > 0: the three
 convolutions' window and a float32 ``[heads, d_k, d_v]`` state), and a
-layer indexes its pool by its number among its own kind.  The engine
+layer indexes its pool by its number among its own kind; Olmo-Hybrid's
+has K/V for its attention layers AND a slot for its delta-rule ones, whose
+``ssm_shape`` is the step kernel's layout of the heads' rectangular states
+(``ops/delta_rule.py state_shape``).  The engine
 builds both pools from the spec
 (``llm/kv_cache.py init_pool`` / ``init_state``), and of each the
 arrays the spec has and nothing else.
 Keys are normalized lowercase-no-separator ("gpt2", "llama", "olmoe",
-"granitemoehybrid", "lfm2moe", "kimik2", "kimilinear", "xing40").
+"granitemoehybrid", "lfm2moe", "kimik2", "kimilinear", "xing40",
+"olmohybrid").
 """
 
 from dataclasses import dataclass
@@ -93,6 +103,9 @@ from .lfm2 import (Lfm2, Lfm2Config, lfm2_init,  # noqa: F401
 from .llama import (Llama, LlamaConfig, llama_init,  # noqa: F401
                     llama_loss_fn, llama_partition_rules, olmoe_loss_fn,
                     olmoe_partition_rules)
+from .olmo_hybrid import (OlmoHybrid, OlmoHybridConfig,  # noqa: F401
+                          olmo_hybrid_init, olmo_hybrid_loss_fn,
+                          olmo_hybrid_partition_rules)
 from .xing import (Xing, XingConfig, xing_init,  # noqa: F401
                    xing_loss_fn, xing_partition_rules)
 
@@ -135,6 +148,10 @@ MODEL_FAMILIES = {
     "xing40": ModelFamily(XingConfig, Xing, xing_init, xing_loss_fn,
                           xing_partition_rules, XingConfig.tiny,
                           cache_spec),
+    "olmohybrid": ModelFamily(
+        OlmoHybridConfig, OlmoHybrid, olmo_hybrid_init,
+        olmo_hybrid_loss_fn, olmo_hybrid_partition_rules,
+        OlmoHybridConfig.tiny, cache_spec),
 }
 
 

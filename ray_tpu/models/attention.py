@@ -6,12 +6,18 @@ the end, is its sibling for a cache that holds no K and V):
 
 - with a ``cache`` (the engine's prefill and decode): this step's K/V
   are written into the paged pool the layers carry (``llm/kv_cache.py
-  paged_store``), then q attends against the history: a decode step
-  (one query row a sequence) on the ``tpu`` backend through the Pallas
-  kernel that reads each sequence's pages where they lie
-  (``ops/paged_attention.py paged_decode``), a prefill and every other
-  backend through ``paged_attend``, the gather that is the kernel's
-  plain definition.  Runs unsharded — the serving engine hosts one
+  paged_store``).  A DECODE step (one query row a sequence) then attends
+  against the history: on the ``tpu`` backend through the Pallas kernel
+  that reads each sequence's pages where they lie
+  (``ops/paged_attention.py paged_decode``), on every other backend
+  through ``paged_attend``, the gather that is the kernel's plain
+  definition.  A PREFILL (more than one row: the engine's always starts
+  at position 0) attends causally among its OWN rows and reads nothing
+  from the pool: through the flash kernel from ``_FLASH_FROM`` rows on
+  the ``tpu`` backend, the dense definition below and elsewhere
+  (``_prefill_impl``), so no ``[T, max_context]`` score array is made
+  and a program's size follows its bucket, not the engine's
+  ``max_context``.  Runs unsharded — the serving engine hosts one
   replica per chip;
 - without one (training, the full forward): grouped KV heads are
   repeated to the query heads, q/k/v are constrained as the activation
@@ -208,6 +214,15 @@ def _decode_kernel(q, k_pages) -> bool:
         and paged_attention.supported(q, k_pages)
 
 
+def _to_query_heads(q, k, v):
+    """GQA: the K/V groups repeated to the query heads."""
+    rep = q.shape[2] // k.shape[2]
+    if rep != 1:
+        k = jnp.repeat(k, rep, axis=2)
+        v = jnp.repeat(v, rep, axis=2)
+    return k, v
+
+
 def attention(cfg, q, k, v, cache=None, scale=None):
     """q: [B, T, H, D]; k, v: [B, T, Hkv, D] (H a multiple of Hkv).
     Returns (att [B, T, H, D], new_cache): ``new_cache`` is the updated
@@ -226,7 +241,15 @@ def attention(cfg, q, k, v, cache=None, scale=None):
             k_pages, v_pages = paged_store(
                 cache["k_pages"], cache["v_pages"], cache["layer"],
                 k, v, cache["page_table"], cache["positions"])
-            if _decode_kernel(q, k_pages):
+            if q.shape[1] > 1:
+                # A prefill: from position 0 (``llm/engine.py
+                # _prefill_annotated``), so the rows attend among
+                # themselves and nothing is read from the pool; a bucket's
+                # padding lies behind the real ones, where the causal mask
+                # hides it from them (``latent_attention`` likewise).
+                att = _attention(cfg, q, *_to_query_heads(q, k, v), scale,
+                                 _prefill_impl(q.shape[1]))
+            elif _decode_kernel(q, k_pages):
                 # A padded row's position is -1: length 0, zeros out.
                 att = paged_attention.paged_decode(
                     q, k_pages, v_pages, cache["layer"],
@@ -237,10 +260,7 @@ def attention(cfg, q, k, v, cache=None, scale=None):
                                    cache["page_table"], cache["positions"],
                                    scale=scale)
             return att, (k_pages, v_pages)
-        rep = q.shape[2] // k.shape[2]
-        if rep != 1:  # GQA: repeat KV groups to full heads
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
+        k, v = _to_query_heads(q, k, v)
         heads = ("batch", "seq", "heads", None)
         q = with_logical_constraint(q, heads, cfg.mesh)
         k = with_logical_constraint(k, heads, cfg.mesh)

@@ -1,6 +1,7 @@
 """ONE decoder for the serving families (Llama and OLMoE, Granite 4.0-H,
-LFM2-MoE, Kimi-K2, Kimi-Linear, Xing4.0): a layer is a MIXER kind plus an
-FFN kind, joined by a RESIDUAL kind, and an architecture is a config and
+LFM2-MoE, Kimi-K2, Kimi-Linear, Xing4.0, Olmo-Hybrid): a layer is a MIXER
+kind plus an FFN kind, joined by a RESIDUAL kind, and an architecture is a
+config and
 the kind it adds (its module, its scan and step, its init and the rules of
 its own leaves stay in its file).
 
@@ -8,6 +9,11 @@ its own leaves stay in its file).
 embed, one ``Block`` (``norm -> mixer -> + -> norm -> FFN -> +``) per
 entry of ``cfg.layer_types``, ``norm_f``, the head.  What it reads of a
 config, and nothing of a family's name:
+
+- ``norm_output`` (a config WITHOUT the attribute, every family but one,
+  norms a sublayer's INPUT, the block above): true, each norm lies on the
+  sublayer's OUTPUT instead, ``x + norm(mixer(x))`` then ``h + norm(
+  ffn(h))`` (``models/olmo_hybrid.py``), under the same names in the tree;
 
 - ``residual`` (``Residual``; a config WITHOUT the attribute, every family
   but one, carries ONE stream ``x`` [B, T, d] and each sublayer adds its
@@ -299,16 +305,22 @@ class Block(nn.Module):
                 return _plus(cfg, x, branch)
             return res.write(cfg, x, maps, branch)
 
+        # Where a sublayer's norm lies: before it (``x + f(norm(x))``), or,
+        # for a config that says ``norm_output``, on what it gives (``x +
+        # norm(f(x))``: the OLMo 2 / OLMo 3 block).
+        after = getattr(cfg, "norm_output", False)
+        norm = RMSNorm(cfg.rms_eps, cfg.dtype, name=mixer.norm)
         u, maps = read(x, 0)
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name=mixer.norm)(u)
-        m = mixer.module(cfg, name=mixer.name)(y, cache)
+        m = mixer.module(cfg, name=mixer.name)(u if after else norm(u),
+                                               cache)
         m, kept = m if isinstance(m, tuple) else (m, None)
         with _scope(mixer.residual_scope):
-            x = write(x, maps, m)
+            x = write(x, maps, norm(m) if after else m)
+        norm = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")
         u, maps = read(x, 1)
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(u)
-        x = ffn(cfg, y, self.dense, positions,
-                functools.partial(write, x, maps))
+        back = functools.partial(write, x, maps)
+        x = ffn(cfg, u if after else norm(u), self.dense, positions,
+                (lambda down: back(norm(down))) if after else back)
         return x if cache is None else (x, kept)
 
 

@@ -223,14 +223,27 @@ def _decayed_scores(left, k, gcum, sub: int):
         *lead, c, c)
 
 
+def _scalar_decayed(gcum):
+    """``exp(G_i - G_j)`` for ``i >= j`` and 0 above the diagonal, for a
+    decay of ONE number a head: gcum [..., C, 1] -> [..., C, C].  With a
+    scalar the decay leaves the sum over the channels, so a chunk's scores
+    are plain matmuls times this matrix and ``_decayed_scores``' blocks are
+    not paid for; only differences <= 0 are exponentiated."""
+    c = gcum.shape[-2]
+    tri = jnp.tril(jnp.ones((c, c), bool))
+    return jnp.exp(jnp.where(tri, gcum - jnp.swapaxes(gcum, -1, -2),
+                             -jnp.inf))
+
+
 def kda_scan(q, k, v, g, beta, chunk: int, sub: int, state=None):
     """The gated delta rule over T positions in chunks.
 
-    q, k, v, g [B, T, H, D] (q scaled, q and k normalised; g <= 0 the log
-    of the decay a key channel), beta [B, T, H], all float32; a padded
+    q, k [B, T, H, D_k], v [B, T, H, D_v] (q scaled, q and k normalised),
+    g <= 0 the log of the decay, [B, T, H, D_k] (a key channel's) or
+    [B, T, H, 1] (ONE a head), beta [B, T, H], all float32; a padded
     position has g = 0 and beta = 0 (the identity).  ``state`` [B, H, D_k,
-    D_v] (None: zeros).  Returns (o [B, T, H, D], the state after the last
-    position).
+    D_v] (None: zeros).  Returns (o [B, T, H, D_v], the state after the
+    last position).
 
     Inside a chunk, with ``G`` the cumulative sum of g and ``Gamma =
     exp(G)``: ``A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)`` below the
@@ -240,7 +253,8 @@ def kda_scan(q, k, v, g, beta, chunk: int, sub: int, state=None):
     S + tril((Q K^T)_decayed) V'``; ``S' = diag(Gamma_C) S + (K * Gamma_C /
     Gamma)^T V'``.  Everything that does not depend on ``S`` is computed
     for all chunks at once; the state is carried from chunk to chunk.  No
-    ``1 / Gamma`` is formed: ``_decayed_scores`` says how.  T is filled up
+    ``1 / Gamma`` is formed: ``_decayed_scores`` says how, and for a
+    scalar decay ``_scalar_decayed``.  T is filled up
     to whole chunks with identity positions; a T shorter than a chunk is
     one chunk of whole blocks."""
     bsz, t, h, d = q.shape
@@ -261,14 +275,22 @@ def kda_scan(q, k, v, g, beta, chunk: int, sub: int, state=None):
     beta = chunks(beta)[..., None]                         # [B,nc,H,C,1]
     gcum = jnp.cumsum(g, axis=-2)
     gamma = jnp.exp(gcum)
+    if g.shape[-1] == 1 < d:            # the decay a head, not a channel
+        decayed = _scalar_decayed(gcum)
+
+        def scores(left):
+            return jnp.einsum("...id,...jd->...ij", left, k) * decayed
+    else:
+        def scores(left):
+            return _decayed_scores(left, k, gcum, sub)
     strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-    a_mat = jnp.where(strict, _decayed_scores(k, k, gcum, sub), 0.0) * beta
+    a_mat = jnp.where(strict, scores(k), 0.0) * beta
     wu = jax.scipy.linalg.solve_triangular(
         a_mat + jnp.eye(chunk, dtype=a_mat.dtype),
         beta * jnp.concatenate([k * gamma, v], axis=-1),
         lower=True, unit_diagonal=True)
     w, u = wu[..., :d], wu[..., d:]
-    qk = _decayed_scores(q, k, gcum, sub)
+    qk = scores(q)
     q_in = q * gamma
     k_out = k * jnp.exp(gcum[..., -1:, :] - gcum)
     g_out = gamma[..., -1, :]                              # [B,nc,H,D]
@@ -282,12 +304,12 @@ def kda_scan(q, k, v, g, beta, chunk: int, sub: int, state=None):
         return s, o
 
     if state is None:
-        state = jnp.zeros((bsz, h, d, d), jnp.float32)
+        state = jnp.zeros((bsz, h, d, v.shape[-1]), jnp.float32)
     state, o = jax.lax.scan(
         one, state, tuple(jnp.moveaxis(z, 1, 0)
                           for z in (w, u, qk, q_in, k_out, g_out)))
     o = jnp.moveaxis(o, 0, 1)                              # [B,nc,H,C,D]
-    o = jnp.moveaxis(o, 2, 3).reshape(bsz, nc * chunk, h, d)
+    o = jnp.moveaxis(o, 2, 3).reshape(bsz, nc * chunk, h, v.shape[-1])
     return o[:, :t], state
 
 
@@ -301,14 +323,17 @@ def _step_kernel(pool, q) -> bool:
 
 def kda_step(pool, layer, slots, fresh, q, k, v, a, beta):
     """The recurrence once for every row of a decode batch, where the
-    states lie: q, k, v, a [B, H, D] float32, beta [B, H]; ``fresh`` [B]:
-    the row starts from zeros; layer ``layer`` of the WHOLE pool [L, slots,
-    H, D_k, D_v].  A row whose slot lies outside the pool (a padded row)
-    changes nothing and gives zeros; live rows have distinct slots.
+    states lie: q, k [B, H, D_k], v [B, H, D_v], a [B, H, D_k] (the decay a
+    key channel) or [B, H, 1] (one a head) float32, beta [B, H]; ``fresh``
+    [B]: the row starts from zeros; layer ``layer`` of the WHOLE pool [L,
+    slots, H / pack, D_k, pack * D_v] (``ops/delta_rule.py state_shape``:
+    ``pack`` heads' values side by side where one head's are no whole
+    tiles; 1 otherwise).  A row whose slot lies outside the pool (a padded
+    row) changes nothing and gives zeros; live rows have distinct slots.
     Multiplies and sums in float32: no matmul rounds the state.  Returns
     (o [B, H, D_v], the pool).
 
-    On the ``tpu`` backend, a float32 pool of whole 128 x 128 states goes
+    On the ``tpu`` backend, a float32 pool of whole tiles goes
     through ``ops/delta_rule.py kda_step``: each running row's state once
     in and once out, no other slot touched.  Any other pool is worked BY
     SLOT in ``jnp``, the kernel's plain definition: the rows' small vectors
@@ -318,9 +343,9 @@ def kda_step(pool, layer, slots, fresh, q, k, v, a, beta):
     itself (three passes over the slab on the chip where the mathematics
     needs two; gathered by row instead, the states moved seven times
     their bytes in loops over the rows: my compiles for a v5e, PR 41)."""
-    if _step_kernel(pool, q):
-        from ..ops import delta_rule
+    from ..ops import delta_rule
 
+    if _step_kernel(pool, q):
         return delta_rule.kda_step(pool, layer, slots, fresh, q, k, v, a,
                                    beta)
     n_slots = pool.shape[1]
@@ -335,13 +360,15 @@ def kda_step(pool, layer, slots, fresh, q, k, v, a, beta):
     q, k, v, beta = (by_slot(x) for x in (q, k, v, beta))
     a = by_slot(a, 1.0)
     keep = 1.0 - by_slot(fresh.astype(jnp.float32))            # [S]
-    s = pool[layer].astype(jnp.float32) * keep[:, None, None, None]
+    s = delta_rule.unpack_states(pool[layer], q.shape[1]).astype(
+        jnp.float32) * keep[:, None, None, None]
     s = a[..., None] * s                                   # S~
     err = v - jnp.sum(s * k[..., None], axis=-2)           # v - S~^T k
     s = s + (beta[..., None] * k)[..., None] * err[..., None, :]
     o = jnp.sum(s * q[..., None], axis=-2)                     # [S, H, D]
     o = jnp.sum(jnp.where(hit[:, :, None, None], o[None], 0.0), axis=1)
-    return o, pool.at[layer].set(s.astype(pool.dtype))
+    return o, pool.at[layer].set(
+        delta_rule.pack_states(s, pool.shape[2]).astype(pool.dtype))
 
 
 def step_conv(x, taps, window, act):
@@ -378,10 +405,29 @@ def _l2_normalised(x):
 
 
 def _load_states(pool, layer, slots):
-    """Each row's state [B, H, D_k, D_v] gathered from its slot (an index
-    outside the pool is clipped: a padded row reads some other row's, and
-    nothing is made of it)."""
+    """Each row's slot of the layer as the pool holds it, float32."""
     return pool.at[layer, slots].get(mode="clip").astype(jnp.float32)
+
+
+def load_states(pool, layer, slots, fresh, heads: int):
+    """Each row's state [B, H, D_k, D_v] for a scan: gathered from its slot
+    of the pool (``kda_step`` has the layout; an index outside the pool is
+    clipped: a padded row reads some other row's, and nothing is made of
+    it), zeros for a ``fresh`` row."""
+    from ..ops.delta_rule import unpack_states
+
+    return jnp.where(
+        fresh[:, None, None, None], 0.0,
+        unpack_states(_load_states(pool, layer, slots), heads))
+
+
+def store_states(pool, layer, slots, states):
+    """The states a scan leaves [B, H, D_k, D_v], each into its row's slot
+    (a slot outside the pool: dropped)."""
+    from ..ops.delta_rule import pack_states
+
+    return pool.at[layer, slots].set(
+        pack_states(states, pool.shape[2]).astype(pool.dtype), mode="drop")
 
 
 class KDAMixer(nn.Module):
@@ -445,13 +491,11 @@ class KDAMixer(nn.Module):
             with jax.named_scope("kda.scan"):
                 s_in = None
                 if cache is not None:
-                    s_in = jnp.where(fresh[:, None, None, None], 0.0,
-                                     _load_states(ssm_pool, layer, slots))
+                    s_in = load_states(ssm_pool, layer, slots, fresh, h)
                 o, s_out = kda_scan(q, k, v, g, beta, cfg.kda_chunk,
                                     cfg.kda_sub_chunk, s_in)
                 if cache is not None:
-                    ssm_pool = ssm_pool.at[layer, slots].set(
-                        s_out.astype(ssm_pool.dtype), mode="drop")
+                    ssm_pool = store_states(ssm_pool, layer, slots, s_out)
         with jax.named_scope("kda.out_norm"):
             y = RMSNorm(cfg.rms_eps, f32, name="o_norm")(o) \
                 * jax.nn.sigmoid(z.astype(f32).reshape(b, t, h, dk))
@@ -478,11 +522,10 @@ MIXERS = {
 
 # ------------------------------------------------------ init, loss, rules
 
-def _special_leaf(cfg: KimiLinearConfig, name: str, key, shape):
-    """The leaves that are not normal(0, 0.02): None for the others."""
+def delta_rule_leaf(taps: int, name: str, key, shape):
+    """The delta-rule mixers' leaves that are not normal(0, 0.02) (this
+    family's and ``models/olmo_hybrid.py``'s): None for the others."""
     leaf = name.rsplit("/", 1)[-1]
-    if leaf == "expert_bias":
-        return EXPERT_BIAS_STD * jax.random.normal(key, shape, jnp.float32)
     if leaf == "A_log":     # a head's rate, uniform in [1, 16]
         return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0,
                                           16.0))
@@ -491,9 +534,16 @@ def _special_leaf(cfg: KimiLinearConfig, name: str, key, shape):
                      * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
         return dt + jnp.log(-jnp.expm1(-dt))
     if leaf == "conv_w":    # PyTorch's depthwise default, no bias
-        bound = cfg.kda_conv ** -0.5
+        bound = taps ** -0.5
         return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
     return None
+
+
+def _special_leaf(cfg: KimiLinearConfig, name: str, key, shape):
+    """The leaves that are not normal(0, 0.02): None for the others."""
+    if name.rsplit("/", 1)[-1] == "expert_bias":
+        return EXPERT_BIAS_STD * jax.random.normal(key, shape, jnp.float32)
+    return delta_rule_leaf(cfg.kda_conv, name, key, shape)
 
 
 def kimi_linear_init(cfg: KimiLinearConfig, rng):

@@ -35,6 +35,19 @@ and the kernel takes lane ``c H + h`` of it for head ``h``.  ``S^T k`` and
 
 Grid (B,): one step a row, walked in order by one core (the copies of a
 row's first block start in the step before).
+
+A state whose ``d_v`` is no whole number of 128-lane tiles (Gated
+DeltaNet's 96 x 192, ``models/olmo_hybrid.py``) would be padded to the next
+tile in HBM and in VMEM alike (192 -> 256: a third more bytes through the
+one thing that sets the time).  The pool then holds ``pack`` heads' states
+SIDE BY SIDE on the lanes (``state_shape``: two heads of 192 are 384 lanes,
+three whole tiles; ``d_k`` = 96 is twelve whole sublane tiles), as
+``[L, slots, H / pack, d_k, pack * d_v]``: no padding anywhere.  ``v`` and
+``o`` in that layout are plain reshapes of ``[B, H, d_v]``; the columns
+that scale a state's rows differ between the heads of a pack, so the kernel
+picks each lane's head with a select (VPU work under the copies).  The
+layout is read from the shapes alone (``pool.shape[2]`` against ``q``'s
+heads); ``pack`` = 1 is the layout above, program for program.
 """
 
 from __future__ import annotations
@@ -46,15 +59,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Heads a block holds: 16 states of 128 x 128 float32 are 1 MB a copy.
-BLOCK_HEADS = 16
+# What one copy moves at most: 16 states of 128 x 128 float32 (blocks of
+# 0.5 MB and of 2 MB were no faster, the module docstring has the runs).
+BLOCK_BYTES = 1 << 20
+
+
+def state_shape(heads: int, d_k: int, d_v: int):
+    """One sequence's states of one layer as the pool holds them, ``(heads
+    / pack, d_k, pack * d_v)``: ``pack`` the fewest heads (1, 2 or 4, a
+    divisor of ``heads``) whose values side by side fill whole 128-lane
+    tiles; 1 where none does (such a pool stays off the kernel)."""
+    pack = next((p for p in (1, 2, 4)
+                 if heads % p == 0 and (p * d_v) % 128 == 0), 1)
+    return heads // pack, d_k, pack * d_v
+
+
+def pack_states(s, pool_heads: int):
+    """[..., H, d_k, d_v] -> the pool's [..., H / pack, d_k, pack * d_v]
+    (nothing at ``pack`` = 1)."""
+    *lead, h, dk, dv = s.shape
+    if h == pool_heads:
+        return s
+    pack = h // pool_heads
+    return jnp.moveaxis(s.reshape(*lead, pool_heads, pack, dk, dv), -3,
+                        -2).reshape(*lead, pool_heads, dk, pack * dv)
+
+
+def unpack_states(s, heads: int):
+    """``pack_states``' inverse: the pool's layout -> [..., H, d_k, d_v]."""
+    *lead, hp, dk, w = s.shape
+    if hp == heads:
+        return s
+    pack = heads // hp
+    return jnp.moveaxis(s.reshape(*lead, hp, dk, pack, w // pack), -2,
+                        -3).reshape(*lead, heads, dk, w // pack)
 
 
 def supported(pool, q) -> bool:
     """Whether the compiled kernel takes these shapes: a float32 pool
-    ``[L, slots, H, d_k, d_v]`` whose states are whole 128 x 128 tiles."""
+    ``[L, slots, H / pack, d_k, pack * d_v]`` whose states are whole tiles
+    (``d_k`` whole sublanes of 8, the lanes whole 128s) and whose heads
+    ``q``'s [B, H, d_k] are a whole number of packs of."""
     return (pool.ndim == 5 and pool.dtype == jnp.float32 and q.ndim == 3
-            and pool.shape[3] % 128 == 0 and pool.shape[4] % 128 == 0)
+            and pool.shape[3] % 8 == 0 and pool.shape[4] % 128 == 0
+            and q.shape[1] % pool.shape[2] == 0)
 
 
 def _back(j, n, n_blk):
@@ -69,7 +117,26 @@ def _step_kernel(layer_ref, slots_ref, fresh_ref, cols_ref, v_ref, s_in,
                  *, heads, hb):
     b, rows = pl.program_id(0), pl.num_programs(0)
     layer, n_slots = layer_ref[0], s_in.shape[1]
-    n_blk = heads // hb
+    pool_heads, width = s_in.shape[2], s_in.shape[4]
+    n_blk, pack = pool_heads // hb, heads // pool_heads
+    # which head of its pack a lane belongs to
+    lane_head = None if pack == 1 else jax.lax.broadcasted_iota(
+        jnp.int32, (1, width), 1) // (width // pack)
+
+    def columns(h0):
+        """The four vectors that scale the rows of pool head ``h0``'s
+        states, each [d_k, 1], or with ``pack`` heads side by side [d_k,
+        pack * d_v]: every lane its own head's."""
+        def column(c, h):
+            return cols_ref[0, :, c * heads + h:c * heads + h + 1]
+
+        out = []
+        for c in range(4):
+            x = column(c, h0 * pack)
+            for u in range(1, pack):
+                x = jnp.where(lane_head >= u, column(c, h0 * pack + u), x)
+            out.append(x)
+        return out
 
     def live(row):
         return (slots_ref[row] >= 0) & (slots_ref[row] < n_slots)
@@ -128,9 +195,7 @@ def _step_kernel(layer_ref, slots_ref, fresh_ref, cols_ref, v_ref, s_in,
 
             for i in range(hb):
                 h = j * hb + i
-                a, k, q, kb = (cols_ref[0, :, c * heads + h:
-                                        c * heads + h + 1]
-                               for c in range(4))              # [d_k, 1]
+                a, k, q, kb = columns(h)
                 s = a * in_buf[slot, i]                        # S~
                 err = v_ref[0, h:h + 1, :] - jnp.sum(
                     s * k, axis=0, keepdims=True)              # v - S~^T k
@@ -151,11 +216,13 @@ def _step_kernel(layer_ref, slots_ref, fresh_ref, cols_ref, v_ref, s_in,
 
 
 def kda_step(pool, layer, slots, fresh, q, k, v, a, beta,
-             *, block_heads: int = BLOCK_HEADS,
+             *, block_heads: int | None = None,
              interpret: bool | None = None):
     """The recurrence once for every row of a decode batch, each on its
-    slot of layer ``layer`` of the WHOLE pool ``[L, slots, H, d_k, d_v]``
-    (float32): q, k, v, a ``[B, H, D]`` and beta ``[B, H]`` float32,
+    slot of layer ``layer`` of the WHOLE pool ``[L, slots, H / pack, d_k,
+    pack * d_v]`` (float32; ``state_shape``): q, k ``[B, H, d_k]``, v ``[B,
+    H, d_v]``, a ``[B, H, d_k]`` (a decay a key channel) or ``[B, H, 1]``
+    (one a head) and beta ``[B, H]`` float32,
     ``slots`` ``[B]`` (an index outside the pool: a padded row, which
     touches nothing and gives zeros), ``fresh`` ``[B]`` (the row starts
     from zeros whatever its slot held).  ``S~ = diag(a) S``; ``S' = S~ +
@@ -165,11 +232,16 @@ def kda_step(pool, layer, slots, fresh, q, k, v, a, beta,
     is left as it was.  ``models/kimi_linear.py kda_step``'s by-slot form is
     its plain definition.
 
-    ``interpret=None`` runs the compiled kernel on the ``tpu`` backend
-    and the interpreter elsewhere (tests)."""
+    ``block_heads``: the pool heads one copy moves (None: the most that
+    divide the pool's heads within ``BLOCK_BYTES``).  ``interpret=None``
+    runs the compiled kernel on the ``tpu`` backend and the interpreter
+    elsewhere (tests)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     f32 = jnp.float32
+    if block_heads is None:
+        block_heads = max(BLOCK_BYTES // (4 * pool.shape[3] * pool.shape[4]),
+                          1)
     # The layer is an operand: 20 call sites cost the tracing of one.
     return _kda_step(
         pool, jnp.asarray(layer, jnp.int32).reshape(1),
@@ -181,13 +253,15 @@ def kda_step(pool, layer, slots, fresh, q, k, v, a, beta,
 @functools.partial(jax.jit, static_argnames=("block_heads", "interpret"))
 def _kda_step(pool, layer, slots, fresh, q, k, v, a, beta, *, block_heads,
               interpret):
-    b, h, dk = q.shape
-    dv = pool.shape[4]
+    b, heads, dk = q.shape
+    h, dv = pool.shape[2], pool.shape[4]    # heads and lanes as the pool's
     hb = max(n for n in range(1, min(block_heads, h) + 1) if h % n == 0)
+    v = v.reshape(b, h, dv)
     # The four vectors that scale a state's rows, as its columns.
-    cols = jnp.stack([a, k, q, beta[..., None] * k], axis=1)   # [B,4,H,D]
-    cols = jnp.moveaxis(cols, 3, 1).reshape(b, dk, 4 * h)
-    kernel = functools.partial(_step_kernel, heads=h, hb=hb)
+    cols = jnp.stack([jnp.broadcast_to(a, k.shape), k, q,
+                      beta[..., None] * k], axis=1)            # [B,4,H,D]
+    cols = jnp.moveaxis(cols, 3, 1).reshape(b, dk, 4 * heads)
+    kernel = functools.partial(_step_kernel, heads=heads, hb=hb)
     o, pool = pl.pallas_call(
         kernel,
         name="kda_step",
@@ -195,7 +269,7 @@ def _kda_step(pool, layer, slots, fresh, q, k, v, a, beta, *, block_heads,
             num_scalar_prefetch=3,
             grid=(b,),
             in_specs=[
-                pl.BlockSpec((1, dk, 4 * h), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, dk, 4 * heads), lambda i, *_: (i, 0, 0)),
                 pl.BlockSpec((1, h, dv), lambda i, *_: (i, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
@@ -219,4 +293,4 @@ def _kda_step(pool, layer, slots, fresh, q, k, v, a, beta, *, block_heads,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(layer, slots, fresh, cols, v, pool)
-    return o, pool
+    return o.reshape(b, heads, -1), pool

@@ -45,8 +45,28 @@ PARENT = {
     "xing40": ((93, "937dcfec63c2317a"),
                (4, 0, 0, 0, (), (), 24, 8),
                (40, 0, 0, 0, (), (), 512, 64)),
+    # (PR 48's own tree: the first row whose block norms each sublayer's
+    # OUTPUT; K/V for its attention layers beside the delta rule's state,
+    # held as pairs of heads at the published widths)
+    "olmohybrid": ((121, "79e4d4c6be19723b"),
+                   (2, 6, 10, 6, (3, 288), (6, 12, 24), 0, 0),
+                   (8, 30, 128, 24, (3, 11520), (15, 96, 384), 0, 0)),
 }
 ROWS = sorted(PARENT)
+# row -> sha256[:16] of the lowered text (``lower().as_text()``, no debug
+# info) of the tiny preset's full forward over [2, 16] tokens and of its
+# decode step [2, 1] through ``jit_forward`` (16 pages of 4, 2 slots, a
+# table of 8 pages), read from PR 47's tree, the parent of the PR that
+# taught the block a second norm placement.
+PARENT_TEXT = {
+    "llama": ("43d357f519b17ee4", "8a441a1186e41b65"),
+    "olmoe": ("d6b57ab637dddbdb", "1f5190c8f2beedf4"),
+    "granitemoehybrid": ("1861a65bd88f2d2d", "b06880576c4ef440"),
+    "lfm2moe": ("3659f4819c814bfa", "f01d30a32d8e8cdb"),
+    "kimik2": ("069d35638dcb9998", "3bc9756184d110a6"),
+    "kimilinear": ("c63a86a8f72f8871", "342c2f867ea51873"),
+    "xing40": ("15032390f5e6a9d8", "5af8f70dce770c0f"),
+}
 
 
 def _published(name):
@@ -107,6 +127,56 @@ def test_the_rows_module_runs_its_layers_through_the_decoder(
                    jnp.zeros((1, 8), jnp.int32))
     assert built == [(f"layer_{i}", kind, i < cfg.n_dense_layers)
                      for i, kind in enumerate(cfg.layer_types)]
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_TEXT))
+def test_the_norms_placement_leaves_the_older_rows_programs_alone(name):
+    """``Block`` reads ``norm_output`` from the config; a config without
+    the attribute (the seven older rows) lowers to the text it lowered to
+    before the block knew the second placement: the full forward and the
+    decode step, letter for letter.  (A K/V row's PREFILL text did change
+    in that PR, by ``models/attention.py``: tests/test_paged_attention.py
+    holds it to the old form's numbers.)"""
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_pool, init_state
+
+    fam = MODEL_FAMILIES[name]
+    cfg = dataclasses.replace(fam.tiny(), remat=False)
+    assert not hasattr(cfg, "norm_output")
+    params = jax.eval_shape(lambda: fam.init(cfg, jax.random.PRNGKey(0)))
+    full = jax.jit(lambda p, t: fam.module(cfg).apply(p, t)).lower(
+        params, jax.ShapeDtypeStruct((2, 16), jnp.int32)).as_text()
+    spec = fam.cache(cfg)
+    kv = jax.eval_shape(lambda: init_pool(spec, 16, 4, cfg.dtype))
+    state = jax.eval_shape(lambda: init_state(spec, 2, cfg.dtype))
+    ints = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    args = [params, ints] + [kv[k] for k in pool_arrays(spec)] + [
+        jax.ShapeDtypeStruct((2, 8), jnp.int32), ints]
+    if state_arrays(spec):
+        args += [state[k] for k in state_arrays(spec)] + [
+            jax.ShapeDtypeStruct((2,), jnp.int32)]
+    step = jit_forward(fam.module(cfg)).lower(*args).as_text()
+    assert tuple(hashlib.sha256(text.encode()).hexdigest()[:16]
+                 for text in (full, step)) == PARENT_TEXT[name]
+
+
+def test_the_blocks_two_norm_placements_share_one_tree():
+    """The output-side block (``norm_output``: ``x + norm(f(x))``) keeps
+    the names the input-side block gives its norms, so the two differ in
+    the program alone; and they do differ."""
+    fam = MODEL_FAMILIES["olmohybrid"]
+    cfg = dataclasses.replace(fam.tiny(), remat=False)
+    assert cfg.norm_output
+
+    class Before(type(cfg)):
+        norm_output = False
+
+    before = Before(**dataclasses.asdict(cfg))
+    params = fam.init(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.arange(12, dtype=jnp.int32)[None]
+    after = fam.module(cfg).apply(params, tokens)
+    other = fam.module(before).apply(params, tokens)     # the same tree
+    assert float(jnp.max(jnp.abs(after - other))) > 1e-3
 
 
 def test_the_hooks_that_left_with_the_wrappers_fail_loud():

@@ -423,11 +423,12 @@ def test_llama_incremental_decode_token_identical():
 def test_cached_forward_goes_through_the_one_attention_core(
         family, monkeypatch):
     """Both blocks hand the paged cache to models/attention.py: each
-    layer stores once and then attends once, from there and nowhere
-    else: through ``paged_attend`` on this backend; where the dispatch
-    says kernel (the ``tpu`` backend's rule, by q's shape), a decode
-    step through ``paged_decode`` and the prefill still through
-    ``paged_attend``, to the same tokens."""
+    layer stores once, from there and nowhere else; a PREFILL then
+    attends among its own rows and reads nothing from the pool (neither
+    ``paged_attend`` nor the kernel is called), a decode step attends
+    once: through ``paged_attend`` on this backend and, where the
+    dispatch says kernel (the ``tpu`` backend's rule, by q's shape),
+    through ``paged_decode``, to the same tokens."""
     import sys
 
     import jax
@@ -458,16 +459,18 @@ def test_cached_forward_goes_through_the_one_attention_core(
         return _decode_loop(fam.module(cfg), params, cfg,
                             fam.cache(cfg).kv_heads, [3, 17, 42], 2)[0]
 
-    def layers(attend):
-        return [("paged_store", "ray_tpu.models.attention"),
-                (attend, "ray_tpu.models.attention")] * cfg.n_layer
+    store = ("paged_store", "ray_tpu.models.attention")
+
+    def layers(attend):     # the prefill's stores, then the decode step
+        return [store] * cfg.n_layer + [
+            store, (attend, "ray_tpu.models.attention")] * cfg.n_layer
 
     tokens = two_steps()
-    assert callers == layers("paged_attend") * 2
+    assert callers == layers("paged_attend")
     monkeypatch.setattr(attention, "_decode_kernel",
                         lambda q, k_pages: q.shape[1] == 1)
     assert two_steps() == tokens
-    assert callers == layers("paged_attend") + layers("paged_decode")
+    assert callers == layers("paged_decode")
 
 
 # --------------------------------------------- the pool stays in place

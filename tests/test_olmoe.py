@@ -219,7 +219,8 @@ def test_engine_builds_every_family_from_its_registry_row(family):
                    "kimilinear", "xing40"))
     assert ("residual" in engine.stats()) == (family == "xing40")
     assert ("state" in engine.stats()) == (
-        family in ("granitemoehybrid", "lfm2moe", "kimilinear"))
+        family in ("granitemoehybrid", "lfm2moe", "kimilinear",
+                   "olmohybrid"))
 
 
 def test_engine_counts_gpt2s_moe_option_too():

@@ -185,3 +185,80 @@ def test_engine_tokens_are_the_same_through_the_kernel(family,
     assert counts["decode_runs"] == stats["steps"] > 0
     assert 0 < counts["kv_rows_read"] < counts["kv_rows_held"]
     assert counts["kv_rows_read"] % (16 * cfg.n_layer) == 0
+
+
+# ------------------------------- a K/V prefill attends among its own rows
+
+def _parent_form(cfg, q, k, v, cache=None, scale=None):
+    """The cached branch as it was before PR 48: store, then
+    ``paged_attend`` over the sequence's whole page table."""
+    from ray_tpu.llm.kv_cache import paged_attend, paged_store
+
+    k_pages, v_pages = paged_store(
+        cache["k_pages"], cache["v_pages"], cache["layer"], k, v,
+        cache["page_table"], cache["positions"])
+    return paged_attend(q, k_pages, v_pages, cache["layer"],
+                        cache["page_table"], cache["positions"],
+                        scale=scale), (k_pages, v_pages)
+
+
+@pytest.mark.parametrize("n", [16, 11, 3], ids=["whole", "padded", "short"])
+@pytest.mark.parametrize("family", ["gpt2", "olmoe", "granitemoehybrid",
+                                    "lfm2moe"])
+def test_a_prefill_among_its_own_rows_equals_paged_attend_over_the_pool(
+        family, n, monkeypatch):
+    """The engine's prefill ([1, 16], ``n`` real positions from 0, the rest
+    padding behind them) of the four K/V families' tiny presets (GPT-2's
+    learned positions, OLMoE's QK-norm over the width and RoPE, Granite's
+    grouped heads with no rotation and its own scale beside the state
+    pool, LFM2's per-head QK-norm) through ``models/attention.py
+    attention`` as it is, which attends among the step's own rows, against
+    the same forward with the cached branch as it was (``paged_attend``
+    over the page table, gathered from a pool that held other numbers):
+    the real positions' logits agree to float32's rounding, the pool's
+    first layer and every page the prompt does not own are BIT-equal, the
+    other layers' rows (which follow the attention before them) to 2e-5."""
+    import ray_tpu.models.decoder as decoder
+    import ray_tpu.models.gpt2 as gpt2
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import (init_pool, init_state, pool_arrays,
+                                      state_arrays)
+    from ray_tpu.models import MODEL_FAMILIES
+
+    fam = MODEL_FAMILIES[family]
+    cfg = dataclasses.replace(fam.tiny(), remat=False, dtype=jnp.float32)
+    params = fam.init(cfg, jax.random.PRNGKey(2))
+    params = jax.tree_util.tree_map(
+        lambda w: 8.0 * w if w.ndim > 1 else w, params)
+    spec = fam.cache(cfg)
+    rng = np.random.default_rng(n)
+    tokens = np.zeros((1, 16), np.int32)
+    tokens[0, :n] = rng.integers(0, cfg.vocab_size, n)
+    positions = np.full((1, 16), -1, np.int32)
+    positions[0, :n] = np.arange(n)
+    table = np.array([[5, 2, 7, 1, 0, 0, 0, 0]], np.int32)  # pages of 4
+
+    def run():
+        pools = [a + 1 for a in init_pool(spec, 9, 4, jnp.float32).values()]
+        state = [a + 1 for a in init_state(spec, 2, jnp.float32).values()]
+        slots = [np.array([1], np.int32)] if state else []
+        assert len(pools) == len(pool_arrays(spec)) == 2
+        assert len(state) == len(state_arrays(spec))
+        out = jit_forward(fam.module(cfg))(
+            params, tokens, *pools, table, positions, *state, *slots)
+        return (np.asarray(out[0]),) + tuple(np.asarray(a) for a in out[1:3])
+
+    logits, k_own, v_own = run()
+    monkeypatch.setattr(decoder, "attention", _parent_form)
+    monkeypatch.setattr(gpt2, "attention", _parent_form)
+    want, k_pool, v_pool = run()
+    size = float(np.max(np.abs(want[0, :n])))
+    assert size > 1e-3 and float(np.max(np.abs(
+        logits[0, :n] - want[0, :n]))) < 1e-4 * size
+    owned = sorted(set(table[0, :-(-n // 4)].tolist()))
+    others = [p for p in range(9) if p not in owned]
+    for own, pool in ((k_own, k_pool), (v_own, v_pool)):
+        np.testing.assert_array_equal(own[0], pool[0])
+        np.testing.assert_array_equal(own[:, others], pool[:, others])
+        np.testing.assert_array_equal(own[:, others], 1.0)
+        np.testing.assert_allclose(own, pool, atol=2e-5, rtol=1e-4)

@@ -613,11 +613,19 @@ def test_lowered_train_step_keeps_the_logits_under_loss_or_lm_head():
 
 
 def test_lowered_engine_forward_names_the_cache_scopes():
+    """A prefill stores its rows and attends among them (no ``kv.attend``:
+    nothing is read from the pool); a decode step attends the pool."""
     fwd, args = _forward_and_args()
     text = fwd.lower(*args).as_text(debug_info=True)
-    for name in ("kv.store", "kv.attend", "attn.core", "embed", "lm_head"):
+    for name in ("kv.store", "attn.core", "embed", "lm_head"):
         assert name in text, name
+    assert "kv.attend" not in text
     assert "jit_fwd" in text        # the reader of decode.device_ms.sat
+    params, tokens, k_pages, v_pages, table, positions = args
+    text = fwd.lower(params, tokens[:, 2:3], k_pages, v_pages, table,
+                     positions[:, 2:3]).as_text(debug_info=True)
+    for name in ("kv.store", "kv.attend", "attn.core", "jit_fwd"):
+        assert name in text, name
 
 
 def test_lowered_sampler_is_a_program_of_its_own_under_scope_sample():

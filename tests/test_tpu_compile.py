@@ -221,9 +221,11 @@ def engine_program(one_chip):
 @contextlib.contextmanager
 def _as_on_tpu(kernel=True):
     """The cached attention dispatches as the backend ``tpu`` would (a
-    decode step takes the compiled paged-decode kernel); with
+    decode step takes the compiled paged-decode kernel, a prefill of 256
+    rows or more the compiled flash kernel among its own rows); with
     ``kernel=False`` it is left as this backend has it."""
     import ray_tpu.models.attention as attention
+    import ray_tpu.ops
     from ray_tpu.ops import paged_attention
 
     with pytest.MonkeyPatch.context() as patch:
@@ -233,6 +235,11 @@ def _as_on_tpu(kernel=True):
             patch.setattr(paged_attention, "paged_decode",
                           functools.partial(paged_attention.paged_decode,
                                             interpret=False))
+            patch.setattr(
+                attention, "_prefill_impl", lambda t: "flash"
+                if t >= attention._FLASH_FROM else "dense")
+            patch.setattr(ray_tpu.ops, "flash_attention", functools.partial(
+                ray_tpu.ops.flash_attention, interpret=False))
         yield
 
 
@@ -308,20 +315,22 @@ def test_large_decode_cell_attends_through_the_paged_kernel(
     _assert_attends_in_place(compiled, pool, 36, 0.3e9)
 
 
-def test_prefill_program_is_the_same_on_either_dispatch(engine_program):
-    """A prefill (T > 1) attends through ``paged_attend`` whatever the
-    backend: the dispatch that hands a decode step to the kernel leaves
-    its program as it was, to the letter."""
-    def text(kernel):
-        # Less what records the Python call stack: each instruction's
-        # metadata and the tables of files and frames at the top.
-        compiled, _ = engine_program("124m", (1, 512), 2048, kernel=kernel)
-        text = re.sub(r",? ?metadata=\{[^}]*\}", "", compiled.as_text())
-        return re.sub(r"(?s)\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n",
-                      text, count=1)
-
-    assert "paged_decode" not in text(True)
-    assert text(True) == text(False)
+@pytest.mark.parametrize("kernel", [True, False],
+                         ids=["flash", "dense"])
+def test_prefill_program_attends_among_its_own_rows(engine_program, kernel):
+    """A prefill (T > 1) stores its K/V and attends among its OWN rows
+    (models/attention.py since PR 48): as the backend ``tpu`` dispatches,
+    through the flash kernel once a layer; either way the program reads
+    nothing back from the pool (no gather of a sequence's pages: its
+    32,768 positions of 12 heads would be [1, 32768, 768] a layer), calls
+    no ``paged_decode`` and holds no score array over ``max_context``."""
+    compiled, pool = engine_program("124m", (1, 512), 2048, kernel=kernel)
+    text = compiled.as_text()
+    assert "paged_decode" not in text
+    assert _kernel_calls(text)["flash_fwd"] == (12 if kernel else 0)
+    positions = 2048 * 16
+    assert not re.search(rf"\[[\d,]*\b{positions}\b[\d,]*\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
 @pytest.mark.parametrize("h,h_kv,d,dtype", [
@@ -904,6 +913,124 @@ def test_kimi_linear_cell_compiles_at_the_benchmarks_sizes(one_chip,
     else:
         assert "kda.scan" in text and "kda.step" not in text
         assert len(made) >= 20
+
+
+def test_kda_step_kernel_compiles_at_30_heads_of_96_by_192(one_chip):
+    """The delta rule's decode step at Olmo-Hybrid's sizes: 30 heads of 96 x
+    192 with ONE decay a head, the pool holding two heads side by side
+    ([12, 16, 15, 96, 384]: whole tiles, ops/delta_rule.py state_shape),
+    blocks of 5 pairs (three a row: the buffers alternate across the rows).
+    One kernel, the donated pool aliased through it and never copied."""
+    from ray_tpu.ops import delta_rule
+
+    on = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    shape = delta_rule.state_shape(30, 96, 192)
+    assert shape == (15, 96, 384)
+    pool = on((12, 16) + shape, jnp.float32)
+    row = on((16, 30, 96), jnp.float32)
+    assert delta_rule.supported(pool, row)
+    compiled = jax.jit(functools.partial(
+        delta_rule.kda_step, layer=3, interpret=False),
+        donate_argnums=(0,)).lower(
+        pool, slots=on((16,), jnp.int32), fresh=on((16,), jnp.bool_),
+        q=row, k=row, v=on((16, 30, 192), jnp.float32),
+        a=on((16, 30, 1), jnp.float32),
+        beta=on((16, 30), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert re.search(r"^\s*(?:ROOT )?%kda_step[\w.]* = .*custom-call\(", text,
+                     re.M)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 12 * 16 * 30 * 96 * 192 * 4
+    assert m.temp_size_in_bytes < 4e6
+    assert _logits_shape(compiled) == (16, 30, 192)
+
+
+@pytest.mark.parametrize("tokens_shape", [(16, 1), (1, 4096)],
+                         ids=["decode", "prefill4096"])
+def test_olmo_hybrid_cell_compiles_at_the_benchmarks_sizes(one_chip,
+                                                           tokens_shape):
+    """The decode and ``prefill[4096]`` programs of serve-olmo-hybrid-7b-4k
+    at the benchmark's sizes (16 layers at the published widths: 12 Gated
+    DeltaNet mixers of 30 heads of 96 x 192, 4 full-attention layers of 30
+    heads of 128; the WHOLE vocabulary; bf16; max_batch 16, 4096 pages of
+    16, max_context 4096), as the backend ``tpu`` builds them: 8.20 GB of
+    weights, K and V pools [4, 4096, 16, 3840] and the state pool's
+    ``conv`` [12, 16, 3, 11520] and float32 ``ssm`` [12, 16, 15, 96, 384],
+    all four aliased to the outputs; 12.67 GB of arguments, under the
+    chip's memory with the prefill's temporaries.  The decode step runs the
+    recurrence through the ``kda_step`` kernel once a linear layer (no
+    gathered or copied state slab) and attends through the paged kernel
+    once an attention layer; the prefill runs the chunked scan (a
+    triangular solve a linear layer) and the flash kernel among its own
+    rows: no ``[.., 4096, 4096]`` float32 array, which the gather over
+    ``max_context`` rows made 2 GB a layer of; the logits are the one served
+    position's."""
+    import ray_tpu.models.kimi_linear as kimi_linear
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_pool, init_state, pages_for
+    from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.models.olmo_hybrid import ATTENTION, GDN, OlmoHybridConfig
+    from ray_tpu.ops import delta_rule
+
+    row = MODEL_FAMILIES["olmohybrid"]
+    cfg = OlmoHybridConfig(layer_types=(GDN, GDN, GDN, ATTENTION) * 4,
+                           attn_impl="dense", remat=False)
+    spec = row.cache(cfg)
+    params = jax.eval_shape(lambda: row.init(cfg, jax.random.PRNGKey(0)))
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert abs(weights - 8.203e9) < 0.001 * 8.203e9
+    kv = jax.eval_shape(lambda: init_pool(spec, 4096, 16, cfg.dtype))
+    state = jax.eval_shape(lambda: init_state(spec, 16, cfg.dtype))
+    assert kv["k_pages"].shape == (4, 4096, 16, 3840)
+    assert state["conv"].shape == (12, 16, 3, 11520)
+    assert state["ssm"].shape == (12, 16, 15, 96, 384)
+    b = tokens_shape[0]
+    ints = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                             sharding=one_chip)
+    with _as_on_tpu(), pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kimi_linear, "_step_kernel", delta_rule.supported)
+        patch.setattr(delta_rule, "kda_step", functools.partial(
+            delta_rule.kda_step, interpret=False))
+        compiled = jit_forward(row.module(cfg)).lower(
+            _on(params, one_chip), ints(tokens_shape),
+            _on(kv["k_pages"], one_chip), _on(kv["v_pages"], one_chip),
+            ints((b, pages_for(4096, 16))), ints(tokens_shape),
+            _on(state["conv"], one_chip), _on(state["ssm"], one_chip),
+            ints((b,)), **_served(tokens_shape, one_chip)).compile()
+    decode = tokens_shape[1] == 1
+    assert 0.70 * 16.9e9 < _device_bytes(compiled) < (
+        12.8e9 if decode else 13.6e9)
+    assert _logits_shape(compiled) == (b, 1, cfg.vocab_size)
+    m = compiled.memory_analysis()
+    pools = [kv["k_pages"], kv["v_pages"], state["conv"], state["ssm"]]
+    assert m.alias_size_in_bytes == sum(a.size * a.dtype.itemsize
+                                        for a in pools)
+    assert m.temp_size_in_bytes < (0.1e9 if decode else 0.9e9)
+    text = compiled.as_text()
+    assert not re.search(r"f32\[[\d,]*4096,4096\]", text)
+    # no pass over the state pool but a prefill's one row written in place
+    shape = ",".join(map(str, state["ssm"].shape))
+    slab = ",".join(map(str, state["ssm"].shape[1:]))
+    passes = [line.strip()[:120] for line in text.splitlines()
+              if (hit := _POOL_PASS.match(line))
+              and hit.group(1) in (shape, slab)
+              and (decode or hit.group(2) != "dynamic-update-slice")]
+    assert not passes, (len(passes), passes[:4])
+
+    def calls(kernel):
+        return len(re.findall(
+            rf"^\s*(?:ROOT )?%{kernel}[\w.]* = .*custom-call\(", text,
+            re.M))
+
+    assert calls("kda_step") == (12 if decode else 0)
+    assert calls("paged_decode") == (4 if decode else 0)
+    assert calls("flash_fwd") == (0 if decode else 4)
+    if decode:
+        assert f"f32[{slab}]" not in text
+    else:
+        assert "gdn.scan" in text and "gdn.step" not in text
 
 
 def _train_step_and_shapes(cfg, loss_chunk):
