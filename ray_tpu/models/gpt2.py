@@ -11,8 +11,11 @@ TPU-first design notes:
   where the flash kernels run per shard of a mesh it is applied through
   a view by shard of the heads (``FusedQKV``), so the split into heads
   moves that weight across the ``tensor`` axis and no activation;
-- jax.checkpoint per block when ``remat`` so long-context activation
-  memory trades against recompute;
+- jax.checkpoint per block when ``remat``: a block keeps its input AND
+  the residual stream after its attention sublayer (``ATTN_RESIDUAL``,
+  already summed over the ``tensor`` axis), so the backward pass reads
+  that sum where it would make ``c_proj``'s matmul and all-reduce again,
+  and recomputes the rest;
 - ``jax.named_scope`` names the parts (``embed``, ``attn.qkv``,
   ``attn.core``, ``attn.out``, ``mlp``, ``lm_head``, ``loss``) inside
   flax's own module scopes (``h_<i>``, ``ln_f``): a device trace and an
@@ -32,6 +35,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..parallel.sharding import logical_shards
 from ..parallel.sharding import with_logical_constraint as _constrain
@@ -148,6 +152,11 @@ class FusedQKV(nn.Module):
         return _constrain(y, ("heads", "batch", None, None), cfg.mesh)
 
 
+# The one value a remat'd block keeps beside its input: the residual
+# stream after the attention sublayer.
+ATTN_RESIDUAL = "attn_residual"
+
+
 class Block(nn.Module):
     cfg: GPT2Config
     use_moe: bool = False
@@ -180,6 +189,10 @@ class Block(nn.Module):
                            kernel_init=nn.initializers.normal(
                                0.02 / (2 * cfg.n_layer) ** 0.5))(att)
             x = x + att
+            if cache is None:
+                # Kept across the remat boundary: the SUM over the
+                # ``tensor`` axis, laid out as the block's input is.
+                x = checkpoint_name(x, ATTN_RESIDUAL)
         y = nn.LayerNorm(dtype=cfg.dtype, name="ln_2")(x)
         with jax.named_scope("mlp"):
             if self.use_moe:
@@ -245,7 +258,10 @@ class GPT2(nn.Module):
         block = Block
         if cfg.remat and not decode:
             # Decode steps are memory-light; remat would only slow them.
-            block = nn.remat(Block, prevent_cse=False)
+            block = nn.remat(
+                Block, prevent_cse=False,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    ATTN_RESIDUAL))
         if decode:
             # ONE pool through every layer, updated where it lies.
             k_pages, v_pages = kv_cache["k_pages"], kv_cache["v_pages"]

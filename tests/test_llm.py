@@ -398,6 +398,44 @@ def test_gpt2_incremental_decode_token_identical():
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
+# program -> sha256[:16] of the lowered text (``lower().as_text()``, no
+# debug info) of GPT-2's tiny preset through ``jit_forward`` over 16 pages
+# of 4 and a table of 8 pages, read from PR 48's tree, the parent of the
+# PR that taught the training block what to keep across its remat boundary.
+GPT2_PARENT_TEXT = {"prefill": ((1, 16), "f422f1cbaf690829"),
+                    "decode": ((2, 1), "57c792f3691bae6e")}
+
+
+@pytest.mark.parametrize("program", sorted(GPT2_PARENT_TEXT))
+def test_gpt2_cached_forward_lowers_to_the_parents_text(program):
+    """What a remat'd block keeps is named on the training path alone
+    (``cache is None``): the serving programs, the prefill as the engine
+    calls it (``last``) and the decode step, lower to the text they
+    lowered to before, letter for letter."""
+    import hashlib
+
+    import jax.numpy as jnp
+
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import init_pool, pool_arrays
+    from ray_tpu.models import MODEL_FAMILIES
+
+    fam = MODEL_FAMILIES["gpt2"]
+    cfg = dataclasses.replace(fam.tiny(), remat=False)
+    params = jax.eval_shape(lambda: fam.init(cfg, jax.random.PRNGKey(0)))
+    spec = fam.cache(cfg)
+    kv = jax.eval_shape(lambda: init_pool(spec, 16, 4, cfg.dtype))
+    shape, want = GPT2_PARENT_TEXT[program]
+    ints = jax.ShapeDtypeStruct(shape, jnp.int32)
+    served = {} if shape[1] == 1 else {
+        "last": jax.ShapeDtypeStruct(shape[:1], jnp.int32)}
+    text = jit_forward(fam.module(cfg)).lower(
+        params, ints, *[kv[k] for k in pool_arrays(spec)],
+        jax.ShapeDtypeStruct((shape[0], 8), jnp.int32), ints,
+        **served).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+
 def test_llama_incremental_decode_token_identical():
     """GQA cache (h_kv < h) + positional RoPE through the paged path."""
     import jax

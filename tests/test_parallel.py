@@ -371,12 +371,10 @@ def _moved_in_blocks(text, t):
                    if re.search(rf"[\[,]{t}[,\]]", f[1])]
 
 
-@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_sharded_step_moves_no_activation_for_the_qkv_split(remat):
-    """fsdp=2 x tensor=2, 4 heads of 64: the compiled training step holds
-    no all-to-all under a block and no collective-permute of an
-    activation there (the parent's held two permutes of [B, T, H*D] a
-    layer and pass under ``attn.qkv/split``)."""
+@functools.lru_cache(maxsize=None)
+def _qkv_step(remat):
+    """The training step of ``_qkv_cfg(4)`` (4 heads of 64) over fsdp=2 x
+    tensor=2, run once: its config, its loss and its compiled text."""
     from ray_tpu.train.train_step import make_optimizer
 
     mesh = gang_mesh({"fsdp": 2, "tensor": 2}, jax.devices()[:4])
@@ -386,8 +384,17 @@ def test_sharded_step_moves_no_activation_for_the_qkv_split(remat):
     tokens = jax.device_put(jnp.zeros((4, cfg.max_seq + 1), jnp.int32),
                             batch_sharding)
     _, metrics = step(state, {"tokens": tokens})
-    assert np.isfinite(float(metrics["loss"]))
-    text = step.compiled().as_text()
+    return cfg, float(metrics["loss"]), step.compiled().as_text()
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_sharded_step_moves_no_activation_for_the_qkv_split(remat):
+    """fsdp=2 x tensor=2, 4 heads of 64: the compiled training step holds
+    no all-to-all under a block and no collective-permute of an
+    activation there (the parent's held two permutes of [B, T, H*D] a
+    layer and pass under ``attn.qkv/split``)."""
+    cfg, loss, text = _qkv_step(remat)
+    assert np.isfinite(loss)
     assert "all-reduce" in text             # it IS the sharded program
     found, activations = _moved_in_blocks(text, cfg.max_seq)
     assert not activations, activations
@@ -396,6 +403,102 @@ def test_sharded_step_moves_no_activation_for_the_qkv_split(remat):
     sample = ('%cp = (f32[2,128,256]{2,1,0}, u32[]) collective-permute-start('
               '%x), metadata={op_name="jit(step)/h_0/attn.qkv/split"}')
     assert _moved_in_blocks(sample, 128)[1]
+
+
+# ---------------------------------------------------------------------
+# What a block keeps across its remat boundary (PR 50): its input and the
+# residual stream after the attention sublayer, so the backward pass makes
+# neither ``c_proj``'s all-reduce nor a matmul again.
+
+_INSTRUCTION = re.compile(
+    r"= (?P<type>\([^=]*?\)|\S+) (?P<op>[\w-]+)\("
+    r".*op_name=\"(?P<name>[^\"]*)\"")
+
+
+def block_sums_and_recomputed(text, t, d):
+    """Of a compiled step: how many all-reduce OPERANDS of an activation
+    (``[.., t, d]``; a combined all-reduce's tuple counts each) lie under
+    each block ``h_<i>``, and the ``op_name`` of every all-reduce, ``dot``
+    and ``convolution`` under ``rematted_computation/``."""
+    sums, recomputed = {}, []
+    for m in filter(None, map(_INSTRUCTION.search, text.splitlines())):
+        op, name = m.group("op").removesuffix("-start"), m.group("name")
+        if op not in ("all-reduce", "dot", "convolution"):
+            continue
+        if "rematted_computation/" in name:
+            recomputed.append((op, name))
+        layer = re.search(r"/(h_\d+)/", name)
+        if op == "all-reduce" and layer:
+            n = len(re.findall(rf"\[[\d,]*\b{t},{d}\]", m.group("type")))
+            sums[layer.group(1)] = sums.get(layer.group(1), 0) + n
+    return sums, recomputed
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_sharded_step_sums_four_activations_a_block(remat):
+    """fsdp=2 x tensor=2: a block's compiled step holds the FOUR
+    activation-sized sums tensor parallelism needs (forward after
+    ``c_proj`` and ``mlp_out``, backward the input cotangents of
+    ``c_attn`` and ``mlp_in``) and, remat'd, makes no sum again and no
+    matmul behind the residual it kept (the parent remade ``c_proj``'s
+    sum, its matmul and ``mlp_in``'s a layer)."""
+    cfg, _, text = _qkv_step(remat)
+    sums, recomputed = block_sums_and_recomputed(text, cfg.max_seq,
+                                                 cfg.d_model)
+    assert sums == {f"h_{i}": 4 for i in range(cfg.n_layer)}, sums
+    # What is made again lies BEFORE the kept residual: ``c_attn`` and the
+    # kernel, which the CPU's compiler does not merge with their first
+    # forward (interpreted here, the kernel is a loop of matmuls; the
+    # chip's executable holds none: tests/test_tpu_compile.py).
+    assert not [r for r in recomputed
+                if r[0] == "all-reduce" or not re.search(
+                    r"/h_\d+/attn\.(qkv|core)/", r[1])], recomputed
+    # The scan sees what it is there to see: a combined pair under a
+    # recomputed block counts two operands, and is listed.
+    sample = ('%ar = (f32[2,128,256]{2,1,0}, f32[2,128,256]{2,1,0}) '
+              'all-reduce-start(%a, %b), metadata={op_name="jit(step)/'
+              'checkpoint/rematted_computation/h_1/attn.out/c_proj/dot"}')
+    assert block_sums_and_recomputed(sample, 128, 256) == (
+        {"h_1": 2}, [("all-reduce", "jit(step)/checkpoint/"
+                      "rematted_computation/h_1/attn.out/c_proj/dot")])
+
+
+def test_remat_changes_what_is_recomputed_not_what_is_computed():
+    """Loss and every gradient leaf of the remat'd program across fsdp=2 x
+    tensor=2 are the ``remat=False`` program's, and so are the bytes it
+    sums over the ``tensor`` axis, as the step's own counter reads them
+    (``xprof.local_programs()["train_step"]["collectives"]``)."""
+    import dataclasses
+
+    from ray_tpu.models.gpt2 import gpt2_init, gpt2_loss_fn
+    from ray_tpu.train import distributed as dist
+    from ray_tpu.util import xprof
+
+    mesh = gang_mesh({"fsdp": 2, "tensor": 2}, jax.devices()[:4])
+    plain = _qkv_cfg(4, remat=False, mesh=mesh)
+    placed, _ = _placed(gpt2_init(plain, jax.random.PRNGKey(0)), mesh)
+    batch = jax.device_put({"tokens": jax.random.randint(
+        jax.random.PRNGKey(1), (4, plain.max_seq + 1), 0, plain.vocab_size,
+        jnp.int32)}, dist.batch_sharding(mesh))
+
+    def run(cfg):
+        compiled = jax.jit(jax.value_and_grad(
+            lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=0))).lower(
+                placed, batch).compile()
+        summed = xprof.harvest_compiled(
+            compiled, dist.mesh_axis_sizes(mesh))["collectives"]
+        return compiled(placed, batch), \
+            summed["tensor"]["by_op"]["all-reduce"]
+
+    (want_loss, want), want_summed = run(plain)
+    (got_loss, got), got_summed = run(dataclasses.replace(plain, remat=True))
+    assert got_summed == want_summed > 0
+    assert float(got_loss) == float(want_loss)
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want))
+    for path, g in jax.tree_util.tree_leaves_with_path(got):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(flat_want[path]), rtol=1e-5, atol=1e-7,
+            err_msg=jax.tree_util.keystr(path))
 
 
 @pytest.mark.parametrize("heads", [4, 3], ids=["heads4", "heads3"])

@@ -1129,3 +1129,14 @@ def test_gpt2_sharded_step_compiles_for_four_chips(
     for axis in ("fsdp", "tensor"):
         assert sum(a["bytes"] for name, a in axes.items()
                    if axis in name) > 0, axes
+    # A block keeps its attention sublayer's summed residual across the
+    # remat boundary (PR 50): the FOUR activation-sized sums a layer that
+    # tensor parallelism needs, and no sum and no matmul made again; the
+    # kernels' second forward is merged with the first, as on one chip.
+    from test_parallel import block_sums_and_recomputed
+
+    sums, recomputed = block_sums_and_recomputed(text, cfg.max_seq,
+                                                 cfg.d_model)
+    assert sums == {f"h_{i}": 4 for i in range(cfg.n_layer)}, sums
+    assert not recomputed, recomputed
+    assert _kernel_calls(text) == dict.fromkeys(KERNEL_NAMES, cfg.n_layer)
