@@ -36,6 +36,20 @@ batch is empty and at ``stop()``.  ``stats()["pipeline"]`` counts
 ``drains`` by cause (``evict``, ``error``, ``empty``, ``stop``) and
 ``rows_discarded`` (launched rows whose result was dropped).
 
+Every program is accounted for where its ids are delivered
+(``stats()["runs"]``, by the name ``stats()["programs"]`` knows it by:
+``llm_decode``, ``llm_prefill[<bucket>]``): how many ran, over how many
+rows and token positions, and what each cost the loop, ``paced_s``: from
+the instant the program before it had its ids on the host, or from its
+own launch where that was later, to the instant ITS ids were on the
+host.  Device-bound that is the program's device time with its sampler
+and feed; host-bound it is the host's pace.  The intervals tile the time
+the pipeline was full; what lies between a delivery and a later launch
+(a drain, ``llm.idle``) is nobody's, ``runs_unpaced_s``, and an interval
+that holds a compile is in no program's sums (``runs_voided_s``).  The
+same intervals by power-of-two milliseconds (``by_ms``, ``s_by_ms``)
+show a stall, which a mean hides and a lifetime maximum cannot date.
+
 Memory pressure is handled vLLM-style by recompute preemption: when a
 running sequence needs a page and the pool is empty, the most recently
 admitted OTHER sequence is evicted — pages freed, tokens kept — and
@@ -126,8 +140,9 @@ class EngineConfig:
 # admission's own time (lock, page allocation), its prefills apart.
 # ``.run`` launches the forward, the sampler and the feed; ``.fetch`` waits
 # for the token ids of the program launched BEFORE that one (of the
-# program itself in a drain); ``.sample`` is the host's bookkeeping per
-# token of the ids just fetched.
+# program itself in a drain) and names it in its tags (``program``, and
+# ``run``: how many of that program were delivered before it);
+# ``.sample`` is the host's bookkeeping per token of the ids just fetched.
 PHASE_LEAVES = (
     "llm.cancel", "llm.admit",
     "llm.prefill.pack", "llm.prefill.run", "llm.prefill.fetch",
@@ -197,14 +212,20 @@ class _Flight:
     ``[max_batch]`` (and the program's routing counters) still on the
     device, and whose each row is."""
 
-    __slots__ = ("kind", "ids", "moe", "residual", "rows", "admitted")
+    __slots__ = ("kind", "name", "ids", "moe", "residual", "rows",
+                 "launched_at", "void", "admitted")
 
-    def __init__(self, kind: str, ids, counters, rows,
-                 admitted: Optional[float] = None):
+    def __init__(self, kind: str, name: str, ids, counters, rows,
+                 launched_at: float, admitted: Optional[float] = None):
         self.kind = kind                # "decode" or "prefill"
+        self.name = name                # ``_call_fwd``'s: the program
         self.ids = ids
         self.moe, self.residual = counters  # ``_call_fwd``'s
         self.rows: List[tuple] = rows   # (sequence, its row of ``ids``)
+        # For stats()["runs"]: when its ``.run`` leaf began, and whether
+        # a compile ran before its delivery.
+        self.launched_at = launched_at
+        self.void = False
         # When a FIRST admission's prefill began: its delivery is the
         # request's first token (TTFT's prefill phase ends there).
         self.admitted = admitted
@@ -218,6 +239,23 @@ _MOE_KEPT = {"decode": ("layer_runs", "pairs", "experts_hit", "max_load"),
 
 # A decode row without a sequence, to the sampler: argmax.
 _IDLE_ROW = (SamplingParams(), (0, 0), 0)
+
+# stats()["runs"]: a program's intervals by power-of-two milliseconds,
+# ``int(ms).bit_length()``: < 1, 1-2, 2-4, ..., 8192-16384, >= 16384.
+RUN_BINS = 16
+
+
+def _run_entry(name: str, runs: int, rows: int, by_ms: List[int],
+               s_by_ms: List[float]) -> Dict[str, Any]:
+    """A program's entry of stats()["runs"] from what ``_file`` keeps:
+    ``tokens`` are the positions it computed (a prefill's bucket a run,
+    as its name has it; a decode step's rows), ``paced_s`` the sum of
+    its timed intervals."""
+    bucket = name.partition("[")[2].rstrip("]")
+    return {"runs": runs, "rows": rows,
+            "tokens": runs * int(bucket) if bucket else rows,
+            "paced_s": sum(s_by_ms), "by_ms": list(by_ms),
+            "s_by_ms": list(s_by_ms)}
 
 
 def jit_forward(model):
@@ -381,6 +419,14 @@ class GenerationEngine:
         self._pipeline = {"launched_ahead": 0, "rows_discarded": 0}
         self._drains = dict.fromkeys(("evict", "error", "empty", "stop"),
                                      0)
+        # The ledger of delivered programs by name (stats()["runs"]),
+        # the seconds between a delivery and a later launch, those of
+        # intervals that held a compile, and the last delivery's instant
+        # on the phases' wall clock (``_file``).
+        self._runs: Dict[str, Dict[str, Any]] = {}
+        self._runs_unpaced_s = self._runs_voided_s = 0.0
+        self._delivered_at: Optional[float] = None
+        self._compiled = False      # ``_call`` compiled since a launch
         # AOT executables by program name (lower().compile()): the
         # compile is timed and the program registered with the xprof
         # plane (rt perf).
@@ -646,6 +692,18 @@ class GenerationEngine:
                 # rows whose result was dropped (EOS, cancellation).
                 "pipeline": {**self._pipeline,
                              "drains": dict(self._drains)},
+                # Every delivered program by name: ``runs``, ``rows``
+                # (delivered or discarded), ``tokens`` (positions
+                # computed), and what each cost the loop, ids on the
+                # host to ids on the host: ``paced_s``, and the same
+                # intervals by power-of-two ms (``by_ms`` counts,
+                # ``s_by_ms`` seconds).  Between two instants with
+                # nothing in flight, paced + unpaced + voided = from
+                # the last delivery before to the last one since.
+                "runs": {name: _run_entry(name, **run)
+                         for name, run in self._runs.items()},
+                "runs_unpaced_s": self._runs_unpaced_s,
+                "runs_voided_s": self._runs_voided_s,
                 "device": dict(self._device),
                 # TTFT phase + TPOT accounting.
                 "ttft_requests": self._ttft_requests,
@@ -827,8 +885,8 @@ class GenerationEngine:
                   **served):
         """The forward of this token shape (``llm_decode``, or
         ``llm_prefill[bucket]``) over the caches, which it updates:
-        returns (logits, (the routing counters or None, the residual
-        kind's or None)).  ``slots`` is
+        returns (the program's name, logits, (the routing counters or None,
+        the residual kind's or None)).  ``slots`` is
         each row's slot of the state pool (a row without a sequence:
         the index outside the pool), taken by a model that has one.
         ``served`` is a prefill's ``last`` (``jit_forward``): the logits
@@ -847,7 +905,7 @@ class GenerationEngine:
             self._state = dict(zip(self._state, rest[:n]))
             rest = rest[n:]
         residual = rest.pop() if self._residual else None
-        return logits, (rest[0] if rest else None, residual)
+        return name, logits, (rest[0] if rest else None, residual)
 
     def _call(self, fn, name: str, *args, **kwargs):
         """Dispatch a jitted function through the AOT executable of this
@@ -871,6 +929,7 @@ class GenerationEngine:
             dt = time.perf_counter() - t0
             self._compile_seconds[name] = dt
             self._compiles += 1
+            self._compiled = True
             self._peak_program_bytes = max(self._peak_program_bytes,
                                            _program_bytes(exe))
             try:
@@ -932,6 +991,12 @@ class GenerationEngine:
         the annotation that launched ``flight``."""
         self._pipeline["launched_ahead"] += bool(self._flights)
         self._flights.append(flight)
+        if self._compiled:
+            # Its launch compiled, while the programs before it were in
+            # the air: their intervals are no program's pace.
+            self._compiled = False
+            for held in self._flights:
+                held.void = True
         if len(self._flights) > 1:
             self._deliver(f"llm.{flight.kind}")
 
@@ -949,7 +1014,17 @@ class GenerationEngine:
         dropped.  A device error surfaces at the fetch, with the flight
         still listed for the poison pass."""
         flight = self._flights[0]
-        with self._phase(leaves + ".fetch"):
+        run = self._runs.get(flight.name)
+        if run is None:
+            with self._lock:
+                run = self._runs[flight.name] = {
+                    "runs": 0, "rows": 0,
+                    "by_ms": [0] * RUN_BINS, "s_by_ms": [0.0] * RUN_BINS}
+        # the annotation names the program whose ids it reads, not the
+        # one just launched: a capture's k-th forward run to end and its
+        # k-th ``.fetch`` to end are the same program
+        with self._phase(leaves + ".fetch", program=flight.name,
+                         run=run["runs"]) as fetch:
             ids = np.asarray(flight.ids).tolist()   # [max_batch] int32
             per_layer = None if flight.moe is None \
                 else np.asarray(flight.moe)         # [layers, 4]
@@ -959,6 +1034,7 @@ class GenerationEngine:
                     self._residual.update(max_row_sum_err=row,
                                           max_col_sum_err=col)
         self._flights.popleft()
+        self._file(flight, run, fetch.ended)
         with self._phase(leaves + ".sample"):
             if per_layer is not None:
                 from ..ops.moe import MOE_COUNTERS
@@ -983,6 +1059,30 @@ class GenerationEngine:
                                    t_first,
                                    tags={"prompt_tokens": seq.prompt_len})
                     seq.first_token_ts = t_first
+
+    def _file(self, flight: _Flight, run: Dict[str, Any],
+              delivered: float) -> None:
+        """Enter a delivered program in the ledger: its interval runs
+        from the later of the delivery before it and its own launch
+        (both the leaves' own clock readings) to ``delivered``, the end
+        of its ``.fetch`` leaf.  Across a drain, the poison pass or an
+        idle wait the launch is the later one, so no interval spans
+        them: that time is ``runs_unpaced_s``."""
+        last, self._delivered_at = self._delivered_at, delivered
+        began = flight.launched_at if last is None \
+            else max(last, flight.launched_at)
+        took = delivered - began
+        with self._lock:
+            run["runs"] += 1
+            run["rows"] += len(flight.rows)
+            if last is not None:
+                self._runs_unpaced_s += began - last
+            if flight.void:
+                self._runs_voided_s += took
+            else:
+                i = min(int(took * 1e3).bit_length(), RUN_BINS - 1)
+                run["by_ms"][i] += 1
+                run["s_by_ms"][i] += took
 
     def _prefill(self, seq: _Sequence) -> None:
         w0, c0 = self._phases.clocks()
@@ -1026,8 +1126,8 @@ class GenerationEngine:
             feed_to = np.full(self.cfg.max_batch, self.cfg.max_batch,
                               np.int32)
             feed_to[0] = seq.slot
-        with self._phase("llm.prefill.run"):
-            logits, counters = self._call_fwd(
+        with self._phase("llm.prefill.run") as launch:
+            name, logits, counters = self._call_fwd(
                 "prefill", tokens, table, positions,
                 np.asarray([seq.slot], np.int32),
                 last=np.asarray([n - 1], np.int32))
@@ -1043,7 +1143,8 @@ class GenerationEngine:
         with self._lock:
             self._running.append(seq)
         self._launched(seq)
-        self._launch(_Flight("prefill", ids, counters, flight_rows,
+        self._launch(_Flight("prefill", name, ids, counters, flight_rows,
+                             launch.began,
                              t_admit if first_admission else None))
 
     def _decode_step(self) -> None:
@@ -1083,8 +1184,8 @@ class GenerationEngine:
                     len(batch) * self._cache_spec.state_layers
             flight_rows = [(seq, seq.slot) for seq in batch]
             sampling = self._pack_sampling(flight_rows)
-        with self._phase("llm.decode.run"):
-            logits, counters = self._call_fwd(
+        with self._phase("llm.decode.run") as launch:
+            name, logits, counters = self._call_fwd(
                 "decode", self._tokens, table, positions, slots)
             ids = self._call(self._sampler, "llm_sample", logits,
                              *sampling)
@@ -1092,7 +1193,8 @@ class GenerationEngine:
         for seq in batch:
             seq.n_cached += 1
             self._launched(seq)
-        self._launch(_Flight("decode", ids, counters, flight_rows))
+        self._launch(_Flight("decode", name, ids, counters, flight_rows,
+                             launch.began))
 
     def _ensure_pages(self, evict: bool) -> bool:
         """A KV slot for every running sequence's next position; False

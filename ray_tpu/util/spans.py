@@ -163,20 +163,25 @@ def annotate(name: str, **tags: Any):
 class _Leaf:
     """One leaf of a ``Phases``: a profiler annotation and, around the
     same statements, a wall-clock pair and a CPU-clock pair added to the
-    leaf's pending sums."""
+    leaf's pending sums.  ``began`` and ``ended`` are the wall clock's
+    two readings, for a caller that files an interval by the leaf's
+    edges and reads no clock of its own (the engine's ledger of runs)."""
 
-    __slots__ = ("_phases", "_sums", "_annotation", "_t0")
+    __slots__ = ("_phases", "_sums", "_annotation", "_t0", "began", "ended")
 
     def __init__(self, phases: "Phases", name: str, annotation):
         self._phases, self._sums = phases, phases._pending[name]
         self._annotation = annotation
 
-    def __enter__(self) -> None:
+    def __enter__(self) -> "_Leaf":
         self._annotation.__enter__()
         self._t0 = self._phases.clocks()
+        self.began = self._t0[0]
+        return self
 
     def __exit__(self, *exc) -> bool:
         wall, cpu = self._phases.clocks()
+        self.ended = wall
         self._sums[0] += wall - self._t0[0]
         self._sums[1] += cpu - self._t0[1]
         self._annotation.__exit__(*exc)

@@ -568,3 +568,270 @@ def test_sampled_tokens_are_the_samplers_for_the_launched_index(setup):
     assert _tokens(eng, eng.submit(prompt, steps, SAMPLED, seed)) == want
     for seq in others:
         list(eng.frames(seq))
+
+
+# ------------------------------------ the ledger of runs, stats()["runs"]
+
+class _Ticks:
+    """A wall clock that moves 2**-10 s at every reading (sums of such
+    steps are exact in binary: the identities hold to the last bit) and
+    further when told to."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def __call__(self):
+        self.now += 2.0 ** -10
+        return self.now
+
+
+def _clocked(eng):
+    """Put ``eng``'s phases, and so its ledger, on a ``_Ticks``."""
+    from ray_tpu.llm.engine import PHASE_LEAVES
+    from ray_tpu.util.spans import Phases
+
+    clock = _Ticks()
+    eng._phases = Phases(PHASE_LEAVES, "llm.other", lock=eng._lock,
+                         cpu_every=16, clock=clock)
+    eng._phase = eng._phases.leaf
+    return clock
+
+
+def _ledger_delta(after, before):
+    """What stats()["runs"] gained, by program and key."""
+    out = {}
+    for name, run in after["runs"].items():
+        was = before["runs"].get(name, {})
+        out[name] = {
+            k: [a - b for a, b in zip(v, was.get(k, [0] * len(v)))]
+            if isinstance(v, list) else v - was.get(k, 0)
+            for k, v in run.items()}
+    return out
+
+
+@pytest.mark.parametrize("family", ["gpt2", "granitemoehybrid"])
+def test_the_ledger_of_runs_adds_up(family):
+    """Prefills of three buckets and decode steps over three rows, one
+    request cancelled with a row in the air: between two instants with
+    nothing in flight the programs' intervals, the time that is nobody's
+    and the intervals that held a compile tile the clock, and the counts
+    are the engine's other counters."""
+    eng = _family_engine(family)
+    _clocked(eng)
+    launches = []
+    real_launch = eng._launch
+    eng._launch = lambda flight: (launches.append(flight.launched_at),
+                                  real_launch(flight))[1]
+
+    def serve():
+        seqs = [eng.submit(p, max_tokens=n) for p, n in MIXED]
+        for _ in range(4):
+            eng.step()
+        eng.cancel(seqs[1].sid)         # running, a row of it in flight
+        _step_until_done(eng, seqs)
+        return eng.stats(), eng._delivered_at
+
+    def total(runs, key, of=lambda name: True):
+        return sum(r[key] for name, r in runs.items() if of(name))
+
+    def prefill(name):
+        return name.startswith("llm_prefill[")
+
+    cold, d0 = serve()      # every program compiles in this pass
+    assert set(cold["runs"]) == {"llm_decode", "llm_prefill[8]",
+                                 "llm_prefill[16]", "llm_prefill[32]"}
+    assert cold["runs_voided_s"] > 0
+    assert total(cold["runs"], "paced_s") + cold["runs_unpaced_s"] \
+        + cold["runs_voided_s"] == d0 - launches[0]
+    warm, d1 = serve()
+    assert warm["compiles"] == cold["compiles"]
+    for stats, runs in ((cold, cold["runs"]), (warm, warm["runs"])):
+        assert total(runs, "runs", prefill) == stats["prefills"]
+        assert runs["llm_decode"]["runs"] == \
+            stats["attention"]["decode_runs"]
+        assert total(runs, "rows") == stats["tokens_generated"] \
+            + stats["pipeline"]["rows_discarded"]
+        assert total(runs, "tokens", prefill) == \
+            stats["prefill_bucket_tokens"]
+    assert warm["pipeline"]["rows_discarded"] == 2
+    gained = _ledger_delta(warm, cold)
+    assert warm["runs_voided_s"] == cold["runs_voided_s"]
+    assert total(gained, "paced_s") + warm["runs_unpaced_s"] \
+        - cold["runs_unpaced_s"] == d1 - d0
+    for name, run in gained.items():
+        assert sum(run["by_ms"]) == run["runs"] > 0, name
+        assert sum(run["s_by_ms"]) == run["paced_s"] > 0, name
+        if prefill(name):
+            assert run["rows"] == run["runs"]
+            assert run["tokens"] == run["runs"] * int(name[12:-1])
+        else:
+            assert run["tokens"] == run["rows"]
+
+
+class _Slow:
+    """Ids that take ``seconds`` of ``clock`` to reach the host."""
+
+    def __init__(self, ids, clock, seconds):
+        self.ids, self.clock, self.seconds = ids, clock, seconds
+
+    def __array__(self, *a, **kw):
+        self.clock.now += self.seconds
+        return np.asarray(self.ids)
+
+
+def test_a_stalled_fetch_lands_in_its_programs_bucket_and_no_other():
+    eng = _family_engine("gpt2")
+    clock = _clocked(eng)
+    _step_until_done(eng, [eng.submit(p, max_tokens=n)
+                           for p, n in MIXED[:2]])
+    before = eng.stats()
+    seqs = [eng.submit(p, max_tokens=n) for p, n in MIXED[:2]]
+    for _ in range(4):
+        eng.step()
+    flight, = eng._flights
+    assert flight.name == "llm_decode"
+    flight.ids = _Slow(flight.ids, clock, 2.0)
+    _step_until_done(eng, seqs)
+    gained = _ledger_delta(eng.stats(), before)
+    # 2 s and a few readings: 1,024 to 2,048 ms
+    slow = int(2000).bit_length()
+    for name, run in gained.items():
+        stalled = [int(name == "llm_decode" and i == slow)
+                   for i in range(16)]
+        assert [n for n in run["by_ms"][8:]] == stalled[8:], name
+        assert sum(run["by_ms"]) == run["runs"]
+    decode = gained["llm_decode"]
+    assert 2.0 <= decode["s_by_ms"][slow] < 2.048
+    assert decode["paced_s"] - decode["s_by_ms"][slow] < 0.1 * decode["runs"]
+
+
+def test_an_interval_that_holds_a_compile_is_in_no_sum(monkeypatch):
+    """The first prefill's launch compiles four programs and is in the
+    air while the first decode step's compiles its own: neither has an
+    interval; the second decode step is the first timed run."""
+    import contextlib
+
+    from ray_tpu.llm import engine as engine_mod
+
+    eng = _family_engine("gpt2")
+    clock = _clocked(eng)
+
+    @contextlib.contextmanager
+    def annotate(name, **tags):
+        if name == "llm.compile":
+            clock.now += 64.0
+        yield
+
+    monkeypatch.setattr(engine_mod, "annotate", annotate)
+    _step_until_done(eng, [eng.submit([9, 4], max_tokens=6)])
+    stats = eng.stats()
+    assert stats["compiles"] == 5
+    prefill, decode = (stats["runs"][name]
+                       for name in ("llm_prefill[8]", "llm_decode"))
+    assert (prefill["runs"], sum(prefill["by_ms"]), prefill["paced_s"]) \
+        == (1, 0, 0.0)
+    assert (decode["runs"], sum(decode["by_ms"])) == (5, 4)
+    assert decode["paced_s"] == sum(decode["s_by_ms"]) < 1.0
+    assert stats["runs_voided_s"] >= 5 * 64.0
+    # a bucket new to a warm engine: the decode step in the air while it
+    # compiles is dropped with it
+    first = eng.submit([9, 4], max_tokens=9)
+    eng.step()
+    eng.step()
+    assert [f.name for f in eng._flights] == ["llm_decode"]
+    _step_until_done(eng, [first, eng.submit(list(range(1, 12)),
+                                             max_tokens=2)])
+    again = _ledger_delta(eng.stats(), stats)
+    assert again["llm_prefill[16]"]["runs"] == 1
+    assert sum(again["llm_prefill[16]"]["by_ms"]) == 0
+    assert sum(again["llm_decode"]["by_ms"]) == \
+        again["llm_decode"]["runs"] - 1
+    assert sum(again["llm_prefill[8]"]["by_ms"]) == 1
+    assert sum(r["paced_s"] for r in again.values()) < 1.0
+
+
+@pytest.mark.parametrize("cause", ["evict", "empty", "stop", "error"])
+def test_no_interval_spans_a_drain_or_the_poison_pass(cause):
+    """128 s pass right after the pipeline is emptied: they are nobody's
+    (``runs_unpaced_s``), and the next program's interval starts at its
+    own launch."""
+    eng = _family_engine("gpt2", **({"num_pages": 12}
+                                    if cause == "evict" else {}))
+    clock = _clocked(eng)
+    real_drain, real_poison = eng._drain, eng._poison
+    jumps = []
+
+    def drain(why):
+        emptied = bool(eng._flights)
+        real_drain(why)
+        if emptied and why == cause:
+            clock.now += 128.0
+            jumps.append(why)
+
+    def poison(e):
+        real_poison(e)
+        clock.now += 128.0
+        jumps.append("error")
+
+    eng._drain, eng._poison = drain, poison
+    requests = MIXED[:2] + MIXED[4:5]
+    seqs = [eng.submit(p, max_tokens=n + 8) for p, n in requests]
+    if cause in ("stop", "error"):
+        eng.step()
+        eng.step()
+        if cause == "stop":
+            eng.stop()
+        else:
+            eng._flights[0].ids = _Unreadable()
+            with pytest.raises(RuntimeError, match="injected") as err:
+                eng.step()
+            eng._poison(err.value)
+            assert all(s.finished for s in seqs) and not eng._flights
+            seqs = [eng.submit(p, max_tokens=n) for p, n in requests]
+    while not all(s.finished for s in seqs):
+        eng.step()
+    if cause == "empty":
+        _step_until_done(eng, [eng.submit([9, 4], max_tokens=3)])
+    stats = eng.stats()
+    assert jumps and set(jumps) == {cause}
+    assert stats["pipeline"]["drains"][cause] >= len(jumps)
+    # (the time after the last drain of all waits for a launch to end it)
+    assert stats["runs_unpaced_s"] >= 128.0 * (
+        len(jumps) - (cause == "empty")) >= 128.0
+    for name, run in stats["runs"].items():
+        assert run["by_ms"][12:] == [0] * 4, name
+    assert sum(r["paced_s"] for r in stats["runs"].values()) \
+        + stats["runs_voided_s"] < 128.0
+
+
+def test_stats_from_another_thread_mid_step_still_add_up():
+    """A program's entry is written in one go under the engine's lock: a
+    reading taken while the loop runs holds as many intervals as runs."""
+    import sys
+
+    eng = _family_engine("gpt2").start()
+    interval = sys.getswitchinterval()
+    try:
+        for p, n in MIXED:                      # every compile
+            eng.generate(p, max_tokens=n)
+        before = eng.stats()
+        sys.setswitchinterval(1e-5)
+        seqs = [eng.submit(p, max_tokens=n + 20) for p, n in MIXED]
+        readings = 0
+        deadline = time.time() + 120
+        while not all(s.finished for s in seqs):
+            assert time.time() < deadline
+            now = eng.stats()
+            for name, run in _ledger_delta(now, before).items():
+                assert sum(run["by_ms"]) == run["runs"], name
+                assert sum(run["s_by_ms"]) == \
+                    pytest.approx(run["paced_s"], abs=1e-9)
+                if name != "llm_decode":
+                    assert run["rows"] == run["runs"]
+                    assert run["tokens"] == run["runs"] * int(name[12:-1])
+            readings += 1
+        assert readings > 3
+    finally:
+        sys.setswitchinterval(interval)
+        eng.stop()
+    assert eng.stats()["runs_voided_s"] == before["runs_voided_s"]

@@ -322,6 +322,42 @@ def test_cpu_capture_of_a_tiny_engine_holds_the_phases(tmp_path):
     assert events["llm.compile"] == [{"program": "llm_prefill[8]"}]
 
 
+def test_cpu_capture_fetches_name_the_flight_they_deliver(tmp_path):
+    """``llm.decode.fetch`` / ``llm.prefill.fetch`` carry ``program`` and
+    ``run``: the program whose ids the leaf read, which is the one
+    launched BEFORE the annotation's own, and how many of it were
+    delivered before; so by name the ordinals count up from the ledger's
+    count at the capture's start."""
+    eng = _engine()
+    first = eng.submit(list(range(1, 12)), max_tokens=6)
+    eng.step()
+    eng.step()                  # a decode step of ``first`` is in the air
+    before = eng.stats()["runs"]
+    assert [f.name for f in eng._flights] == ["llm_decode"]
+
+    def run():
+        eng.submit([5, 6, 7], max_tokens=3)
+        while not first.finished:
+            eng.step()
+
+    events = _capture(tmp_path, run)
+    after = eng.stats()["runs"]
+    # the fetch under the prefill's annotation read the decode step
+    # launched before it; the prefill's own ids were read under the
+    # next decode step's
+    assert [e["program"] for e in events["llm.prefill.fetch"]] == \
+        ["llm_decode"]
+    fetched = events["llm.prefill.fetch"] + events["llm.decode.fetch"]
+    assert {e["program"] for e in fetched} == {"llm_decode",
+                                               "llm_prefill[8]"}
+    for name in ("llm_decode", "llm_prefill[8]"):
+        start = before.get(name, {"runs": 0})["runs"]
+        assert sorted(e["run"] for e in fetched if e["program"] == name) \
+            == list(range(start, after[name]["runs"]))
+    assert after["llm_prefill[8]"]["runs"] == 1
+    assert "llm_prefill[16]" not in {e["program"] for e in fetched}
+
+
 def test_ids_are_fetched_after_the_next_launch_inside_its_annotation(
         monkeypatch):
     """The pipeline by the order of events on the host: every name is
