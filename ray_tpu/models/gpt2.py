@@ -28,7 +28,6 @@ torch+DeepSpeed, here the model is native).
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -39,8 +38,10 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..parallel.sharding import logical_shards
 from ..parallel.sharding import with_logical_constraint as _constrain
+from ..util import xprof
 from .attention import attention, attention_qkv, qkv_by_head
-from .layers import served_position
+from .layers import (XentLayout, chunked_xent, chunked_xent_over,
+                     served_position, xent_layout)
 
 
 @dataclass(frozen=True)
@@ -304,89 +305,14 @@ def gpt2_init(cfg: GPT2Config, rng) -> Any:
     return GPT2(init_cfg).init(rng, tokens)
 
 
-def _xent_chunks(x, targets, chunk: int):
-    """``x`` [b,t,d] and ``targets`` [b,t] as the scan reads them:
-    [n,b,c,d] and [n,b,c], ``n = t // chunk`` chunks along the sequence."""
-    b, t, d = x.shape
-    n = t // chunk
-    return (jnp.moveaxis(x.reshape(b, n, chunk, d), 1, 0),
-            jnp.moveaxis(targets.reshape(b, n, chunk), 1, 0))
-
-
-def _xent_chunk(xc, wte, tc):
-    """One chunk's float32 logits, their log-sum-exp and the sum of the
-    rows' losses."""
-    logits = jnp.einsum("bcd,vd->bcv", xc, wte,
-                        preferred_element_type=jnp.float32)
-    lse = jax.nn.logsumexp(logits, axis=-1)                  # [b,c]
-    tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
-    return logits, lse, jnp.sum(lse - tgt)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _chunked_xent(x, wte, targets, chunk: int) -> jnp.ndarray:
-    """Fused chunked cross entropy (custom_vjp): never materializes the
-    [B, T, V] logits tensor in HBM, and makes each chunk's logits ONCE.
-
-    The fp32 logits (~3.3 GB at GPT-2 pretraining shapes, several HBM
-    round-trips through log_softmax and its VJP) would be the biggest
-    memory consumer of the step; a scan over seq chunks keeps the live
-    slab at O(chunk*V).  The loss is the last thing the forward pass
-    computes and its value is a scalar, so its cotangent only SCALES
-    the gradient: under differentiation the one scan that makes a
-    chunk's logits also folds their softmax-minus-onehot straight into
-    the dX / dWte einsums (three vocabulary-sized matmuls a chunk, none
-    recomputed), the residuals are those two gradients, and the
-    backward rule multiplies them by the cotangent.  Called without
-    differentiation (evaluation) it is the value-only scan: one matmul
-    a chunk."""
-    def body(total, xt):
-        xc, tc = xt
-        _logits, _lse, loss = _xent_chunk(xc, wte, tc)
-        return total + loss, None
-
-    with jax.named_scope("loss"):
-        total, _ = jax.lax.scan(body, jnp.float32(0.0),
-                                _xent_chunks(x, targets, chunk))
-    b, t, _d = x.shape
-    return total / (b * t)
-
-
-def _chunked_xent_fwd(x, wte, targets, chunk):
-    b, t, d = x.shape
-    scale = 1.0 / (b * t)
-
-    def body(carry, xt):
-        total, dw = carry
-        xc, tc = xt
-        logits, lse, loss = _xent_chunk(xc, wte, tc)
-        p = jnp.exp(logits - lse[..., None])
-        onehot = jax.nn.one_hot(tc, wte.shape[0], dtype=p.dtype)
-        dl = ((p - onehot) * scale).astype(x.dtype)
-        dx_c = jnp.einsum("bcv,vd->bcd", dl, wte)
-        # fp32 accumulator: bf16 chunk-wise accumulation would
-        # compound rounding across T/chunk scan steps.
-        dw = dw + jnp.einsum("bcv,bcd->vd", dl, xc,
-                             preferred_element_type=jnp.float32)
-        return (total + loss, dw), dx_c
-
-    with jax.named_scope("loss"):
-        (total, dw), dxs = jax.lax.scan(
-            body, (jnp.float32(0.0), jnp.zeros(wte.shape, jnp.float32)),
-            _xent_chunks(x, targets, chunk))
-        dx = jnp.moveaxis(dxs, 0, 1).reshape(b, t, d)
-        # The empty array carries wte's dtype to the backward rule.
-        return total / (b * t), (dx, dw, jnp.zeros((0,), wte.dtype))
-
-
-def _chunked_xent_bwd(chunk, res, g):
-    dx, dw, like_wte = res
-    with jax.named_scope("loss"):
-        return ((dx * g).astype(dx.dtype),
-                (dw * g).astype(like_wte.dtype), None)
-
-
-_chunked_xent.defvjp(_chunked_xent_fwd, _chunked_xent_bwd)
+def loss_layout(cfg: GPT2Config, shape, loss_chunk: int) -> XentLayout:
+    """How ``gpt2_loss_fn`` computes the loss of ``[B, T] = shape`` input
+    tokens: ``layers.xent_layout`` on ``cfg.mesh``, but for an MoE config,
+    whose auxiliary loss reads the whole forward.  Pure: the tests and the
+    step's telemetry ask what the program asked."""
+    if cfg.moe_num_experts > 0:
+        return XentLayout()
+    return xent_layout(cfg.mesh, shape, loss_chunk)
 
 
 def gpt2_loss_fn(cfg: GPT2Config, params, batch,
@@ -395,15 +321,19 @@ def gpt2_loss_fn(cfg: GPT2Config, params, batch,
     MoE configs add the load-balancing auxiliary loss (ops/moe.py)."""
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    t = inputs.shape[1]
     moe = cfg.moe_num_experts > 0
-    if loss_chunk and t % loss_chunk == 0 and t > loss_chunk \
-            and cfg.mesh is None and not moe:
-        # Sharded runs keep the einsum whole so GSPMD can partition the
-        # vocab dim; single-chip runs take the chunked low-HBM path.
+    layout = loss_layout(cfg, inputs.shape, loss_chunk)
+    xprof.note("loss", path=layout.path, token_shards=layout.shards,
+               chunk=layout.chunk)
+    if layout.path == "chunked":
+        # No [B, T, V] logits: one chip scans all the tokens, the chips
+        # of a mesh each their own share of them (an odd vocabulary
+        # leaves GSPMD the same whole logits on every ``tensor`` shard).
         x = GPT2(cfg).apply(params, inputs, return_hidden=True)
         wte = params["params"]["wte"].astype(cfg.dtype)
-        return _chunked_xent(x, wte, targets, loss_chunk)
+        if layout.shards == 1:
+            return chunked_xent(x, wte, targets, layout.chunk)
+        return chunked_xent_over(cfg.mesh, layout, x, wte, targets)
     if moe:
         logits, state = GPT2(cfg).apply(params, inputs,
                                         mutable=["intermediates"])

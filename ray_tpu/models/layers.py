@@ -9,7 +9,11 @@ bias, silu) and of LFM2's ``ShortConvMixer`` (3 taps, neither), written
 once; ``init_by_leaf`` makes the seeded weights of all three.
 ``served_position`` is what GPT-2 and the decoder cut their hidden state
 to, before the final norm and the head, for a caller that serves one
-position of each row.
+position of each row.  ``chunked_xent`` is the head's loss without the
+[B, T, V] logits (one scan makes each chunk's logits once and their
+gradient with them), ``chunked_xent_over`` the same with the tokens cut
+over a mesh, and ``xent_layout`` says which a batch takes: GPT-2 trains
+through them (``gpt2.loss_layout``), the decoder's loss does not yet.
 """
 
 from __future__ import annotations
@@ -18,12 +22,14 @@ import dataclasses
 import functools
 import math
 import zlib
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..parallel.sharding import logical_spec
 
 
 def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
@@ -211,3 +217,175 @@ def init_by_leaf(model, cfg, rng, special: Optional[Callable] = None):
                           jnp.dtype(cfg.param_dtype))
 
     return jax.tree_util.tree_map_with_path(make, shapes)
+
+
+# ---------------------------------------------------------------------
+# The chunked cross entropy: the loss of a tied or untied head without the
+# [B, T, V] logits, on one chip or with the tokens split over a mesh.
+
+def _xent_chunks(x, targets, chunk: int):
+    """``x`` [b,t,d] and ``targets`` [b,t] as the scan reads them:
+    [n,b,c,d] and [n,b,c], ``n = t // chunk`` chunks along the sequence."""
+    b, t, d = x.shape
+    n = t // chunk
+    return (jnp.moveaxis(x.reshape(b, n, chunk, d), 1, 0),
+            jnp.moveaxis(targets.reshape(b, n, chunk), 1, 0))
+
+
+def _xent_chunk(xc, wte, tc):
+    """One chunk's float32 logits, their log-sum-exp and the sum of the
+    rows' losses."""
+    logits = jnp.einsum("bcd,vd->bcv", xc, wte,
+                        preferred_element_type=jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)                  # [b,c]
+    tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
+    return logits, lse, jnp.sum(lse - tgt)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def chunked_xent(x, wte, targets, chunk: int) -> jnp.ndarray:
+    """Fused chunked cross entropy (custom_vjp): never materializes the
+    [B, T, V] logits tensor in HBM, and makes each chunk's logits ONCE.
+
+    The fp32 logits (~3.3 GB at GPT-2 pretraining shapes, several HBM
+    round-trips through log_softmax and its VJP) would be the biggest
+    memory consumer of the step; a scan over seq chunks keeps the live
+    slab at O(chunk*V).  The loss is the last thing the forward pass
+    computes and its value is a scalar, so its cotangent only SCALES
+    the gradient: under differentiation the one scan that makes a
+    chunk's logits also folds their softmax-minus-onehot straight into
+    the dX / dWte einsums (three vocabulary-sized matmuls a chunk, none
+    recomputed), the residuals are those two gradients, and the
+    backward rule multiplies them by the cotangent.  Called without
+    differentiation (evaluation) it is the value-only scan: one matmul
+    a chunk."""
+    def body(total, xt):
+        xc, tc = xt
+        _logits, _lse, loss = _xent_chunk(xc, wte, tc)
+        return total + loss, None
+
+    with jax.named_scope("loss"):
+        total, _ = jax.lax.scan(body, jnp.float32(0.0),
+                                _xent_chunks(x, targets, chunk))
+    b, t, _d = x.shape
+    return total / (b * t)
+
+
+def _chunked_xent_fwd(x, wte, targets, chunk):
+    b, t, d = x.shape
+    scale = 1.0 / (b * t)
+
+    def body(carry, xt):
+        total, dw = carry
+        xc, tc = xt
+        logits, lse, loss = _xent_chunk(xc, wte, tc)
+        p = jnp.exp(logits - lse[..., None])
+        onehot = jax.nn.one_hot(tc, wte.shape[0], dtype=p.dtype)
+        dl = ((p - onehot) * scale).astype(x.dtype)
+        dx_c = jnp.einsum("bcv,vd->bcd", dl, wte)
+        # fp32 accumulator: bf16 chunk-wise accumulation would
+        # compound rounding across T/chunk scan steps.
+        dw = dw + jnp.einsum("bcv,bcd->vd", dl, xc,
+                             preferred_element_type=jnp.float32)
+        return (total + loss, dw), dx_c
+
+    with jax.named_scope("loss"):
+        (total, dw), dxs = jax.lax.scan(
+            body, (jnp.float32(0.0), jnp.zeros(wte.shape, jnp.float32)),
+            _xent_chunks(x, targets, chunk))
+        dx = jnp.moveaxis(dxs, 0, 1).reshape(b, t, d)
+        # The empty array carries wte's dtype to the backward rule.
+        return total / (b * t), (dx, dw, jnp.zeros((0,), wte.dtype))
+
+
+def _chunked_xent_bwd(chunk, res, g):
+    dx, dw, like_wte = res
+    with jax.named_scope("loss"):
+        return ((dx * g).astype(dx.dtype),
+                (dw * g).astype(like_wte.dtype), None)
+
+
+chunked_xent.defvjp(_chunked_xent_fwd, _chunked_xent_bwd)
+
+
+class XentLayout(NamedTuple):
+    """How a loss over ``[B, T]`` tokens is computed (``xent_layout``)."""
+    path: str = "whole"         # "chunked": the one-scan loss | "whole"
+    chunk: int = 0              # positions of a scan step on ONE shard
+    rows: Tuple[str, ...] = ()  # mesh axes that cut the batch's rows
+    positions: Tuple[str, ...] = ()     # ... each chunk's positions
+    shards: int = 1             # into how many shards the tokens are cut
+
+
+def xent_layout(mesh, shape, chunk: int) -> XentLayout:
+    """Which loss ``[B, T] = shape`` tokens take at ``chunk`` positions a
+    scan step, by what can be seen: the mesh's axes and the shapes.
+
+    ``T`` is no whole number of chunks (or one at most): whole logits.
+    No mesh: chunked, on the one device.  A mesh: the tokens are cut over
+    the axes that carry the batch (the table's ``batch`` row fitted to
+    ``B``) and then over those of its ``vocab`` row (``tensor``: the axis
+    the head's matmul would lie on, which an odd vocabulary leaves with
+    the same logits on every shard): the rows a batch shard holds where
+    that axis divides them, else the positions of each chunk where it
+    divides those, else whole logits, the program GSPMD partitions by
+    itself; so too where the sequence is cut (the scan runs along it), or
+    where no axis cuts anything."""
+    b, t = shape
+    if not chunk or t % chunk or t <= chunk:
+        return XentLayout()
+    if mesh is None:
+        return XentLayout("chunked", chunk)
+    if _axes(mesh, "seq", t):
+        return XentLayout()
+    rows, positions = _axes(mesh, "batch", b), ()
+    head = tuple(a for a in _axes(mesh, "vocab") if a not in rows)
+    if head and b // _shards(mesh, rows) % _shards(mesh, head) == 0:
+        rows += head
+    elif head and chunk % _shards(mesh, head) == 0:
+        positions = head
+    elif head or not rows:
+        return XentLayout()
+    return XentLayout("chunked", chunk // _shards(mesh, positions), rows,
+                      positions, _shards(mesh, rows + positions))
+
+
+def _axes(mesh, name: str, size: Optional[int] = None) -> Tuple[str, ...]:
+    """The axes of ``mesh`` on which the table lays a dimension with the
+    logical name ``name`` (with ``size``: those that divide it)."""
+    spec = logical_spec(mesh, (name,), None if size is None else (size,))
+    entry = spec[0] if len(spec) else None      # pruned: no entry left
+    if entry is None:
+        return ()
+    return (entry,) if isinstance(entry, str) else tuple(entry)
+
+
+def _shards(mesh, axes) -> int:
+    return math.prod(mesh.shape[a] for a in axes)
+
+
+def chunked_xent_over(mesh, layout: XentLayout, x, wte, targets):
+    """``chunked_xent`` with the tokens cut as ``layout`` says: every
+    shard scans its own rows (or its own positions of each chunk)
+    against the whole ``wte`` and the shards' mean losses are summed, so
+    no shard makes another's logits.  Differentiated, ``shard_map``'s
+    transpose sums ``d wte`` over the mesh ONCE, after the scan (the
+    scan's accumulator is a shard's own partial sum), and leaves ``dx``
+    cut by tokens."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    b, t, d = x.shape
+    axes = layout.rows + layout.positions
+    n = t // (layout.chunk * _shards(mesh, layout.positions))
+    spec = P(layout.rows or None, None, layout.positions or None)
+
+    def shard(x, wte, targets):
+        rows = x.shape[0]
+        loss = chunked_xent(x.reshape(rows, -1, d), wte,
+                            targets.reshape(rows, -1), layout.chunk)
+        return jax.lax.psum(loss, axes) / layout.shards
+
+    return shard_map(shard, mesh=mesh, check_vma=False,
+                     in_specs=(spec, P(), spec), out_specs=P())(
+        x.reshape(b, n, -1, d), wte, targets.reshape(b, n, -1))

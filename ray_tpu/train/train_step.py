@@ -140,7 +140,7 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
 
     import time as _time
 
-    from ..util import goodput
+    from ..util import goodput, xprof
     from ..util.spans import annotate
     from .session import step_account
 
@@ -170,8 +170,10 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
             if first:
                 account.void()      # a period that compiles is no step
                 t0 = _time.perf_counter()
-                with annotate("train.step.compile"):
+                with annotate("train.step.compile"), \
+                        xprof.traced_notes() as notes:
                     aot[0] = jitted.lower(state, batch).compile()
+                timed_step.notes = notes
                 timed_step.compile_seconds = _time.perf_counter() - t0
             with account.leaf("train.step.dispatch",
                               step=calls[0] if period is None else period):
@@ -181,7 +183,6 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
             account.close()
         if first:
             try:
-                from ..util import xprof
                 from ..util.metrics import Gauge
 
                 dt = _time.perf_counter() - t0
@@ -190,7 +191,8 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
                       "XLA compile) step invocation.").set(dt)
                 info = xprof.register_compiled("train_step", aot[0],
                                                mesh_axes=mesh_axes,
-                                               compile_seconds=dt)
+                                               compile_seconds=dt,
+                                               notes=timed_step.notes)
                 timed_step.collective_counts = \
                     (info or {}).get("collective_counts")
             except Exception:
@@ -198,10 +200,13 @@ def make_sharded_train_step(loss_fn, optimizer, mesh=None,
         return out
 
     # The executable every call runs (None before the first call), what
-    # its compile took and the collectives it holds by kind ({"all-reduce":
-    # n, ..., "collective-permute": n}, as xprof's "train_step" row has
-    # them): callers check the program that ran.
+    # its compile took, the collectives it holds by kind ({"all-reduce":
+    # n, ..., "collective-permute": n}) and what the traced code said of
+    # it ({"loss": {path, token_shards, chunk}} from GPT-2's loss), all as
+    # xprof's "train_step" row has them: callers check the program that
+    # ran.
     timed_step.compiled = lambda: aot[0]
     timed_step.compile_seconds = None
     timed_step.collective_counts = None
+    timed_step.notes = None
     return timed_step

@@ -24,6 +24,7 @@ request path.
 
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 import time
@@ -508,6 +509,32 @@ def _reset_local() -> None:
         _PROGRAMS.clear()
 
 
+_NOTES = threading.local()
+
+
+@contextlib.contextmanager
+def traced_notes():
+    """Collects what the code traced under it says of the program it
+    builds (``note``): yields the dict, {key: facts}, that fills while a
+    ``lower()`` inside the block runs the Python that makes the program."""
+    before = getattr(_NOTES, "open", None)
+    _NOTES.open = notes = {}
+    try:
+        yield notes
+    finally:
+        _NOTES.open = before
+
+
+def note(key: str, **facts: Any) -> None:
+    """A static fact of the program being traced on this thread (which
+    path a loss took, by what shapes), for whoever collects them
+    (``traced_notes``; ``train/train_step.py`` files them in the step's
+    row); nothing where nobody does."""
+    notes = getattr(_NOTES, "open", None)
+    if notes is not None:
+        notes[key] = facts
+
+
 def harvest_compiled(compiled: Any,
                      mesh_axes: Optional[Dict[str, int]] = None
                      ) -> Dict[str, Any]:
@@ -554,15 +581,17 @@ def harvest_compiled(compiled: Any,
 
 def register_compiled(name: str, compiled: Any,
                       mesh_axes: Optional[Dict[str, int]] = None,
-                      compile_seconds: Optional[float] = None
+                      compile_seconds: Optional[float] = None,
+                      notes: Optional[Dict[str, Any]] = None
                       ) -> Optional[Dict[str, Any]]:
     """Harvest one compiled program and publish its ``rt_xla_*``
     series; returns the harvested info (None on total failure).
     ``mesh_axes`` is the ORDERED {axis: size} of the mesh the program
     was compiled against (``dict(zip(mesh.axis_names,
-    mesh.devices.shape))``)."""
+    mesh.devices.shape))``); ``notes`` ({key: facts}, ``traced_notes``)
+    join the row under their keys."""
     try:
-        info = harvest_compiled(compiled, mesh_axes)
+        info = {**(notes or {}), **harvest_compiled(compiled, mesh_axes)}
         info["compiles"] = 1
         info["compile_seconds"] = float(compile_seconds or 0.0)
         with _PLOCK:

@@ -9,8 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.gpt2 import (GPT2, GPT2Config, _chunked_xent, gpt2_init,
+from ray_tpu.models.gpt2 import (GPT2, GPT2Config, gpt2_init,
                                  gpt2_loss_fn)
+from ray_tpu.models.layers import chunked_xent
 from ray_tpu.models.llama import (Llama, LlamaConfig, llama_init,
                                   llama_loss_fn)
 from ray_tpu.train.train_step import (TrainState, make_optimizer,
@@ -164,7 +165,7 @@ def test_chunked_xent_bf16_gradients_are_the_recomputing_backwards(chunk):
     x = jax.random.normal(kx, (2, 128, d), jnp.float32).astype(jnp.bfloat16)
     wte = (0.02 * jax.random.normal(kw, (v, d))).astype(jnp.bfloat16)
     targets = jax.random.randint(kt, (2, 128), 0, v, jnp.int32)
-    dx, dw = jax.jit(jax.grad(_chunked_xent, argnums=(0, 1)),
+    dx, dw = jax.jit(jax.grad(chunked_xent, argnums=(0, 1)),
                      static_argnums=3)(x, wte, targets, chunk)
     want_dx, want_dw = jax.jit(_recomputing_xent_grads, static_argnums=3)(
         x, wte, targets, chunk)
@@ -211,6 +212,35 @@ def test_chunked_xent_makes_each_chunks_logits_once(differentiated,
     dots = _vocab_dots(jaxpr, cfg.vocab_size)
     assert len(dots) == matmuls, dots
     assert len(set(dots)) == 1 and len(dots[0]) == 1, dots
+
+
+# rows of a step -> sha256[:16] of the lowered text (``lower().as_text()``,
+# no debug info) of the one-chip GPT-2 124M training step at the two
+# one-chip cells' shapes (flash, remat, loss_chunk 256, AdamW, donated
+# state), read from PR 51's tree, the parent of the PR that moved the
+# chunked loss to models/layers.py and taught it a mesh.
+GPT2_124M_STEP_PARENT_TEXT = {32: "6e14fd881a23fe60", 16: "341f20972642216d"}
+
+
+@pytest.mark.parametrize("rows", sorted(GPT2_124M_STEP_PARENT_TEXT))
+def test_one_chip_train_step_lowers_to_the_parents_text(rows):
+    """With ``cfg.mesh`` None the loss takes the chunked scan on the one
+    device, as before the tokens could be cut over a mesh: the whole step
+    of ``train-gpt2-124m`` (32 rows) and ``train-gpt2-124m-b16`` lowers to
+    the text it lowered to before, letter for letter."""
+    import hashlib
+
+    cfg = GPT2Config(attn_impl="flash", remat=True)
+    optimizer = make_optimizer(total_steps=100)
+    state = jax.eval_shape(lambda: TrainState.create(
+        gpt2_init(cfg, jax.random.PRNGKey(0)), optimizer))
+    step = make_sharded_train_step(
+        lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=256), optimizer,
+        telemetry=False)
+    text = step.lower(state, {"tokens": jax.ShapeDtypeStruct(
+        (rows, cfg.max_seq + 1), jnp.int32)}).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        GPT2_124M_STEP_PARENT_TEXT[rows]
 
 
 def test_llama_flash_agrees_with_dense():

@@ -372,19 +372,27 @@ def _moved_in_blocks(text, t):
 
 
 @functools.lru_cache(maxsize=None)
-def _qkv_step(remat):
+def _qkv_step(remat, vocab_size=320, loss_chunk=0):
     """The training step of ``_qkv_cfg(4)`` (4 heads of 64) over fsdp=2 x
-    tensor=2, run once: its config, its loss and its compiled text."""
+    tensor=2, run once: its config, its loss, its compiled text and its
+    row in xprof's table of programs."""
+    import dataclasses
+
+    from ray_tpu.util import xprof
+
     from ray_tpu.train.train_step import make_optimizer
 
     mesh = gang_mesh({"fsdp": 2, "tensor": 2}, jax.devices()[:4])
-    cfg = _qkv_cfg(4, remat=remat, mesh=mesh)
+    cfg = dataclasses.replace(_qkv_cfg(4, remat=remat, mesh=mesh),
+                              vocab_size=vocab_size)
     state, step, batch_sharding = sharded_step(
-        cfg, mesh, make_optimizer(total_steps=10, warmup_steps=2))
+        cfg, mesh, make_optimizer(total_steps=10, warmup_steps=2),
+        loss_chunk=loss_chunk)
     tokens = jax.device_put(jnp.zeros((4, cfg.max_seq + 1), jnp.int32),
                             batch_sharding)
     _, metrics = step(state, {"tokens": tokens})
-    return cfg, float(metrics["loss"]), step.compiled().as_text()
+    return (cfg, float(metrics["loss"]), step.compiled().as_text(),
+            xprof.local_programs()["train_step"])
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
@@ -393,7 +401,7 @@ def test_sharded_step_moves_no_activation_for_the_qkv_split(remat):
     no all-to-all under a block and no collective-permute of an
     activation there (the parent's held two permutes of [B, T, H*D] a
     layer and pass under ``attn.qkv/split``)."""
-    cfg, loss, text = _qkv_step(remat)
+    cfg, loss, text, _ = _qkv_step(remat)
     assert np.isfinite(loss)
     assert "all-reduce" in text             # it IS the sharded program
     found, activations = _moved_in_blocks(text, cfg.max_seq)
@@ -421,15 +429,26 @@ def block_sums_and_recomputed(text, t, d):
     each block ``h_<i>``, and the ``op_name`` of every all-reduce, ``dot``
     and ``convolution`` under ``rematted_computation/``."""
     sums, recomputed = {}, []
+    # The TPU's compiler makes a sum whose reader takes a part of the rows
+    # (the last block's ``mlp_out`` before the split loss) a reduce-
+    # scatter: a fusion that calls an ``%all-reduce-scatter`` computation,
+    # here one whose input is an activation.
+    scatters = set(re.findall(
+        rf"^%(all-reduce-scatter[\w.]*) \([^)]*\[[\d,]*\b{t},{d}\]",
+        text, re.M))
     for m in filter(None, map(_INSTRUCTION.search, text.splitlines())):
         op, name = m.group("op").removesuffix("-start"), m.group("name")
-        if op not in ("all-reduce", "dot", "convolution"):
+        called = re.search(r"calls=%(all-reduce-scatter[\w.]*)", m.string)
+        scattered = bool(op == "fusion" and called
+                         and called.group(1) in scatters)
+        if op not in ("all-reduce", "dot", "convolution") and not scattered:
             continue
         if "rematted_computation/" in name:
             recomputed.append((op, name))
         layer = re.search(r"/(h_\d+)/", name)
-        if op == "all-reduce" and layer:
-            n = len(re.findall(rf"\[[\d,]*\b{t},{d}\]", m.group("type")))
+        if (op == "all-reduce" or scattered) and layer:
+            n = 1 if scattered else len(re.findall(
+                rf"\[[\d,]*\b{t},{d}\]", m.group("type")))
             sums[layer.group(1)] = sums.get(layer.group(1), 0) + n
     return sums, recomputed
 
@@ -442,7 +461,7 @@ def test_sharded_step_sums_four_activations_a_block(remat):
     ``c_attn`` and ``mlp_in``) and, remat'd, makes no sum again and no
     matmul behind the residual it kept (the parent remade ``c_proj``'s
     sum, its matmul and ``mlp_in``'s a layer)."""
-    cfg, _, text = _qkv_step(remat)
+    cfg, _, text, _ = _qkv_step(remat)
     sums, recomputed = block_sums_and_recomputed(text, cfg.max_seq,
                                                  cfg.d_model)
     assert sums == {f"h_{i}": 4 for i in range(cfg.n_layer)}, sums
@@ -461,6 +480,17 @@ def test_sharded_step_sums_four_activations_a_block(remat):
     assert block_sums_and_recomputed(sample, 128, 256) == (
         {"h_1": 2}, [("all-reduce", "jit(step)/checkpoint/"
                       "rematted_computation/h_1/attn.out/c_proj/dot")])
+    # ... and a sum the chip's compiler made a reduce-scatter counts one.
+    # (one of a weight's gradient, ``.1``, does not)
+    sample = ('%all-reduce-scatter (input: f32[2,128,256]) -> f32[18,8,128] {\n'
+              '%all-reduce-scatter.1 (input.1: f32[256,512]) -> f32[128,512] {\n'
+              '%fusion.5 = f32[18,8,128]{2,1,0} fusion(%f), kind=kCustom, '
+              'calls=%all-reduce-scatter, metadata={op_name="jit(step)/'
+              'jvp(GPT2)/h_1/mlp/mlp_out/dot_general"}\n'
+              '%fusion.7 = f32[128,512]{1,0} fusion(%g), kind=kCustom, '
+              'calls=%all-reduce-scatter.1, metadata={op_name="jit(step)/'
+              'transpose(jvp(GPT2))/h_1/mlp/mlp_in/dot_general"}')
+    assert block_sums_and_recomputed(sample, 128, 256) == ({"h_1": 1}, [])
 
 
 def test_remat_changes_what_is_recomputed_not_what_is_computed():
@@ -610,3 +640,263 @@ def test_c_attn_is_stored_as_dense_stores_it_and_restores_its_checkpoint(
     want = jax.jit(lambda p: gpt2_loss_fn(plain, p, batch, loss_chunk=0))(
         params)
     np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+
+
+# ---------------------------------------------------------------------
+# The head and its loss under a mesh (PR 52): the one-scan chunked loss
+# with the TOKENS cut over the batch's axes and over ``tensor`` too, each
+# chip against the whole tied ``wte``; an odd vocabulary left GSPMD the
+# same whole float32 logits on both ``tensor`` shards.
+
+ODD_VOCAB = 321         # no axis of the mesh divides it, as 50,257
+
+
+@pytest.mark.parametrize("axes,kw,shape,chunk,want", [
+    (None, {}, (4, 128), 32, ("chunked", 32, (), (), 1)),
+    (None, {}, (4, 128), 0, ("whole", 0, (), (), 1)),
+    (None, {}, (4, 128), 128, ("whole", 0, (), (), 1)),
+    (None, {}, (4, 128), 48, ("whole", 0, (), (), 1)),
+    ({"fsdp": 2, "tensor": 2}, {}, (4, 128), 32,
+     ("chunked", 32, ("fsdp", "tensor"), (), 4)),
+    ({"fsdp": 2, "tensor": 2}, {}, (2, 128), 32,
+     ("chunked", 16, ("fsdp",), ("tensor",), 4)),
+    ({"fsdp": 2, "tensor": 2}, {}, (6, 128), 32,
+     ("chunked", 16, ("fsdp",), ("tensor",), 4)),
+    ({"fsdp": 2, "tensor": 2}, {}, (2, 128), 1, ("whole", 0, (), (), 1)),
+    ({"fsdp": 2, "tensor": 2}, {}, (3, 128), 32,
+     ("chunked", 16, (), ("tensor",), 2)),
+    ({"fsdp": 2, "tensor": 2}, {}, (3, 128), 1, ("whole", 0, (), (), 1)),
+    ({"fsdp": 4}, {}, (4, 128), 32, ("chunked", 32, ("fsdp",), (), 4)),
+    ({"fsdp": 4}, {}, (3, 128), 32, ("whole", 0, (), (), 1)),
+    ({"dcn": 2, "data": 2, "tensor": 2}, {}, (8, 128), 32,
+     ("chunked", 32, ("dcn", "data", "tensor"), (), 8)),
+    ({"data": 2, "seq": 2, "tensor": 2}, {}, (4, 128), 32,
+     ("whole", 0, (), (), 1)),
+    ({"data": 2, "expert": 2, "tensor": 2},
+     dict(moe_num_experts=4, moe_every=2), (4, 128), 32,
+     ("whole", 0, (), (), 1)),
+    (None, dict(moe_num_experts=4, moe_every=2), (4, 128), 32,
+     ("whole", 0, (), (), 1)),
+])
+def test_loss_layout_names_the_path_by_the_mesh_and_the_shapes(
+        axes, kw, shape, chunk, want):
+    """The one question the program asks: whole logits or the chunked
+    scan, and over which axes the tokens are cut.  Rows before positions;
+    a sequence axis, an MoE config, a ``T`` that is no whole number of
+    chunks, or a ``tensor`` axis that divides neither: whole logits."""
+    from ray_tpu.models.gpt2 import loss_layout
+
+    mesh = axes and gang_mesh(axes, jax.devices()[:int(np.prod(
+        list(axes.values())))])
+    assert tuple(loss_layout(_qkv_cfg(4, mesh=mesh, **kw), shape,
+                             chunk)) == want
+
+
+@functools.lru_cache(maxsize=None)
+def _loss_and_grads(rows, tensor, remat, loss_chunk):
+    """Loss and gradients of the odd-vocabulary ``_qkv_cfg(4)`` on seeded
+    tokens: across fsdp=2 x ``tensor`` (0: one device, no mesh)."""
+    import dataclasses
+
+    from ray_tpu.models.gpt2 import gpt2_init, gpt2_loss_fn
+    from ray_tpu.train import distributed as dist
+
+    plain = dataclasses.replace(_qkv_cfg(4, remat=remat),
+                                vocab_size=ODD_VOCAB)
+    params = gpt2_init(plain, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.random.randint(
+        jax.random.PRNGKey(1), (rows, plain.max_seq + 1), 0, ODD_VOCAB,
+        jnp.int32)}
+    cfg = plain
+    if tensor:
+        mesh = gang_mesh({"fsdp": 2, "tensor": tensor},
+                         jax.devices()[:2 * tensor])
+        cfg = dataclasses.replace(plain, mesh=mesh)
+        params, _ = _placed(params, mesh)
+        batch = jax.device_put(batch, dist.batch_sharding(mesh))
+    return jax.jit(jax.value_and_grad(
+        lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=loss_chunk)))(
+            params, batch)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("rows,chunk,cut", [
+    (4, 32, "rows"), (2, 32, "positions"), (2, 1, "neither")])
+def test_split_chunked_loss_is_the_whole_logits_loss(rows, chunk, cut,
+                                                     remat):
+    """fsdp=2 x tensor=2, an odd vocabulary: with the tokens cut over
+    both axes (two rows a batch shard: one a chip; one row: half of each
+    chunk's positions) loss and every gradient leaf are the whole-logits
+    path's and the one-device chunked loss's; where ``tensor`` divides
+    neither the rows nor a chunk, the program IS the whole-logits one."""
+    import dataclasses
+
+    from ray_tpu.models.gpt2 import gpt2_init, gpt2_loss_fn, loss_layout
+
+    mesh = gang_mesh({"fsdp": 2, "tensor": 2}, jax.devices()[:4])
+    cfg = dataclasses.replace(_qkv_cfg(4, remat=remat, mesh=mesh),
+                              vocab_size=ODD_VOCAB)
+    layout = loss_layout(cfg, (rows, cfg.max_seq), chunk)
+    assert (layout.path, layout.rows, layout.positions, layout.shards) == {
+        "rows": ("chunked", ("fsdp", "tensor"), (), 4),
+        "positions": ("chunked", ("fsdp",), ("tensor",), 4),
+        "neither": ("whole", (), (), 1)}[cut]
+    if cut == "neither":
+        tokens = jax.ShapeDtypeStruct((rows, cfg.max_seq + 1), jnp.int32)
+        params = jax.eval_shape(
+            lambda: gpt2_init(cfg, jax.random.PRNGKey(0)))
+        texts = [jax.jit(jax.value_and_grad(
+            lambda p, b: gpt2_loss_fn(cfg, p, b, loss_chunk=c))).lower(
+                params, {"tokens": tokens}).as_text() for c in (chunk, 0)]
+        assert texts[0] == texts[1]
+        return
+    got_loss, got = _loss_and_grads(rows, 2, remat, chunk)
+    for name, (want_loss, want) in (
+            ("whole logits", _loss_and_grads(rows, 2, remat, 0)),
+            ("one device", _loss_and_grads(rows, 0, False, chunk))):
+        np.testing.assert_allclose(float(got_loss), float(want_loss),
+                                   rtol=1e-5, err_msg=name)
+        flat_want = dict(jax.tree_util.tree_leaves_with_path(want))
+        for path, g in jax.tree_util.tree_leaves_with_path(got):
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(flat_want[path]), rtol=2e-4,
+                atol=2e-6, err_msg=name + jax.tree_util.keystr(path))
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?(?P<name>[\w.-]+) \(.*\) -> .* \{$")
+_CALLED = re.compile(r"(?:body|condition|calls|to_apply)=%?([\w.-]+)")
+_RESULT = re.compile(r"^\s*(?:ROOT )?%?(?P<name>[\w.-]+) = "
+                     r"(?P<type>\(.*?\)|\S+) (?P<op>[\w-]+)\((?P<args>.*)")
+_ARRAY = re.compile(r"\b[a-z]+\d*\[([\d,]+)\]")
+
+
+def _dims(type_str):
+    """The shapes (tuples of ints) of every array a type string names."""
+    return [tuple(int(n) for n in dims.split(","))
+            for dims in _ARRAY.findall(type_str)]
+
+
+def _instructions(text):
+    """(computation, name, type, opcode, the rest of the line) of every
+    instruction of a compiled text, and the computations that run inside
+    a ``while``: the loops' bodies and conditions and all they call."""
+    found, calls, roots, comp = [], {}, set(), None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            comp = head.group("name")
+            continue
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        found.append((comp, m.group("name"), m.group("type"), m.group("op"),
+                      m.group("args")))
+        called = _CALLED.findall(line)
+        calls.setdefault(comp, set()).update(called)
+        if m.group("op") == "while":
+            roots.update(called)
+    looped, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c not in looped:
+            looped.add(c)
+            todo += calls.get(c, ())
+    return found, looped
+
+
+def vocab_arrays(text, v):
+    """The shape of every array of a compiled text that has ``v`` as a
+    dimension (a tuple's elements each)."""
+    found, _ = _instructions(text)
+    return {dims for _, _, type_str, _, _ in found
+            for dims in _dims(type_str) if v in dims}
+
+
+def vocab_matmuls(text, v, d):
+    """(in a loop?, tokens) of every ``dot`` / ``convolution`` of a
+    compiled text whose result or an operand has the vocabulary ``v`` as
+    a dimension: the tokens are the other dimensions of its array that is
+    no ``[v, d]`` weight (the logits, or their cotangent)."""
+    found, looped = _instructions(text)
+    types = {name: type_str for _, name, type_str, _, _ in found}
+    out = []
+    for comp, _, type_str, op, args in found:
+        if op not in ("dot", "convolution"):
+            continue
+        operands = re.findall(r"%([\w.-]+)", args.split("), ")[0])
+        arrays = [dims for t in [type_str] + [types.get(o, "")
+                                              for o in operands]
+                  for dims in _dims(t) if v in dims]
+        tokens = [int(np.prod(dims)) // v for dims in arrays
+                  if sorted(dims) != sorted((v, d))]
+        if arrays:
+            out.append((comp in looped, min(tokens, default=0)))
+    return out
+
+
+def summed_across_chips(text, shape):
+    """For every all-reduce / reduce-scatter OPERAND of ``shape`` in a
+    compiled text (a combined one's tuple counts each): whether the
+    reduction runs inside a ``while``."""
+    found, looped = _instructions(text)
+    return [comp in looped for comp, _, type_str, op, _ in found
+            if op.removesuffix("-start") in ("all-reduce", "reduce-scatter")
+            for dims in _dims(type_str) if dims == tuple(shape)]
+
+
+_LOOPED_SUM = """\
+%body.1 (p: (s32[], f32[321,256])) -> (s32[], f32[321,256]) {
+  %dw = f32[321,256]{1,0} dot(%dl, %xc), lhs_contracting_dims={0}
+  %ar = f32[321,256]{1,0} all-reduce(%dw), replica_groups={{0,1,2,3}}, to_apply=%add
+  %dl = f32[2,128,321]{2,1,0} dot(%x, %wte), lhs_contracting_dims={2}
+}
+ENTRY %main.2 (a: f32[8]) -> f32[] {
+  %w = (s32[], f32[321,256]{1,0}) while(%t), condition=%cond.3, body=%body.1
+  %ars = (f32[256]{0}, f32[321,256]{1,0}) all-reduce-start(%g, %dw2), to_apply=%add
+}
+"""
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_sharded_step_makes_a_quarter_of_the_logits_a_chip(remat):
+    """The compiled step of fsdp=2 x tensor=2 with an odd vocabulary and
+    ``loss_chunk``: no array of a batch shard's ``[rows, T, V]`` logits
+    (nor any past a chip's quarter of a chunk), the three vocabulary-sized
+    matmuls in the loss's loop on a quarter of a chunk's tokens each, ``d
+    wte`` summed across the chips ONCE and outside that loop, and a
+    block's four activation sums as before."""
+    chunk = 32
+    cfg, loss, text, row = _qkv_step(remat, ODD_VOCAB, chunk)
+    assert np.isfinite(loss)
+    # The step's own harvest says which loss it compiled, beside its
+    # collectives by kind.
+    assert row["loss"] == {"path": "chunked", "token_shards": 4,
+                           "chunk": chunk}
+    assert row["collective_counts"]["all-reduce"] > 0
+    v, d, t = cfg.vocab_size, cfg.d_model, cfg.max_seq
+    rows = 4                            # _qkv_step's batch: 2 an fsdp shard
+    quarter = rows * chunk // 4
+    logits = {dims for dims in vocab_arrays(text, v)
+              if sorted(dims) not in (sorted((v, d)), sorted((v, d // 2)))}
+    assert logits and all(np.prod(dims) // v <= quarter
+                          for dims in logits), logits
+    assert (rows // 2, t, v) not in logits
+    matmuls = vocab_matmuls(text, v, d)
+    assert matmuls == [(True, quarter)] * 3, matmuls
+    # (the embedding's own gradient is summed as [V, D / fsdp])
+    assert summed_across_chips(text, (v, d)) == [False]
+    sums, _ = block_sums_and_recomputed(text, t, d)
+    assert sums == {f"h_{i}": 4 for i in range(cfg.n_layer)}, sums
+    # The whole-logits program of the same step is what these scans are
+    # there to see: a batch shard's logits, matmuls outside any loop.
+    _, _, whole, row = _qkv_step(remat, ODD_VOCAB, 0)
+    assert row["loss"] == {"path": "whole", "token_shards": 1, "chunk": 0}
+    assert (rows // 2, t, v) in vocab_arrays(whole, v)
+    assert whole.count(" while(") < text.count(" while(")
+    assert not any(looped for looped, _ in vocab_matmuls(whole, v, d))
+    # ... and a synthetic text: a sum of [V, D] inside a loop's body is
+    # told from one outside, a combined sum counts its [V, D] operand.
+    assert summed_across_chips(_LOOPED_SUM, (v, d)) == [True, False]
+    assert (2, 128, v) in vocab_arrays(_LOOPED_SUM, v)
+    # (``d wte``'s tokens are read off its operand, the logits' cotangent)
+    assert vocab_matmuls(_LOOPED_SUM, v, d) == [(True, 256), (True, 256)]

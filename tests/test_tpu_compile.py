@@ -1135,8 +1135,97 @@ def test_gpt2_sharded_step_compiles_for_four_chips(
     # kernels' second forward is merged with the first, as on one chip.
     from test_parallel import block_sums_and_recomputed
 
+    # (the last block's ``mlp_out`` sum, which feeds the split loss
+    # alone, is a reduce-scatter here and counts as the sum it is)
     sums, recomputed = block_sums_and_recomputed(text, cfg.max_seq,
                                                  cfg.d_model)
     assert sums == {f"h_{i}": 4 for i in range(cfg.n_layer)}, sums
     assert not recomputed, recomputed
     assert _kernel_calls(text) == dict.fromkeys(KERNEL_NAMES, cfg.n_layer)
+    # The head and its loss on a quarter of the tokens a chip (PR 52): 16
+    # rows are 8 an fsdp shard and 4 a chip, so no array holds a batch
+    # shard's [8, 1024, V] logits and the largest with the vocabulary is
+    # one chunk of a chip's rows; ``d wte`` is summed across the chips
+    # once, after the loss's loop.
+    _assert_loss_is_split(text, cfg, rows=16, chunk=256)
+
+
+def _assert_loss_is_split(text, cfg, rows, chunk):
+    from test_parallel import summed_across_chips, vocab_arrays
+
+    v, d, t = cfg.vocab_size, cfg.d_model, cfg.max_seq
+    arrays = vocab_arrays(text, v)
+    assert (rows // 2, t, v) not in arrays
+    assert max(int(np.prod(dims)) // v for dims in arrays
+               if v * d not in (np.prod(dims), 2 * np.prod(dims))) \
+        == rows // 4 * chunk, arrays
+    assert summed_across_chips(text, (v, d)) == [False]
+    under_loss = [line for line in text.splitlines()
+                  if re.search(r'op_name="[^"]*[/(]loss[)/]', line)]
+    assert sum(" while(" in line for line in under_loss) == 1
+
+
+def _cell_step(mesh, n_layer, loss_chunk):
+    """``train-gpt2-large-fsdp2x2``'s own step (its ``program_config``,
+    its traffic's batch and optimizer, the state placed by the family's
+    rules) at ``n_layer`` layers, compiled for the described mesh."""
+    import dataclasses
+
+    from benchmark.harness import manifest
+    from benchmark.harness.families import family_of
+    from ray_tpu.parallel.partition_rules import tree_shardings
+    from ray_tpu.train import distributed as dist
+    from ray_tpu.train.train_step import (TrainState, make_optimizer,
+                                          make_sharded_train_step)
+
+    cell = manifest.load_cell("train-gpt2-large-fsdp2x2")
+    fam, tr = family_of(cell.config), cell.traffic
+    plain = fam.program_config({**cell.config, "n_layer": n_layer},
+                               attn_impl=tr["step"]["attn_impl"],
+                               remat=tr["step"]["remat"])
+    cfg = dataclasses.replace(plain, mesh=mesh)
+    optimizer = make_optimizer(**tr["step"]["optimizer"])
+    state = jax.eval_shape(lambda: TrainState.create(
+        fam.init(plain, jax.random.PRNGKey(0)), optimizer))
+    shardings = tree_shardings(mesh, dist.fitted_state_specs(
+        state, mesh, dist.rules_for_model(fam.partition_rules)))
+    batch_sharding = NamedSharding(mesh, PartitionSpec("fsdp"))
+    step = make_sharded_train_step(
+        lambda p, b: fam.loss(cfg, p, b, loss_chunk=loss_chunk), optimizer,
+        mesh=mesh, state_shardings=shardings, batch_sharding=batch_sharding,
+        telemetry=False)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (tr["global_batch"], tr["seq_len"] + 1), jnp.int32)}
+    assert tr["step"]["loss_chunk"] == 256
+    return cfg, step.lower(_on(state, shardings),
+                           _on(batch, batch_sharding)).compile()
+
+
+# The cell's 36 layers take ~1.5 min a compile: outside tier-1.  There (my
+# compiles, PR 52) 16.009 -> 13.773 GB a chip.
+@pytest.mark.parametrize("n_layer,spared", [
+    (2, 4.5e9), pytest.param(36, 2.0e9, marks=pytest.mark.slow)])
+def test_large_fsdp2x2_cell_keeps_no_whole_logits(mesh_2x2, compiled_kernel,
+                                                  n_layer, spared):
+    """The four-chip cell's step at its own widths (1,280 wide, 20 heads,
+    32 x 1024 tokens, ``loss_chunk`` 256) against the whole-logits program
+    of the same tree (``loss_chunk=0``: the parent's path): gigabytes less
+    a chip, no ``f32[16,1024,50257]``, the same kernel calls and the same
+    four sums a block."""
+    cfg, split = _cell_step(mesh_2x2, n_layer, 256)
+    _, whole = _cell_step(mesh_2x2, n_layer, 0)
+    assert _device_bytes(split) < HBM_BYTES
+    assert _device_bytes(whole) - _device_bytes(split) > spared
+    text, whole_text = split.as_text(), whole.as_text()
+    assert "f32[16,1024,50257]" in whole_text
+    assert "f32[16,1024,50257]" not in text
+    _assert_loss_is_split(text, cfg, rows=32, chunk=256)
+    assert _kernel_calls(text) == _kernel_calls(whole_text) == \
+        dict.fromkeys(KERNEL_NAMES, n_layer)
+    from test_parallel import block_sums_and_recomputed
+
+    sums, recomputed = block_sums_and_recomputed(text, cfg.max_seq,
+                                                 cfg.d_model)
+    assert (sums, recomputed) == block_sums_and_recomputed(
+        whole_text, cfg.max_seq, cfg.d_model)
+    assert sums == {f"h_{i}": 4 for i in range(n_layer)}, sums

@@ -503,6 +503,10 @@ def test_sharded_step_registers_collectives_on_both_axes():
         assert set(counts) == set(xprof.COLLECTIVE_OPS), counts
         assert counts["all-reduce"] > 0 and counts["all-gather"] > 0
         assert step.collective_counts == counts
+        # ... and what the traced loss said of itself (whole logits
+        # here: loss_chunk=0).
+        assert prog["loss"] == step.notes["loss"] == {{
+            "path": "whole", "token_shards": 1, "chunk": 0}}
 
         # ...and the facts went out as rt_xla_* gauges.
         names = {{s["name"] for s in registry().snapshot()}}
