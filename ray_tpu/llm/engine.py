@@ -70,7 +70,14 @@ index outside the pool.  A model with LATENT attention (Kimi-K2) has a
 paged pool of ONE array, a row a position a layer and no V pool
 (kv_cache.py ``pool_arrays`` / ``init_pool``): the engine builds,
 carries, donates and aliases the arrays the spec names, whichever they
-are.
+are.  A model with SLIDING-WINDOW layers (Command A+) keeps its K/V in
+TWO GROUPS (kv_cache.py): beside the pages of the layers that keep every
+position a sequence takes, at admission, the whole RING of the window
+group, ``window`` positions however long it grows, and gives it back with
+its pages; each group has its arrays, its table and its ``PagePool``
+(``num_pages`` is the first group's; the second's pages follow from
+``max_batch`` and the window), and ``stats()["attention"]`` counts what
+each group's layers read and hold.
 
 Tokens are chosen ON THE DEVICE: after each forward one jitted sampler
 (sampling.py ``jit_sampler``) takes the device-resident logits of the
@@ -112,8 +119,8 @@ import numpy as np
 
 from ..util import chips
 from ..util.spans import Phases, annotate
-from .kv_cache import (PagePool, SlotPool, init_pool, init_state,
-                       pages_for)
+from .kv_cache import (WINDOW_ARRAYS, PagePool, SlotPool, init_pool,
+                       init_state, pages_for, ring_pages)
 from .sampling import (SamplingParams, jit_feed, jit_sampler, pack_rows,
                        seed_words)
 
@@ -165,7 +172,8 @@ class _Sequence:
     """One in-flight generation request (engine-internal)."""
 
     __slots__ = ("sid", "tokens", "prompt_len", "max_tokens", "params",
-                 "seed", "out", "pages", "slot", "n_cached", "launched",
+                 "seed", "out", "pages", "ring", "slot", "n_cached",
+                 "launched",
                  "generated", "finished", "cancelled", "submitted_ts",
                  "request_id", "first_token_ts", "last_token_ts",
                  "warmup")
@@ -182,6 +190,9 @@ class _Sequence:
         self.seed = seed_words(seed)    # the sampler's key, two uint32
         self.out: "queue.Queue" = queue.Queue()
         self.pages: List[int] = []
+        # Its ring in the window group (a model with window layers): whole
+        # while it runs, absent otherwise.
+        self.ring: List[int] = []
         # Its row while it runs: of the decode batch, of the device's
         # token array and, where the model keeps one, of the state pool.
         self.slot: Optional[int] = None
@@ -268,7 +279,10 @@ def jit_forward(model):
     through the layers by the model, they are updated in place: the
     program scatters the new rows and holds no second pool
     (tests/test_llm.py and tests/test_tpu_compile.py pin that).  Then
-    the page table and the positions.  A model whose cache spec has
+    the page table and the positions.  A model with window layers takes
+    the window group's ``window_k_pages`` and ``window_v_pages`` after
+    ``v_pages`` (donated and aliased alike) and its ``window_table``
+    after ``page_table``.  A model whose cache spec has
     recurrent layers takes, after the positions, the state pool's arrays
     (llm/kv_cache.py ``state_arrays``: ``conv`` and ``ssm``, or ``conv``
     alone; donated and updated in place as the pages are) and each row's
@@ -291,14 +305,15 @@ def jit_forward(model):
     from ..models import family_of
     from ..models.decoder import residual_counters
     from ..ops.moe import moe_counters
-    from .kv_cache import pool_arrays, state_arrays
+    from .kv_cache import pool_arrays, pool_tables, state_arrays
 
     spec = family_of(model.cfg).cache(model.cfg)
     pools, held = pool_arrays(spec), state_arrays(spec)
+    tables = pool_tables(spec)
     n = len(pools)
 
-    def run(p, tokens, paged, page_table, positions, state, last):
-        cache = dict(zip(pools, paged, strict=True), page_table=page_table)
+    def run(p, tokens, paged, page_tables, positions, state, last):
+        cache = dict(zip(pools + tables, paged + page_tables, strict=True))
         if held:
             cache.update(zip(held + ("slots",), state, strict=True))
         (logits, new), sown = model.apply(
@@ -312,19 +327,27 @@ def jit_forward(model):
 
     # The parameters' names are part of the compiled program's text (and
     # of the compile cache's key): the K/V families keep theirs.
-    if n == 2:
+    if n == 4:
+        def fwd(p, tokens, k_pages, v_pages, window_k_pages,
+                window_v_pages, page_table, window_table, positions,
+                *state, last=None):
+            return run(p, tokens, (k_pages, v_pages, window_k_pages,
+                                   window_v_pages),
+                       (page_table, window_table), positions, state, last)
+    elif n == 2:
         def fwd(p, tokens, k_pages, v_pages, page_table, positions, *state,
                 last=None):
-            return run(p, tokens, (k_pages, v_pages), page_table,
+            return run(p, tokens, (k_pages, v_pages), (page_table,),
                        positions, state, last)
     else:
         def fwd(p, tokens, latent_pages, page_table, positions, *state,
                 last=None):
-            return run(p, tokens, (latent_pages,), page_table, positions,
+            return run(p, tokens, (latent_pages,), (page_table,), positions,
                        state, last)
 
+    first = 3 + n + len(tables)     # of the state pool's arrays
     return jax.jit(fwd, donate_argnums=tuple(range(2, 2 + n)) + tuple(
-        range(4 + n, 4 + n + len(held))))
+        range(first, first + len(held))))
 
 
 def _program_bytes(exe) -> int:
@@ -370,8 +393,15 @@ class GenerationEngine:
         self._pages_per_seq = pages_for(self.max_context,
                                         self.cfg.page_size)
         self.pool = PagePool(self.cfg.num_pages, self.cfg.page_size)
+        # The window group (a spec with window layers; else no pool, no
+        # arrays, no table): every row's ring, whole or absent.
+        self._ring_pages = ring_pages(spec, self.cfg.page_size)
+        self.window_pool = PagePool(
+            self.cfg.max_batch * self._ring_pages, self.cfg.page_size,
+            group="window") if self._ring_pages else None
         self._kv = init_pool(spec, self.cfg.num_pages, self.cfg.page_size,
-                             model_cfg.dtype)
+                             model_cfg.dtype,
+                             self.cfg.max_batch * self._ring_pages)
         # One slot a running sequence: its row of the decode batch
         # (it keeps it while it runs, so the device's ids of one step
         # are the next step's tokens row for row) and, where the model
@@ -477,12 +507,23 @@ class GenerationEngine:
         # are then beside it as ``latent_dim`` / ``rope_dim``),
         # ``kv_rows_held`` what a gather of every row's whole page table
         # moves (max_batch x pages_per_seq x page_size x layers a run).
+        # With window layers both count BOTH groups, each by what its
+        # layers read (a window layer ``min(n_cached + 1, window)`` rows,
+        # in whole pages) and hold (its ring), and the window group's part
+        # is beside them: ``window_rows_read``, ``window_rows_held``, and
+        # ``window_positions_dropped``, the rows a layer that kept every
+        # position would have read and these did not.
         self._attention = {
             "decode_runs": 0, "kv_rows_read": 0, "kv_rows_held": 0,
             "kv_row_bytes": sum(a.shape[-1] * a.dtype.itemsize
-                                for a in self._kv.values()),
+                                for name, a in self._kv.items()
+                                if name not in WINDOW_ARRAYS),
             **({"latent_dim": spec.latent_dim, "rope_dim": spec.rope_dim}
-               if spec.latent_dim else {})}
+               if spec.latent_dim else {}),
+            **({"window": spec.window, "window_layers": spec.window_layers,
+                "window_rows_read": 0, "window_rows_held": 0,
+                "window_positions_dropped": 0}
+               if spec.window_layers else {})}
         # A step's leaves are handed over with the step's own time in
         # one go, under the lock: a stats() taken mid-step still sums up.
         self._phases = Phases(PHASE_LEAVES, "llm.other", lock=self._lock,
@@ -670,6 +711,13 @@ class GenerationEngine:
                 "compiles": self._compiles,
                 "kv_pages_used": self.pool.used,
                 "kv_pages_total": self.pool.num_pages,
+                # ... by group, where the K/V pool has two
+                **({"kv_pages": {
+                    "full": {"used": self.pool.used,
+                             "total": self.pool.num_pages},
+                    "window": {"used": self.window_pool.used,
+                               "total": self.window_pool.num_pages}}}
+                   if self.window_pool else {}),
                 "running": len(self._running),
                 "waiting": len(self._waiting),
                 "steps": self._steps,
@@ -853,12 +901,17 @@ class GenerationEngine:
                     pages = self.pool.alloc(n_pages)
                     if pages is None:
                         return      # wait for frees/retirements
-                    seq.slot = self.slots.take()
-                    if seq.slot is None:        # as many slots as rows
+                    # ... and, of the window group, its whole ring
+                    ring = self.window_pool.alloc(self._ring_pages) \
+                        if self.window_pool else []
+                    slot = None if ring is None else self.slots.take()
+                    if slot is None:    # as many slots, and rings, as rows
                         self.pool.free(pages)
+                        if ring:
+                            self.window_pool.free(ring)
                         return
                     self._waiting.popleft()
-                    seq.pages = pages
+                    seq.pages, seq.ring, seq.slot = pages, ring, slot
                     oversized = None
             if oversized is not None:
                 self._retire(oversized,
@@ -876,15 +929,24 @@ class GenerationEngine:
                 self._retire(seq, error=repr(e))
                 raise
 
-    def _page_table_row(self, seq: _Sequence) -> np.ndarray:
-        row = np.zeros(self._pages_per_seq, np.int32)
-        row[:len(seq.pages)] = seq.pages
-        return row
+    def _tables(self, rows: List[tuple], n_rows: int) -> tuple:
+        """The page tables of a launch, one a group: ``rows`` are
+        (sequence, its row) pairs, the other rows zeros."""
+        table = np.zeros((n_rows, self._pages_per_seq), np.int32)
+        for seq, row in rows:
+            table[row, :len(seq.pages)] = seq.pages
+        if not self.window_pool:
+            return (table,)
+        rings = np.zeros((n_rows, self._ring_pages), np.int32)
+        for seq, row in rows:
+            rings[row] = seq.ring
+        return table, rings
 
-    def _call_fwd(self, kind: str, tokens, table, positions, slots,
+    def _call_fwd(self, kind: str, tokens, tables, positions, slots,
                   **served):
         """The forward of this token shape (``llm_decode``, or
-        ``llm_prefill[bucket]``) over the caches, which it updates:
+        ``llm_prefill[bucket]``) over the caches, which it updates
+        (``tables``: ``_tables``'s):
         returns (the program's name, logits, (the routing counters or None,
         the residual kind's or None)).  ``slots`` is
         each row's slot of the state pool (a row without a sequence:
@@ -893,7 +955,8 @@ class GenerationEngine:
         are then that one position's."""
         name = f"llm_{kind}[{tokens.shape[1]}]" \
             if kind == "prefill" else f"llm_{kind}"
-        args = (self._params, tokens, *self._kv.values(), table, positions)
+        args = (self._params, tokens, *self._kv.values(), *tables,
+                positions)
         if self._state is not None:
             args += (*self._state.values(), slots)
         logits, *rest = self._call(self._fwd, name, *args, **served)
@@ -1117,10 +1180,18 @@ class GenerationEngine:
             pad = _bucket(n)
             tokens = np.zeros((1, pad), np.int32)
             tokens[0, :n] = seq.tokens
+            # From position 0, always: a multi-row step attends among its
+            # own rows and reads nothing of the pool (models/attention.py),
+            # and a window layer's store keeps its last ``window`` rows on
+            # that assumption.
+            if seq.n_cached != 0:
+                raise ValueError(
+                    f"a prefill starts at position 0, not {seq.n_cached}: "
+                    "a multi-row step reads nothing of the cache")
             positions = np.full((1, pad), -1, np.int32)
             positions[0, :n] = np.arange(n)
-            table = self._page_table_row(seq)[None, :]
             flight_rows = [(seq, 0)]
+            tables = self._tables(flight_rows, 1)
             sampling = self._pack_sampling(flight_rows)
             # its id, row 0 of the sampler's, to its own row
             feed_to = np.full(self.cfg.max_batch, self.cfg.max_batch,
@@ -1128,7 +1199,7 @@ class GenerationEngine:
             feed_to[0] = seq.slot
         with self._phase("llm.prefill.run") as launch:
             name, logits, counters = self._call_fwd(
-                "prefill", tokens, table, positions,
+                "prefill", tokens, tables, positions,
                 np.asarray([seq.slot], np.int32),
                 last=np.asarray([n - 1], np.int32))
             ids = self._call(
@@ -1164,29 +1235,39 @@ class GenerationEngine:
             return
         with self._phase("llm.decode.pack"):
             positions = np.full((B, 1), -1, np.int32)
-            table = np.zeros((B, self._pages_per_seq), np.int32)
             slots = np.full(B, B, np.int32)
-            pages_read = 0
+            flight_rows = [(seq, seq.slot) for seq in batch]
+            tables = self._tables(flight_rows, B)
+            spec, page = self._cache_spec, self.cfg.page_size
+            pages_read = ring_read = 0
             for seq in batch:
                 slots[seq.slot] = seq.slot
                 positions[seq.slot, 0] = seq.n_cached
-                table[seq.slot] = self._page_table_row(seq)
-                pages_read += pages_for(seq.n_cached + 1,
-                                        self.cfg.page_size)
-            rows = self.cfg.page_size * self._cache_spec.kv_layers
+                pages_read += pages_for(seq.n_cached + 1, page)
+                if spec.window_layers:
+                    ring_read += pages_for(
+                        min(seq.n_cached + 1, spec.window), page)
+            rows = page * spec.kv_layers
             counts = self._attention
             counts["decode_runs"] += 1
             counts["kv_rows_read"] += pages_read * rows
-            counts["kv_rows_held"] += table.size * rows
+            counts["kv_rows_held"] += tables[0].size * rows
+            if spec.window_layers:
+                rows = page * spec.window_layers
+                counts["kv_rows_read"] += ring_read * rows
+                counts["kv_rows_held"] += tables[1].size * rows
+                counts["window_rows_read"] += ring_read * rows
+                counts["window_rows_held"] += tables[1].size * rows
+                counts["window_positions_dropped"] += \
+                    (pages_read - ring_read) * rows
             if self._state_counts:
                 self._state_counts["decode_runs"] += 1
                 self._state_counts["state_rows_updated"] += \
                     len(batch) * self._cache_spec.state_layers
-            flight_rows = [(seq, seq.slot) for seq in batch]
             sampling = self._pack_sampling(flight_rows)
         with self._phase("llm.decode.run") as launch:
             name, logits, counters = self._call_fwd(
-                "decode", self._tokens, table, positions, slots)
+                "decode", self._tokens, tables, positions, slots)
             ids = self._call(self._sampler, "llm_sample", logits,
                              *sampling)
             self._feed(ids, self._all_rows)
@@ -1250,11 +1331,15 @@ class GenerationEngine:
 
     def _release(self, seq: _Sequence) -> None:
         """Give back what ``seq`` holds of the device's caches: its pages
-        and its slot (at its last launch, retirement, cancellation and
-        eviction alike: a re-prefill rebuilds the state from position
-        0).  A second call finds nothing to give."""
+        (of both groups, where the pool has two) and its slot (at its last
+        launch, retirement, cancellation and eviction alike: a re-prefill
+        rebuilds the state from position 0).  A second call finds nothing
+        to give."""
         self.pool.free(seq.pages)
         seq.pages = []
+        if seq.ring:
+            self.window_pool.free(seq.ring)
+            seq.ring = []
         self.slots.give(seq.slot)
         seq.slot = None
 

@@ -77,9 +77,26 @@ takes ``latent_pages``, the page table and the positions, then ``conv``,
 ``ssm`` and the slots, and returns the three arrays in that order, all
 donated and aliased (``llm/engine.py jit_forward``).
 
-``PagePool`` is the host-side allocator; it exports
-``rt_llm_kv_pages_{used,total}`` gauges on every alloc/free so KV
-occupancy is visible in ``rt telemetry`` and the doctor can see leaks.
+A model with SLIDING-WINDOW layers (``models/cohere.py``: three layers
+in four see the last 4,096 positions) keeps its K/V in TWO GROUPS of
+layers, each with arrays, a page count, a table and a host allocator of
+its own: the ``kv_layers`` that attend every earlier position hold them
+all, in ``k_pages`` / ``v_pages`` through ``page_table``, as above; the
+``window_layers`` hold a RING of ``window`` positions a sequence, in
+``window_k_pages`` / ``window_v_pages`` [window layers, max_batch x ring
+pages, page, h_kv*d] through ``window_table`` [B, ring pages] (position
+``p`` at ring row ``p mod window``: ``models/attention.py _ring``; the
+store and the decode kernel are the ones above, handed ring positions and
+``min(length, window)``).  A sequence takes its whole ring with its first
+pages and gives it back with them, so the second group's size follows
+from ``max_batch`` and the window alone, and what a window layer holds
+stops growing at the window whatever the sequence's length.  A spec
+without window layers has ONE group, exactly as above.
+
+``PagePool`` is the host-side allocator, one a group; it exports
+``rt_llm_kv_pages_{used,total}`` gauges (tag ``group``: ``full``,
+``window``) on every alloc/free so KV occupancy is visible in ``rt
+telemetry`` and the doctor can see leaks.
 """
 
 from __future__ import annotations
@@ -102,20 +119,46 @@ def init_cache(n_layer: int, num_pages: int, page_size: int,
             "v_pages": jnp.zeros(shape, dtype)}
 
 
+WINDOW_ARRAYS = ("window_k_pages", "window_v_pages")
+
+
 def pool_arrays(spec) -> Tuple[str, ...]:
     """The paged pool's arrays for a cache spec, in the order the forward
-    takes and returns them: K and V, or the one latent pool."""
-    return ("latent_pages",) if spec.latent_dim else ("k_pages", "v_pages")
+    takes and returns them: K and V (then the window group's K and V,
+    where the spec has window layers), or the one latent pool."""
+    if spec.latent_dim:
+        return ("latent_pages",)
+    return ("k_pages", "v_pages") + (WINDOW_ARRAYS if spec.window_layers
+                                     else ())
 
 
-def init_pool(spec, num_pages: int, page_size: int,
-              dtype: Any) -> Dict[str, Any]:
-    """The paged pool a cache spec asks for (``pool_arrays``), zeros."""
-    if not spec.latent_dim:
-        return init_cache(spec.kv_layers, num_pages, page_size,
+def pool_tables(spec) -> Tuple[str, ...]:
+    """The page tables the forward takes after the pool's arrays: one a
+    group."""
+    return ("page_table",) + (("window_table",) if spec.window_layers
+                              else ())
+
+
+def ring_pages(spec, page_size: int) -> int:
+    """Pages of one sequence's ring in the window group (0: no group)."""
+    return pages_for(spec.window, page_size) if spec.window_layers else 0
+
+
+def init_pool(spec, num_pages: int, page_size: int, dtype: Any,
+              window_pages: int = 0) -> Dict[str, Any]:
+    """The paged pool a cache spec asks for (``pool_arrays``), zeros:
+    ``num_pages`` of the group that keeps every position, ``window_pages``
+    of the window group where the spec has one."""
+    if spec.latent_dim:
+        return {"latent_pages": jnp.zeros(
+            (spec.kv_layers, num_pages, page_size, spec.row_width), dtype)}
+    pool = init_cache(spec.kv_layers, num_pages, page_size,
+                      spec.kv_heads, spec.head_dim, dtype)
+    if spec.window_layers:
+        ring = init_cache(spec.window_layers, window_pages, page_size,
                           spec.kv_heads, spec.head_dim, dtype)
-    return {"latent_pages": jnp.zeros(
-        (spec.kv_layers, num_pages, page_size, spec.row_width), dtype)}
+        pool.update(zip(WINDOW_ARRAYS, ring.values()))
+    return pool
 
 
 def state_arrays(spec) -> Tuple[str, ...]:
@@ -265,14 +308,17 @@ class PagePool:
     asked for or stays queued — partial grants would deadlock two
     growing sequences against each other), LIFO free list for locality,
     occupancy exported as ``rt_llm_kv_pages_used`` /
-    ``rt_llm_kv_pages_total`` gauges on every transition.
+    ``rt_llm_kv_pages_total`` gauges on every transition, tagged with the
+    ``group`` of layers whose pages these are.
     """
 
-    def __init__(self, num_pages: int, page_size: int):
+    def __init__(self, num_pages: int, page_size: int,
+                 group: str = "full"):
         if num_pages <= 0 or page_size <= 0:
             raise ValueError("num_pages and page_size must be > 0")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
+        self._tags = {"group": group}
         self._free: List[int] = list(range(self.num_pages - 1, -1, -1))
         self._lock = threading.Lock()
         # Gauge handles cached once — alloc/free is the decode hot
@@ -285,9 +331,10 @@ class PagePool:
             self._gauges = (
                 Gauge("rt_llm_kv_pages_used",
                       "KV-cache pages currently allocated to "
-                      "sequences."),
+                      "sequences.", tag_keys=("group",)),
                 Gauge("rt_llm_kv_pages_total",
-                      "Total KV-cache pages in the device pool."))
+                      "Total KV-cache pages in the device pool.",
+                      tag_keys=("group",)))
         except Exception:
             pass
         self._publish(self.num_pages)
@@ -329,8 +376,9 @@ class PagePool:
         if self._gauges is None:
             return
         try:
-            self._gauges[0].set(float(self.num_pages - free_now))
-            self._gauges[1].set(float(self.num_pages))
+            self._gauges[0].set(float(self.num_pages - free_now),
+                                tags=self._tags)
+            self._gauges[1].set(float(self.num_pages), tags=self._tags)
         except Exception:
             pass
 

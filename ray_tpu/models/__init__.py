@@ -1,6 +1,6 @@
 """ray_tpu.models — TPU-first reference model families.
 
-Nine families run through both the trainer and the serving engine:
+Ten families run through both the trainer and the serving engine:
 GPT-2 (pretrain baseline, BASELINE.json headline metric), Llama
 (RoPE/GQA/SwiGLU), OLMoE (the Llama block with QK-norm and dropless
 top-k sparse experts, ops/moe.py), Granite 4.0-H (``granitemoehybrid``:
@@ -27,7 +27,12 @@ DeltaNet mixers, the delta rule with ONE decay a head, betas up to 2 and a
 rectangular 96 x 192 state a head, three layers in four, and full
 multi-head attention with a QK-norm and no position encoding in the fourth;
 every FFN dense; each norm on its sublayer's OUTPUT,
-models/olmo_hybrid.py).  All but GPT-2 are ONE decoder
+models/olmo_hybrid.py) and Command A+ (``cohere2moe``: three
+sliding-window layers with RoPE over adjacent pairs, which keep a ring of
+4,096 positions a sequence, beside one full-attention layer without a
+position encoding, which keeps them all; a PARALLEL block, attention and
+experts under ONE LayerNorm; four shared experts averaged; a tied head,
+models/cohere.py).  All but GPT-2 are ONE decoder
 (models/decoder.py: the layer loop, the block, grouped-query attention
 around the core of models/attention.py, the FFN, the loss, the rules every
 tree shares) over a config; what more than one mixer is built from
@@ -40,14 +45,16 @@ edit.
 ``MODEL_FAMILIES`` is the one table the engine (``llm/engine.py``) and
 the multi-host training plane (``train.distributed.rules_for_model``)
 resolve a family through.  A ROW is a config class, its module, init,
-loss, partition rules, a tiny preset for tests, and its cache spec.  A
-tenth family is a config (published sizes, ``tiny``; ``layer_types``,
+loss, partition rules, a tiny preset for tests, and its cache spec.  An
+eleventh family is a config (published sizes, ``tiny``; ``layer_types``,
 one entry a layer; ``mixers``, which maps each entry to its KIND; what
 the FFN reads: ``n_dense_layers``, ``experts``, ``shared_d_ff``; and,
 where the layers hand one another more than one stream, ``residual``, the
 RESIDUAL kind, ``decoder.Residual``: how the state begins and ends and how
 a sublayer reads and writes it; ``norm_output`` where each norm lies on its
-sublayer's output), the kind it adds, and a row whose module
+sublayer's output; ``parallel_block`` where both sublayers read ONE norm;
+``norm`` where that is not ``RMSNorm``), the kind it adds, and a row whose
+module
 is ``Decoder`` under its name (a config may extend another row's:
 ``family_of`` takes the row of the config's own class first).  A
 KIND (``decoder.Mixer``) says three things in one place: the module that
@@ -76,18 +83,26 @@ convolutions' window and a float32 ``[heads, d_k, d_v]`` state), and a
 layer indexes its pool by its number among its own kind; Olmo-Hybrid's
 has K/V for its attention layers AND a slot for its delta-rule ones, whose
 ``ssm_shape`` is the step kernel's layout of the heads' rectangular states
-(``ops/delta_rule.py state_shape``).  The engine
+(``ops/delta_rule.py state_shape``).  A family with sliding-window
+layers keeps its K/V in TWO GROUPS: ``kv_layers`` that hold every
+position through ``page_table``, and ``window_layers`` that hold a ring
+of ``window`` positions a sequence through ``window_table``
+(``window_k_pages`` / ``window_v_pages``; Command A+: 1 and 3 of every 4
+layers, a window of 4,096).  The engine
 builds both pools from the spec
 (``llm/kv_cache.py init_pool`` / ``init_state``), and of each the
 arrays the spec has and nothing else.
 Keys are normalized lowercase-no-separator ("gpt2", "llama", "olmoe",
 "granitemoehybrid", "lfm2moe", "kimik2", "kimilinear", "xing40",
-"olmohybrid").
+"olmohybrid", "cohere2moe").
 """
 
 from dataclasses import dataclass
 from typing import Any, Callable
 
+from .cohere import (Cohere2Moe, Cohere2MoeConfig,  # noqa: F401
+                     cohere2_moe_init, cohere2_moe_loss_fn,
+                     cohere2_moe_partition_rules)
 from .decoder import CacheSpec, cache_spec
 from .gpt2 import (GPT2, GPT2Config, gpt2_init, gpt2_loss_fn,  # noqa: F401
                    gpt2_partition_rules)
@@ -152,6 +167,10 @@ MODEL_FAMILIES = {
         OlmoHybridConfig, OlmoHybrid, olmo_hybrid_init,
         olmo_hybrid_loss_fn, olmo_hybrid_partition_rules,
         OlmoHybridConfig.tiny, cache_spec),
+    "cohere2moe": ModelFamily(
+        Cohere2MoeConfig, Cohere2Moe, cohere2_moe_init,
+        cohere2_moe_loss_fn, cohere2_moe_partition_rules,
+        Cohere2MoeConfig.tiny, cache_spec),
 }
 
 

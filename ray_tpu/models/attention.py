@@ -17,8 +17,11 @@ the end, is its sibling for a cache that holds no K and V):
   the ``tpu`` backend, the dense definition below and elsewhere
   (``_prefill_impl``), so no ``[T, max_context]`` score array is made
   and a program's size follows its bucket, not the engine's
-  ``max_context``.  Runs unsharded — the serving engine hosts one
-  replica per chip;
+  ``max_context``; grouped K and V go to the flash kernel as they are,
+  which reads a query head's group where it lies (no copy repeated to
+  the query heads).  A layer with a sliding ``window`` keeps a RING of
+  ``window`` positions a sequence in place of them all (``_ring``).
+  Runs unsharded — the serving engine hosts one replica per chip;
 - without one (training, the full forward): grouped KV heads are
   repeated to the query heads, q/k/v are constrained as the activation
   table says, and ``cfg.attn_impl`` picks ``dense`` (XLA-fused,
@@ -71,6 +74,7 @@ step's shape alone:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -90,23 +94,31 @@ def _sharded(fn, mesh, logical, shape):
 _FLASH = dict(causal=True, block_q=1024, block_k=1024)   # _attention says why
 
 
-def _attention(cfg, q, k, v, scale=None, impl=None):
-    """q, k: [B, T, H, D]; v: [B, T, H, Dv] -> [B, T, H, Dv] (Dv <= D:
-    the kernels take one width, so a narrower v is padded for them and
-    the padding cut off the result).  ``impl``: in place of
-    ``cfg.attn_impl``."""
+def _attention(cfg, q, k, v, scale=None, impl=None, window=None):
+    """q: [B, T, H, D]; k: [B, T, Hkv, D]; v: [B, T, Hkv, Dv] -> [B, T, H,
+    Dv] (Dv <= D: the kernels take one width, so a narrower v is padded
+    for them and the padding cut off the result; H a multiple of Hkv: the
+    flash kernel on one device reads each group's K/V where they lie,
+    every other path repeats them to the query heads).  ``impl``: in
+    place of ``cfg.attn_impl``.  ``window``: key ``j`` meets query ``i``
+    iff ``0 <= i - j < window`` (None: ``j <= i``)."""
     impl = impl or cfg.attn_impl
     dv = v.shape[-1]
     if impl != "dense" and dv != q.shape[-1]:
         v = jnp.pad(v, ((0, 0),) * 3 + ((0, q.shape[-1] - dv),))
-        return _attention(cfg, q, k, v, scale, impl)[..., :dv]
+        return _attention(cfg, q, k, v, scale, impl, window)[..., :dv]
     if impl == "dense":
+        k, v = _to_query_heads(q, k, v)
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                             preferred_element_type=jnp.float32)
         scores = scores * (q.shape[-1] ** -0.5 if scale is None else scale)
         t = q.shape[1]
         mask = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0) >= \
             jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+        if window is not None:
+            mask = mask & (
+                jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+                - jax.lax.broadcasted_iota(jnp.int32, (t, t), 1) < window)
         scores = jnp.where(mask[None, None], scores, -1e30)
         p = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", p, v)
@@ -129,17 +141,23 @@ def _attention(cfg, q, k, v, scale=None, impl=None):
         from ..ops import flash_attention
 
         flash = functools.partial(flash_attention, scale=scale, **_FLASH)
+        if window is not None:
+            flash = functools.partial(flash, window=window)
         if cfg.mesh is None or cfg.mesh.size == 1:
             return flash(q, k, v)
         # A Mosaic kernel is not partitioned automatically: across a
         # mesh it runs per shard, batch and heads split as the table
         # says (attention is independent over both; the sequence stays
         # whole — splitting it is ring attention's job).
+        k, v = _to_query_heads(q, k, v)
         return _sharded(flash, cfg.mesh, ("batch", None, "heads", None),
                         q.shape)(q, k, v)
     from ..parallel.ring_attention import ring_attention
     from ..parallel.ulysses import ulysses_attention
 
+    if window is not None:
+        raise ValueError(f"attn_impl={impl!r} knows no window")
+    k, v = _to_query_heads(q, k, v)
     if cfg.mesh is None:
         raise ValueError(f"attn_impl={impl!r} needs cfg.mesh")
     if scale is not None:
@@ -223,14 +241,46 @@ def _to_query_heads(q, k, v):
     return k, v
 
 
-def attention(cfg, q, k, v, cache=None, scale=None):
+def _ring(positions, window: int):
+    """Where a window layer's rows go and what a decode step reads of
+    them.  The layer's pages are a RING of ``window`` rows a sequence:
+    position ``p`` lies at ring row ``p mod window``.  Of a step's rows
+    only the last ``window`` real ones are stored (an earlier one would
+    land on a later one's row: a prefill's start, which the band hides
+    from every later query anyway), so after the store the ring holds
+    exactly the positions ``(p - window, p]`` of the newest row ``p``,
+    each once, and softmax does not ask in which order.  Returns (the
+    store's positions [B, T], < 0: dropped; the newest row's count of
+    live ring rows less one [B, 1]: what ``paged_attend`` masks by)."""
+    newest = jnp.max(positions, axis=1, keepdims=True)
+    kept = (positions >= 0) & (positions > newest - window)
+    return (jnp.where(kept, positions % window, -1),
+            jnp.minimum(positions, window - 1))
+
+
+def attention(cfg, q, k, v, cache=None, scale=None, window=None,
+              scope=None):
     """q: [B, T, H, D]; k, v: [B, T, Hkv, D] (H a multiple of Hkv).
     Returns (att [B, T, H, D], new_cache): ``new_cache`` is the updated
     (k_pages, v_pages) when ``cache`` ({"k_pages", "v_pages", "layer",
     "page_table", "positions"}) is given, else None.  ``scale``
     multiplies q k^T (None: ``D ** -0.5``; a model that states its own,
-    models/granite.py, passes it), on every branch."""
-    with jax.named_scope("attn.core"):
+    models/granite.py, passes it), on every branch.
+
+    ``window`` (None: every earlier key): key ``j`` meets query ``i`` iff
+    ``0 <= i - j < window``.  With a cache the layer's pages are then a
+    ring (``_ring``): ``page_table`` names ``window`` rows of pages a
+    sequence however long it grows, a prefill attends among its own rows
+    under the band and stores the last ``window`` of them, a decode step
+    stores at ``p mod window`` and reads ``min(p + 1, window)`` rows.
+    Without one it is the dense definition's mask (a kernel whose
+    backward knows no window must not train a full triangle in silence:
+    any other ``attn_impl`` raises).  ``scope``: a name the cached branch
+    is filed under, inside ``attn.core`` (a model with layers of two
+    kinds tells them apart in a capture: ``attn.window``, ``attn.full``)."""
+    with jax.named_scope("attn.core"), \
+            (jax.named_scope(scope) if scope and cache is not None
+             else contextlib.nullcontext()):
         if cache is not None:
             # The pool stores the Hkv GROUPED heads; each query head
             # meets its group at attend time, so GQA shrinks the pooled
@@ -238,34 +288,42 @@ def attention(cfg, q, k, v, cache=None, scale=None):
             from ..llm.kv_cache import paged_attend, paged_store
             from ..ops import paged_attention
 
+            stored = newest = cache["positions"]
+            if window is not None:
+                stored, newest = _ring(stored, window)
             k_pages, v_pages = paged_store(
                 cache["k_pages"], cache["v_pages"], cache["layer"],
-                k, v, cache["page_table"], cache["positions"])
+                k, v, cache["page_table"], stored)
             if q.shape[1] > 1:
                 # A prefill: from position 0 (``llm/engine.py
-                # _prefill_annotated``), so the rows attend among
-                # themselves and nothing is read from the pool; a bucket's
-                # padding lies behind the real ones, where the causal mask
-                # hides it from them (``latent_attention`` likewise).
-                att = _attention(cfg, q, *_to_query_heads(q, k, v), scale,
-                                 _prefill_impl(q.shape[1]))
+                # _prefill_annotated`` refuses another), so the rows
+                # attend among themselves and nothing is read from the
+                # pool; a bucket's padding lies behind the real ones,
+                # where the causal mask hides it from them
+                # (``latent_attention`` likewise).
+                att = _attention(cfg, q, k, v, scale,
+                                 _prefill_impl(q.shape[1]), window)
             elif _decode_kernel(q, k_pages):
                 # A padded row's position is -1: length 0, zeros out.
                 att = paged_attention.paged_decode(
                     q, k_pages, v_pages, cache["layer"],
-                    cache["page_table"], cache["positions"][:, 0] + 1,
-                    scale=scale)
+                    cache["page_table"], newest[:, 0] + 1, scale=scale)
             else:
                 att = paged_attend(q, k_pages, v_pages, cache["layer"],
-                                   cache["page_table"], cache["positions"],
+                                   cache["page_table"], newest,
                                    scale=scale)
             return att, (k_pages, v_pages)
+        if window is not None and cfg.attn_impl != "dense":
+            raise ValueError(
+                f"attn_impl={cfg.attn_impl!r} would train a window of "
+                f"{window} as a full triangle: its backward knows no "
+                "window; train it through attn_impl='dense'")
         k, v = _to_query_heads(q, k, v)
         heads = ("batch", "seq", "heads", None)
         q = with_logical_constraint(q, heads, cfg.mesh)
         k = with_logical_constraint(k, heads, cfg.mesh)
         v = with_logical_constraint(v, heads, cfg.mesh)
-        return _attention(cfg, q, k, v, scale), None
+        return _attention(cfg, q, k, v, scale, window=window), None
 
 
 # A cached prefill of this many rows or more takes the flash kernel on

@@ -1,7 +1,7 @@
 """ONE decoder for the serving families (Llama and OLMoE, Granite 4.0-H,
-LFM2-MoE, Kimi-K2, Kimi-Linear, Xing4.0, Olmo-Hybrid): a layer is a MIXER
-kind plus an FFN kind, joined by a RESIDUAL kind, and an architecture is a
-config and
+LFM2-MoE, Kimi-K2, Kimi-Linear, Xing4.0, Olmo-Hybrid, Command A+): a
+layer is a MIXER kind plus an FFN kind, joined by a RESIDUAL kind, and an
+architecture is a config and
 the kind it adds (its module, its scan and step, its init and the rules of
 its own leaves stay in its file).
 
@@ -10,6 +10,13 @@ embed, one ``Block`` (``norm -> mixer -> + -> norm -> FFN -> +``) per
 entry of ``cfg.layer_types``, ``norm_f``, the head.  What it reads of a
 config, and nothing of a family's name:
 
+- ``parallel_block`` (a config WITHOUT the attribute, every family but
+  one, runs its two sublayers one after the other, the block above): true,
+  ONE norm a layer, both sublayers read it and their branches are added
+  once, ``y = norm(x)``; ``x + mixer(y) + ffn(y)`` (``models/cohere.py``);
+- ``norm`` (a config WITHOUT the attribute: ``RMSNorm``): the module every
+  norm of the block and ``norm_f`` are built from (``layers.LayerNorm``:
+  the mean taken out);
 - ``norm_output`` (a config WITHOUT the attribute, every family but one,
   norms a sublayer's INPUT, the block above): true, each norm lies on the
   sublayer's OUTPUT instead, ``x + norm(mixer(x))`` then ``h + norm(
@@ -30,15 +37,19 @@ config, and nothing of a family's name:
 - the FFN (``ffn``): the first ``n_dense_layers`` a dense SwiGLU of
   ``d_ff``, the others ``ops/moe.py MoEMLP`` with the arguments
   ``experts`` gives (None: no layer has experts) and, where
-  ``shared_d_ff`` is there and not 0, a shared SwiGLU beside them;
+  ``shared_d_ff`` is there and not 0, a shared SwiGLU beside them
+  (times ``shared_multiplier`` where a config has one: several shared
+  experts averaged);
 - ``tied_head`` (the head is the embedding), and Granite's multipliers
   where a config has them (``embedding_multiplier``,
-  ``residual_multiplier``, ``logits_scaling``): a config without one gets
-  no multiply in its program;
+  ``residual_multiplier``, ``logits_scaling``; Cohere's ``logit_scale``
+  where it is not 1): a config without one gets no multiply in its
+  program;
 - ``vocab_size``, ``d_model``, ``rms_eps``, ``dtype``, ``remat``, ``mesh``.
 
-With a cache (``kv_cache``: the pools' arrays, ``page_table``, ``slots``
-where there is a state pool; ``positions`` [B, T], < 0 padding) each layer
+With a cache (``kv_cache``: the pools' arrays, ``page_table``,
+``window_table`` where there are window layers, ``slots`` where there is a
+state pool; ``positions`` [B, T], < 0 padding) each layer
 is handed the arrays ITS KIND keeps, whole, and its number among its own
 kind, and what it returns is put back: every pool is carried whole
 through the layers and updated where it lies (``llm/engine.py
@@ -85,6 +96,11 @@ class CacheSpec:
     ssm_shape: Tuple[int, ...] = ()     # the same, float32; (): none
     latent_dim: int = 0                 # > 0: ONE latent row a position
     rope_dim: int = 0                   # (c_kv | k_pe), no K/V pools
+    # A SECOND group of K/V layers, which keep ``window`` positions a
+    # sequence however long it grows (a ring of pages of its own, with a
+    # table of its own), beside the ``kv_layers`` that keep them all.
+    window_layers: int = 0
+    window: int = 0
 
     @property
     def row_width(self) -> int:
@@ -111,9 +127,12 @@ class Mixer:
 
     @property
     def index(self) -> str:
-        """What its arrays are indexed through: pages by the table, a
-        recurrent state by the row's slot."""
-        return "page_table" if self.keeps[0].endswith("_pages") else "slots"
+        """What its arrays are indexed through: pages by their group's
+        table, a recurrent state by the row's slot."""
+        if not self.keeps[0].endswith("_pages"):
+            return "slots"
+        return "window_table" if self.keeps[0].startswith("window_") \
+            else "page_table"
 
 
 @dataclass(frozen=True)
@@ -157,11 +176,11 @@ def residual_counters(intermediates):
 def cache_spec(cfg) -> CacheSpec:
     """The ``CacheSpec`` of a config, from its layers' kinds."""
     fields = {"kv_layers": 0, "kv_heads": 0, "head_dim": 0,
-              "state_layers": 0}
+              "state_layers": 0, "window_layers": 0}
+    counts = {"page_table": "kv_layers", "window_table": "window_layers",
+              "slots": "state_layers"}
     for kind, mixer in cfg.mixers.items():
-        count = "kv_layers" if mixer.index == "page_table" \
-            else "state_layers"
-        fields[count] += cfg.layer_types.count(kind)
+        fields[counts[mixer.index]] += cfg.layer_types.count(kind)
         fields.update(mixer.spec(cfg))
     return CacheSpec(**fields)
 
@@ -179,16 +198,28 @@ def _scope(name: Optional[str]):
 
 # ------------------------------------------------ grouped-query attention
 
+def _head_dim(cfg) -> int:
+    """A head's width: the config's own where it states one (Command A+:
+    128 heads of 128 over a model 4,096 wide), else ``d_model / n_head``."""
+    return getattr(cfg, "head_dim", cfg.d_model // cfg.n_head)
+
+
 def gqa(cfg, y, cache=None, *, rope: bool = True,
-        qk_norm: Optional[str] = None, scale: Optional[float] = None):
+        qk_norm: Optional[str] = None, scale: Optional[float] = None,
+        interleaved: bool = False, window: Optional[int] = None,
+        scope: Optional[str] = None):
     """y [B, T, d] -> (out [B, T, d], the K/V pool updated or None), the
     submodules made in the CALLER's scope.  ``qk_norm``: ``"width"`` (an
     RMSNorm over the whole q and k before the split into heads: OLMoE),
     ``"head"`` (over each head's own: LFM2) or None; ``rope`` False: no
-    position encoding (Granite); ``scale``: the score's, None 1/sqrt(d).
-    The cache stores the GROUPED heads (after RoPE); the full forward
-    repeats them to the query heads."""
-    h, hk, dh = cfg.n_head, cfg.n_kv_head, cfg.d_model // cfg.n_head
+    position encoding (Granite); ``interleaved``: RoPE turns adjacent
+    pairs; ``scale``: the score's, None 1/sqrt(d); ``window``: a sliding
+    window (``models/attention.py attention``: its cache is the SECOND
+    group's, ``window_k_pages`` / ``window_v_pages`` through
+    ``window_table``); ``scope``: what a capture files the cached core
+    under.  The cache stores the GROUPED heads (after RoPE); the full
+    forward repeats them to the query heads."""
+    h, hk, dh = cfg.n_head, cfg.n_kv_head, _head_dim(cfg)
     b, t = y.shape[0], y.shape[1]
     positions = cache["positions"] if cache is not None else None
     dense = _dense(cfg)
@@ -204,9 +235,20 @@ def gqa(cfg, y, cache=None, *, rope: bool = True,
         if qk_norm == "head":
             with jax.named_scope("attn.qk_norm"):
                 q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
-        if rope:
-            q, k = (_rope(z, cfg.rope_theta, positions) for z in (q, k))
-    att, kept = attention(cfg, q, k, v, cache, scale=scale)
+        if rope:    # (the pairing is said only where it is not the default)
+            pairs = {"interleaved": True} if interleaved else {}
+            q, k = (_rope(z, cfg.rope_theta, positions, **pairs)
+                    for z in (q, k))
+    kind = {}           # what only a layer with a window or a scope says
+    if window is not None:
+        kind["window"] = window
+        if cache is not None:       # the second group's arrays and table
+            cache = {**cache, "k_pages": cache["window_k_pages"],
+                     "v_pages": cache["window_v_pages"],
+                     "page_table": cache["window_table"]}
+    if scope is not None:
+        kind["scope"] = scope
+    att, kept = attention(cfg, q, k, v, cache, scale=scale, **kind)
     with jax.named_scope("attn.out"):
         out = dense(cfg.d_model, name="wo")(att.reshape(b, t, h * dh))
     return out, kept
@@ -218,11 +260,16 @@ class Attention(nn.Module):
     rope: bool = True
     qk_norm: Optional[str] = None
     scale: Optional[float] = None
+    interleaved: bool = False
+    window: Optional[int] = None
+    core_scope: Optional[str] = None    # ``gqa``'s ``scope``
 
     @nn.compact
     def __call__(self, y, cache=None):
         return gqa(self.cfg, y, cache, rope=self.rope,
-                   qk_norm=self.qk_norm, scale=self.scale)
+                   qk_norm=self.qk_norm, scale=self.scale,
+                   interleaved=self.interleaved, window=self.window,
+                   scope=self.core_scope)
 
 
 def attention_kind(module, name: Optional[str] = "attn", **names) -> Mixer:
@@ -230,7 +277,18 @@ def attention_kind(module, name: Optional[str] = "attn", **names) -> Mixer:
     pool at the grouped heads' width."""
     return Mixer(module, name, ("k_pages", "v_pages"),
                  lambda cfg: {"kv_heads": cfg.n_kv_head,
-                              "head_dim": cfg.d_model // cfg.n_head},
+                              "head_dim": _head_dim(cfg)},
+                 **names)
+
+
+def window_kind(module, name: Optional[str] = "attn", **names) -> Mixer:
+    """The kind of a grouped-query attention layer with a sliding window
+    (``cfg.sliding_window`` positions): K and V in the SECOND group's
+    pages, a ring of that many positions a sequence."""
+    return Mixer(module, name, ("window_k_pages", "window_v_pages"),
+                 lambda cfg: {"kv_heads": cfg.n_kv_head,
+                              "head_dim": _head_dim(cfg),
+                              "window": cfg.sliding_window},
                  **names)
 
 
@@ -271,9 +329,15 @@ def ffn(cfg, y, dense: bool, positions, write):
                 y, None if positions is None else positions >= 0)
             if getattr(cfg, "shared_d_ff", 0):
                 with jax.named_scope("moe.shared"):
-                    down = down + _swiglu(
+                    shared = _swiglu(
                         cfg, y, cfg.shared_d_ff,
                         ("shared_gate", "shared_up", "shared_down"))
+                    # (several shared experts AVERAGED are one SwiGLU of
+                    # their summed width times 1 / their number)
+                    mult = getattr(cfg, "shared_multiplier", 1.0)
+                    if mult != 1.0:
+                        shared = shared * jnp.asarray(mult, shared.dtype)
+                    down = down + shared
         return write(down)
 
 
@@ -305,18 +369,31 @@ class Block(nn.Module):
                 return _plus(cfg, x, branch)
             return res.write(cfg, x, maps, branch)
 
+        Norm = getattr(cfg, "norm", RMSNorm)    # the module, as data
+        if getattr(cfg, "parallel_block", False):
+            # ONE norm, read by both sublayers; their branches added once:
+            # ``x + mixer(y) + ffn(y)``, ``y = norm(x)``.
+            if res is not None or getattr(cfg, "norm_output", False):
+                raise ValueError("a parallel block has one stream and "
+                                 "norms its input")
+            y = Norm(cfg.rms_eps, cfg.dtype, name=mixer.norm)(x)
+            m = mixer.module(cfg, name=mixer.name)(y, cache)
+            m, kept = m if isinstance(m, tuple) else (m, None)
+            x = ffn(cfg, y, self.dense, positions,
+                    lambda down: _plus(cfg, x, m + down))
+            return x if cache is None else (x, kept)
         # Where a sublayer's norm lies: before it (``x + f(norm(x))``), or,
         # for a config that says ``norm_output``, on what it gives (``x +
         # norm(f(x))``: the OLMo 2 / OLMo 3 block).
         after = getattr(cfg, "norm_output", False)
-        norm = RMSNorm(cfg.rms_eps, cfg.dtype, name=mixer.norm)
+        norm = Norm(cfg.rms_eps, cfg.dtype, name=mixer.norm)
         u, maps = read(x, 0)
         m = mixer.module(cfg, name=mixer.name)(u if after else norm(u),
                                                cache)
         m, kept = m if isinstance(m, tuple) else (m, None)
         with _scope(mixer.residual_scope):
             x = write(x, maps, norm(m) if after else m)
-        norm = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")
+        norm = Norm(cfg.rms_eps, cfg.dtype, name="mlp_norm")
         u, maps = read(x, 1)
         back = functools.partial(write, x, maps)
         x = ffn(cfg, u if after else norm(u), self.dense, positions,
@@ -376,7 +453,8 @@ class Decoder(nn.Module):
             x = served_position(x, last)
         if res is not None:
             x = res.end(cfg, x)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
+        x = getattr(cfg, "norm", RMSNorm)(cfg.rms_eps, cfg.dtype,
+                                           name="norm_f")(x)
         with jax.named_scope("lm_head"):
             if getattr(cfg, "tied_head", False):
                 logits = jnp.einsum("btd,vd->btv", x, emb.astype(cfg.dtype),
@@ -389,6 +467,8 @@ class Decoder(nn.Module):
                                     preferred_element_type=jnp.float32)
             if hasattr(cfg, "logits_scaling"):
                 logits = logits / cfg.logits_scaling
+            if getattr(cfg, "logit_scale", 1.0) != 1.0:
+                logits = logits * cfg.logit_scale
             logits = _constrain(logits, ("batch", "seq", "vocab"), cfg.mesh)
         return (logits, new) if cached else logits
 

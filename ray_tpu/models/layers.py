@@ -4,7 +4,9 @@ that a sequence keeps in its slot of the state pool between steps.
 
 ``RMSNorm`` and ``_rope`` serve Llama, OLMoE, LFM2 and Kimi-K2 (Granite
 takes the norm; Kimi-K2 rotates a 64-wide PART of a head, with YaRN's
-frequencies: ``yarn_inv_freq``, ``yarn_mscale``); ``slot_conv`` is the conv of Granite's ``Mamba2Mixer`` (4 taps,
+frequencies: ``yarn_inv_freq``, ``yarn_mscale``; Command A+ turns
+adjacent pairs, ``interleaved``, and norms with ``LayerNorm``, which
+takes the mean out); ``slot_conv`` is the conv of Granite's ``Mamba2Mixer`` (4 taps,
 bias, silu) and of LFM2's ``ShortConvMixer`` (3 taps, neither), written
 once; ``init_by_leaf`` makes the seeded weights of all three.
 ``served_position`` is what GPT-2 and the decoder cut their hidden state
@@ -84,9 +86,12 @@ def _rope_tables(seq_len: int, head_dim: int, theta: float, yarn=None):
     return np.cos(angles), np.sin(angles)
 
 
-def _rope(x, theta: float, positions=None, yarn=None):
+def _rope(x, theta: float, positions=None, yarn=None,
+          interleaved: bool = False):
     """Rotary embedding over [B, T, H, D] (D even; rotate-half: dimension
-    i turns with dimension i + D/2).  ``positions`` ([B, T] absolute,
+    i turns with dimension i + D/2; ``interleaved``, the source's
+    ``rope_gptj``: dimension 2i with 2i + 1, at the same frequency
+    ``theta ** (-2i / D)``).  ``positions`` ([B, T] absolute,
     negative = padding) selects per-token angles for the decode path;
     None means contiguous 0..T-1 (training/prefill full forward) served
     from the cached tables.  ``yarn`` ((factor, original_max, beta_fast,
@@ -105,6 +110,11 @@ def _rope(x, theta: float, positions=None, yarn=None):
         angles = pos[..., None] * freqs            # [B, T, half]
         cos = jnp.cos(angles)[:, :, None, :]
         sin = jnp.sin(angles)[:, :, None, :]
+    if interleaved:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                        axis=-1).reshape(x.shape)
+        return out.astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                           axis=-1)
@@ -120,6 +130,23 @@ class RMSNorm(nn.Module):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
         x32 = x.astype(jnp.float32)
+        norm = x32 * jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        return (norm * scale).astype(self.dtype)
+
+
+class LayerNorm(nn.Module):
+    """``(x - mean) / sqrt(var + eps) * scale`` in float32: the mean taken
+    out, where ``RMSNorm`` leaves it in; no bias (Cohere's)."""
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
         norm = x32 * jax.lax.rsqrt(
             jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         return (norm * scale).astype(self.dtype)

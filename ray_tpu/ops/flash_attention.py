@@ -168,16 +168,97 @@ def _over_block(causal, sched, qi, ki, bq, bk, tile):
         pl.when(ki == qi)(walk)
 
 
-def _scores(q, k, scale, mask):
+def band_span(t: int, block: int, window: int) -> int:
+    """k blocks of ``block`` rows that can meet the band ``0 <= i - j <
+    window`` of one q block of as many rows: the diagonal one and those
+    before it that hold a key less than ``window`` behind the block's
+    first row; never more than the sequence has."""
+    return min((window + block - 2) // block + 1, t // block)
+
+
+def band_schedule(t: int, block: int, d: int, window: int
+                  ) -> CausalSchedule:
+    """The walk of a ``[t, t]`` score square under the band ``0 <= i - j <
+    window`` in square grid blocks (``_over_band``).  The two edge blocks
+    are walked in strips (``sub`` and ``pairs`` as the causal walk's, the
+    far edge mirrored) where the causal kernel walks its diagonal AND the
+    window is whole blocks; else ``sub`` is 0 and every block that meets
+    the band is computed whole.  ``visited`` / ``square`` count
+    sub-blocks (grid blocks without a walk)."""
+    sched = causal_schedule(t, block, block, d)
+    nq, span = t // block, band_span(t, block, window)
+    blocks = sum(min(qi + 1, span) for qi in range(nq))
+    if not sched.sub or window % block:
+        return CausalSchedule(0, (), blocks, nq * nq)
+    n = block // sched.sub
+    far = sum(1 for qi in range(nq) if window // block <= min(qi, span - 1))
+    edges = (nq + far) * len(sched.pairs)
+    return CausalSchedule(sched.sub, sched.pairs,
+                          (blocks - nq - far) * n * n + edges,
+                          nq * nq * n * n)
+
+
+def _over_band(call, sched, qi, back, tile):
+    """Run the forward's body ``tile(q rows, kv rows, mask, lead)`` over
+    what q block ``qi`` computes of the k block ``back`` blocks before
+    its diagonal one, under the band ``0 <= i - j < call.window`` (blocks
+    of ``bq`` = ``bk`` rows).  Blocks wholly inside the band are computed
+    whole and unmasked; the diagonal block and the block the window's far
+    edge crosses are the two EDGE blocks.  Where ``sched``
+    (``band_schedule``) walks them in strips: the diagonal one as the
+    causal kernel walks it, the far one mirrored (q sub-block ``i``
+    against kv sub-blocks ``i..``, the first masked to the columns
+    strictly above its diagonal).  Otherwise an edge block is computed
+    whole under the band's mask."""
+    blk, win = call.bq, call.window
+    live = back <= qi                       # the k block exists
+    far = back * blk + blk - 1 >= win       # holds a pair past the window
+
+    def whole(mask=None):
+        return lambda: tile(_ALL, _ALL, mask and mask(), False)
+
+    def band():
+        behind = back * blk \
+            + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0) \
+            - jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+        return (behind >= 0) & (behind < win)
+
+    pl.when(live & (back > 0) & jnp.logical_not(far))(whole())
+    if not sched.sub:           # (``band_schedule`` says when)
+        pl.when(live & ((back == 0) | far))(whole(band))
+        return
+    sub = sched.sub
+
+    def near_edge():
+        diag = _causal_mask(0, 0, sub, sub)
+        for r, c in sched.strips():
+            tile(r, c, diag, False)
+
+    def far_edge():
+        above = jnp.logical_not(_causal_mask(0, 0, sub, sub))
+        for i in range(blk // sub):
+            tile(slice(i * sub, (i + 1) * sub), slice(i * sub, blk),
+                 above, True)
+
+    pl.when(back == 0)(near_edge)
+    if win // blk < call.span:              # the far edge's block is walked
+        pl.when(live & (back == win // blk))(far_edge)
+
+
+def _scores(q, k, scale, mask, lead=False):
     """float32 scores of the rows ``q`` against the keys ``k``; ``mask``
     (None: none) covers the whole tile or, narrower than it, the trailing
-    columns of a strip: the square the diagonal crosses."""
+    columns of a strip: the square the diagonal crosses (``lead``: the
+    LEADING columns, the square a window's far edge crosses)."""
     s = _dot(q, k, _NT) * scale                             # (rows, keys)
     if mask is None:
         return s
     w = mask.shape[1]
     if w == s.shape[1]:
         return jnp.where(mask, s, _NEG_INF)
+    if lead:
+        return jnp.concatenate(
+            [jnp.where(mask, s[:, :w], _NEG_INF), s[:, w:]], axis=1)
     return jnp.concatenate(
         [s[:, :-w], jnp.where(mask, s[:, -w:], _NEG_INF)], axis=1)
 
@@ -208,6 +289,11 @@ class _Call(NamedTuple):
     bk: int
     causal: bool
     interpret: bool
+    # The forward alone (``flash_attention``'s ``window`` and grouped K/V):
+    window: int = 0     # > 0: key j meets query i iff 0 <= i - j < window
+    span: int = 0       # ... and the k blocks a q block then walks
+    group: int = 1      # query heads that read ONE K/V head where it lies
+    fold: bool = False  # K/V are [b * heads / group, t, d] (folded)
 
     @property
     def w(self):                     # lanes of a block
@@ -218,6 +304,8 @@ class _Call(NamedTuple):
         return self.heads // self.hpp
 
     def schedule(self, t):
+        if self.window:
+            return band_schedule(t, self.bq, self.d, self.window)
         if self.causal:
             return causal_schedule(t, self.bq, self.bk, self.d)
         blocks = (t // self.bq) * (t // self.bk)
@@ -261,13 +349,22 @@ def _column(ref, a, rows):
 def _specs(call, qi, ki):
     """BlockSpecs of a kernel over the grid ``(b, head block, x, y)``;
     ``qi`` / ``ki`` pick the q / kv block from ``(x, y)``."""
-    def rows(n, col, at):
-        return pl.BlockSpec((1, n, call.w),
-                            lambda b, h, x, y: (b, at(x, y), col + h))
+    def rows(n, col, at, group=1):
+        if group == 1:
+            def index(b, h, x, y):
+                return b, at(x, y), col + h
+        elif call.fold:         # a row of [b * h_kv, t, d] is a K/V head
+            def index(b, h, x, y):
+                return b // group, at(x, y), col + h
+        else:                   # the group's block of [b, t, h_kv * d]
+            def index(b, h, x, y):
+                return b, at(x, y), col + h // group
+        return pl.BlockSpec((1, n, call.w), index)
 
     cq, ck, cv = call.cols
-    return {"q": rows(call.bq, cq, qi), "k": rows(call.bk, ck, ki),
-            "v": rows(call.bk, cv, ki),
+    return {"q": rows(call.bq, cq, qi),
+            "k": rows(call.bk, ck, ki, call.group),
+            "v": rows(call.bk, cv, ki, call.group),
             "q_out": rows(call.bq, 0, qi), "kv_out": rows(call.bk, 0, ki),
             # per-row statistics: [b * heads, 8, t], see _fwd_kernel
             "stat": pl.BlockSpec(
@@ -282,14 +379,17 @@ _PARAMS = dict(compiler_params=pltpu.CompilerParams(
 
 
 # --------------------------------------------------------------- forward
-def _softmax_step(q, k, v, m, l, scale, mask):
+def _softmax_step(q, k, v, m, l, scale, mask, lead=False):
     """One online-softmax update of the rows ``q`` by the keys ``k``:
     running max ``m`` and denominator ``l``, each (rows, 128) with its
     value in every lane (measured on v5e, PR 34: as (rows, 1) columns the
     whole-block forward takes 18.9 ms where this takes 16.6, to the same
     bits), float32.  Returns them with ``alpha``, by which the rows'
     accumulator shrinks, and ``P V``, which it gains."""
-    s = _scores(q, k, scale, mask)
+    # (``lead`` is said only by a window's far edge: the trainers' call is
+    # the four arguments it was)
+    s = _scores(q, k, scale, mask, lead) if lead \
+        else _scores(q, k, scale, mask)
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - _lanes(m_new, s.shape[1]))
     alpha = jnp.exp(m - m_new)
@@ -309,20 +409,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def tile(r, c, mask):
+    def tile(r, c, mask, lead=False):
         """Rows ``r`` of the q block attend rows ``c`` of the kv block."""
         q, k, v = q_ref[0, r, :], k_ref[0, c, :], v_ref[0, c, :]
         alphas, pvs = [], []
         for a in heads:
             m_scr[a, r, :], l_scr[a, r, :], alpha, pv = _softmax_step(
                 _head(q, a, call), k, v, m_scr[a, r, :], l_scr[a, r, :],
-                call.scale, mask)
+                call.scale, mask, lead)
             alphas.append(_lanes(alpha, call.w))
             pvs.append(pv)
         acc_scr[r, :] = acc_scr[r, :] * _by_head(alphas, call) \
             + _by_head(pvs, call)
 
-    _over_block(call.causal, sched, qi, ki, call.bq, call.bk, tile)
+    if call.window:     # grid step ki is the k block span - 1 - ki back
+        _over_band(call, sched, qi, call.span - 1 - ki, tile)
+    else:
+        _over_block(call.causal, sched, qi, ki, call.bq, call.bk, tile)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -353,13 +456,20 @@ def _qkv(ops):
 def _flash_forward(ops, *, call):
     q, k, v = _qkv(ops)
     b, t, _ = q.shape
-    spec = _specs(call, *_Q_MAJOR)
+    spec, nk = _specs(call, *_Q_MAJOR), t // call.bk
+    if call.window:
+        # Only the k blocks that meet the band: step y of q block x reads
+        # the block span - 1 - y back (before the sequence's start: block
+        # 0 again, which costs no copy, and the kernel leaves it out).
+        nk = call.span
+        spec = _specs(call, _Q_MAJOR[0], lambda x, y: jnp.maximum(
+            x - (call.span - 1) + y, 0))
     with jax.named_scope("flash_fwd"):
         out, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, call=call,
                               sched=call.schedule(t)),
             name="flash_fwd",
-            grid=(b, call.n, t // call.bq, t // call.bk),
+            grid=(b, call.n, t // call.bq, nk),
             in_specs=[spec["q"], spec["k"], spec["v"]],
             out_specs=[spec["q_out"], spec["stat"]],
             out_shape=[
@@ -533,6 +643,27 @@ def _flash_bwd(call, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+# ------------------------------------------------------ forward-only op
+# A window, or K/V read by group where they lie, is the serving prefill's:
+# the backward kernels know neither, and say so.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _flash_served(ops, call):
+    return _flash_forward(ops, call=call)[0]
+
+
+def _flash_served_fwd(ops, call):
+    return _flash_served(ops, call), None
+
+
+def _flash_served_bwd(call, res, g):
+    raise NotImplementedError(
+        "flash_attention with a window or grouped K/V has no backward "
+        "kernels: train through attn_impl='dense'")
+
+
+_flash_served.defvjp(_flash_served_fwd, _flash_served_bwd)
+
+
 # ------------------------------------------- partial (lse-exposing) op
 # Same kernels, but the row-wise log-sum-exp is a real (differentiable)
 # output: ring attention merges per-ring-step partial outputs with
@@ -556,7 +687,8 @@ def _flash_lse_bwd(call, res, g):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def _plan(t, h, d, causal, block_q, block_k, interpret, scale, fused=False):
+def _plan(t, h, d, causal, block_q, block_k, interpret, scale, fused=False,
+          window=None, group=1):
     """What the public ops hand the kernels for ``h`` heads of ``d`` over
     ``t`` positions: the call (layout by ``_packs``, clamped blocks,
     scale) and the annotation that says, when the call is traced, which
@@ -576,12 +708,25 @@ def _plan(t, h, d, causal, block_q, block_k, interpret, scale, fused=False):
     call = _Call(heads, d, per, (0, n, 2 * n) if fused else (0, 0, 0),
                  d ** -0.5 if scale is None else float(scale),
                  block_q, block_k, bool(causal), bool(interpret))
+    if window is not None or group != 1:
+        if window is not None and (window < 1 or not causal
+                                   or block_q != block_k):
+            raise ValueError(
+                f"a window ({window}) needs causal attention in square "
+                f"blocks, got causal={causal}, blocks ({block_q}, "
+                f"{block_k})")
+        call = call._replace(
+            window=window or 0, group=group, fold=not hpp,
+            span=band_span(t, block_k, window) if window else 0)
     sched = call.schedule(t)
     note = spans.annotate("flash.schedule", t=t, block=block_q,
                           sub=sched.sub, visited=sched.visited,
                           square=sched.square,
                           layout="packed" if hpp else "folded",
-                          heads_per_program=call.hpp)
+                          heads_per_program=call.hpp,
+                          **({"window": call.window, "span": call.span,
+                              "group": call.group}
+                             if call.window or call.group != 1 else {}))
     return call, note
 
 
@@ -590,11 +735,19 @@ def _run(op, q, k, v, **kw):
     in the layout their shape allows; returns what ``op`` returns with
     ``out`` as ``[B, T, H, D]`` again."""
     b, t, h, d = q.shape
+    group = h // k.shape[2]
+    if group != 1:
+        if _packs(h, d) == 2:
+            # Two heads of 64 share a block's lanes: each needs its K/V
+            # head in its own half, which only a copy gives.
+            k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+        else:
+            kw["group"] = group
     call, note = _plan(t, h, d, **kw)
     if call.heads == h:             # packed: merging H and D is a bitcast
-        ops = tuple(x.reshape(b, t, h * d) for x in (q, k, v))
+        ops = tuple(x.reshape(b, t, -1) for x in (q, k, v))
     else:
-        ops = tuple(x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+        ops = tuple(x.transpose(0, 2, 1, 3).reshape(-1, t, d)
                     for x in (q, k, v))
     with note:
         res = op(ops, call)
@@ -625,9 +778,20 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = True,
 def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: int = 256, block_k: int = 256,
                     interpret: bool | None = None,
-                    scale: float | None = None):
+                    scale: float | None = None,
+                    window: int | None = None):
     """Causal flash attention.  q, k, v: [B, T, H, D] -> [B, T, H, D];
     ``scale`` multiplies q k^T (None: ``D ** -0.5``).
+
+    Forward only (differentiating it raises), for a serving prefill: k
+    and v may hold FEWER heads than q, ``[B, T, H / group, D]``, and are
+    then read where they lie, a query head's programs mapped to its
+    group's block, never repeated to H heads (heads of 64, two to a
+    block, are repeated here: see ``_run``); and with ``window`` key ``j``
+    meets query ``i`` iff ``0 <= i - j < window``: the kernel's grid
+    holds only the k blocks that meet that band (``band_span``) and masks
+    the two edge blocks (``_over_band``).  ``window=None`` with as many
+    K/V heads as query heads is the kernel every trainer runs.
 
     ``interpret=None`` auto-selects: the compiled kernel when the
     backend is ``tpu`` (never interpreted there unless a caller asks by
@@ -637,8 +801,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     Block sizes must keep T % block == 0 (pretraining shapes are
     128-multiples; assert early rather than mask the tail).
     """
-    return _run(_flash, q, k, v, causal=causal, block_q=block_q,
-                block_k=block_k, interpret=interpret, scale=scale)[0]
+    kw = dict(causal=causal, block_q=block_q, block_k=block_k,
+              interpret=interpret, scale=scale)
+    if window is None and k.shape[2] == q.shape[2]:
+        return _run(_flash, q, k, v, **kw)[0]
+    return _run(_flash_served, q, k, v, window=window, **kw)[0]
 
 
 def flash_attention_qkv(qkv, heads: int, *, causal: bool = True,

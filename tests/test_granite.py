@@ -340,7 +340,7 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(params, tokens):
 
 def test_the_registry_builds_the_fourth_family():
     row = MODEL_FAMILIES["granitemoehybrid"]
-    assert len(MODEL_FAMILIES) == 9 and row.config is GraniteConfig
+    assert len(MODEL_FAMILIES) == 10 and row.config is GraniteConfig
     assert family_of(row.tiny()).module is Granite
     spec = row.cache(GraniteConfig())       # as published: 36 + 4 layers
     assert (spec.kv_layers, spec.kv_heads, spec.head_dim) == (4, 8, 128)

@@ -325,7 +325,7 @@ def test_flash_training_forward_pads_v_to_the_keys_width(params, tokens):
 
 def test_the_registry_builds_the_sixth_family():
     row = MODEL_FAMILIES["kimik2"]
-    assert len(MODEL_FAMILIES) == 9 and row.config is KimiK2Config
+    assert len(MODEL_FAMILIES) == 10 and row.config is KimiK2Config
     assert family_of(row.tiny()).module is KimiK2
     spec = row.cache(KimiK2Config())        # as published
     assert spec == CacheSpec(61, 0, 0, latent_dim=512, rope_dim=64)

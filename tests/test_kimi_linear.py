@@ -616,7 +616,7 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(params, tokens):
 
 def test_the_registry_builds_the_seventh_family():
     row = MODEL_FAMILIES["kimilinear"]
-    assert len(MODEL_FAMILIES) == 9 and row.config is KimiLinearConfig
+    assert len(MODEL_FAMILIES) == 10 and row.config is KimiLinearConfig
     assert family_of(row.tiny()).module is KimiLinear
     full = KimiLinearConfig()               # as published
     assert [i + 1 for i, kind in enumerate(full.layer_types)

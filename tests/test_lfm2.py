@@ -288,7 +288,7 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(params, tokens):
 
 def test_the_registry_builds_the_fifth_family():
     row = MODEL_FAMILIES["lfm2moe"]
-    assert len(MODEL_FAMILIES) == 9 and row.config is Lfm2Config
+    assert len(MODEL_FAMILIES) == 10 and row.config is Lfm2Config
     assert family_of(row.tiny()).module is Lfm2
     spec = row.cache(Lfm2Config())      # as published: 30 + 10 layers
     assert spec == CacheSpec(10, 8, 64, 30, (2, 2048), ())

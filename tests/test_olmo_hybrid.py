@@ -489,7 +489,7 @@ def test_the_lowered_decode_step_holds_the_kernel_the_reader_files_by_name():
 
 def test_the_registry_builds_the_ninth_family():
     row = MODEL_FAMILIES["olmohybrid"]
-    assert len(MODEL_FAMILIES) == 9 and row.config is OlmoHybridConfig
+    assert len(MODEL_FAMILIES) == 10 and row.config is OlmoHybridConfig
     assert family_of(CFG) is row and row.module is OlmoHybrid
     assert row.cache(CFG) == CacheSpec(
         kv_layers=2, kv_heads=6, head_dim=10, state_layers=6,

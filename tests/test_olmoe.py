@@ -216,7 +216,7 @@ def test_engine_builds_every_family_from_its_registry_row(family):
     assert seq.generated == 3 and engine.stats()["step_errors"] == 0
     assert ("moe" in engine.stats()) == (
         family in ("olmoe", "granitemoehybrid", "lfm2moe", "kimik2",
-                   "kimilinear", "xing40"))
+                   "kimilinear", "xing40", "cohere2moe"))
     assert ("residual" in engine.stats()) == (family == "xing40")
     assert ("state" in engine.stats()) == (
         family in ("granitemoehybrid", "lfm2moe", "kimilinear",

@@ -1033,6 +1033,103 @@ def test_olmo_hybrid_cell_compiles_at_the_benchmarks_sizes(one_chip,
         assert "gdn.scan" in text and "gdn.step" not in text
 
 
+def _command_a_cell():
+    """serve-command-a-plus-16k's configuration, cache spec and the shapes
+    of its weights and both groups of its K/V pool (max_batch 16, 16,384
+    pages of 16 in the full group, 16 rings of 256 pages in the window
+    group, max_context 16,384)."""
+    from ray_tpu.llm.kv_cache import init_pool, ring_pages
+    from ray_tpu.models import MODEL_FAMILIES
+    from ray_tpu.models.cohere import FULL, SLIDING, Cohere2MoeConfig
+
+    row = MODEL_FAMILIES["cohere2moe"]
+    cfg = Cohere2MoeConfig(layer_types=(SLIDING, SLIDING, SLIDING, FULL),
+                           held_experts=16, vocab_size=32768,
+                           attn_impl="dense", remat=False)
+    spec = row.cache(cfg)
+    params = jax.eval_shape(lambda: row.init(cfg, jax.random.PRNGKey(0)))
+    rings = 16 * ring_pages(spec, 16)
+    kv = jax.eval_shape(lambda: init_pool(spec, 16384, 16, cfg.dtype, rings))
+    return row, cfg, spec, params, kv
+
+
+def _command_a_program(one_chip, tokens_shape):
+    """One program of the cell as the backend ``tpu`` builds it, compiled
+    for the described chip: (compiled, cfg, params, kv)."""
+    from ray_tpu.llm.engine import jit_forward
+    from ray_tpu.llm.kv_cache import pages_for, pool_arrays
+
+    row, cfg, spec, params, kv = _command_a_cell()
+    b = tokens_shape[0]
+    ints = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                             sharding=one_chip)
+    with _as_on_tpu():
+        compiled = jit_forward(row.module(cfg)).lower(
+            _on(params, one_chip), ints(tokens_shape),
+            *(_on(kv[name], one_chip) for name in pool_arrays(spec)),
+            ints((b, pages_for(16384, 16))),
+            ints((b, pages_for(spec.window, 16))), ints(tokens_shape),
+            **_served(tokens_shape, one_chip)).compile()
+    return compiled, cfg, params, kv
+
+
+@pytest.mark.parametrize("tokens_shape", [(16, 1), (1, 16384)],
+                         ids=["decode", "prefill16384"])
+def test_command_a_cell_compiles_at_the_benchmarks_sizes(one_chip,
+                                                         tokens_shape):
+    """The decode and ``prefill[16384]`` programs of
+    serve-command-a-plus-16k at the benchmark's sizes (one period of 4
+    layers at the published widths: 128 query heads of 128 on 8 K/V heads,
+    a window of 4,096, 16 of 128 experts of 4,096 held beside the four
+    shared ones, an eighth of the vocabulary; bf16), as the backend ``tpu``
+    builds them: 9.47 GB of weights; the K/V pool in TWO groups, the full
+    layer's ``k_pages`` / ``v_pages`` [1, 16384, 16, 1024] (16 x 16,384
+    positions) and the three window layers' ``window_k_pages`` /
+    ``window_v_pages`` [3, 4096, 16, 1024] (16 rings of 4,096 positions:
+    what a window layer holds stops at the window), all four aliased to
+    the outputs.  The decode step attends through the paged kernel once a
+    layer, the rings through the same kernel as the full layer's pages;
+    the prefill through the flash kernel once a layer, under the band in
+    three of them, K and V read by group where they lie: no ``[.., T, T]``
+    score array and no K or V repeated to 128 heads; the logits are the one
+    served position's."""
+    compiled, cfg, params, kv = _command_a_program(one_chip, tokens_shape)
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert abs(weights - 9.47e9) < 0.002 * 9.47e9
+    assert kv["k_pages"].shape == (1, 16384, 16, 1024)
+    assert kv["window_k_pages"].shape == (3, 4096, 16, 1024)
+    b, t = tokens_shape
+    decode = t == 1
+    assert 0.25 * 16.9e9 < _device_bytes(compiled) < 0.95 * 16.9e9
+    assert _logits_shape(compiled) == (b, 1, cfg.vocab_size)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == sum(a.size * a.dtype.itemsize
+                                        for a in kv.values())
+    assert m.temp_size_in_bytes < (0.2e9 if decode else 4.0e9)
+    text = compiled.as_text()
+    # (q and the output are [1, T, 128 x 128] with T = 16,384 too: a score
+    # array has a heads dimension before its two T's)
+    assert decode or not re.search(
+        rf"(f32|bf16)\[(\d+,)*([2-9]|\d\d+),{t},{t}\]", text)
+    # K or V at the query heads' width: a group's head broadcast 16 times,
+    # or made [1, T, 128, 128] by anything but q's own projection and RoPE
+    repeated = re.findall(rf"bf16\[1,{t},(?:8,16|128),128\]\S* "
+                          r"(?:broadcast|concatenate|gather)\(", text)
+    assert not repeated, repeated[:3]
+    assert len(re.findall(rf"= bf16\[1,{t},128,128\]\S* fusion\(",
+                          text)) <= 3         # q after RoPE, three layers
+
+    def calls(kernel):
+        return len(re.findall(
+            rf"^\s*(?:ROOT )?%{kernel}[\w.]* = .*custom-call\(", text,
+            re.M))
+
+    assert calls("paged_decode") == (4 if decode else 0)
+    assert calls("flash_fwd") == (0 if decode else 4)
+    assert ("attn.window" in text) and ("attn.full" in text)
+
+
 def _train_step_and_shapes(cfg, loss_chunk):
     from ray_tpu.models.gpt2 import gpt2_init, gpt2_loss_fn
     from ray_tpu.train.train_step import TrainState, make_optimizer

@@ -296,7 +296,7 @@ def test_adamw_decays_the_maps_matrices_and_not_their_biases_and_gates():
 
 def test_the_registry_builds_the_eighth_family():
     row = MODEL_FAMILIES["xing40"]
-    assert len(MODEL_FAMILIES) == 9 and row.config is XingConfig
+    assert len(MODEL_FAMILIES) == 10 and row.config is XingConfig
     assert family_of(row.tiny()).module is Xing
     # its config extends Kimi-K2's, whose own row still finds Kimi-K2's
     assert family_of(KimiK2Config.tiny()).module is KimiK2
