@@ -48,17 +48,23 @@ dispatch, experts and combine run over a static CAPACITY of rows and not
 over ``S x k``: the sort is stable and a pair without an expert here
 carries the largest key, so the held pairs are exactly the first
 ``sum(load)`` entries of the sorted order, and the head of it is all
-there is to gather, multiply and add back (by token, in float32).  The
-capacity is twice the balanced share, ``2 x S x k x held / num_experts``,
-rounded up to whole tiles of 128 rows (``compact_capacity``: one tile
-more where the grouped matmul would take 512-row tiles), and the
-branch exists only where that is at most half of ``S x k``: never in a
-layer that holds all its experts, never in a decode step.  It is taken
-under ``jax.lax.cond(sum(load) <= capacity, ...)``: a router that crowds
-onto the held experts falls back to the path over all ``S x k`` rows for
-that layer and that call, so every pair is still computed, and the op
-stays differentiable.  Each call sows ``compact`` (bool: it took the
-compact branch) beside ``load``.
+there is to gather, multiply and add back: by token, as a matmul with
+each row's weight at its token, summed in float32.  In one block that
+matmul is ``[S, capacity] x [capacity, d]`` and grows with the square of
+the rows; where that costs (``combine_blocks``: from ``S``, ``k`` and the
+capacity alone) the head is sorted once more, by token, and each block of
+128 tokens multiplies the window of ``128 x k`` rows that holds its own,
+so the combine grows with ``S``.  The capacity is twice the balanced
+share, ``2 x S x k x held / num_experts``, rounded up to whole tiles of
+128 rows (``compact_capacity``: one tile more where the grouped matmul
+would take 512-row tiles), and the branch exists only where that is at
+most half of ``S x k``: never in a layer that holds all its experts,
+never in a decode step.  It is taken under
+``jax.lax.cond(sum(load) <= capacity, ...)``: a router that crowds onto
+the held experts falls back to the path over all ``S x k`` rows for that
+layer and that call, so every pair is still computed, and the op stays
+differentiable.  Each call sows ``compact`` (bool: it took the compact
+branch) beside ``load``.
 
 Each layer sows ``moe`` into flax's ``intermediates``: ``load`` [E] (pairs
 per expert), ``prob_mean`` [E] (the mean score; under ``sigmoid`` scoring
@@ -142,6 +148,32 @@ def compact_capacity(pairs: int, held: int, num_experts: int
     if held == num_experts or 2 * capacity > pairs:
         return None
     return capacity + ROW_TILE if capacity % 512 == 0 else capacity
+
+
+def combine_blocks(tokens: int, k: int, capacity: int) -> int:
+    """Blocks of tokens the compact path's combine runs in; 1: ONE
+    placement matmul ``[tokens, capacity] x [capacity, d]``.  From the
+    shapes alone, as ``compact_capacity``.
+
+    A block is one tile of 128 tokens, whose rows lie in a window of at
+    most ``128 x k`` rows once the head is sorted by token: blocks
+    multiply ``tokens x 128 x k x d`` where one block multiplies ``tokens
+    x capacity x d``, and pay a second sort, a gather of ``capacity``
+    rows and a loop turn a block for it.  They are taken where one block
+    would multiply at least four times as much AND the square ``tokens x
+    capacity`` has 2^23 entries or more.  On a v5e, the combine alone, one
+    block | blocks, ms (PERF.md PR 54): 16 of 128 experts, top-8, d 4,096
+    (``capacity`` = 2 x tokens + 128) at 16,384 tokens 24.6 | 4.6, 8,192
+    6.2 | 2.3, 4,096 1.60 | 0.91, 2,048 0.46 | 0.34 (the edge: 2^23.04
+    entries), 1,024 0.23 | 0.23; where the capacity is only twice the
+    window (Kimi's 2,176 rows for 4,096 tokens of 7,168: 0.75 | 0.62) or
+    the square small (Granite's 1,024 x 5,248: 0.31 | 0.33) nothing is
+    won, and the one matmul stays: those programs are what they were."""
+    window = ROW_TILE * k
+    if tokens % ROW_TILE or capacity < 4 * window \
+            or tokens * capacity < 1 << 23:
+        return 1
+    return tokens // ROW_TILE
 
 
 class MoEMLP(nn.Module):
@@ -258,17 +290,58 @@ class MoEMLP(nn.Module):
             out = experts_of(rows)
             with jax.named_scope("moe.combine"):
                 in_group = jnp.arange(capacity) < jnp.sum(load)
-                out = jnp.where(in_group[:, None], out, 0)
-                # By token, as a matmul with [S, capacity], a row's
-                # weight at its token: S x capacity x d multiplications,
-                # 0.13 TFLOP at Kimi's 4,096 bucket.  (A scatter-add of
-                # the rows runs at 1.5 us a row on a v5e: the layer takes
-                # 8.26 ms with it and 5.23 with this; PERF.md, PR 43.)
-                weight = weights.astype(dtype).reshape(-1)[head]
-                place = jnp.where(jnp.arange(s)[:, None] == token[None, :],
-                                  weight[None, :], 0)
-                y = jnp.dot(place, out, preferred_element_type=jnp.float32)
-            return y.astype(dtype)
+                # By token, as a matmul with a row's weight at its token
+                # (a scatter-add of the rows runs at 1.5 us a row on a
+                # v5e: PERF.md, PR 43).
+                blocks = combine_blocks(s, k, capacity)
+                if blocks == 1:
+                    # [S, capacity] x [capacity, d]: 0.13 TFLOP at Kimi's
+                    # 4,096 bucket (4.4 at Command A+'s 16,384: blocks).
+                    out = jnp.where(in_group[:, None], out, 0)
+                    weight = weights.astype(dtype).reshape(-1)[head]
+                    place = jnp.where(
+                        jnp.arange(s)[:, None] == token[None, :],
+                        weight[None, :], 0)
+                    y = jnp.dot(place, out,
+                                preferred_element_type=jnp.float32)
+                    return y.astype(dtype)
+                # By blocks of ``bt`` tokens.  Sorted by pair (token x k
+                # + slot; a row behind the last group keeps the largest
+                # key and token ``S``), a block's rows lie in one run of
+                # at most ``bt x k`` rows that starts at the count of the
+                # rows before it, and the block's matmul is over a window
+                # of that many rows which covers the run.  Which rows of
+                # a window count is still decided by comparing tokens, so
+                # a window that ``dynamic_slice`` moves back from the end
+                # of the rows gives the same sum.  What lies behind the
+                # last group is zeroed in the window (zeroed before the
+                # sort it is one more [capacity, d] array: 155 MB more of
+                # ``prefill[16384]`` by the compiler's count).
+                bt = s // blocks
+                pair = jnp.where(in_group, head, s * k)
+                by_token = jnp.argsort(pair, stable=True)
+                token = (pair // k)[by_token]
+                out = out[by_token]
+                weight = weights.astype(dtype).reshape(-1)[head[by_token]]
+                edges = jnp.arange(blocks, dtype=jnp.int32) * bt
+                starts = jnp.sum(token[None, :] < edges[:, None], axis=1,
+                                 dtype=jnp.int32)
+
+                def block(_, at):
+                    low, start = at
+                    window, tok, w = (
+                        jax.lax.dynamic_slice_in_dim(a, start, bt * k)
+                        for a in (out, token, weight))
+                    window = jnp.where((tok < s)[:, None], window, 0)
+                    place = jnp.where(
+                        (low + jnp.arange(bt))[:, None] == tok[None, :],
+                        w[None, :], 0)
+                    return None, jnp.dot(
+                        place, window, preferred_element_type=jnp.float32
+                    ).astype(dtype)
+
+                _, y = jax.lax.scan(block, None, (edges, starts))
+            return y.reshape(s, d)
 
         with jax.named_scope("moe.dispatch"):
             order = jnp.argsort(experts.reshape(-1), stable=True)

@@ -380,6 +380,45 @@ def test_the_eight_expert_shares_and_the_shared_experts_add_up(params):
     np.testing.assert_allclose(routed + shared, whole, atol=3e-5)
 
 
+# --------------------------------- the cell's share, combined by blocks
+
+def test_the_cells_share_combines_by_blocks_and_equals_the_reference():
+    """The cell's experts at a reduced depth and width: 16 of 128 held,
+    top-8, one window layer and the full one.  A prompt of 1,100 tokens
+    prefills in the 2,048 bucket: 16,384 pairs in a capacity of 4,224,
+    which ``ops/moe.py combine_blocks`` sends through 16 blocks of 128
+    tokens.  Both layers take the compact branch, and the prefill and 6
+    decode steps equal the reference's full forward as the tiny model's
+    do."""
+    from ray_tpu.ops.moe import (combine_blocks, compact_capacity,
+                                 moe_layers)
+
+    cfg = Cohere2MoeConfig.tiny(
+        remat=False, layer_types=(SLIDING, FULL), n_experts=128,
+        experts_per_token=8, first_expert=32, held_experts=16,
+        max_seq=2048)
+    config = dict(CONFIG, num_hidden_layers=2, num_experts=16,
+                  first_expert=32, num_experts_per_tok=8,
+                  layer_types=list(cfg.layer_types),
+                  published={"num_experts": 128})
+    capacity = compact_capacity(2048 * 8, 16, 128)
+    assert (capacity, combine_blocks(2048, 8, capacity)) == (4224, 16)
+    params = _scaled(cohere2_moe_init(cfg, jax.random.PRNGKey(11)))
+    prompt = np.random.default_rng(5).integers(0, 256, 1100).tolist()
+    padded = jnp.asarray([prompt + [0] * (2048 - 1100)], jnp.int32)
+    _, state = Cohere2Moe(cfg).apply(params, padded,
+                                     mutable=["intermediates"])
+    layers = moe_layers(state["intermediates"])
+    assert [bool(m["compact"]) for m in layers] == [True, True]
+    assert all(1100 < int(m["load"].sum()) <= capacity for m in layers)
+    served, logits = faults.serve(cfg, params, [prompt], 7, max_batch=2)
+    want = np.asarray(ref.forward(
+        config, params, jnp.asarray([prompt + served[0][:-1]], jnp.int32))
+    )[0][len(prompt) - 1:]
+    assert float(np.std(want)) > 0.3
+    np.testing.assert_allclose(np.stack(logits[0]), want, atol=1e-4)
+
+
 # ---------------------------------------------------- names and the registry
 
 def _lowered(cfg, shape):

@@ -10,8 +10,8 @@ import pytest
 
 from ray_tpu.models.gpt2 import GPT2Config, gpt2_init, gpt2_loss_fn
 import ray_tpu.ops.moe as moe_ops
-from ray_tpu.ops.moe import (MoEMLP, compact_capacity, moe_counters,
-                             moe_layers, moe_losses)
+from ray_tpu.ops.moe import (MoEMLP, combine_blocks, compact_capacity,
+                             moe_counters, moe_layers, moe_losses)
 
 
 def _layer(e=4, k=2, d=16, ff=32):
@@ -327,43 +327,42 @@ def test_router_crowded_onto_the_share_takes_the_fallback(first):
     assert (np.abs(np.asarray(y[0])).sum(-1) > 0).all()
 
 
-def test_the_shares_add_up_to_the_whole_layer_with_compaction_on():
-    """tests/test_granite.py's property at a prefill shape: four shares
-    of 2 of 8 experts over 128 rows x top-2 (256 pairs, capacity 128),
-    each through the compact branch, add up to the layer that holds all
-    eight, and every pair is computed once."""
-    whole = _share(n=8, held=None, k=2)
-    x = jax.random.normal(jax.random.PRNGKey(3), (1, 128, 16))
+@pytest.mark.parametrize("n,rows,blocks", [(8, 128, 1), (16, 4096, 32)],
+                         ids=["one_block", "blocks"])
+def test_the_shares_add_up_to_the_whole_layer_with_compaction_on(
+        n, rows, blocks):
+    """tests/test_granite.py's property at a prefill shape: the shares
+    of 2 of ``n`` experts each, every one through the compact branch,
+    add up to the layer that holds them all, and every pair is computed
+    once.  Four shares over 128 rows x top-2 (256 pairs, capacity 128:
+    one placement matmul), and eight over 4,096 rows (capacity 2,176:
+    combined by 32 blocks of tokens)."""
+    assert combine_blocks(rows, 2, compact_capacity(rows * 2, 2, n)) \
+        == blocks
+    whole = _share(n=n, held=None, k=2)
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, rows, 16))
     params = jax.tree_util.tree_map(
         lambda w: 10.0 * w, whole.init(jax.random.PRNGKey(1), x))
     want, stats = _apply(whole, params, x)
     assert not bool(stats["compact"])
     total, pairs = 0.0, 0
-    for rank in range(4):
+    for first in range(0, n, 2):
         part = dict(params["params"])
         for name in ("w_gate", "w_up", "w_down"):
-            part[name] = part[name][2 * rank:2 * rank + 2]
-        y, stats = _apply(_share(2 * rank, n=8, held=2, k=2),
+            part[name] = part[name][first:first + 2]
+        y, stats = _apply(_share(first, n=n, held=2, k=2),
                           {"params": part}, x)
         assert bool(stats["compact"])
         total, pairs = total + y, pairs + int(stats["load"].sum())
-    assert pairs == 128 * 2
+    assert pairs == rows * 2
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
-def test_gradients_through_the_compact_branch_equal_the_plain_paths(
-        monkeypatch, gated):
-    """``lax.cond`` keeps the op differentiable: the gradient of every
-    leaf and of the input through the compact branch is the plain
-    path's."""
-    layer = _share(6, gated)
-    x = jax.random.normal(jax.random.PRNGKey(2), (1, 512, 16))
-    params = jax.tree_util.tree_map(
-        lambda w: 10.0 * w, layer.init(jax.random.PRNGKey(1), x))
-    valid = jnp.asarray(np.random.default_rng(2).random((1, 512)) < 0.8)
-
+def _assert_compact_grads_equal_plain(monkeypatch, layer, params, x,
+                                      valid=None):
+    """Every leaf's gradient and the input's through the compact branch
+    equal the plain path's; returns the leaves."""
     def grads():
         def loss(p, x):
             y, state = layer.apply(p, x, valid, mutable=["intermediates"])
@@ -377,13 +376,29 @@ def test_gradients_through_the_compact_branch_equal_the_plain_paths(
     want, took_plain = grads()
     assert bool(took) and not bool(took_plain)
     flat = jax.tree_util.tree_leaves_with_path(got)
-    assert len(flat) == (5 if gated else 4)
     for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want)):
         scale = float(jnp.max(jnp.abs(w)))
         assert scale > 0, jax.tree_util.keystr(path)
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    atol=1e-5 * scale,
                                    err_msg=jax.tree_util.keystr(path))
+    return flat
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_gradients_through_the_compact_branch_equal_the_plain_paths(
+        monkeypatch, gated):
+    """``lax.cond`` keeps the op differentiable: the gradient of every
+    leaf and of the input through the compact branch is the plain
+    path's."""
+    layer = _share(6, gated)
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 512, 16))
+    params = jax.tree_util.tree_map(
+        lambda w: 10.0 * w, layer.init(jax.random.PRNGKey(1), x))
+    valid = jnp.asarray(np.random.default_rng(2).random((1, 512)) < 0.8)
+    flat = _assert_compact_grads_equal_plain(monkeypatch, layer, params, x,
+                                             valid)
+    assert len(flat) == (5 if gated else 4)
 
 
 # ----------------------------------------- which programs hold the branch
@@ -456,6 +471,213 @@ def test_capacity_is_twice_the_balanced_share_in_tiles_the_kernel_likes(
     compiler's grouped matmul would take 512-row tiles and multiply one
     for every small group."""
     assert compact_capacity(pairs, held, n) == want
+
+
+# ------------------------------------------- the combine by blocks of tokens
+
+# 4,096 rows x top-2 on 2 of 16 experts: 8,192 pairs in a capacity of
+# 2,176, which ``combine_blocks`` sends through 32 blocks of 128 tokens,
+# each over a window of 256 rows.
+BLOCKED = dict(n=16, held=2, k=2)
+BLOCKED_ROWS, BLOCKED_CAPACITY, BLOCKED_BLOCKS = 4096, 2176, 32
+
+
+def _blocked_case(routing, first, seed=0):
+    """(layer, params, x) whose router sends the rows as ``routing``
+    says: ``random``; ``one_token`` (row 1,005 holds both its pairs here,
+    its ten neighbours none); ``full_tail`` (the last 1,000 rows hold
+    both their pairs here and no other row any: 2,000 rows of the 2,176,
+    every window of the tail full to its bound of 128 x k rows, and the
+    last ones moved back from the end)."""
+    layer = _share(first, **BLOCKED)
+    x = np.array(jax.random.normal(jax.random.PRNGKey(seed),
+                                   (1, BLOCKED_ROWS, 16)))
+    params = jax.tree_util.tree_map(
+        lambda w: 10.0 * w, layer.init(jax.random.PRNGKey(1),
+                                       jnp.asarray(x)))
+    x[0, :, 0] = 0.0
+    if routing == "one_token":
+        x[0, 1000:1011, 0] = -5.0
+        x[0, 1005, 0] = 5.0
+    elif routing == "full_tail":
+        x[0, :, 0] = -5.0
+        x[0, -1000:, 0] = 5.0
+    router = np.asarray(params["params"]["router"]).copy()
+    router[0] = 0.0
+    router[0, first:first + 2] = 3.0    # feature 0 decides for the share
+    params["params"]["router"] = jnp.asarray(router)
+    return layer, params, jnp.asarray(x)
+
+
+@pytest.mark.parametrize("mixed", [False, True],
+                         ids=["all_valid", "valid_mixed"])
+@pytest.mark.parametrize("routing,first", [
+    ("random", 0), ("random", 14), ("one_token", 6), ("full_tail", 6)])
+def test_blocked_combine_equals_plain_path_and_loop(monkeypatch, routing,
+                                                    first, mixed):
+    """The compact branch at a shape the rule sends through blocks gives
+    the per-token loop's result and the plain path's, whatever the
+    routing: a token with all its k pairs here between neighbours with
+    none, and windows full to the bound at the end of the rows."""
+    assert compact_capacity(BLOCKED_ROWS * 2, 2, 16) == BLOCKED_CAPACITY
+    assert combine_blocks(BLOCKED_ROWS, 2, BLOCKED_CAPACITY) \
+        == BLOCKED_BLOCKS
+    layer, params, x = _blocked_case(routing, first)
+    valid = None
+    if mixed:
+        valid = np.random.default_rng(first).random((1, BLOCKED_ROWS)) < 0.9
+        valid[0, 1005] = True
+        valid = jnp.asarray(valid)
+    y, stats = _apply(layer, params, x, valid)
+    pairs = int(stats["load"].sum())
+    assert bool(stats["compact"]) and 0 < pairs <= BLOCKED_CAPACITY
+    if routing == "full_tail" and not mixed:
+        assert pairs == 2000 > BLOCKED_CAPACITY - 128 * 2
+    want = _per_token_loop(
+        params["params"], x[0], 2, True, True, _silu, first,
+        None if valid is None else np.asarray(valid[0]))
+    if routing == "one_token":
+        held = np.abs(want[1000:1011]).sum(-1) > 0
+        assert held.tolist() == [False] * 5 + [True] + [False] * 5
+    np.testing.assert_allclose(np.asarray(y[0]), want, atol=1e-5)
+    assert float(np.abs(want).max()) > 1e-2
+    _plain(monkeypatch)
+    y_plain, stats_plain = _apply(layer, params, x, valid)
+    assert not bool(stats_plain["compact"])
+    np.testing.assert_array_equal(stats["load"], stats_plain["load"])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_plain),
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("routing", ["random", "full_tail"])
+def test_gradients_through_the_blocked_combine_equal_the_plain_paths(
+        monkeypatch, routing):
+    """The scan over blocks is differentiable: every leaf's gradient and
+    the input's are the plain path's."""
+    layer, params, x = _blocked_case(routing, 6, seed=2)
+    _assert_compact_grads_equal_plain(monkeypatch, layer, params, x)
+
+
+def _walk(jaxpr, turns=1):
+    """(equation, how often it runs) of a jaxpr and of every jaxpr under
+    it; a ``scan``'s body runs ``length`` times."""
+    for eqn in jaxpr.eqns:
+        yield eqn, turns
+        inner = turns * eqn.params.get("length", 1) \
+            if eqn.primitive.name == "scan" else turns
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub, inner)
+
+
+def _compact_branch(layer, rows, d):
+    (eqn,) = _conds(layer, rows, d)
+    return eqn.params["branches"][1].jaxpr
+
+
+def _dot_flops(jaxpr):
+    total = 0
+    for eqn, turns in _walk(jaxpr):
+        if eqn.primitive.name == "dot_general":
+            (contract, _), _ = eqn.params["dimension_numbers"]
+            inner = np.prod([eqn.invars[0].aval.shape[i] for i in contract])
+            total += 2 * turns * int(inner) * int(
+                np.prod(eqn.outvars[0].aval.shape))
+    return total
+
+
+def test_the_blocked_branch_holds_no_array_of_tokens_by_capacity():
+    """Command A+'s 4,096 bucket (16 of 128, top-8: 8,320 rows): in the
+    compact branch no array has ``S`` and ``capacity`` as its two
+    dimensions nor ``S x k`` rows, and its placement matmuls multiply
+    ``S x (128 x k) x d``, where one block multiplies ``S x capacity x
+    d``."""
+    rows, d, k = 4096, 8, 8
+    layer = MoEMLP(d_model=d, d_ff=24, num_experts=128, top_k=k,
+                   gated=True, held_experts=16)
+    capacity = compact_capacity(rows * k, 16, 128)
+    blocks = combine_blocks(rows, k, capacity)
+    assert (capacity, blocks) == (8320, 32)
+    branch = _compact_branch(layer, rows, d)
+    shapes = {tuple(v.aval.shape) for eqn, _ in _walk(branch)
+              for v in eqn.outvars}
+    assert not [s for s in shapes if rows in s and capacity in s]
+    assert not [s for s in shapes if len(s) > 1 and rows * k in s]
+    assert (capacity, d) in shapes
+    assert _dot_flops(branch) == rows * (rows // blocks * k) * d * 2
+    # one block, at the bucket below the rule's edge: the whole square
+    small = _compact_branch(layer, 1024, d)
+    assert combine_blocks(1024, k, 2176) == 1
+    assert _dot_flops(small) == 1024 * 2176 * d * 2
+
+
+# (rows, experts, held, top-k) -> sha256[:16] of ``lower().as_text()`` of
+# the layer's forward and of its gradient (d 8, d_ff 24, gated, bf16),
+# read from PR 53's tree, the parent of the PR that taught the combine
+# its blocks: shapes where the rule keeps ONE block.
+ONE_BLOCK_PARENT_TEXT = {
+    (4096, 384, 12, 8): ("05bafec921e63b42", "2438b8914f3438f8"),   # Kimi
+    (1024, 384, 12, 8): ("83f0aff9857a59e4", "c26652d991a8bcac"),
+    (2048, 256, 16, 8): ("8604edff3c18cab5", "047c31e942cbdb5d"),   # -Linear
+    (1024, 72, 18, 10): ("ca2a707a04cafebb", "71978db29a38f5c5"),   # Granite
+    (1024, 128, 16, 8): ("0a45488f99ab7b14", "a33a580defcac2f3"),   # Command A+
+}
+
+
+@pytest.mark.parametrize("shape", sorted(ONE_BLOCK_PARENT_TEXT))
+def test_one_block_lowers_to_the_parents_text(shape):
+    """The largest prefill bucket of each cell that keeps one block (and
+    Command A+'s smallest): forward and gradient lower to the text they
+    lowered to before the combine knew blocks, letter for letter."""
+    rows, n, held, k = shape
+    assert combine_blocks(rows, k, compact_capacity(rows * k, held, n)) == 1
+    assert _lowered_texts(rows, n, held, k) == ONE_BLOCK_PARENT_TEXT[shape]
+
+
+def _lowered_texts(rows, n, held, k):
+    import hashlib
+
+    layer = MoEMLP(d_model=8, d_ff=24, num_experts=n, top_k=k, gated=True,
+                   held_experts=held)
+    x = jax.ShapeDtypeStruct((1, rows, 8), jnp.float32)
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype)))
+
+    def forward(p, x):
+        return layer.apply(p, x, mutable=["intermediates"])
+
+    def loss(p, x):
+        return jnp.sum(forward(p, x)[0].astype(jnp.float32) ** 2)
+    return tuple(
+        hashlib.sha256(jax.jit(f).lower(params, x).as_text().encode()
+                       ).hexdigest()[:16]
+        for f in (forward, jax.grad(loss, argnums=(0, 1))))
+
+
+# configuration -> (experts, held, top-k, {prefill bucket: blocks}): every
+# bucket of the four cells that run the compact branch, and Kimi-Linear's
+# 4,096 that none runs.  1: the one placement matmul.
+COMBINE_BLOCKS = {
+    "command-a-plus-05-2026": (128, 16, 8, {1024: 1, 2048: 16, 4096: 32,
+                                            8192: 64, 16384: 128}),
+    "kimi-k2.5": (384, 12, 8, {1024: 1, 4096: 1}),
+    "kimi-linear-48b-a3b": (256, 16, 8, {2048: 1, 4096: 32}),
+    "granite-4.0-h-small": (72, 18, 10, {512: 1, 1024: 1}),
+}
+
+
+@pytest.mark.parametrize("name,bucket", [
+    (name, bucket) for name, row in COMBINE_BLOCKS.items()
+    for bucket in row[3]])
+def test_the_rule_by_configuration_and_bucket(name, bucket):
+    """Blocks of 128 tokens where one block would multiply at least four
+    times as much and the square is large enough to pay for the second
+    sort; the one matmul elsewhere."""
+    n, held, k, want = COMBINE_BLOCKS[name]
+    capacity = compact_capacity(bucket * k, held, n)
+    blocks = combine_blocks(bucket, k, capacity)
+    assert blocks == want[bucket]
+    if blocks > 1:
+        assert bucket % blocks == 0 and bucket // blocks * k <= capacity
 
 
 def test_counters_hold_compact_as_their_fourth_entry():

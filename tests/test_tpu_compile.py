@@ -1128,6 +1128,14 @@ def test_command_a_cell_compiles_at_the_benchmarks_sizes(one_chip,
     assert calls("paged_decode") == (4 if decode else 0)
     assert calls("flash_fwd") == (0 if decode else 4)
     assert ("attn.window" in text) and ("attn.full" in text)
+    if not decode:
+        # The compact experts' combine by blocks of 128 tokens
+        # (``ops/moe.py combine_blocks``): one loop a layer and no
+        # [T, capacity] placement array, fused or not; 15.20 GB as with the
+        # one matmul (PR 53: 15.196).
+        assert f"[{t},{2 * t + 128}]" not in text
+        assert len(re.findall(r" while\(", text)) == 4
+        assert _device_bytes(compiled) < 15.21e9
 
 
 def _train_step_and_shapes(cfg, loss_chunk):
