@@ -60,6 +60,7 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as PS
 
 from .decoder import Decoder, Mixer, decoder_rules, next_token_loss
@@ -87,7 +88,20 @@ class KimiLinearConfig:
     kda_head_dim: int = 128
     kda_conv: int = 4
     kda_gate_rank: int = 128            # of the decay's and the output gate's
-    kda_chunk: int = 64                 # kda_scan's chunk
+    # kda_scan's chunk, chosen INSIDE a forward of three KDA layers at these
+    # widths (examples/probes/delta_scan_probe.py --layers 3, my chip runs,
+    # PR 57; ms a forward at 1,024 | 2,048 positions): chunks of 32 9.33 |
+    # 21.80, of 64 10.63 | 24.35; the form before PR 57 (a triangular solve
+    # a chunk of 64, --tree a checkout of 0b5bcbc) 13.26 | 30.85.  One
+    # layer's scan ALONE pays layout copies that a program's neighbours
+    # absorb and orders the chunks otherwise (32 heads of 128 x 128, ms at
+    # 1,024 | 2,048 | 4,096, before PR 57 and then with PR 57's first form of
+    # the inverse: chunks of 32 1.85 | 3.63 | 7.52 and 1.75 | 3.50 | 8.06; of
+    # 64 3.17 | 7.14 | 10.78 and 1.93 | 4.65 | 8.56; of 128 4.91 | 10.17 |
+    # 20.17 and 3.07 | 6.99 | 14.29; as committed 3.85 at 2,048 in chunks of
+    # 32, 4.91 of 64): ``_decayed_scores``' pairwise blocks grow with the
+    # chunk.
+    kda_chunk: int = 32
     kda_sub_chunk: int = 16             # and the blocks inside it
     n_head: int = 32                    # the latent layers'
     q_lora_rank: Optional[int] = None
@@ -225,16 +239,65 @@ def _decayed_scores(left, k, gcum, sub: int):
 
 def _scalar_decayed(gcum):
     """``exp(G_i - G_j)`` for ``i >= j`` and 0 above the diagonal, for a
-    decay of ONE number a head: gcum [..., C, 1] -> [..., C, C].  With a
+    decay of ONE number a head: gcum [..., C] -> [..., C, C].  With a
     scalar the decay leaves the sum over the channels, so a chunk's scores
     are plain matmuls times this matrix and ``_decayed_scores``' blocks are
     not paid for; only differences <= 0 are exponentiated."""
-    c = gcum.shape[-2]
+    c = gcum.shape[-1]
     tri = jnp.tril(jnp.ones((c, c), bool))
-    return jnp.exp(jnp.where(tri, gcum - jnp.swapaxes(gcum, -1, -2),
+    return jnp.exp(jnp.where(tri, gcum[..., :, None] - gcum[..., None, :],
                              -jnp.inf))
 
 
+def _nilpotent_inverse(n):
+    """``(I + N)^-1`` for strictly lower-triangular ``N`` [..., s, s]: ``(I -
+    N)(I + N^2)(I + N^4) ..`` up to the last power below ``s``, exact because
+    ``N^s = 0``.  The products are float32 multiplies and sums over every
+    block at once, no matmul: nothing rounds an operand."""
+    def times(x, y):
+        return jnp.sum(x[..., :, :, None] * y[..., None, :, :], axis=-2)
+
+    s = n.shape[-1]
+    inv, power, reach = jnp.eye(s, dtype=n.dtype) - n, n, 2
+    while reach < s:                    # power: N^(reach / 2)
+        power = times(power, power)
+        inv = inv + times(inv, power)
+        reach *= 2
+    return inv
+
+
+def _unit_lower_inverse(a, block: int):
+    """``(I + A)^-1`` for strictly lower-triangular ``A`` [..., C, C] (C whole
+    blocks), exact in finitely many products and with no triangular solve:
+    the diagonal blocks of ``block`` rows by ``_nilpotent_inverse``, then
+    blocks joined two by two, ``inv([[P, 0], [R, Q]]) = [[P', 0], [-Q' R P',
+    Q']]``, doubling until one block is the chunk.  A level is two matmuls
+    over the whole ``[C, C]`` arrays, ``T <- (I - T R) T`` with ``T`` the
+    block-diagonal of the inverses so far and ``R`` the blocks of ``A`` that
+    join each pair: written so, a level's result feeds matmuls only.  An odd
+    count of blocks leaves the last one unpaired until a later level."""
+    *lead, c, _ = a.shape
+    nb = c // block
+    own = jnp.eye(nb, dtype=bool)[:, None, :, None]    # a block's own columns
+    diag = jnp.sum(jnp.where(own, a.reshape(*lead, nb, block, nb, block),
+                             0.0), axis=-2)            # [.., nb, s, s]
+    t = jnp.where(own, _nilpotent_inverse(diag)[..., None, :],
+                  0.0).reshape(a.shape)
+    eye = jnp.eye(c, dtype=a.dtype)
+    of, width = np.arange(c), block
+    while width < c:
+        b = of // width
+        pair = (b[:, None] % 2 == 1) & (b[None, :] == b[:, None] - 1)
+        t = (eye - t @ jnp.where(pair, a, 0.0)) @ t
+        width *= 2
+    return t
+
+
+# (``jax.jit``: a program traces and lowers the scan once, not once a layer:
+# the lowered module of Olmo-Hybrid's 16-layer prefill is 441 thousand
+# characters with it and 918 without, the form before PR 57 578, and every
+# warm start of a program pays for its size, PERF.md PR 57)
+@functools.partial(jax.jit, static_argnums=(5, 6))
 def kda_scan(q, k, v, g, beta, chunk: int, sub: int, state=None):
     """The gated delta rule over T positions in chunks.
 
@@ -247,15 +310,23 @@ def kda_scan(q, k, v, g, beta, chunk: int, sub: int, state=None):
 
     Inside a chunk, with ``G`` the cumulative sum of g and ``Gamma =
     exp(G)``: ``A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)`` below the
-    diagonal; ``(I + A) [W | U] = diag(beta) [K * Gamma | V]`` (a unit
-    lower-triangular solve: each row's correction takes the rows before
-    it); with the incoming state ``S``: ``V' = U - W S``; ``O = (Q * Gamma)
-    S + tril((Q K^T)_decayed) V'``; ``S' = diag(Gamma_C) S + (K * Gamma_C /
-    Gamma)^T V'``.  Everything that does not depend on ``S`` is computed
-    for all chunks at once; the state is carried from chunk to chunk.  No
-    ``1 / Gamma`` is formed: ``_decayed_scores`` says how, and for a
-    scalar decay ``_scalar_decayed``.  T is filled up
-    to whole chunks with identity positions; a T shorter than a chunk is
+    diagonal; ``T = (I + A)^-1`` (``_unit_lower_inverse``, from diagonal
+    blocks of ``sub`` rows: each row's correction takes the rows before
+    it); ``[W | U] = T diag(beta) [K * Gamma | V]``; with the incoming
+    state ``S``: ``V' = U - W S``; ``O = (Q * Gamma) S + tril((Q
+    K^T)_decayed) V'``; ``S' = diag(Gamma_C) S + (K * Gamma_C / Gamma)^T
+    V'``.  Everything that does not read ``S`` is a
+    matmul over all chunks at once: with ``qk`` the decayed ``Q K^T`` and
+    ``K_out = K * Gamma_C / Gamma``, ``Q~ = Q * Gamma - qk W``, ``P = qk
+    U``, ``M = K_out^T W`` and ``B = K_out^T U``; the pass over the chunks
+    is ``O_c = Q~_c S + P_c; S <- Gamma_C S - M_c S + B_c``: it carries the
+    state and two matmuls that do not wait for each other.  (The decay stays
+    a float32 multiply beside ``M``: inside it, it would be rounded with the
+    matmul's operands once a chunk.)  No ``1 / Gamma`` is formed:
+    ``_decayed_scores`` says how, and for a scalar decay
+    ``_scalar_decayed``, whose ``G`` is kept [.., C] with no axis of one
+    behind it (the chip lays such an axis out as whole tiles).  T is filled
+    up to whole chunks with identity positions; a T shorter than a chunk is
     one chunk of whole blocks."""
     bsz, t, h, d = q.shape
     if t < chunk:
@@ -271,44 +342,48 @@ def kda_scan(q, k, v, g, beta, chunk: int, sub: int, state=None):
         return jnp.moveaxis(
             z.reshape((bsz, nc, chunk) + z.shape[2:]), 3, 2)
 
-    q, k, v, g = (chunks(z) for z in (q, k, v, g))
+    q, k, v = (chunks(z) for z in (q, k, v))
     beta = chunks(beta)[..., None]                         # [B,nc,H,C,1]
-    gcum = jnp.cumsum(g, axis=-2)
-    gamma = jnp.exp(gcum)
     if g.shape[-1] == 1 < d:            # the decay a head, not a channel
+        gcum = jnp.cumsum(chunks(g[..., 0]), axis=-1)      # [B,nc,H,C]
         decayed = _scalar_decayed(gcum)
+        to_end = jnp.exp(gcum[..., -1:] - gcum)[..., None]
+        gamma = jnp.exp(gcum)[..., None]                   # [B,nc,H,C,1]
 
         def scores(left):
             return jnp.einsum("...id,...jd->...ij", left, k) * decayed
     else:
+        gcum = jnp.cumsum(chunks(g), axis=-2)              # [B,nc,H,C,D]
+        to_end = jnp.exp(gcum[..., -1:, :] - gcum)
+        gamma = jnp.exp(gcum)
+
         def scores(left):
             return _decayed_scores(left, k, gcum, sub)
     strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
     a_mat = jnp.where(strict, scores(k), 0.0) * beta
-    wu = jax.scipy.linalg.solve_triangular(
-        a_mat + jnp.eye(chunk, dtype=a_mat.dtype),
-        beta * jnp.concatenate([k * gamma, v], axis=-1),
-        lower=True, unit_diagonal=True)
+    t_mat = _unit_lower_inverse(a_mat, sub)
+    wu = t_mat @ (beta * jnp.concatenate([k * gamma, v], axis=-1))
     w, u = wu[..., :d], wu[..., d:]
     qk = scores(q)
-    q_in = q * gamma
-    k_out = k * jnp.exp(gcum[..., -1:, :] - gcum)
-    g_out = gamma[..., -1, :]                              # [B,nc,H,D]
+    q_in = q * gamma - qk @ w                              # Q~
+    p = qk @ u
+    k_out = k * to_end
+    m = jnp.einsum("...ck,...cj->...kj", k_out, w)         # [B,nc,H,D,D]
+    b = jnp.einsum("...ck,...cv->...kv", k_out, u)         # [B,nc,H,D,Dv]
+    g_out = jnp.swapaxes(gamma[..., -1:, :], -1, -2)       # [B,nc,H,D|1,1]
 
     def one(s, blk):
-        w_c, u_c, qk_c, q_c, k_c, g_c = blk
-        v_new = u_c - jnp.einsum("bhck,bhkv->bhcv", w_c, s)
-        o = jnp.einsum("bhck,bhkv->bhcv", q_c, s) \
-            + jnp.einsum("bhcj,bhjv->bhcv", qk_c, v_new)
-        s = g_c[..., None] * s + jnp.einsum("bhck,bhcv->bhkv", k_c, v_new)
+        q_c, p_c, m_c, b_c, g_c = blk
+        o = jnp.einsum("bhck,bhkv->bhcv", q_c, s) + p_c
+        s = g_c * s - jnp.einsum("bhkj,bhjv->bhkv", m_c, s) + b_c
         return s, o
 
     if state is None:
         state = jnp.zeros((bsz, h, d, v.shape[-1]), jnp.float32)
     state, o = jax.lax.scan(
         one, state, tuple(jnp.moveaxis(z, 1, 0)
-                          for z in (w, u, qk, q_in, k_out, g_out)))
-    o = jnp.moveaxis(o, 0, 1)                              # [B,nc,H,C,D]
+                          for z in (q_in, p, m, b, g_out)))
+    o = jnp.moveaxis(o, 0, 1)                              # [B,nc,H,C,Dv]
     o = jnp.moveaxis(o, 2, 3).reshape(bsz, nc * chunk, h, v.shape[-1])
     return o[:, :t], state
 

@@ -94,12 +94,22 @@ class OlmoHybridConfig:
     gdn_key_dim: int = 96
     gdn_value_dim: int = 192
     gdn_conv: int = 4
-    # kda_scan's chunk: at 30 heads of 96 x 192 one layer's scan of 4,096
-    # | 1,024 positions took 6.9 | 0.94 ms in chunks of 8, 5.7 | 1.07 of 16,
-    # 5.6 | 1.41 of 32, 8.6 | 3.3 of 64, 15.4 | 3.7 of 128 (my chip runs,
-    # PR 48): the triangular solve a chunk grows faster than the
-    # sequential pass over the chunks shrinks.
-    gdn_chunk: int = 32
+    # kda_scan's chunk, chosen INSIDE a forward of three linear layers at
+    # these widths (examples/probes/delta_scan_probe.py --layers 3, my chip
+    # runs, PR 57; ms a forward at 1,024 | 4,096 positions): chunks of 64
+    # 11.25 | 56.00, of 128 10.77 | 52.55 (of 256 63.81 at 4,096, an earlier
+    # run); the form before PR 57 (a triangular solve a chunk of 32, --tree
+    # a checkout of 0b5bcbc) 14.34 | 56.71.  One layer's scan ALONE pays
+    # layout copies that a program's neighbours absorb and tells the chunks
+    # apart no more (30 heads of 96 x 192, ms at 1,024 | 2,048 | 4,096,
+    # before PR 57 and then with PR 57's first form of the inverse: chunks of
+    # 32 1.31 | 2.37 | 5.62 and 0.97 | 2.12 | 5.85; of 64 3.19 | 4.68 | 8.52
+    # and 0.85 | 2.14 | 5.64; of 128 3.59 | 7.15 | 15.30 and 0.73 | 2.21 |
+    # 5.68; as committed 6.16 at 4,096 in chunks of 128).  ``M`` and ``B``
+    # are ``d_k x (d_k + d_v)`` a chunk: at 32 they are 425 MB a layer at
+    # 4,096 positions (the cell's prefill[4096] passes its 0.9 GB of
+    # temporaries), at 128 106 MB.
+    gdn_chunk: int = 128
     d_ff: int = 11008
     max_seq: int = 65536
     rms_eps: float = 1e-6
@@ -232,7 +242,8 @@ class GDNMixer(nn.Module):
                 if cache is not None:
                     s_in = load_states(ssm_pool, layer, slots, fresh, h)
                 # (a scalar decay's scores have no blocks inside a chunk:
-                # ``sub`` only rounds a short forward up, to whole sublanes)
+                # ``sub`` is the rows of the inverse's diagonal blocks and
+                # rounds a short forward up, to whole sublanes)
                 o, s_out = kda_scan(q, k, v, g, beta, cfg.gdn_chunk, 8, s_in)
                 if cache is not None:
                     ssm_pool = store_states(ssm_pool, layer, slots, s_out)

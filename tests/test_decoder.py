@@ -57,14 +57,16 @@ ROWS = sorted(PARENT)
 # info) of the tiny preset's full forward over [2, 16] tokens and of its
 # decode step [2, 1] through ``jit_forward`` (16 pages of 4, 2 slots, a
 # table of 8 pages), read from PR 47's tree, the parent of the PR that
-# taught the block a second norm placement.
+# taught the block a second norm placement.  (Kimi-Linear's full forward
+# holds ``kda_scan``: its text is PR 57's, which wrote the scan as matmuls;
+# its decode step, which holds no scan, is still PR 47's.)
 PARENT_TEXT = {
     "llama": ("43d357f519b17ee4", "8a441a1186e41b65"),
     "olmoe": ("d6b57ab637dddbdb", "1f5190c8f2beedf4"),
     "granitemoehybrid": ("1861a65bd88f2d2d", "b06880576c4ef440"),
     "lfm2moe": ("3659f4819c814bfa", "f01d30a32d8e8cdb"),
     "kimik2": ("069d35638dcb9998", "3bc9756184d110a6"),
-    "kimilinear": ("c63a86a8f72f8871", "342c2f867ea51873"),
+    "kimilinear": ("3544cd92bc534df9", "342c2f867ea51873"),
     "xing40": ("15032390f5e6a9d8", "5af8f70dce770c0f"),
 }
 

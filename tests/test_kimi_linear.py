@@ -126,19 +126,47 @@ def _drawn(t, decay, seed=0, b=2, h=3, d=16):
     return (q, k, v, g, beta), state
 
 
+@pytest.mark.parametrize("c,block", [(8, 8), (12, 4), (24, 8), (48, 16),
+                                     (128, 8), (128, 16)])
+def test_the_inverse_by_blocks_is_the_inverse(c, block):
+    """``(I + A)^-1`` of a strictly lower-triangular ``A`` with entries to 2,
+    by blocks: one block, two levels, an odd count of blocks (the last one
+    joined a level later), and a chunk of 128 from blocks of 8 and of 16."""
+    from ray_tpu.models.kimi_linear import _unit_lower_inverse
+
+    a = np.tril(np.random.default_rng(c + block).uniform(
+        -2, 2, (2, 3, c, c)), -1) / np.sqrt(block)
+    got = _unit_lower_inverse(jnp.asarray(a, jnp.float32), block)
+    want = np.linalg.inv(np.eye(c) + a)
+    assert float(np.max(np.abs(np.triu(got, 1)))) == 0.0
+    np.testing.assert_allclose(got, want, rtol=2e-5,
+                               atol=2e-5 * np.max(np.abs(want)))
+
+
 @pytest.mark.parametrize("carried", [False, True], ids=["zero", "carried"])
-@pytest.mark.parametrize("t", [1, 3, 16, 32, 45])
-def test_chunked_scan_equals_the_recurrence(t, carried):
+@pytest.mark.parametrize("t,chunk,sub", [
+    (1, 16, 4), (3, 16, 4), (16, 16, 4), (32, 16, 4), (45, 16, 4),
+    (130, 64, 16), (300, 64, 16), (130, 128, 16), (300, 128, 16),
+    (40, 64, 16)])
+def test_chunked_scan_equals_the_recurrence(t, chunk, sub, carried):
     """Lengths that are and are not whole chunks of 16 (blocks of 4), one
-    shorter than a block, from a zero and from a carried state."""
-    args, state = _drawn(t, 0.3, seed=t)
+    shorter than a block; chunks of 64 and 128 in blocks of 16 with betas
+    to 2 (the family draws them under 1; the scan is Olmo-Hybrid's too),
+    several chunks and a last one part-filled; 40 positions under a chunk
+    of 64: one chunk of THREE blocks; from a zero and from a carried
+    state."""
+    (q, k, v, g, beta), state = _drawn(t, 0.3, seed=t + chunk - 16)
+    if chunk > 16:
+        beta = 2.0 * beta
+    args = (q, k, v, g, beta)
     state = state if carried else None
     want, s_want = _recurrence(*args, state)
-    got, s_got = kda_scan(*args, 16, 4, state)
-    np.testing.assert_allclose(got, want, atol=2e-6)
-    np.testing.assert_allclose(s_got, s_want, atol=2e-6)
-    # (the solve is real: without the correction the outputs differ)
-    plain, _ = faults._uncorrected(None)[0](*args, 16, 4, state)
+    got, s_got = kda_scan(*args, chunk, sub, state)
+    atol = 2e-6 if chunk == 16 else 5e-6
+    np.testing.assert_allclose(got, want, atol=atol)
+    np.testing.assert_allclose(s_got, s_want, atol=atol)
+    # (the correction is real: without it the outputs differ)
+    plain, _ = faults._uncorrected(None)[0](*args, chunk, sub, state)
     assert t == 1 or float(jnp.max(jnp.abs(plain - want))) > 1e-2
 
 
@@ -446,7 +474,9 @@ def test_the_lowered_forward_names_the_scopes_the_readers_file_by():
                    for x in text.splitlines())
     assert any("kda.step" in x and "scatter" in x
                for x in decode.splitlines())
-    assert "triangular_solve" in prefill and "triangular_solve" not in decode
+    # the chunked scan is matmuls and one loop: no triangular solve
+    assert "triangular_solve" not in prefill + decode
+    assert "stablehlo.while" in prefill
     assert "tpu_custom_call" not in decode      # 16 x 16 states: ``jnp``
 
 
@@ -588,9 +618,9 @@ def test_the_model_given_a_share_equals_the_reference_given_it(params,
 # -------------------------------------------------------------- training
 
 def test_loss_and_every_gradient_leaf_equal_the_reference(params, tokens):
-    """Through the chunked scan and its triangular solve, against the
-    reference's gradients through the token-by-token recurrence; and
-    ``expert_bias`` takes no gradient."""
+    """Through the chunked scan, its inverse by blocks and its pass over
+    the chunks, against the reference's gradients through the
+    token-by-token recurrence; and ``expert_bias`` takes no gradient."""
     loss, grads = jax.jit(lambda p: jax.value_and_grad(
         lambda q: kimi_linear_loss_fn(CFG, q, {"tokens": tokens}))(p))(
             params)

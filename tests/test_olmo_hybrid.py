@@ -144,21 +144,54 @@ def _drawn(t, decay, seed=0, b=2, h=3, dk=12, dv=24):
 
 
 @pytest.mark.parametrize("carried", [False, True], ids=["zero", "carried"])
-@pytest.mark.parametrize("t", [1, 3, 16, 32, 45])
-def test_chunked_scan_equals_the_recurrence(t, carried):
+@pytest.mark.parametrize("t,chunk", [
+    (1, 16), (3, 16), (16, 16), (32, 16), (45, 16),
+    (130, 64), (300, 64), (130, 128), (300, 128), (20, 64)])
+def test_chunked_scan_equals_the_recurrence(t, chunk, carried):
     """A rectangular state and a scalar decay, betas above 1: lengths that
-    are and are not whole chunks of 16, one shorter than a sublane tile,
-    from a zero and from a carried state."""
-    args, state = _drawn(t, 0.3, seed=t)
+    are and are not whole chunks of 16, one shorter than a sublane tile;
+    chunks of 64 and 128 in blocks of 8 (the inverse joins three and four
+    levels of blocks by matmuls), several chunks and a last one
+    part-filled; 20 positions under a chunk of 64: one chunk of THREE blocks,
+    the last joined a level after the first two; from a zero and from a
+    carried state."""
+    args, state = _drawn(t, 0.3, seed=t + chunk - 16)
     assert float(jnp.max(args[4])) > 1.0 or t == 1
     state = state if carried else None
     want, s_want = _recurrence(*args, state)
-    got, s_got = kda_scan(*args, 16, 8, state)
+    got, s_got = kda_scan(*args, chunk, 8, state)
     np.testing.assert_allclose(got, want, atol=5e-6)
     np.testing.assert_allclose(s_got, s_want, atol=5e-6)
-    # (the solve is real: without the correction the outputs differ)
-    plain, _ = faults._uncorrected()[0](*args, 16, 8, state)
+    # (the correction is real: without it the outputs differ)
+    plain, _ = faults._uncorrected()[0](*args, chunk, 8, state)
     assert t == 1 or float(jnp.max(jnp.abs(plain - want))) > 1e-2
+
+
+@pytest.mark.parametrize("decay", ["head", "channel"])
+def test_the_scans_gradient_is_the_recurrences(decay):
+    """The trainer's path: the gradient of a scalar loss through the
+    chunked form (autodiff through its matmuls and the pass over the
+    chunks) is the token-by-token recurrence's, for every input and the
+    carried state, with one decay a head and with one a channel."""
+    (q, k, v, g, beta), state = _drawn(70, 0.3, seed=21)
+    if decay == "channel":
+        g = g * jnp.linspace(0.5, 1.5, q.shape[-1])
+    mix = jnp.asarray(np.random.default_rng(2).normal(
+        size=v.shape[-1:]), jnp.float32)
+
+    def loss(scan, args):
+        o, s = scan(*args)
+        return jnp.sum(jnp.tanh(o) * mix) + jnp.sum(s * s) / s.size
+
+    args = (q, k, v, g, beta, state)
+    want = jax.grad(lambda a: loss(_recurrence, a))(args)
+    got = jax.grad(lambda a: loss(
+        lambda q, k, v, g, beta, s: kda_scan(q, k, v, g, beta, 32, 8, s),
+        a))(args)
+    for name, x, y in zip("q k v g beta state".split(), got, want):
+        scale = float(jnp.max(jnp.abs(y)))
+        assert scale > 1e-3, name
+        assert float(jnp.max(jnp.abs(x - y))) < 2e-5 * max(scale, 1.0), name
 
 
 def test_the_scalar_decay_is_the_channel_decay_with_equal_channels():
@@ -453,7 +486,9 @@ def test_the_lowered_forward_names_the_scopes_the_readers_file_by():
     assert "kv.attend" not in prefill
     assert "stablehlo.sine" not in decode + prefill           # no rope
     assert "mlp.dense" not in decode                # no experts to set apart
-    assert "triangular_solve" in prefill and "triangular_solve" not in decode
+    # the chunked scan is matmuls and one loop: no triangular solve
+    assert "triangular_solve" not in prefill + decode
+    assert "stablehlo.while" in prefill
     assert "tpu_custom_call" not in decode      # 12 x 24 states: ``jnp``
 
 

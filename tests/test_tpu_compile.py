@@ -107,6 +107,34 @@ def _device_bytes(compiled) -> float:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
+def _scan_loop_dots(text, scope):
+    """One count a ``while`` of the compiled text whose body works under
+    ``scope`` (``gdn.scan``, ``kda.scan``): the matmuls one turn holds,
+    those inside the fusions it calls too."""
+    comps = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\) -> [^\n]*\{\n(.*?)^\}", text,
+        re.M | re.S)}
+
+    def dots(name):
+        body = comps.get(name, "")
+        return len(re.findall(r" (?:convolution|dot)\(", body)) + sum(
+            dots(callee) for callee in re.findall(r"calls=%?([\w.\-]+)", body))
+
+    return [dots(body) for body in re.findall(
+        r" while\([^\n]*?body=%?([\w.\-]+)", text)
+        if scope in comps.get(body, "")]
+
+
+def _assert_scan_is_matmuls(text, scope, layers):
+    """The chunked delta-rule scan of a compiled program: no triangular
+    solve (nor its expanded loop: the ONE loop a mixer layer is the pass
+    over the chunks), and a turn of that pass holds the state's two
+    matmuls."""
+    assert "triangular" not in text
+    loops = _scan_loop_dots(text, scope)
+    assert len(loops) == layers and all(n <= 2 for n in loops), loops
+
+
 KERNEL_NAMES = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
@@ -820,9 +848,9 @@ def test_kimi_linear_cell_compiles_at_the_benchmarks_sizes(one_chip,
     to call as the ONE buffer it came in (each call aliases it in and out
     and copies the running rows' states itself): no copy of the pool, no
     slice or in-place write of a slab, no loop over the rows; the prefill
-    runs the chunked scan (a triangular solve a KDA layer) and the flash
-    kernel, and holds under 1 GB of temporaries; the logits are the one
-    served position's (every row's were 1.34 GB in float32)."""
+    runs the chunked scan (batched matmuls and one short loop a KDA layer)
+    and the flash kernel, and holds under 1 GB of temporaries; the logits
+    are the one served position's (every row's were 1.34 GB in float32)."""
     import ray_tpu.models.attention as attention
     import ray_tpu.models.kimi_linear as kimi_linear
     import ray_tpu.ops
@@ -896,6 +924,7 @@ def test_kimi_linear_cell_compiles_at_the_benchmarks_sizes(one_chip,
     assert calls("paged_decode_latent") == (7 if decode else 0)
     assert calls("flash_fwd") == (0 if decode else 7)
     assert calls("kda_step") == (20 if decode else 0)
+    _assert_scan_is_matmuls(text, "kda.scan", 0 if decode else 20)
     # what MAKES an array of the pool's shape (not a parameter, an element
     # of a tuple or the tuple returned): instruction and opcode
     made = [(hit.group(1), hit.group(2)) for line in text.splitlines()
@@ -961,11 +990,11 @@ def test_olmo_hybrid_cell_compiles_at_the_benchmarks_sizes(one_chip,
     chip's memory with the prefill's temporaries.  The decode step runs the
     recurrence through the ``kda_step`` kernel once a linear layer (no
     gathered or copied state slab) and attends through the paged kernel
-    once an attention layer; the prefill runs the chunked scan (a
-    triangular solve a linear layer) and the flash kernel among its own
-    rows: no ``[.., 4096, 4096]`` float32 array, which the gather over
-    ``max_context`` rows made 2 GB a layer of; the logits are the one served
-    position's."""
+    once an attention layer; the prefill runs the chunked scan (batched
+    matmuls and one short loop a linear layer) and the flash kernel among
+    its own rows: no ``[.., 4096, 4096]`` float32 array, which the gather
+    over ``max_context`` rows made 2 GB a layer of; the logits are the one
+    served position's."""
     import ray_tpu.models.kimi_linear as kimi_linear
     from ray_tpu.llm.engine import jit_forward
     from ray_tpu.llm.kv_cache import init_pool, init_state, pages_for
@@ -1031,6 +1060,7 @@ def test_olmo_hybrid_cell_compiles_at_the_benchmarks_sizes(one_chip,
         assert f"f32[{slab}]" not in text
     else:
         assert "gdn.scan" in text and "gdn.step" not in text
+    _assert_scan_is_matmuls(text, "gdn.scan", 0 if decode else 12)
 
 
 def _command_a_cell():
