@@ -835,3 +835,166 @@ def test_stats_from_another_thread_mid_step_still_add_up():
         sys.setswitchinterval(interval)
         eng.stop()
     assert eng.stats()["runs_voided_s"] == before["runs_voided_s"]
+
+
+# ------------------------------ the contract of stats() over the caches
+
+# The keys every engine's stats() has, then those a family's layout adds.
+STATS_KEYS = {
+    "attention", "compiles", "cpu_sample", "device", "evictions",
+    "kv_pages_total", "kv_pages_used", "last_error", "llm.other",
+    "max_context", "peak_hbm_bytes", "phase_cpu_s", "phase_s", "pipeline",
+    "prefill_bucket_tokens", "prefill_tokens", "prefills", "programs",
+    "running", "runs", "runs_unpaced_s", "runs_voided_s", "sampling",
+    "step_cpu_s", "step_errors", "step_s", "steps", "tokens_generated",
+    "tpot_count", "tpot_s_total", "ttft_prefill_s_total", "ttft_requests",
+    "ttft_waiting_s_total", "waiting"}
+_MOE = {"moe", "moe_prefill"}
+FAMILY_KEYS = {
+    "gpt2": set(), "llama": set(), "olmoe": _MOE,
+    "granitemoehybrid": _MOE | {"state"}, "lfm2moe": _MOE | {"state"},
+    "kimik2": _MOE, "kimilinear": _MOE | {"state"},
+    "xing40": _MOE | {"residual"}, "olmohybrid": {"state"},
+    "cohere2moe": _MOE | {"kv_pages"}}
+ATTENTION_KEYS = {"decode_runs", "kv_rows_read", "kv_rows_held",
+                  "kv_row_bytes"}
+LATENT_KEYS = {"latent_dim", "rope_dim"}
+WINDOW_KEYS = {"window", "window_layers", "window_rows_read",
+               "window_rows_held", "window_positions_dropped"}
+STATE_KEYS = {"slots_total", "slots_used", "decode_runs",
+              "state_rows_updated", "state_row_bytes", "mixer_weight_bytes"}
+
+# (b) three requests a pool of 12 pages of 4 holds whole (3 + 2 + 3), the
+# third past the tiny window of 8; (c) three that need 22 pages between them.
+HELD = (([5, 100, 23, 77, 9], 4), ([9, 4], 6), (list(range(20, 29)), 3))
+TIGHT = tuple((p, n + 8) for p, n in MIXED[:2] + MIXED[4:5])
+
+
+def _gauges(name):
+    """The series of one gauge as the registry holds them now, by their
+    ``group`` tag (None: the gauge has no tags)."""
+    from ray_tpu.util.metrics import registry
+
+    for snap in registry().snapshot():
+        if snap["name"] == name:
+            return {s["tags"].get("group"): s["value"]
+                    for s in snap["series"]}
+    raise AssertionError(f"gauge {name} not published")
+
+
+def test_the_contract_covers_every_family():
+    from ray_tpu.models import MODEL_FAMILIES
+
+    assert set(FAMILY_KEYS) == set(MODEL_FAMILIES)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILY_KEYS))
+def test_stats_of_what_a_sequence_holds_by_family(family):
+    """What stats() say of the device's caches, for every family's
+    layout: (a) the key sets, absent keys included; (b) with a pool that
+    holds every request, the closed form of what the decode steps read,
+    hold and update; (c) with a pool that forces an eviction, every
+    stream whole, the victim's too, and everything given back at the end:
+    each group's pages, every slot."""
+    from math import ceil, prod
+
+    from ray_tpu.models import MODEL_FAMILIES
+
+    page, pages, rows = 4, 12, 3
+    eng = _family_engine(family, num_pages=pages, max_batch=rows)
+    cfg = eng.model_cfg
+    spec = MODEL_FAMILIES[family].cache(cfg)
+    per_seq = ceil(min(cfg.max_seq, pages * page) / page)
+    ring = ceil(spec.window / page) if spec.window_layers else 0
+
+    # (a) before any traffic and after it: the same keys
+    fresh = eng.stats()
+    _step_until_done(eng, [eng.submit(list(p), max_tokens=n)
+                           for p, n in HELD])
+    stats = eng.stats()
+    for s in (fresh, stats):
+        optional = FAMILY_KEYS[family] - (_MOE if s is fresh else set())
+        assert set(s) == STATS_KEYS | optional
+        assert set(s["attention"]) == ATTENTION_KEYS \
+            | (LATENT_KEYS if spec.latent_dim else set()) \
+            | (WINDOW_KEYS if spec.window_layers else set())
+        if "state" in s:
+            assert set(s["state"]) == STATE_KEYS
+        if "kv_pages" in s:
+            assert set(s["kv_pages"]) == {"full", "window"}
+            assert all(set(g) == {"used", "total"}
+                       for g in s["kv_pages"].values())
+    assert ("state" in stats) == bool(spec.state_layers)
+    assert ("kv_pages" in stats) == bool(spec.window_layers)
+
+    # (b) a request of p prompt tokens and m tokens out takes part in
+    # m - 1 decode steps, at p .. p + m - 2 positions cached
+    steps = [(len(p), len(p) + n - 1) for p, n in HELD]
+    runs = max(n for _, n in HELD) - 1
+    full = sum(ceil((c + 1) / page) for a, b in steps for c in range(a, b))
+    kept = sum(ceil(min(c + 1, spec.window) / page)
+               for a, b in steps for c in range(a, b)) if ring else 0
+    att = stats["attention"]
+    assert att["decode_runs"] == runs
+    assert att["kv_rows_read"] == \
+        page * (full * spec.kv_layers + kept * spec.window_layers)
+    assert att["kv_rows_held"] == runs * rows * page * (
+        per_seq * spec.kv_layers + ring * spec.window_layers)
+    if spec.latent_dim:
+        assert (att["latent_dim"], att["rope_dim"]) == \
+            (spec.latent_dim, spec.rope_dim)
+        assert att["kv_row_bytes"] == spec.row_width * 4    # float32 here
+    else:
+        assert att["kv_row_bytes"] == 2 * spec.kv_heads * spec.head_dim * 4
+    if spec.window_layers:
+        assert (att["window"], att["window_layers"]) == \
+            (spec.window, spec.window_layers)
+        assert att["window_rows_read"] == page * kept * spec.window_layers
+        assert att["window_rows_held"] == \
+            runs * rows * page * ring * spec.window_layers
+        assert att["window_positions_dropped"] == \
+            page * (full - kept) * spec.window_layers > 0
+        assert stats["kv_pages"] == {
+            "full": {"used": 0, "total": pages},
+            "window": {"used": 0, "total": rows * ring}}
+    assert (stats["kv_pages_used"], stats["kv_pages_total"]) == (0, pages)
+    if spec.state_layers:
+        assert stats["state"] == {
+            "slots_total": rows, "slots_used": 0, "decode_runs": runs,
+            "state_rows_updated":
+                sum(b - a for a, b in steps) * spec.state_layers,
+            "state_row_bytes":
+                4 * (prod(spec.conv_shape) if spec.conv_shape else 0)
+                + 4 * (prod(spec.ssm_shape) if spec.ssm_shape else 0),
+            "mixer_weight_bytes": cfg.mixer_params()
+            * np.dtype(cfg.param_dtype).itemsize}
+    assert stats["evictions"] == 0
+
+    # (c) the same engine, traffic its pool cannot hold at once
+    seqs = [eng.submit(list(p), max_tokens=n) for p, n in TIGHT]
+    streams = _step_until_done(eng, seqs)
+    after = eng.stats()
+    assert after["evictions"] > 0
+    for seq, (_, n) in zip(seqs, TIGHT):
+        frames = []
+        while not seq.out.empty():
+            frames.append(seq.out.get())
+        assert [f["index"] for f in frames[:-1]] == list(range(n))
+        assert frames[-1] == {"done": True, "reason": "length",
+                              "n_tokens": n}
+    # ... and each stream is what the request gives alone, unevicted
+    evicted = after["evictions"]
+    alone = [_step_until_done(eng, [eng.submit(list(p), max_tokens=n)])[0]
+             for p, n in TIGHT]
+    assert streams == alone
+    after = eng.stats()
+    assert after["evictions"] == evicted
+    assert after["running"] == after["waiting"] == 0
+    assert after["kv_pages_used"] == 0
+    assert _gauges("rt_llm_kv_pages_used")["full"] == 0.0
+    assert _gauges("rt_llm_state_slots_used")[None] == 0.0
+    if spec.window_layers:
+        assert all(g["used"] == 0 for g in after["kv_pages"].values())
+        assert _gauges("rt_llm_kv_pages_used")["window"] == 0.0
+    if spec.state_layers:
+        assert after["state"]["slots_used"] == 0
