@@ -57,27 +57,11 @@ re-prefills (prompt + everything it already generated) when pages free
 up, so already-streamed tokens are never re-emitted and greedy output
 is unchanged.
 
-A model whose layers are not all attention keeps a SECOND kind of cache
-(models.CacheSpec; kv_cache.py ``init_state`` / ``SlotPool``): beside its
-pages (for the layers that hold K/V, and only those) a sequence takes one
-slot of the state pool at admission, for what its recurrent layers keep
-(Granite: a conv window and a state-space state; LFM2: a conv window
-alone), and gives it back with its pages at retirement,
-cancellation and eviction (the re-prefill rebuilds the state from
-position 0).  Both pools are donated to the one jitted forward and
-updated where they lie; a decode row without a sequence carries a slot
-index outside the pool.  A model with LATENT attention (Kimi-K2) has a
-paged pool of ONE array, a row a position a layer and no V pool
-(kv_cache.py ``pool_arrays`` / ``init_pool``): the engine builds,
-carries, donates and aliases the arrays the spec names, whichever they
-are.  A model with SLIDING-WINDOW layers (Command A+) keeps its K/V in
-TWO GROUPS (kv_cache.py): beside the pages of the layers that keep every
-position a sequence takes, at admission, the whole RING of the window
-group, ``window`` positions however long it grows, and gives it back with
-its pages; each group has its arrays, its table and its ``PagePool``
-(``num_pages`` is the first group's; the second's pages follow from
-``max_batch`` and the window), and ``stats()["attention"]`` counts what
-each group's layers read and hold.
+What a sequence keeps on the device between steps (pages of a group of
+layers, a ring, a slot), in which arrays, through which tables and from
+which allocators, is ``kv_cache.py SequenceCache``'s and told there: the
+engine takes, grows and gives back a sequence's holding through it,
+hands its arrays to the one jitted forward and schedules.
 
 Tokens are chosen ON THE DEVICE: after each forward one jitted sampler
 (sampling.py ``jit_sampler``) takes the device-resident logits of the
@@ -119,8 +103,7 @@ import numpy as np
 
 from ..util import chips
 from ..util.spans import Phases, annotate
-from .kv_cache import (WINDOW_ARRAYS, PagePool, SlotPool, init_pool,
-                       init_state, pages_for, ring_pages)
+from .kv_cache import Holding, SequenceCache
 from .sampling import (SamplingParams, jit_feed, jit_sampler, pack_rows,
                        seed_words)
 
@@ -172,8 +155,7 @@ class _Sequence:
     """One in-flight generation request (engine-internal)."""
 
     __slots__ = ("sid", "tokens", "prompt_len", "max_tokens", "params",
-                 "seed", "out", "pages", "ring", "slot", "n_cached",
-                 "launched",
+                 "seed", "out", "held", "n_cached", "launched",
                  "generated", "finished", "cancelled", "submitted_ts",
                  "request_id", "first_token_ts", "last_token_ts",
                  "warmup")
@@ -189,13 +171,10 @@ class _Sequence:
         self.params = params
         self.seed = seed_words(seed)    # the sampler's key, two uint32
         self.out: "queue.Queue" = queue.Queue()
-        self.pages: List[int] = []
-        # Its ring in the window group (a model with window layers): whole
-        # while it runs, absent otherwise.
-        self.ring: List[int] = []
-        # Its row while it runs: of the decode batch, of the device's
-        # token array and, where the model keeps one, of the state pool.
-        self.slot: Optional[int] = None
+        # What it holds of the device's caches; ``held.slot`` is its row
+        # while it runs: of the decode batch and of the device's token
+        # array.
+        self.held = Holding()
         # Advanced at LAUNCH: positions written into KV pages by the
         # programs launched so far, and the tokens those programs
         # sample (the next one's index: the sampler's key word).
@@ -221,7 +200,7 @@ class _Sequence:
 class _Flight:
     """A launched program whose token ids the host has not read: the ids
     ``[max_batch]`` (and the program's routing counters) still on the
-    device, and whose each row is."""
+    device, and which sequence each of its rows is."""
 
     __slots__ = ("kind", "name", "ids", "moe", "residual", "rows",
                  "launched_at", "void", "admitted")
@@ -378,55 +357,24 @@ class GenerationEngine:
         self.model_cfg = model_cfg
         family = family_of(model_cfg)
         self._model = family.module(model_cfg)
-        # What a sequence keeps on the device, by layer kind
-        # (models.CacheSpec): pages for the layers with K/V, a slot of
-        # the state pool for the recurrent ones.
-        spec = self._cache_spec = family.cache(model_cfg)
         if params is None:
             params = family.init(model_cfg, jax.random.PRNGKey(seed))
         self._params = params
         # What this engine computes on, as JAX reports it (stats()).
         self._device = chips.describe_devices()
-        self.max_context = min(
-            self.cfg.max_context or model_cfg.max_seq, model_cfg.max_seq,
-            self.cfg.num_pages * self.cfg.page_size)
-        self._pages_per_seq = pages_for(self.max_context,
-                                        self.cfg.page_size)
-        self.pool = PagePool(self.cfg.num_pages, self.cfg.page_size)
-        # The window group (a spec with window layers; else no pool, no
-        # arrays, no table): every row's ring, whole or absent.
-        self._ring_pages = ring_pages(spec, self.cfg.page_size)
-        self.window_pool = PagePool(
-            self.cfg.max_batch * self._ring_pages, self.cfg.page_size,
-            group="window") if self._ring_pages else None
-        self._kv = init_pool(spec, self.cfg.num_pages, self.cfg.page_size,
-                             model_cfg.dtype,
-                             self.cfg.max_batch * self._ring_pages)
-        # One slot a running sequence: its row of the decode batch
-        # (it keeps it while it runs, so the device's ids of one step
-        # are the next step's tokens row for row) and, where the model
-        # has recurrent layers, of the state pool.  What the decode
-        # steps' recurrent layers move is counted beside the slots
-        # (stats()["state"], absent for a model without such layers):
-        # ``state_rows_updated`` = running rows x recurrent layers, one
-        # row = what one sequence keeps in one such layer, by the spec
-        # (``state_row_bytes``: the conv window, and the state where
-        # there is one; read and written once a step);
-        # ``mixer_weight_bytes`` = one layer's mixer weights, by the
-        # model's config (``mixer_params``).
-        self.slots = SlotPool(self.cfg.max_batch)
-        self._state = None
-        self._state_counts: Dict[str, int] = {}
-        if spec.state_layers:
-            self._state = init_state(spec, self.cfg.max_batch,
-                                     model_cfg.dtype)
-            self._state_counts = {
-                "decode_runs": 0, "state_rows_updated": 0,
-                "state_row_bytes": sum(
-                    int(a[0, 0].size) * a.dtype.itemsize
-                    for a in self._state.values()),
-                "mixer_weight_bytes": model_cfg.mixer_params()
-                * np.dtype(model_cfg.param_dtype).itemsize}
+        # Everything a sequence keeps on the device, by layer kind, with
+        # its allocators, tables and counters.  One layer's mixer weights
+        # (stats()["state"], where the cache has a state pool) are a fact
+        # of the model's config.
+        mixer_params = getattr(model_cfg, "mixer_params", None)
+        self.cache = SequenceCache(
+            family.cache(model_cfg), num_pages=self.cfg.num_pages,
+            page_size=self.cfg.page_size, max_batch=self.cfg.max_batch,
+            max_context=self.cfg.max_context, max_seq=model_cfg.max_seq,
+            dtype=model_cfg.dtype,
+            mixer_weight_bytes=mixer_params()
+            * np.dtype(model_cfg.param_dtype).itemsize
+            if mixer_params else 0)
 
         self._fwd = jit_forward(self._model)
         # A config with a residual kind (models/decoder.py Residual):
@@ -497,33 +445,6 @@ class GenerationEngine:
         # search; the others were argmax alone (stats()["sampling"]).
         self._sampling = {"rows_greedy": 0, "rows_sampled": 0,
                           "steps": 0, "steps_sampled": 0}
-        # What the decode steps' attention reads of the pool, counted
-        # from the packed rows (stats()["attention"]): ``kv_rows_read``
-        # is what the paged-decode kernel's copies move (each running
-        # row's whole pages up to its length, this step's token
-        # included, times the layers; one row = what one position of
-        # one layer occupies in the pool, ``kv_row_bytes``: its K and
-        # its V, or its one latent row with the padding, whose widths
-        # are then beside it as ``latent_dim`` / ``rope_dim``),
-        # ``kv_rows_held`` what a gather of every row's whole page table
-        # moves (max_batch x pages_per_seq x page_size x layers a run).
-        # With window layers both count BOTH groups, each by what its
-        # layers read (a window layer ``min(n_cached + 1, window)`` rows,
-        # in whole pages) and hold (its ring), and the window group's part
-        # is beside them: ``window_rows_read``, ``window_rows_held``, and
-        # ``window_positions_dropped``, the rows a layer that kept every
-        # position would have read and these did not.
-        self._attention = {
-            "decode_runs": 0, "kv_rows_read": 0, "kv_rows_held": 0,
-            "kv_row_bytes": sum(a.shape[-1] * a.dtype.itemsize
-                                for name, a in self._kv.items()
-                                if name not in WINDOW_ARRAYS),
-            **({"latent_dim": spec.latent_dim, "rope_dim": spec.rope_dim}
-               if spec.latent_dim else {}),
-            **({"window": spec.window, "window_layers": spec.window_layers,
-                "window_rows_read": 0, "window_rows_held": 0,
-                "window_positions_dropped": 0}
-               if spec.window_layers else {})}
         # A step's leaves are handed over with the step's own time in
         # one go, under the lock: a stats() taken mid-step still sums up.
         self._phases = Phases(PHASE_LEAVES, "llm.other", lock=self._lock,
@@ -609,10 +530,10 @@ class GenerationEngine:
             raise ValueError("empty prompt")
         if any(t < 0 or t >= self.model_cfg.vocab_size for t in prompt):
             raise ValueError("prompt token out of vocab range")
-        if len(prompt) + 1 > self.max_context:
+        if len(prompt) + 1 > self.cache.max_context:
             raise ValueError(
                 f"prompt of {len(prompt)} tokens exceeds the engine's "
-                f"max context {self.max_context}")
+                f"max context {self.cache.max_context}")
         if params is not None:
             params.validate()
         sid = next(self._ids)
@@ -709,15 +630,11 @@ class GenerationEngine:
                 **self._phases.totals(),
                 "prefills": self._prefills,
                 "compiles": self._compiles,
-                "kv_pages_used": self.pool.used,
-                "kv_pages_total": self.pool.num_pages,
-                # ... by group, where the K/V pool has two
-                **({"kv_pages": {
-                    "full": {"used": self.pool.used,
-                             "total": self.pool.num_pages},
-                    "window": {"used": self.window_pool.used,
-                               "total": self.window_pool.num_pages}}}
-                   if self.window_pool else {}),
+                # What the sequences hold of the device's caches and what
+                # the decode steps read of it: ``kv_pages_used`` /
+                # ``_total`` (``kv_pages`` by group, where there are two),
+                # ``attention``, and ``state`` where there is a state pool.
+                **self.cache.stats(),
                 "running": len(self._running),
                 "waiting": len(self._waiting),
                 "steps": self._steps,
@@ -727,14 +644,13 @@ class GenerationEngine:
                 # a power-of-two bucket costs is the difference
                 "prefill_bucket_tokens": self._prefill_bucket_tokens,
                 "evictions": self._evictions,
-                "max_context": self.max_context,
+                "max_context": self.cache.max_context,
                 "step_errors": self._step_errors,
                 "last_error": self._last_error,
                 # Compiled programs (forwards, the sampler, the
                 # placement of a prefill's row) by name -> compile seconds.
                 "programs": dict(self._compile_seconds),
                 "sampling": dict(self._sampling),
-                "attention": dict(self._attention),
                 # The depth-one pipeline: programs launched while
                 # another's ids were unread, drains by cause, launched
                 # rows whose result was dropped (EOS, cancellation).
@@ -775,12 +691,6 @@ class GenerationEngine:
                 # doubly stochastic over its live rows.
                 **({"residual": dict(self._residual)}
                    if self._residual else {}),
-                # The second kind of cache (absent for a model without
-                # recurrent layers).
-                **({"state": {"slots_total": self.slots.slots,
-                              "slots_used": self.slots.used,
-                              **self._state_counts}}
-                   if self._state is not None else {}),
             }
 
     # ------------------------------------------------------ engine loop
@@ -893,29 +803,13 @@ class GenerationEngine:
                 # Always make progress when nothing is running yet.
                 if cost > budget and self._running:
                     return
-                n_pages = pages_for(len(seq.tokens), self.cfg.page_size)
-                if n_pages > self.pool.num_pages:
-                    self._waiting.popleft()
-                    oversized = seq
-                else:
-                    pages = self.pool.alloc(n_pages)
-                    if pages is None:
-                        return      # wait for frees/retirements
-                    # ... and, of the window group, its whole ring
-                    ring = self.window_pool.alloc(self._ring_pages) \
-                        if self.window_pool else []
-                    slot = None if ring is None else self.slots.take()
-                    if slot is None:    # as many slots, and rings, as rows
-                        self.pool.free(pages)
-                        if ring:
-                            self.window_pool.free(ring)
-                        return
-                    self._waiting.popleft()
-                    seq.pages, seq.ring, seq.slot = pages, ring, slot
-                    oversized = None
-            if oversized is not None:
-                self._retire(oversized,
-                             error="sequence exceeds KV pool capacity")
+                oversized = not self.cache.fits(len(seq.tokens))
+                if not oversized \
+                        and not self.cache.take(seq.held, len(seq.tokens)):
+                    return      # wait for frees/retirements
+                self._waiting.popleft()
+            if oversized:
+                self._retire(seq, error="sequence exceeds KV pool capacity")
                 continue
             budget -= cost
             try:
@@ -929,24 +823,11 @@ class GenerationEngine:
                 self._retire(seq, error=repr(e))
                 raise
 
-    def _tables(self, rows: List[tuple], n_rows: int) -> tuple:
-        """The page tables of a launch, one a group: ``rows`` are
-        (sequence, its row) pairs, the other rows zeros."""
-        table = np.zeros((n_rows, self._pages_per_seq), np.int32)
-        for seq, row in rows:
-            table[row, :len(seq.pages)] = seq.pages
-        if not self.window_pool:
-            return (table,)
-        rings = np.zeros((n_rows, self._ring_pages), np.int32)
-        for seq, row in rows:
-            rings[row] = seq.ring
-        return table, rings
-
     def _call_fwd(self, kind: str, tokens, tables, positions, slots,
                   **served):
         """The forward of this token shape (``llm_decode``, or
         ``llm_prefill[bucket]``) over the caches, which it updates
-        (``tables``: ``_tables``'s):
+        (``tables``: the cache's, of this launch):
         returns (the program's name, logits, (the routing counters or None,
         the residual kind's or None)).  ``slots`` is
         each row's slot of the state pool (a row without a sequence:
@@ -955,18 +836,10 @@ class GenerationEngine:
         are then that one position's."""
         name = f"llm_{kind}[{tokens.shape[1]}]" \
             if kind == "prefill" else f"llm_{kind}"
-        args = (self._params, tokens, *self._kv.values(), *tables,
-                positions)
-        if self._state is not None:
-            args += (*self._state.values(), slots)
-        logits, *rest = self._call(self._fwd, name, *args, **served)
-        n = len(self._kv)
-        self._kv = dict(zip(self._kv, rest[:n]))
-        rest = rest[n:]
-        if self._state is not None:
-            n = len(self._state)
-            self._state = dict(zip(self._state, rest[:n]))
-            rest = rest[n:]
+        logits, *rest = self._call(
+            self._fwd, name, self._params, tokens,
+            *self.cache.args(tables, positions, slots), **served)
+        rest = self.cache.take_back(rest)
         residual = rest.pop() if self._residual else None
         return name, logits, (rest[0] if rest else None, residual)
 
@@ -1034,7 +907,7 @@ class GenerationEngine:
         model's max_seq.  Asked of the launched count at launch and of
         the delivered count at delivery."""
         return count >= seq.max_tokens \
-            or seq.prompt_len + count - 1 >= self.max_context
+            or seq.prompt_len + count - 1 >= self.cache.max_context
 
     def _launched(self, seq: _Sequence) -> None:
         """Count the program just launched for ``seq``.  At its length
@@ -1046,7 +919,7 @@ class GenerationEngine:
             with self._lock:
                 if seq in self._running:
                     self._running.remove(seq)
-            self._release(seq)
+            self.cache.release(seq.held)
 
     def _launch(self, flight: _Flight) -> None:
         """``flight`` is on the device's queue: now read the ids of the
@@ -1191,16 +1064,16 @@ class GenerationEngine:
             positions = np.full((1, pad), -1, np.int32)
             positions[0, :n] = np.arange(n)
             flight_rows = [(seq, 0)]
-            tables = self._tables(flight_rows, 1)
+            tables = self.cache.tables(flight_rows, 1)
             sampling = self._pack_sampling(flight_rows)
             # its id, row 0 of the sampler's, to its own row
             feed_to = np.full(self.cfg.max_batch, self.cfg.max_batch,
                               np.int32)
-            feed_to[0] = seq.slot
+            feed_to[0] = seq.held.slot
         with self._phase("llm.prefill.run") as launch:
             name, logits, counters = self._call_fwd(
                 "prefill", tokens, tables, positions,
-                np.asarray([seq.slot], np.int32),
+                np.asarray([seq.held.slot], np.int32),
                 last=np.asarray([n - 1], np.int32))
             ids = self._call(
                 self._sampler, "llm_sample",
@@ -1236,34 +1109,12 @@ class GenerationEngine:
         with self._phase("llm.decode.pack"):
             positions = np.full((B, 1), -1, np.int32)
             slots = np.full(B, B, np.int32)
-            flight_rows = [(seq, seq.slot) for seq in batch]
-            tables = self._tables(flight_rows, B)
-            spec, page = self._cache_spec, self.cfg.page_size
-            pages_read = ring_read = 0
-            for seq in batch:
-                slots[seq.slot] = seq.slot
-                positions[seq.slot, 0] = seq.n_cached
-                pages_read += pages_for(seq.n_cached + 1, page)
-                if spec.window_layers:
-                    ring_read += pages_for(
-                        min(seq.n_cached + 1, spec.window), page)
-            rows = page * spec.kv_layers
-            counts = self._attention
-            counts["decode_runs"] += 1
-            counts["kv_rows_read"] += pages_read * rows
-            counts["kv_rows_held"] += tables[0].size * rows
-            if spec.window_layers:
-                rows = page * spec.window_layers
-                counts["kv_rows_read"] += ring_read * rows
-                counts["kv_rows_held"] += tables[1].size * rows
-                counts["window_rows_read"] += ring_read * rows
-                counts["window_rows_held"] += tables[1].size * rows
-                counts["window_positions_dropped"] += \
-                    (pages_read - ring_read) * rows
-            if self._state_counts:
-                self._state_counts["decode_runs"] += 1
-                self._state_counts["state_rows_updated"] += \
-                    len(batch) * self._cache_spec.state_layers
+            flight_rows = [(seq, seq.held.slot) for seq in batch]
+            tables = self.cache.tables(flight_rows, B)
+            for seq, row in flight_rows:
+                slots[row] = row
+                positions[row, 0] = seq.n_cached
+            self.cache.count_decode(positions)
             sampling = self._pack_sampling(flight_rows)
         with self._phase("llm.decode.run") as launch:
             name, logits, counters = self._call_fwd(
@@ -1290,12 +1141,7 @@ class GenerationEngine:
         """Guarantee a KV slot for position ``seq.n_cached``; on pool
         exhaustion evict the most recently admitted other sequence
         (recompute preemption) and retry, if ``evict`` allows."""
-        needed = seq.n_cached // self.cfg.page_size + 1
-        while len(seq.pages) < needed:
-            pages = self.pool.alloc(1)
-            if pages is not None:
-                seq.pages.extend(pages)
-                continue
+        while not self.cache.grow(seq.held, seq.n_cached):
             if not evict:
                 return False
             victim = None
@@ -1324,24 +1170,10 @@ class GenerationEngine:
             if victim in self._running:
                 self._running.remove(victim)
             self._waiting.appendleft(victim)
-        self._release(victim)
+        self.cache.release(victim.held)
         victim.n_cached = 0
         self._evictions += 1
         self._count("evictions")
-
-    def _release(self, seq: _Sequence) -> None:
-        """Give back what ``seq`` holds of the device's caches: its pages
-        (of both groups, where the pool has two) and its slot (at its last
-        launch, retirement, cancellation and eviction alike: a re-prefill
-        rebuilds the state from position 0).  A second call finds nothing
-        to give."""
-        self.pool.free(seq.pages)
-        seq.pages = []
-        if seq.ring:
-            self.window_pool.free(seq.ring)
-            seq.ring = []
-        self.slots.give(seq.slot)
-        seq.slot = None
 
     def _emit_token(self, seq: _Sequence, tok: int) -> None:
         """The host's bookkeeping for one token the device chose."""
@@ -1376,7 +1208,7 @@ class GenerationEngine:
         if seq.finished:
             return
         seq.finished = True
-        self._release(seq)
+        self.cache.release(seq.held)
         self._seqs.pop(seq.sid, None)
         if seq.first_token_ts is not None and \
                 seq.last_token_ts is not None and seq.generated > 1:

@@ -1,102 +1,55 @@
-"""Paged KV cache — fixed-size pages from one preallocated device pool.
+"""What a sequence keeps on the device between steps, and the one object
+that owns it: ``SequenceCache``.
 
-vLLM-style memory management adapted to JAX/TPU: the K/V history of
-every running sequence lives in ONE device buffer per model (K and V
-each [n_layer, num_pages, page_size, n_kv_head * head_dim]), carved
-into fixed-size pages.  The heads are FOLDED into the minor dimension:
-with a 64-wide head last, the TPU tiles the pool page-minor and every
-scatter and gather pays a transpose of the whole layer; a page that is
-a row-major [page_size, h_kv*d] tile is updated where it lies.  A
-sequence maps logical token positions to physical pages through its
-page table (position p lives in page ``table[p // page_size]`` at slot
-``p % page_size``), so sequences grow without reallocation or copying,
-free pages are recycled at step granularity, and fragmentation is
-bounded by one partial page per sequence.  Because the pool shape is static, the jitted decode step
-compiles once — admission/retirement only edits page tables and host
-accounting.
+A sequence's HOLDING is of three kinds, and a model's ``models.CacheSpec``
+says which of them its layers need:
 
-Three functions implement the data path, called from the one attention
-core (``models/attention.py``, cached branch), once a layer each:
-``paged_store`` scatters fresh K/V into pages; then, for a decode step
-(one query row a sequence) on the ``tpu`` backend,
-``ops/paged_attention.py paged_decode``, a Pallas kernel, reads each
-sequence's pages where they lie, through the page table and up to the
-sequence's length; for a prefill, and on every other backend,
-``paged_attend`` (pure jnp, here) gathers the batch's pages and runs
-masked attention: it is the kernel's plain definition and what the
-kernel is tested against.  All three take the WHOLE pool and a layer
-index, and the forward carries that one pool from layer to layer:
-slicing a layer out and stacking the layers again makes XLA build a new
-pool beside the donated one.
+- PAGES OF A GROUP of layers, vLLM-style: the group's history lives in
+  preallocated device buffers carved into fixed-size pages ([layers,
+  pages, page_size, row]), and a sequence maps position ``p`` to page
+  ``table[p // page_size]``, slot ``p % page_size`` through its page
+  table, so it grows a page at a time without copying and the jitted
+  step compiles once for the static pool.  A row is a layer's K and V of
+  one position with the heads FOLDED into the minor dimension
+  (``k_pages`` / ``v_pages``, row ``h_kv * d``: with a 64-wide head last
+  the TPU tiles the pool page-minor and every scatter pays a transpose),
+  or, for latent attention, the ONE row every head shares
+  (``latent_pages``: ``c_kv`` then ``k_pe`` then zeros up to whole tiles
+  of 128 lanes, ``CacheSpec.row_width``; the store, the kernel and
+  ``kv_row_bytes`` share that padding).
+- A RING: the layers of the window group see the last ``window``
+  positions, kept at ring row ``p mod window`` in ``window_k_pages`` /
+  ``window_v_pages`` through ``window_table``
+  (``models/attention.py _ring``).  A sequence takes its whole ring with
+  its first pages and gives it back with them, so that group's pool is
+  ``max_batch`` rings and what it holds stops growing at the window.
+- A SLOT: its row of the decode batch and of the device's token array,
+  for every model, which it keeps while it runs; and, where the layers
+  are recurrent, of the state pool: ``conv`` [state layers, slots, ...]
+  in the model's dtype and ``ssm`` [state layers, slots, ...] float32,
+  whichever the spec gives a shape (``state_arrays``), of fixed size
+  whatever the sequence's length.  A slot is not cleared when it changes
+  hands: a forward from position 0 starts from zeros.
 
-A model with recurrent layers (``models/granite.py``: Mamba-2 mixers)
-keeps a SECOND kind of cache beside the pages: one slot a sequence in the
-state pool, ``conv`` [state layers, slots, d_conv-1, conv_dim] (the conv
-window, in the model's dtype) and ``ssm`` [state layers, slots, H, P, N]
-(float32), of fixed size whatever the sequence's length
-(``init_state``).  A model whose recurrent layers keep a window and NO
-state (``models/lfm2.py``: short-conv mixers, ``ssm_shape == ()``) has a
-state pool of the one array ``conv``: ``state_arrays`` names what a
-spec's pool holds, and the engine carries, donates and aliases those and
-nothing else.  The K/V pool is then built for the attention layers
-only.  The model reads and writes a row's slot where it lies, through a
-``[B]`` slot index (an index outside the pool: a padded row, nothing
-changed), and carries both arrays whole through its layers as the K/V
-pool is carried; ``SlotPool`` is the host-side allocator, a sequence
-takes a slot with its first pages and gives it back with them.  The slot
-is also the sequence's row of the decode batch, for every model: a
-sequence keeps its row while it runs, so the ids one decode step leaves
-on the device are the next step's tokens row for row (llm/engine.py).
+The kinds are independent; a spec may ask for any mix (K/V; K/V with
+``conv`` and ``ssm``, or ``conv`` alone; latent; latent with a state;
+K/V in two groups).  ``pool_arrays``, ``pool_tables`` and
+``state_arrays`` name a spec's arrays and tables in the one order
+``llm/engine.py jit_forward`` takes and returns them: the paged arrays,
+the tables, the positions, then the state arrays and the slots; every
+array is donated, carried WHOLE from layer to layer with a layer index
+(slicing a layer out and stacking again makes XLA build a second pool),
+and updated where it lies.  ``PagePool`` (one a group) and ``SlotPool``
+are the host-side allocators, with occupancy gauges.
 
-A model with LATENT attention (``models/kimi.py``: MLA) keeps ONE pool
-and no V pool beside it: ``latent_pages`` [layers, pages, page, row],
-where a position's row of a layer is the compressed vector every head
-shares, ``c_kv`` (``latent_dim`` numbers, after its norm), then the one
-rotary key ``k_pe`` (``rope_dim`` numbers, after RoPE), then zeros up to
-whole tiles of 128 lanes (512 + 64 -> 640: ``CacheSpec.row_width``; the
-padding is the pool's, the store's and the kernel's one shared
-decision, and ``kv_row_bytes`` counts it).  ``pool_arrays`` names what a
-spec's paged pool holds and ``init_pool`` builds it; ``latent_store``
-scatters the rows; a decode step attends IN the latent space
-(``ops/paged_attention.py paged_decode_latent`` on the ``tpu`` backend:
-the row is read once, for the scores and, its first ``latent_dim``
-lanes, for the values; ``latent_attend`` here is its plain definition);
-a prefill starts at position 0 and needs nothing from the pool
-(``models/attention.py latent_attention``).
-
-The two kinds of pool are independent, and a model may have BOTH
-(``models/kimi_linear.py``: latent attention in 7 layers, delta-rule
-mixers in 20): its spec has ``latent_dim`` > 0, so the paged pool is the
-one array ``latent_pages`` over the ``kv_layers`` latent layers, AND
-``state_layers`` > 0, so there is a state pool of ``conv`` (the three
-convolutions' windows, [state layers, slots, 3, 3 H d_k] in the model's
-dtype) and ``ssm`` (each head's ``d_k x d_v`` matrix, [state layers,
-slots, H, d_k, d_v] float32: 2 MB a slot a layer at 32 heads of 128).  A
-layer indexes its pool by its number among its own kind; the forward
-takes ``latent_pages``, the page table and the positions, then ``conv``,
-``ssm`` and the slots, and returns the three arrays in that order, all
-donated and aliased (``llm/engine.py jit_forward``).
-
-A model with SLIDING-WINDOW layers (``models/cohere.py``: three layers
-in four see the last 4,096 positions) keeps its K/V in TWO GROUPS of
-layers, each with arrays, a page count, a table and a host allocator of
-its own: the ``kv_layers`` that attend every earlier position hold them
-all, in ``k_pages`` / ``v_pages`` through ``page_table``, as above; the
-``window_layers`` hold a RING of ``window`` positions a sequence, in
-``window_k_pages`` / ``window_v_pages`` [window layers, max_batch x ring
-pages, page, h_kv*d] through ``window_table`` [B, ring pages] (position
-``p`` at ring row ``p mod window``: ``models/attention.py _ring``; the
-store and the decode kernel are the ones above, handed ring positions and
-``min(length, window)``).  A sequence takes its whole ring with its first
-pages and gives it back with them, so the second group's size follows
-from ``max_batch`` and the window alone, and what a window layer holds
-stops growing at the window whatever the sequence's length.  A spec
-without window layers has ONE group, exactly as above.
-
-``PagePool`` is the host-side allocator, one a group; it exports
-``rt_llm_kv_pages_{used,total}`` gauges (tag ``group``: ``full``,
-``window``) on every alloc/free so KV occupancy is visible in ``rt
-telemetry`` and the doctor can see leaks.
+The data path is called from the one attention core
+(``models/attention.py``, cached branch), once a layer: ``paged_store``
+/ ``latent_store`` scatter the new rows; a decode step on the ``tpu``
+backend reads each sequence's pages where they lie
+(``ops/paged_attention.py``); ``paged_attend`` / ``latent_attend`` here
+are the kernels' plain definitions and every other backend's path.  A
+prefill starts at position 0, attends among its own rows and reads
+nothing of the pool.
 """
 
 from __future__ import annotations
@@ -106,6 +59,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def init_cache(n_layer: int, num_pages: int, page_size: int,
@@ -436,3 +390,202 @@ class SlotPool:
             self._gauges[1].set(float(self.slots))
         except Exception:
             pass
+
+
+class Holding:
+    """What one sequence holds of the device's caches: its pages in the
+    group that keeps every position (they grow with it), its ring in the
+    window group (whole or absent) and its slot (None: it is not
+    running).  ``SequenceCache`` fills and empties it."""
+
+    __slots__ = ("pages", "ring", "slot")
+
+    def __init__(self):
+        self.pages: List[int] = []
+        self.ring: List[int] = []
+        self.slot: Optional[int] = None
+
+
+class SequenceCache:
+    """Everything the sequences of one engine keep on the device: the
+    arrays a cache spec asks for (``paged`` then ``state``, in the
+    forward's order), a ``PagePool`` a group (``pools``: ``full``, and
+    ``window`` where the spec has window layers: ``max_batch`` rings of
+    ``ring_pages``), the ``SlotPool`` (``slots``: ``max_batch``), the page
+    tables of a launch and the counters of what the decode steps read and
+    hold.  Called from the engine thread; ``stats`` from any."""
+
+    def __init__(self, spec, *, num_pages: int, page_size: int,
+                 max_batch: int, max_context: Optional[int], max_seq: int,
+                 dtype: Any, mixer_weight_bytes: int = 0):
+        self._spec, self.page_size = spec, page_size
+        self.max_context = min(max_context or max_seq, max_seq,
+                               num_pages * page_size)
+        self.pages_per_seq = pages_for(self.max_context, page_size)
+        self.ring_pages = ring_pages(spec, page_size)
+        self.pools = {"full": PagePool(num_pages, page_size)}
+        if self.ring_pages:
+            self.pools["window"] = PagePool(max_batch * self.ring_pages,
+                                            page_size, group="window")
+        self.slots = SlotPool(max_batch)
+        self.paged = init_pool(spec, num_pages, page_size, dtype,
+                               max_batch * self.ring_pages)
+        self.state = init_state(spec, max_batch, dtype)
+        # What the decode steps' attention reads of the pool
+        # (stats()["attention"]): ``kv_rows_read`` is what the
+        # paged-decode kernel's copies move (each running row's whole
+        # pages up to its length, this step's token included, times the
+        # layers; one row = what one position of one layer occupies,
+        # ``kv_row_bytes``: its K and its V, or its one latent row with
+        # the padding, whose widths are then beside it), ``kv_rows_held``
+        # what a gather of every row's whole page table moves.  With
+        # window layers both count BOTH groups, each by what its layers
+        # read (``min(n_cached + 1, window)`` rows, in whole pages) and
+        # hold (its ring); the window group's part is beside them, with
+        # ``window_positions_dropped``: the rows a layer that kept every
+        # position would have read and these did not.
+        self._attention = {
+            "decode_runs": 0, "kv_rows_read": 0, "kv_rows_held": 0,
+            "kv_row_bytes": sum(a.shape[-1] * a.dtype.itemsize
+                                for name, a in self.paged.items()
+                                if name not in WINDOW_ARRAYS),
+            **({"latent_dim": spec.latent_dim, "rope_dim": spec.rope_dim}
+               if spec.latent_dim else {}),
+            **({"window": spec.window, "window_layers": spec.window_layers,
+                "window_rows_read": 0, "window_rows_held": 0,
+                "window_positions_dropped": 0}
+               if spec.window_layers else {})}
+        # What their recurrent layers move (stats()["state"], absent
+        # without such layers): ``state_rows_updated`` = running rows x
+        # recurrent layers, one row = what one sequence keeps in one such
+        # layer (``state_row_bytes``, read and written once a step);
+        # ``mixer_weight_bytes`` = one layer's mixer weights, the engine's
+        # word.
+        self._state_counts = {
+            "decode_runs": 0, "state_rows_updated": 0,
+            "state_row_bytes": sum(int(a[0, 0].size) * a.dtype.itemsize
+                                   for a in self.state.values()),
+            "mixer_weight_bytes": mixer_weight_bytes} if self.state else {}
+
+    # ------------------------------------------------ a sequence's holding
+    def fits(self, n_tokens: int) -> bool:
+        """Whether a prompt of ``n_tokens`` can ever be taken: False is
+        "larger than the whole pool", not "not now"."""
+        return pages_for(n_tokens, self.page_size) \
+            <= self.pools["full"].num_pages
+
+    def take(self, held: Holding, n_tokens: int) -> bool:
+        """Everything a prompt of ``n_tokens`` needs, or nothing: its
+        pages, then its whole ring, then a slot (as many slots, and
+        rings, as rows); what was taken goes back where a later one is
+        refused."""
+        pool, window = self.pools["full"], self.pools.get("window")
+        pages = pool.alloc(pages_for(n_tokens, self.page_size))
+        if pages is None:
+            return False
+        ring = window.alloc(self.ring_pages) if window else []
+        slot = None if ring is None else self.slots.take()
+        if slot is None:
+            pool.free(pages)
+            if ring:
+                window.free(ring)
+            return False
+        held.pages, held.ring, held.slot = pages, ring, slot
+        return True
+
+    def grow(self, held: Holding, position: int) -> bool:
+        """Room for ``position``'s row (the ring has it already): a page
+        more where it opens one; False where the pool is dry."""
+        while len(held.pages) <= position // self.page_size:
+            page = self.pools["full"].alloc(1)
+            if page is None:
+                return False
+            held.pages.extend(page)
+        return True
+
+    def release(self, held: Holding) -> None:
+        """Give everything back (a re-prefill rebuilds the state from
+        position 0).  A second call finds nothing to give."""
+        self.pools["full"].free(held.pages)
+        held.pages = []
+        if held.ring:
+            self.pools["window"].free(held.ring)
+            held.ring = []
+        self.slots.give(held.slot)
+        held.slot = None
+
+    # ------------------------------------------------------- a launch
+    def tables(self, rows: List[tuple], n_rows: int) -> tuple:
+        """The page tables of a launch, one a group: ``rows`` are (owner,
+        its row) pairs, an owner carrying its ``Holding`` as ``held``; the
+        other rows zeros."""
+        table = np.zeros((n_rows, self.pages_per_seq), np.int32)
+        for owner, row in rows:
+            table[row, :len(owner.held.pages)] = owner.held.pages
+        if not self.ring_pages:
+            return (table,)
+        rings = np.zeros((n_rows, self.ring_pages), np.int32)
+        for owner, row in rows:
+            rings[row] = owner.held.ring
+        return table, rings
+
+    def args(self, tables: tuple, positions, slots) -> tuple:
+        """The forward's arguments after the tokens (``jit_forward``):
+        ``slots`` is each row's slot of the state pool (a row without a
+        sequence: an index outside it), passed where there is one."""
+        args = (*self.paged.values(), *tables, positions)
+        return args + (*self.state.values(), slots) if self.state else args
+
+    def take_back(self, outputs: list) -> list:
+        """Keep the donated arrays a forward returned after its logits,
+        and hand back what is left."""
+        n, m = len(self.paged), len(self.paged) + len(self.state)
+        self.paged = dict(zip(self.paged, outputs[:n]))
+        self.state = dict(zip(self.state, outputs[n:m]))
+        return outputs[m:]
+
+    def count_decode(self, positions) -> None:
+        """Count one decode step by its ``positions`` [max_batch, 1]: a
+        running row's is what it has cached, an idle row's negative."""
+        spec, page, counts = self._spec, self.page_size, self._attention
+        window = spec.window if spec.window_layers else 0
+        running = pages_read = ring_read = 0
+        for cached in positions[:, 0].tolist():
+            if cached >= 0:         # it reads its pages, this token's too
+                running += 1
+                pages_read += -(-(cached + 1) // page)
+                if window:
+                    ring_read += -(-min(cached + 1, window) // page)
+        rows = page * spec.kv_layers
+        counts["decode_runs"] += 1
+        counts["kv_rows_read"] += pages_read * rows
+        counts["kv_rows_held"] += len(positions) * self.pages_per_seq * rows
+        if window:
+            rows = page * spec.window_layers
+            held = len(positions) * self.ring_pages * rows
+            counts["kv_rows_read"] += ring_read * rows
+            counts["kv_rows_held"] += held
+            counts["window_rows_read"] += ring_read * rows
+            counts["window_rows_held"] += held
+            counts["window_positions_dropped"] += \
+                (pages_read - ring_read) * rows
+        if self.state:
+            self._state_counts["decode_runs"] += 1
+            self._state_counts["state_rows_updated"] += \
+                running * spec.state_layers
+
+    def stats(self) -> Dict[str, Any]:
+        """Its part of the engine's stats(): pages used and in all (of
+        the group that keeps every position, then by group where there
+        are two), ``attention``, and ``state`` where there is a state
+        pool."""
+        used = {group: {"used": pool.used, "total": pool.num_pages}
+                for group, pool in self.pools.items()}
+        return {
+            "kv_pages_used": used["full"]["used"],
+            "kv_pages_total": used["full"]["total"],
+            **({"kv_pages": used} if len(used) > 1 else {}),
+            "attention": dict(self._attention),
+            **({"state": {"slots_total": self.slots.slots,
+                          "slots_used": self.slots.used,
+                          **self._state_counts}} if self.state else {})}

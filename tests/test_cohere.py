@@ -236,17 +236,18 @@ def test_the_engine_serves_it_through_both_groups(params):
     forward's (and so the reference's); an eviction's re-prefill reproduces
     it; stats() count what each group's layers read and hold."""
     engine = _engine(params, max_batch=4)
-    assert list(engine._kv) == ["k_pages", "v_pages", "window_k_pages",
+    assert list(engine.cache.paged) == ["k_pages", "v_pages", "window_k_pages",
                                 "window_v_pages"]
-    assert engine._kv["k_pages"].shape == (1, 64, 4, 64)
-    assert engine._kv["window_k_pages"].shape == (3, 4 * 2, 4, 64)
-    assert engine.window_pool.num_pages == 8 and engine._ring_pages == 2
+    assert engine.cache.paged["k_pages"].shape == (1, 64, 4, 64)
+    assert engine.cache.paged["window_k_pages"].shape == (3, 4 * 2, 4, 64)
+    assert engine.cache.pools["window"].num_pages == 8 \
+        and engine.cache.ring_pages == 2
     assert _gauge("rt_llm_kv_pages_total", "window") == 8.0
     assert _gauge("rt_llm_kv_pages_total", "full") == 64.0
     requests = ((PROMPTS[0], 9), (PROMPTS[3], 9))
     seqs = [engine.submit(list(p), max_tokens=n) for p, n in requests]
     engine.step()
-    assert [len(s.ring) for s in seqs] == [2, 2]
+    assert [len(s.held.ring) for s in seqs] == [2, 2]
     assert engine.stats()["kv_pages"]["window"] == {"used": 4, "total": 8}
     assert _gauge("rt_llm_kv_pages_used", "window") == 4.0
     while not all(s.finished for s in seqs):
@@ -274,7 +275,7 @@ def test_the_engine_serves_it_through_both_groups(params):
     assert att["window_rows_read"] == ring * 4 * 3
     assert att["kv_rows_read"] == full * 4 * 1 + ring * 4 * 3
     assert att["window_positions_dropped"] == (full - ring) * 4 * 3
-    per_seq = engine._pages_per_seq
+    per_seq = engine.cache.pages_per_seq
     assert att["window_rows_held"] == 8 * 4 * 2 * 4 * 3
     assert att["kv_rows_held"] == 8 * 4 * (per_seq * 4 + 2 * 4 * 3)
     # an eviction gives the ring back with the pages, and the re-prefill
@@ -284,7 +285,7 @@ def test_the_engine_serves_it_through_both_groups(params):
     out = _run(tight, *requests)
     assert tight.stats()["evictions"] > 0
     assert out == _run(_engine(params), *requests)
-    assert tight.window_pool.used == 0 and tight.pool.used == 0
+    assert [p.used for p in tight.cache.pools.values()] == [0, 0]
 
 
 def test_a_prefill_that_does_not_start_at_position_0_is_refused(params):
@@ -294,8 +295,7 @@ def test_a_prefill_that_does_not_start_at_position_0_is_refused(params):
     engine = _engine(params)
     seq = engine.submit(list(PROMPTS[0]), max_tokens=4)
     engine._waiting.clear()
-    seq.pages, seq.ring = engine.pool.alloc(2), engine.window_pool.alloc(2)
-    seq.slot = engine.slots.take()
+    assert engine.cache.take(seq.held, len(seq.tokens))
     seq.n_cached = 3
     with pytest.raises(ValueError, match="starts at position 0, not 3"):
         engine._prefill(seq)
@@ -321,7 +321,8 @@ def test_a_spec_without_window_layers_builds_the_parents_arrays():
         assert tuple(pool) == pool_arrays(spec)
         assert not any(k.startswith("window_") for k in pool)
     engine = GenerationEngine("llama")
-    assert engine.window_pool is None and "kv_pages" not in engine.stats()
+    assert list(engine.cache.pools) == ["full"]
+    assert "kv_pages" not in engine.stats()
     assert "window" not in engine.stats()["attention"]
     names = list(inspect.signature(jit_forward(
         engine._model).__wrapped__).parameters)

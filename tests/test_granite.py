@@ -201,10 +201,10 @@ def test_a_slot_taken_again_serves_as_a_fresh_engine_does(params):
     cut = engine.submit(list(PROMPTS[1]), max_tokens=30)
     for _ in range(4):
         engine.step()
-    assert engine.stats()["state"]["slots_used"] == 1 and cut.slot == 0
+    assert engine.stats()["state"]["slots_used"] == 1 and cut.held.slot == 0
     engine.cancel(cut.sid)
     engine.step()
-    assert cut.finished and cut.slot is None
+    assert cut.finished and cut.held.slot is None
     assert engine.stats()["state"]["slots_used"] == 0
     assert _run(engine, (PROMPTS[0], 9)) == [again[0]]
 
@@ -259,7 +259,7 @@ def test_engine_counts_what_the_state_and_the_experts_moved(params):
                for n in (len(PROMPTS[0]), len(PROMPTS[1]))
                for i in range(1, 5))
     assert att["kv_rows_read"] == want
-    assert att["kv_rows_held"] == runs * 4 * engine._pages_per_seq * 4
+    assert att["kv_rows_held"] == runs * 4 * engine.cache.pages_per_seq * 4
     assert "state" not in GenerationEngine(model="olmoe").stats()
 
 

@@ -352,8 +352,8 @@ def test_engine_holds_one_latent_pool_and_counts_what_a_row_is(params):
     engine = GenerationEngine(
         model_cfg=CFG, params=params, engine_cfg=EngineConfig(
             page_size=4, num_pages=64, max_batch=2))
-    assert list(engine._kv) == ["latent_pages"]
-    assert engine._kv["latent_pages"].shape == (3, 64, 4, 128)
+    assert list(engine.cache.paged) == ["latent_pages"]
+    assert engine.cache.paged["latent_pages"].shape == (3, 64, 4, 128)
     seqs = [engine.submit(list(p), max_tokens=5) for p in PROMPTS[:2]]
     while not all(s.finished for s in seqs):
         engine.step()

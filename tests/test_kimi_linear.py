@@ -408,12 +408,12 @@ def test_engine_holds_both_pools_and_counts_what_each_moved(params):
     4 KDA layers, and stats() carries ["attention"] (a latent row) and
     ["state"] (a window and a float32 matrix a head) together."""
     engine = _engine(params, max_batch=4)
-    assert list(engine._kv) == ["latent_pages"]
-    assert engine._kv["latent_pages"].shape == (2, 64, 4, 128)
-    assert set(engine._state) == {"conv", "ssm"}
-    assert engine._state["conv"].shape == (4, 4, 3, 192)
-    assert engine._state["ssm"].shape == (4, 4, 4, 16, 16)
-    assert engine._state["ssm"].dtype == jnp.float32
+    assert list(engine.cache.paged) == ["latent_pages"]
+    assert engine.cache.paged["latent_pages"].shape == (2, 64, 4, 128)
+    assert set(engine.cache.state) == {"conv", "ssm"}
+    assert engine.cache.state["conv"].shape == (4, 4, 3, 192)
+    assert engine.cache.state["ssm"].shape == (4, 4, 4, 16, 16)
+    assert engine.cache.state["ssm"].dtype == jnp.float32
     _run(engine, (PROMPTS[0], 5), (PROMPTS[1], 5))
     stats = engine.stats()
     state, moe, att = stats["state"], stats["moe"], stats["attention"]

@@ -126,10 +126,10 @@ def test_a_slot_that_changes_hands_is_not_read_by_its_next_owner(params):
     cut = engine.submit(list(PROMPTS[1]), max_tokens=30)
     for _ in range(4):
         engine.step()
-    assert engine.stats()["state"]["slots_used"] == 1 and cut.slot == 0
+    assert engine.stats()["state"]["slots_used"] == 1 and cut.held.slot == 0
     engine.cancel(cut.sid)
     engine.step()
-    assert cut.finished and cut.slot is None
+    assert cut.finished and cut.held.slot is None
     assert _run(engine, (PROMPTS[0], 9)) == [again[0]]
     requests = ((PROMPTS[0], 20), (PROMPTS[2], 20))
     tight = _engine(params, num_pages=10)
@@ -145,8 +145,8 @@ def test_engine_counts_a_state_pool_that_is_a_window_alone(params):
     + the taps.  stats()["moe"]: ``layer_runs`` counts the 3 layers that
     HAVE experts of the 5; the K/V rows the ONE attention layer."""
     engine = _engine(params, max_batch=4)
-    assert set(engine._state) == {"conv"}
-    assert engine._state["conv"].shape == (4, 4, 2, 64)
+    assert set(engine.cache.state) == {"conv"}
+    assert engine.cache.state["conv"].shape == (4, 4, 2, 64)
     for prompt in PROMPTS[:2]:
         engine.submit(list(prompt), max_tokens=5)
     engine.step()

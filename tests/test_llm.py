@@ -321,6 +321,188 @@ def test_pages_for():
     assert pages_for(0, 16) == 1   # a sequence always holds >=1 page
 
 
+# ------------------------ the one object over what a sequence holds
+
+# One family a layout of the device's caches: K/V; K/V + conv + ssm;
+# K/V + conv; latent; latent + a state; K/V in two groups.
+LAYOUTS = {"kv": "gpt2", "kv_conv_ssm": "granitemoehybrid",
+           "kv_conv": "lfm2moe", "latent": "kimik2",
+           "latent_state": "kimilinear", "kv_two_groups": "cohere2moe"}
+
+
+def _sequence_cache(layout, num_pages=10, max_batch=2):
+    """A cache of pages of 4 over ``layout``'s tiny spec (a context of
+    ``num_pages`` pages), and the spec."""
+    from ray_tpu.llm.kv_cache import SequenceCache
+    from ray_tpu.models import MODEL_FAMILIES
+
+    fam = MODEL_FAMILIES[LAYOUTS[layout]]
+    cfg = fam.tiny()
+    spec = fam.cache(cfg)
+    return SequenceCache(spec, num_pages=num_pages, page_size=4,
+                         max_batch=max_batch, max_context=None,
+                         max_seq=cfg.max_seq, dtype=cfg.dtype,
+                         mixer_weight_bytes=7), spec
+
+
+def _in_use(cache):
+    return [pool.used for pool in cache.pools.values()] + [cache.slots.used]
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_sequence_cache_takes_everything_or_nothing(layout):
+    """Pages, then the ring, then a slot: a refusal at any of the three
+    leaves nothing taken; "larger than the pool" is told from "not now";
+    a page more only where a position opens one; everything goes back
+    once, and a second give-back gives nothing."""
+    from ray_tpu.llm.kv_cache import Holding
+
+    cache, spec = _sequence_cache(layout)
+    ring = 2 if spec.window_layers else 0
+    assert (cache.max_context, cache.pages_per_seq) == (40, 10)
+    assert cache.ring_pages == ring
+    assert list(cache.pools) == ["full"] + ["window"] * bool(ring)
+    nothing = [0] * len(cache.pools) + [0]
+    a, b, c = Holding(), Holding(), Holding()
+    # larger than the whole pool: never; the whole pool: now
+    assert not cache.fits(41) and cache.fits(40)
+    assert cache.take(a, 23)                    # 6 pages of 10
+    assert (len(a.pages), len(a.ring), a.slot) == (6, ring, 0)
+    # pages refused: it fits, but not now
+    assert cache.fits(20) and not cache.take(b, 20)
+    assert (b.pages, b.ring, b.slot) == ([], [], None)
+    assert _in_use(cache) == [6] + [ring] * bool(ring) + [1]
+    if ring:
+        # pages taken, ring refused: the pages go back
+        rest = cache.pools["window"].alloc(2)
+        assert not cache.take(b, 8) and _in_use(cache) == [6, 4, 1]
+        cache.pools["window"].free(rest)
+    # pages (and ring) taken, slot refused: both go back
+    spare = cache.slots.take()
+    assert not cache.take(b, 8)
+    assert (b.pages, b.ring, b.slot) == ([], [], None)
+    assert _in_use(cache) == [6] + [ring] * bool(ring) + [2]
+    cache.slots.give(spare)
+    assert cache.take(b, 8) and b.slot == 1     # 2 pages more, the last slot
+    assert not cache.take(c, 4)                 # as many rings, slots as rows
+    assert _in_use(cache) == [8] + [4] * bool(ring) + [2]
+    # room for a position: within its pages nothing, past them one page
+    assert cache.grow(b, 7) and len(b.pages) == 2
+    assert cache.grow(b, 8) and len(b.pages) == 3
+    assert cache.grow(b, 12) and len(b.pages) == 4
+    assert not cache.grow(b, 16) and len(b.pages) == 4      # dry
+    assert len(b.ring) == ring                  # whole from the start
+    for held in (a, b, a, b, c):                # the second time: nothing
+        cache.release(held)
+        assert (held.pages, held.ring, held.slot) == ([], [], None)
+    assert _in_use(cache) == nothing
+    assert cache.take(c, 40) and c.slot == 0    # lowest slot first
+
+
+class _Owner:
+    def __init__(self, held):
+        self.held = held
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_sequence_cache_hands_the_forward_its_arguments(layout):
+    """The arrays a spec names, in the forward's order; the tables of a
+    launch, one a group, zeros for the rows without a sequence; the
+    donated arrays taken back from a forward's outputs, the rest
+    returned; its stats() keys by layout."""
+    from ray_tpu.llm.kv_cache import (Holding, pool_arrays, pool_tables,
+                                      state_arrays)
+
+    cache, spec = _sequence_cache(layout, max_batch=3)
+    assert tuple(cache.paged) == pool_arrays(spec)
+    assert tuple(cache.state) == state_arrays(spec)
+    assert all(a.shape[:2] == (spec.state_layers, 3)
+               for a in cache.state.values())
+    groups = {"k_pages": (spec.kv_layers, 10), "v_pages": (spec.kv_layers,
+                                                           10),
+              "latent_pages": (spec.kv_layers, 10),
+              "window_k_pages": (spec.window_layers, 3 * cache.ring_pages),
+              "window_v_pages": (spec.window_layers, 3 * cache.ring_pages)}
+    for name, a in cache.paged.items():
+        assert a.shape[:3] == groups[name] + (4,)
+    owners = [_Owner(Holding()), _Owner(Holding())]
+    assert cache.take(owners[0].held, 9) and cache.take(owners[1].held, 2)
+    tables = cache.tables([(owners[0], 2), (owners[1], 0)], 3)
+    assert len(tables) == len(pool_tables(spec))
+    table = tables[0]
+    assert table.shape == (3, cache.pages_per_seq) and table.dtype == np.int32
+    assert table[2, :3].tolist() == owners[0].held.pages
+    assert table[0, :1].tolist() == owners[1].held.pages
+    assert not table[1].any() and not table[2, 3:].any()
+    if spec.window_layers:
+        rings = tables[1]
+        assert rings.shape == (3, cache.ring_pages)
+        assert rings[2].tolist() == owners[0].held.ring
+        assert not rings[1].any()
+    one, = cache.tables([(owners[0], 0)], 1)[:1]
+    assert one.shape == (1, cache.pages_per_seq)
+    args = cache.args(tables, "positions", "slots")
+    paged, state = list(cache.paged.values()), list(cache.state.values())
+    want = paged + list(tables) + ["positions"] \
+        + (state + ["slots"] if state else [])
+    assert len(args) == len(want)
+    assert all(x is y for x, y in zip(args, want))
+    # outputs after the logits: the donated arrays, then what is left
+    new = [object() for _ in paged + state]
+    assert cache.take_back(new + ["moe", "residual"]) == ["moe", "residual"]
+    assert list(cache.paged.values()) + list(cache.state.values()) == new
+    assert tuple(cache.paged) == pool_arrays(spec)
+    assert cache.take_back(new) == []
+    stats = cache.stats()
+    assert set(stats) == {"kv_pages_used", "kv_pages_total", "attention"} \
+        | ({"kv_pages"} if spec.window_layers else set()) \
+        | ({"state"} if spec.state_layers else set())
+    assert (stats["kv_pages_used"], stats["kv_pages_total"]) == (4, 10)
+    if spec.state_layers:
+        assert stats["state"]["slots_used"] == 2
+        assert stats["state"]["mixer_weight_bytes"] == 7
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_sequence_cache_counts_a_decode_step_by_its_positions(layout):
+    """What a decode step reads, holds and updates, counted from the
+    step's positions alone: the closed forms over the running rows, by
+    ``pages_for``."""
+    from ray_tpu.llm.kv_cache import pages_for
+
+    cache, spec = _sequence_cache(layout, num_pages=64, max_batch=5)
+    page, want = 4, {}
+    steps = ([0, -1, 3, -1, 4], [7, 8, -1, 11, 12], [-1, 31, -1, -1, -1],
+             [255, 1, 2, 3, 200])
+    for cached in steps:
+        positions = np.full((5, 1), -1, np.int32)
+        positions[:, 0] = cached
+        cache.count_decode(positions)
+        rows = [n for n in cached if n >= 0]
+        full = sum(pages_for(n + 1, page) for n in rows)
+        adds = {"decode_runs": 1,
+                "kv_rows_read": full * page * spec.kv_layers,
+                "kv_rows_held":
+                    5 * cache.pages_per_seq * page * spec.kv_layers}
+        if spec.window_layers:
+            kept = sum(pages_for(min(n + 1, spec.window), page)
+                       for n in rows)
+            per = page * spec.window_layers
+            held = 5 * cache.ring_pages * per
+            adds["kv_rows_read"] += kept * per
+            adds["kv_rows_held"] += held
+            adds.update(window_rows_read=kept * per, window_rows_held=held,
+                        window_positions_dropped=(full - kept) * per)
+        if spec.state_layers:
+            adds["state_rows_updated"] = len(rows) * spec.state_layers
+        for key, n in adds.items():
+            want[key] = want.get(key, 0) + n
+    stats = cache.stats()
+    got = {**stats["attention"], **stats.get("state", {})}
+    assert {key: got[key] for key in want} == want
+    assert all(type(got[key]) is int for key in want)
+
+
 # ----------------------------------------------- decode-mode identity
 
 

@@ -119,21 +119,21 @@ def test_cancel_mid_stream_frees_kv_pages_to_baseline(setup):
         return None
 
     eng, _ = setup
-    baseline = eng.pool.used
+    baseline = eng.cache.pools["full"].used
     assert baseline == 0
     seq = eng.submit([1, 2, 3], max_tokens=500)
     it = eng.frames(seq)
     next(it)
     next(it)
-    assert eng.pool.used > baseline       # pages held mid-stream
+    assert eng.cache.pools["full"].used > baseline   # held mid-stream
     eng.cancel(seq.sid)
     frames = list(it)
     assert frames[-1] == {"done": True, "reason": "cancelled",
                           "n_tokens": seq.generated}
     deadline = time.time() + 10
-    while time.time() < deadline and eng.pool.used != baseline:
+    while time.time() < deadline and eng.cache.pools["full"].used != baseline:
         time.sleep(0.05)
-    assert eng.pool.used == baseline
+    assert eng.cache.pools["full"].used == baseline
     assert gauge() == float(baseline)
     assert eng.stats()["running"] == 0
 
@@ -273,7 +273,7 @@ def test_submit_rejects_bad_requests(setup):
     with pytest.raises(ValueError):
         eng.submit([CFG.vocab_size + 5])
     with pytest.raises(ValueError):
-        eng.submit(list(range(eng.max_context)))   # no room to decode
+        eng.submit(list(range(eng.cache.max_context)))   # no room to decode
     with pytest.raises(ValueError):
         eng.submit([1], params=SamplingParams(top_p=2.0))
 
@@ -397,7 +397,7 @@ def test_pipelined_batch_gives_what_each_request_gives_alone(family):
     assert 1 <= drains <= 3
     assert pipe["launched_ahead"] - was["launched_ahead"] >= \
         programs - 2 * drains - 1
-    assert after["kv_pages_used"] == 0 and eng.slots.used == 0
+    assert after["kv_pages_used"] == 0 and eng.cache.slots.used == 0
     assert after["tokens_generated"] - before["tokens_generated"] == \
         sum(n for _, n in MIXED)
 
@@ -430,8 +430,9 @@ def test_eos_mid_batch_costs_one_row_step_and_frees_the_row():
     stats = eng.stats()
     # one launched row dropped for every stream an EOS cut short
     assert stats["pipeline"]["rows_discarded"] == cut_short
-    assert stats["kv_pages_used"] == 0 and eng.pool.available == 64
-    assert eng.slots.used == 0 and stats["state"]["slots_used"] == 0
+    assert stats["kv_pages_used"] == 0
+    assert eng.cache.pools["full"].available == 64
+    assert eng.cache.slots.used == 0 and stats["state"]["slots_used"] == 0
     assert stats["running"] == stats["waiting"] == 0
 
 
@@ -447,7 +448,7 @@ def test_cancel_with_a_program_in_flight_drops_its_row():
     eng.cancel(cut.sid)
     eng.step()
     # retired at once, pages and row back, nothing of the launched step
-    assert cut.finished and cut.slot is None and cut.pages == []
+    assert cut.finished and cut.held.slot is None and cut.held.pages == []
     assert cut.generated == 3 and len(cut.tokens) == 4 + 3
     frames = []
     while not cut.out.empty():
@@ -462,7 +463,7 @@ def test_cancel_with_a_program_in_flight_drops_its_row():
     assert tokens == [fresh.generate([9, 4], max_tokens=12),
                       fresh.generate([80, 1, 33], max_tokens=5)]
     fresh.stop()
-    assert eng.pool.used == 0 and eng.slots.used == 0
+    assert eng.cache.pools["full"].used == 0 and eng.cache.slots.used == 0
 
 
 def test_stop_delivers_the_ids_left_unread():
@@ -496,7 +497,7 @@ def test_eviction_with_a_program_in_flight_drains_first():
     assert stats["evictions"] > 0
     assert stats["pipeline"]["drains"]["evict"] >= stats["evictions"] > 0
     assert stats["pipeline"]["rows_discarded"] == 0
-    assert stats["kv_pages_used"] == 0 and tight.slots.used == 0
+    assert stats["kv_pages_used"] == 0 and tight.cache.slots.used == 0
 
 
 class _Unreadable:
@@ -530,7 +531,7 @@ def test_a_device_error_surfaces_at_the_fetch_and_the_loop_lives():
         stats = eng.stats()
         assert stats["step_errors"] == 1
         assert stats["pipeline"]["drains"]["error"] == 1
-        assert stats["kv_pages_used"] == 0 and eng.slots.used == 0
+        assert stats["kv_pages_used"] == 0 and eng.cache.slots.used == 0
         assert stats["running"] == stats["waiting"] == 0
         assert not eng._flights
         # the loop lives, and serves what a fresh engine serves

@@ -420,11 +420,11 @@ def test_the_engine_serves_it_through_both_pools_without_an_edit(params):
     so the reference's); a slot that changes hands and an eviction's
     re-prefill reproduce it; stats() carry both pools' counters."""
     engine = _engine(params, max_batch=4)
-    assert list(engine._kv) == ["k_pages", "v_pages"]
-    assert engine._kv["k_pages"].shape == (2, 64, 4, 60)
-    assert engine._state["conv"].shape == (6, 4, 3, 288)
-    assert engine._state["ssm"].shape == (6, 4, 6, 12, 24)
-    assert engine._state["ssm"].dtype == jnp.float32
+    assert list(engine.cache.paged) == ["k_pages", "v_pages"]
+    assert engine.cache.paged["k_pages"].shape == (2, 64, 4, 60)
+    assert engine.cache.state["conv"].shape == (6, 4, 3, 288)
+    assert engine.cache.state["ssm"].shape == (6, 4, 6, 12, 24)
+    assert engine.cache.state["ssm"].dtype == jnp.float32
     out = _run(engine, (PROMPTS[0], 9), (PROMPTS[2], 9))
     served, _ = faults.serve(CFG, params, [PROMPTS[0], PROMPTS[2]], 9,
                              page=4)

@@ -331,7 +331,7 @@ def test_engine_counts_the_residual_path(params):
     engine = GenerationEngine(
         model_cfg=CFG, params=params, engine_cfg=EngineConfig(
             page_size=4, num_pages=64, max_batch=2))
-    assert list(engine._kv) == ["latent_pages"]
+    assert list(engine.cache.paged) == ["latent_pages"]
     assert engine.stats()["residual"]["streams"] == 4
     seqs = [engine.submit(list(p), max_tokens=5) for p in PROMPTS[:2]]
     while not all(s.finished for s in seqs):
