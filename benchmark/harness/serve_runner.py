@@ -24,6 +24,15 @@ from .manifest import Cell
 from .stats import percentile
 
 TRACE_SECONDS = 3.0
+# The capture's whole budget, from its start to the RPC's return: the
+# TRACE_SECONDS and the export of the .xplane.pb inside the replica, beside
+# the engine (~1.1 s a MB of trace: 140-152 s in
+# serve-kimi-linear-48b-a3b-longout, PERF.md section 7).  The wait after the
+# window follows it; there is no second, smaller limit.  The margin is the
+# timer's own start and the RPC's way back.
+CAPTURE_BUDGET_S = 300.0
+CAPTURE_MARGIN_S = 15.0
+SNAPSHOT_WAIT_S = 120.0
 
 
 def _warmup(handle, cell: Cell, sizes: Dict[str, int], seed: int) -> None:
@@ -52,23 +61,58 @@ def _warmup(handle, cell: Cell, sizes: Dict[str, int], seed: int) -> None:
                            f"{b['tokens']} ({b['error']})")
 
 
+def xplane_under(log_dir: str):
+    """The capture's ``.xplane.pb`` under ``log_dir``, or None."""
+    found = sorted(glob.glob(os.path.join(log_dir, "plugins", "profile",
+                                          "*", "*.xplane.pb")))
+    return found[0] if found else None
+
+
+def no_xplane_under(log_dir: str) -> str:
+    """For a failure's text: where no capture lay, and what did."""
+    lay = sorted(os.path.relpath(os.path.join(d, f), log_dir)
+                 for d, _, files in os.walk(log_dir) for f in files)
+    return (f"no *.xplane.pb under {log_dir}/plugins/profile/*/; there "
+            f"lay: {lay or 'nothing'}")
+
+
+def _still_running(t0: float, budget_s: float) -> str:
+    return ("the profiler capture was still running "
+            f"{time.perf_counter() - t0:.1f} s after its start (budget "
+            f"{budget_s:g} s): the export of the .xplane.pb in the replica "
+            "outlives CAPTURE_BUDGET_S")
+
+
 def _during(out_dir: str, box: Dict[str, Any], handle, seconds: float,
-            trace: bool):
+            trace: bool, budget_s: float = CAPTURE_BUDGET_S):
     """``during`` callback of the load generator: the engine's counters
     read at the window's start and end, and in a traced run a few seconds of profiler
-    capture in the worker that holds the chip, a third into the window."""
+    capture in the worker that holds the chip, a third into the window.
+    The capture leaves ``box["xplane"]`` with what it cost, or
+    ``box["no_capture"]``: why there is none (``_await_timers`` raises it)."""
     def capture() -> None:
         import ray_tpu
 
+        box["t_capture"] = t0 = time.perf_counter()
         try:
             log_dir = ray_tpu.get(handle.method("bench_trace").remote(
-                os.path.join(out_dir, "trace"), TRACE_SECONDS), timeout=300)
-            found = glob.glob(os.path.join(log_dir, "plugins", "profile",
-                                           "*", "*.xplane.pb"))
-            if found:
-                box["xplane"] = found[0]
+                os.path.join(out_dir, "trace"), TRACE_SECONDS),
+                timeout=budget_s)
+        except ray_tpu.GetTimeoutError:
+            box["no_capture"] = _still_running(t0, budget_s)
+            return
         except Exception as e:  # noqa: BLE001 — the run then fails
-            box["error"] = repr(e)
+            box["no_capture"] = ("the profiler capture raised after "
+                                 f"{time.perf_counter() - t0:.1f} s: {e!r}")
+            return
+        box["capture_s"] = time.perf_counter() - t0
+        path = xplane_under(log_dir)
+        if path is None:
+            box["no_capture"] = (
+                f"the profiler capture returned after {box['capture_s']:.1f}"
+                f" s and left {no_xplane_under(log_dir)}")
+            return
+        box["xplane"], box["xplane_bytes"] = path, os.path.getsize(path)
 
     def snapshot(key: str):
         def take() -> None:
@@ -83,16 +127,37 @@ def _during(out_dir: str, box: Dict[str, Any], handle, seconds: float,
         return take
 
     def during(t0: float) -> None:
-        plan = [(0.0, snapshot("at_start")), (seconds, snapshot("at_end"))] \
-            + ([(seconds / 3.0, capture)] if trace else [])
-        box["timers"] = []
-        for after_s, fn in plan:
-            timer = threading.Timer(
+        def timer(after_s: float, fn) -> threading.Timer:
+            t = threading.Timer(
                 max(after_s - (time.perf_counter() - t0), 0.0), fn)
-            timer.daemon = True
-            timer.start()
-            box["timers"].append(timer)
+            t.daemon = True
+            t.start()
+            return t
+
+        box["timers"] = [timer(0.0, snapshot("at_start")),
+                         timer(seconds, snapshot("at_end"))]
+        if trace:
+            box["capture"] = (timer(seconds / 3.0, capture), budget_s)
     return during
+
+
+def _await_timers(box: Dict[str, Any],
+                  margin_s: float = CAPTURE_MARGIN_S) -> None:
+    """After the window and the drain: the two snapshots' short wait, and
+    for the capture what is left of its budget, and the margin.  A traced
+    run that ends here without its trace says which of three things
+    happened."""
+    for timer in box["timers"]:
+        timer.join(timeout=SNAPSHOT_WAIT_S)
+    if "capture" not in box:
+        return
+    timer, budget_s = box["capture"]
+    started = box.get("t_capture", time.perf_counter())
+    timer.join(timeout=max(started + budget_s - time.perf_counter(), 0.0)
+               + margin_s)
+    if "xplane" not in box:
+        raise BenchFailure(box.get("no_capture")
+                           or _still_running(started, budget_s))
 
 
 def _settled_stats(handle, wait_s: float = 3.0) -> Dict[str, Any]:
@@ -209,8 +274,7 @@ def run(cell: Cell, args, t_process: float, out_dir: str) -> Dict[str, Any]:
             load = client.open_loop(handle, requests, seconds,
                                     cell.traffic["client_threads"], during)
         after = _settled_stats(handle)
-        for timer in box["timers"]:
-            timer.join(timeout=120)
+        _await_timers(box)
         time.sleep(1.0)          # a metrics flush tick of the replica
         memory = _xla_memory()
     except BaseException as e:  # noqa: BLE001 — reported after shutdown
@@ -249,8 +313,6 @@ def run(cell: Cell, args, t_process: float, out_dir: str) -> Dict[str, Any]:
     if after["evictions"] != before["evictions"]:
         problems.append(f"{after['evictions'] - before['evictions']} "
                         "evictions: the pool was sized so that none occurs")
-    if args.trace and "xplane" not in box:
-        problems.append(f"no profiler capture came back: {box.get('error')}")
     if not ok:
         problems.append("no request completed")
 
@@ -289,6 +351,9 @@ def run(cell: Cell, args, t_process: float, out_dir: str) -> Dict[str, Any]:
             "engine_tokens": box["at_end"]["tokens_generated"]
             - box["at_start"]["tokens_generated"],
             "memory": memory}
+    if args.trace:
+        info["capture_s"] = box["capture_s"]
+        info["xplane_bytes"] = box["xplane_bytes"]
     log("serve: " + json.dumps(info))
     # every program named here ran in set-up (the warm-up runs each bucket)
     peak = max(memory["program_total"].values(), default=0.0)

@@ -127,7 +127,10 @@ def main(argv=None) -> int:
         ctx["trace"] = None
         if args.trace:
             if not ctx.get("trace_path"):
-                raise cluster.BenchFailure("the traced run left no trace")
+                # a training run's: serve_runner raises its own three
+                raise cluster.BenchFailure(
+                    "the traced run left " + serve_runner.no_xplane_under(
+                        os.path.join(out_dir, "trace")))
             ctx["trace"] = trace.reduce(
                 trace.load(ctx["trace_path"], ctx["host_spans"]),
                 ctx["host_spans"], ctx["default_host"])
